@@ -1,8 +1,13 @@
 """Serve the flagship LM with batched generation + HTTP ingress.
 
 python examples/serve_llm.py --size tiny --replicas 1
+python examples/serve_llm.py --size small_1b --use-tpu
 Then: curl -X POST http://<addr>/LM -d '[1,2,3,4]'  (one prompt per request;
 the router groups concurrent requests into step batches)
+
+``--use-tpu`` deploys each replica with ``num_tpus=1``: its worker is the one
+process that opens its chip, so a one-chip host takes one replica. Without
+it the replicas serve from the host.
 """
 
 import argparse
@@ -15,6 +20,7 @@ def main():
     p.add_argument("--size", default="tiny")
     p.add_argument("--replicas", type=int, default=1)
     p.add_argument("--max-new-tokens", type=int, default=16)
+    p.add_argument("--use-tpu", action="store_true")
     args = p.parse_args()
 
     import ray_tpu
@@ -22,8 +28,11 @@ def main():
 
     ray_tpu.init(num_cpus=args.replicas + 2)
 
-    @serve.deployment(num_replicas=args.replicas, batch_max_size=8,
-                      batch_wait_timeout_s=0.02)
+    @serve.deployment(
+        num_replicas=args.replicas, batch_max_size=8,
+        batch_wait_timeout_s=0.02,
+        ray_actor_options={"num_tpus": 1} if args.use_tpu else None,
+    )
     class LM:
         def __init__(self, size, max_new):
             import jax

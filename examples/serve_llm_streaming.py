@@ -6,7 +6,11 @@ requests admitted between compiled multi-step decode blocks), and tokens
 stream replica -> handle -> chunked HTTP as they are produced.
 
     python examples/serve_llm_streaming.py --size tiny
+    python examples/serve_llm_streaming.py --size small_1b --use-tpu
     curl -N -X POST http://<addr>/LLM/stream -d '[1,2,3,4,5]'
+
+``--use-tpu`` deploys the replica with ``num_tpus=1``: its worker is the one
+process that opens the chip. Without it the replica serves from the host.
 """
 
 import argparse
@@ -18,6 +22,7 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument("--size", default="tiny", choices=["tiny", "small_1b"])
     p.add_argument("--max-new-tokens", type=int, default=32)
+    p.add_argument("--use-tpu", action="store_true")
     args = p.parse_args()
 
     import ray_tpu
@@ -40,8 +45,11 @@ def main():
     max_len = 128 if size == "tiny" else 512
     buckets = (16, 32) if size == "tiny" else (128, 256)
 
-    @serve.deployment(num_replicas=1,
-                      ray_actor_options={"max_concurrency": 8})
+    actor_options = {"max_concurrency": 8}
+    if args.use_tpu:
+        actor_options["num_tpus"] = 1
+
+    @serve.deployment(num_replicas=1, ray_actor_options=actor_options)
     class LLM(serve.LLMServer):
         def __init__(self):
             super().__init__(model_factory, max_slots=2, max_len=max_len,

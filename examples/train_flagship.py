@@ -1,10 +1,15 @@
 """Fine-tune the flagship LM with JaxTrainer (the BASELINE north-star shape).
 
-Single host:   python examples/train_flagship.py --size tiny --workers 1
+Host only:     python examples/train_flagship.py --size tiny --workers 1
 Simulated pod: python examples/train_flagship.py --size tiny --workers 2 \
                    --devices-per-worker 4 --dp 2 --sp 2 --tp 2
+One chip:      python examples/train_flagship.py --size bench_400m --use-tpu
 Real pod: one worker per TPU VM (the worker group assembles the global mesh
-via jax.distributed; ScalingConfig(use_tpu=True)).
+via jax.distributed), again with --use-tpu.
+
+``--use-tpu`` is ``ScalingConfig(use_tpu=True)``: each worker asks for the
+``TPU`` resource and is the one process that opens its host's chips. Without
+it the workers are pinned to the host's CPU and never touch a chip.
 """
 
 import argparse

@@ -3557,7 +3557,10 @@ class CoreWorker:
         self._shutdown.set()
         install_ref_hooks(None, None)
         try:
-            self.io.run(self.server.stop_async())
+            # Bounded: this wait has been seen to last forever with the IO
+            # loop idle (a rare flake, also at the PR 22 parent; cause not
+            # found). The connections are closed just below either way.
+            self.io.submit(self.server.stop_async()).result(timeout=5)
         except Exception:
             pass
         for c in (self.gcs, self.raylet):

@@ -1408,8 +1408,20 @@ class GcsServer:
         period = GLOBAL_CONFIG.health_check_period_ms / 1e3
         timeout = GLOBAL_CONFIG.health_check_timeout_ms / 1e3
         while True:
+            slept = time.monotonic()
             await asyncio.sleep(period)
             now = time.monotonic()
+            late = now - slept - period
+            if late > period:
+                # This process was not running — its loop was blocked, or
+                # the whole host froze, as it does for seconds per chip
+                # while a process opens a TPU — so it could take no
+                # heartbeat either: its own pause is not the nodes' silence.
+                logger.warning(
+                    "health check ran %.1fs late (this process or the "
+                    "host stalled): not counted against the nodes", late)
+                for nid in self.node_heartbeat:
+                    self.node_heartbeat[nid] += late
             for nid, last in list(self.node_heartbeat.items()):
                 info = self.nodes.get(nid)
                 if info is not None and info.alive and now - last > timeout:

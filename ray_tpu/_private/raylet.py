@@ -262,7 +262,7 @@ class WorkerHandle:
         self.addr: str = ""  # worker's own RPC server address
         self.lease_id: Optional[bytes] = None
         self.actor_id: Optional[bytes] = None
-        self.tpu = False  # spawned with TPU runtime env (site hooks intact)
+        self.tpu = False  # TPU-flavour: pinned to the chip (node.worker_env)
         self.registered = asyncio.Event()
 
     @property
@@ -325,6 +325,9 @@ class Raylet:
         self.gcs: Optional[rpc.Connection] = None
         # workers
         self.workers: Dict[bytes, WorkerHandle] = {}
+        # every TPU-flavour process spawned and not yet reaped: starting,
+        # serving or dying, each holds (or is about to open) the chip
+        self._tpu_procs: List[subprocess.Popen] = []
         self.idle: List[WorkerHandle] = []
         self.leases: Dict[bytes, Lease] = {}
         self.drivers: Dict[bytes, rpc.Connection] = {}
@@ -810,11 +813,19 @@ class Raylet:
                     misses = 0
                     self.gcs._do_close()
             self._pump_infeasible(expire=True)
+            slept = time.monotonic()
             await asyncio.sleep(period)
+            late = time.monotonic() - slept - period
+            if late > 2.0:
+                # the GCS declares this node dead after
+                # health_check_timeout_ms without a heartbeat: name the gap
+                logger.warning(
+                    "heartbeat ran %.1fs late: this process's event loop "
+                    "or the host stalled", late)
 
     # ------------- worker pool -------------
     def _start_worker_process(self, tpu: bool = False) -> WorkerHandle:
-        from ray_tpu._private.node import clean_env
+        from ray_tpu._private.node import worker_env
 
         worker_id = WorkerID.from_random().binary()
         log_dir = os.path.join(self.session_dir, "logs")
@@ -831,15 +842,15 @@ class Raylet:
             "--worker-id", worker_id.hex(),
             "--session-dir", self.session_dir,
         ]
-        env = clean_env(tpu=tpu)
-        env["RAYTPU_WORKER"] = "1"
         proc = subprocess.Popen(
-            cmd, stdout=out, stderr=subprocess.STDOUT, env=env,
+            cmd, stdout=out, stderr=subprocess.STDOUT, env=worker_env(tpu),
             start_new_session=True,
         )
         out.close()
         w = WorkerHandle(worker_id, proc)
         w.tpu = tpu
+        if tpu:
+            self._tpu_procs.append(proc)
         self.workers[worker_id] = w
         self._ever_workers.add(worker_id)
         return w
@@ -895,7 +906,32 @@ class Raylet:
                 pass
         if w.proc is not None and w.proc.poll() is None:
             w.proc.terminate()
+            if w.tpu:
+                # the chip is free only once the process is gone, and the
+                # next TPU worker is held back until then (_chip_held)
+                await self._wait_worker_gone(w.proc)
         self._pump_lease_queue()
+
+    @staticmethod
+    async def _wait_worker_gone(proc: subprocess.Popen,
+                                grace_s: float = 10.0):
+        """Wait until a signalled worker has been reaped; a worker that
+        ignores SIGTERM for ``grace_s`` is killed."""
+        deadline = time.monotonic() + grace_s
+        while proc.poll() is None:
+            if time.monotonic() >= deadline:
+                proc.kill()
+                deadline = float("inf")
+            await asyncio.sleep(0.02)
+
+    def _chip_held(self) -> bool:
+        """One process per chip: on a one-chip node a second TPU-flavour
+        worker could not open the device, so none is started while one
+        is starting, alive or not yet reaped."""
+        self._tpu_procs = [p for p in self._tpu_procs if p.poll() is None]
+        return bool(self._tpu_procs) and (
+            self.total_resources.get("TPU", 0) == 1
+        )
 
     # ------------- resources -------------
     def _can_fit(self, resources: Dict[str, float]) -> bool:
@@ -1513,6 +1549,8 @@ class Raylet:
         ]
         for wid in dead_boot:
             self.workers.pop(wid, None)
+        if tpu and self._chip_held():
+            return
         starting = sum(
             1 for w in self.workers.values()
             if not w.registered.is_set() and w.tpu == tpu

@@ -312,9 +312,9 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
     """``steps`` decode iterations as ONE compiled program with on-device
     per-slot sampling — the serving engine's unit of work. One host
     transfer ([B, steps] int32 tokens) per block instead of per token:
-    essential when the host<->device link has real latency (remote-TPU
-    tunnel; same trick as decode_loop, but with per-slot positions so
-    slots admitted at different times share the batch).
+    the host<->device link's latency is paid once per block (same trick
+    as decode_loop, but with per-slot positions so slots admitted at
+    different times share the batch).
 
     Returns (tokens [B, steps], cache, token', pos', counts')."""
     def step(carry, _):

@@ -26,16 +26,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS_TPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_PALLAS_TPU = False
-
-from ray_tpu.ops.attention import NEG_INF, causal_attention, repeat_kv
+from ray_tpu.ops.attention import NEG_INF, repeat_kv
 
 # Lane width: scratch row-stat buffers (m, l) are replicated across 128 lanes.
 _LANES = 128
@@ -333,19 +326,16 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention on one device (or one shard under shard_map).
 
-    Falls back to the dense XLA path when the sequence does not tile or the
-    Pallas TPU backend is unavailable (pure-CPU wheels).
+    Always the Pallas kernel: ``interpret`` defaults to the interpreter on
+    the CPU backend (tests) and to the compiled kernel everywhere else.
     """
     b, s, h, d = q.shape
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     # Snap blocks to divisors of the sequence: a seq divisible by 512 but
-    # not 1024 must still use the kernel (with 512 tiles), not the dense
-    # O(S^2) fallback.
+    # not 1024 uses 512 tiles, and one that no block divides is one tile.
     block_q = _fit_block(block_q, s)
     block_kv = _fit_block(block_kv, s)
-    if (not _HAVE_PALLAS_TPU) or s % block_q or s % block_kv:
-        return causal_attention(q, k, v, causal=causal)
     n_rep = h // k.shape[2]
     k = repeat_kv(k, n_rep)
     v = repeat_kv(v, n_rep)
@@ -384,9 +374,7 @@ def flash_attention_sharded(
         )
     spec = jax.sharding.PartitionSpec(dp_axis, None, tp_axis, None)
     kv_spec = spec
-    from ray_tpu.mesh.plan import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         functools.partial(flash_attention, **kw),
         mesh=mesh,
         in_specs=(spec, kv_spec, kv_spec),

@@ -29,9 +29,7 @@ from ray_tpu.ops.attention import (
 
 def _ring_body(q, k, v, *, axis_name: str, seq_len_per_shard: int):
     """Runs on one device inside shard_map; q/k/v are local blocks [B,Sl,H,D]."""
-    from ray_tpu.mesh.plan import axis_size as _axis_size
-
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, sl, h, d = q.shape
     n_rep = h // k.shape[2]
@@ -86,9 +84,7 @@ def ring_attention(
     body = partial(
         _ring_body, axis_name=axis_name, seq_len_per_shard=q.shape[1] // sp
     )
-    from ray_tpu.mesh.plan import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

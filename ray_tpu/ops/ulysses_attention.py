@@ -25,9 +25,7 @@ from ray_tpu.ops.attention import causal_attention, repeat_kv
 
 def _ulysses_body(q, k, v, *, axis_name: str, local_attn):
     """Runs per-device inside shard_map; q/k/v local [B, S/sp, H, D]."""
-    from ray_tpu.mesh.plan import axis_size as _axis_size
-
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     n_rep = q.shape[2] // k.shape[2]
     if k.shape[2] % sp:
         # too few kv heads to split: replicate them up to the q head count
@@ -78,9 +76,7 @@ def ulysses_attention(
     else:
         local_attn = causal_attention
     spec = P(dp_axis, axis_name, tp_axis, None)
-    from ray_tpu.mesh.plan import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         partial(_ulysses_body, axis_name=axis_name, local_attn=local_attn),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -225,9 +225,7 @@ def pipeline_loss_fn(
     mask = batch.get("mask")
     if mask is None:
         mask = jnp.ones(batch["tokens"].shape, jnp.float32)
-    from ray_tpu.mesh.plan import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspecs, data_spec, data_spec, data_spec),
@@ -463,9 +461,7 @@ def pipeline_grads_1f1b(
     mask = batch.get("mask")
     if mask is None:
         mask = jnp.ones(batch["tokens"].shape, jnp.float32)
-    from ray_tpu.mesh.plan import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspecs, data_spec, data_spec, data_spec),

@@ -14,8 +14,9 @@ unmeasurable, this module:
    test asserts agreement), so the scale-out arithmetic stands on
    compiler-measured ground, not hand-waving.
 3. **Combines it with published v5p roofline numbers and the measured
-   single-chip efficiency anchor** (BENCH_r04: 57.9% MFU at 367M on one
-   v5e with the same flash+remat train step) into a stated v5p-64 MFU
+   single-chip efficiency anchor** (an earlier one-chip run, no longer on
+   record: 57.9% MFU at 367M on one v5e with the same flash+remat train
+   step) into a stated v5p-64 MFU
    estimate with every assumption listed in the result.
 
 Run: ``python -m ray_tpu.parallel.projection`` (or the
@@ -135,8 +136,6 @@ def extract_device_cost(
     batch = {"tokens": tok, "targets": tok, "mask": msk}
     compiled = step.lower(abstract_state, batch).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
     mem = compiled.memory_analysis()
     return {
         "flops_per_device": float(ca.get("flops", 0.0)),
@@ -180,8 +179,9 @@ def project_v5p64(
 
     ``efficiency_anchor`` is the fraction of peak the per-device matmul
     stream achieves on real silicon — anchored to the MEASURED
-    single-chip train MFU of this repo's identical step (BENCH_r04:
-    0.579 at 367M/seq-2048 on v5e, 0.52 at seq 8192), discounted to
+    single-chip train MFU of this repo's identical step (an earlier
+    one-chip run, no longer on record: 0.579 at 367M/seq-2048 on v5e,
+    0.52 at seq 8192), discounted to
     0.55 for the larger weights' HBM traffic. ``dp_overlap`` is the
     fraction of the dp gradient all-reduce hidden behind the backward
     pass (the 1F1B tail leaves less room than full DP overlap).
@@ -278,8 +278,9 @@ def project_v5p64(
             "(3D torus; ring collectives use 2 links of an axis)",
             "v5p-64 = 32 chips (megacore: 1 device per chip)",
             f"efficiency anchor {efficiency_anchor}: measured 0.579 "
-            "single-chip MFU of this exact train step at 367M "
-            "(BENCH_r04), discounted for 6B HBM weight traffic",
+            "single-chip MFU of this exact train step at 367M (an "
+            "earlier one-chip run, no longer on record), discounted "
+            "for 6B HBM weight traffic",
             f"dp all-reduce {dp_overlap:.0%} overlapped with backward",
             "tp all-reduces and pp sends serialize with compute "
             "(no overlap credit — conservative)",

@@ -242,9 +242,7 @@ def test_in_graph_collective_verbs():
         gathered = in_graph.allgather(x, "dp")
         return total, gathered
 
-    from ray_tpu.mesh.plan import get_shard_map
-
-    total, gathered = get_shard_map()(
+    total, gathered = jax.shard_map(
         body, mesh=mesh, in_specs=P("dp"),
         out_specs=(P(), P("dp", None)), check_vma=False,
     )(xs)

@@ -101,12 +101,28 @@ def test_oom_monitor_kills_newest_lease_and_task_retries(tmp_path):
             _t.sleep(0.8)
             return "survived"
 
+        from ray_tpu._private.worker import global_worker
+
+        logs = os.path.join(global_worker.cluster.session_dir, "logs")
+
+        def monitor_killed():
+            # the raylet's own word for it: a kill can land before the
+            # attempt has written its marker, and on a loaded box the
+            # first attempt can start later than any fixed sleep
+            return any(
+                "killing worker" in open(os.path.join(logs, f)).read()
+                for f in os.listdir(logs) if f.startswith("raylet-")
+            )
+
         ref = slow.remote(str(marker_dir))
-        time.sleep(1.0)  # monitor kills the first attempt(s)
+        deadline = time.monotonic() + 30
+        while not monitor_killed():  # monitor kills the first attempt(s)
+            assert time.monotonic() < deadline, (
+                "the OOM monitor never killed an attempt"
+            )
+            time.sleep(0.05)
         fake.write_text("0.0")  # relax pressure: next retry completes
         assert ray_tpu.get(ref, timeout=60) == "survived"
-        attempts = len(list(marker_dir.iterdir()))
-        assert attempts >= 2, "the OOM monitor never killed an attempt"
     finally:
         os.environ.pop("RAYTPU_FAKE_MEM_USAGE_FILE", None)
         ray_tpu.shutdown()
