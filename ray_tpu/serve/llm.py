@@ -23,20 +23,63 @@ Token streaming rides the caller-owned streaming generator protocol
 
 from __future__ import annotations
 
+import bisect
 import collections
+import itertools
 import queue
 import threading
-from typing import Callable, List, Optional
+import time
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 
 _END = object()
+_RID = itertools.count(1)  # process-unique request ids, shared by spans
+
+# Upper bucket edges of the latency histograms in ``LLMEngine.stats()``,
+# in ms: ten a decade (ratio 1.26) from 1 ms to 10 s. Fixed, so that two
+# snapshots, or two replicas, subtract and add bucket by bucket.
+_HIST_BOUNDS_MS = tuple(round(10 ** (i / 10), 3) for i in range(41))
+
+_COUNTERS = (
+    "requests_submitted", "requests_admitted", "requests_first_emitted",
+    "requests_finished", "requests_cancelled", "requests_failed",
+    "prefill_tokens", "prefill_padded_tokens", "tokens_emitted",
+    "slot_steps", "capacity_steps",
+)
+_PHASES = (
+    "admit_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
+    "block_sync_s", "block_emit_s", "idle_wait_s", "loop_s",
+)
+
+
+class _Histogram:
+    """Counts per fixed bucket, with the exact sum and count. One writer
+    (the engine thread); ``counts[i]`` holds ``bounds[i-1] <= x <
+    bounds[i]``, the last bucket everything from the last edge up."""
+
+    __slots__ = ("counts", "sum", "count")
+
+    def __init__(self):
+        self.counts = [0] * (len(_HIST_BOUNDS_MS) + 1)
+        self.sum = 0.0
+        self.count = 0
+
+    def add(self, ms: float) -> None:
+        self.counts[bisect.bisect_right(_HIST_BOUNDS_MS, ms)] += 1
+        self.sum += ms
+        self.count += 1
+
+    def snapshot(self) -> Dict:
+        return {"counts": list(self.counts), "sum": self.sum,
+                "count": self.count}
 
 
 class _Request:
     __slots__ = ("prompt", "max_new_tokens", "temperature", "out", "seed",
-                 "produced", "cancelled", "finished")
+                 "produced", "cancelled", "finished",
+                 "rid", "t_submit", "t_admit", "t_first")
 
     def __init__(self, prompt, max_new_tokens, temperature, seed):
         self.prompt = prompt
@@ -47,6 +90,9 @@ class _Request:
         self.produced = 0
         self.cancelled = False
         self.finished = False
+        self.rid = next(_RID)
+        # time.perf_counter() at submit / pop from pending / first token
+        self.t_submit = self.t_admit = self.t_first = 0.0
 
 
 class LLMEngine:
@@ -74,6 +120,12 @@ class LLMEngine:
 
         self._jax = jax
         self._jnp = jnp
+        # Host spans in the profiler's own trace (same file and clock as
+        # the device plane; a no-op while no trace runs). Rule for cost:
+        # a span's arguments are integers the loop already holds or counts
+        # in O(max_slots); no span takes a lock or reads the device; one
+        # span per phase per iteration.
+        self._span = jax.profiler.TraceAnnotation
         params, config = prepare_for_inference(params, config)
         self.params = params
         self.config = config
@@ -114,6 +166,16 @@ class LLMEngine:
         self._stop = False
         self._failure: Optional[BaseException] = None
         self._steps = 0  # decode iterations (observability)
+        # Counters behind stats(): written by the engine thread only
+        # (requests_submitted: by submit(), under the lock). Every key
+        # exists from the start, so a reader's copy never sees a resize.
+        self._n: Dict[str, int] = dict.fromkeys(_COUNTERS, 0)
+        self._t: Dict[str, float] = dict.fromkeys(_PHASES, 0.0)
+        self._blocks_by_steps: Dict[int, int] = dict.fromkeys(
+            (self.burst_block_steps, self.block_steps), 0)
+        self._hist: Dict[str, _Histogram] = {
+            k: _Histogram() for k in (
+                "queue_wait_ms", "admit_to_first_ms", "submit_to_first_ms")}
         # Warm BOTH static-K decode variants before accepting traffic:
         # the first load-threshold crossing would otherwise trigger a
         # seconds-scale XLA compile mid-burst — the exact moment the
@@ -161,8 +223,10 @@ class LLMEngine:
             raise RuntimeError(
                 "LLMEngine is not running"
             ) from self._failure
+        req.t_submit = time.perf_counter()
         with self._lock:
             self.pending.append(req)
+            self._n["requests_submitted"] += 1
         self._work.set()
         return req
 
@@ -185,13 +249,55 @@ class LLMEngine:
     def generate(self, prompt_ids, **kw) -> List[int]:
         return list(self.generate_stream(prompt_ids, **kw))
 
-    def stats(self):
+    def stats(self) -> Dict:
+        """What the engine counted since it started, as plain numbers,
+        lists and dicts; copied without stopping the loop, so a snapshot
+        is consistent per field, not across fields. Subtract two
+        snapshots for a rate or a window's distribution.
+
+        - ``steps`` decode steps dispatched, ``active`` occupied slots,
+          ``pending`` requests waiting for a slot.
+        - Requests at each boundary: ``requests_submitted``, ``_admitted``
+          (popped from ``pending``, prefill dispatched),
+          ``_first_emitted``, ``_finished`` (ran to ``max_new_tokens`` or
+          EOS), ``_cancelled`` (consumer gone: dropped at admission or
+          freed mid-decode), ``_failed`` (ended by the loop's exit:
+          device error or shutdown). ``prefill_tokens`` (unpadded) and
+          ``prefill_padded_tokens`` (the bucket's length) per admission;
+          ``tokens_emitted``.
+        - Histograms of ms (``counts`` per bucket of ``hist_bounds_ms``,
+          one more than edges: ``counts[i]`` holds ``bounds[i-1] <= x <
+          bounds[i]``; exact ``sum`` and ``count``): ``queue_wait_ms``
+          submit -> popped from ``pending``
+          (host time: waiting for the loop to come round and for a free
+          slot; the prefill is then dispatched, not started),
+          ``admit_to_first_ms`` popped -> first token put on the
+          request's queue (the device's queue ahead of the prefill, the
+          prefill, the hold until the next block is dispatched, the
+          sync), ``submit_to_first_ms`` the two together.
+        - Seconds the loop spent per phase: ``admit_s``, ``dispatch_s``,
+          ``firsts_sync_s`` and ``block_sync_s`` (blocked on the device),
+          ``firsts_emit_s``, ``block_emit_s``, ``idle_wait_s`` (nothing to
+          do), and ``loop_s``, the sum of whole iterations: what no phase
+          covers is the difference.
+        - Per dispatched block: ``blocks_by_steps`` ``{"2": n, "8": n}``,
+          ``slot_steps`` (live slots x steps), ``capacity_steps``
+          (``max_slots`` x steps).
+        """
         with self._lock:
-            return {
+            out = {
                 "steps": self._steps,
                 "active": sum(r is not None for r in self.slot_req),
                 "pending": len(self.pending),
             }
+        out.update(self._n)
+        out.update(self._t)
+        out["blocks_by_steps"] = {
+            str(k): n for k, n in self._blocks_by_steps.items()}
+        out["hist_bounds_ms"] = list(_HIST_BOUNDS_MS)
+        for name, h in self._hist.items():
+            out[name] = h.snapshot()
+        return out
 
     def shutdown(self):
         self._stop = True
@@ -214,33 +320,45 @@ class LLMEngine:
         from ray_tpu.models.generation import prefill_into_slot
 
         jnp = self._jnp
-        while True:
-            with self._lock:
-                free = next(
-                    (i for i, r in enumerate(self.slot_req) if r is None),
-                    None,
-                )
-                if free is None or not self.pending:
-                    return
-                req = self.pending.popleft()
-            if req.cancelled:
-                continue
-            n = len(req.prompt)
-            bucket = self._bucket_for(n)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = req.prompt
-            logits, self.cache = prefill_into_slot(
-                self.params, jnp.asarray(padded), jnp.int32(n),
-                jnp.int32(free), self.cache, self.config,
-            )
-            first = self._first_token(logits, req.temperature, req.seed)
-            self.tok = self.tok.at[free].set(first)
-            self.pos = self.pos.at[free].set(n)
-            self.temps = self.temps.at[free].set(req.temperature)
-            self.seeds = self.seeds.at[free].set(req.seed)
-            self.counts = self.counts.at[free].set(1)
-            self.slot_req[free] = req
-            self._pending_first.append((req, first))
+        with self._span("raytpu.engine.admit", pending=len(self.pending)):
+            while True:
+                with self._lock:
+                    free = next(
+                        (i for i, r in enumerate(self.slot_req)
+                         if r is None),
+                        None,
+                    )
+                    if free is None or not self.pending:
+                        return
+                    req = self.pending.popleft()
+                if req.cancelled:
+                    self._n["requests_cancelled"] += 1
+                    continue
+                req.t_admit = time.perf_counter()
+                n = len(req.prompt)
+                bucket = self._bucket_for(n)
+                self._n["requests_admitted"] += 1
+                self._n["prefill_tokens"] += n
+                self._n["prefill_padded_tokens"] += bucket
+                self._hist["queue_wait_ms"].add(
+                    (req.t_admit - req.t_submit) * 1e3)
+                with self._span("raytpu.engine.prefill", rid=req.rid,
+                                tokens=n, bucket=bucket, slot=free):
+                    padded = np.zeros((1, bucket), np.int32)
+                    padded[0, :n] = req.prompt
+                    logits, self.cache = prefill_into_slot(
+                        self.params, jnp.asarray(padded), jnp.int32(n),
+                        jnp.int32(free), self.cache, self.config,
+                    )
+                    first = self._first_token(
+                        logits, req.temperature, req.seed)
+                    self.tok = self.tok.at[free].set(first)
+                    self.pos = self.pos.at[free].set(n)
+                    self.temps = self.temps.at[free].set(req.temperature)
+                    self.seeds = self.seeds.at[free].set(req.seed)
+                    self.counts = self.counts.at[free].set(1)
+                self.slot_req[free] = req
+                self._pending_first.append((req, first))
 
     def _first_token(self, logits, temperature, seed):
         """On-device first-token sample (scalar int32, not synced)."""
@@ -262,14 +380,24 @@ class LLMEngine:
         if req is None or req.finished:
             return True
         req.out.put(token)
+        if req.produced == 0:
+            req.t_first = time.perf_counter()
+            self._n["requests_first_emitted"] += 1
+            self._hist["admit_to_first_ms"].add(
+                (req.t_first - req.t_admit) * 1e3)
+            self._hist["submit_to_first_ms"].add(
+                (req.t_first - req.t_submit) * 1e3)
         req.produced += 1
-        done = (
+        self._n["tokens_emitted"] += 1
+        complete = (
             req.produced >= req.max_new_tokens
             or (self.eos_id is not None and token == self.eos_id)
-            or req.cancelled
         )
+        done = complete or req.cancelled
         if done:
             req.finished = True
+            self._n["requests_finished" if complete
+                    else "requests_cancelled"] += 1
             req.out.put(_END)
         return done
 
@@ -281,19 +409,29 @@ class LLMEngine:
         __init__): light load -> short blocks -> short admission waits."""
         from ray_tpu.models.generation import decode_block
 
-        active = sum(
-            r is not None and not r.finished for r in self.slot_req
-        )
+        live = [r for r in self.slot_req
+                if r is not None and not r.finished]
+        active = len(live)
         steps = (
             self.block_steps
             if active > self.max_slots // 2
             else self.burst_block_steps
         )
-        toks, self.cache, self.tok, self.pos, self.counts = decode_block(
-            self.params, self.cache, self.tok, self.pos, self.temps,
-            self.seeds, self.counts, self.config, steps,
-        )
+        with self._span(
+            "raytpu.engine.dispatch", steps=steps, live=active,
+            kv_rows=sum(len(r.prompt) + r.produced for r in live),
+            firsts=len(self._pending_first), pending=len(self.pending),
+        ):
+            toks, self.cache, self.tok, self.pos, self.counts = (
+                decode_block(
+                    self.params, self.cache, self.tok, self.pos,
+                    self.temps, self.seeds, self.counts, self.config,
+                    steps,
+                ))
         self._steps += steps
+        self._blocks_by_steps[steps] += 1
+        self._n["slot_steps"] += active * steps
+        self._n["capacity_steps"] += self.max_slots * steps
         snapshot = list(self.slot_req)  # slot -> req at dispatch
         return toks, snapshot
 
@@ -305,61 +443,84 @@ class LLMEngine:
         firsts, self._pending_first = self._pending_first, []
         if not firsts:
             return
-        vals = np.asarray(self._jnp.stack([t for _, t in firsts]))
-        for (req, _), v in zip(firsts, vals):
-            self._emit(req, int(v))
+        # rids as one string; a comma would end the value in the
+        # profiler's "name#k=v,k=v#" encoding, so they are space-separated
+        with self._span("raytpu.engine.retire_firsts", n=len(firsts),
+                        rids=" ".join(str(r.rid) for r, _ in firsts)):
+            stacked = self._jnp.stack([t for _, t in firsts])
+            t0 = time.perf_counter()
+            vals = np.asarray(stacked)
+            t1 = time.perf_counter()
+            for (req, _), v in zip(firsts, vals):
+                self._emit(req, int(v))
+            self._t["firsts_sync_s"] += t1 - t0
+            self._t["firsts_emit_s"] += time.perf_counter() - t1
 
     def _retire_block(self, toks_dev, snapshot):
         """Host-sync one block's tokens and deliver them in step order."""
-        toks = np.asarray(toks_dev)  # [B, K] — THE one sync per block
-        for k in range(toks.shape[1]):
+        with self._span(
+            "raytpu.engine.retire_block", steps=toks_dev.shape[1],
+            live=sum(r is not None and not r.finished for r in snapshot),
+        ):
+            t0 = time.perf_counter()
+            toks = np.asarray(toks_dev)  # [B, K] — THE one sync per block
+            t1 = time.perf_counter()
+            for k in range(toks.shape[1]):
+                for slot, req in enumerate(snapshot):
+                    if req is None or req.finished:
+                        continue
+                    self._emit(req, int(toks[slot, k]))
+            # free slots whose requests finished (table may already have
+            # a NEWER request in the slot — only clear if it's still this
+            # one)
             for slot, req in enumerate(snapshot):
-                if req is None or req.finished:
-                    continue
-                self._emit(req, int(toks[slot, k]))
-        # free slots whose requests finished (table may already have a
-        # NEWER request in the slot — only clear if it's still this one)
-        for slot, req in enumerate(snapshot):
-            if req is not None and req.finished and (
-                self.slot_req[slot] is req
-            ):
-                self.slot_req[slot] = None
+                if req is not None and req.finished and (
+                    self.slot_req[slot] is req
+                ):
+                    self.slot_req[slot] = None
+            self._t["block_sync_s"] += t1 - t0
+            self._t["block_emit_s"] += time.perf_counter() - t1
 
     def _loop(self):
         inflight: "collections.deque" = collections.deque()
         depth = 1 if self.pipeline else 0
+        clock, spent = time.perf_counter, self._t
         try:
             while not self._stop:
+                t0 = clock()
                 self._admit()
+                t1 = clock()
+                spent["admit_s"] += t1 - t0
                 active = any(r is not None and not r.finished
                              for r in self.slot_req)
                 if active:
                     inflight.append(self._dispatch_block())
+                    spent["dispatch_s"] += clock() - t1
                     self._retire_firsts()  # sync waits on prefills only
                 while len(inflight) > (depth if active else 0):
                     self._retire_block(*inflight.popleft())
                 if not active and not self.pending and not inflight:
-                    self._work.wait(timeout=0.05)
-                    self._work.clear()
+                    t2 = clock()
+                    with self._span("raytpu.engine.idle"):
+                        self._work.wait(timeout=0.05)
+                        self._work.clear()
+                    spent["idle_wait_s"] += clock() - t2
+                spent["loop_s"] += clock() - t0
         except BaseException as e:  # device error / teardown
             self._failure = e
         finally:
             # no consumer may block forever on a dead engine: fail every
             # live and pending request explicitly
             err = self._failure or RuntimeError("LLMEngine shut down")
-            for req in list(self.slot_req) + [r for r, _ in
-                                              self._pending_first]:
-                if req is not None and not req.finished:
-                    req.finished = True
-                    req.out.put(err if self._failure else _END)
-                    req.out.put(_END)
             with self._lock:
                 pending, self.pending = list(self.pending), (
                     collections.deque()
                 )
-            for req in pending:
-                if not req.finished:
+            for req in list(self.slot_req) + [
+                    r for r, _ in self._pending_first] + pending:
+                if req is not None and not req.finished:
                     req.finished = True
+                    self._n["requests_failed"] += 1
                     req.out.put(err if self._failure else _END)
                     req.out.put(_END)
 

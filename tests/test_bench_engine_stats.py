@@ -1,0 +1,128 @@
+"""The benchmark's readers of ``LLMEngine.stats()`` snapshots
+(``benchmarks/readers/engine_stats.py``) on hand-made snapshots, and the
+six per-layer metrics that name them. No JAX in this process."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks import common  # noqa: E402
+
+common.load_plugins(os.path.join(ROOT, "benchmarks"))
+percentile = common.READERS["stats_delta_hist_percentile"]
+ratio = common.READERS["stats_delta_ratio"]
+
+BOUNDS = [1.0, 10.0, 100.0, 1000.0]  # five buckets: <1, 1-10, ..., >=1000
+NEW_METRICS = {
+    "serve-chat-steady": [
+        "engine.queue_wait_p50_ms", "engine.queue_wait_p90_ms.chat",
+        "engine.admit_to_first_p50_ms", "engine.admit_to_first_p90_ms.chat",
+        "engine.prefill_pad_share"],
+    "serve-doc-burst": [
+        "engine.queue_wait_p50_ms", "engine.admit_to_first_p50_ms",
+        "engine.prefill_pad_share"],
+    "serve-chat-saturated": ["engine.loop_host_share"],
+    "train4-gptj-seq2048": [],
+}
+
+
+def _facts(mid_counts, end_counts, **scalars):
+    def snap(counts, which):
+        s = {"steps": 0, "active": 0, "pending": 0,
+             "hist_bounds_ms": BOUNDS,
+             "h": {"counts": counts, "sum": 0.0, "count": sum(counts)}}
+        s.update({k: v[which] for k, v in scalars.items()})
+        return s
+    return {"backlog": {"mid": snap(mid_counts, 0),
+                        "end": snap(end_counts, 1)}}
+
+
+@pytest.mark.parametrize("mid,end,q,want", [
+    # ten in [10, 100): rank 5 of 10 is half way through the bucket
+    ([0, 0, 0, 0, 0], [0, 0, 10, 0, 0], 50, 55.0),
+    # four in [1, 10), six in [10, 100): rank 9 is 5/6 through the second
+    ([0, 0, 0, 0, 0], [0, 4, 6, 0, 0], 90, 10.0 + 90.0 * 5 / 6),
+    # rank 5 of 10 falls on the last of the first bucket's five: its edge
+    ([0, 0, 0, 0, 0], [5, 5, 0, 0, 0], 50, 1.0),
+    # the first bucket starts at 0
+    ([0, 0, 0, 0, 0], [4, 0, 0, 0, 0], 50, 0.5),
+    # the last bucket has no upper edge: its lower one
+    ([0, 0, 0, 0, 0], [0, 0, 0, 0, 3], 50, 1000.0),
+    # only the difference counts: what mid already held is not in it
+    ([7, 7, 0, 0, 0], [7, 7, 0, 2, 0], 50, 550.0),
+    # nothing observed between the snapshots
+    ([1, 2, 3, 0, 0], [1, 2, 3, 0, 0], 50, None),
+])
+def test_hist_percentile_of_the_difference(mid, end, q, want):
+    got = percentile(_facts(mid, end), {"hist": "h", "q": q})
+    assert got == (None if want is None else pytest.approx(want))
+
+
+@pytest.mark.parametrize("facts", [
+    # a commit before the counters existed: the old three keys only
+    {"backlog": {"mid": {"steps": 1, "active": 0, "pending": 0},
+                 "end": {"steps": 9, "active": 0, "pending": 0}}},
+    {"backlog": {"mid": _facts([0] * 5, [1] * 5)["backlog"]["mid"]}},
+    {"backlog": {}},
+    {},
+])
+def test_readers_leave_the_metric_out_where_a_key_is_missing(facts):
+    assert percentile(facts, {"hist": "h", "q": 50}) is None
+    assert ratio(facts, {"num": ["a"], "den": ["b"], "scale": 100.0}) is None
+
+
+def test_ratio_of_summed_differences_with_subtracted_keys():
+    facts = _facts([0] * 5, [0] * 5, padded=(1000, 1640), real=(700, 1100),
+                   loop_s=(10.0, 30.0), firsts_sync_s=(1.0, 3.0),
+                   block_sync_s=(4.0, 12.0), idle_wait_s=(2.0, 6.0),
+                   frozen=(5, 5))
+    pad = {"num": ["padded", "-real"], "den": ["padded"], "scale": 100.0}
+    assert ratio(facts, pad) == pytest.approx(100.0 * (640 - 400) / 640)
+    host = {"num": ["loop_s", "-firsts_sync_s", "-block_sync_s",
+                    "-idle_wait_s"],
+            "den": ["loop_s", "-idle_wait_s"], "scale": 100.0}
+    assert ratio(facts, host) == pytest.approx(100.0 * (20 - 2 - 8 - 4) / 16)
+    assert ratio(facts, {"num": ["real"], "den": ["padded"]}) == (
+        pytest.approx(400 / 640))
+    assert ratio(facts, {"num": ["real"], "den": ["frozen"]}) is None
+    assert ratio(facts, {"num": ["real", "nope"], "den": ["padded"]}) is None
+
+
+def test_the_six_metrics_resolve_in_their_cells():
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
+         "--list"], capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    rows = {r["cell"]: r for r in map(json.loads, out.stdout.splitlines())}
+    mine = ("stats_delta_hist_percentile", "stats_delta_ratio")
+    for cell, names in NEW_METRICS.items():
+        got = [k for k, rd in rows[cell]["per_layer"].items() if rd in mine]
+        assert got == names, cell
+
+
+def test_cpu_rehearsal_walks_the_new_readers():
+    """The whole control flow on the host at rehearsal sizes: the engine's
+    counters reach the readers through the runner's two snapshots. Exit
+    code 10: never a result."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
+         "--workload", "serve-chat-steady", "--seed", "2147483999",
+         "--seconds", "6", "--trace", "1", "--rehearse-cpu"],
+        capture_output=True, text=True, timeout=600, env=env)
+    assert out.returncode == common.REHEARSAL_RC, out.stdout[-3000:]
+    walked = next(json.loads(ln.split(": ", 1)[1])
+                  for ln in out.stdout.splitlines()
+                  if ln.startswith("readers walked on the host"))
+    for name in NEW_METRICS["serve-chat-steady"]:
+        assert isinstance(walked[name], float), (name, walked[name])
+    assert 0.0 <= walked["engine.prefill_pad_share"] < 100.0
+    assert walked["engine.queue_wait_p50_ms"] <= (
+        walked["engine.queue_wait_p90_ms.chat"])

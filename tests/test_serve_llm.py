@@ -246,3 +246,228 @@ def test_engine_failure_unblocks_consumers():
     # engine is dead: new submissions are refused, not silently queued
     with pytest.raises(RuntimeError, match="not running"):
         eng.submit(np.arange(4, dtype=np.int32), max_new_tokens=2)
+
+
+# ---------------- the engine's own counters and spans ----------------
+
+
+def _settled_stats(eng, timeout_s=30.0):
+    """stats() once the loop has freed every slot (the consumer sees a
+    request's end one step before the loop clears its slot)."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        s = eng.stats()
+        if s["active"] == 0 and s["pending"] == 0:
+            return s
+        time.sleep(0.01)
+    raise AssertionError(f"engine did not drain: {eng.stats()}")
+
+
+def test_engine_stats_lifecycle_and_block_counters():
+    """N requests through generate(): every lifecycle counter reads N, the
+    three histograms hold N observations whose sums add up, and the block
+    counters agree with ``steps``."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=2, max_len=64,
+                    prefill_buckets=(16, 32))
+    try:
+        s0 = eng.stats()
+        assert (s0["steps"], s0["active"], s0["pending"]) == (0, 0, 0)
+        assert s0["requests_submitted"] == 0 and s0["tokens_emitted"] == 0
+        assert s0["blocks_by_steps"] == {"2": 0, "8": 0}
+        lengths = [8, 12, 5, 20, 17]  # buckets 16, 16, 16, 32, 32
+        outs = [None] * len(lengths)
+
+        def run(i):
+            outs[i] = eng.generate(
+                (np.arange(lengths[i]) % cfg.vocab_size).astype(np.int32),
+                max_new_tokens=6)
+
+        ts = [threading.Thread(target=run, args=(i,))
+              for i in range(len(lengths))]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=180)
+        assert all(o is not None and len(o) == 6 for o in outs)
+        s = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    n = len(lengths)
+    for key in ("requests_submitted", "requests_admitted",
+                "requests_first_emitted", "requests_finished"):
+        assert s[key] == n, (key, s[key])
+    assert s["requests_cancelled"] == 0 and s["requests_failed"] == 0
+    assert s["tokens_emitted"] == 6 * n
+    assert s["prefill_tokens"] == sum(lengths)
+    assert s["prefill_padded_tokens"] == 16 * 3 + 32 * 2
+    hists = {k: s[k] for k in ("queue_wait_ms", "admit_to_first_ms",
+                               "submit_to_first_ms")}
+    bounds = s["hist_bounds_ms"]
+    assert bounds[0] == 1.0 and bounds[-1] == 10000.0
+    assert all(b / a <= 1.3 for a, b in zip(bounds, bounds[1:]))
+    for name, h in hists.items():
+        assert h["count"] == n and sum(h["counts"]) == n, name
+        assert len(h["counts"]) == len(bounds) + 1
+        assert h["sum"] >= 0.0
+    assert hists["submit_to_first_ms"]["sum"] == pytest.approx(
+        hists["queue_wait_ms"]["sum"] + hists["admit_to_first_ms"]["sum"],
+        rel=1e-9, abs=1e-6)
+    # blocks: every dispatched step is in exactly one length's count
+    assert sum(int(k) * v for k, v in s["blocks_by_steps"].items()) == (
+        s["steps"] - s0["steps"])
+    assert set(s["blocks_by_steps"]) == {"2", "8"}
+    assert s["capacity_steps"] == eng.max_slots * s["steps"]
+    # every token but a request's first came out of one live slot-step
+    assert 6 * n - n <= s["slot_steps"] <= s["capacity_steps"]
+    for key in ("admit_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
+                "block_sync_s", "block_emit_s", "idle_wait_s", "loop_s"):
+        assert isinstance(s[key], float) and s[key] >= 0.0, key
+    assert s["loop_s"] > 0.0 and s["block_sync_s"] > 0.0
+    # it crosses the actor boundary and is printed: plain data only
+    assert json.loads(json.dumps(s)) == s
+
+
+def test_engine_stats_count_cancelled_requests():
+    """A consumer that goes away mid-decode, and one whose request is
+    dropped at admission, each count as cancelled; nothing is lost."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=1, max_len=128,
+                    prefill_buckets=(16,))
+    try:
+        prompt = np.arange(1, 9, dtype=np.int32)
+        stream = eng.generate_stream(prompt, max_new_tokens=100)
+        assert isinstance(next(stream), int)
+        # the only slot is taken: this one waits in pending, and is
+        # cancelled there
+        waiting = eng.submit(prompt, max_new_tokens=4)
+        waiting.cancelled = True
+        stream.close()  # the consumer of the running request goes away
+        s = _settled_stats(eng)
+        assert eng.generate(prompt, max_new_tokens=3)  # still serving
+        s2 = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    assert s["requests_submitted"] == 2 and s["requests_admitted"] == 1
+    assert s["requests_cancelled"] == 2 and s["requests_finished"] == 0
+    assert s["requests_first_emitted"] == 1
+    assert 1 <= s["tokens_emitted"] < 100
+    assert s["queue_wait_ms"]["count"] == 1  # the dropped one never admitted
+    assert s2["requests_finished"] == 1 and s2["requests_cancelled"] == 2
+    assert s2["requests_submitted"] == (
+        s2["requests_finished"] + s2["requests_cancelled"]
+        + s2["requests_failed"])
+
+
+def test_engine_stats_count_failed_requests():
+    """test_engine_failure_unblocks_consumers' setup: the request the dead
+    loop fails lands in ``requests_failed``, admitted and never emitted."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=2, max_len=64,
+                    prefill_buckets=(16,))
+    eng._dispatch_block = lambda: (_ for _ in ()).throw(
+        RuntimeError("device fell over")
+    )
+    with pytest.raises(RuntimeError, match="device fell over"):
+        list(eng.generate_stream(np.arange(4, dtype=np.int32),
+                                 max_new_tokens=4))
+    eng._thread.join(timeout=10)
+    assert not eng._thread.is_alive()
+    s = eng.stats()
+    assert s["requests_submitted"] == 1 and s["requests_admitted"] == 1
+    assert s["requests_failed"] == 1
+    assert s["requests_first_emitted"] == 0 and s["requests_finished"] == 0
+    assert s["queue_wait_ms"]["count"] == 1
+    assert s["admit_to_first_ms"]["count"] == 0
+
+
+def test_llm_module_imports_without_jax():
+    """The driver of a chip run imports ``ray_tpu.serve`` and must stay off
+    JAX (benchmarks/run.py fails a run whose driver opened a backend)."""
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, ray_tpu.serve.llm; print('jax' in sys.modules)"],
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
+
+
+_TRACE_SCRIPT = """
+import json, sys, threading
+import jax, numpy as np
+from ray_tpu.models.transformer import TransformerConfig, init_params
+from ray_tpu.serve.llm import LLMEngine
+
+cfg = TransformerConfig.tiny()
+eng = LLMEngine(init_params(cfg, jax.random.key(0)), cfg, max_slots=2,
+                max_len=64, prefill_buckets=(16,))
+eng.generate(np.arange(1, 9, dtype=np.int32), max_new_tokens=4)  # warm
+options = jax.profiler.ProfileOptions()
+options.python_tracer_level = 0  # as benchmarks/trace.py:start
+jax.profiler.start_trace(sys.argv[1], profiler_options=options)
+reqs = [eng.submit(np.arange(1, 9, dtype=np.int32), max_new_tokens=12)
+        for _ in range(2)]
+for r in reqs:
+    for _ in range(12):
+        r.out.get(timeout=120)
+jax.profiler.stop_trace()
+eng.shutdown()
+
+import glob
+from jax.profiler import ProfileData
+path = sorted(glob.glob(sys.argv[1] + "/plugins/profile/*/*.xplane.pb"))[-1]
+events = []
+for plane in ProfileData.from_file(path).planes:
+    for line in plane.lines:
+        for e in line.events:
+            if e.name.startswith("raytpu.engine."):
+                events.append({"name": e.name, "line": line.name,
+                               "stats": {k: v for k, v in e.stats}})
+print(json.dumps({"rids": [r.rid for r in reqs], "events": events}))
+"""
+
+
+def test_engine_spans_land_in_the_profilers_trace(tmp_path):
+    """A short host trace (Python tracer off) around two requests holds
+    ``raytpu.engine.prefill`` spans with the two rids and
+    ``raytpu.engine.dispatch`` spans with their block's length. Runs in a
+    process of its own, under its own time limit."""
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c", _TRACE_SCRIPT, str(tmp_path)],
+        capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    by_name = {}
+    for e in got["events"]:
+        by_name.setdefault(e["name"], []).append(e)
+    prefills = by_name["raytpu.engine.prefill"]
+    assert sorted(int(e["stats"]["rid"]) for e in prefills) == sorted(
+        got["rids"])
+    for e in prefills:
+        assert int(e["stats"]["tokens"]) == 8
+        assert int(e["stats"]["bucket"]) == 16
+        assert int(e["stats"]["slot"]) in (0, 1)
+    dispatches = by_name["raytpu.engine.dispatch"]
+    assert dispatches and all(
+        int(e["stats"]["steps"]) in (2, 8) and int(e["stats"]["live"]) >= 1
+        and int(e["stats"]["kv_rows"]) >= 8 for e in dispatches)
+    firsts = by_name["raytpu.engine.retire_firsts"]
+    assert sorted(int(r) for e in firsts
+                  for r in str(e["stats"]["rids"]).split()) == sorted(
+        got["rids"])
+    assert by_name["raytpu.engine.retire_block"]
+    assert by_name["raytpu.engine.admit"]
+    # one thread writes them all: the engine's
+    assert len({e["line"] for e in got["events"]}) == 1
