@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import gc
 import hashlib
 import logging
 import os
@@ -41,6 +42,19 @@ logger = logging.getLogger(__name__)
 
 MODE_DRIVER = "driver"
 MODE_WORKER = "worker"
+
+# Set, per thread, while the cyclic collector runs in it. The collector cuts
+# in wherever Python code runs, also inside a ``with self._ref_lock:`` or the
+# memory store's lock, and an ``ObjectRef.__del__`` it runs there must not
+# take those locks again (see ``_on_ref_deleted``).
+_gc_tls = threading.local()
+
+
+def _note_gc_phase(phase, info):
+    _gc_tls.collecting = phase == "start"
+
+
+gc.callbacks.append(_note_gc_phase)
 
 
 def _conduit_available() -> bool:
@@ -642,6 +656,14 @@ class CoreWorker:
                 self.io.submit(self._send_borrow(ref, add=True))
 
     def _on_ref_deleted(self, ref: ObjectRef):
+        if getattr(_gc_tls, "collecting", False):
+            # ``ref`` died in a reference cycle, and the frame the collector
+            # interrupted may be in the middle of ``_on_ref_created`` or of a
+            # memory-store call on this very thread: taking their locks here
+            # never returns (the tier-1 hang of ROADMAP D11). The IO loop
+            # finishes the job from its top level, where no lock is held.
+            self.io.call_soon(self._on_ref_deleted, ref)
+            return
         with self._ref_lock:
             n = self._refcounts.get(ref.id, 0) - 1
             if n <= 0:

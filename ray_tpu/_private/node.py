@@ -95,6 +95,11 @@ def worker_env(tpu: bool) -> Dict[str, str]:
         env["JAX_PLATFORMS"] = "tpu" if tpu else "cpu"
     env.setdefault("JAX_COMPILATION_CACHE_DIR", default_compile_cache_dir())
     env["RAYTPU_WORKER"] = "1"
+    # stdout is the worker's log file: unbuffered, a print() reaches the
+    # log, and through the log monitor the driver, when it is made and not
+    # a block-buffer later (log_to_driver; green only under a shell that
+    # exported the variable until PR 27)
+    env["PYTHONUNBUFFERED"] = "1"
     return env
 
 

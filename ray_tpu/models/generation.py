@@ -8,9 +8,12 @@ prefill + ``lax.scan`` decode loop into two XLA programs (one per phase) —
 static shapes, no per-token Python. Batched greedy or temperature sampling.
 
 TPU notes: cache layout [L, B, S_max, H_kv, D] keeps the per-layer slices
-contiguous for the scanned stack; decode attends q[B,1,H,D] against the
-full cache with a position mask (masked lanes are free — the MXU work is
-the [1 x S_max] band); GQA caches only kv_heads.
+contiguous for the scanned stack; GQA caches only kv_heads. Decode is
+bound by HBM reads, and a masked cache row is read like a live one: the
+mask only discards what was already streamed. So the engine's decode
+attention (``_attend_prefix_plus_self``) walks the cache in row chunks
+and stops at the longest live sequence; ``generate``'s single-sequence
+path (``_attend_cached``) still reads all S_max rows under its mask.
 """
 
 from __future__ import annotations
@@ -148,7 +151,23 @@ def decode_step(params, token, cache, pos, config: TransformerConfig):
 # the TPU-shaped analog of vLLM's iteration-level batching.)
 
 
-def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos):
+# Rows of cache one iteration of the decode attention reads. 256 rows of
+# 8 slots x 16 heads x 256 dims in bf16 are 16.8 MB each of K and V.
+DECODE_ATTN_CHUNK = 256
+
+
+def attn_rows_walked(bound: int, s_max: int,
+                     chunk: int = DECODE_ATTN_CHUNK) -> int:
+    """Rows of every slot's cache that one decode step reads when the
+    largest ``pos`` among its lanes is ``bound``: whole chunks up to it,
+    at most ``s_max``. Plain integers: the engine's host-side count of
+    what ``_attend_prefix_plus_self`` walks on the device."""
+    chunk = min(chunk, s_max)
+    return min(-(-min(bound, s_max) // chunk) * chunk, s_max)
+
+
+def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
+                             chunk: int = DECODE_ATTN_CHUNK):
     """q [B,1,H,D] against the UNWRITTEN cache prefix (k_pos < pos,
     strict — the row at ``pos`` may hold stale garbage) plus the fresh
     (k_new, v_new) [B,1,Hkv,D] as one extra logical position. Exactly
@@ -156,28 +175,66 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos):
     ``k_pos <= pos`` — but lets the caller defer ALL cache writes out of
     the layer scan (one scatter per step instead of 2 per layer: TPU
     scatters serialize, and 64 scatter-rows/step were the measured
-    small-op bottleneck of 7B decode — VERDICT r4 weak #3)."""
-    n_rep = q.shape[2] // ck.shape[2]
-    k = repeat_kv(ck, n_rep)
-    v = repeat_kv(cv, n_rep)
-    scale = q.shape[-1] ** -0.5
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
-    k_pos = jnp.arange(k.shape[1])
-    mask = k_pos[None, :] < pos[:, None]  # [B, S_max], STRICT
-    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    self_score = jnp.einsum(
+    small-op bottleneck of 7B decode — VERDICT r4 weak #3).
+
+    A masked row is not free: it is an HBM read, and the read is all a
+    decode step's attention costs. So the cache is walked in chunks of
+    ``chunk`` rows with an online softmax in float32 (running max, sum
+    and accumulator, seeded by the self position), for
+    ceil(max(pos) / chunk) iterations: the trip count is data, one
+    compiled program serves every length. Within a chunk the per-slot
+    strict mask stays, so this is the same attention over the same rows
+    (bf16 operands, float32 scores and accumulation). A lane at ``pos``
+    0 attends itself alone and does not move the bound: that is where
+    the engine parks its free slots.
+
+    ck/cv are one layer's [B,S_max,Hkv,D], or with ``layer`` the whole
+    [L,B,S_max,Hkv,D] cache: the chunk is then sliced out of the big
+    buffer INSIDE the loop (a layer sliced outside it would be copied
+    whole, S_max rows, before the loop could read it)."""
+    if layer is None:
+        ck, cv, layer = ck[None], cv[None], 0
+    _, B, s_max, h_kv, d = ck.shape
+    n_rep = q.shape[2] // h_kv
+    chunk = min(chunk, s_max)
+    scale = d ** -0.5
+    f32 = jnp.float32
+    m = jnp.einsum(
         "bqhd,bqhd->bhq", q, repeat_kv(k_new, n_rep),
-        preferred_element_type=jnp.float32,
-    )[..., None] * scale  # [B,H,1,1]
-    all_scores = jnp.concatenate([scores, self_score], axis=-1)
-    probs = jax.nn.softmax(all_scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs[..., :-1], v)
-    out = out + probs[..., -1:].transpose(0, 2, 1, 3) * repeat_kv(
-        v_new, n_rep
+        preferred_element_type=f32,
+    )[..., None] * scale  # [B,H,1,1]: the self position's score
+    acc = repeat_kv(v_new, n_rep).astype(f32).transpose(0, 2, 1, 3)
+
+    def walk(c, state):
+        m, l, acc = state
+        # the last chunk of an S_max that chunk does not divide starts
+        # early (a slice must stay in bounds) and masks what it re-reads
+        lo = c * chunk
+        start = jnp.minimum(lo, s_max - chunk)
+        at, size = (layer, 0, start, 0, 0), (1, B, chunk, h_kv, d)
+        k = repeat_kv(lax.dynamic_slice(ck, at, size)[0], n_rep)
+        v = repeat_kv(lax.dynamic_slice(cv, at, size)[0], n_rep)
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, k, preferred_element_type=f32
+        ) * scale
+        k_pos = start + jnp.arange(chunk)
+        mask = (k_pos >= lo)[None, :] & (k_pos[None, :] < pos[:, None])
+        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)  # a masked row: exp(-1e30 - m) == 0
+        shrink = jnp.exp(m - m_new)
+        l = shrink * l + p.sum(axis=-1, keepdims=True)
+        acc = shrink * acc + jnp.einsum(
+            "bhqk,bkhd->bhqd", p.astype(q.dtype), v,
+            preferred_element_type=f32,
+        )
+        return m_new, l, acc
+
+    bound = jnp.minimum(jnp.max(pos), s_max)
+    _, l, acc = lax.fori_loop(
+        0, (bound + chunk - 1) // chunk, walk, (m, jnp.ones_like(m), acc)
     )
-    return out
+    return (acc / l).transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 def _decode_forward_multi(params, token, cache, pos,
@@ -187,8 +244,9 @@ def _decode_forward_multi(params, token, cache, pos,
 
     Two structures, selected by ``RAYTPU_DECODE_DEFERRED_WRITES``:
 
-    * deferred (=1): the layer scan only READS the cache (sliced in as
-      scan xs) and attends prefix-plus-self; each layer's fresh k/v come
+    * deferred (=1): the layer scan only READS the cache (closed over,
+      its chunks sliced by layer index) and attends prefix-plus-self;
+      each layer's fresh k/v come
       out as scan ys and land with ONE batched scatter after the scan
       ([L,Hkv,D] rows per slot) instead of two scatters per layer inside
       it — 2 scatters/step vs 2L. Candidate fix for the small-op-bound
@@ -213,11 +271,13 @@ def _decode_forward_multi_deferred(params, token, cache, pos,
     x = params["embed"].astype(c.dtype)[token][:, None]  # [B,1,D]
     b_idx = jnp.arange(B)
 
+    ck, cv = cache["k"], cache["v"]  # read-only inside the scan
+
     def layer(x, layer_in):
-        lp, ck, cv = layer_in  # per-layer cache slices [B,S,Hkv,D]
+        lp, li = layer_in
 
         def cached_attn(q, k, v):
-            out = _attend_prefix_plus_self(q, ck, cv, k, v, pos)
+            out = _attend_prefix_plus_self(q, ck, cv, k, v, pos, layer=li)
             return out, (k[:, 0].astype(ck.dtype),
                          v[:, 0].astype(cv.dtype))
 
@@ -225,7 +285,7 @@ def _decode_forward_multi_deferred(params, token, cache, pos,
         return y, kv_new
 
     x, (ks, vs) = lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"])
+        layer, x, (params["layers"], jnp.arange(c.n_layers))
     )
     # ks/vs: [L,B,Hkv,D] — one scatter writes every layer's row for every
     # slot (adjacent advanced indices keep their place: [L,B,Hkv,D])
@@ -252,9 +312,9 @@ def _decode_forward_multi_carry(params, token, cache, pos,
             # per-slot attention WITHOUT a pre-write (prefix + self; see
             # _attend_prefix_plus_self) — the scatters below only feed
             # LATER steps, so they stay off the attention critical path
-            ck = lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-            out = _attend_prefix_plus_self(q, ck, cv, k, v, pos)
+            out = _attend_prefix_plus_self(
+                q, ck_all, cv_all, k, v, pos, layer=li
+            )
             ck2 = ck_all.at[li, b_idx, pos].set(
                 k[:, 0].astype(ck_all.dtype)
             )
@@ -316,13 +376,18 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
     as decode_loop, but with per-slot positions so slots admitted at
     different times share the batch).
 
+    A lane at ``pos`` 0 is PARKED: it stays at 0 (the engine puts a freed
+    slot there), so the attention's row bound, the largest ``pos``,
+    follows the live sequences; a free lane that kept counting would
+    drag it to S_max on an idle engine.
+
     Returns (tokens [B, steps], cache, token', pos', counts')."""
     def step(carry, _):
         tok, cache, pos, counts = carry
         logits, cache = _decode_forward_multi(params, tok, cache, pos,
                                               config)
         nxt = _sample_vec(logits, temps, seeds, counts)
-        return (nxt, cache, pos + 1, counts + 1), nxt
+        return (nxt, cache, pos + (pos > 0), counts + 1), nxt
 
     (token, cache, pos, counts), toks = lax.scan(
         step, (token, cache, pos, counts), None, length=steps

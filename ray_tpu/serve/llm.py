@@ -46,7 +46,7 @@ _COUNTERS = (
     "requests_submitted", "requests_admitted", "requests_first_emitted",
     "requests_finished", "requests_cancelled", "requests_failed",
     "prefill_tokens", "prefill_padded_tokens", "tokens_emitted",
-    "slot_steps", "capacity_steps",
+    "slot_steps", "capacity_steps", "attn_rows_read", "attn_rows_capacity",
 )
 _PHASES = (
     "admit_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
@@ -158,6 +158,10 @@ class LLMEngine:
         self.counts = jnp.zeros(max_slots, jnp.int32)  # sample counter
         # host-side slot table
         self.slot_req: List[Optional[_Request]] = [None] * max_slots
+        # What ``self.pos`` holds on the device, kept in step on the host
+        # (set at admission, advanced at dispatch, 0 for a parked lane):
+        # the decode attention walks the cache up to its largest entry.
+        self._rows: List[int] = [0] * max_slots
         self.pending: "collections.deque[_Request]" = collections.deque()
         self._pending_first: List = []  # (req, device first-token scalar)
         self._first_fn = None  # lazily-jitted first-token sampler
@@ -179,9 +183,10 @@ class LLMEngine:
         # Warm BOTH static-K decode variants before accepting traffic:
         # the first load-threshold crossing would otherwise trigger a
         # seconds-scale XLA compile mid-burst — the exact moment the
-        # adaptive length exists to protect. Warm decode writes garbage
-        # rows at pos 0..K-1 of empty slots; the state reset below and
-        # prefill's strict masking make that invisible.
+        # adaptive length exists to protect. Every lane is parked at pos 0
+        # (see decode_block), so warm decode writes garbage to row 0 of
+        # empty slots only; the state reset below and prefill's strict
+        # masking make that invisible.
         self._warm_blocks()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
@@ -282,7 +287,11 @@ class LLMEngine:
           covers is the difference.
         - Per dispatched block: ``blocks_by_steps`` ``{"2": n, "8": n}``,
           ``slot_steps`` (live slots x steps), ``capacity_steps``
-          (``max_slots`` x steps).
+          (``max_slots`` x steps); ``attn_rows_read``, the cache rows the
+          decode attention walked (per step and slot: whole chunks up to
+          the longest sequence in the batch), and ``attn_rows_capacity``
+          (``max_len`` x ``max_slots`` x steps), what walking the whole
+          cache would have read.
         """
         with self._lock:
             out = {
@@ -358,6 +367,7 @@ class LLMEngine:
                     self.seeds = self.seeds.at[free].set(req.seed)
                     self.counts = self.counts.at[free].set(1)
                 self.slot_req[free] = req
+                self._rows[free] = n
                 self._pending_first.append((req, first))
 
     def _first_token(self, logits, temperature, seed):
@@ -407,7 +417,7 @@ class LLMEngine:
         dispatch time, and the not-yet-emitted first tokens of requests
         admitted since the previous dispatch. K adapts to load (see
         __init__): light load -> short blocks -> short admission waits."""
-        from ray_tpu.models.generation import decode_block
+        from ray_tpu.models.generation import attn_rows_walked, decode_block
 
         live = [r for r in self.slot_req
                 if r is not None and not r.finished]
@@ -417,10 +427,12 @@ class LLMEngine:
             if active > self.max_slots // 2
             else self.burst_block_steps
         )
+        bound = max(self._rows)  # the block's first step walks up to here
         with self._span(
             "raytpu.engine.dispatch", steps=steps, live=active,
             kv_rows=sum(len(r.prompt) + r.produced for r in live),
             firsts=len(self._pending_first), pending=len(self.pending),
+            bound=bound,
         ):
             toks, self.cache, self.tok, self.pos, self.counts = (
                 decode_block(
@@ -432,6 +444,11 @@ class LLMEngine:
         self._blocks_by_steps[steps] += 1
         self._n["slot_steps"] += active * steps
         self._n["capacity_steps"] += self.max_slots * steps
+        self._n["attn_rows_read"] += self.max_slots * sum(
+            attn_rows_walked(bound + k, self.max_len) for k in range(steps))
+        self._n["attn_rows_capacity"] += self.max_slots * self.max_len * steps
+        # as decode_block leaves pos: a step on, parked lanes stay at 0
+        self._rows = [r + steps if r else 0 for r in self._rows]
         snapshot = list(self.slot_req)  # slot -> req at dispatch
         return toks, snapshot
 
@@ -472,11 +489,15 @@ class LLMEngine:
                     self._emit(req, int(toks[slot, k]))
             # free slots whose requests finished (table may already have
             # a NEWER request in the slot — only clear if it's still this
-            # one)
+            # one), and park the lane at pos 0: decode_block keeps it
+            # there, out of the attention's row bound. Queued behind the
+            # block in flight, which still ran the lane: no sync.
             for slot, req in enumerate(snapshot):
                 if req is not None and req.finished and (
                     self.slot_req[slot] is req
                 ):
+                    self.pos = self.pos.at[slot].set(0)
+                    self._rows[slot] = 0
                     self.slot_req[slot] = None
             self._t["block_sync_s"] += t1 - t0
             self._t["block_emit_s"] += time.perf_counter() - t1

@@ -20,7 +20,57 @@ from ray_tpu._private.node import default_compile_cache_dir  # noqa: E402
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", default_compile_cache_dir())
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
+
 import pytest  # noqa: E402
+
+# A time limit of its own for every phase (setup, call, teardown) of every
+# test: the slowest test here takes ~35 s, and a cluster test that waits for
+# ever otherwise holds its xdist worker, and the files queued behind it,
+# until the whole run's limit cuts everything (PR 27's refused runs). At
+# _PHASE_LIMIT_S the phase fails with every thread's stack in its report.
+# It ends waits that Python can interrupt. Not a wait inside a ``__del__``,
+# which swallows the exception, nor one in C; and no hard exit behind it,
+# because under --dist loadfile xdist hands a crashed worker's file to a
+# new worker, which waits out the same test again.
+_PHASE_LIMIT_S = 240
+
+
+def _limited(item, phase):
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def on_alarm(signum, frame):
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        pytest.fail(f"{item.nodeid}: {phase} still running after "
+                    f"{_PHASE_LIMIT_S} s (tests/conftest.py)")
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, _PHASE_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_setup(item):
+    yield from _limited(item, "setup")
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_call(item):
+    yield from _limited(item, "call")
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_teardown(item):
+    yield from _limited(item, "teardown")
 
 
 def pytest_configure(config):

@@ -467,3 +467,51 @@ def test_wait_returns_at_most_num_returns(rt):
     # the leftovers are still waitable
     done2, pending2 = ray_tpu.wait(pending, num_returns=5, timeout=30)
     assert len(done2) == 5 and not pending2
+
+
+@pytest.mark.parametrize("held", ["_ref_lock", "memory_store._lock"])
+def test_ref_dropped_by_the_cyclic_collector_takes_no_held_lock(rt, held):
+    """The collector can cut in anywhere Python runs, also inside the
+    reference counter's own critical section or the memory store's: an
+    ``ObjectRef.__del__`` it runs there used to take the same lock again
+    on the same thread and never return (the tier-1 hang of ROADMAP D11:
+    ``submit_task -> _on_ref_created -> [gc] -> __del__ ->
+    _on_ref_deleted``). The count still comes down, on the IO loop."""
+    import functools
+    import gc
+    import threading
+
+    from ray_tpu._private.worker import global_worker
+
+    cw = global_worker.core_worker
+    lock = functools.reduce(getattr, held.split("."), cw)
+    ref = ray_tpu.put("x")
+    oid = ref.id
+    assert cw._refcounts[oid] == 1 and oid in cw._owned
+
+    class Cycle:
+        pass
+
+    gc.collect()
+    gc.disable()  # the cycle below dies where this test says, nowhere else
+    try:
+        c = Cycle()
+        c.me, c.ref = c, ref
+        del c, ref  # the only ref now lives in unreachable garbage
+
+        def collect_inside_the_critical_section():
+            with lock:
+                gc.collect()
+
+        t = threading.Thread(
+            target=collect_inside_the_critical_section, daemon=True)
+        t.start()
+        t.join(20)
+        assert not t.is_alive(), f"collector deadlocked on {held}"
+    finally:
+        gc.enable()
+    deadline = time.monotonic() + 20
+    while oid in cw._refcounts or oid in cw._owned:
+        assert time.monotonic() < deadline, "the deferred release never ran"
+        time.sleep(0.01)
+    assert cw.memory_store.get(oid) is None

@@ -24,11 +24,12 @@ NEW_METRICS = {
     "serve-chat-steady": [
         "engine.queue_wait_p50_ms", "engine.queue_wait_p90_ms.chat",
         "engine.admit_to_first_p50_ms", "engine.admit_to_first_p90_ms.chat",
-        "engine.prefill_pad_share"],
+        "engine.prefill_pad_share", "engine.kv_read_share"],
     "serve-doc-burst": [
         "engine.queue_wait_p50_ms", "engine.admit_to_first_p50_ms",
         "engine.prefill_pad_share"],
-    "serve-chat-saturated": ["engine.loop_host_share"],
+    "serve-chat-saturated": ["engine.loop_host_share",
+                             "engine.kv_read_share"],
     "train4-gptj-seq2048": [],
 }
 
@@ -95,7 +96,7 @@ def test_ratio_of_summed_differences_with_subtracted_keys():
     assert ratio(facts, {"num": ["real", "nope"], "den": ["padded"]}) is None
 
 
-def test_the_six_metrics_resolve_in_their_cells():
+def test_the_engine_stats_metrics_resolve_in_their_cells():
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
          "--list"], capture_output=True, text=True, timeout=120)
@@ -124,5 +125,6 @@ def test_cpu_rehearsal_walks_the_new_readers():
     for name in NEW_METRICS["serve-chat-steady"]:
         assert isinstance(walked[name], float), (name, walked[name])
     assert 0.0 <= walked["engine.prefill_pad_share"] < 100.0
+    assert 0.0 < walked["engine.kv_read_share"] <= 100.0
     assert walked["engine.queue_wait_p50_ms"] <= (
         walked["engine.queue_wait_p90_ms.chat"])

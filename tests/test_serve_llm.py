@@ -330,6 +330,93 @@ def test_engine_stats_lifecycle_and_block_counters():
     assert json.loads(json.dumps(s)) == s
 
 
+def test_engine_attention_walks_live_rows_only():
+    """Two requests, one after the other, long then short: ``stats()``
+    counts the rows the decode attention walked (whole 256-row chunks up
+    to the longest sequence in the batch, every slot, every step) against
+    the whole cache. The long request's freed lane is parked at pos 0 on
+    the device, so the short one walks one chunk, not two; and the host's
+    copy of ``pos`` equals the device's at every dispatch."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=2, max_len=640,
+                    prefill_buckets=(16, 256))
+    seen = []
+    dispatch = eng._dispatch_block
+
+    def checked_dispatch():
+        seen.append((list(eng._rows), np.asarray(eng.pos).tolist()))
+        return dispatch()
+
+    eng._dispatch_block = checked_dispatch
+    try:
+        for n in (255, 8):
+            out = eng.generate(
+                (np.arange(n) % cfg.vocab_size).astype(np.int32),
+                max_new_tokens=4)
+            assert len(out) == 4
+            s = _settled_stats(eng)
+            assert np.asarray(eng.pos).tolist() == [0, 0] == eng._rows
+    finally:
+        eng.shutdown()
+    # each request: first token from its prefill, then three 2-step blocks
+    # (the third is in flight when the fourth token retires)
+    assert s["steps"] == 12 and s["blocks_by_steps"] == {"2": 6, "8": 0}
+    assert [rows for rows, _ in seen] == [
+        [255, 0], [257, 0], [259, 0], [8, 0], [10, 0], [12, 0]]
+    assert all(rows == pos for rows, pos in seen)
+    # per step: rows of the chunks up to the bound, x 2 slots. The long
+    # one steps 255 256 | 257 258 | 259 260, the short one stays under 256
+    assert s["attn_rows_read"] == 2 * (256 + 256 + 4 * 512) + 2 * 6 * 256
+    assert s["attn_rows_capacity"] == 2 * 640 * 12
+    assert s["attn_rows_read"] <= s["attn_rows_capacity"]
+
+
+def test_decode_block_parks_lanes_at_pos_zero():
+    """A lane at pos 0 stays there through a block, whatever its cache
+    rows and token hold, and the live lanes' tokens do not depend on it:
+    not on its garbage, and not on a stale pos it might have had."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generation import (
+        decode_block,
+        init_kv_cache,
+        prefill_into_slot,
+        prepare_for_inference,
+    )
+
+    params, cfg = _tiny_model()
+    params, icfg = prepare_for_inference(params, cfg)
+    prompt = jnp.arange(1, 9, dtype=jnp.int32)[None]
+
+    def run(dead_pos, dead_tok, garbage):
+        cache = init_kv_cache(icfg, 3, 48)
+        if garbage:
+            cache = jax.tree.map(lambda x: x.at[:, 1].set(50.0), cache)
+        logits, cache = prefill_into_slot(
+            params, prompt, jnp.int32(8), jnp.int32(0), cache, icfg)
+        _, cache = prefill_into_slot(
+            params, prompt[:, :5], jnp.int32(5), jnp.int32(2), cache, icfg)
+        first = jnp.argmax(logits).astype(jnp.int32)
+        tok = jnp.asarray([first, dead_tok, 3], jnp.int32)
+        pos = jnp.asarray([8, dead_pos, 5], jnp.int32)
+        z = jnp.zeros(3, jnp.int32)
+        toks, _c, _t, pos_out, _n = decode_block(
+            params, cache, tok, pos, jnp.zeros(3, jnp.float32), z, z,
+            icfg, 4)
+        return np.asarray(toks), np.asarray(pos_out).tolist()
+
+    toks, pos_out = run(0, 0, False)
+    assert pos_out == [12, 0, 9]
+    toks_g, pos_g = run(0, 7, True)
+    toks_s, pos_s = run(47, 7, True)  # a stale lane: counted, not parked
+    assert pos_g == [12, 0, 9] and pos_s == [12, 51, 9]
+    for other in (toks_g, toks_s):
+        np.testing.assert_array_equal(other[[0, 2]], toks[[0, 2]])
+
+
 def test_engine_stats_count_cancelled_requests():
     """A consumer that goes away mid-decode, and one whose request is
     dropped at admission, each count as cancelled; nothing is lost."""
@@ -462,7 +549,8 @@ def test_engine_spans_land_in_the_profilers_trace(tmp_path):
     dispatches = by_name["raytpu.engine.dispatch"]
     assert dispatches and all(
         int(e["stats"]["steps"]) in (2, 8) and int(e["stats"]["live"]) >= 1
-        and int(e["stats"]["kv_rows"]) >= 8 for e in dispatches)
+        and int(e["stats"]["kv_rows"]) >= 8
+        and int(e["stats"]["bound"]) >= 8 for e in dispatches)
     firsts = by_name["raytpu.engine.retire_firsts"]
     assert sorted(int(r) for e in firsts
                   for r in str(e["stats"]["rids"]).split()) == sorted(
