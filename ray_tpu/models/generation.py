@@ -8,7 +8,12 @@ prefill + ``lax.scan`` decode loop into two XLA programs (one per phase) —
 static shapes, no per-token Python. Batched greedy or temperature sampling.
 
 TPU notes: cache layout [L, B, S_max, H_kv, D] keeps the per-layer slices
-contiguous for the scanned stack; GQA caches only kv_heads. Decode is
+contiguous for the scanned stack; GQA caches only kv_heads; latent
+attention caches one latent row a token a layer (``init_kv_cache``: the
+cache is a pytree that the mixer defines, and the engine never looks
+inside it). ``generate``'s single-sequence path below is MHA/GQA only;
+the engine's ``prefill_into_slot`` / ``decode_block`` run every block
+the config can describe. Decode is
 bound by HBM reads, and a masked cache row is read like a live one: the
 mask only discards what was already streamed. So the engine's decode
 attention (``_attend_prefix_plus_self``) walks the cache in row chunks
@@ -28,9 +33,13 @@ from jax import lax
 from ray_tpu.models.transformer import (
     TransformerConfig,
     _rms_norm,
+    apply_block,
     apply_layer,
+    layer_groups,
+    mla_expand,
+    scan_stack,
 )
-from ray_tpu.ops.attention import NEG_INF, repeat_kv
+from ray_tpu.ops.attention import NEG_INF, causal_attention, repeat_kv
 
 
 def prepare_for_inference(params, config: TransformerConfig):
@@ -53,7 +62,16 @@ def prepare_for_inference(params, config: TransformerConfig):
 
 def init_kv_cache(config: TransformerConfig, batch: int,
                   max_len: int) -> Dict[str, jax.Array]:
+    """The cache is a pytree that the mixer defines; every leaf is
+    [L, B, S_max, ...]. MHA/GQA: ``k`` and ``v`` of [.., Hkv, D]. Latent
+    attention: the row ``[c_kv | rot(k_r)]`` a token a layer, as ``ckv`` of
+    [.., kv_lora_rank] (key and value at once) and ``kr`` of
+    [.., qk_rope_dim] (two arrays: see _attend_latent_prefix_plus_self)."""
     c = config
+    if c.mixer == "mla":
+        rows = (c.n_layers, batch, max_len)
+        return {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
+                "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}
     shape = (c.n_layers, batch, max_len, c.kv_heads, c.d_head)
     return {
         "k": jnp.zeros(shape, c.dtype),
@@ -85,6 +103,11 @@ def _forward_cached(params, tokens, cache, start_pos, config):
     is the SAME ``apply_layer`` the training paths use — only the attention
     callable differs (cache-writing, cache-attending)."""
     c = config
+    if c.mixer != "mha" or (c.moe_experts and c.n_dense_layers):
+        raise NotImplementedError(
+            "generate()'s single-sequence path runs the MHA/GQA block in one "
+            "stack; other blocks are served through prefill_into_slot and "
+            "decode_block")
     x = params["embed"].astype(c.dtype)[tokens]
     S = tokens.shape[1]
     positions = start_pos + jnp.arange(S)
@@ -166,6 +189,34 @@ def attn_rows_walked(bound: int, s_max: int,
     return min(-(-min(bound, s_max) // chunk) * chunk, s_max)
 
 
+def _walk_rows(body, m, acc, pos, s_max: int, chunk: int):
+    """The schedule and the state both decode attentions share: an online
+    softmax (running max ``m``, sum ``l`` starting at 1, accumulator
+    ``acc``: seeded by the token's own position) carried through ``state =
+    body(start, attended, state)`` for each chunk of ``chunk`` cache rows
+    up to the longest live sequence, ceil(max(pos) / chunk) iterations:
+    the trip count is data, so one compiled program serves every length.
+    ``start`` is the chunk's first row; ``attended()`` gives the mask
+    [B, chunk] of the rows a lane attends (row < pos, strict). The last
+    chunk of an S_max that chunk does not divide starts early (a slice
+    must stay in bounds) and masks what it re-reads. Returns (l, acc)."""
+    def walk(c, state):
+        lo = c * chunk
+        start = jnp.minimum(lo, s_max - chunk)
+
+        def attended():
+            k_pos = start + jnp.arange(chunk)
+            return (k_pos >= lo)[None, :] & (k_pos[None, :] < pos[:, None])
+
+        return body(start, attended, state)
+
+    bound = jnp.minimum(jnp.max(pos), s_max)
+    _, l, acc = lax.fori_loop(
+        0, (bound + chunk - 1) // chunk, walk, (m, jnp.ones_like(m), acc)
+    )
+    return l, acc
+
+
 def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
                              chunk: int = DECODE_ATTN_CHUNK):
     """q [B,1,H,D] against the UNWRITTEN cache prefix (k_pos < pos,
@@ -179,14 +230,12 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
 
     A masked row is not free: it is an HBM read, and the read is all a
     decode step's attention costs. So the cache is walked in chunks of
-    ``chunk`` rows with an online softmax in float32 (running max, sum
-    and accumulator, seeded by the self position), for
-    ceil(max(pos) / chunk) iterations: the trip count is data, one
-    compiled program serves every length. Within a chunk the per-slot
-    strict mask stays, so this is the same attention over the same rows
-    (bf16 operands, float32 scores and accumulation). A lane at ``pos``
-    0 attends itself alone and does not move the bound: that is where
-    the engine parks its free slots.
+    ``chunk`` rows (``_walk_rows``) with an online softmax in float32
+    (running max, sum and accumulator, seeded by the self position).
+    Within a chunk the per-slot strict mask stays, so this is the same
+    attention over the same rows (bf16 operands, float32 scores and
+    accumulation). A lane at ``pos`` 0 attends itself alone and does not
+    move the bound: that is where the engine parks its free slots.
 
     ck/cv are one layer's [B,S_max,Hkv,D], or with ``layer`` the whole
     [L,B,S_max,Hkv,D] cache: the chunk is then sliced out of the big
@@ -205,21 +254,15 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
     )[..., None] * scale  # [B,H,1,1]: the self position's score
     acc = repeat_kv(v_new, n_rep).astype(f32).transpose(0, 2, 1, 3)
 
-    def walk(c, state):
+    def body(start, attended, state):
         m, l, acc = state
-        # the last chunk of an S_max that chunk does not divide starts
-        # early (a slice must stay in bounds) and masks what it re-reads
-        lo = c * chunk
-        start = jnp.minimum(lo, s_max - chunk)
         at, size = (layer, 0, start, 0, 0), (1, B, chunk, h_kv, d)
         k = repeat_kv(lax.dynamic_slice(ck, at, size)[0], n_rep)
         v = repeat_kv(lax.dynamic_slice(cv, at, size)[0], n_rep)
         s = jnp.einsum(
             "bqhd,bkhd->bhqk", q, k, preferred_element_type=f32
         ) * scale
-        k_pos = start + jnp.arange(chunk)
-        mask = (k_pos >= lo)[None, :] & (k_pos[None, :] < pos[:, None])
-        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+        s = jnp.where(attended()[:, None, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)  # a masked row: exp(-1e30 - m) == 0
         shrink = jnp.exp(m - m_new)
@@ -230,23 +273,145 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
         )
         return m_new, l, acc
 
-    bound = jnp.minimum(jnp.max(pos), s_max)
-    _, l, acc = lax.fori_loop(
-        0, (bound + chunk - 1) // chunk, walk, (m, jnp.ones_like(m), acc)
-    )
+    l, acc = _walk_rows(body, m, acc, pos, s_max, chunk)
     return (acc / l).transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
+                                    pos, *, layer, scale: float,
+                                    chunk: int = DECODE_ATTN_CHUNK):
+    """``_attend_prefix_plus_self`` for a latent cache: ONE key that all
+    heads share, in two parts, the latent ``ckv`` [L,B,S_max,R] (which is
+    the value as well) and the rotary key ``kr`` [L,B,S_max,rope]. q_lat
+    [B,H,R] (the query with W_uk absorbed) and q_rope [B,H,rope] against
+    the unwritten prefix plus the token's own (c_new [B,R], r_new
+    [B,rope]); scores (q_lat . c + q_rope . r) * scale in float32. The
+    same walk (``_walk_rows``) and the same online softmax; a chunk of
+    latents is read once and serves as key and as value. Returns o_lat
+    [B,H,R], float32 accumulation cast to q's type.
+
+    Two things the chip decided (PR 28, read from its trace). The chunks
+    are the LEFT operand of the scores' products and pass an
+    ``optimization_barrier``: without it the compiler lays the WHOLE cache
+    out rows-minor for the products, a 1.2 GB copy once a layer a step.
+    And the two parts are two arrays because the chip's own layout for an
+    array whose minor dim is 576 (no multiple of 128) is rows-minor too,
+    which cost two copies of the cache per block at the program's edges;
+    512 is a multiple, and the rotary part is a ninth of the bytes."""
+    B, s_max = ckv.shape[1], ckv.shape[2]
+    chunk = min(chunk, s_max)
+    f32 = jnp.float32
+    m = (jnp.einsum("bhd,bd->bh", q_lat, c_new, preferred_element_type=f32)
+         + jnp.einsum("bhd,bd->bh", q_rope, r_new,
+                      preferred_element_type=f32))[:, None] * scale
+    acc = jnp.broadcast_to(c_new.astype(f32)[:, None], q_lat.shape)
+
+    def body(start, attended, state):
+        m, l, acc = state  # [B,1,H], [B,1,H], [B,H,R]
+        c, r = lax.optimization_barrier((
+            lax.dynamic_slice(ckv, (layer, 0, start, 0),
+                              (1, B, chunk, ckv.shape[-1]))[0],
+            lax.dynamic_slice(kr, (layer, 0, start, 0),
+                              (1, B, chunk, kr.shape[-1]))[0]))
+        s = (jnp.einsum("bkd,bhd->bkh", c, q_lat, preferred_element_type=f32)
+             + jnp.einsum("bkd,bhd->bkh", r, q_rope,
+                          preferred_element_type=f32)) * scale
+        s = jnp.where(attended()[:, :, None], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)  # a masked row: exp(-1e30 - m) == 0
+        shrink = jnp.exp(m - m_new)
+        l = shrink * l + p.sum(axis=1, keepdims=True)
+        acc = shrink[:, 0, :, None] * acc + jnp.einsum(
+            "bkh,bkd->bhd", p.astype(q_lat.dtype), c,
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    l, acc = _walk_rows(body, m, acc, pos, s_max, chunk)
+    return (acc / l[:, 0, :, None]).astype(q_lat.dtype)
+
+
+def _mla_scale(c: TransformerConfig) -> float:
+    return (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
+
+
+def _latent(fn):
+    """Marks an ``attn_fn`` that takes latents (transformer._mla_mixer)."""
+    fn.latent = True
+    return fn
+
+
+def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig):
+    """One decode layer's ``attn_fn`` for ``c.mixer``: attends the
+    cache's prefix plus the token itself WITHOUT a pre-write (see
+    _attend_prefix_plus_self) and returns (output, the cache with the
+    token's row written at ``pos``): the write only feeds LATER steps, so
+    it stays off the attention's critical path. ``b_idx`` is
+    arange(B)."""
+    if c.mixer == "mla":
+        @_latent
+        def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
+            # Absorbed form: W_uk goes into the query and W_uv onto the
+            # output, so the walk sees one key that all heads share, whose
+            # latent part is the value as well.
+            ckv, kr = cache["ckv"], cache["kr"]
+            q_lat = jnp.einsum("bshk,chk->bshc", q_nope,
+                               wp["wuk"].astype(c.dtype))
+            c_new, r_new = c_kv[:, 0], k_r[:, 0, 0]  # [B,R], [B,rope]
+            o_lat = _attend_latent_prefix_plus_self(
+                q_lat[:, 0], q_rope[:, 0], ckv, kr, c_new, r_new, pos,
+                layer=li, scale=_mla_scale(c))
+            out = jnp.einsum("bshc,chk->bshk", o_lat[:, None],
+                             wp["wuv"].astype(c.dtype))
+            return out, {
+                "ckv": ckv.at[li, b_idx, pos].set(c_new.astype(ckv.dtype)),
+                "kr": kr.at[li, b_idx, pos].set(r_new.astype(kr.dtype))}
+
+        return cached_attn
+
+    def cached_attn(q, k, v):
+        ck_all, cv_all = cache["k"], cache["v"]
+        out = _attend_prefix_plus_self(
+            q, ck_all, cv_all, k, v, pos, layer=li
+        )
+        ck2 = ck_all.at[li, b_idx, pos].set(
+            k[:, 0].astype(ck_all.dtype)
+        )
+        cv2 = cv_all.at[li, b_idx, pos].set(
+            v[:, 0].astype(cv_all.dtype)
+        )
+        return out, {"k": ck2, "v": cv2}
+
+    return cached_attn
+
+
+def _add_stats(total, stats):
+    return {k: total[k] + v for k, v in stats.items()} if stats else total
+
+
+def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
+    """Names of the int32 counters ``decode_block`` returns with its
+    tokens for this model (none for a model without dropless experts)."""
+    if config.moe_experts and config.moe_impl == "dropless":
+        return ("moe_assignments", "moe_experts_touched",
+                "moe_experts_capacity", "moe_max_load")
+    return ()
+
+
+def _zero_stats(config: TransformerConfig):
+    return {k: jnp.zeros((), jnp.int32) for k in block_stat_keys(config)}
 
 
 def _decode_forward_multi(params, token, cache, pos,
                           config: TransformerConfig):
     """Core of the per-slot decode step (tokens [B] at per-slot positions
     pos [B]); shared by decode_step_multi and the scanned decode_block.
+    Returns (logits [B,V], cache, stats).
 
     Two structures, selected by ``RAYTPU_DECODE_DEFERRED_WRITES``:
 
-    * deferred (=1): the layer scan only READS the cache (closed over,
-      its chunks sliced by layer index) and attends prefix-plus-self;
-      each layer's fresh k/v come
+    * deferred (=1, MHA/GQA only): the layer scan only READS the cache
+      (closed over, its chunks sliced by layer index) and attends
+      prefix-plus-self; each layer's fresh k/v come
       out as scan ys and land with ONE batched scatter after the scan
       ([L,Hkv,D] rows per slot) instead of two scatters per layer inside
       it — 2 scatters/step vs 2L. Candidate fix for the small-op-bound
@@ -258,7 +423,8 @@ def _decode_forward_multi(params, token, cache, pos,
     """
     import os as _os
 
-    if _os.environ.get("RAYTPU_DECODE_DEFERRED_WRITES", "0") == "1":
+    if _os.environ.get("RAYTPU_DECODE_DEFERRED_WRITES", "0") == "1" and (
+            config.mixer == "mha"):
         return _decode_forward_multi_deferred(params, token, cache, pos,
                                               config)
     return _decode_forward_multi_carry(params, token, cache, pos, config)
@@ -294,49 +460,33 @@ def _decode_forward_multi_deferred(params, token, cache, pos,
     x = _rms_norm(x, params["final_ln"]["scale"])
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
-    return logits[:, 0, :], {"k": new_k, "v": new_v}
+    return logits[:, 0, :], {"k": new_k, "v": new_v}, {}
 
 
 def _decode_forward_multi_carry(params, token, cache, pos,
                                 config: TransformerConfig):
     c = config
-    B = token.shape[0]
     x = params["embed"].astype(c.dtype)[token][:, None]  # [B,1,D]
-    b_idx = jnp.arange(B)
-
-    def layer(carry, layer_in):
-        x, ck_all, cv_all = carry
-        lp, li = layer_in
-
-        def cached_attn(q, k, v):
-            # per-slot attention WITHOUT a pre-write (prefix + self; see
-            # _attend_prefix_plus_self) — the scatters below only feed
-            # LATER steps, so they stay off the attention critical path
-            out = _attend_prefix_plus_self(
-                q, ck_all, cv_all, k, v, pos, layer=li
+    # a parked lane's token picks no expert (only a routed layer asks)
+    live = (pos > 0)[:, None] if block_stat_keys(c) else None
+    b_idx = jnp.arange(token.shape[0])
+    carry = (x, cache, _zero_stats(c))
+    for stack, lc, first in layer_groups(params, c):
+        def layer(carry, lp, li, lc=lc):
+            x, cache, total = carry
+            y, _aux, cache, stats = apply_block(
+                x, lp, lc, pos[:, None],
+                _decode_attn(cache, li, pos, b_idx, lc),
+                token_mask=live,
             )
-            ck2 = ck_all.at[li, b_idx, pos].set(
-                k[:, 0].astype(ck_all.dtype)
-            )
-            cv2 = cv_all.at[li, b_idx, pos].set(
-                v[:, 0].astype(cv_all.dtype)
-            )
-            return out, (ck2, cv2)
+            return y, cache, _add_stats(total, stats)
 
-        y, _aux, (ck_all, cv_all) = apply_layer(
-            x, lp, c, pos[:, None], cached_attn
-        )
-        return (y, ck_all, cv_all), None
-
-    (x, new_k, new_v), _ = lax.scan(
-        layer,
-        (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(c.n_layers)),
-    )
-    x = _rms_norm(x, params["final_ln"]["scale"])
+        carry = scan_stack(layer, carry, stack, lc, first)
+    x, cache, stats = carry
+    x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
-    return logits[:, 0, :], {"k": new_k, "v": new_v}
+    return logits[:, 0, :], cache, stats
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -347,7 +497,7 @@ def decode_step_multi(params, token, cache, pos, config: TransformerConfig):
     Inactive slots simply decode garbage into their own lane — they attend
     only their own cache row, so active slots are unaffected; the engine
     ignores their outputs. Returns (logits [B, V], cache)."""
-    return _decode_forward_multi(params, token, cache, pos, config)
+    return _decode_forward_multi(params, token, cache, pos, config)[:2]
 
 
 def _sample_vec(logits, temps, seeds, counts):
@@ -381,18 +531,23 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
     follows the live sequences; a free lane that kept counting would
     drag it to S_max on an idle engine.
 
-    Returns (tokens [B, steps], cache, token', pos', counts')."""
+    Returns (tokens [B, steps], cache, token', pos', counts', stats):
+    ``stats`` holds the block's int32 counters named by
+    ``block_stat_keys`` (an empty dict for most models), summed over its
+    steps and layers; they leave the device with the tokens."""
     def step(carry, _):
-        tok, cache, pos, counts = carry
-        logits, cache = _decode_forward_multi(params, tok, cache, pos,
-                                              config)
+        tok, cache, pos, counts, total = carry
+        logits, cache, stats = _decode_forward_multi(
+            params, tok, cache, pos, config)
         nxt = _sample_vec(logits, temps, seeds, counts)
-        return (nxt, cache, pos + (pos > 0), counts + 1), nxt
+        return (nxt, cache, pos + (pos > 0), counts + 1,
+                _add_stats(total, stats)), nxt
 
-    (token, cache, pos, counts), toks = lax.scan(
-        step, (token, cache, pos, counts), None, length=steps
+    (token, cache, pos, counts, stats), toks = lax.scan(
+        step, (token, cache, pos, counts, _zero_stats(config)), None,
+        length=steps,
     )
-    return toks.T, cache, token, pos, counts
+    return toks.T, cache, token, pos, counts, stats
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(4,))
@@ -404,23 +559,42 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     never attended: the slot's kv_valid mask stops at its position, and
     decode overwrites those cells before reaching them.
 
+    Latent attention takes the plain form here: per-head keys and values
+    are expanded from the prompt's latents and attended causally over the
+    prompt; what the slot keeps is the latent rows ``c_kv`` and ``rot(k_r)``.
+
     Returns (last-valid-token logits [V], cache)."""
     c = config
-    single = {
-        "k": jnp.zeros_like(cache["k"][:, :1]),
-        "v": jnp.zeros_like(cache["v"][:, :1]),
-    }
-    s_max = cache["k"].shape[2]
+    single = jax.tree.map(lambda a: jnp.zeros_like(a[:, :1]), cache)
+    s_max = jax.tree.leaves(cache)[0].shape[2]
     S = prompt.shape[1]
     x = params["embed"].astype(c.dtype)[prompt]
     positions = jnp.arange(S)
     kv_valid = (jnp.arange(s_max) < prompt_len)[None]  # [1, S_max]
+    # a prompt's padding picks no expert (only a routed layer asks)
+    real = (positions < prompt_len)[None] if block_stat_keys(c) else None
 
-    def layer(carry, layer_in):
-        x, ck_all, cv_all = carry
-        lp, li = layer_in
+    def slot_attn(single, li):
+        if c.mixer == "mla":
+            @_latent
+            def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
+                new = {
+                    "ckv": lax.dynamic_update_slice(
+                        single["ckv"], c_kv[None].astype(
+                            single["ckv"].dtype), (li, 0, 0, 0)),
+                    "kr": lax.dynamic_update_slice(
+                        single["kr"], k_r[None, :, :, 0].astype(
+                            single["kr"].dtype), (li, 0, 0, 0))}
+                # the plain form over the prompt alone (q and k are both
+                # nope + rope wide: the scale is causal_attention's own)
+                k, v = mla_expand(c_kv, k_r, wp, c)
+                q = jnp.concatenate([q_nope, q_rope], -1)
+                return causal_attention(q, k, v), new
+
+            return cached_attn
 
         def cached_attn(q, k, v):
+            ck_all, cv_all = single["k"], single["v"]
             ck2 = lax.dynamic_update_slice(
                 ck_all, k[None].astype(ck_all.dtype), (li, 0, 0, 0, 0)
             )
@@ -429,14 +603,11 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
             )
             ck = lax.dynamic_index_in_dim(ck2, li, 0, keepdims=False)
             cv = lax.dynamic_index_in_dim(cv2, li, 0, keepdims=False)
-            return _attend_prefill(q, ck, cv, positions, kv_valid), (
-                ck2, cv2
-            )
+            return _attend_prefill(q, ck, cv, positions, kv_valid), {
+                "k": ck2, "v": cv2
+            }
 
-        y, _aux, (ck_all, cv_all) = apply_layer(
-            x, lp, c, positions, cached_attn
-        )
-        return (y, ck_all, cv_all), None
+        return cached_attn
 
     def _attend_prefill(q, ck, cv, q_pos, kv_valid_b):
         n_rep = q.shape[2] // ck.shape[2]
@@ -454,22 +625,26 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
-    (x, single_k, single_v), _ = lax.scan(
-        layer,
-        (x, single["k"], single["v"]),
-        (params["layers"], jnp.arange(c.n_layers)),
-    )
-    x = _rms_norm(x, params["final_ln"]["scale"])
+    carry = (x, single)
+    for stack, lc, first in layer_groups(params, c):
+        def layer(carry, lp, li, lc=lc):
+            x, single = carry
+            y, _aux, single, _stats = apply_block(
+                x, lp, lc, positions, slot_attn(single, li),
+                token_mask=real,
+            )
+            return y, single
+
+        carry = scan_stack(layer, carry, stack, lc, first)
+    x, single = carry
+    x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     last = x[0, prompt_len - 1]  # [D] — last REAL token's features
     logits = last @ head.astype(c.dtype)
-    new_k = lax.dynamic_update_slice(
-        cache["k"], single_k, (0, slot, 0, 0, 0)
-    )
-    new_v = lax.dynamic_update_slice(
-        cache["v"], single_v, (0, slot, 0, 0, 0)
-    )
-    return logits, {"k": new_k, "v": new_v}
+    return logits, jax.tree.map(
+        lambda big, one: lax.dynamic_update_slice(
+            big, one, (0, slot) + (0,) * (big.ndim - 2)),
+        cache, single)
 
 
 def _sample(logits, rng, temperature: float):

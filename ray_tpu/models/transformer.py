@@ -7,9 +7,16 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
 - pure pytree params + functional apply; no framework magic between the
   model and `jax.jit`, so shardings attach cleanly;
 - layers stacked and iterated with `lax.scan` → O(1) compile time in depth,
-  XLA-friendly static control flow;
-- GPT-J-style *parallel* attention+MLP block (one residual add, fuses well);
-- rotary position embeddings, RMSNorm, optional GQA (n_kv_heads);
+  XLA-friendly static control flow (one scan per stack of like layers:
+  leading dense layers, then the rest);
+- the block is DESCRIBED by `TransformerConfig`, and parameters, logical
+  axes, the parameter count, the cache and the forwards follow from the
+  description. The defaults are the GPT-J-style *parallel* attention+MLP
+  block (one residual add, fuses well) with rotary position embeddings,
+  RMSNorm, optional GQA (n_kv_heads); the other forms are a sequential
+  two-norm block, gated FFNs, latent attention (`mixer="mla"`), and
+  dropless routed experts with a shared expert (`moe_impl="dropless"`):
+  `TransformerConfig.glm47_flash()` is all of them at once;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -60,10 +67,50 @@ class TransformerConfig:
     # recompute — only for memory-bound configs).
     remat_policy: str = "dots"
     tie_embeddings: bool = False
+    # ---- the block's description; the defaults are GPT-J's block ----
+    # Mixer: "mha" (MHA/GQA, the fields above) or "mla" (latent attention:
+    # queries through a q_lora_rank bottleneck, keys and values expanded
+    # from one kv_lora_rank latent per token, plus a qk_rope_dim rotary key
+    # that all heads share; d_head and rotary_dim are then unused). The
+    # cache a mixer keeps follows from it (generation.init_kv_cache).
+    mixer: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # "parallel": y = x + attn(ln1 x) + ffn(ln1 x), one norm;
+    # "sequential": h = x + attn(ln1 x), y = h + ffn(ln2 h).
+    residual: str = "parallel"
+    activation: str = "gelu"  # gelu | silu
+    gated_ffn: bool = False  # wo(act(wg x) * wi x) instead of wo(act(wi x))
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    # Routed experts. "capacity": GShard dispatch with dropped overflow
+    # (ops/moe.moe_ffn, softmax gates, ungated experts of width d_ff).
+    # "dropless": ops/moe.routed_ffn: sigmoid scores, the moe_top_k
+    # largest of score + bias chosen, weights from the scores, normalised,
+    # times moe_route_scale; gated experts of width moe_d_ff;
+    # moe_shared_experts experts that every token takes. The first
+    # n_dense_layers layers keep a dense FFN of width d_ff (their weights
+    # are params["dense_layers"]; params["layers"] holds the rest).
+    moe_impl: str = "capacity"
+    moe_d_ff: int = 0
+    moe_shared_experts: int = 0
+    moe_route_scale: float = 1.0
+    n_dense_layers: int = 0
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.moe_experts else 0
+
+    def dense_variant(self) -> "TransformerConfig":
+        """The same block with a dense FFN: the leading layers' config."""
+        return dataclasses.replace(self, moe_experts=0, n_dense_layers=0)
 
     def param_count(self) -> int:
         d, f, h, kv, dh = (
@@ -73,13 +120,33 @@ class TransformerConfig:
             self.kv_heads,
             self.d_head,
         )
-        if self.moe_experts:
-            ffn = d * self.moe_experts + 2 * self.moe_experts * d * f
+        mats = 3 if self.gated_ffn else 2
+        dense_ffn = mats * d * f
+        if not self.moe_experts:
+            ffn = dense_ffn
+        elif self.moe_impl == "dropless":
+            fe = self.moe_d_ff or f
+            ffn = (d + 1) * self.moe_experts + mats * d * fe * (
+                self.moe_experts + self.moe_shared_experts)
         else:
-            ffn = 2 * d * f
-        per_layer = d * dh * (h + 2 * kv) + h * dh * d + ffn + d
+            ffn = d * self.moe_experts + 2 * self.moe_experts * d * f
+        if self.mixer == "mla":
+            qk = self.qk_nope_dim + self.qk_rope_dim
+            attn = (d * self.q_lora_rank + self.q_lora_rank
+                    + self.q_lora_rank * h * qk
+                    + d * (self.kv_lora_rank + self.qk_rope_dim)
+                    + self.kv_lora_rank
+                    + self.kv_lora_rank * h * (self.qk_nope_dim
+                                               + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        else:
+            attn = d * dh * (h + 2 * kv) + h * dh * d
+        norms = d * (2 if self.residual == "sequential" else 1)
+        n_dense = self.n_dense_layers if self.moe_experts else 0
+        layers = (self.n_layers * (attn + norms) + n_dense * dense_ffn
+                  + (self.n_layers - n_dense) * ffn)
         head = 0 if self.tie_embeddings else d * self.vocab_size
-        return self.vocab_size * d + self.n_layers * per_layer + d + head
+        return self.vocab_size * d + layers + d + head
 
     # ---- canonical sizes ----
     @staticmethod
@@ -121,6 +188,41 @@ class TransformerConfig:
         )
 
     @staticmethod
+    def glm47_flash(n_layers: int = 47, **kw) -> "TransformerConfig":
+        """GLM-4.7-Flash (zai-org/GLM-4.7-Flash config.json, model_type
+        glm4_moe_lite) at its published widths: latent attention with 20
+        heads, one dense layer, then layers of 64 routed experts (4 a
+        token, sigmoid scores, bias-corrected choice) and a shared one.
+        ``n_layers`` counts the dense layer. Its multi-token-prediction
+        module (num_nextn_predict_layers 1) is not part of the block."""
+        base = dict(
+            vocab_size=154880, d_model=2048, n_layers=n_layers, n_heads=20,
+            d_ff=10240, max_seq_len=202752, mixer="mla", q_lora_rank=768,
+            kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64,
+            v_head_dim=256, residual="sequential", activation="silu",
+            gated_ffn=True, norm_eps=1e-5, rope_theta=1e6, moe_experts=64,
+            moe_top_k=4, moe_impl="dropless", moe_d_ff=1536,
+            moe_shared_experts=1, moe_route_scale=1.8, n_dense_layers=1,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_mla_moe(**kw) -> "TransformerConfig":
+        """The same block at test size (CPU)."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=3, n_heads=4, d_ff=160,
+            max_seq_len=1024, mixer="mla", q_lora_rank=24, kv_lora_rank=16,
+            qk_nope_dim=12, qk_rope_dim=8, v_head_dim=16,
+            residual="sequential", activation="silu", gated_ffn=True,
+            norm_eps=1e-5, rope_theta=1e6, moe_experts=8, moe_top_k=4,
+            moe_impl="dropless", moe_d_ff=48, moe_shared_experts=1,
+            moe_route_scale=1.8, n_dense_layers=1,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny(**kw) -> "TransformerConfig":
         base = dict(
             vocab_size=256, d_model=64, n_layers=2, n_heads=4,
@@ -142,36 +244,92 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     def dense_init(key, shape, fan_in):
         return (jax.random.normal(key, shape) * (fan_in ** -0.5)).astype(pd)
 
-    L = c.n_layers
-    layers = {
-        "ln1": {"scale": jnp.ones((L, c.d_model), pd)},
-        "attn": {
-            "wq": dense_init(k_q, (L, c.d_model, c.n_heads, c.d_head), c.d_model),
-            "wk": dense_init(k_k, (L, c.d_model, c.kv_heads, c.d_head), c.d_model),
-            "wv": dense_init(k_v, (L, c.d_model, c.kv_heads, c.d_head), c.d_model),
-            "wo": dense_init(k_o, (L, c.n_heads, c.d_head, c.d_model),
-                             c.n_heads * c.d_head),
-        },
-    }
-    if c.moe_experts:
-        E = c.moe_experts
-        k_rt = jax.random.fold_in(k_wi, 1)
-        layers["moe"] = {
-            "router": dense_init(k_rt, (L, c.d_model, E), c.d_model),
-            "wi": dense_init(k_wi, (L, E, c.d_model, c.d_ff), c.d_model),
-            "wo": dense_init(k_wo, (L, E, c.d_ff, c.d_model), c.d_ff),
-        }
-    else:
-        layers["mlp"] = {
-            "wi": dense_init(k_wi, (L, c.d_model, c.d_ff), c.d_model),
-            "wo": dense_init(k_wo, (L, c.d_ff, c.d_model), c.d_ff),
-        }
+    def gate(key, shape, fan_in):  # the third matrix of a gated FFN
+        return {"wg": dense_init(jax.random.fold_in(key, 2), shape, fan_in)
+                } if c.gated_ffn else {}
+
+    def stack(lc: TransformerConfig, L: int, salt: int) -> Dict:
+        """L layers of ``lc``'s block, stacked on a leading axis."""
+        kq, kk, kv, ko, kwi, kwo = (
+            (k_q, k_k, k_v, k_o, k_wi, k_wo) if not salt else
+            [jax.random.fold_in(k, salt) for k in (k_q, k_k, k_v, k_o,
+                                                   k_wi, k_wo)])
+        d = lc.d_model
+        layers = {"ln1": {"scale": jnp.ones((L, d), pd)}}
+        if lc.residual == "sequential":
+            layers["ln2"] = {"scale": jnp.ones((L, d), pd)}
+        if lc.mixer == "mla":
+            h, r_q, r_kv = lc.n_heads, lc.q_lora_rank, lc.kv_lora_rank
+            qk = lc.qk_nope_dim + lc.qk_rope_dim
+            layers["attn"] = {
+                "wdq": dense_init(kq, (L, d, r_q), d),
+                "q_norm": jnp.ones((L, r_q), pd),
+                "wuq": dense_init(jax.random.fold_in(kq, 1),
+                                  (L, r_q, h, qk), r_q),
+                "wdkv": dense_init(kk, (L, d, r_kv + lc.qk_rope_dim), d),
+                "kv_norm": jnp.ones((L, r_kv), pd),
+                "wuk": dense_init(jax.random.fold_in(kk, 1),
+                                  (L, r_kv, h, lc.qk_nope_dim), r_kv),
+                "wuv": dense_init(kv, (L, r_kv, h, lc.v_head_dim), r_kv),
+                "wo": dense_init(ko, (L, h, lc.v_head_dim, d),
+                                 h * lc.v_head_dim),
+            }
+        else:
+            layers["attn"] = {
+                "wq": dense_init(kq, (L, d, lc.n_heads, lc.d_head), d),
+                "wk": dense_init(kk, (L, d, lc.kv_heads, lc.d_head), d),
+                "wv": dense_init(kv, (L, d, lc.kv_heads, lc.d_head), d),
+                "wo": dense_init(ko, (L, lc.n_heads, lc.d_head, d),
+                                 lc.n_heads * lc.d_head),
+            }
+        if lc.moe_experts and lc.moe_impl == "dropless":
+            E, f = lc.moe_experts, lc.moe_d_ff or lc.d_ff
+            k_rt = jax.random.fold_in(kwi, 1)
+            layers["moe"] = {
+                "router": dense_init(k_rt, (L, d, E), d),
+                # the selection's correction bias: trained in the published
+                # model (to even out the experts' load), here seeded, non-zero
+                # and small against the scores' spread (~0.2): at 0.1 the
+                # fullest expert drew 5.5 x the mean load on the chip
+                "bias": (0.02 * jax.random.normal(
+                    jax.random.fold_in(k_rt, 1), (L, E))).astype(pd),
+                "wi": dense_init(kwi, (L, E, d, f), d),
+                "wo": dense_init(kwo, (L, E, f, d), f),
+                **gate(kwi, (L, E, d, f), d),
+            }
+            if lc.moe_shared_experts:
+                fs, ks = f * lc.moe_shared_experts, jax.random.fold_in(kwi, 3)
+                layers["moe"]["shared"] = {
+                    "wi": dense_init(ks, (L, d, fs), d),
+                    "wo": dense_init(jax.random.fold_in(kwo, 3),
+                                     (L, fs, d), fs),
+                    **gate(ks, (L, d, fs), d),
+                }
+        elif lc.moe_experts:
+            E = lc.moe_experts
+            k_rt = jax.random.fold_in(kwi, 1)
+            layers["moe"] = {
+                "router": dense_init(k_rt, (L, d, E), d),
+                "wi": dense_init(kwi, (L, E, d, lc.d_ff), d),
+                "wo": dense_init(kwo, (L, E, lc.d_ff, d), lc.d_ff),
+            }
+        else:
+            layers["mlp"] = {
+                "wi": dense_init(kwi, (L, d, lc.d_ff), d),
+                "wo": dense_init(kwo, (L, lc.d_ff, d), lc.d_ff),
+                **gate(kwi, (L, d, lc.d_ff), d),
+            }
+        return layers
+
+    n_dense = c.n_dense_layers if c.moe_experts else 0
     params = {
         "embed": (jax.random.normal(k_emb, (c.vocab_size, c.d_model)) * 0.02
                   ).astype(pd),
-        "layers": layers,
+        "layers": stack(c, c.n_layers - n_dense, 0),
         "final_ln": {"scale": jnp.ones((c.d_model,), pd)},
     }
+    if n_dense:
+        params["dense_layers"] = stack(c.dense_variant(), n_dense, 7)
     if not c.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (c.d_model, c.vocab_size),
                                        c.d_model)
@@ -180,33 +338,108 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
 
 def param_logical_axes(config: TransformerConfig) -> Dict:
     """Same-structure tree of logical axis-name tuples (None = no sharding)."""
-    axes = {
-        "embed": ("vocab", "embed"),
-        "layers": {
-            "ln1": {"scale": ("layers", "embed")},
-            "attn": {
+
+    def gate(axes):
+        return {"wg": axes} if config.gated_ffn else {}
+
+    def stack(lc: TransformerConfig) -> Dict:
+        layers = {"ln1": {"scale": ("layers", "embed")}}
+        if lc.residual == "sequential":
+            layers["ln2"] = {"scale": ("layers", "embed")}
+        if lc.mixer == "mla":
+            layers["attn"] = {
+                "wdq": ("layers", "embed", None),
+                "q_norm": ("layers", None),
+                "wuq": ("layers", None, "heads", "head_dim"),
+                "wdkv": ("layers", "embed", None),
+                "kv_norm": ("layers", None),
+                "wuk": ("layers", None, "heads", "head_dim"),
+                "wuv": ("layers", None, "heads", "head_dim"),
+                "wo": ("layers", "heads", "head_dim", "embed"),
+            }
+        else:
+            layers["attn"] = {
                 "wq": ("layers", "embed", "heads", "head_dim"),
                 "wk": ("layers", "embed", "kv_heads", "head_dim"),
                 "wv": ("layers", "embed", "kv_heads", "head_dim"),
                 "wo": ("layers", "heads", "head_dim", "embed"),
-            },
-        },
+            }
+        if lc.moe_experts:
+            wi = ("layers", "experts", "embed", "mlp")
+            layers["moe"] = {
+                "router": ("layers", "embed", "experts"),
+                "wi": wi,
+                "wo": ("layers", "experts", "mlp", "embed"),
+            }
+            if lc.moe_impl == "dropless":
+                layers["moe"].update(bias=("layers", "experts"), **gate(wi))
+                if lc.moe_shared_experts:
+                    layers["moe"]["shared"] = {
+                        "wi": ("layers", "embed", "mlp"),
+                        "wo": ("layers", "mlp", "embed"),
+                        **gate(("layers", "embed", "mlp")),
+                    }
+        else:
+            layers["mlp"] = {
+                "wi": ("layers", "embed", "mlp"),
+                "wo": ("layers", "mlp", "embed"),
+                **gate(("layers", "embed", "mlp")),
+            }
+        return layers
+
+    axes = {
+        "embed": ("vocab", "embed"),
+        "layers": stack(config),
         "final_ln": {"scale": ("embed",)},
     }
-    if config.moe_experts:
-        axes["layers"]["moe"] = {
-            "router": ("layers", "embed", "experts"),
-            "wi": ("layers", "experts", "embed", "mlp"),
-            "wo": ("layers", "experts", "mlp", "embed"),
-        }
-    else:
-        axes["layers"]["mlp"] = {
-            "wi": ("layers", "embed", "mlp"),
-            "wo": ("layers", "mlp", "embed"),
-        }
+    if config.moe_experts and config.n_dense_layers:
+        axes["dense_layers"] = stack(config.dense_variant())
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
+
+
+def layer_groups(params: Dict, config: TransformerConfig):
+    """The stacks of layers in the order they run, each as (stacked
+    weights, the config of that stack's block, index of its first layer):
+    the leading dense layers where the model has them, then the rest. Each
+    stack is one ``lax.scan``."""
+    groups = []
+    n_dense = config.n_dense_layers if config.moe_experts else 0
+    if n_dense:
+        groups.append((params["dense_layers"], config.dense_variant(), 0))
+    groups.append((params["layers"], config, n_dense))
+    return groups
+
+
+_EXPERT_WEIGHTS = ("wg", "wi", "wo")
+
+
+def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
+    """``lax.scan`` of ``body(carry, lp, li) -> carry`` over one stack of
+    layers, ``li`` counting from ``first``. A scan hands its body one
+    layer's slice of every stacked weight; a slice that feeds a compiler
+    kernel is COPIED out of the stack first, and for dropless routed
+    experts (``lax.ragged_dot``) that copy is the whole layer's experts,
+    read or not. So those weights stay out of the scanned tree: ``lp``
+    carries them whole, [layers, E, ...], with ``lp["moe"]["layer"]``
+    saying which layer's experts to use (``ops/moe.routed_ffn``)."""
+    n = jax.tree.leaves(stack)[0].shape[0]
+    held = {}
+    if lc.moe_experts and lc.moe_impl == "dropless":
+        held = {k: stack["moe"][k] for k in _EXPERT_WEIGHTS
+                if k in stack["moe"]}
+        stack = {**stack, "moe": {k: v for k, v in stack["moe"].items()
+                                  if k not in held}}
+
+    def step(carry, layer_in):
+        lp, li = layer_in
+        if held:
+            lp = {**lp, "moe": {**lp["moe"], **held, "layer": li - first}}
+        return body(carry, lp, li), None
+
+    carry, _ = lax.scan(step, carry, (stack, jnp.arange(first, first + n)))
+    return carry
 
 
 # ---------------------------------------------------------------------------
@@ -219,15 +452,15 @@ def _rms_norm(x, scale, eps=1e-6):
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
-def _rotary(q, k, rotary_dim, positions):
+def _rotary(q, k, rotary_dim, positions, base=10000.0):
     """Apply rotary embeddings to the first `rotary_dim` dims of q/k.
 
-    q/k: [B, S, H, D]; positions: [S] global token positions, or [B, S]
-    per-sequence positions (continuous-batching decode, where slots sit at
-    different depths).
+    q/k: [B, S, H, D] (k's H may be 1: a key all heads share); positions:
+    [S] global token positions, or [B, S] per-sequence positions
+    (continuous-batching decode, where slots sit at different depths).
     """
     d2 = rotary_dim // 2
-    inv_freq = 1.0 / (10000.0 ** (jnp.arange(0, d2) / d2))
+    inv_freq = 1.0 / (base ** (jnp.arange(0, d2) / d2))
     freqs = (
         positions[..., None].astype(jnp.float32) * inv_freq
     )  # [S,d2] or [B,S,d2]
@@ -280,36 +513,111 @@ def select_attn_fn(config: TransformerConfig,
     raise ValueError(f"unknown attn_impl {c.attn_impl!r}")
 
 
-def apply_layer(
-    x: jax.Array,  # [B, S, D]
-    lp: Dict,  # ONE layer's params (no leading L dim)
-    config: TransformerConfig,
-    positions: jax.Array,
-    attn_fn,
-    mesh: Optional[jax.sharding.Mesh] = None,
-):
-    """GPT-J parallel block: y = x + attn(ln(x)) + ffn(ln(x)).
-
-    Shared by the scanned single-program forward below, the pipeline
-    schedule (parallel/pipeline.py), and the KV-cached generation path
-    (models/generation.py). ``attn_fn(q, k, v)`` may return either the
-    attention output or ``(output, extra)`` — ``extra`` (e.g. updated KV
-    caches) is passed through. Returns (y, aux_loss, extra)."""
-    c = config
-    h = _rms_norm(x, lp["ln1"]["scale"])
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"].astype(c.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wk"].astype(c.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wv"].astype(c.dtype))
-    q, k = _rotary(q, k, c.rotary_dim, positions)
+def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", h, wp["wk"].astype(c.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", h, wp["wv"].astype(c.dtype))
+    q, k = _rotary(q, k, c.rotary_dim, positions, c.rope_theta)
     attn_out = attn_fn(q, k, v)
     extra = None
     if isinstance(attn_out, tuple):
         attn_out, extra = attn_out
     # Named for remat policies ("dots_attn" saves it).
     attn_out = checkpoint_name(attn_out, "attn_out")
-    a = jnp.einsum("bshk,hkd->bsd", attn_out,
-                   lp["attn"]["wo"].astype(c.dtype))
-    if c.moe_experts:
+    return jnp.einsum("bshk,hkd->bsd", attn_out,
+                      wp["wo"].astype(c.dtype)), extra
+
+
+def mla_expand(c_kv, k_r, wp, c: TransformerConfig):
+    """The plain form's keys and values from latents: c_kv [B,S,r] and the
+    shared rotary key k_r [B,S,1,rope] -> k [B,S,H,nope+rope], v
+    [B,S,H,v]."""
+    k_nope = jnp.einsum("bsc,chk->bshk", c_kv, wp["wuk"].astype(c.dtype))
+    v = jnp.einsum("bsc,chk->bshk", c_kv, wp["wuv"].astype(c.dtype))
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_r, k_nope.shape[:3] + k_r.shape[3:])],
+        axis=-1)
+    return k, v
+
+
+def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """Latent attention. Scores are (q_nope . k_nope + q_rope . k_r) /
+    sqrt(nope + rope); every head shares the one rotary key. ``attn_fn``
+    is either the usual ``attn_fn(q, k, v)`` over per-head keys and values
+    (the plain form: training, the uncached forward), or, marked
+    ``attn_fn.latent``, ``attn_fn(q_nope, q_rope, c_kv, k_r, wp)`` working
+    on the latents themselves and returning per-head outputs [B,S,H,v]:
+    that is how the serving paths keep and walk a cache of latents."""
+    r, nope = c.kv_lora_rank, c.qk_nope_dim
+    with jax.named_scope("raytpu.mla.project"):
+        c_q = _rms_norm(
+            jnp.einsum("bsd,dr->bsr", h, wp["wdq"].astype(c.dtype)),
+            wp["q_norm"], c.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", c_q, wp["wuq"].astype(c.dtype))
+        kv = jnp.einsum("bsd,dr->bsr", h, wp["wdkv"].astype(c.dtype))
+        c_kv = _rms_norm(kv[..., :r], wp["kv_norm"], c.norm_eps)
+        q_rope, k_r = _rotary(q[..., nope:], kv[:, :, None, r:],
+                              c.qk_rope_dim, positions, c.rope_theta)
+        q_nope = q[..., :nope]
+    with jax.named_scope("raytpu.mla.attend"):
+        if getattr(attn_fn, "latent", False):
+            attn_out = attn_fn(q_nope, q_rope, c_kv, k_r, wp)
+        else:
+            k, v = mla_expand(c_kv, k_r, wp, c)
+            attn_out = attn_fn(jnp.concatenate([q_nope, q_rope], -1), k, v)
+    extra = None
+    if isinstance(attn_out, tuple):
+        attn_out, extra = attn_out
+    attn_out = checkpoint_name(attn_out, "attn_out")
+    with jax.named_scope("raytpu.mla.project"):
+        a = jnp.einsum("bshk,hkd->bsd", attn_out, wp["wo"].astype(c.dtype))
+    return a, extra
+
+
+_MIXERS = {"mha": _mha_mixer, "mla": _mla_mixer}
+_ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
+
+
+def _dense_ffn(h, wp, c: TransformerConfig):
+    act = _ACTIVATIONS[c.activation]
+    m = jnp.einsum("bsd,df->bsf", h, wp["wi"].astype(c.dtype))
+    if c.gated_ffn:
+        m = act(jnp.einsum("bsd,df->bsf", h, wp["wg"].astype(c.dtype))) * m
+    else:
+        m = act(m)
+    return jnp.einsum("bsf,fd->bsd", m, wp["wo"].astype(c.dtype))
+
+
+def apply_block(
+    x: jax.Array,  # [B, S, D]
+    lp: Dict,  # ONE layer's params (no leading L dim)
+    config: TransformerConfig,
+    positions: jax.Array,
+    attn_fn,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    token_mask: Optional[jax.Array] = None,  # [B, S] bool
+):
+    """One block as ``config`` describes it (mixer, residual form, FFN).
+    ``token_mask`` marks the tokens that count (a parked decode lane, the
+    padding of a prompt do not): a dropless routed layer sends the others
+    to no expert. Returns (y, aux_loss, extra, moe_stats): ``extra`` is
+    what ``attn_fn`` returned beside its output, ``moe_stats`` a dict of
+    int32 scalars from a dropless routed layer (else empty)."""
+    c = config
+    h = _rms_norm(x, lp["ln1"]["scale"], c.norm_eps)
+    a, extra = _MIXERS[c.mixer](h, lp["attn"], c, positions, attn_fn)
+    if c.residual == "sequential":
+        x = x + a
+        h = _rms_norm(x, lp["ln2"]["scale"], c.norm_eps)
+    aux, stats = jnp.zeros((), jnp.float32), {}
+    if c.moe_experts and c.moe_impl == "dropless":
+        from ray_tpu.ops.moe import routed_ffn
+
+        m, stats = routed_ffn(
+            h, lp["moe"], top_k=c.moe_top_k, route_scale=c.moe_route_scale,
+            act=_ACTIVATIONS[c.activation], token_mask=token_mask,
+        )
+    elif c.moe_experts:
         from ray_tpu.ops.moe import moe_ffn
 
         m, aux = moe_ffn(
@@ -322,11 +630,20 @@ def apply_layer(
             mesh=mesh,
         )
     else:
-        m = jnp.einsum("bsd,df->bsf", h, lp["mlp"]["wi"].astype(c.dtype))
-        m = jax.nn.gelu(m)
-        m = jnp.einsum("bsf,fd->bsd", m, lp["mlp"]["wo"].astype(c.dtype))
-        aux = jnp.zeros((), jnp.float32)
-    return x + a + m, aux, extra
+        m = _dense_ffn(h, lp["mlp"], c)
+    if c.residual == "sequential":
+        return x + m, aux, extra, stats
+    return x + a + m, aux, extra, stats
+
+
+def apply_layer(x, lp, config, positions, attn_fn, mesh=None):
+    """``apply_block`` without the routed layer's counters: (y, aux_loss,
+    extra). Shared by the scanned single-program forward below and the
+    pipeline schedule (parallel/pipeline.py); the KV-cached generation
+    paths (models/generation.py) call ``apply_block``. ``attn_fn(q, k,
+    v)`` may return either the attention output or ``(output, extra)`` —
+    ``extra`` (e.g. updated KV caches) is passed through."""
+    return apply_block(x, lp, config, positions, attn_fn, mesh)[:3]
 
 
 def remat_wrap(layer_fn, config: TransformerConfig):
@@ -365,16 +682,16 @@ def forward(
     positions = jnp.arange(tokens.shape[1])
     attn_fn = select_attn_fn(c, mesh)
 
-    def layer(carry, lp):
-        x, aux = carry
-        y, a, _ = apply_layer(x, lp, c, positions, attn_fn, mesh=mesh)
-        return (y, aux + a), None
+    carry = (x, jnp.zeros((), jnp.float32))
+    for stack, lc, _first in layer_groups(params, c):
+        def layer(carry, lp, lc=lc):
+            x, aux = carry
+            y, a, _ = apply_layer(x, lp, lc, positions, attn_fn, mesh=mesh)
+            return (y, aux + a), None
 
-    layer = remat_wrap(layer, c)
-    (x, aux), _ = lax.scan(
-        layer, (x, jnp.zeros((), jnp.float32)), params["layers"]
-    )
-    x = _rms_norm(x, params["final_ln"]["scale"])
+        carry, _ = lax.scan(remat_wrap(layer, c), carry, stack)
+    x, aux = carry
+    x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
     return (logits, aux) if return_aux else logits
@@ -396,6 +713,6 @@ def loss_fn(
     if mask is None:
         mask = jnp.ones_like(ll)
     ce = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    if config.moe_experts:
+    if config.moe_experts and config.moe_impl == "capacity":
         ce = ce + config.moe_aux_weight * aux / config.n_layers
     return ce

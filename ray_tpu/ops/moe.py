@@ -10,6 +10,11 @@ parallelism (the reference ships NO EP/MoE at all — SURVEY.md §2.5).
 
 Refs: GShard (Lepikhin et al.), Switch Transformers (Fedus et al.) — see
 PAPERS.md.
+
+``routed_ffn`` below is the other kind of routed layer: dropless (no
+capacity, no dispatch tensor), for models whose mathematics has no dropped
+token. It runs on one chip's experts; sharding it over ``ep`` is open
+(ROADMAP).
 """
 
 from __future__ import annotations
@@ -107,3 +112,96 @@ def moe_ffn(
     mean_prob = probs.mean(axis=(0, 1))
     aux = (frac * mean_prob).sum() * e
     return out, aux
+
+
+def routed_ffn(
+    x: jax.Array,  # [..., D]
+    wp,  # router [D,E], bias [E], wi/wo (+ wg) [E,...], optional "shared"
+    *,
+    top_k: int,
+    route_scale: float = 1.0,
+    act=jax.nn.silu,
+    token_mask: Optional[jax.Array] = None,  # x.shape[:-1], bool
+):
+    """Dropless routed experts with bias-corrected selection, one
+    implementation for a prompt's S tokens and a decode step's B.
+
+    Scores are ``s = sigmoid(x W_r)`` in float32 (a true float32 product:
+    a near-tie between the k-th and the next expert is decided here). The
+    ``top_k`` experts with the largest ``s + bias`` are chosen; a chosen
+    expert's weight is its ``s`` over the chosen ones' sum, times
+    ``route_scale``. Every chosen (token, expert) pair is computed: the
+    pairs are sorted by expert and the experts' FFNs run as three grouped
+    products (``lax.ragged_dot``) over the sorted rows, so an expert that
+    no token chose is not read, and there is no capacity and no
+    [tokens, E, C] tensor. ``wp["shared"]`` is an FFN every token takes.
+
+    Tokens outside ``token_mask`` (a parked lane, a prompt's padding) are
+    sent to no expert: their pairs sort behind the last group, and their
+    routed output is zero.
+
+    With ``wp["layer"]`` (an index) the experts' weights are those of a
+    whole stack of layers, [layers, E, ...], and the products run over
+    layers x E groups of which only this layer's have rows: the kernel
+    skips an empty group, and nothing slices (copies) a layer's experts
+    out of the stack (``transformer.scan_stack``).
+
+    Returns (y like x, stats): int32 scalars ``moe_assignments`` (pairs
+    computed), ``moe_experts_touched`` (experts with at least one),
+    ``moe_experts_capacity`` (E), ``moe_max_load`` (the fullest expert's
+    pairs)."""
+    f32 = jnp.float32
+    d, E = x.shape[-1], wp["router"].shape[-1]
+    x2 = x.reshape(-1, d)
+    n = x2.shape[0]
+    gated = "wg" in wp
+    with jax.named_scope("raytpu.moe.route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x2.astype(f32), wp["router"].astype(f32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(s + wp["bias"].astype(f32), top_k)  # [n,k]
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        w = w / w.sum(-1, keepdims=True) * route_scale
+        expert = idx.reshape(-1)  # pair p = token p // k, choice p % k
+        if token_mask is not None:
+            live = jnp.repeat(token_mask.reshape(-1), top_k)
+            expert = jnp.where(live, expert, E)  # behind every group
+            w = w * token_mask.reshape(-1, 1)
+        order = jnp.argsort(expert, stable=True)
+        edges = jnp.searchsorted(
+            expert[order], jnp.arange(E + 1), side="left",
+            method="compare_all")
+        sizes = jnp.diff(edges).astype(jnp.int32)  # pairs per expert
+    with jax.named_scope("raytpu.moe.experts"):
+        groups = sizes
+        if "layer" in wp:
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros(wp["wi"].shape[0] * E, jnp.int32), sizes,
+                (wp["layer"] * E,))
+
+        def experts(rows, w):
+            w = w.reshape((-1,) + w.shape[-2:]).astype(x.dtype)
+            return jax.lax.ragged_dot(rows, w, groups)
+
+        xs = x2[order // top_k]  # [n*k, D], sorted by expert
+        h = experts(xs, wp["wi"])
+        h = act(experts(xs, wp["wg"])) * h if gated else act(h)
+        ys = experts(h, wp["wo"])
+        # rows behind the last group hold nothing defined: select, then
+        # back to token order and the weighted sum over a token's choices
+        ys = jnp.where((jnp.arange(n * top_k) < edges[E])[:, None], ys, 0)
+        ys = ys[jnp.argsort(order)].reshape(n, top_k, d)
+        y = jnp.einsum("nkd,nk->nd", ys.astype(f32), w)
+    if "shared" in wp:
+        with jax.named_scope("raytpu.moe.shared"):
+            sp = wp["shared"]
+            m = x2 @ sp["wi"].astype(x.dtype)
+            m = act(x2 @ sp["wg"].astype(x.dtype)) * m if gated else act(m)
+            y = y + (m @ sp["wo"].astype(x.dtype)).astype(f32)
+    stats = {
+        "moe_assignments": edges[E].astype(jnp.int32),
+        "moe_experts_touched": (sizes > 0).sum().astype(jnp.int32),
+        "moe_experts_capacity": jnp.int32(E),
+        "moe_max_load": sizes.max(),
+    }
+    return y.astype(x.dtype).reshape(x.shape), stats

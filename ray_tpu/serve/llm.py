@@ -114,6 +114,7 @@ class LLMEngine:
         import jax.numpy as jnp
 
         from ray_tpu.models.generation import (
+            block_stat_keys,
             init_kv_cache,
             prepare_for_inference,
         )
@@ -173,7 +174,12 @@ class LLMEngine:
         # Counters behind stats(): written by the engine thread only
         # (requests_submitted: by submit(), under the lock). Every key
         # exists from the start, so a reader's copy never sees a resize.
-        self._n: Dict[str, int] = dict.fromkeys(_COUNTERS, 0)
+        # The model's own per-block counters (generation.block_stat_keys:
+        # e.g. a routed layer's experts touched) come back from the device
+        # with each block's tokens and are summed under their names.
+        self._n: Dict[str, int] = dict.fromkeys(
+            _COUNTERS + block_stat_keys(config), 0)
+        self._last_block_stats: Dict[str, int] = {}
         self._t: Dict[str, float] = dict.fromkeys(_PHASES, 0.0)
         self._blocks_by_steps: Dict[int, int] = dict.fromkeys(
             (self.burst_block_steps, self.block_steps), 0)
@@ -197,7 +203,7 @@ class LLMEngine:
 
         jnp = self._jnp
         for steps in {self.burst_block_steps, self.block_steps}:
-            _toks, self.cache, _t, _p, _c = decode_block(
+            _toks, self.cache, _t, _p, _c, _s = decode_block(
                 self.params, self.cache, self.tok, self.pos, self.temps,
                 self.seeds, self.counts, self.config, steps,
             )
@@ -291,7 +297,16 @@ class LLMEngine:
           decode attention walked (per step and slot: whole chunks up to
           the longest sequence in the batch), and ``attn_rows_capacity``
           (``max_len`` x ``max_slots`` x steps), what walking the whole
-          cache would have read.
+          cache would have read (rows of whatever the mixer caches: K and
+          V rows, or latent rows).
+        - Whatever counters the model's ``decode_block`` returns with its
+          tokens (``generation.block_stat_keys``), summed over retired
+          blocks. For dropless routed experts, over steps and expert
+          layers: ``moe_assignments`` ((token, expert) pairs computed:
+          unparked lanes x experts per token), ``moe_experts_touched``
+          (experts that got at least one), ``moe_experts_capacity``
+          (experts there are), ``moe_max_load`` (the fullest expert's
+          pairs).
         """
         with self._lock:
             out = {
@@ -433,8 +448,11 @@ class LLMEngine:
             kv_rows=sum(len(r.prompt) + r.produced for r in live),
             firsts=len(self._pending_first), pending=len(self.pending),
             bound=bound,
+            # of the last block retired (the one in flight is not read)
+            experts_touched=self._last_block_stats.get(
+                "moe_experts_touched", 0),
         ):
-            toks, self.cache, self.tok, self.pos, self.counts = (
+            toks, self.cache, self.tok, self.pos, self.counts, stats = (
                 decode_block(
                     self.params, self.cache, self.tok, self.pos,
                     self.temps, self.seeds, self.counts, self.config,
@@ -450,7 +468,7 @@ class LLMEngine:
         # as decode_block leaves pos: a step on, parked lanes stay at 0
         self._rows = [r + steps if r else 0 for r in self._rows]
         snapshot = list(self.slot_req)  # slot -> req at dispatch
-        return toks, snapshot
+        return (toks, stats), snapshot
 
     def _retire_firsts(self):
         """Emit admitted requests' first tokens. Called right after the
@@ -473,15 +491,20 @@ class LLMEngine:
             self._t["firsts_sync_s"] += t1 - t0
             self._t["firsts_emit_s"] += time.perf_counter() - t1
 
-    def _retire_block(self, toks_dev, snapshot):
-        """Host-sync one block's tokens and deliver them in step order."""
+    def _retire_block(self, block_dev, snapshot):
+        """Host-sync one block's tokens (and the model's counters, which
+        left the device with them) and deliver them in step order."""
         with self._span(
-            "raytpu.engine.retire_block", steps=toks_dev.shape[1],
+            "raytpu.engine.retire_block", steps=block_dev[0].shape[1],
             live=sum(r is not None and not r.finished for r in snapshot),
         ):
             t0 = time.perf_counter()
-            toks = np.asarray(toks_dev)  # [B, K] — THE one sync per block
+            # [B, K] — THE one sync per block
+            toks, stats = self._jax.device_get(block_dev)
             t1 = time.perf_counter()
+            self._last_block_stats = {k: int(v) for k, v in stats.items()}
+            for k, v in self._last_block_stats.items():
+                self._n[k] += v
             for k in range(toks.shape[1]):
                 for slot, req in enumerate(snapshot):
                     if req is None or req.finished:

@@ -50,10 +50,10 @@ def test_deferred_equals_carry_multi_step(kv_heads):
     pos_a = pos_b = pos
     tok_a = tok_b = tok
     for _step in range(5):
-        la, cache_a = _decode_forward_multi_carry(
+        la, cache_a, _ = _decode_forward_multi_carry(
             params, tok_a, cache_a, pos_a, cfg
         )
-        lb, cache_b = _decode_forward_multi_deferred(
+        lb, cache_b, _ = _decode_forward_multi_deferred(
             params, tok_b, cache_b, pos_b, cfg
         )
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))

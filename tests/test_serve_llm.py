@@ -403,7 +403,7 @@ def test_decode_block_parks_lanes_at_pos_zero():
         tok = jnp.asarray([first, dead_tok, 3], jnp.int32)
         pos = jnp.asarray([8, dead_pos, 5], jnp.int32)
         z = jnp.zeros(3, jnp.int32)
-        toks, _c, _t, pos_out, _n = decode_block(
+        toks, _c, _t, pos_out, _n, _s = decode_block(
             params, cache, tok, pos, jnp.zeros(3, jnp.float32), z, z,
             icfg, 4)
         return np.asarray(toks), np.asarray(pos_out).tolist()

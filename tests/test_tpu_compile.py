@@ -56,3 +56,47 @@ def test_flash_kernel_compiles_for_v5e(v5e, shape, grad):
     hlo = jax.jit(fn).lower(x, x, x).compile().as_text()
     # fwd is one kernel; fwd+bwd adds the dq and the dk/dv kernels
     assert hlo.count("tpu_custom_call") == (3 if grad else 1)
+
+
+@pytest.mark.parametrize("program", ["decode_block", "prefill_2048"])
+def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program):
+    """The served cut of GLM-4.7-Flash (8 layers, every width as
+    published, bf16) at the benchmark's engine sizes: 32 slots x 4,096
+    latent rows. The compiler has to take ``lax.ragged_dot`` at 64 groups
+    and the walk over the latent cache, the latent cache has to be
+    updated in place, and the program has to leave room on a 16 GB chip
+    (ISSUE 28: under 14.5 GiB)."""
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.glm47_flash(8, param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 32, 4096)))
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if program == "decode_block":
+        low = gen.decode_block.lower(
+            params, cache, arr((32,)), arr((32,)), arr((32,), jnp.float32),
+            arr((32,)), arr((32,)), cfg, 8)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, 2048)), arr(()), arr(()), cache, cfg)
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = 8 * 32 * 4096 * 576 * 2
+    assert mem.alias_size_in_bytes >= cache_bytes  # no copy of the cache
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.5 * 2 ** 30
+    hlo = compiled.as_text()
+    assert hlo.count("ragged-dot") >= 3  # the experts' grouped products
+    assert "raytpu.moe.experts" in hlo and "raytpu.mla.attend" in hlo
