@@ -60,6 +60,74 @@ def prepare_for_inference(params, config: TransformerConfig):
     return cast, dataclasses.replace(config, param_dtype=config.dtype)
 
 
+def decode_weight_formats(params, config: TransformerConfig, slots: int,
+                          max_len: int, steps: int):
+    """The ``Format`` (physical layout + sharding) in which the compiled
+    ``decode_block`` reads each leaf of ``params``, as a tree like
+    ``params``: ``decode_block`` for ``slots`` lanes of ``max_len`` rows is
+    compiled with every weight's layout left to the compiler
+    (``Layout.AUTO``) and the program's parameter layouts are read back.
+    A leaf the program never reads has layout ``None``. ``params`` may be
+    arrays or ``ShapeDtypeStruct`` s with a sharding; nothing runs."""
+    from jax.experimental.layout import Format, Layout
+
+    auto = jax.tree.map(lambda x: Format(Layout.AUTO, x.sharding), params)
+    # shapes alone: an array that carries a layout may not be asked AUTO
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding), params)
+    cache = jax.eval_shape(lambda: init_kv_cache(config, slots, max_len))
+
+    def lane(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype)
+
+    compiled = jax.jit(
+        decode_block, static_argnames=("config", "steps"),
+        donate_argnums=(1,), in_shardings=(auto,) + (None,) * 6,
+    ).lower(
+        params, cache, lane(jnp.int32), lane(jnp.int32), lane(jnp.float32),
+        lane(jnp.int32), lane(jnp.int32), config, steps,
+    ).compile()
+    return compiled.input_formats[0][0]
+
+
+def lay_out_for_decode(params, config: TransformerConfig, slots: int,
+                       max_len: int, steps: int):
+    """Place every serving weight ONCE where the compiled ``decode_block``
+    reads it: committed to its device, in the physical layout the program
+    asks for (``decode_weight_formats``). A jitted program compiles for
+    the layouts its arguments carry, so a weight left in another layout
+    is re-laid-out INSIDE the program, once a block, while requests wait:
+    on a v5e the three stacked int8 q/k/v projections of a 6B model, 470
+    MB each. The decode step decides (it runs once a token);
+    ``prefill_into_slot`` compiles for what it is handed. One algorithm
+    steered by the compiler's answer for the model and shapes in front of
+    it: where the compiler asks for the layout a leaf already has (every
+    leaf, on the CPU backend) no byte moves.
+
+    A leaf that moves is DONATED, one at a time: set-up's peak rises by
+    one leaf, no weight exists twice afterwards, and the arrays of the
+    tree passed in that were moved are deleted (the engine owns its
+    weights). A leaf that stays is committed where it lies (no copy).
+    Names, logical shapes, dtypes, values and ``QTensor`` structure are
+    unchanged. Returns (params, leaves moved, bytes moved)."""
+    leaves, treedef = jax.tree.flatten(params)
+    asked = jax.tree.leaves(
+        decode_weight_formats(params, config, slots, max_len, steps))
+    moves = [f.layout is not None and f.layout != x.format.layout
+             for x, f in zip(leaves, asked)]
+    nbytes = sum(x.nbytes for x, move in zip(leaves, moves) if move)
+    with jax.profiler.TraceAnnotation(
+            "raytpu.engine.layout", weights_relaid=sum(moves),
+            weights_relaid_bytes=nbytes):
+        # a move waits, so that the next leaf's copy is allocated after
+        # this leaf's donated buffer is free again
+        placed = [
+            jax.block_until_ready(jax.device_put(x, f, donate=True))
+            if move else jax.device_put(x, x.sharding)
+            for x, f, move in zip(leaves, asked, moves)]
+    return treedef.unflatten(placed), sum(moves), nbytes
+
+
 def init_kv_cache(config: TransformerConfig, batch: int,
                   max_len: int) -> Dict[str, jax.Array]:
     """The cache is a pytree that the mixer defines; every leaf is

@@ -13,6 +13,17 @@ returns the dequantized array. Every weight use in the model/generation
 code is already ``w.astype(cfg.dtype)``, so quantized checkpoints are
 drop-in — no forward-path changes, and ``lax.scan`` over stacked layer
 weights slices the (q, s) leaves together.
+
+Where a quantised weight's PHYSICAL layout is decided: not here. This
+module fixes names, logical shapes ([L, d, h, k], ...) and dtypes, which
+every reader of a parameter tree relies on. The order of a leaf's bytes
+in device memory belongs to whoever runs the weight: a training step or
+``generate()`` takes the chip's default, and the serving engine places
+each leaf once, at set-up, in the layout its compiled ``decode_block``
+reads (``generation.lay_out_for_decode``; on a v5e the int8 ``wq`` /
+``wk`` / ``wv`` then lie with the head dimension outside the contracted
+one). A ``QTensor`` keeps its structure through that: ``q`` may move,
+``s`` stays.
 """
 
 from __future__ import annotations

@@ -47,6 +47,7 @@ _COUNTERS = (
     "requests_finished", "requests_cancelled", "requests_failed",
     "prefill_tokens", "prefill_padded_tokens", "tokens_emitted",
     "slot_steps", "capacity_steps", "attn_rows_read", "attn_rows_capacity",
+    "weights_relaid", "weights_relaid_bytes",
 )
 _PHASES = (
     "admit_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
@@ -103,6 +104,12 @@ class LLMEngine:
     ``prefill_buckets``: prompt pad lengths (one compile each).
     ``eos_id``: generation stops early when the model emits it (None =
     always run to max_new_tokens).
+
+    The engine takes the weights over: at set-up each leaf is committed to
+    its device in the physical layout the compiled ``decode_block`` reads
+    it in (``generation.lay_out_for_decode``), and a leaf that had to move
+    is donated, so the caller's array of it is deleted. ``self.params``
+    keeps every name, logical shape and dtype.
     """
 
     def __init__(self, params, config, *, max_slots: int = 8,
@@ -116,6 +123,7 @@ class LLMEngine:
         from ray_tpu.models.generation import (
             block_stat_keys,
             init_kv_cache,
+            lay_out_for_decode,
             prepare_for_inference,
         )
 
@@ -128,7 +136,6 @@ class LLMEngine:
         # span per phase per iteration.
         self._span = jax.profiler.TraceAnnotation
         params, config = prepare_for_inference(params, config)
-        self.params = params
         self.config = config
         self.max_slots = max_slots
         self.max_len = max_len
@@ -151,12 +158,26 @@ class LLMEngine:
         # pipeline depth 1: dispatch block k+1 before fetching block k's
         # tokens, so the device never waits on the host link
         self.pipeline = pipeline
-        self.cache = init_kv_cache(config, max_slots, max_len)
-        self.tok = jnp.zeros(max_slots, jnp.int32)  # next token per slot
-        self.pos = jnp.zeros(max_slots, jnp.int32)  # its absolute position
-        self.temps = jnp.zeros(max_slots, jnp.float32)
-        self.seeds = jnp.zeros(max_slots, jnp.int32)
-        self.counts = jnp.zeros(max_slots, jnp.int32)  # sample counter
+        # The engine owns where each weight lies and in which PHYSICAL
+        # layout: decided once, here, by the compiled decode_block (the
+        # short block: its copies would come round most often), before the
+        # cache is allocated, so that set-up's peak (the weights + one
+        # leaf) stays under serving's.
+        self.params, relaid, relaid_bytes = lay_out_for_decode(
+            params, config, max_slots, max_len, self.burst_block_steps)
+        # Its own state is committed to the weights' device like them: an
+        # output of a program with a committed argument is committed, a
+        # fresh jnp.zeros is not, and jit compiles once for each. Committed
+        # from the start, the programs warmed below are the ones that
+        # serve, whatever the arrays' history.
+        self._home = jax.tree.leaves(self.params)[0].sharding
+        self.cache = jax.device_put(
+            init_kv_cache(config, max_slots, max_len), self._home)
+        self.tok = self._lanes(jnp.int32)  # next token per slot
+        self.pos = self._lanes(jnp.int32)  # its absolute position
+        self.temps = self._lanes(jnp.float32)
+        self.seeds = self._lanes(jnp.int32)
+        self.counts = self._lanes(jnp.int32)  # sample counter
         # host-side slot table
         self.slot_req: List[Optional[_Request]] = [None] * max_slots
         # What ``self.pos`` holds on the device, kept in step on the host
@@ -179,6 +200,8 @@ class LLMEngine:
         # with each block's tokens and are summed under their names.
         self._n: Dict[str, int] = dict.fromkeys(
             _COUNTERS + block_stat_keys(config), 0)
+        self._n["weights_relaid"] = relaid
+        self._n["weights_relaid_bytes"] = relaid_bytes
         self._last_block_stats: Dict[str, int] = {}
         self._t: Dict[str, float] = dict.fromkeys(_PHASES, 0.0)
         self._blocks_by_steps: Dict[int, int] = dict.fromkeys(
@@ -198,6 +221,11 @@ class LLMEngine:
                                         name="llm-engine")
         self._thread.start()
 
+    def _lanes(self, dtype):
+        """Zeros, one a slot, committed where the weights are."""
+        return self._jax.device_put(
+            self._jnp.zeros(self.max_slots, dtype), self._home)
+
     def _warm_blocks(self):
         from ray_tpu.models.generation import decode_block
 
@@ -207,9 +235,9 @@ class LLMEngine:
                 self.params, self.cache, self.tok, self.pos, self.temps,
                 self.seeds, self.counts, self.config, steps,
             )
-        self.tok = jnp.zeros(self.max_slots, jnp.int32)
-        self.pos = jnp.zeros(self.max_slots, jnp.int32)
-        self.counts = jnp.zeros(self.max_slots, jnp.int32)
+        self.tok = self._lanes(jnp.int32)
+        self.pos = self._lanes(jnp.int32)
+        self.counts = self._lanes(jnp.int32)
 
     # -- public --
 
@@ -299,6 +327,11 @@ class LLMEngine:
           (``max_len`` x ``max_slots`` x steps), what walking the whole
           cache would have read (rows of whatever the mixer caches: K and
           V rows, or latent rows).
+        - Set once, at set-up: ``weights_relaid`` weights moved into the
+          physical layout the compiled ``decode_block`` reads them in
+          (``generation.lay_out_for_decode``; 0 where the compiler asks
+          for the layouts they came in, as on the CPU), and
+          ``weights_relaid_bytes``, their size.
         - Whatever counters the model's ``decode_block`` returns with its
           tokens (``generation.block_stat_keys``), summed over retired
           blocks. For dropless routed experts, over steps and expert
@@ -396,8 +429,11 @@ class LLMEngine:
                     lg[None], t[None], s[None], jnp.zeros(1, jnp.int32)
                 )[0]
             )
+        # committed like all the engine holds, whoever made the logits:
+        # one executable, and a first token that is committed too
         return self._first_fn(
-            logits, jnp.float32(temperature), jnp.int32(seed)
+            self._jax.device_put(logits, self._home),
+            jnp.float32(temperature), jnp.int32(seed)
         )
 
     def _emit(self, req: Optional[_Request], token: int) -> bool:

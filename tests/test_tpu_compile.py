@@ -100,3 +100,104 @@ def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program):
     hlo = compiled.as_text()
     assert hlo.count("ragged-dot") >= 3  # the experts' grouped products
     assert "raytpu.moe.experts" in hlo and "raytpu.mla.attend" in hlo
+
+
+@pytest.fixture(scope="module")
+def gptj_served(v5e):
+    """GPT-J-6B int8 at the benchmark's engine sizes (8 slots x 1,024
+    rows), as shapes on the described chip: the weights in the layouts
+    the chip hands out, and in the layouts the engine leaves them in
+    (``generation.lay_out_for_decode``: what the compiled 2-step
+    ``decode_block`` asks for)."""
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.quant import quantize_params_int8
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    trained = TransformerConfig.gptj_6b()
+    _, cfg = gen.prepare_for_inference({}, trained)
+    shapes = jax.eval_shape(
+        lambda: gen.prepare_for_inference(quantize_params_int8(
+            init_params(trained, jax.random.key(0))), trained)[0])
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    as_made = described(shapes)
+    asked = {steps: gen.decode_weight_formats(as_made, cfg, 8, 1024, steps)
+             for steps in (2, 8)}
+    as_served = jax.tree.map(
+        lambda a, f: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=f),
+        as_made, asked[2])
+    cache = described(
+        jax.eval_shape(lambda: gen.init_kv_cache(cfg, 8, 1024)))
+    return cfg, as_made, as_served, asked, cache
+
+
+def _copies(hlo: str, of: str):
+    """``copy`` instructions of the compiled text whose result's type and
+    leading dimensions are ``of`` (``"s8["``, ``"s8[28,"``)."""
+    import re
+
+    return re.findall(r"= " + re.escape(of) + r"[\d,]*\]\S* copy\(", hlo)
+
+
+def _lower(program, params, cache, cfg, v5e):
+    from ray_tpu.models import generation as gen
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    kind, size = program.split("_")
+    if kind == "decode":
+        return gen.decode_block.lower(
+            params, cache, arr((8,)), arr((8,)), arr((8,), jnp.float32),
+            arr((8,)), arr((8,)), cfg, int(size))
+    return gen.prefill_into_slot.lower(
+        params, arr((1, int(size))), arr(()), arr(()), cache, cfg)
+
+
+def test_gptj_decode_asks_for_three_weights_in_another_layout(
+        gptj_served, v5e):
+    """Both block lengths ask for the same layouts, and they differ from
+    what the chip hands out for exactly the three stacked int8 q/k/v
+    projections (the head dimension outside the contracted one): 3 leaves,
+    1.41e9 bytes, what ``stats()["weights_relaid"]`` reads on the chip."""
+    cfg, as_made, _served, asked, cache = gptj_served
+    assert jax.tree.leaves(asked[2]) == jax.tree.leaves(asked[8])
+    handed = _lower("decode_2", as_made, cache, cfg, v5e).compile(
+    ).input_formats[0][0]
+    moved = [(tuple(k.key for k in path[:3]), a.shape, a.dtype,
+              f.layout.major_to_minor)
+             for (path, a), f, h in zip(
+                 jax.tree_util.tree_flatten_with_path(as_made)[0],
+                 jax.tree.leaves(asked[2]), jax.tree.leaves(handed))
+             if f.layout != h.layout]
+    assert sorted(moved) == [
+        (("layers", "attn", w), (28, 4096, 16, 256), jnp.int8, (0, 2, 1, 3))
+        for w in ("wk", "wq", "wv")]
+
+
+@pytest.mark.parametrize(
+    "program", ["decode_2", "decode_8", "prefill_128", "prefill_1024"])
+def test_gptj_serving_programs_copy_no_stacked_weight(
+        gptj_served, v5e, program):
+    """Lowered the way the engine calls them since ISSUE 29 (the weights
+    carry the layouts ``decode_block`` asked for), ``decode_block`` holds
+    no ``copy`` of a stacked ``[28, ...]`` int8 weight and under 0.1 GiB
+    of temporaries (with the layouts the chip hands out: three copies of
+    470 MB a block, 1.32 GiB), and ``prefill_into_slot`` no int8 copy at
+    bucket 128 (before: 3 a layer through HBM) and at most the 2 into
+    fast memory at 1,024 (before: 3)."""
+    cfg, as_made, as_served, _asked, cache = gptj_served
+    now = _lower(program, as_served, cache, cfg, v5e).compile()
+    hlo = now.as_text()
+    if program.startswith("decode"):
+        assert not _copies(hlo, "s8[28,")
+        assert now.memory_analysis().temp_size_in_bytes < 0.1 * 2 ** 30
+        assert now.memory_analysis().alias_size_in_bytes >= (
+            2 * 28 * 8 * 1024 * 16 * 256 * 2)  # the cache, in place
+    else:
+        assert len(_copies(hlo, "s8[")) <= {"128": 0, "1024": 2}[
+            program.split("_")[1]]
+    before = _lower(program, as_made, cache, cfg, v5e).compile().as_text()
+    assert len(_copies(before, "s8[")) == 3  # what the layouts took away
