@@ -107,49 +107,6 @@ def run_rl_bench():
         algo.stop()
 
 
-def _prior_bench_files():
-    import glob
-    import os
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    out = []
-    for path in sorted(glob.glob(os.path.join(here, "BENCH_r*.json"))):
-        try:
-            with open(path) as f:
-                out.append(json.load(f))
-        except Exception:
-            continue
-    return out
-
-
-def ratchet_floors(static_floors):
-    """max(static floor, 0.98 x best prior BENCH value) per micro metric."""
-    best = {}
-    for bench in _prior_bench_files():
-        micro = (bench.get("detail") or {}).get("micro") or {}
-        for key in static_floors:
-            val = micro.get(key)
-            if isinstance(val, (int, float)):
-                best[key] = max(best.get(key, 0.0), float(val))
-    return {
-        k: max(f, 0.98 * best.get(k, 0.0))
-        for k, f in static_floors.items()
-    }
-
-
-def best_prior_mfu() -> float:
-    best = 0.0
-    for bench in _prior_bench_files():
-        if bench.get("metric", "").startswith("train_step_mfu") and (
-            "cpu" not in bench.get("metric", "")
-        ):
-            try:
-                best = max(best, float(bench.get("value", 0.0)))
-            except (TypeError, ValueError):
-                pass
-    return best
-
-
 def main():
     import jax
     import jax.numpy as jnp
@@ -201,45 +158,6 @@ def main():
             12 * cfg.n_layers * cfg.n_heads * cfg.d_head * batch * seq * seq // 2
         )
         return dt, flops / dt / peak, tokens_per_step / dt
-
-    def measure_inference(cfg, batch, prompt_len, new_tokens):
-        """Serving shape (BASELINE: batched inference TTFT): prefill latency
-        + steady-state decode throughput via the KV cache."""
-        from ray_tpu.models.generation import (
-            decode_loop,
-            prefill,
-            prepare_for_inference,
-        )
-        from ray_tpu.models.transformer import init_params
-
-        params = jax.jit(
-            lambda k: init_params(cfg, k),
-        )(jax.random.key(0))
-        params, cfg = prepare_for_inference(params, cfg)
-        prompt = jax.random.randint(
-            jax.random.key(1), (batch, prompt_len), 0, cfg.vocab_size
-        ).astype(jnp.int32)
-        max_len = prompt_len + new_tokens + 1
-        logits, cache = prefill(params, prompt, cfg, max_len)  # compile
-        jax.block_until_ready(logits)
-        t0 = time.perf_counter()
-        logits, cache = prefill(params, prompt, cfg, max_len)
-        jax.block_until_ready(logits)
-        ttft_ms = (time.perf_counter() - t0) * 1e3
-        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        args = (params, first, cache, jnp.array(prompt_len, jnp.int32),
-                cfg, new_tokens, 0.0, jax.random.key(2))
-        jax.block_until_ready(decode_loop(*args))  # compile
-        t0 = time.perf_counter()
-        out = decode_loop(*args)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        return {
-            "batch": batch,
-            "prompt_len": prompt_len,
-            "ttft_ms": round(ttft_ms, 2),
-            "decode_tokens_per_s": round(batch * new_tokens / dt, 1),
-        }
 
     def measure_continuous_serving():
         """Serving bench at the BASELINE north-star scale (Llama-2-7B
@@ -340,8 +258,7 @@ def main():
             eng.shutdown()
 
     cfg = TransformerConfig.bench_400m()
-    # best-of-2: host-clock noise is about ±1% run to run, which matters
-    # against a 0.98x ratchet floor
+    # best-of-2: host-clock noise is about ±1% run to run
     dt, mfu, tps = measure(cfg, batch=8, seq=2048, iters=10)
     dt2, mfu2, tps2 = measure(cfg, batch=8, seq=2048, iters=10)
     if mfu2 > mfu:
@@ -355,10 +272,6 @@ def main():
         "step_ms": round(lc_dt * 1e3, 2),
         "tokens_per_s": round(lc_tps, 1),
     }
-    inference = measure_inference(
-        dataclasses.replace(cfg, attn_impl="dense", remat=False),
-        batch=8, prompt_len=1024, new_tokens=64,
-    )
     serving = measure_continuous_serving()
     # release the serving section's device footprint (7B int8 weights
     # + KV caches) before the micro/RL sections — leftover HBM and
@@ -503,59 +416,52 @@ def main():
 
     # ---- perf floor gate (reference ray_perf.py role: a GATE, not a
     # printout — regressions fail the bench run) ----
-    # RATCHET (VERDICT r3 item 10): the effective floor per metric is
-    # max(static floor, 0.98 x best value in any checked-in BENCH_r*.json)
-    # so a 3% regression vs best-ever fails the run instead of slipping
-    # silently. Static floors remain the order-of-magnitude backstop.
+    # Static floors: an order-of-magnitude backstop per micro metric (no
+    # record of earlier runs is kept to compare with).
     STATIC_FLOORS = {
-        # r8 ratchet: the native task hot path (inlined small returns +
+        # r8: the native task hot path (inlined small returns +
         # conduit-core batched dispatch) measures ~8-9.5k tasks/s and
         # ~10-15k pipelined actor calls/s on the 24-core dev box
         # (pre-r8: ~6k/7.5k). The static floors sit at roughly half the
         # measured envelope — an order-of-magnitude backstop that must
-        # also pass on slower shared CI boxes; catching same-box
-        # regressions (including a full slide back to pre-r8 cost) is
-        # the 0.98x BENCH_r*.json ratchet's job once a post-r8 BENCH
-        # lands.
+        # also pass on slower shared CI boxes.
         "tasks_per_s": 4000.0,
         "actor_calls_pipelined_per_s": 5000.0,
         # r11 sync-RTT recovery (reaper-thread completion + caller-
         # thread direct submit): dev box ~1000 calls/s (was ~800 at r8-
-        # r10); static floor at well under half for slow CI boxes — the
-        # 0.98x ratchet gates the same-box RTT regression story, and
+        # r10); static floor at well under half for slow CI boxes;
         # actor_call_sync_rtt_us is recorded beside it in micro detail
         "actor_calls_per_s": 300.0,
         # control plane (r11): RPC-plane mutations/s against the file-
         # backed group-commit GCS (dev box ~3000; floor at roughly a
-        # quarter — shared CI IO is noisy; ratchet owns regressions)
+        # quarter — shared CI IO is noisy)
         "gcs_mutations_per_s": 800.0,
         "put_gbps": 0.4,
         # raylet-to-raylet 256 MiB pull, same-host shm fast path
-        # (conservative backstop: the shared CI box is slow; the 0.98x
-        # ratchet owns regressions). The socket-plane bar
-        # (transfer_socket_gbps) is recorded but not ratcheted — its
-        # run-to-run variance on a timeshared box would flake the gate.
+        # (conservative backstop: the shared CI box is slow). The
+        # socket-plane bar (transfer_socket_gbps) is recorded but not
+        # gated — its run-to-run variance on a timeshared box would
+        # flake the gate.
         "transfer_gbps": 0.3,
         # serving plane (r9): streamed tokens/s/replica under open-loop
         # traffic against the autoscaled deployment (dev box ~85-90;
-        # floor at roughly half, ratchet owns same-box regressions)
+        # floor at roughly half)
         "serving_tokens_per_s_per_replica": 40.0,
         # compute plane (r10): gang-coherent lockstep steps/s on the
         # 2-host CPU MeshGroup (dev box ~290; backstop at an order of
-        # magnitude under, the 0.98x ratchet owns same-box regressions)
+        # magnitude under)
         "mesh_group_steps_per_s": 30.0,
         # data plane (r12): sustained streaming ingest into the running
         # 2-host gang (placement-routed production + per-rank prefetch
         # over the pull plane, sync ~95ms steps). Dev box ~80-90k
         # rows/s / ~80-90 MB/s; backstop well under for shared CI
-        # boxes — the 0.98x BENCH ratchet owns same-box regressions.
+        # boxes.
         "data_plane_rows_per_s": 15000.0,
         "data_plane_bytes_per_s": 15e6,
     }
-    floors = ratchet_floors(STATIC_FLOORS)
     violations = []
     if isinstance(micro, dict) and "error" not in micro:
-        for key, floor in floors.items():
+        for key, floor in STATIC_FLOORS.items():
             val = micro.get(key)
             if val is not None and val < floor:
                 violations.append(
@@ -569,8 +475,7 @@ def main():
             })
         # serving-plane contract (r9): the deployment must actually have
         # scaled out on SLO burn, post-scale p95 TTFT must sit inside a
-        # generous static ceiling (ratcheting a latency DOWN rides the
-        # tokens/s floor instead), and backpressure rejections must stay
+        # generous static ceiling, and backpressure rejections must stay
         # bounded — observable, not unbounded queueing OR mass rejection.
         sv = micro.get("serving_scale") or {}
         if "error" not in sv and sv:
@@ -635,9 +540,8 @@ def main():
                     "value": gf.get("old_primary_fenced"),
                     "floor": "== 1",
                 })
-        # sync actor RTT: recorded AND statically bounded (the real
-        # gate is the actor_calls_per_s ratchet; this ceiling catches
-        # an order-of-magnitude latency slide on any box)
+        # sync actor RTT: recorded AND statically bounded (this ceiling
+        # catches an order-of-magnitude latency slide on any box)
         if (micro.get("actor_call_sync_rtt_us") or 0.0) > 10_000.0:
             violations.append({
                 "metric": "actor_call_sync_rtt_us",
@@ -648,7 +552,7 @@ def main():
         if "error" not in mgb and mgb:
             # gang spin-up is a latency contract (recover() pays it per
             # re-place): generous static ceiling, steps/s rides the
-            # ratcheted floor above
+            # floor above
             if (mgb.get("spinup_s") or 1e9) > 60.0:
                 violations.append({
                     "metric": "mesh_group_spinup_s",
@@ -706,7 +610,7 @@ def main():
                     "metric": "weight_fanout_egress_ratio",
                     "value": wf.get("egress_ratio"), "floor": "<= 2.5",
                 })
-    mfu_floor = max(0.40, 0.98 * best_prior_mfu())
+    mfu_floor = 0.40
     if mfu < mfu_floor:
         violations.append(
             {"metric": metric, "value": mfu,
@@ -716,8 +620,7 @@ def main():
     # ---- raylint gate: the static invariants (tools/raylint, DESIGN.md
     # "Enforced invariants") are part of the bench contract — a new
     # finding fails the run exactly like a perf-floor violation, and
-    # the count lands in the JSON detail so regressions show in the
-    # BENCH_r*.json trajectory.
+    # the count lands in the JSON detail.
     try:
         from tools.raylint import lint_paths
 
@@ -780,7 +683,6 @@ def main():
             "tokens_per_s": round(tps, 1),
             "attn_impl": cfg.attn_impl,
             "long_ctx": long_ctx,
-            "inference": inference,
             "serving": serving,
             "micro": micro,
             "raylint_findings": raylint_findings,

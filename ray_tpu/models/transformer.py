@@ -33,7 +33,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import causal_attention
 
@@ -61,10 +60,9 @@ class TransformerConfig:
     moe_aux_weight: float = 0.01
     remat: bool = False
     # What the checkpointed layer saves: "dots" keeps matmul outputs (cheap
-    # elementwise recompute only, ~0 extra FLOPs), "dots_attn" additionally
-    # saves the attention-kernel output (measured slower on v5e — see
-    # remat_wrap), "full" saves nothing (classic full-layer remat, ~+33%
-    # recompute — only for memory-bound configs).
+    # elementwise recompute only, ~0 extra FLOPs), "full" saves nothing
+    # (classic full-layer remat, ~+33% recompute — only for memory-bound
+    # configs).
     remat_policy: str = "dots"
     tie_embeddings: bool = False
     # ---- the block's description; the defaults are GPT-J's block ----
@@ -522,8 +520,6 @@ def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     extra = None
     if isinstance(attn_out, tuple):
         attn_out, extra = attn_out
-    # Named for remat policies ("dots_attn" saves it).
-    attn_out = checkpoint_name(attn_out, "attn_out")
     return jnp.einsum("bshk,hkd->bsd", attn_out,
                       wp["wo"].astype(c.dtype)), extra
 
@@ -568,7 +564,6 @@ def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     extra = None
     if isinstance(attn_out, tuple):
         attn_out, extra = attn_out
-    attn_out = checkpoint_name(attn_out, "attn_out")
     with jax.named_scope("raytpu.mla.project"):
         a = jnp.einsum("bshk,hkd->bsd", attn_out, wp["wo"].astype(c.dtype))
     return a, extra
@@ -654,16 +649,6 @@ def remat_wrap(layer_fn, config: TransformerConfig):
         policy = None  # save nothing: classic full-layer remat
     elif config.remat_policy == "dots":
         policy = cp.dots_with_no_batch_dims_saveable
-    elif config.remat_policy == "dots_attn":
-        # also saves the (non-dot) attention-kernel output. Measured SLOWER
-        # than "dots" on v5e: the flash custom-vjp needs the lse residual
-        # either way, so the fwd kernel re-runs regardless and the saved
-        # activations just add HBM traffic. Kept as a knob for configs
-        # where the trade differs.
-        policy = cp.save_from_both_policies(
-            cp.dots_with_no_batch_dims_saveable,
-            cp.save_only_these_names("attn_out"),
-        )
     else:
         raise ValueError(f"unknown remat_policy {config.remat_policy!r}")
     return jax.checkpoint(layer_fn, policy=policy)

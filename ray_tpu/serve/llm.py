@@ -116,7 +116,7 @@ class LLMEngine:
                  max_len: int = 1024,
                  prefill_buckets: tuple = (64, 128, 256, 512, 1024),
                  eos_id: Optional[int] = None, block_steps: int = 8,
-                 burst_block_steps: int = 2, pipeline: bool = True):
+                 burst_block_steps: int = 2):
         import jax
         import jax.numpy as jnp
 
@@ -155,9 +155,6 @@ class LLMEngine:
         self.burst_block_steps = min(
             self.block_steps, max(1, int(burst_block_steps))
         )
-        # pipeline depth 1: dispatch block k+1 before fetching block k's
-        # tokens, so the device never waits on the host link
-        self.pipeline = pipeline
         # The engine owns where each weight lies and in which PHYSICAL
         # layout: decided once, here, by the compiled decode_block (the
         # short block: its copies would come round most often), before the
@@ -562,8 +559,10 @@ class LLMEngine:
             self._t["block_emit_s"] += time.perf_counter() - t1
 
     def _loop(self):
+        # One block stays in flight while slots are live: block k+1 is
+        # dispatched before block k's tokens are fetched, so the device
+        # never waits on the host link.
         inflight: "collections.deque" = collections.deque()
-        depth = 1 if self.pipeline else 0
         clock, spent = time.perf_counter, self._t
         try:
             while not self._stop:
@@ -577,7 +576,7 @@ class LLMEngine:
                     inflight.append(self._dispatch_block())
                     spent["dispatch_s"] += clock() - t1
                     self._retire_firsts()  # sync waits on prefills only
-                while len(inflight) > (depth if active else 0):
+                while len(inflight) > (1 if active else 0):
                     self._retire_block(*inflight.popleft())
                 if not active and not self.pending and not inflight:
                     t2 = clock()

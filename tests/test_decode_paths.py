@@ -1,70 +1,16 @@
-"""Deferred-write decode path == carry decode path, bit for bit.
+"""The decode attention's chunked walk == the plain masked form.
 
-The deferred structure (attend prefix-plus-self, one batched scatter
-after the layer scan — candidate fix for the scatter-bound 7B decode)
-must be numerically identical to the r4-proven carry structure at every
-step, for GQA and per-slot positions. Selection is
-RAYTPU_DECODE_DEFERRED_WRITES; this test calls both internals directly.
+``_attend_prefix_plus_self`` reads the cache in row chunks up to the
+longest live sequence with an online softmax; it must give what scoring
+every row under a mask gives, for GQA, per-slot positions, parked lanes
+and a cache length the chunk does not divide.
 """
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.generation import (
-    _decode_forward_multi_carry,
-    _decode_forward_multi_deferred,
-    init_kv_cache,
-    prefill_into_slot,
-)
-from ray_tpu.models.transformer import TransformerConfig, init_params
-
-
-@pytest.mark.parametrize("kv_heads", [None, 2])  # MHA and GQA
-def test_deferred_equals_carry_multi_step(kv_heads):
-    cfg = dataclasses.replace(
-        TransformerConfig.tiny(max_seq_len=64),
-        n_kv_heads=kv_heads,
-    )
-    params = init_params(cfg, jax.random.key(0))
-    params = jax.tree.map(lambda x: x.astype(cfg.dtype), params)
-    B = 4
-    cache = init_kv_cache(cfg, B, 64)
-    # stagger slots at different positions via per-slot prefill
-    rng = np.random.RandomState(0)
-    pos = []
-    for slot, n in enumerate([3, 7, 1, 5]):
-        prompt = jnp.asarray(rng.randint(0, 255, (1, 8)), jnp.int32)
-        _, cache = prefill_into_slot(
-            params, prompt, jnp.int32(n), jnp.int32(slot), cache, cfg
-        )
-        pos.append(n)
-    pos = jnp.asarray(pos, jnp.int32)
-    tok = jnp.asarray(rng.randint(0, 255, B), jnp.int32)
-
-    cache_a = jax.tree.map(jnp.copy, cache)
-    cache_b = jax.tree.map(jnp.copy, cache)
-    pos_a = pos_b = pos
-    tok_a = tok_b = tok
-    for _step in range(5):
-        la, cache_a, _ = _decode_forward_multi_carry(
-            params, tok_a, cache_a, pos_a, cfg
-        )
-        lb, cache_b, _ = _decode_forward_multi_deferred(
-            params, tok_b, cache_b, pos_b, cfg
-        )
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-        np.testing.assert_array_equal(
-            np.asarray(cache_a["k"]), np.asarray(cache_b["k"])
-        )
-        np.testing.assert_array_equal(
-            np.asarray(cache_a["v"]), np.asarray(cache_b["v"])
-        )
-        tok_a = tok_b = jnp.argmax(la, axis=-1).astype(jnp.int32)
-        pos_a = pos_b = pos_a + 1
 
 def _masked_full_row_attention(q, ck, cv, k_new, v_new, pos):
     """The plain form of ``_attend_prefix_plus_self``: every row of the

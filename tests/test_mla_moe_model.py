@@ -156,6 +156,22 @@ def test_engine_serves_the_reference_tokens(params):
         eng.shutdown()
 
 
+def test_generate_gives_the_reference_tokens(params):
+    """``generate()`` runs the engine's two programs, so it generates this
+    block too (latent cache, a leading dense layer, dropless experts):
+    two rows of one length, 270 prompt tokens of a 320-row cache (the
+    decode attention walks a second chunk)."""
+    prompts = np.stack([prompt(30, 270), prompt(31, 270)])
+    out = np.asarray(gen.generate(
+        params, jnp.asarray(prompts), CFG, max_new_tokens=12, max_len=320))
+    assert out.shape == (2, 12)
+    for p, ids in zip(prompts, out):
+        logits = ref_logits(
+            params, list(p) + list(ids[:-1]))[len(p) - 1:]
+        margin = ref.served_token_margin(logits, jnp.asarray(ids, jnp.int32))
+        assert float(margin.max()) < TOL
+
+
 # -- (b) absorbed decode attention against the plain form ------------------
 
 def test_absorbed_decode_attention_matches_plain_form():

@@ -230,16 +230,27 @@ def test_flash_transformer_forward_matches_dense():
     )
 
 
-def test_kv_cache_generation_matches_full_forward():
-    """Greedy decode through the KV cache must match recomputing the full
-    forward pass every step (exact: same arithmetic, fp32)."""
+@pytest.mark.parametrize("max_len", [64, 300])
+@pytest.mark.parametrize("n_kv_heads", [None, 2])  # MHA and GQA
+def test_kv_cache_generation_matches_full_forward(n_kv_heads, max_len):
+    """Greedy decode through the KV cache (``generate``: the engine's two
+    programs) must give the tokens that recomputing the full forward pass
+    every step gives, in float32: the chunked online softmax is not the
+    dense softmax bit for bit, so the tokens are pinned, not the logits.
+    The prompt fills the cache but for 30 rows: of 300 rows the decode
+    attention walks two chunks, and the second starts early because the
+    chunk does not divide the cache."""
+    from ray_tpu.models.generation import DECODE_ATTN_CHUNK, generate
+
+    assert max_len < DECODE_ATTN_CHUNK or (
+        max_len - 30 > DECODE_ATTN_CHUNK and max_len % DECODE_ATTN_CHUNK)
     cfg = dataclasses.replace(
-        TransformerConfig.tiny(max_seq_len=64), dtype=jnp.float32
+        TransformerConfig.tiny(max_seq_len=max_len), dtype=jnp.float32,
+        n_kv_heads=n_kv_heads,
     )
     params = init_params(cfg, jax.random.key(0))
-    prompt = jax.random.randint(jax.random.key(1), (2, 8), 0, cfg.vocab_size)
-
-    from ray_tpu.models.generation import generate
+    prompt = jax.random.randint(
+        jax.random.key(1), (2, max_len - 30), 0, cfg.vocab_size)
 
     out = generate(params, prompt, cfg, max_new_tokens=6)
 
@@ -270,3 +281,32 @@ def test_generation_sampling_and_bounds():
     assert ((out >= 0) & (out < cfg.vocab_size)).all()
     with pytest.raises(ValueError, match="exceeds max_len"):
         generate(params, prompt, cfg, max_new_tokens=64)
+
+
+def test_sampled_generation_is_deterministic_per_rng():
+    """``temperature > 0``: the same ``rng`` gives the same tokens, another
+    ``rng`` another stream, and ``max_new_tokens=1`` returns the first
+    token of the longer run alone."""
+    from ray_tpu.models.generation import generate
+
+    cfg = dataclasses.replace(
+        TransformerConfig.tiny(max_seq_len=32), dtype=jnp.float32
+    )
+    params = init_params(cfg, jax.random.key(0))
+    prompt = jax.random.randint(jax.random.key(1), (3, 4), 0, cfg.vocab_size)
+
+    def sample(seed, n):
+        return np.asarray(generate(
+            params, prompt, cfg, max_new_tokens=n, temperature=1.0,
+            rng=jax.random.key(seed)))
+
+    out = sample(7, 12)
+    assert out.shape == (3, 12)
+    np.testing.assert_array_equal(sample(7, 12), out)
+    assert (sample(8, 12) != out).any()
+    np.testing.assert_array_equal(sample(7, 1), out[:, :1])
+    # rows of one prompt under one rng still draw their own streams
+    same = jnp.tile(prompt[:1], (3, 1))
+    rows = np.asarray(generate(params, same, cfg, max_new_tokens=12,
+                               temperature=1.0, rng=jax.random.key(7)))
+    assert (rows[0] != rows[1]).any()

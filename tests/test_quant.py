@@ -66,11 +66,7 @@ def test_quantized_forward_close_to_bf16():
 
 
 def test_quantized_generation_decodes():
-    from ray_tpu.models.generation import (
-        decode_loop,
-        prefill,
-        prepare_for_inference,
-    )
+    from ray_tpu.models.generation import generate, prepare_for_inference
 
     cfg = TransformerConfig.tiny(n_layers=2)
     params = quantize_params_int8(init_params(cfg, jax.random.key(0)))
@@ -81,10 +77,7 @@ def test_quantized_generation_decodes():
     )
     prompt = jax.random.randint(jax.random.key(1), (2, 8), 0,
                                 cfg.vocab_size).astype(jnp.int32)
-    logits, cache = prefill(params, prompt, cfg, 32)
-    first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    out = decode_loop(params, first, cache, jnp.array(8, jnp.int32), cfg,
-                      8, 0.0, jax.random.key(2))
+    out = generate(params, prompt, cfg, max_new_tokens=8, max_len=32)
     assert np.asarray(out).shape == (2, 8)
 
 

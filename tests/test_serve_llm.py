@@ -39,8 +39,10 @@ def _tiny_model():
 
 
 def test_engine_matches_generate():
-    """Continuous-batching decode must reproduce the plain generate()
-    output for interleaved greedy requests."""
+    """The engine's slot bookkeeping (interleaved greedy requests over two
+    slots, padded prompts, short and long blocks) must reproduce
+    generate(): the straight-line use of the same two programs, one
+    request alone in a fresh cache."""
     import jax
 
     from ray_tpu.models.generation import generate, prepare_for_inference
