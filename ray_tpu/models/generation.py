@@ -369,7 +369,7 @@ def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     tokens for this model (none for a model without dropless experts)."""
     if config.moe_experts and config.moe_impl == "dropless":
         return ("moe_assignments", "moe_experts_touched",
-                "moe_experts_capacity", "moe_max_load")
+                "moe_experts_capacity", "moe_max_load", "moe_weight_visits")
     return ()
 
 

@@ -416,12 +416,15 @@ _EXPERT_WEIGHTS = ("wg", "wi", "wo")
 def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
     """``lax.scan`` of ``body(carry, lp, li) -> carry`` over one stack of
     layers, ``li`` counting from ``first``. A scan hands its body one
-    layer's slice of every stacked weight; a slice that feeds a compiler
-    kernel is COPIED out of the stack first, and for dropless routed
-    experts (``lax.ragged_dot``) that copy is the whole layer's experts,
-    read or not. So those weights stay out of the scanned tree: ``lp``
-    carries them whole, [layers, E, ...], with ``lp["moe"]["layer"]``
-    saying which layer's experts to use (``ops/moe.routed_ffn``)."""
+    layer's slice of every stacked weight, and a slice that feeds a kernel
+    (the compiler's own or a Pallas one: a custom call's operand is a
+    whole array) is COPIED out of the stack first: for dropless routed
+    experts that copy is the whole layer's experts, read or not. So those
+    weights stay out of the scanned tree: ``lp`` carries them whole,
+    [layers, E, ...], with ``lp["moe"]["layer"]`` saying which layer's
+    experts to use, and the grouped product's block index picks
+    (layer, expert) inside the kernel (``ops/moe.routed_ffn``,
+    ``ops/grouped_matmul``)."""
     n = jax.tree.leaves(stack)[0].shape[0]
     held = {}
     if lc.moe_experts and lc.moe_impl == "dropless":

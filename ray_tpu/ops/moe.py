@@ -13,8 +13,11 @@ PAPERS.md.
 
 ``routed_ffn`` below is the other kind of routed layer: dropless (no
 capacity, no dispatch tensor), for models whose mathematics has no dropped
-token. It runs on one chip's experts; sharding it over ``ep`` is open
-(ROADMAP).
+token. Its experts run as two calls of the Pallas grouped product in
+``ops/grouped_matmul.py`` (gate and up in one pass, then down), which
+streams the touched experts' weights out of the stacked array in pieces
+of megabytes. It runs on one chip's experts and has no backward pass;
+sharding it over ``ep`` and training it are open (ROADMAP R2).
 """
 
 from __future__ import annotations
@@ -24,6 +27,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.grouped_matmul import group_schedule, grouped_matmul
 
 
 def _top_k_mask(probs: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
@@ -131,25 +136,29 @@ def routed_ffn(
     ``top_k`` experts with the largest ``s + bias`` are chosen; a chosen
     expert's weight is its ``s`` over the chosen ones' sum, times
     ``route_scale``. Every chosen (token, expert) pair is computed: the
-    pairs are sorted by expert and the experts' FFNs run as three grouped
-    products (``lax.ragged_dot``) over the sorted rows, so an expert that
-    no token chose is not read, and there is no capacity and no
-    [tokens, E, C] tensor. ``wp["shared"]`` is an FFN every token takes.
+    pairs are sorted by expert and the experts' FFNs run as grouped
+    products (``grouped_matmul``: bf16 operands, float32 accumulation;
+    gate, up and the activation in one kernel, down in a second) over the
+    sorted rows, so an expert that no token chose is not read, and there
+    is no capacity and no [tokens, E, C] tensor. ``wp["shared"]`` is an
+    FFN every token takes.
 
     Tokens outside ``token_mask`` (a parked lane, a prompt's padding) are
     sent to no expert: their pairs sort behind the last group, and their
     routed output is zero.
 
     With ``wp["layer"]`` (an index) the experts' weights are those of a
-    whole stack of layers, [layers, E, ...], and the products run over
-    layers x E groups of which only this layer's have rows: the kernel
-    skips an empty group, and nothing slices (copies) a layer's experts
-    out of the stack (``transformer.scan_stack``).
+    whole stack of layers, [layers, E, ...]: the kernel's block index is
+    (layer, expert, ...), so it reads this layer's touched experts where
+    they lie and nothing slices (copies) a layer's experts out of the
+    stack (``transformer.scan_stack``).
 
     Returns (y like x, stats): int32 scalars ``moe_assignments`` (pairs
     computed), ``moe_experts_touched`` (experts with at least one),
     ``moe_experts_capacity`` (E), ``moe_max_load`` (the fullest expert's
-    pairs)."""
+    pairs), ``moe_weight_visits`` (the (expert, 128-row tile) pairs the
+    kernel's schedule visits: ``moe_experts_touched`` where every
+    expert's rows sit in one tile, as a decode step's 128 rows do)."""
     f32 = jnp.float32
     d, E = x.shape[-1], wp["router"].shape[-1]
     x2 = x.reshape(-1, d)
@@ -173,20 +182,17 @@ def routed_ffn(
             method="compare_all")
         sizes = jnp.diff(edges).astype(jnp.int32)  # pairs per expert
     with jax.named_scope("raytpu.moe.experts"):
-        groups = sizes
-        if "layer" in wp:
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros(wp["wi"].shape[0] * E, jnp.int32), sizes,
-                (wp["layer"] * E,))
+        schedule = group_schedule(sizes, n * top_k)
 
-        def experts(rows, w):
-            w = w.reshape((-1,) + w.shape[-2:]).astype(x.dtype)
-            return jax.lax.ragged_dot(rows, w, groups)
+        def experts(rows, *names, act=None):
+            stacks = [wp[k].astype(x.dtype).reshape((-1, E) + wp[k].shape[-2:])
+                      for k in names]  # [E, k, n]: a stack of one layer
+            return grouped_matmul(rows, stacks, schedule,
+                                  layer=wp.get("layer", 0), act=act)
 
         xs = x2[order // top_k]  # [n*k, D], sorted by expert
-        h = experts(xs, wp["wi"])
-        h = act(experts(xs, wp["wg"])) * h if gated else act(h)
-        ys = experts(h, wp["wo"])
+        h = experts(xs, *(("wg", "wi") if gated else ("wi",)), act=act)
+        ys = experts(h, "wo")
         # rows behind the last group hold nothing defined: select, then
         # back to token order and the weighted sum over a token's choices
         ys = jnp.where((jnp.arange(n * top_k) < edges[E])[:, None], ys, 0)
@@ -203,5 +209,6 @@ def routed_ffn(
         "moe_experts_touched": (sizes > 0).sum().astype(jnp.int32),
         "moe_experts_capacity": jnp.int32(E),
         "moe_max_load": sizes.max(),
+        "moe_weight_visits": schedule.visits,
     }
     return y.astype(x.dtype).reshape(x.shape), stats

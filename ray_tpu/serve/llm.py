@@ -336,7 +336,10 @@ class LLMEngine:
           unparked lanes x experts per token), ``moe_experts_touched``
           (experts that got at least one), ``moe_experts_capacity``
           (experts there are), ``moe_max_load`` (the fullest expert's
-          pairs).
+          pairs), ``moe_weight_visits`` (the (expert, row tile) pairs the
+          grouped product's schedule visits, ``ops/grouped_matmul``: over
+          ``moe_experts_touched`` it is 1.0 where every expert's rows sit
+          in one tile, as a decode step's do).
         """
         with self._lock:
             out = {
