@@ -14,11 +14,16 @@ TPU notes: cache layout [L, B, S_max, H_kv, D] keeps the per-layer slices
 contiguous for the scanned stack; GQA caches only kv_heads; latent
 attention caches one latent row a token a layer (``init_kv_cache``: the
 cache is a pytree that the mixer defines, and the engine never looks
-inside it). Both programs run every block the config can describe.
+inside it), and a block with an indexer the index key of each layer that
+owns one. Both programs run every block the config can describe.
 Decode is bound by HBM reads, and a masked cache row is read like a live
 one: the mask only discards what was already streamed. So the decode
 attention (``_attend_prefix_plus_self``) walks the cache in row chunks
-and stops at the longest live sequence.
+and stops at the longest live sequence. A block with an indexer reads
+less still: it scores the prefix's index keys, picks ``index_topk`` rows a
+lane, and attends those alone (``_decode_choice``,
+``_attend_latent_chosen``: a masked walk, see there); its prefill attends block by block under the
+mask of each query's chosen rows (``_prefill_choice``, ``_attend_masked``).
 """
 
 from __future__ import annotations
@@ -127,14 +132,41 @@ def lay_out_for_decode(params, config: TransformerConfig, slots: int,
     return treedef.unflatten(placed), sum(moves), nbytes
 
 
+def _ckr_width(c: TransformerConfig) -> int:
+    return -(-(c.kv_lora_rank + c.qk_rope_dim) // 128) * 128
+
+
+def _ckr_rows(c_kv, k_r, width: int):
+    """[c_kv | rot(k_r) | zeros] along the last axis, ``width`` wide."""
+    pad = width - c_kv.shape[-1] - k_r.shape[-1]
+    return jnp.concatenate(
+        [c_kv, k_r, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)], -1)
+
+
 def init_kv_cache(config: TransformerConfig, batch: int,
                   max_len: int) -> Dict[str, jax.Array]:
     """The cache is a pytree that the mixer defines; every leaf is
-    [L, B, S_max, ...]. MHA/GQA: ``k`` and ``v`` of [.., Hkv, D]. Latent
+    [layers that keep it, B, S_max, ...]: what a slot keeps is its rows
+    of every leaf. MHA/GQA: ``k`` and ``v`` of [L, .., Hkv, D]. Latent
     attention: the row ``[c_kv | rot(k_r)]`` a token a layer, as ``ckv`` of
-    [.., kv_lora_rank] (key and value at once) and ``kr`` of
-    [.., qk_rope_dim] (two arrays: see _attend_latent_prefix_plus_self)."""
+    [L, .., kv_lora_rank] (key and value at once) and ``kr`` of
+    [L, .., qk_rope_dim] (two arrays: see _attend_latent_prefix_plus_self).
+    A latent block with an indexer GATHERS its rows, and a gather wants
+    rows that lie whole: its slot keeps the same row as ONE array ``ckr``
+    of [L, .., kv_lora_rank + qk_rope_dim, padded with zeros to whole
+    lanes of 128] (576 -> 640; the chip lays a 64- or 576-wide array out
+    rows-minor, and gathering from that costs a copy of the cache a
+    layer), and ``ik`` of [layers that own an indexer, .., index_head_dim]:
+    the index key a token, the rows kept for CHOOSING what the layers
+    above attend (the choice itself lives one step, in the layer scan's
+    carry)."""
     c = config
+    if c.mixer == "mla" and c.index_topk:
+        rows = (batch, max_len)
+        return {"ckr": jnp.zeros((c.n_layers,) + rows + (_ckr_width(c),),
+                                 c.dtype),
+                "ik": jnp.zeros((c.n_index_layers,) + rows
+                                + (c.index_head_dim,), c.dtype)}
     if c.mixer == "mla":
         rows = (c.n_layers, batch, max_len)
         return {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
@@ -162,15 +194,23 @@ def attn_rows_walked(bound: int, s_max: int,
     """Rows of every slot's cache that one decode step reads when the
     largest ``pos`` among its lanes is ``bound``: whole chunks up to it,
     at most ``s_max``. Plain integers: the engine's host-side count of
-    what ``_attend_prefix_plus_self`` walks on the device."""
+    what ``_attend_prefix_plus_self`` walks on the device (``chunk``:
+    ``decode_attn_chunk`` of the model)."""
     chunk = min(chunk, s_max)
     return min(-(-min(bound, s_max) // chunk) * chunk, s_max)
 
 
-def _walk_rows(body, m, acc, pos, s_max: int, chunk: int):
+def decode_attn_chunk(config: TransformerConfig) -> int:
+    """Rows of cache one iteration of ``config``'s decode attention
+    reads."""
+    return DSA_CHUNK if config.index_topk else DECODE_ATTN_CHUNK
+
+
+def _walk_rows(body, m, acc, pos, s_max: int, chunk: int, l=None):
     """The schedule and the state both decode attentions share: an online
     softmax (running max ``m``, sum ``l`` starting at 1, accumulator
-    ``acc``: seeded by the token's own position) carried through ``state =
+    ``acc``: seeded by the token's own position; a walk in which the own
+    position is only a candidate passes its own ``l``) carried through ``state =
     body(start, attended, state)`` for each chunk of ``chunk`` cache rows
     up to the longest live sequence, ceil(max(pos) / chunk) iterations:
     the trip count is data, so one compiled program serves every length.
@@ -190,7 +230,8 @@ def _walk_rows(body, m, acc, pos, s_max: int, chunk: int):
 
     bound = jnp.minimum(jnp.max(pos), s_max)
     _, l, acc = lax.fori_loop(
-        0, (bound + chunk - 1) // chunk, walk, (m, jnp.ones_like(m), acc)
+        0, (bound + chunk - 1) // chunk, walk,
+        (m, jnp.ones_like(m) if l is None else l, acc)
     )
     return l, acc
 
@@ -306,6 +347,274 @@ def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
     return (acc / l[:, 0, :, None]).astype(q_lat.dtype)
 
 
+# ---------------- learned sparse attention over the latent cache ----------------
+# (a block with an indexer, ``TransformerConfig.index_topk``: each query
+# attends the ``index_topk`` cache rows its layer's indexer scores best;
+# transformer.index_project makes the index queries, keys and weights.)
+
+# Rows one iteration of the decode step reads, of index keys (scoring) and
+# of latent rows (the attention: 12 lanes x 2,048 rows x 640 numbers are
+# 31 MB; a 20,000-row lane is ten iterations), and the prompt's queries
+# one iteration of the prefill choice scores against the whole prompt
+# (128 queries x 32 heads x 24,576 rows of float32: 0.4 GB).
+DSA_CHUNK = 2048
+DSA_QUERY_BLOCK = 128
+# Queries and rows one tile of the prefill attention holds, and the heads
+# it expands keys and values for at a time (16 heads x 1,024 x 1,024
+# float32 scores are 64 MB; keys and values of 16 heads over 24,576 tokens
+# 0.2 GB each, where all 64 at once would not fit beside the weights; 1,024
+# ran 119 TFLOP/s at 24,576 tokens where 512 ran 105, my chip run, PR 32).
+PREFILL_ATTN_BLOCK = 1024
+PREFILL_HEAD_GROUP = 16
+
+
+def _index_scores(q, w, k):
+    """q [..., T, nI, dI] and w [..., T, nI] (float32) against the index
+    keys k [..., S, dI]: ``sum_j w_j relu(q_j . k_s)``, [..., T, S] in
+    float32 (operands in the compute dtype, float32 products)."""
+    s = jnp.einsum("...tjd,...sd->...tjs", q, k,
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * w[..., None]).sum(-2)
+
+
+def _block_of(n: int, most: int) -> int:
+    """The largest block of at most ``most`` that divides ``n`` when halved
+    down from ``most`` (``n`` itself where it is smaller)."""
+    blk = min(most, n)
+    while n % blk:
+        blk //= 2
+    return blk
+
+
+def select_rows(scores, valid, k: int):
+    """The mask [..., S] of the ``k`` largest ``scores`` (float32) among
+    the ``valid`` rows, ties to the lower row, every valid row where there
+    are at most ``k``: exactly ``lax.top_k``'s set, without its sort, and
+    a mask is what both attentions want (timed alone on a v5e, 12 lanes x
+    25,600 scores: 0.67 ms against ``lax.top_k``'s 0.95, both with ~0.6 ms
+    of dispatch in them; in the traced cell the choice is 0.5 % of a
+    decode step; my chip runs, PR 32). The k-th largest score is found
+    bit by bit (32
+    counts over the row: the largest threshold that at least ``k`` scores
+    reach), then the rows above it are taken and, of the rows that equal
+    it, the first few."""
+    u = lax.bitcast_convert_type(scores, jnp.uint32)
+    # order-preserving: a float's bits, sign flipped (negatives: all bits)
+    key = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+    key = jnp.where(valid, key, jnp.uint32(0))  # under every score
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = (key >= cand[..., None]).sum(-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+
+    t = lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = key > t[..., None]
+    level = (key == t[..., None]) & valid
+    room = k - above.sum(-1, dtype=jnp.int32)
+    return above | (level & (jnp.cumsum(level, -1, dtype=jnp.int32)
+                             <= room[..., None]))
+
+
+def _prefill_choice(q, k, w, topk: int):
+    """A prompt's choice: index queries q [S, nI, dI], keys k [S, dI],
+    weights w [S, nI] -> the mask [S, S] whose row t marks the
+    min(t + 1, topk) rows s <= t that query t attends. Scored and chosen
+    ``DSA_QUERY_BLOCK`` queries at a time against every row."""
+    S = q.shape[0]
+    blk = _block_of(S, DSA_QUERY_BLOCK)
+    rows = jnp.arange(S)
+
+    def one(block):
+        qb, wb, tb = block
+        with jax.named_scope("raytpu.dsa.index"):
+            scores = _index_scores(qb, wb, k)  # [blk, S]
+        with jax.named_scope("raytpu.dsa.select"):
+            return select_rows(scores, rows[None, :] <= tb[:, None], topk)
+
+    masks = lax.map(one, (q.reshape((-1, blk) + q.shape[1:]),
+                          w.reshape(-1, blk, w.shape[-1]),
+                          rows.reshape(-1, blk)))
+    return masks.reshape(S, S)
+
+
+def _attend_masked(q, k, v, mask, block: int = PREFILL_ATTN_BLOCK):
+    """Attention of one sequence under an arbitrary mask that is causal
+    at least: q, k [S, H, D], v [S, H, Dv], ``mask`` [S, S] (row t: the
+    rows query t attends, none beyond t, at least one). Tile by tile with
+    an online softmax in float32 (bf16 operands), the rows' tiles only up
+    to the queries' own: memory grows with S, not with S squared. Scores
+    are scaled by 1/sqrt(D). Returns [S, H, Dv] in q's type."""
+    S, H, D = q.shape
+    blk = _block_of(S, block)
+    f32 = jnp.float32
+    scale = D ** -0.5
+
+    def queries(i):
+        qb = lax.dynamic_slice_in_dim(q, i * blk, blk)
+
+        def rows(j, state):
+            m, l, acc = state  # [H,blk,1], [H,blk,1], [H,blk,Dv]
+            kb = lax.dynamic_slice_in_dim(k, j * blk, blk)
+            vb = lax.dynamic_slice_in_dim(v, j * blk, blk)
+            mb = lax.dynamic_slice(mask, (i * blk, j * blk), (blk, blk))
+            s = jnp.einsum("qhd,khd->hqk", qb, kb,
+                           preferred_element_type=f32) * scale
+            s = jnp.where(mb[None], s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            p = jnp.where(mb[None], jnp.exp(s - m_new), 0.0)
+            shrink = jnp.exp(m - m_new)
+            l = shrink * l + p.sum(-1, keepdims=True)
+            acc = shrink * acc + jnp.einsum(
+                "hqk,khd->hqd", p.astype(q.dtype), vb,
+                preferred_element_type=f32)
+            return m_new, l, acc
+
+        m0 = jnp.full((H, blk, 1), NEG_INF, f32)
+        _, l, acc = lax.fori_loop(
+            0, i + 1, rows,
+            (m0, jnp.zeros_like(m0), jnp.zeros((H, blk, v.shape[-1]), f32)))
+        return (acc / l).transpose(1, 0, 2).astype(q.dtype)
+
+    out = lax.map(queries, jnp.arange(S // blk))
+    return out.reshape(S, H, v.shape[-1])
+
+
+def _decode_choice(q, w, ik, slot, k_new, pos, topk: int):
+    """A decode step's choice: q [B, nI, dI] and w [B, nI] of each lane's
+    token against the lane's index keys ``ik[slot]`` [B, S_max, dI] below
+    ``pos`` plus the token's own ``k_new`` [B, dI] as the row at ``pos``
+    (a candidate like any other; its cache row is not written yet). The
+    keys are scored ``DSA_CHUNK`` rows at a time up to the longest lane,
+    like ``_walk_rows``. Returns the mask [B, S_max] of the
+    min(pos + 1, topk) best rows of each lane (``select_rows``: exactly
+    ``lax.top_k``'s set)."""
+    B, s_max, d_i = ik.shape[1:]
+    chunk = min(DSA_CHUNK, s_max)
+    with jax.named_scope("raytpu.dsa.index"):
+        def walk(c, scores):
+            start = jnp.minimum(c * chunk, s_max - chunk)
+            kc = lax.dynamic_slice(ik, (slot, 0, start, 0),
+                                   (1, B, chunk, d_i))[0]
+            sc = _index_scores(q[:, None], w[:, None], kc)[:, 0]
+            return lax.dynamic_update_slice(scores, sc, (0, start))
+
+        bound = jnp.minimum(jnp.max(pos), s_max)
+        scores = lax.fori_loop(
+            0, (bound + chunk - 1) // chunk, walk,
+            jnp.zeros((B, s_max), jnp.float32))
+        own = _index_scores(q[:, None], w[:, None], k_new[:, None])[:, 0]
+        rows = jnp.arange(s_max)[None, :]
+        scores = jnp.where(rows == pos[:, None], own, scores)
+    with jax.named_scope("raytpu.dsa.select"):
+        return select_rows(scores, rows <= pos[:, None], topk)
+
+
+def _attend_latent_chosen(q_lat, q_rope, ckr, row_new, pos, chosen, *,
+                          layer, scale: float, chunk: int = DSA_CHUNK):
+    """``_attend_latent_prefix_plus_self`` for a block that chooses: q_lat
+    [B,H,R] and q_rope [B,H,rope] attend the rows of ``chosen`` [B,S_max]
+    alone. The cache is ``ckr`` [L,B,S_max,W], rows [c | rot(k_r) | zeros];
+    the token's own ``row_new`` [B,W] stands for the row at ``pos`` (not
+    written yet) and counts only where ``chosen`` has it. The same walk
+    (``_walk_rows``: whole chunks up to the longest lane) with the online
+    softmax over the chosen rows; one product gives a chunk's scores (the
+    query laid out like a row). Returns o_lat [B,H,R].
+
+    Why a masked walk and not a gather of the chosen rows: timed alone on
+    a v5e, XLA's gather of 2,048 rows x 640 numbers for 12 lanes read 1.02
+    ms a layer, and the walk was built on that; the reading held ~0.6 ms
+    of dispatch, so the gather's device time is not known (PERF.md,
+    section 6, PR 32). The choice decides what is attended, not yet what
+    is read: in the traced cell this walk is 38 % of a decode step."""
+    B, s_max, width = ckr.shape[1:]
+    chunk = min(chunk, s_max)
+    f32 = jnp.float32
+    r_lat = q_lat.shape[-1]
+    q = _ckr_rows(q_lat, q_rope, width)  # [B,H,W]
+    has_own = jnp.take_along_axis(chosen, pos[:, None], axis=1)  # [B,1]
+    own = jnp.einsum("bhd,bd->bh", q, row_new,
+                     preferred_element_type=f32)[:, None] * scale
+    m = jnp.where(has_own[:, :, None], own, NEG_INF)  # [B,1,H]
+    l = jnp.broadcast_to(has_own[:, :, None].astype(f32), m.shape)
+    acc = has_own[:, :, None] * jnp.broadcast_to(
+        row_new[:, None, :r_lat].astype(f32), q_lat.shape)
+
+    def body(start, attended, state):
+        m, l, acc = state  # [B,1,H], [B,1,H], [B,H,R]
+        rows = lax.optimization_barrier(lax.dynamic_slice(
+            ckr, (layer, 0, start, 0), (1, B, chunk, width))[0])
+        take = attended() & lax.dynamic_slice(chosen, (0, start),
+                                              (B, chunk))
+        s = jnp.einsum("bkd,bhd->bkh", rows, q,
+                       preferred_element_type=f32) * scale
+        s = jnp.where(take[:, :, None], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        p = jnp.where(take[:, :, None], jnp.exp(s - m_new), 0.0)
+        shrink = jnp.exp(m - m_new)
+        l = shrink * l + p.sum(axis=1, keepdims=True)
+        acc = shrink[:, 0, :, None] * acc + jnp.einsum(
+            "bkh,bkd->bhd", p.astype(q_lat.dtype), rows[..., :r_lat],
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    l, acc = _walk_rows(body, m, acc, pos, s_max, chunk, l=l)
+    return (acc / l[:, 0, :, None]).astype(q_lat.dtype)
+
+
+def _prefill_attn_chosen(single, li, wp, choice, c: TransformerConfig):
+    """One prefill layer's ``attn_fn`` for a latent block with an indexer
+    (the counterpart of ``_decode_attn_chosen``). ``single`` is one slot's
+    cache, ``wp`` the layer's attention weights with its kind
+    (``scan_stack``), ``choice`` what the layer scan carries: the mask
+    [S, S] of the nearest layer below that owns an indexer, and that
+    layer's index keys ``k`` [S, dI]. Returns (output, (single with this
+    layer's rows written, choice))."""
+    S = choice["mask"].shape[0]
+
+    def choose(project):
+        def afresh(_):
+            q, k, w = project(_own_indexer(wp))
+            return {"mask": _prefill_choice(q[0], k[0], w[0],
+                                            c.index_topk), "k": k[0]}
+
+        return lax.cond(wp["index_own"], afresh, lambda _: choice, None)
+
+    @_latent
+    def cached_attn(q_nope, q_rope, c_kv, k_r, wp, chosen):
+        def put(name, rows, layer):
+            return lax.dynamic_update_slice(
+                single[name], rows[None].astype(single[name].dtype),
+                (layer, 0, 0, 0))
+
+        new = {"ckr": put("ckr", _ckr_rows(
+                   c_kv, k_r[:, :, 0], single["ckr"].shape[-1]), li),
+               "ik": put("ik", chosen["k"][None], wp["index_slot"])}
+        q = jnp.concatenate([q_nope, q_rope], -1)[0]  # [S,H,D]
+        n_h = q.shape[1]
+        g = PREFILL_HEAD_GROUP if n_h % PREFILL_HEAD_GROUP == 0 else n_h
+
+        def heads(i):  # keys and values of g heads at a time
+            def of(x):
+                return lax.dynamic_slice_in_dim(x, i * g, g, axis=1)
+
+            k, v = mla_expand(c_kv, k_r, {"wuk": of(wp["wuk"]),
+                                          "wuv": of(wp["wuv"])}, c)
+            return _attend_masked(of(q), k[0], v[0], chosen["mask"])
+
+        out = lax.map(heads, jnp.arange(n_h // g))  # [H/g,S,g,v]
+        out = out.transpose(1, 0, 2, 3).reshape(S, n_h, -1)
+        return out[None], (new, chosen)
+
+    cached_attn.choose = choose
+    return cached_attn
+
+
+def _own_indexer(wp):
+    """This layer's indexer out of its stack's (transformer.scan_stack)."""
+    return jax.tree.map(lambda a: a[wp["index_local"]], wp["indexer"])
+
+
 def _mla_scale(c: TransformerConfig) -> float:
     return (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
 
@@ -314,6 +623,50 @@ def _latent(fn):
     """Marks an ``attn_fn`` that takes latents (transformer._mla_mixer)."""
     fn.latent = True
     return fn
+
+
+def _decode_attn_chosen(cache, li, pos, b_idx, c: TransformerConfig, wp,
+                        choice):
+    """``_decode_attn`` for a latent block with an indexer. ``wp`` is the
+    layer's attention weights with its kind (``scan_stack``); ``choice``
+    is what the layer scan carries: the rows chosen by the nearest layer
+    below that owns an indexer, ``mask`` [B,S_max], and that layer's new
+    index key ``k`` [B,dI]. A layer that owns an indexer chooses afresh
+    (a ``lax.cond``: the others run nothing of it). Every layer attends
+    its own latent rows at the chosen places alone; the token's own row,
+    where chosen, is taken from the fresh latents (its cache row is
+    written after). Returns (output, (cache, choice))."""
+    ckr, ik = cache["ckr"], cache["ik"]
+
+    def choose(project):
+        def afresh(_):
+            q, k, w = project(_own_indexer(wp))  # [B,1,nI,dI] [B,1,dI] ..
+            return {"mask": _decode_choice(
+                q[:, 0], w[:, 0], ik, wp["index_slot"], k[:, 0], pos,
+                c.index_topk), "k": k[:, 0]}
+
+        return lax.cond(wp["index_own"], afresh, lambda _: choice, None)
+
+    @_latent
+    def cached_attn(q_nope, q_rope, c_kv, k_r, wp, chosen):
+        q_lat = jnp.einsum("bshk,chk->bshc", q_nope,
+                           wp["wuk"].astype(c.dtype))
+        row = _ckr_rows(c_kv[:, 0], k_r[:, 0, 0], ckr.shape[-1]).astype(
+            ckr.dtype)  # [B,W]: the token's own
+        o_lat = _attend_latent_chosen(
+            q_lat[:, 0], q_rope[:, 0], ckr, row, pos, chosen["mask"],
+            layer=li, scale=_mla_scale(c))
+        out = jnp.einsum("bshc,chk->bshk", o_lat[:, None],
+                         wp["wuv"].astype(c.dtype))
+        # the index key is written by every layer that attends its choice:
+        # the same row, the same value
+        return out, ({
+            "ckr": ckr.at[li, b_idx, pos].set(row),
+            "ik": ik.at[wp["index_slot"], b_idx, pos].set(
+                chosen["k"].astype(ik.dtype))}, chosen)
+
+    cached_attn.choose = choose
+    return cached_attn
 
 
 def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig):
@@ -361,16 +714,38 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig):
 
 
 def _add_stats(total, stats):
-    return {k: total[k] + v for k, v in stats.items()} if stats else total
+    return {**total, **{k: total[k] + v for k, v in stats.items()}}
 
 
 def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     """Names of the int32 counters ``decode_block`` returns with its
-    tokens for this model (none for a model without dropless experts)."""
+    tokens for this model: a dropless routed layer's (``ops/moe.
+    routed_ffn``), and a block with an indexer's: ``dsa_rows_scored``
+    (index keys scored), ``dsa_rows_selected`` (rows attended:
+    min(pos + 1, index_topk) a live lane a layer) and ``dsa_rows_live``
+    (rows a walk over every live row would attend), summed over lanes,
+    layers and steps. None for the other models."""
+    keys = ()
     if config.moe_experts and config.moe_impl == "dropless":
-        return ("moe_assignments", "moe_experts_touched",
-                "moe_experts_capacity", "moe_max_load", "moe_weight_visits")
-    return ()
+        keys += ("moe_assignments", "moe_experts_touched",
+                 "moe_experts_capacity", "moe_max_load", "moe_weight_visits")
+    if config.index_topk:
+        keys += ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_live")
+    return keys
+
+
+def _dsa_stats(pos, c: TransformerConfig, s_max: int):
+    """One decode step's selection counters, from the lanes' positions."""
+    live, rows = pos > 0, pos + 1
+    chunk = min(DSA_CHUNK, s_max)  # whole chunks, as _decode_choice walks
+    bound = jnp.minimum(jnp.max(pos), s_max)
+    return {
+        "dsa_rows_scored": c.n_index_layers * pos.shape[0] * jnp.minimum(
+            (bound + chunk - 1) // chunk * chunk, s_max),
+        "dsa_rows_selected": c.n_layers * jnp.where(
+            live, jnp.minimum(rows, c.index_topk), 0).sum(),
+        "dsa_rows_live": c.n_layers * jnp.where(live, rows, 0).sum(),
+    }
 
 
 def _zero_stats(config: TransformerConfig):
@@ -387,21 +762,32 @@ def _decode_forward_multi(params, token, cache, pos,
     c = config
     x = params["embed"].astype(c.dtype)[token][:, None]  # [B,1,D]
     # a parked lane's token picks no expert (only a routed layer asks)
-    live = (pos > 0)[:, None] if block_stat_keys(c) else None
-    b_idx = jnp.arange(token.shape[0])
-    carry = (x, cache, _zero_stats(c))
+    routed = c.moe_experts and c.moe_impl == "dropless"
+    live = (pos > 0)[:, None] if routed else None
+    B = token.shape[0]
+    b_idx = jnp.arange(B)
+    choice = None  # a block with an indexer: what the layers hand on
+    if c.index_topk:
+        choice = {"mask": jnp.zeros((B, cache["ik"].shape[2]), bool),
+                  "k": jnp.zeros((B, c.index_head_dim), c.dtype)}
+    carry = (x, cache, _zero_stats(c), choice)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
-            x, cache, total = carry
+            x, cache, total, choice = carry
+            attn = (_decode_attn(cache, li, pos, b_idx, lc)
+                    if choice is None else _decode_attn_chosen(
+                        cache, li, pos, b_idx, lc, lp["attn"], choice))
             y, _aux, cache, stats = apply_block(
-                x, lp, lc, pos[:, None],
-                _decode_attn(cache, li, pos, b_idx, lc),
-                token_mask=live,
-            )
-            return y, cache, _add_stats(total, stats)
+                x, lp, lc, pos[:, None], attn, token_mask=live)
+            if choice is not None:
+                cache, choice = cache
+            return y, cache, _add_stats(total, stats), choice
 
         carry = scan_stack(layer, carry, stack, lc, first)
-    x, cache, stats = carry
+    x, cache, stats, _choice = carry
+    if c.index_topk:
+        stats = _add_stats(stats, _dsa_stats(
+            pos, c, cache["ik"].shape[2]))
     x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
@@ -472,15 +858,20 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(4,))
 def prefill_into_slot(params, prompt, prompt_len, slot, cache,
                       config: TransformerConfig):
-    """Run ONE padded prompt [1, Sb] and write its K/V into ``slot`` of the
-    shared batch cache (static shapes: Sb is a bucket size; compile count =
-    number of buckets). Positions past prompt_len write junk K/V that is
-    never attended: the slot's kv_valid mask stops at its position, and
-    decode overwrites those cells before reaching them.
+    """Run ONE padded prompt [1, Sb] and write its rows into ``slot`` of
+    the shared batch cache (static shapes: Sb is a bucket size; compile
+    count = number of buckets). Positions past prompt_len write junk rows
+    that are never attended: the slot's kv_valid mask stops at its
+    position, and decode overwrites those cells before reaching them.
 
     Latent attention takes the plain form here: per-head keys and values
     are expanded from the prompt's latents and attended causally over the
-    prompt; what the slot keeps is the latent rows ``c_kv`` and ``rot(k_r)``.
+    prompt; what the slot keeps is the latent rows ``c_kv`` and ``rot(k_r)``
+    of every layer and, where the block has an indexer, the index key of
+    every layer that owns one. Such a block's queries attend their chosen
+    rows alone, tile by tile (``_prefill_choice``, ``_attend_masked``:
+    the choice, a mask [Sb, Sb], travels from the layer that makes it to
+    the layers that share it in the layer scan's carry).
 
     Returns (last-valid-token logits [V], cache)."""
     c = config
@@ -491,7 +882,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     positions = jnp.arange(S)
     kv_valid = (jnp.arange(s_max) < prompt_len)[None]  # [1, S_max]
     # a prompt's padding picks no expert (only a routed layer asks)
-    real = (positions < prompt_len)[None] if block_stat_keys(c) else None
+    routed = c.moe_experts and c.moe_impl == "dropless"
+    real = (positions < prompt_len)[None] if routed else None
 
     def slot_attn(single, li):
         if c.mixer == "mla":
@@ -544,18 +936,24 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
-    carry = (x, single)
+    choice = None
+    if c.index_topk:
+        choice = {"mask": jnp.zeros((S, S), bool),
+                  "k": jnp.zeros((S, c.index_head_dim), c.dtype)}
+    carry = (x, single, choice)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
-            x, single = carry
+            x, single, choice = carry
+            attn = (slot_attn(single, li) if choice is None else
+                    _prefill_attn_chosen(single, li, lp["attn"], choice, lc))
             y, _aux, single, _stats = apply_block(
-                x, lp, lc, positions, slot_attn(single, li),
-                token_mask=real,
-            )
-            return y, single
+                x, lp, lc, positions, attn, token_mask=real)
+            if choice is not None:
+                single, choice = single
+            return y, single, choice
 
         carry = scan_stack(layer, carry, stack, lc, first)
-    x, single = carry
+    x, single, _choice = carry
     x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     last = x[0, prompt_len - 1]  # [D] — last REAL token's features

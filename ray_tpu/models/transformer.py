@@ -16,7 +16,10 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   RMSNorm, optional GQA (n_kv_heads); the other forms are a sequential
   two-norm block, gated FFNs, latent attention (`mixer="mla"`), and
   dropless routed experts with a shared expert (`moe_impl="dropless"`):
-  `TransformerConfig.glm47_flash()` is all of them at once;
+  `TransformerConfig.glm47_flash()` is all of them at once; a latent
+  block may also own an indexer that picks the cache rows its queries
+  attend (`index_topk`, `indexer_types`), and a routed layer may hold a
+  share of its experts (`moe_experts_held`);
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -28,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -97,10 +100,58 @@ class TransformerConfig:
     moe_shared_experts: int = 0
     moe_route_scale: float = 1.0
     n_dense_layers: int = 0
+    # A chip's share of a layer's experts: this stack holds the
+    # moe_experts_held experts from moe_first_expert on (0 = all of them).
+    # The router keeps its moe_experts outputs and its moe_top_k a token;
+    # what an absent expert would have added is left out (ops/moe.py).
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    # Learned sparse attention over the latent cache (mixer "mla" only;
+    # index_topk 0 = every row is attended). A layer whose indexer_types
+    # entry is "full" owns an indexer: index_n_heads queries of
+    # index_head_dim from the query latent, ONE key a token from the
+    # layer's input (LayerNorm, rotary on its first qk_rope_dim dims), a
+    # weight a head; row s scores sum_j w_j relu(q_j . k_s) for query t,
+    # and the query attends the min(t + 1, index_topk) best rows s <= t,
+    # softmax over those alone. A "shared" layer has no indexer and
+    # attends the choice of the nearest "full" layer below it (the first
+    # layer is "full"). One entry a layer, dense layers first.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Tuple[str, ...] = ()
+
+    def __post_init__(self):
+        if self.index_topk:
+            kinds = tuple(self.indexer_types)
+            if self.mixer != "mla" or len(kinds) != self.n_layers or (
+                    kinds and kinds[0] != "full") or (
+                    set(kinds) - {"full", "shared"}):
+                raise ValueError(
+                    "index_topk needs mixer 'mla' and one indexer_types "
+                    "entry a layer ('full' | 'shared'), the first 'full'")
+            object.__setattr__(self, "indexer_types", kinds)
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_experts
+
+    def index_slots(self, first: int = 0, n: Optional[int] = None):
+        """For the layers ``first .. first + n``: the slot (counted over
+        the whole model's "full" layers) of the indexer whose choice each
+        attends. A "full" layer's is its own."""
+        owns = [k == "full" for k in self.indexer_types]
+        slots = [sum(owns[:i + 1]) - 1 for i in range(len(owns))]
+        return slots[first:None if n is None else first + n]
+
+    @property
+    def n_index_layers(self) -> int:
+        return sum(k == "full" for k in self.indexer_types
+                   ) if self.index_topk else 0
 
     @property
     def n_expert_layers(self) -> int:
@@ -125,7 +176,7 @@ class TransformerConfig:
         elif self.moe_impl == "dropless":
             fe = self.moe_d_ff or f
             ffn = (d + 1) * self.moe_experts + mats * d * fe * (
-                self.moe_experts + self.moe_shared_experts)
+                self.experts_held + self.moe_shared_experts)
         else:
             ffn = d * self.moe_experts + 2 * self.moe_experts * d * f
         if self.mixer == "mla":
@@ -141,8 +192,12 @@ class TransformerConfig:
             attn = d * dh * (h + 2 * kv) + h * dh * d
         norms = d * (2 if self.residual == "sequential" else 1)
         n_dense = self.n_dense_layers if self.moe_experts else 0
+        indexer = self.n_index_layers * (
+            self.q_lora_rank * self.index_n_heads * self.index_head_dim
+            + d * (self.index_head_dim + self.index_n_heads)
+            + 2 * self.index_head_dim)
         layers = (self.n_layers * (attn + norms) + n_dense * dense_ffn
-                  + (self.n_layers - n_dense) * ffn)
+                  + (self.n_layers - n_dense) * ffn + indexer)
         head = 0 if self.tie_embeddings else d * self.vocab_size
         return self.vocab_size * d + layers + d + head
 
@@ -206,6 +261,34 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     @staticmethod
+    def glm52(n_layers: int = 78, **kw) -> "TransformerConfig":
+        """GLM-5.2 (zai-org/GLM-5.2 config.json, model_type glm_moe_dsa)
+        at its published widths: latent attention with 64 heads and a
+        learned selection of 2,048 cache rows a query (an indexer on the
+        three dense layers and then on every fourth layer, its choice
+        shared by the three layers above it), three dense layers, then
+        layers of 256 routed experts (8 a token) and a shared one.
+        ``n_layers`` counts the dense layers; a cut passes its own
+        ``n_dense_layers`` / ``indexer_types`` / ``moe_experts_held``. The
+        multi-token-prediction module is not part of the block."""
+        dense = kw.get("n_dense_layers", 3)
+        base = dict(
+            vocab_size=154880, d_model=6144, n_layers=n_layers, n_heads=64,
+            d_ff=12288, max_seq_len=1048576, mixer="mla", q_lora_rank=2048,
+            kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64,
+            v_head_dim=256, residual="sequential", activation="silu",
+            gated_ffn=True, norm_eps=1e-5, rope_theta=8e6, moe_experts=256,
+            moe_top_k=8, moe_impl="dropless", moe_d_ff=2048,
+            moe_shared_experts=1, moe_route_scale=2.5, n_dense_layers=dense,
+            index_topk=2048, index_n_heads=32, index_head_dim=128,
+            indexer_types=tuple(
+                "full" if i < dense or (i - dense) % 4 == 3 else "shared"
+                for i in range(n_layers)),
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny_mla_moe(**kw) -> "TransformerConfig":
         """The same block at test size (CPU)."""
         base = dict(
@@ -219,6 +302,19 @@ class TransformerConfig:
         )
         base.update(kw)
         return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_dsa_moe(**kw) -> "TransformerConfig":
+        """``tiny_mla_moe`` with an indexer (GLM-5.2's kind of block) at
+        test size: six layers ``full | shared shared shared full shared``,
+        16 rows a query."""
+        base = dict(
+            n_layers=6, rope_theta=8e6, moe_top_k=2, moe_route_scale=2.5,
+            index_topk=16, index_n_heads=4, index_head_dim=16,
+            indexer_types=("full",) + ("shared",) * 3 + ("full", "shared"),
+        )
+        base.update(kw)
+        return TransformerConfig.tiny_mla_moe(**base)
 
     @staticmethod
     def tiny(**kw) -> "TransformerConfig":
@@ -246,8 +342,9 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
         return {"wg": dense_init(jax.random.fold_in(key, 2), shape, fan_in)
                 } if c.gated_ffn else {}
 
-    def stack(lc: TransformerConfig, L: int, salt: int) -> Dict:
-        """L layers of ``lc``'s block, stacked on a leading axis."""
+    def stack(lc: TransformerConfig, L: int, salt: int, first: int) -> Dict:
+        """L layers of ``lc``'s block (the model's layers from ``first``
+        on), stacked on a leading axis."""
         kq, kk, kv, ko, kwi, kwo = (
             (k_q, k_k, k_v, k_o, k_wi, k_wo) if not salt else
             [jax.random.fold_in(k, salt) for k in (k_q, k_k, k_v, k_o,
@@ -272,6 +369,20 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                 "wo": dense_init(ko, (L, h, lc.v_head_dim, d),
                                  h * lc.v_head_dim),
             }
+            n_own = lc.index_topk and sum(
+                k == "full" for k in lc.indexer_types[first:first + L])
+            if n_own:  # one indexer for each "full" layer of this stack
+                ki = jax.random.fold_in(kq, 5)
+                nI, dI = lc.index_n_heads, lc.index_head_dim
+                layers["attn"]["indexer"] = {
+                    "wq": dense_init(ki, (n_own, r_q, nI, dI), r_q),
+                    "wk": dense_init(jax.random.fold_in(ki, 1),
+                                     (n_own, d, dI), d),
+                    "k_norm": {"scale": jnp.ones((n_own, dI), pd),
+                               "bias": jnp.zeros((n_own, dI), pd)},
+                    "ww": dense_init(jax.random.fold_in(ki, 2),
+                                     (n_own, d, nI), d),
+                }
         else:
             layers["attn"] = {
                 "wq": dense_init(kq, (L, d, lc.n_heads, lc.d_head), d),
@@ -283,14 +394,17 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
         if lc.moe_experts and lc.moe_impl == "dropless":
             E, f = lc.moe_experts, lc.moe_d_ff or lc.d_ff
             k_rt = jax.random.fold_in(kwi, 1)
+            router = dense_init(k_rt, (L, d, E), d)
+            E = lc.experts_held  # the router stays whole; the rest is held
             layers["moe"] = {
-                "router": dense_init(k_rt, (L, d, E), d),
+                "router": router,
                 # the selection's correction bias: trained in the published
                 # model (to even out the experts' load), here seeded, non-zero
                 # and small against the scores' spread (~0.2): at 0.1 the
                 # fullest expert drew 5.5 x the mean load on the chip
                 "bias": (0.02 * jax.random.normal(
-                    jax.random.fold_in(k_rt, 1), (L, E))).astype(pd),
+                    jax.random.fold_in(k_rt, 1),
+                    (L, lc.moe_experts))).astype(pd),
                 "wi": dense_init(kwi, (L, E, d, f), d),
                 "wo": dense_init(kwo, (L, E, f, d), f),
                 **gate(kwi, (L, E, d, f), d),
@@ -323,11 +437,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     params = {
         "embed": (jax.random.normal(k_emb, (c.vocab_size, c.d_model)) * 0.02
                   ).astype(pd),
-        "layers": stack(c, c.n_layers - n_dense, 0),
+        "layers": stack(c, c.n_layers - n_dense, 0, n_dense),
         "final_ln": {"scale": jnp.ones((c.d_model,), pd)},
     }
     if n_dense:
-        params["dense_layers"] = stack(c.dense_variant(), n_dense, 7)
+        params["dense_layers"] = stack(c.dense_variant(), n_dense, 7, 0)
     if not c.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (c.d_model, c.vocab_size),
                                        c.d_model)
@@ -340,7 +454,7 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
     def gate(axes):
         return {"wg": axes} if config.gated_ffn else {}
 
-    def stack(lc: TransformerConfig) -> Dict:
+    def stack(lc: TransformerConfig, first: int, L: int) -> Dict:
         layers = {"ln1": {"scale": ("layers", "embed")}}
         if lc.residual == "sequential":
             layers["ln2"] = {"scale": ("layers", "embed")}
@@ -355,6 +469,14 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
                 "wuv": ("layers", None, "heads", "head_dim"),
                 "wo": ("layers", "heads", "head_dim", "embed"),
             }
+            if lc.index_topk and "full" in lc.indexer_types[first:first + L]:
+                layers["attn"]["indexer"] = {
+                    "wq": ("layers", None, None, None),
+                    "wk": ("layers", "embed", None),
+                    "k_norm": {"scale": ("layers", None),
+                               "bias": ("layers", None)},
+                    "ww": ("layers", "embed", None),
+                }
         else:
             layers["attn"] = {
                 "wq": ("layers", "embed", "heads", "head_dim"),
@@ -385,13 +507,14 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
             }
         return layers
 
+    n_dense = config.n_dense_layers if config.moe_experts else 0
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": stack(config),
+        "layers": stack(config, n_dense, config.n_layers - n_dense),
         "final_ln": {"scale": ("embed",)},
     }
-    if config.moe_experts and config.n_dense_layers:
-        axes["dense_layers"] = stack(config.dense_variant())
+    if n_dense:
+        axes["dense_layers"] = stack(config.dense_variant(), 0, n_dense)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -424,22 +547,44 @@ def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
     [layers, E, ...], with ``lp["moe"]["layer"]`` saying which layer's
     experts to use, and the grouped product's block index picks
     (layer, expert) inside the kernel (``ops/moe.routed_ffn``,
-    ``ops/grouped_matmul``)."""
-    n = jax.tree.leaves(stack)[0].shape[0]
-    held = {}
+    ``ops/grouped_matmul``).
+
+    Layers of one stack may differ in kind where the block has an indexer
+    (``lc.index_topk``): only the "full" ones own one, so the stack's
+    indexers, [own layers, ...], travel whole as well, and each layer is
+    told, as scanned scalars in ``lp["attn"]``, whether it owns one
+    (``index_own``), which of the stack's it is (``index_local``) and
+    which of the model's choices it attends (``index_slot``)."""
+    n = stack["ln1"]["scale"].shape[0]
+    held, kinds = {}, None
     if lc.moe_experts and lc.moe_impl == "dropless":
         held = {k: stack["moe"][k] for k in _EXPERT_WEIGHTS
                 if k in stack["moe"]}
         stack = {**stack, "moe": {k: v for k, v in stack["moe"].items()
                                   if k not in held}}
+    if lc.index_topk:
+        types = lc.indexer_types
+        own = [k == "full" for k in types[first:first + n]]
+        before = sum(k == "full" for k in types[:first])
+        slots = lc.index_slots(first, n)
+        kinds = {"index_own": jnp.array(own),
+                 "index_slot": jnp.array(slots, jnp.int32),
+                 "index_local": jnp.array(
+                     [max(s - before, 0) for s in slots], jnp.int32)}
+        indexer = stack["attn"].get("indexer")
+        stack = {**stack, "attn": {k: v for k, v in stack["attn"].items()
+                                   if k != "indexer"}}
 
     def step(carry, layer_in):
-        lp, li = layer_in
+        lp, li, kind = layer_in
         if held:
             lp = {**lp, "moe": {**lp["moe"], **held, "layer": li - first}}
+        if kind is not None:
+            lp = {**lp, "attn": {**lp["attn"], **kind, "indexer": indexer}}
         return body(carry, lp, li), None
 
-    carry, _ = lax.scan(step, carry, (stack, jnp.arange(first, first + n)))
+    carry, _ = lax.scan(
+        step, carry, (stack, jnp.arange(first, first + n), kinds))
     return carry
 
 
@@ -539,6 +684,30 @@ def mla_expand(c_kv, k_r, wp, c: TransformerConfig):
     return k, v
 
 
+def index_project(h, c_q, ip, c: TransformerConfig, positions):
+    """One indexer's side of a layer: from the layer's normed input ``h``
+    [B,S,d] and its query latent ``c_q`` [B,S,r_q] the index queries
+    [B,S,nI,dI] and the ONE key a token [B,S,dI] (LayerNorm, then like
+    the queries rotary on the first ``qk_rope_dim`` dims), both in the
+    compute dtype, and the heads' weights [B,S,nI] in float32, scaled by
+    1/sqrt(nI dI). Row s then scores ``sum_j w_j relu(q_j . k_s)``."""
+    f32 = jnp.float32
+    with jax.named_scope("raytpu.dsa.index"):
+        q = jnp.einsum("bsr,rjk->bsjk", c_q, ip["wq"].astype(c.dtype))
+        k = jnp.einsum("bsd,dk->bsk", h, ip["wk"].astype(c.dtype))
+        k32 = k.astype(f32)
+        k32 = k32 - k32.mean(-1, keepdims=True)
+        k32 = k32 * lax.rsqrt((k32 * k32).mean(-1, keepdims=True) + 1e-6)
+        k = (k32 * ip["k_norm"]["scale"].astype(f32)
+             + ip["k_norm"]["bias"].astype(f32)).astype(c.dtype)
+        q, k = _rotary(q, k[:, :, None], c.qk_rope_dim, positions,
+                       c.rope_theta)
+        w = jnp.einsum("bsd,dj->bsj", h, ip["ww"].astype(c.dtype),
+                       preferred_element_type=f32)
+        w = w * (c.index_n_heads * c.index_head_dim) ** -0.5
+    return q, k[:, :, 0], w
+
+
 def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     """Latent attention. Scores are (q_nope . k_nope + q_rope . k_r) /
     sqrt(nope + rope); every head shares the one rotary key. ``attn_fn``
@@ -558,9 +727,17 @@ def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
         q_rope, k_r = _rotary(q[..., nope:], kv[:, :, None, r:],
                               c.qk_rope_dim, positions, c.rope_theta)
         q_nope = q[..., :nope]
+    chosen = ()
+    if c.index_topk:
+        if not hasattr(attn_fn, "choose"):
+            raise NotImplementedError(
+                "a block with an indexer runs on the serving paths only "
+                "(generation.prefill_into_slot / decode_block)")
+        chosen = (attn_fn.choose(
+            lambda ip: index_project(h, c_q, ip, c, positions)),)
     with jax.named_scope("raytpu.mla.attend"):
         if getattr(attn_fn, "latent", False):
-            attn_out = attn_fn(q_nope, q_rope, c_kv, k_r, wp)
+            attn_out = attn_fn(q_nope, q_rope, c_kv, k_r, wp, *chosen)
         else:
             k, v = mla_expand(c_kv, k_r, wp, c)
             attn_out = attn_fn(jnp.concatenate([q_nope, q_rope], -1), k, v)
@@ -614,6 +791,7 @@ def apply_block(
         m, stats = routed_ffn(
             h, lp["moe"], top_k=c.moe_top_k, route_scale=c.moe_route_scale,
             act=_ACTIVATIONS[c.activation], token_mask=token_mask,
+            first_expert=c.moe_first_expert,
         )
     elif c.moe_experts:
         from ray_tpu.ops.moe import moe_ffn

@@ -16,8 +16,10 @@ capacity, no dispatch tensor), for models whose mathematics has no dropped
 token. Its experts run as two calls of the Pallas grouped product in
 ``ops/grouped_matmul.py`` (gate and up in one pass, then down), which
 streams the touched experts' weights out of the stacked array in pieces
-of megabytes. It runs on one chip's experts and has no backward pass;
-sharding it over ``ep`` and training it are open (ROADMAP R2).
+of megabytes. It may hold a chip's share of the layer's experts (the
+router stays whole, what an absent expert would have added is left out);
+it has no exchange between chips and no backward pass: those are open
+(ROADMAP R2).
 """
 
 from __future__ import annotations
@@ -119,14 +121,22 @@ def moe_ffn(
     return out, aux
 
 
+# Tokens one pass of ``routed_ffn`` sorts and gathers: a longer prompt goes
+# through in pieces of this many, so that the [tokens x top_k, D] rows of
+# the sorted pairs stay a few hundred megabytes (a 24,576-token prompt at
+# 8 experts a token and 6,144 wide would be 2.4 GB, four times over).
+ROUTED_TOKENS_A_PASS = 4096
+
+
 def routed_ffn(
     x: jax.Array,  # [..., D]
-    wp,  # router [D,E], bias [E], wi/wo (+ wg) [E,...], optional "shared"
+    wp,  # router [D,E], bias [E], wi/wo (+ wg) [H,...], optional "shared"
     *,
     top_k: int,
     route_scale: float = 1.0,
     act=jax.nn.silu,
     token_mask: Optional[jax.Array] = None,  # x.shape[:-1], bool
+    first_expert: int = 0,
 ):
     """Dropless routed experts with bias-corrected selection, one
     implementation for a prompt's S tokens and a decode step's B.
@@ -147,23 +157,55 @@ def routed_ffn(
     sent to no expert: their pairs sort behind the last group, and their
     routed output is zero.
 
+    A SHARE of the experts: where the weights hold fewer experts than the
+    router has outputs (``wi`` [H, ...], H < E), they are the experts
+    ``first_expert .. first_expert + H`` of the layer, as one of the chips
+    that divide it would hold them. The router keeps its E outputs and its
+    ``top_k`` a token, and a chosen expert's weight is normalised over ALL
+    the chosen, held or not; a pair that goes to an absent expert sorts
+    behind the last group like a parked lane's, and what it would have
+    added is left out: the result is this share's part of the layer's
+    (the shares' parts, with the shared expert counted once, add up to
+    the whole layer's). Nothing stands in for the other chips.
+
     With ``wp["layer"]`` (an index) the experts' weights are those of a
     whole stack of layers, [layers, E, ...]: the kernel's block index is
     (layer, expert, ...), so it reads this layer's touched experts where
     they lie and nothing slices (copies) a layer's experts out of the
     stack (``transformer.scan_stack``).
 
+    More than ``ROUTED_TOKENS_A_PASS`` tokens (a long prompt) go through
+    in passes of that many, each sorted and computed on its own.
+
     Returns (y like x, stats): int32 scalars ``moe_assignments`` (pairs
     computed), ``moe_experts_touched`` (experts with at least one),
-    ``moe_experts_capacity`` (E), ``moe_max_load`` (the fullest expert's
+    ``moe_experts_capacity`` (the experts a step could read: those HELD
+    here, E for a whole layer), ``moe_max_load`` (the fullest expert's
     pairs), ``moe_weight_visits`` (the (expert, 128-row tile) pairs the
     kernel's schedule visits: ``moe_experts_touched`` where every
     expert's rows sit in one tile, as a decode step's 128 rows do)."""
     f32 = jnp.float32
-    d, E = x.shape[-1], wp["router"].shape[-1]
+    d, E = x.shape[-1], wp["wi"].shape[-3]  # E: the experts held here
+    whole = E == wp["router"].shape[-1]
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
     gated = "wg" in wp
+    if n > ROUTED_TOKENS_A_PASS and n % ROUTED_TOKENS_A_PASS == 0:
+        mask = (jnp.ones(n, bool) if token_mask is None
+                else token_mask.reshape(-1))
+
+        def one_pass(args):
+            return routed_ffn(
+                args[0], wp, top_k=top_k, route_scale=route_scale, act=act,
+                token_mask=args[1], first_expert=first_expert)
+
+        y, stats = jax.lax.map(one_pass, (
+            x2.reshape(-1, ROUTED_TOKENS_A_PASS, d),
+            mask.reshape(-1, ROUTED_TOKENS_A_PASS)))
+        # per pass; an expert touched in two passes was read twice
+        stats = {k: v.max() if k in ("moe_max_load", "moe_experts_capacity")
+                 else v.sum() for k, v in stats.items()}
+        return y.reshape(x.shape), stats
     with jax.named_scope("raytpu.moe.route"):
         s = jax.nn.sigmoid(jnp.dot(
             x2.astype(f32), wp["router"].astype(f32),
@@ -172,6 +214,9 @@ def routed_ffn(
         w = jnp.take_along_axis(s, idx, axis=-1)
         w = w / w.sum(-1, keepdims=True) * route_scale
         expert = idx.reshape(-1)  # pair p = token p // k, choice p % k
+        if not whole:  # a share: an absent expert's pair is left out
+            expert = expert - first_expert
+            expert = jnp.where((expert >= 0) & (expert < E), expert, E)
         if token_mask is not None:
             live = jnp.repeat(token_mask.reshape(-1), top_k)
             expert = jnp.where(live, expert, E)  # behind every group

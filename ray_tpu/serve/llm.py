@@ -339,7 +339,15 @@ class LLMEngine:
           pairs), ``moe_weight_visits`` (the (expert, row tile) pairs the
           grouped product's schedule visits, ``ops/grouped_matmul``: over
           ``moe_experts_touched`` it is 1.0 where every expert's rows sit
-          in one tile, as a decode step's do).
+          in one tile, as a decode step's do). Where the stack holds a
+          share of a layer's experts, ``moe_experts_capacity`` counts the
+          experts HELD. For a block with an indexer, over live lanes,
+          layers and steps: ``dsa_rows_scored`` (index keys an indexer
+          scored: whole chunks up to the longest lane, every lane, each
+          layer that owns an indexer), ``dsa_rows_selected`` (rows
+          attended: min(pos + 1, index_topk) a lane a layer) and
+          ``dsa_rows_live`` (rows a walk over every live row would have
+          attended: pos + 1).
         """
         with self._lock:
             out = {
@@ -468,7 +476,11 @@ class LLMEngine:
         dispatch time, and the not-yet-emitted first tokens of requests
         admitted since the previous dispatch. K adapts to load (see
         __init__): light load -> short blocks -> short admission waits."""
-        from ray_tpu.models.generation import attn_rows_walked, decode_block
+        from ray_tpu.models.generation import (
+            attn_rows_walked,
+            decode_attn_chunk,
+            decode_block,
+        )
 
         live = [r for r in self.slot_req
                 if r is not None and not r.finished]
@@ -487,6 +499,7 @@ class LLMEngine:
             # of the last block retired (the one in flight is not read)
             experts_touched=self._last_block_stats.get(
                 "moe_experts_touched", 0),
+            selected=self._last_block_stats.get("dsa_rows_selected", 0),
         ):
             toks, self.cache, self.tok, self.pos, self.counts, stats = (
                 decode_block(
@@ -498,8 +511,10 @@ class LLMEngine:
         self._blocks_by_steps[steps] += 1
         self._n["slot_steps"] += active * steps
         self._n["capacity_steps"] += self.max_slots * steps
+        chunk = decode_attn_chunk(self.config)
         self._n["attn_rows_read"] += self.max_slots * sum(
-            attn_rows_walked(bound + k, self.max_len) for k in range(steps))
+            attn_rows_walked(bound + k, self.max_len, chunk)
+            for k in range(steps))
         self._n["attn_rows_capacity"] += self.max_slots * self.max_len * steps
         # as decode_block leaves pos: a step on, parked lanes stay at 0
         self._rows = [r + steps if r else 0 for r in self._rows]
