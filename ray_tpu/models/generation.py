@@ -18,17 +18,21 @@ inside it), and a block with an indexer the index key of each layer that
 owns one. Both programs run every block the config can describe.
 Decode is bound by HBM reads, and a masked cache row is read like a live
 one: the mask only discards what was already streamed. So the decode
-attention (``_attend_prefix_plus_self``) walks the cache in row chunks
-and stops at the longest live sequence. A block with an indexer reads
-less still: it scores the prefix's index keys, picks ``index_topk`` rows a
-lane, and attends those alone (``_decode_choice``,
-``_attend_latent_chosen``: a masked walk, see there); its prefill attends block by block under the
-mask of each query's chosen rows (``_prefill_choice``, ``_attend_masked``).
+attention of both dense caches (``_attend_prefix_plus_self``,
+``_attend_latent_prefix_plus_self``) is one Pallas kernel
+(``ops/decode_attention``) that reads each slot in row chunks up to the
+slot's OWN length, and nothing of a parked slot. A block with an indexer
+attends fewer rows still: it scores the prefix's index keys, picks
+``index_topk`` rows a lane, and attends those alone (``_decode_choice``,
+``_attend_latent_chosen``: a masked walk up to the longest lane, see
+there); its prefill attends block by block under the mask of each query's
+chosen rows (``_prefill_choice``, ``_attend_masked``).
 """
 
 from __future__ import annotations
 
-from functools import partial
+import math
+from functools import lru_cache, partial
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -44,6 +48,11 @@ from ray_tpu.models.transformer import (
     scan_stack,
 )
 from ray_tpu.ops.attention import NEG_INF, causal_attention, repeat_kv
+from ray_tpu.ops.decode_attention import (
+    chunk_rows,
+    decode_attention,
+    slot_schedule,
+)
 
 
 def prepare_for_inference(params, config: TransformerConfig):
@@ -184,36 +193,53 @@ def init_kv_cache(config: TransformerConfig, batch: int,
 # the TPU-shaped analog of vLLM's iteration-level batching.)
 
 
-# Rows of cache one iteration of the decode attention reads. 256 rows of
-# 8 slots x 16 heads x 256 dims in bf16 are 16.8 MB each of K and V.
-DECODE_ATTN_CHUNK = 256
+def dense_attn_chunk(arrays) -> int:
+    """Rows of one slot that one visit of the decode attention's kernel
+    reads of the cache ``arrays`` ([L,B,S_max,...] each): from what a row
+    weighs in all of them together (``ops/decode_attention.chunk_rows``)."""
+    return chunk_rows(
+        sum(math.prod(a.shape[3:]) * a.dtype.itemsize for a in arrays),
+        arrays[0].shape[2])
 
 
-def attn_rows_walked(bound: int, s_max: int,
-                     chunk: int = DECODE_ATTN_CHUNK) -> int:
-    """Rows of every slot's cache that one decode step reads when the
-    largest ``pos`` among its lanes is ``bound``: whole chunks up to it,
-    at most ``s_max``. Plain integers: the engine's host-side count of
-    what ``_attend_prefix_plus_self`` walks on the device (``chunk``:
-    ``decode_attn_chunk`` of the model)."""
-    chunk = min(chunk, s_max)
-    return min(-(-min(bound, s_max) // chunk) * chunk, s_max)
+def _visits(pos, arrays, chunk: Optional[int] = None):
+    """One decode step's schedule for the kernel over the cache ``arrays``
+    (``ops/decode_attention.slot_schedule``); ``chunk`` only where a test
+    names its own."""
+    s_max = arrays[0].shape[2]
+    return slot_schedule(
+        pos, s_max, min(chunk or dense_attn_chunk(arrays), s_max))
 
 
-def decode_attn_chunk(config: TransformerConfig) -> int:
-    """Rows of cache one iteration of ``config``'s decode attention
-    reads."""
-    return DSA_CHUNK if config.index_topk else DECODE_ATTN_CHUNK
+@lru_cache(maxsize=None)
+def decode_attn_chunk(config: TransformerConfig, s_max: int) -> int:
+    """Rows of cache one iteration of ``config``'s decode attention reads
+    of a slot of ``s_max`` rows."""
+    if config.index_topk:
+        return min(DSA_CHUNK, s_max)
+    cache = jax.eval_shape(lambda: init_kv_cache(config, 1, s_max))
+    return dense_attn_chunk(jax.tree.leaves(cache))
 
 
-def _walk_rows(body, m, acc, pos, s_max: int, chunk: int, l=None):
-    """The schedule and the state both decode attentions share: an online
-    softmax (running max ``m``, sum ``l`` starting at 1, accumulator
-    ``acc``: seeded by the token's own position; a walk in which the own
-    position is only a candidate passes its own ``l``) carried through ``state =
-    body(start, attended, state)`` for each chunk of ``chunk`` cache rows
-    up to the longest live sequence, ceil(max(pos) / chunk) iterations:
-    the trip count is data, so one compiled program serves every length.
+def attn_rows_walked(rows: int, s_max: int, chunk: int) -> int:
+    """Rows of ONE slot's cache that one decode step reads when the slot's
+    ``pos`` is ``rows``: whole chunks of ``chunk`` (``decode_attn_chunk``
+    of the model) up to it, at most ``s_max``; none for a parked slot.
+    Plain integers: the engine's host-side count of what the decode
+    attention's kernel reads on the device, slot by slot. (The block with
+    an indexer walks every slot up to the longest lane: there ``rows`` is
+    the largest ``pos``, for every slot.)"""
+    return min(-(-min(rows, s_max) // chunk) * chunk, s_max)
+
+
+def _walk_rows(body, m, acc, pos, s_max: int, chunk: int, l):
+    """The schedule of the indexed block's decode attention
+    (``_attend_latent_chosen``; the dense caches are read slot by slot by
+    ``ops/decode_attention``): an online softmax (running max ``m``, sum
+    ``l``, accumulator ``acc``) carried through ``state = body(start,
+    attended, state)`` for each chunk of ``chunk`` cache rows up to the
+    longest live sequence, ceil(max(pos) / chunk) iterations: the trip
+    count is data, so one compiled program serves every length.
     ``start`` is the chunk's first row; ``attended()`` gives the mask
     [B, chunk] of the rows a lane attends (row < pos, strict). The last
     chunk of an S_max that chunk does not divide starts early (a slice
@@ -230,14 +256,13 @@ def _walk_rows(body, m, acc, pos, s_max: int, chunk: int, l=None):
 
     bound = jnp.minimum(jnp.max(pos), s_max)
     _, l, acc = lax.fori_loop(
-        0, (bound + chunk - 1) // chunk, walk,
-        (m, jnp.ones_like(m) if l is None else l, acc)
+        0, (bound + chunk - 1) // chunk, walk, (m, l, acc)
     )
     return l, acc
 
 
 def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
-                             chunk: int = DECODE_ATTN_CHUNK):
+                             chunk: Optional[int] = None, schedule=None):
     """q [B,1,H,D] against the UNWRITTEN cache prefix (k_pos < pos,
     strict — the row at ``pos`` may hold stale garbage) plus the fresh
     (k_new, v_new) [B,1,Hkv,D] as one extra logical position. Exactly
@@ -246,105 +271,71 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
     row only feeds LATER steps (``_decode_attn``).
 
     A masked row is not free: it is an HBM read, and the read is all a
-    decode step's attention costs. So the cache is walked in chunks of
-    ``chunk`` rows (``_walk_rows``) with an online softmax in float32
-    (running max, sum and accumulator, seeded by the self position).
-    Within a chunk the per-slot strict mask stays, so this is the same
-    attention over the same rows (bf16 operands, float32 scores and
-    accumulation). A lane at ``pos`` 0 attends itself alone and does not
-    move the bound: that is where the engine parks its free slots.
+    decode step's attention costs. So each slot's rows are read in chunks
+    of ``chunk`` up to the slot's OWN ``pos`` by one Pallas kernel
+    (``ops/decode_attention``: its ``schedule`` of (slot, chunk) visits is
+    made once a step and shared by the layers; made here where none is
+    given) with an online softmax in float32 (running max, sum and
+    accumulator, seeded here by the self position). Within a chunk the
+    strict mask stays, so this is the same attention over the same rows
+    (bf16 operands, float32 scores and accumulation). A lane at ``pos`` 0
+    attends itself alone and reads nothing: that is where the engine
+    parks its free slots.
 
     ck/cv are one layer's [B,S_max,Hkv,D], or with ``layer`` the whole
-    [L,B,S_max,Hkv,D] cache: the chunk is then sliced out of the big
-    buffer INSIDE the loop (a layer sliced outside it would be copied
-    whole, S_max rows, before the loop could read it)."""
+    [L,B,S_max,Hkv,D] cache: the kernel reads a chunk where it lies in
+    the big buffer (a layer sliced out of it would be copied whole)."""
     if layer is None:
         ck, cv, layer = ck[None], cv[None], 0
-    _, B, s_max, h_kv, d = ck.shape
+    h_kv, d = ck.shape[3:]
     n_rep = q.shape[2] // h_kv
-    chunk = min(chunk, s_max)
     scale = d ** -0.5
     f32 = jnp.float32
     m = jnp.einsum(
-        "bqhd,bqhd->bhq", q, repeat_kv(k_new, n_rep),
+        "bqhd,bqhd->bh", q, repeat_kv(k_new, n_rep),
         preferred_element_type=f32,
-    )[..., None] * scale  # [B,H,1,1]: the self position's score
-    acc = repeat_kv(v_new, n_rep).astype(f32).transpose(0, 2, 1, 3)
-
-    def body(start, attended, state):
-        m, l, acc = state
-        at, size = (layer, 0, start, 0, 0), (1, B, chunk, h_kv, d)
-        k = repeat_kv(lax.dynamic_slice(ck, at, size)[0], n_rep)
-        v = repeat_kv(lax.dynamic_slice(cv, at, size)[0], n_rep)
-        s = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k, preferred_element_type=f32
-        ) * scale
-        s = jnp.where(attended()[:, None, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)  # a masked row: exp(-1e30 - m) == 0
-        shrink = jnp.exp(m - m_new)
-        l = shrink * l + p.sum(axis=-1, keepdims=True)
-        acc = shrink * acc + jnp.einsum(
-            "bhqk,bkhd->bhqd", p.astype(q.dtype), v,
-            preferred_element_type=f32,
-        )
-        return m_new, l, acc
-
-    l, acc = _walk_rows(body, m, acc, pos, s_max, chunk)
-    return (acc / l).transpose(0, 2, 1, 3).astype(q.dtype)
+    ) * scale  # [B,H]: the self position's score
+    acc = repeat_kv(v_new, n_rep)[:, 0].astype(f32)
+    out = decode_attention(
+        (q[:, 0],), (ck,), cv, m, acc, pos,
+        schedule or _visits(pos, (ck, cv), chunk), layer=layer, scale=scale)
+    return out[:, None]
 
 
 def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
                                     pos, *, layer, scale: float,
-                                    chunk: int = DECODE_ATTN_CHUNK):
+                                    chunk: Optional[int] = None,
+                                    schedule=None):
     """``_attend_prefix_plus_self`` for a latent cache: ONE key that all
     heads share, in two parts, the latent ``ckv`` [L,B,S_max,R] (which is
     the value as well) and the rotary key ``kr`` [L,B,S_max,rope]. q_lat
     [B,H,R] (the query with W_uk absorbed) and q_rope [B,H,rope] against
     the unwritten prefix plus the token's own (c_new [B,R], r_new
     [B,rope]); scores (q_lat . c + q_rope . r) * scale in float32. The
-    same walk (``_walk_rows``) and the same online softmax; a chunk of
-    latents is read once and serves as key and as value. Returns o_lat
-    [B,H,R], float32 accumulation cast to q's type.
+    same kernel and the same online softmax: to it this is the
+    grouped-query form with one KV head, a key in two parts and no value
+    array; a chunk of latents is read once and serves as key and as
+    value. Returns o_lat [B,H,R], float32 accumulation cast to q's type.
 
-    Two things the chip decided (PR 28, read from its trace). The chunks
-    are the LEFT operand of the scores' products and pass an
-    ``optimization_barrier``: without it the compiler lays the WHOLE cache
-    out rows-minor for the products, a 1.2 GB copy once a layer a step.
-    And the two parts are two arrays because the chip's own layout for an
-    array whose minor dim is 576 (no multiple of 128) is rows-minor too,
-    which cost two copies of the cache per block at the program's edges;
-    512 is a multiple, and the rotary part is a ninth of the bytes."""
-    B, s_max = ckv.shape[1], ckv.shape[2]
-    chunk = min(chunk, s_max)
+    The two parts are two arrays (PR 28, read from the chip's trace)
+    because the chip's own layout for an array whose minor dim is 576 (no
+    multiple of 128) is rows-minor, which cost two copies of the cache
+    per block at the program's edges; 512 is a multiple, and the rotary
+    part is a ninth of the bytes. The 64-wide rotary part lies rows-minor
+    on the chip as well (compiled for a described v5e:
+    ``bf16[8,32,4096,64]{2,3,1,0}``) and a kernel takes its operands
+    row-major, so it is handed over with rows last: there the swap is a
+    bitcast, where the array as it is was copied whole before every
+    call."""
     f32 = jnp.float32
     m = (jnp.einsum("bhd,bd->bh", q_lat, c_new, preferred_element_type=f32)
          + jnp.einsum("bhd,bd->bh", q_rope, r_new,
-                      preferred_element_type=f32))[:, None] * scale
+                      preferred_element_type=f32)) * scale
     acc = jnp.broadcast_to(c_new.astype(f32)[:, None], q_lat.shape)
-
-    def body(start, attended, state):
-        m, l, acc = state  # [B,1,H], [B,1,H], [B,H,R]
-        c, r = lax.optimization_barrier((
-            lax.dynamic_slice(ckv, (layer, 0, start, 0),
-                              (1, B, chunk, ckv.shape[-1]))[0],
-            lax.dynamic_slice(kr, (layer, 0, start, 0),
-                              (1, B, chunk, kr.shape[-1]))[0]))
-        s = (jnp.einsum("bkd,bhd->bkh", c, q_lat, preferred_element_type=f32)
-             + jnp.einsum("bkd,bhd->bkh", r, q_rope,
-                          preferred_element_type=f32)) * scale
-        s = jnp.where(attended()[:, :, None], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # a masked row: exp(-1e30 - m) == 0
-        shrink = jnp.exp(m - m_new)
-        l = shrink * l + p.sum(axis=1, keepdims=True)
-        acc = shrink[:, 0, :, None] * acc + jnp.einsum(
-            "bkh,bkd->bhd", p.astype(q_lat.dtype), c,
-            preferred_element_type=f32)
-        return m_new, l, acc
-
-    l, acc = _walk_rows(body, m, acc, pos, s_max, chunk)
-    return (acc / l[:, 0, :, None]).astype(q_lat.dtype)
+    return decode_attention(
+        (q_lat, q_rope), (ckv, kr.swapaxes(2, 3)), None, m, acc, pos,
+        schedule or _visits(pos, (ckv, kr), chunk), layer=layer,
+        scale=scale, rows_last=(False, True))
 
 
 # ---------------- learned sparse attention over the latent cache ----------------
@@ -669,13 +660,15 @@ def _decode_attn_chosen(cache, li, pos, b_idx, c: TransformerConfig, wp,
     return cached_attn
 
 
-def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig):
+def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig,
+                 schedule=None):
     """One decode layer's ``attn_fn`` for ``c.mixer``: attends the
     cache's prefix plus the token itself WITHOUT a pre-write (see
     _attend_prefix_plus_self) and returns (output, the cache with the
     token's row written at ``pos``): the write only feeds LATER steps, so
     it stays off the attention's critical path. ``b_idx`` is
-    arange(B)."""
+    arange(B); ``schedule`` the step's visits (``slot_schedule``), made
+    by the attention where none is given."""
     if c.mixer == "mla":
         @_latent
         def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
@@ -688,7 +681,7 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig):
             c_new, r_new = c_kv[:, 0], k_r[:, 0, 0]  # [B,R], [B,rope]
             o_lat = _attend_latent_prefix_plus_self(
                 q_lat[:, 0], q_rope[:, 0], ckv, kr, c_new, r_new, pos,
-                layer=li, scale=_mla_scale(c))
+                layer=li, scale=_mla_scale(c), schedule=schedule)
             out = jnp.einsum("bshc,chk->bshk", o_lat[:, None],
                              wp["wuv"].astype(c.dtype))
             return out, {
@@ -700,8 +693,7 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig):
     def cached_attn(q, k, v):
         ck_all, cv_all = cache["k"], cache["v"]
         out = _attend_prefix_plus_self(
-            q, ck_all, cv_all, k, v, pos, layer=li
-        )
+            q, ck_all, cv_all, k, v, pos, layer=li, schedule=schedule)
         ck2 = ck_all.at[li, b_idx, pos].set(
             k[:, 0].astype(ck_all.dtype)
         )
@@ -766,15 +758,17 @@ def _decode_forward_multi(params, token, cache, pos,
     live = (pos > 0)[:, None] if routed else None
     B = token.shape[0]
     b_idx = jnp.arange(B)
-    choice = None  # a block with an indexer: what the layers hand on
-    if c.index_topk:
+    choice = schedule = None
+    if c.index_topk:  # a block with an indexer: what the layers hand on
         choice = {"mask": jnp.zeros((B, cache["ik"].shape[2]), bool),
                   "k": jnp.zeros((B, c.index_head_dim), c.dtype)}
+    else:  # the attention's visits, the same for every layer of the step
+        schedule = _visits(pos, jax.tree.leaves(cache))
     carry = (x, cache, _zero_stats(c), choice)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
             x, cache, total, choice = carry
-            attn = (_decode_attn(cache, li, pos, b_idx, lc)
+            attn = (_decode_attn(cache, li, pos, b_idx, lc, schedule)
                     if choice is None else _decode_attn_chosen(
                         cache, li, pos, b_idx, lc, lp["attn"], choice))
             y, _aux, cache, stats = apply_block(
@@ -832,9 +826,10 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
     batch.
 
     A lane at ``pos`` 0 is PARKED: it stays at 0 (the engine puts a freed
-    slot there), so the attention's row bound, the largest ``pos``,
-    follows the live sequences; a free lane that kept counting would
-    drag it to S_max on an idle engine.
+    slot there), and the decode attention reads nothing of its slot (in a
+    block with an indexer it does not move the walk's bound, the largest
+    ``pos``); a free lane that kept counting would be read to S_max on an
+    idle engine.
 
     Returns (tokens [B, steps], cache, token', pos', counts', stats):
     ``stats`` holds the block's int32 counters named by

@@ -179,7 +179,7 @@ class LLMEngine:
         self.slot_req: List[Optional[_Request]] = [None] * max_slots
         # What ``self.pos`` holds on the device, kept in step on the host
         # (set at admission, advanced at dispatch, 0 for a parked lane):
-        # the decode attention walks the cache up to its largest entry.
+        # the decode attention reads each slot's cache up to its entry.
         self._rows: List[int] = [0] * max_slots
         self.pending: "collections.deque[_Request]" = collections.deque()
         self._pending_first: List = []  # (req, device first-token scalar)
@@ -319,11 +319,13 @@ class LLMEngine:
         - Per dispatched block: ``blocks_by_steps`` ``{"2": n, "8": n}``,
           ``slot_steps`` (live slots x steps), ``capacity_steps``
           (``max_slots`` x steps); ``attn_rows_read``, the cache rows the
-          decode attention walked (per step and slot: whole chunks up to
-          the longest sequence in the batch), and ``attn_rows_capacity``
-          (``max_len`` x ``max_slots`` x steps), what walking the whole
-          cache would have read (rows of whatever the mixer caches: K and
-          V rows, or latent rows).
+          decode attention read (per step and live slot: whole chunks up
+          to the slot's own length, none for a parked slot; a block with
+          an indexer: every slot's chunks up to the longest sequence in
+          the batch), and ``attn_rows_capacity`` (``max_len`` x
+          ``max_slots`` x steps), what reading the whole cache would have
+          read (rows of whatever the mixer caches: K and V rows, or
+          latent rows).
         - Set once, at set-up: ``weights_relaid`` weights moved into the
           physical layout the compiled ``decode_block`` reads them in
           (``generation.lay_out_for_decode``; 0 where the compiler asks
@@ -511,10 +513,14 @@ class LLMEngine:
         self._blocks_by_steps[steps] += 1
         self._n["slot_steps"] += active * steps
         self._n["capacity_steps"] += self.max_slots * steps
-        chunk = decode_attn_chunk(self.config)
-        self._n["attn_rows_read"] += self.max_slots * sum(
-            attn_rows_walked(bound + k, self.max_len, chunk)
-            for k in range(steps))
+        # what the attention reads: each live slot's chunks up to its own
+        # length (a block with an indexer: every slot's, up to the longest)
+        chunk = decode_attn_chunk(self.config, self.max_len)
+        lanes = ([bound] * self.max_slots if self.config.index_topk
+                 else [r for r in self._rows if r])
+        self._n["attn_rows_read"] += sum(
+            attn_rows_walked(r + k, self.max_len, chunk)
+            for r in lanes for k in range(steps))
         self._n["attn_rows_capacity"] += self.max_slots * self.max_len * steps
         # as decode_block leaves pos: a step on, parked lanes stay at 0
         self._rows = [r + steps if r else 0 for r in self._rows]

@@ -35,7 +35,10 @@ import pytest  # noqa: E402
 # It ends waits that Python can interrupt. Not a wait inside a ``__del__``,
 # which swallows the exception, nor one in C; and no hard exit behind it,
 # because under --dist loadfile xdist hands a crashed worker's file to a
-# new worker, which waits out the same test again.
+# new worker, which waits out the same test again. A test that rehearses a
+# whole benchmark cell in a subprocess (a cluster, a replica, a window: a
+# minute alone, several under six workers) asks for its own limit with
+# ``@pytest.mark.phase_limit(seconds)``.
 _PHASE_LIMIT_S = 240
 
 
@@ -43,14 +46,16 @@ def _limited(item, phase):
     if threading.current_thread() is not threading.main_thread():
         yield
         return
+    own = item.get_closest_marker("phase_limit")
+    limit = own.args[0] if own else _PHASE_LIMIT_S
 
     def on_alarm(signum, frame):
         faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
         pytest.fail(f"{item.nodeid}: {phase} still running after "
-                    f"{_PHASE_LIMIT_S} s (tests/conftest.py)")
+                    f"{limit} s (tests/conftest.py)")
 
     old = signal.signal(signal.SIGALRM, on_alarm)
-    signal.setitimer(signal.ITIMER_REAL, _PHASE_LIMIT_S)
+    signal.setitimer(signal.ITIMER_REAL, limit)
     try:
         yield
     finally:
@@ -78,6 +83,11 @@ def pytest_configure(config):
         "markers",
         "slow: long-running scale/chaos tests (deselect with -m 'not slow' "
         "for the fast tier)",
+    )
+    config.addinivalue_line(
+        "markers",
+        "phase_limit(seconds): this test's own limit for each of its "
+        "phases, in place of the 240 s every test gets",
     )
     config.addinivalue_line(
         "markers",

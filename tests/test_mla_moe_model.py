@@ -382,6 +382,7 @@ def test_block_counters_add_up_on_a_scripted_run(params):
     assert s["attn_rows_read"] > 0
 
 
+@pytest.mark.phase_limit(600)  # a minute alone; six workers share the cores
 def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         doc = json.load(f)
@@ -390,9 +391,12 @@ def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
             if cell in m.get("workloads", ())]
     out = subprocess.run(
         [sys.executable, "benchmarks/run.py", "--workload", cell, "--seed",
-         str(2 ** 31 + 7), "--seconds", "6", "--trace", "1",
+         # a window whose second half holds several decode blocks even
+         # with six test workers on the cores: the counters' readers
+         # divide what was retired between its middle and its end
+         str(2 ** 31 + 7), "--seconds", "16", "--trace", "1",
          "--rehearse-cpu"],
-        cwd=ROOT, capture_output=True, text=True, timeout=600,
+        cwd=ROOT, capture_output=True, text=True, timeout=570,
         # the suite's eight virtual host devices are not the cell's one
         env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
     assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]

@@ -231,23 +231,25 @@ def test_flash_transformer_forward_matches_dense():
 
 
 @pytest.mark.parametrize("max_len", [64, 300])
-@pytest.mark.parametrize("n_kv_heads", [None, 2])  # MHA and GQA
+@pytest.mark.parametrize("n_kv_heads", [None, 4])  # MHA and GQA
 def test_kv_cache_generation_matches_full_forward(n_kv_heads, max_len):
     """Greedy decode through the KV cache (``generate``: the engine's two
     programs) must give the tokens that recomputing the full forward pass
     every step gives, in float32: the chunked online softmax is not the
     dense softmax bit for bit, so the tokens are pinned, not the logits.
-    The prompt fills the cache but for 30 rows: of 300 rows the decode
-    attention walks two chunks, and the second starts early because the
-    chunk does not divide the cache."""
-    from ray_tpu.models.generation import DECODE_ATTN_CHUNK, generate
+    The prompt fills the cache but for 30 rows, and the heads are wide
+    enough that the decode attention's kernel reads a slot of 300 rows in
+    several visits (128 rows each for MHA, 256 for GQA), the last of which
+    starts early because the chunk does not divide the cache."""
+    from ray_tpu.models.generation import decode_attn_chunk, generate
 
-    assert max_len < DECODE_ATTN_CHUNK or (
-        max_len - 30 > DECODE_ATTN_CHUNK and max_len % DECODE_ATTN_CHUNK)
     cfg = dataclasses.replace(
-        TransformerConfig.tiny(max_seq_len=max_len), dtype=jnp.float32,
-        n_kv_heads=n_kv_heads,
+        TransformerConfig.tiny(max_seq_len=max_len, n_heads=8, d_head=128),
+        dtype=jnp.float32, n_kv_heads=n_kv_heads,
     )
+    chunk = decode_attn_chunk(cfg, max_len)
+    assert chunk == max_len == 64 or (
+        max_len - 30 > chunk and max_len % chunk)
     params = init_params(cfg, jax.random.key(0))
     prompt = jax.random.randint(
         jax.random.key(1), (2, max_len - 30), 0, cfg.vocab_size)

@@ -332,46 +332,92 @@ def test_engine_stats_lifecycle_and_block_counters():
     assert json.loads(json.dumps(s)) == s
 
 
+def _blocks_fetched(pos, s_max, chunk):
+    """Cache blocks the decode attention's kernel fetches for lanes at
+    ``pos``: its grid's visits in order, a fetch wherever the block a
+    visit names differs from the one before (the pipeline's rule)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.decode_attention import slot_schedule
+
+    sched = slot_schedule(jnp.asarray(pos, jnp.int32), s_max, chunk)
+    n = int(sched.visits)
+    named = list(zip(np.asarray(sched.fetch_slot)[:n].tolist(),
+                     np.asarray(sched.fetch_row)[:n].tolist()))
+    return 1 + sum(a != b for a, b in zip(named, named[1:]))
+
+
 def test_engine_attention_walks_live_rows_only():
-    """Two requests, one after the other, long then short: ``stats()``
-    counts the rows the decode attention walked (whole 256-row chunks up
-    to the longest sequence in the batch, every slot, every step) against
-    the whole cache. The long request's freed lane is parked at pos 0 on
-    the device, so the short one walks one chunk, not two; and the host's
-    copy of ``pos`` equals the device's at every dispatch."""
+    """``stats()`` counts the rows the decode attention read, slot by
+    slot: whole 256-row chunks up to each live slot's OWN length, nothing
+    for a parked slot, against the whole cache. Two requests one after the
+    other (long, then short in the freed lane, which was parked at pos 0
+    on the device): exact counts. Then a short one beside a long one: each
+    counts its own chunks. At every dispatch the host's copy of ``pos``
+    equals the device's, and the host's count equals what the kernel's
+    grid fetches for those positions."""
+    import jax
+
+    from ray_tpu.models.generation import attn_rows_walked, decode_attn_chunk
+    from ray_tpu.models.transformer import TransformerConfig, init_params
     from ray_tpu.serve.llm import LLMEngine
 
-    params, cfg = _tiny_model()
-    eng = LLMEngine(params, cfg, max_slots=2, max_len=640,
-                    prefill_buckets=(16, 256))
+    # 16 heads of 64: a row of K and V weighs 4 KB, a visit reads 256 rows
+    cfg = TransformerConfig.tiny(n_heads=16, d_head=64)
+    assert decode_attn_chunk(cfg, 640) == 256
+    eng = LLMEngine(init_params(cfg, jax.random.key(0)), cfg, max_slots=2,
+                    max_len=640, prefill_buckets=(16, 256))
     seen = []
     dispatch = eng._dispatch_block
 
     def checked_dispatch():
-        seen.append((list(eng._rows), np.asarray(eng.pos).tolist()))
-        return dispatch()
+        rows, pos = list(eng._rows), np.asarray(eng.pos).tolist()
+        out = dispatch()
+        seen.append((rows, pos, max(eng._rows) - max(rows)))
+        return out
+
+    def ask(n, new):
+        out = eng.generate(
+            (np.arange(n) % cfg.vocab_size).astype(np.int32),
+            max_new_tokens=new)
+        assert len(out) == new
 
     eng._dispatch_block = checked_dispatch
     try:
         for n in (255, 8):
-            out = eng.generate(
-                (np.arange(n) % cfg.vocab_size).astype(np.int32),
-                max_new_tokens=4)
-            assert len(out) == 4
+            ask(n, 4)
             s = _settled_stats(eng)
             assert np.asarray(eng.pos).tolist() == [0, 0] == eng._rows
+        # each request: first token from its prefill, then three 2-step
+        # blocks (the third is in flight when the fourth token retires)
+        assert s["steps"] == 12 and s["blocks_by_steps"] == {"2": 6, "8": 0}
+        assert [rows for rows, _, _ in seen] == [
+            [255, 0], [257, 0], [259, 0], [8, 0], [10, 0], [12, 0]]
+        # the long one steps 255 256 | 257 258 | 259 260: one chunk, then
+        # two; the short one stays in its first; the other lane reads 0
+        assert s["attn_rows_read"] == (256 + 256 + 4 * 512) + 6 * 256
+        assert s["attn_rows_capacity"] == 2 * 640 * 12
+        # a short request beside a long one that is still decoding
+        long = threading.Thread(target=ask, args=(250, 40))
+        long.start()
+        while long.is_alive() and not any(eng._rows):
+            time.sleep(0.01)
+        ask(8, 4)
+        long.join()
+        s = _settled_stats(eng)
     finally:
         eng.shutdown()
-    # each request: first token from its prefill, then three 2-step blocks
-    # (the third is in flight when the fourth token retires)
-    assert s["steps"] == 12 and s["blocks_by_steps"] == {"2": 6, "8": 0}
-    assert [rows for rows, _ in seen] == [
-        [255, 0], [257, 0], [259, 0], [8, 0], [10, 0], [12, 0]]
-    assert all(rows == pos for rows, pos in seen)
-    # per step: rows of the chunks up to the bound, x 2 slots. The long
-    # one steps 255 256 | 257 258 | 259 260, the short one stays under 256
-    assert s["attn_rows_read"] == 2 * (256 + 256 + 4 * 512) + 2 * 6 * 256
-    assert s["attn_rows_capacity"] == 2 * 640 * 12
+    assert all(rows == pos for rows, pos, _ in seen)
+    both = [rows for rows, _, _ in seen[6:] if all(rows)]
+    assert any(max(r) > 256 > min(r) for r in both)  # two chunks, and one
+    # the host's count, dispatch by dispatch and step by step, is what the
+    # kernel's grid fetches
+    steps = [[[r + k if r else 0 for r in rows] for k in range(n)]
+             for rows, _, n in seen]
+    assert s["attn_rows_read"] == sum(
+        attn_rows_walked(r, 640, 256) for block in steps for pos in block
+        for r in pos) == 256 * sum(
+        _blocks_fetched(pos, 640, 256) for block in steps for pos in block)
     assert s["attn_rows_read"] <= s["attn_rows_capacity"]
 
 

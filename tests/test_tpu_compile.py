@@ -69,7 +69,7 @@ def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
     has to take the walk over the latent cache, the latent cache has to
     be updated in place, and the program has to leave room on a 16 GB
     chip (ISSUE 28: under 14.5 GiB)."""
-    # here the backend is the CPU, where the kernel would be interpreted
+    # here the backend is the CPU, where the kernels would be interpreted
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from ray_tpu.models import generation as gen
     from ray_tpu.models.transformer import TransformerConfig, init_params
@@ -108,10 +108,34 @@ def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
     assert len(kernels) == 2 and "ragged-dot" not in hlo
     assert not _copies(hlo, "bf16[7,64,")  # the experts stay in the stack
     assert "raytpu.mla.attend" in hlo
+    if program == "decode_block":
+        # ISSUE 33: the latent rows are read by the decode attention's
+        # kernel (one call in the dense layer's stack, one in the routed
+        # layers'), under the scope ``readers/scope_time.py`` looks for;
+        # no chunk is copied into fast memory first, and no cache array
+        # is laid out again (the rotary keys are read rows-minor, as the
+        # chip keeps them)
+        attends = [line for line in hlo.splitlines()
+                   if "tpu_custom_call" in line
+                   and "raytpu.mla.attend" in line]
+        assert len(attends) == 2
+        assert all("decode_attention" in line for line in attends)
+        assert "dynamic-slice_bitcast_fusion" not in hlo
+        assert not _copies(hlo, "bf16[8,32,4096,")
 
 
 @pytest.fixture(scope="module")
-def gptj_served(v5e):
+def as_on_the_chip():
+    """Here the backend is the CPU, where a Pallas kernel would be
+    interpreted: the programs of this file are compiled as the chip runs
+    them."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        yield
+
+
+@pytest.fixture(scope="module")
+def gptj_served(v5e, as_on_the_chip):
     """GPT-J-6B int8 at the benchmark's engine sizes (8 slots x 1,024
     rows), as shapes on the described chip: the weights in the layouts
     the chip hands out, and in the layouts the engine leaves them in
@@ -204,6 +228,12 @@ def test_gptj_serving_programs_copy_no_stacked_weight(
         assert now.memory_analysis().temp_size_in_bytes < 0.1 * 2 ** 30
         assert now.memory_analysis().alias_size_in_bytes >= (
             2 * 28 * 8 * 1024 * 16 * 256 * 2)  # the cache, in place
+        # ISSUE 33: K and V are read by the decode attention's kernel,
+        # where they lie in the stacked cache
+        kernels = [line for line in hlo.splitlines()
+                   if "tpu_custom_call" in line]
+        assert len(kernels) == 1 and "decode_attention" in kernels[0]
+        assert not _copies(hlo, "bf16[28,8,1024,")
     else:
         assert len(_copies(hlo, "s8[")) <= {"128": 0, "1024": 2}[
             program.split("_")[1]]
