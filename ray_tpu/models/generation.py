@@ -15,7 +15,12 @@ contiguous for the scanned stack; GQA caches only kv_heads; latent
 attention caches one latent row a token a layer (``init_kv_cache``: the
 cache is a pytree that the mixer defines, and the engine never looks
 inside it), and a block with an indexer the index key of each layer that
-owns one. Both programs run every block the config can describe.
+owns one. A layer whose mixer is a state-space recurrence keeps no rows
+at all: its slot holds a STATE of fixed size (the recurrent state and the
+convolution's last inputs, the cache's ``"state"`` subtree), which
+``prefill_into_slot`` hands over as it stands after the prompt's last
+real token and ``decode_block`` updates in place once a token. Both
+programs run every block the config can describe.
 Decode is bound by HBM reads, and a masked cache row is read like a live
 one: the mask only discards what was already streamed. So the decode
 attention of both dense caches (``_attend_prefix_plus_self``,
@@ -43,9 +48,12 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     _rms_norm,
     apply_block,
+    embed_tokens,
     layer_groups,
+    lm_logits,
     mla_expand,
     scan_stack,
+    ssm_split,
 )
 from ray_tpu.ops.attention import NEG_INF, causal_attention, repeat_kv
 from ray_tpu.ops.decode_attention import (
@@ -53,6 +61,7 @@ from ray_tpu.ops.decode_attention import (
     decode_attention,
     slot_schedule,
 )
+from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_step
 
 
 def prepare_for_inference(params, config: TransformerConfig):
@@ -154,9 +163,13 @@ def _ckr_rows(c_kv, k_r, width: int):
 
 def init_kv_cache(config: TransformerConfig, batch: int,
                   max_len: int) -> Dict[str, jax.Array]:
-    """The cache is a pytree that the mixer defines; every leaf is
-    [layers that keep it, B, S_max, ...]: what a slot keeps is its rows
-    of every leaf. MHA/GQA: ``k`` and ``v`` of [L, .., Hkv, D]. Latent
+    """The cache is a pytree that the mixers define. Its leaves are of
+    two kinds, told apart by where they sit (``cache_rows``,
+    ``cache_state``): ROW leaves, [layers that keep it, B, S_max, ...],
+    one row a cached token, at the top level; and STATE leaves, [layers
+    that keep it, B, ...] with no S_max axis, under ``"state"``: what a
+    slot keeps whatever its length. A slot is index ``b`` of axis 1 of
+    every leaf. MHA/GQA: ``k`` and ``v`` of [L, .., Hkv, D]. Latent
     attention: the row ``[c_kv | rot(k_r)]`` a token a layer, as ``ckv`` of
     [L, .., kv_lora_rank] (key and value at once) and ``kr`` of
     [L, .., qk_rope_dim] (two arrays: see _attend_latent_prefix_plus_self).
@@ -168,7 +181,22 @@ def init_kv_cache(config: TransformerConfig, batch: int,
     layer), and ``ik`` of [layers that own an indexer, .., index_head_dim]:
     the index key a token, the rows kept for CHOOSING what the layers
     above attend (the choice itself lives one step, in the layer scan's
-    carry)."""
+    carry).
+
+    Heads narrower than a 128-lane (``_kv_row``) lie flat in their row,
+    ``k`` and ``v`` of [L, .., Hkv x D]: the chip lays [.., Hkv, 64] out
+    rows-minor, and the decode attention's kernel, which takes its
+    operands row-major, was handed two copies of the whole cache a block
+    (compiled for a described v5e, PR 35).
+
+    A model with state-space layers (``layer_types``) keeps ``k`` / ``v``
+    for its attention layers alone and, for its "ssm" layers, ``state``:
+    ``ssm`` of [those layers, B, H, P, N] in float32 (rounded to bf16 at
+    every one of thousands of steps it would be another result) and
+    ``conv``, the convolution's last ``ssm_conv - 1`` inputs, of [those
+    layers, B, (ssm_conv - 1) x width] in the compute dtype: the taps side
+    by side in ONE minor axis, a whole number of 128-lanes, because
+    [.., 3, width] would be tiled with its 3 rows padded to 16."""
     c = config
     if c.mixer == "mla" and c.index_topk:
         rows = (batch, max_len)
@@ -180,10 +208,55 @@ def init_kv_cache(config: TransformerConfig, batch: int,
         rows = (c.n_layers, batch, max_len)
         return {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
                 "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}
-    shape = (c.n_layers, batch, max_len, c.kv_heads, c.d_head)
-    return {
+    shape = (c.n_attn_layers, batch, max_len) + _kv_row(c)
+    cache = {
         "k": jnp.zeros(shape, c.dtype),
         "v": jnp.zeros(shape, c.dtype),
+    }
+    if c.n_ssm_layers:
+        slots = (c.n_ssm_layers, batch)
+        cache["state"] = {
+            "ssm": jnp.zeros(slots + (c.ssm_heads, c.ssm_head_dim,
+                                      c.ssm_state), jnp.float32),
+            "conv": jnp.zeros(slots + ((c.ssm_conv - 1) * c.ssm_conv_width,),
+                              c.dtype)}
+    return cache
+
+
+def _kv_row(c: TransformerConfig) -> Tuple[int, ...]:
+    """The shape of one token's key (or value) in a layer of the MHA/GQA
+    cache: (Hkv, D), or flat (Hkv x D,) where D alone is no whole number
+    of 128-lanes and the heads together are."""
+    if c.d_head % 128 and (c.kv_heads * c.d_head) % 128 == 0:
+        return (c.kv_heads * c.d_head,)
+    return (c.kv_heads, c.d_head)
+
+
+def cache_rows(cache: Dict) -> Dict:
+    """The cache's row leaves ([layers, B, S_max, ...]: what grows with a
+    sequence), as a tree."""
+    return {k: v for k, v in cache.items() if k != "state"}
+
+
+def cache_state(cache: Dict) -> Dict:
+    """The cache's state leaves ([layers, B, ...]: what a slot keeps
+    whatever its length), as a tree; empty for most models."""
+    return cache.get("state", {})
+
+
+def slot_footprint(cache: Dict) -> Dict[str, int]:
+    """What one slot costs, from the cache's shapes alone: ``state_bytes``
+    whatever its length, ``row_bytes`` a cached token (over all layers),
+    and ``state_layers``, the layers that keep a state (a decode step
+    reads and writes every slot's state once a state layer)."""
+    state = jax.tree.leaves(cache_state(cache))
+    return {
+        "state_bytes": sum(a.dtype.itemsize * math.prod(a.shape[2:])
+                           * a.shape[0] for a in state),
+        "row_bytes": sum(a.dtype.itemsize * math.prod(a.shape[3:])
+                         * a.shape[0]
+                         for a in jax.tree.leaves(cache_rows(cache))),
+        "state_layers": max((a.shape[0] for a in state), default=0),
     }
 
 
@@ -218,7 +291,7 @@ def decode_attn_chunk(config: TransformerConfig, s_max: int) -> int:
     if config.index_topk:
         return min(DSA_CHUNK, s_max)
     cache = jax.eval_shape(lambda: init_kv_cache(config, 1, s_max))
-    return dense_attn_chunk(jax.tree.leaves(cache))
+    return dense_attn_chunk(jax.tree.leaves(cache_rows(cache)))
 
 
 def attn_rows_walked(rows: int, s_max: int, chunk: int) -> int:
@@ -299,6 +372,36 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
     out = decode_attention(
         (q[:, 0],), (ck,), cv, m, acc, pos,
         schedule or _visits(pos, (ck, cv), chunk), layer=layer, scale=scale)
+    return out[:, None]
+
+
+def _attend_flat_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer,
+                                  schedule=None):
+    """``_attend_prefix_plus_self`` over a cache whose rows hold their KV
+    heads flat, ck / cv [L,B,S_max,Hkv x D] (``_kv_row``). To the kernel
+    this is one key Hkv x D wide that all heads share: a query head's own
+    D numbers sit in its KV head's place and zeros elsewhere, so its score
+    is its own head's; it gathers all Hkv heads' values and keeps its own.
+    The kernel streams the same rows either way, and the rows are all a
+    decode step's attention costs."""
+    B, _, n_heads, d = q.shape
+    h_kv = k_new.shape[2]
+    n_rep = n_heads // h_kv
+    scale = d ** -0.5
+    f32 = jnp.float32
+    own = (jnp.arange(n_heads)[:, None] // n_rep
+           == jnp.arange(h_kv)[None, :])  # [H,Hkv]
+    q_wide = (q[:, 0, :, None, :] * own[None, :, :, None].astype(q.dtype)
+              ).reshape(B, n_heads, h_kv * d)
+    m = jnp.einsum("bqhd,bqhd->bh", q, repeat_kv(k_new, n_rep),
+                   preferred_element_type=f32) * scale
+    acc = jnp.broadcast_to(v_new.reshape(B, 1, h_kv * d).astype(f32),
+                           (B, n_heads, h_kv * d))
+    out = decode_attention(
+        (q_wide,), (ck,), cv, m, acc, pos,
+        schedule or _visits(pos, (ck, cv)), layer=layer, scale=scale)
+    out = jnp.einsum("bhgd,hg->bhd", out.reshape(B, n_heads, h_kv, d),
+                     own.astype(out.dtype))
     return out[:, None]
 
 
@@ -692,17 +795,96 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig,
 
     def cached_attn(q, k, v):
         ck_all, cv_all = cache["k"], cache["v"]
-        out = _attend_prefix_plus_self(
+        attend = (_attend_flat_prefix_plus_self if ck_all.ndim == 4
+                  else _attend_prefix_plus_self)
+        out = attend(
             q, ck_all, cv_all, k, v, pos, layer=li, schedule=schedule)
+        row = ck_all.shape[3:]  # (Hkv, D), or flat
         ck2 = ck_all.at[li, b_idx, pos].set(
-            k[:, 0].astype(ck_all.dtype)
+            k[:, 0].reshape((-1,) + row).astype(ck_all.dtype)
         )
         cv2 = cv_all.at[li, b_idx, pos].set(
-            v[:, 0].astype(cv_all.dtype)
+            v[:, 0].reshape((-1,) + row).astype(cv_all.dtype)
         )
-        return out, {"k": ck2, "v": cv2}
+        return out, {**cache, "k": ck2, "v": cv2}
 
     return cached_attn
+
+
+def _ssm_scan_inputs(xbc, wp, c: TransformerConfig):
+    """The convolved channels as the scan's x, B, C, and A."""
+    x, B, C = ssm_split(jax.nn.silu(xbc), c)
+    return x, B, C, -jnp.exp(wp["a_log"].astype(jnp.float32))
+
+
+def _recurrence(recur):
+    """What a state-space layer's body hands ``apply_block`` where an
+    attention layer hands its ``attn_fn``: nothing to attend with, and
+    the layer's recurrence as ``.recur`` (``transformer._ssm_mixer``)."""
+    def no_attention(*_a):
+        raise TypeError("a state-space layer has no attention")
+
+    no_attention.recur = recur
+    return no_attention
+
+
+def _decode_recur(cache, li, c: TransformerConfig):
+    """One decode layer's ``attn_fn`` for a state-space layer (the
+    counterpart of ``_decode_attn``; ``transformer._ssm_mixer`` calls its
+    ``recur``): every lane's convolution window moves on one token and its
+    state one step (``ops/ssm.ssm_step``), parked lanes too (their slots
+    are overwritten whole by the next prefill). Layer ``li``'s states are
+    taken out of the whole [layers, B, ...] leaves and put back where
+    they were, so the update is in place. Returns (y, the cache)."""
+    def recur(xbc, dt, wp):
+        state = cache_state(cache)
+        k1 = c.ssm_conv - 1
+        with jax.named_scope("raytpu.ssm.conv"):
+            tail = lax.dynamic_index_in_dim(state["conv"], li, 0, False)
+            tail = tail.reshape(tail.shape[0], k1, -1)
+            out = causal_conv(xbc, wp["conv_w"], wp["conv_b"], tail)
+            tail = jnp.concatenate([tail[:, 1:], xbc.astype(tail.dtype)], 1)
+            conv = lax.dynamic_update_index_in_dim(
+                state["conv"], tail.reshape(tail.shape[0], -1), li, 0)
+        with jax.named_scope("raytpu.ssm.update"):
+            x, B, C, A = _ssm_scan_inputs(out, wp, c)
+            y, new = ssm_step(
+                lax.dynamic_index_in_dim(state["ssm"], li, 0, False),
+                x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], wp["d"])
+            ssm = lax.dynamic_update_index_in_dim(state["ssm"], new, li, 0)
+        return y[:, None], {**cache, "state": {"ssm": ssm, "conv": conv}}
+
+    return _recurrence(recur)
+
+
+def _prefill_recur(single, li, prompt_len, c: TransformerConfig):
+    """One prefill layer's ``attn_fn`` for a state-space layer: the
+    convolution and the chunked scan (``ops/ssm.ssm_chunked``) over the
+    padded prompt from an empty state. What the slot is handed is the
+    state AT ``prompt_len`` (the padding's ``dt`` is taken as 0: it
+    neither decays nor adds) and the last ``ssm_conv - 1`` REAL inputs of
+    the convolution (zeros where the prompt is shorter). ``single`` is
+    one slot's cache. Returns (y, single with this layer's state)."""
+    def recur(xbc, dt, wp):
+        state = cache_state(single)
+        k1, S = c.ssm_conv - 1, xbc.shape[1]
+        with jax.named_scope("raytpu.ssm.conv"):
+            out = causal_conv(xbc, wp["conv_w"], wp["conv_b"])
+            at = prompt_len - k1 + jnp.arange(k1)
+            tail = jnp.where((at >= 0)[None, :, None], jnp.take(
+                xbc, jnp.clip(at, 0, S - 1), axis=1), 0)
+            conv = lax.dynamic_update_index_in_dim(
+                state["conv"], tail.reshape(1, -1).astype(
+                    state["conv"].dtype), li, 0)
+        with jax.named_scope("raytpu.ssm.scan"):
+            x, B, C, A = _ssm_scan_inputs(out, wp, c)
+            y, end = ssm_chunked(
+                x, dt, A, B, C, wp["d"], c.ssm_chunk,
+                valid=(jnp.arange(S) < prompt_len)[None])
+            ssm = lax.dynamic_update_index_in_dim(state["ssm"], end, li, 0)
+        return y, {**single, "state": {"ssm": ssm, "conv": conv}}
+
+    return _recurrence(recur)
 
 
 def _add_stats(total, stats):
@@ -752,7 +934,7 @@ def _decode_forward_multi(params, token, cache, pos,
     and each layer writes its token's row. Returns (logits [B,V], cache,
     stats)."""
     c = config
-    x = params["embed"].astype(c.dtype)[token][:, None]  # [B,1,D]
+    x = embed_tokens(params, token, c)[:, None]  # [B,1,D]
     # a parked lane's token picks no expert (only a routed layer asks)
     routed = c.moe_experts and c.moe_impl == "dropless"
     live = (pos > 0)[:, None] if routed else None
@@ -763,14 +945,18 @@ def _decode_forward_multi(params, token, cache, pos,
         choice = {"mask": jnp.zeros((B, cache["ik"].shape[2]), bool),
                   "k": jnp.zeros((B, c.index_head_dim), c.dtype)}
     else:  # the attention's visits, the same for every layer of the step
-        schedule = _visits(pos, jax.tree.leaves(cache))
+        schedule = _visits(pos, jax.tree.leaves(cache_rows(cache)))
     carry = (x, cache, _zero_stats(c), choice)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
             x, cache, total, choice = carry
-            attn = (_decode_attn(cache, li, pos, b_idx, lc, schedule)
-                    if choice is None else _decode_attn_chosen(
-                        cache, li, pos, b_idx, lc, lp["attn"], choice))
+            if "ssm" in lp:
+                attn = _decode_recur(cache, li, lc)
+            elif choice is None:
+                attn = _decode_attn(cache, li, pos, b_idx, lc, schedule)
+            else:
+                attn = _decode_attn_chosen(
+                    cache, li, pos, b_idx, lc, lp["attn"], choice)
             y, _aux, cache, stats = apply_block(
                 x, lp, lc, pos[:, None], attn, token_mask=live)
             if choice is not None:
@@ -782,10 +968,7 @@ def _decode_forward_multi(params, token, cache, pos,
     if c.index_topk:
         stats = _add_stats(stats, _dsa_stats(
             pos, c, cache["ik"].shape[2]))
-    x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
-    return logits[:, 0, :], cache, stats
+    return lm_logits(params, x, c)[:, 0, :], cache, stats
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -868,12 +1051,17 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     the choice, a mask [Sb, Sb], travels from the layer that makes it to
     the layers that share it in the layer scan's carry).
 
+    A state-space layer runs its chunked scan over the padded prompt and
+    the slot's STATE leaves are overwritten whole with the state at
+    ``prompt_len`` (``_prefill_recur``): a reused slot starts from an
+    empty state, whatever the lane did while it was parked.
+
     Returns (last-valid-token logits [V], cache)."""
     c = config
     single = jax.tree.map(lambda a: jnp.zeros_like(a[:, :1]), cache)
-    s_max = jax.tree.leaves(cache)[0].shape[2]
+    s_max = jax.tree.leaves(cache_rows(cache))[0].shape[2]
     S = prompt.shape[1]
-    x = params["embed"].astype(c.dtype)[prompt]
+    x = embed_tokens(params, prompt, c)
     positions = jnp.arange(S)
     kv_valid = (jnp.arange(s_max) < prompt_len)[None]  # [1, S_max]
     # a prompt's padding picks no expert (only a routed layer asks)
@@ -901,14 +1089,32 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
 
         def cached_attn(q, k, v):
             ck_all, cv_all = single["k"], single["v"]
+            if ck_all.ndim == 4:  # the heads lie flat in their row
+                k_rows, v_rows = (x.reshape(x.shape[:2] + (-1,))
+                                  for x in (k, v))
+            else:
+                k_rows, v_rows = k, v
             ck2 = lax.dynamic_update_slice(
-                ck_all, k[None].astype(ck_all.dtype), (li, 0, 0, 0, 0)
+                ck_all, k_rows[None].astype(ck_all.dtype),
+                (li,) + (0,) * (ck_all.ndim - 1)
             )
             cv2 = lax.dynamic_update_slice(
-                cv_all, v[None].astype(cv_all.dtype), (li, 0, 0, 0, 0)
+                cv_all, v_rows[None].astype(cv_all.dtype),
+                (li,) + (0,) * (cv_all.ndim - 1)
             )
+            if c.layer_types:
+                # the prompt alone, as the latent form above: a real token
+                # attends nothing past itself, so no padding and none of
+                # the slot's other S_max - S rows (the form below scores
+                # all S_max rows of the slot: 1 GB of scores a layer at 32
+                # heads x 2,048 x 4,096)
+                return causal_attention(q, k, v), {
+                    **single, "k": ck2, "v": cv2}
             ck = lax.dynamic_index_in_dim(ck2, li, 0, keepdims=False)
             cv = lax.dynamic_index_in_dim(cv2, li, 0, keepdims=False)
+            if ck.ndim == 3:  # flat rows: the heads as an axis again
+                ck, cv = (x.reshape(x.shape[:2] + k.shape[2:])
+                          for x in (ck, cv))
             return _attend_prefill(q, ck, cv, positions, kv_valid), {
                 "k": ck2, "v": cv2
             }
@@ -939,8 +1145,13 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
             x, single, choice = carry
-            attn = (slot_attn(single, li) if choice is None else
-                    _prefill_attn_chosen(single, li, lp["attn"], choice, lc))
+            if "ssm" in lp:
+                attn = _prefill_recur(single, li, prompt_len, lc)
+            elif choice is None:
+                attn = slot_attn(single, li)
+            else:
+                attn = _prefill_attn_chosen(
+                    single, li, lp["attn"], choice, lc)
             y, _aux, single, _stats = apply_block(
                 x, lp, lc, positions, attn, token_mask=real)
             if choice is not None:
@@ -953,6 +1164,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     last = x[0, prompt_len - 1]  # [D] — last REAL token's features
     logits = last @ head.astype(c.dtype)
+    if c.logit_scale != 1.0:
+        logits = logits * c.logit_scale
     return logits, jax.tree.map(
         lambda big, one: lax.dynamic_update_slice(
             big, one, (0, slot) + (0,) * (big.ndim - 2)),

@@ -19,7 +19,11 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   `TransformerConfig.glm47_flash()` is all of them at once; a latent
   block may also own an indexer that picks the cache rows its queries
   attend (`index_topk`, `indexer_types`), and a routed layer may hold a
-  share of its experts (`moe_experts_held`);
+  share of its experts (`moe_experts_held`); and a model may hold layers
+  of two KINDS (`layer_types`): beside the attention layers, layers whose
+  mixer is a state-space recurrence (`ops/ssm.py`: a fixed-size state a
+  head and a short convolution), two stacks of parameters run in the
+  order the list gives;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -38,6 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.ssm import causal_conv, ssm_chunked
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,8 +125,48 @@ class TransformerConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     indexer_types: Tuple[str, ...] = ()
+    # Layers of two kinds in one model (mixer "mha", sequential residual,
+    # dense FFN). layer_types: one entry a layer, "attention" (the mixer
+    # above) or "ssm"; empty = every layer attends. An "ssm" layer's mixer
+    # is a state-space recurrence (ops/ssm.py): [z | xBC | dt] = W_in h of
+    # widths ssm_inner | ssm_conv_width | ssm_heads; xBC through a causal
+    # depthwise convolution of ssm_conv taps and a SiLU, split into x
+    # (ssm_heads x ssm_head_dim), B and C (ssm_groups x ssm_state each);
+    # dt = softplus(dt + dt_bias), A = -exp(a_log) a head; the state
+    # H [ssm_head_dim, ssm_state] a head: H_t = exp(dt A) H_{t-1} + dt x
+    # B^T, y = H C + D x; out = W_out RMSNorm(y * silu(z)). Its parameters
+    # are params["ssm_layers"]; params["layers"] holds the attention layers.
+    layer_types: Tuple[str, ...] = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256  # tokens a chunk of the prefill's scan
+    # Four scalars a block may multiply by (1 / None: the usual forms).
+    # x_0 = embed_scale * E[token]; x += residual_scale * Mixer(..) and
+    # x += residual_scale * FFN(..); logits = logit_scale * (x E^T);
+    # attention scores q . k * attn_scale (None: 1 / sqrt(d_head)).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    attn_scale: Optional[float] = None
 
     def __post_init__(self):
+        if self.layer_types:
+            kinds = tuple(self.layer_types)
+            if len(kinds) != self.n_layers or set(kinds) - {
+                    "attention", "ssm"} or self.mixer != "mha" or (
+                    self.residual != "sequential") or self.moe_experts or (
+                    "ssm" in kinds and (
+                        not self.ssm_heads * self.ssm_head_dim
+                        * self.ssm_state or self.ssm_heads
+                        % self.ssm_groups)):
+                raise ValueError(
+                    "layer_types needs one entry a layer ('attention' | "
+                    "'ssm'), mixer 'mha', a sequential block with a dense "
+                    "FFN, and the ssm_* sizes (heads a multiple of groups)")
+            object.__setattr__(self, "layer_types", kinds)
         if self.index_topk:
             kinds = tuple(self.indexer_types)
             if self.mixer != "mla" or len(kinds) != self.n_layers or (
@@ -152,6 +197,24 @@ class TransformerConfig:
     def n_index_layers(self) -> int:
         return sum(k == "full" for k in self.indexer_types
                    ) if self.index_topk else 0
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return sum(k == "ssm" for k in self.layer_types)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers whose mixer attends: the layers the K/V cache holds."""
+        return self.n_layers - self.n_ssm_layers
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of the convolution: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def n_expert_layers(self) -> int:
@@ -196,7 +259,11 @@ class TransformerConfig:
             self.q_lora_rank * self.index_n_heads * self.index_head_dim
             + d * (self.index_head_dim + self.index_n_heads)
             + 2 * self.index_head_dim)
-        layers = (self.n_layers * (attn + norms) + n_dense * dense_ffn
+        inner, width = self.ssm_inner, self.ssm_conv_width
+        ssm = (d * (inner + width + self.ssm_heads) + inner * d
+               + width * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner)
+        layers = (self.n_attn_layers * attn + self.n_ssm_layers * ssm
+                  + self.n_layers * norms + n_dense * dense_ffn
                   + (self.n_layers - n_dense) * ffn + indexer)
         head = 0 if self.tie_embeddings else d * self.vocab_size
         return self.vocab_size * d + layers + d + head
@@ -289,6 +356,49 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     @staticmethod
+    def granite4_h_micro(**kw) -> "TransformerConfig":
+        """granite-4.0-h-micro (ibm-granite/granite-4.0-h-micro
+        config.json, model_type granitemoehybrid) as published: 40 layers
+        in a period of ten, attention (32 query and 8 KV heads of 64, no
+        positional term, scores x 1/64) at 5, 15, 25, 35 and state-space
+        layers (64 heads of 64, state 128, one group, 4 taps, chunks of
+        256) elsewhere; every layer's gated FFN 8,192 wide; the embedding
+        x 12, each branch x 0.22, the tied head's logits / 8."""
+        base = dict(
+            vocab_size=100352, d_model=2048, n_layers=40, n_heads=32,
+            n_kv_heads=8, d_head=64, d_ff=8192, rotary_dim=0,
+            max_seq_len=131072, residual="sequential", activation="silu",
+            gated_ffn=True, norm_eps=1e-5, tie_embeddings=True,
+            layer_types=tuple("attention" if i % 10 == 5 else "ssm"
+                              for i in range(40)),
+            ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+            ssm_conv=4, ssm_chunk=256, embed_scale=12.0,
+            residual_scale=0.22, logit_scale=1 / 8, attn_scale=1 / 64,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_ssm_hybrid(**kw) -> "TransformerConfig":
+        """The same kind of model at test size (CPU): six layers
+        ``ssm ssm attention ssm ssm attention`` (a period of three, twice),
+        4 state-space heads of 8 over a state of 16, chunks of 8; two KV
+        heads of 64, which lie flat in their cache row as the model's do
+        (``generation._kv_row``)."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=6, n_heads=4, n_kv_heads=2,
+            d_head=64, d_ff=96, rotary_dim=0, max_seq_len=1024,
+            residual="sequential", activation="silu", gated_ffn=True,
+            norm_eps=1e-5, tie_embeddings=True,
+            layer_types=("ssm", "ssm", "attention") * 2,
+            ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=1,
+            ssm_conv=4, ssm_chunk=8, embed_scale=12.0, residual_scale=0.22,
+            logit_scale=1 / 8, attn_scale=1 / 64,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny_mla_moe(**kw) -> "TransformerConfig":
         """The same block at test size (CPU)."""
         base = dict(
@@ -342,9 +452,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
         return {"wg": dense_init(jax.random.fold_in(key, 2), shape, fan_in)
                 } if c.gated_ffn else {}
 
-    def stack(lc: TransformerConfig, L: int, salt: int, first: int) -> Dict:
+    def stack(lc: TransformerConfig, L: int, salt: int, first: int,
+              attends: bool = True) -> Dict:
         """L layers of ``lc``'s block (the model's layers from ``first``
-        on), stacked on a leading axis."""
+        on), stacked on a leading axis; without ``attends`` the norms and
+        the FFN alone (another mixer's weights are the caller's)."""
         kq, kk, kv, ko, kwi, kwo = (
             (k_q, k_k, k_v, k_o, k_wi, k_wo) if not salt else
             [jax.random.fold_in(k, salt) for k in (k_q, k_k, k_v, k_o,
@@ -353,7 +465,9 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
         layers = {"ln1": {"scale": jnp.ones((L, d), pd)}}
         if lc.residual == "sequential":
             layers["ln2"] = {"scale": jnp.ones((L, d), pd)}
-        if lc.mixer == "mla":
+        if not attends:
+            pass
+        elif lc.mixer == "mla":
             h, r_q, r_kv = lc.n_heads, lc.q_lora_rank, lc.kv_lora_rank
             qk = lc.qk_nope_dim + lc.qk_rope_dim
             layers["attn"] = {
@@ -433,13 +547,46 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
             }
         return layers
 
+    def ssm_stack(L: int) -> Dict:
+        """L state-space layers: the block's norms and FFN as ``stack``
+        draws them, and the mixer's own parameters with the initialisers
+        the layer was published with (they set how fast a state forgets:
+        normal values would let it explode or vanish): A = -exp(a_log)
+        with exp(a_log) ~ U(1, 16), dt_bias the inverse softplus of a step
+        log-uniform in 0.001-0.1, D = 1, the convolution U(+-1/sqrt(taps))."""
+        layers = stack(c, L, 11, 0, attends=False)
+        d, inner, width, nh = c.d_model, c.ssm_inner, c.ssm_conv_width, \
+            c.ssm_heads
+        ks = jax.random.split(jax.random.fold_in(k_q, 13), 7)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (L, nh), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+        bound = c.ssm_conv ** -0.5
+        layers["ssm"] = {
+            "wz": dense_init(ks[0], (L, d, inner), d),
+            "wxbc": dense_init(ks[1], (L, d, width), d),
+            "wdt": dense_init(ks[2], (L, d, nh), d),
+            "conv_w": jax.random.uniform(
+                ks[3], (L, c.ssm_conv, width), minval=-bound,
+                maxval=bound).astype(pd),
+            "conv_b": jnp.zeros((L, width), pd),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[4], (L, nh), minval=1.0, maxval=16.0)).astype(pd),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+            "d": jnp.ones((L, nh), pd),
+            "norm": jnp.ones((L, inner), pd),
+            "wo": dense_init(ks[6], (L, inner, d), inner),
+        }
+        return layers
+
     n_dense = c.n_dense_layers if c.moe_experts else 0
     params = {
         "embed": (jax.random.normal(k_emb, (c.vocab_size, c.d_model)) * 0.02
                   ).astype(pd),
-        "layers": stack(c, c.n_layers - n_dense, 0, n_dense),
+        "layers": stack(c, c.n_attn_layers - n_dense, 0, n_dense),
         "final_ln": {"scale": jnp.ones((c.d_model,), pd)},
     }
+    if c.n_ssm_layers:
+        params["ssm_layers"] = ssm_stack(c.n_ssm_layers)
     if n_dense:
         params["dense_layers"] = stack(c.dense_variant(), n_dense, 7, 0)
     if not c.tie_embeddings:
@@ -513,6 +660,22 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
         "layers": stack(config, n_dense, config.n_layers - n_dense),
         "final_ln": {"scale": ("embed",)},
     }
+    if config.n_ssm_layers:
+        ssm = stack(config, 0, config.n_ssm_layers)
+        del ssm["attn"]
+        ssm["ssm"] = {
+            "wz": ("layers", "embed", "mlp"),
+            "wxbc": ("layers", "embed", None),
+            "wdt": ("layers", "embed", None),
+            "conv_w": ("layers", None, None),
+            "conv_b": ("layers", None),
+            "a_log": ("layers", None),
+            "dt_bias": ("layers", None),
+            "d": ("layers", None),
+            "norm": ("layers", "mlp"),
+            "wo": ("layers", "mlp", "embed"),
+        }
+        axes["ssm_layers"] = ssm
     if n_dense:
         axes["dense_layers"] = stack(config.dense_variant(), 0, n_dense)
     if not config.tie_embeddings:
@@ -524,7 +687,13 @@ def layer_groups(params: Dict, config: TransformerConfig):
     """The stacks of layers in the order they run, each as (stacked
     weights, the config of that stack's block, index of its first layer):
     the leading dense layers where the model has them, then the rest. Each
-    stack is one ``lax.scan``."""
+    stack is one ``lax.scan``. A model with layers of two kinds
+    (``config.layer_types``) is ONE group whose "stack" holds both kinds'
+    stacks, ``{"attention": .., "ssm": ..}``: ``scan_stack`` runs them in
+    the listed order."""
+    if config.layer_types:
+        return [({"attention": params["layers"],
+                  "ssm": params["ssm_layers"]}, config, 0)]
     groups = []
     n_dense = config.n_dense_layers if config.moe_experts else 0
     if n_dense:
@@ -554,7 +723,15 @@ def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
     indexers, [own layers, ...], travel whole as well, and each layer is
     told, as scanned scalars in ``lp["attn"]``, whether it owns one
     (``index_own``), which of the stack's it is (``index_local``) and
-    which of the model's choices it attends (``index_slot``)."""
+    which of the model's choices it attends (``index_slot``).
+
+    Layers of two kinds with different parameters (``lc.layer_types``)
+    are two stacks, ``stack[kind]`` (``layer_groups``), run in the listed
+    order by ``_scan_kinds``; ``li`` then counts within the layer's own
+    kind (its place in that kind's stack, and in whatever cache leaf
+    that kind keeps)."""
+    if lc.layer_types:
+        return _scan_kinds(body, carry, stack, lc.layer_types)
     n = stack["ln1"]["scale"].shape[0]
     held, kinds = {}, None
     if lc.moe_experts and lc.moe_impl == "dropless":
@@ -586,6 +763,50 @@ def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
     carry, _ = lax.scan(
         step, carry, (stack, jnp.arange(first, first + n), kinds))
     return carry
+
+
+def _scan_kinds(body, carry, stacks: Dict, kinds: Tuple[str, ...]):
+    """``body(carry, lp, li) -> carry`` over layers whose kind, one of
+    ``stacks``' keys, is listed in ``kinds``; ``lp`` is the layer's slice
+    of its kind's stack and ``li`` its index there. The list is cut into
+    its shortest repeating period (ten layers, four times) and a period
+    into runs of one kind: a ``lax.scan`` over the periods holds one
+    ``lax.scan`` a run (a run of one layer: the body itself), so the
+    program has one body a RUN OF THE PERIOD, not one a layer. Every scan
+    counts indices and the body takes its layer out of the WHOLE stack
+    with a dynamic index, which the compiler fuses into the products that
+    read it: a scan handed a run's slice of a stack as its ``xs`` would
+    copy the slice first (hundreds of MB a run)."""
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1)
+                  if n % p == 0 and kinds == kinds[:p] * (n // p))
+    runs = []  # (kind, layers of that kind before it in the period, length)
+    for i, kind in enumerate(kinds[:period]):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, kinds[:i].count(kind), 1])
+
+    def one_period(carry, rep):
+        for kind, before, length in runs:
+            stack = stacks[kind]
+            base = rep * kinds[:period].count(kind) + before
+
+            def one(carry, j, stack=stack, base=base):
+                li = base + j
+                lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                    a, li, 0, keepdims=False), stack)
+                return body(carry, lp, li), None
+
+            if length == 1:
+                carry, _ = one(carry, 0)
+            else:
+                carry, _ = lax.scan(one, carry, jnp.arange(length))
+        return carry, None
+
+    if n == period:
+        return one_period(carry, 0)[0]
+    return lax.scan(one_period, carry, jnp.arange(n // period))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -663,13 +884,69 @@ def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
     k = jnp.einsum("bsd,dhk->bshk", h, wp["wk"].astype(c.dtype))
     v = jnp.einsum("bsd,dhk->bshk", h, wp["wv"].astype(c.dtype))
-    q, k = _rotary(q, k, c.rotary_dim, positions, c.rope_theta)
+    if c.rotary_dim:  # 0: no positional term at all
+        q, k = _rotary(q, k, c.rotary_dim, positions, c.rope_theta)
+    if c.attn_scale is not None:
+        # every attention (dense, flash, the decode kernel) scales its
+        # scores by 1/sqrt(d_head) itself: the queries carry the rest
+        q = q * (c.attn_scale * c.d_head ** 0.5)
     attn_out = attn_fn(q, k, v)
     extra = None
     if isinstance(attn_out, tuple):
         attn_out, extra = attn_out
     return jnp.einsum("bshk,hkd->bsd", attn_out,
                       wp["wo"].astype(c.dtype)), extra
+
+
+def ssm_split(xbc, c: TransformerConfig):
+    """The convolved channels [..., ssm_conv_width] as x [..., H, P] and
+    B, C [..., G, N]."""
+    inner, gn = c.ssm_inner, c.ssm_groups * c.ssm_state
+    lead = xbc.shape[:-1]
+    return (xbc[..., :inner].reshape(lead + (c.ssm_heads, c.ssm_head_dim)),
+            xbc[..., inner:inner + gn].reshape(
+                lead + (c.ssm_groups, c.ssm_state)),
+            xbc[..., inner + gn:].reshape(lead + (c.ssm_groups, c.ssm_state)))
+
+
+def _ssm_whole_sequence(xbc, dt, wp, c: TransformerConfig):
+    """An "ssm" layer's recurrence over whole sequences from an empty
+    state (the uncached forward): the convolution, then the chunked scan.
+    xbc [B,S,width] before its convolution, dt [B,S,H] after its
+    softplus. Returns (y [B,S,H,P], None)."""
+    with jax.named_scope("raytpu.ssm.conv"):
+        x, B, C = ssm_split(jax.nn.silu(causal_conv(
+            xbc, wp["conv_w"], wp["conv_b"])), c)
+    with jax.named_scope("raytpu.ssm.scan"):
+        y, _state = ssm_chunked(
+            x, dt, -jnp.exp(wp["a_log"].astype(jnp.float32)), B, C,
+            wp["d"], c.ssm_chunk)
+    return y, None
+
+
+def _ssm_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """The state-space mixer of an "ssm" layer (``TransformerConfig.
+    layer_types``; the equations are in the config's comment). The
+    recurrence itself, convolution and scan, is ``attn_fn.recur(xbc, dt,
+    wp) -> (y, extra)`` where the serving paths bring one (they keep the
+    state and the convolution's tail in a slot: ``generation.py``), else
+    the whole sequence from an empty state."""
+    with jax.named_scope("raytpu.ssm.project"):
+        z = jnp.einsum("bsd,df->bsf", h, wp["wz"].astype(c.dtype))
+        xbc = jnp.einsum("bsd,df->bsf", h, wp["wxbc"].astype(c.dtype))
+        dt = jax.nn.softplus(
+            jnp.einsum("bsd,dh->bsh", h, wp["wdt"].astype(c.dtype),
+                       preferred_element_type=jnp.float32)
+            + wp["dt_bias"].astype(jnp.float32))
+    recur = getattr(attn_fn, "recur", None) or partial(
+        _ssm_whole_sequence, c=c)
+    y, extra = recur(xbc, dt, wp)
+    with jax.named_scope("raytpu.ssm.gate"):
+        y = y.reshape(z.shape) * jax.nn.silu(z)
+        y = _rms_norm(y, wp["norm"], c.norm_eps)
+    with jax.named_scope("raytpu.ssm.project"):
+        out = jnp.einsum("bsf,fd->bsd", y, wp["wo"].astype(c.dtype))
+    return out, extra
 
 
 def mla_expand(c_kv, k_r, wp, c: TransformerConfig):
@@ -780,7 +1057,12 @@ def apply_block(
     int32 scalars from a dropless routed layer (else empty)."""
     c = config
     h = _rms_norm(x, lp["ln1"]["scale"], c.norm_eps)
-    a, extra = _MIXERS[c.mixer](h, lp["attn"], c, positions, attn_fn)
+    if "ssm" in lp:  # a layer of the other kind (TransformerConfig.layer_types)
+        a, extra = _ssm_mixer(h, lp["ssm"], c, positions, attn_fn)
+    else:
+        a, extra = _MIXERS[c.mixer](h, lp["attn"], c, positions, attn_fn)
+    if c.residual_scale != 1.0:
+        a = a * c.residual_scale
     if c.residual == "sequential":
         x = x + a
         h = _rms_norm(x, lp["ln2"]["scale"], c.norm_eps)
@@ -807,6 +1089,8 @@ def apply_block(
         )
     else:
         m = _dense_ffn(h, lp["mlp"], c)
+    if c.residual_scale != 1.0:
+        m = m * c.residual_scale
     if c.residual == "sequential":
         return x + m, aux, extra, stats
     return x + a + m, aux, extra, stats
@@ -844,23 +1128,41 @@ def forward(
 ):
     """Returns logits [B, S, vocab] (and the MoE aux loss if return_aux)."""
     c = config
-    x = params["embed"].astype(c.dtype)[tokens]  # [B, S, D]
+    x = embed_tokens(params, tokens, c)  # [B, S, D]
     positions = jnp.arange(tokens.shape[1])
     attn_fn = select_attn_fn(c, mesh)
 
     carry = (x, jnp.zeros((), jnp.float32))
-    for stack, lc, _first in layer_groups(params, c):
+    for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, lc=lc):
             x, aux = carry
             y, a, _ = apply_layer(x, lp, lc, positions, attn_fn, mesh=mesh)
             return (y, aux + a), None
 
-        carry, _ = lax.scan(remat_wrap(layer, c), carry, stack)
+        layer = remat_wrap(layer, c)
+        if lc.layer_types:  # two kinds of layer: scan_stack orders them
+            carry = scan_stack(lambda carry, lp, _li: layer(carry, lp)[0],
+                               carry, stack, lc, first)
+        else:
+            carry, _ = lax.scan(layer, carry, stack)
     x, aux = carry
+    logits = lm_logits(params, x, c)
+    return (logits, aux) if return_aux else logits
+
+
+def embed_tokens(params, tokens, c: TransformerConfig):
+    """Token ids [...] -> the first hidden state [..., D]."""
+    x = params["embed"].astype(c.dtype)[tokens]
+    return x * c.embed_scale if c.embed_scale != 1.0 else x
+
+
+def lm_logits(params, x, c: TransformerConfig):
+    """Hidden states [B, S, D] -> logits [B, S, V]: the final norm and
+    the head (the embedding's transpose where tied)."""
     x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
-    return (logits, aux) if return_aux else logits
+    return logits * c.logit_scale if c.logit_scale != 1.0 else logits
 
 
 def loss_fn(

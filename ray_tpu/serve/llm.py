@@ -3,8 +3,12 @@
 Parity: the reference's Serve LLM path streams responses from replicas
 (``python/ray/serve/_private/replica.py:325``) and batches dynamically
 (``batching.py``); modern serving engines add ITERATION-LEVEL scheduling
-(admit new requests between decode steps over a shared KV cache). This is
-the TPU-shaped version of that design:
+(admit new requests between decode steps over a shared cache). This is
+the TPU-shaped version of that design. The cache is whatever the model's
+mixers keep for a slot (``generation.init_kv_cache``): rows that grow with
+the sequence (K/V rows, latent rows, index keys) and, for layers that
+carry a recurrent state, a state of fixed size; the engine sees slots and
+never looks inside one.
 
 - a FIXED pool of decode slots (static shapes — XLA compiles exactly two
   programs: bucketed prefill-insert and one multi-position decode step);
@@ -47,7 +51,8 @@ _COUNTERS = (
     "requests_finished", "requests_cancelled", "requests_failed",
     "prefill_tokens", "prefill_padded_tokens", "tokens_emitted",
     "slot_steps", "capacity_steps", "attn_rows_read", "attn_rows_capacity",
-    "weights_relaid", "weights_relaid_bytes",
+    "state_slots_updated", "weights_relaid", "weights_relaid_bytes",
+    "slot_state_bytes", "slot_row_bytes",
 )
 _PHASES = (
     "admit_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
@@ -97,10 +102,11 @@ class _Request:
 
 
 class LLMEngine:
-    """Continuous-batching decode engine over one model + one KV cache.
+    """Continuous-batching decode engine over one model + one cache of
+    ``max_slots`` slots.
 
     ``max_slots``: concurrent sequences (the decode batch width).
-    ``max_len``: per-slot KV capacity.
+    ``max_len``: per-slot capacity in cached rows.
     ``prefill_buckets``: prompt pad lengths (one compile each).
     ``eos_id``: generation stops early when the model emits it (None =
     always run to max_new_tokens).
@@ -125,6 +131,7 @@ class LLMEngine:
             init_kv_cache,
             lay_out_for_decode,
             prepare_for_inference,
+            slot_footprint,
         )
 
         self._jax = jax
@@ -199,6 +206,10 @@ class LLMEngine:
             _COUNTERS + block_stat_keys(config), 0)
         self._n["weights_relaid"] = relaid
         self._n["weights_relaid_bytes"] = relaid_bytes
+        foot = slot_footprint(self.cache)
+        self._n["slot_state_bytes"] = foot["state_bytes"]
+        self._n["slot_row_bytes"] = foot["row_bytes"]
+        self._state_layers = foot["state_layers"]
         self._last_block_stats: Dict[str, int] = {}
         self._t: Dict[str, float] = dict.fromkeys(_PHASES, 0.0)
         self._blocks_by_steps: Dict[int, int] = dict.fromkeys(
@@ -325,8 +336,15 @@ class LLMEngine:
           the batch), and ``attn_rows_capacity`` (``max_len`` x
           ``max_slots`` x steps), what reading the whole cache would have
           read (rows of whatever the mixer caches: K and V rows, or
-          latent rows).
-        - Set once, at set-up: ``weights_relaid`` weights moved into the
+          latent rows); ``state_slots_updated``, the slot states a block
+          read and wrote: EVERY slot's, parked or live, once a step and
+          layer that keeps a state (``max_slots`` x state layers x steps;
+          0 for a model whose slots keep rows only), so ``slot_steps`` x
+          state layers over it is the share that belonged to a live lane.
+        - Set once, at set-up, from the cache's shapes: ``slot_state_bytes``
+          what a slot keeps whatever its length (recurrent states; 0 for
+          most models) and ``slot_row_bytes`` what one cached token costs
+          over all layers. ``weights_relaid`` weights moved into the
           physical layout the compiled ``decode_block`` reads them in
           (``generation.lay_out_for_decode``; 0 where the compiler asks
           for the layouts they came in, as on the CPU), and
@@ -522,6 +540,9 @@ class LLMEngine:
             attn_rows_walked(r + k, self.max_len, chunk)
             for r in lanes for k in range(steps))
         self._n["attn_rows_capacity"] += self.max_slots * self.max_len * steps
+        # decode_block steps every lane's state, a parked lane's too
+        self._n["state_slots_updated"] += (
+            self.max_slots * self._state_layers * steps)
         # as decode_block leaves pos: a step on, parked lanes stay at 0
         self._rows = [r + steps if r else 0 for r in self._rows]
         snapshot = list(self.slot_req)  # slot -> req at dispatch

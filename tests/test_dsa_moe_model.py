@@ -415,7 +415,7 @@ def test_the_list_resolves_the_new_cell():
                  "model.prefill_dsa_time_share",
                  "kernel.decode_hbm_share.dsa_moe"):
         assert name in row["per_layer"]
-    assert len(rows) == 6
+    assert len(rows) >= 6  # later PRs add cells
 
 
 def test_new_cell_rehearses_on_the_host_with_every_reader_walked():

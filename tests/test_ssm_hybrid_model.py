@@ -1,0 +1,417 @@
+"""A model of state-space layers and attention layers (granite-4.0-h-micro's
+kind) against its plain reference, at test size on the CPU with seeded
+random weights: the chunked scan against the token-by-token recurrence,
+a padded bucket's state, the engine's two programs through a slot for
+both kinds of layer in one model, slots reused and slots left alone, the
+engine end to end with its new counters, the ablations a comparison must
+refuse, the two copies of the reference, and the existing models' configs
+under the new defaults."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import generation as gen
+from ray_tpu.models import reference_ssm as ref
+from ray_tpu.models.transformer import (
+    TransformerConfig,
+    forward,
+    init_params,
+    param_logical_axes,
+)
+from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_step
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# six layers, ssm ssm attention twice over; chunks of 8 tokens
+CFG = TransformerConfig.tiny_ssm_hybrid(dtype=jnp.float32)
+TOL = 2e-4  # float32 against float32: rounding order only
+
+
+def hp_of(cfg):
+    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
+            "d_head": cfg.d_head, "eps": cfg.norm_eps,
+            "embed_scale": cfg.embed_scale,
+            "residual_scale": cfg.residual_scale,
+            "logit_scale": cfg.logit_scale, "attn_scale": cfg.attn_scale,
+            "layer_types": cfg.layer_types, "ssm_heads": cfg.ssm_heads,
+            "ssm_head_dim": cfg.ssm_head_dim, "ssm_state": cfg.ssm_state,
+            "ssm_groups": cfg.ssm_groups}
+
+
+HP = hp_of(CFG)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(CFG, jax.random.key(0))
+
+
+def tokens_of(n, seed=1):
+    return jax.random.randint(jax.random.key(seed), (n,), 0, CFG.vocab_size)
+
+
+def ref_logits(params, tokens, **kw):
+    with jax.default_matmul_precision("highest"):
+        return ref.forward_logits(params, tokens, HP, **kw)
+
+
+def prefill(params, cache, slot, prompt, bucket):
+    padded = jnp.zeros((1, bucket), jnp.int32).at[0, :len(prompt)].set(prompt)
+    return gen.prefill_into_slot(
+        params, padded, jnp.int32(len(prompt)), jnp.int32(slot), cache, CFG)
+
+
+# -- the recurrence itself -------------------------------------------------
+
+def scan_inputs(seed, b, s, h=4, p=8, g=2, n=16):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    return dict(
+        x=jax.random.normal(ks[0], (b, s, h, p)),
+        dt=jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)) - 2.0),
+        A=-jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.5)),
+        B=jax.random.normal(ks[3], (b, s, g, n)),
+        C=jax.random.normal(ks[4], (b, s, g, n)),
+        D=jax.random.normal(ks[5], (h,)))
+
+
+def token_by_token(a, state0=None):
+    """The recurrence one ``ssm_step`` a token: what ``ssm_chunked`` must
+    equal."""
+    b, s, h, p = a["x"].shape
+    state = (jnp.zeros((b, h, p, a["B"].shape[-1])) if state0 is None
+             else state0)
+    ys = []
+    for t in range(s):
+        y, state = ssm_step(state, a["x"][:, t], a["dt"][:, t], a["A"],
+                            a["B"][:, t], a["C"][:, t], a["D"])
+        ys.append(y)
+    return jnp.stack(ys, 1), state
+
+
+@pytest.mark.parametrize("length", [5, 8, 19, 37])
+@pytest.mark.parametrize("with_state0", [False, True], ids=["empty", "state0"])
+def test_chunked_scan_equals_the_recurrence(length, with_state0):
+    a = scan_inputs(length, 2, length)
+    state0 = (jax.random.normal(jax.random.key(9), (2, 4, 8, 16))
+              if with_state0 else None)
+    y, end = ssm_chunked(**a, chunk=8, state0=state0)
+    want_y, want_end = token_by_token(a, state0)
+    np.testing.assert_allclose(y, want_y, atol=TOL, rtol=TOL)
+    np.testing.assert_allclose(end, want_end, atol=TOL, rtol=TOL)
+
+
+def test_ssm_step_equals_the_references_token():
+    a = scan_inputs(3, 1, 6, g=1)
+    _, state = token_by_token(a)
+    # the reference's own recurrence over the same six tokens, one head's
+    # B and C for all (one group)
+    want = jnp.zeros((4, 8, 16))
+    for t in range(6):
+        want = (jnp.exp(a["dt"][0, t] * a["A"])[:, None, None] * want
+                + (a["dt"][0, t][:, None] * a["x"][0, t])[:, :, None]
+                * a["B"][0, t, 0][None, None, :])
+    np.testing.assert_allclose(state[0], want, atol=TOL, rtol=TOL)
+
+
+def test_a_padded_buckets_end_state_is_the_state_at_prompt_len():
+    a = scan_inputs(4, 1, 32)
+    n = 21
+    valid = (jnp.arange(32) < n)[None]
+    _, end = ssm_chunked(**a, chunk=8, valid=valid)
+    short = {k: (v[:, :n] if v.ndim > 1 else v) for k, v in a.items()}
+    _, want = ssm_chunked(**short, chunk=8)
+    np.testing.assert_allclose(end, want, atol=TOL, rtol=TOL)
+    _, ignored = ssm_chunked(**a, chunk=8)  # the padding taken as tokens
+    assert float(jnp.abs(ignored - want).max()) > 1e-2
+
+
+def test_causal_conv_continues_from_its_tail():
+    x = jax.random.normal(jax.random.key(0), (2, 11, 6))
+    w = jax.random.normal(jax.random.key(1), (4, 6))
+    bias = jax.random.normal(jax.random.key(2), (6,))
+    whole = causal_conv(x, w, bias)
+    rest = causal_conv(x[:, 7:], w, bias, tail=x[:, 4:7])
+    np.testing.assert_allclose(rest, whole[:, 7:], atol=1e-6)
+
+
+# -- the model --------------------------------------------------------------
+
+def test_config_follows_the_published_numbers():
+    c = TransformerConfig.granite4_h_micro()
+    assert c.param_count() == 3_191_396_096  # 3.19 B: ISSUE 35's count
+    assert (c.n_layers, c.n_ssm_layers, c.n_attn_layers) == (40, 36, 4)
+    assert [i for i, k in enumerate(c.layer_types) if k == "attention"] == [
+        5, 15, 25, 35]
+    assert (c.ssm_inner, c.ssm_conv_width) == (4096, 4352)
+    assert (c.embed_scale, c.residual_scale, c.logit_scale,
+            c.attn_scale) == (12.0, 0.22, 0.125, 0.015625)
+    assert c.rotary_dim == 0 and c.tie_embeddings
+    foot = gen.slot_footprint(jax.eval_shape(
+        lambda: gen.init_kv_cache(dataclasses.replace(
+            c, dtype=jnp.bfloat16), 48, 4096)))
+    assert foot == {"state_bytes": 76_437_504, "row_bytes": 8192,
+                    "state_layers": 36}
+
+
+def test_params_axes_and_count_agree(params):
+    assert sum(x.size for x in jax.tree.leaves(params)) == CFG.param_count()
+    axes = param_logical_axes(CFG)
+    shapes = jax.tree.map(lambda a: a.ndim, params)
+    assert jax.tree.map(len, axes, is_leaf=lambda x: isinstance(
+        x, tuple)) == shapes
+    assert params["layers"]["ln1"]["scale"].shape[0] == 2
+    assert params["ssm_layers"]["ln1"]["scale"].shape[0] == 4
+    assert "attn" not in params["ssm_layers"]
+
+
+def test_layer_types_are_checked():
+    with pytest.raises(ValueError):
+        TransformerConfig.tiny_ssm_hybrid(layer_types=("ssm",) * 5)
+    with pytest.raises(ValueError):
+        TransformerConfig.tiny_ssm_hybrid(ssm_state=0)
+
+
+def test_existing_models_are_untouched_by_the_new_defaults():
+    for c in (TransformerConfig.gptj_6b(), TransformerConfig.glm47_flash(8),
+              TransformerConfig.glm52(6, n_dense_layers=1, indexer_types=(
+                  "full",) + ("shared",) * 3 + ("full", "shared")),
+              TransformerConfig.tiny()):
+        assert c.layer_types == () and c.n_ssm_layers == 0
+        assert c.n_attn_layers == c.n_layers
+        assert (c.embed_scale, c.residual_scale, c.logit_scale,
+                c.attn_scale) == (1.0, 1.0, 1.0, None)
+    c = TransformerConfig.gptj_6b()
+    cache = jax.eval_shape(lambda: gen.init_kv_cache(
+        dataclasses.replace(c, dtype=jnp.bfloat16), 8, 1024))
+    assert set(cache) == {"k", "v"} and cache["k"].shape == (
+        28, 8, 1024, 16, 256)
+    assert gen.slot_footprint(cache) == {
+        "state_bytes": 0, "row_bytes": 458_752, "state_layers": 0}
+
+
+def test_the_uncached_forward_matches_the_reference(params):
+    toks = tokens_of(37)
+    got = forward(params, toks[None], CFG)[0]
+    want, _ = ref_logits(params, toks)
+    assert float(ref.vector_distance(got, want)[0]) < TOL
+
+
+@pytest.mark.parametrize("prompt_len,bucket", [(13, 16), (21, 32), (32, 32)])
+def test_prefill_and_decode_through_a_slot_match_the_reference(
+        params, prompt_len, bucket):
+    """Both kinds of layer in one model: a chunked, padded prefill into a
+    slot, then N decode steps through the slot's rows and state, against
+    the reference's full forward over prompt + answer: logits at every
+    position, and every state-space layer's state at the end."""
+    n_new = 11
+    toks = tokens_of(prompt_len + n_new, seed=prompt_len)
+    want, want_states = ref_logits(params, toks)
+    cache = gen.init_kv_cache(CFG, 3, 64)
+    logits, cache = prefill(params, cache, 1, toks[:prompt_len], bucket)
+    assert float(ref.vector_distance(logits, want[prompt_len - 1])[0]) < TOL
+    pos = jnp.array([0, prompt_len, 0], jnp.int32)
+    for t in range(prompt_len, prompt_len + n_new):
+        tok = jnp.array([0, toks[t], 0], jnp.int32)
+        lg, cache = gen.decode_step_multi(params, tok, cache, pos, CFG)
+        assert float(ref.vector_distance(lg[1], want[t])[0]) < TOL
+        pos = pos + (pos > 0)
+    for i, state in enumerate(want_states):
+        got = gen.cache_state(cache)["ssm"][i, 1]
+        assert float(ref.state_distance(got, state)) < TOL
+
+
+def test_prefill_leaves_the_other_slots_bit_identical(params):
+    cache = gen.init_kv_cache(CFG, 3, 64)
+    _, cache = prefill(params, cache, 1, tokens_of(9, 2), 16)
+    before = jax.tree.map(lambda a: np.asarray(a[:, 1]), cache)
+    _, cache = prefill(params, cache, 0, tokens_of(14, 3), 16)
+    _, cache = prefill(params, cache, 2, tokens_of(5, 4), 16)
+    after = jax.tree.map(lambda a: np.asarray(a[:, 1]), cache)
+    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+        assert np.array_equal(a, b)
+    assert float(jnp.abs(gen.cache_state(cache)["ssm"][:, 1]).max()) > 0
+
+
+def test_decode_block_steps_a_parked_lanes_state_too(params):
+    """What ``state_slots_updated`` counts: every slot's state is read
+    and written a step, the parked lane's as well (its slot is
+    overwritten whole by the next prefill)."""
+    cache = gen.init_kv_cache(CFG, 2, 64)
+    _, cache = prefill(params, cache, 0, tokens_of(9, 2), 16)
+    parked = np.asarray(gen.cache_state(cache)["ssm"][:, 1])
+    assert not parked.any()
+    zeros = jnp.zeros(2, jnp.int32)
+    _t, cache, _tok, pos, _c, stats = gen.decode_block(
+        params, cache, jnp.array([3, 5], jnp.int32),
+        jnp.array([9, 0], jnp.int32), jnp.zeros(2), zeros, zeros, CFG, 2)
+    assert pos.tolist() == [11, 0] and stats == {}
+    assert np.asarray(gen.cache_state(cache)["ssm"][:, 1]).any()
+
+
+# -- the engine ---------------------------------------------------------------
+
+def engine_of(params, **kw):
+    from ray_tpu.serve.llm import LLMEngine
+
+    return LLMEngine(
+        jax.tree.map(jnp.array, params), CFG, max_slots=2, max_len=64,
+        prefill_buckets=(16, 32), **kw)
+
+
+def worst_margin(params, prompt, ids):
+    """How far the served tokens' logits lie under the reference's
+    largest, teacher-forced on the served tokens (0: the same tokens)."""
+    seq = jnp.asarray(list(prompt) + list(ids[:-1]), jnp.int32)
+    logits, _ = ref_logits(params, seq)
+    return float(ref.served_token_margin(
+        logits[len(prompt) - 1:], jnp.asarray(ids, jnp.int32)).max())
+
+
+def test_engine_serves_two_requests_admitted_at_different_times(params):
+    from ray_tpu.serve.llm import _END
+
+    eng = engine_of(params)
+    try:
+        a, b = np.asarray(tokens_of(13, 5)), np.asarray(tokens_of(20, 6))
+        first = eng.submit(a, max_new_tokens=12)
+        got_a = [first.out.get(timeout=120)]  # decoding when b arrives
+        got_b = eng.generate(b, max_new_tokens=6)
+        while (item := first.out.get(timeout=120)) is not _END:
+            assert not isinstance(item, BaseException), item
+            got_a.append(item)
+        assert len(got_a) == 12 and len(got_b) == 6
+        assert worst_margin(params, a, got_a) < TOL
+        assert worst_margin(params, b, got_b) < TOL
+        s = eng.stats()
+        assert s["slot_state_bytes"] == 4 * (4 * 8 * 16 * 4 + 3 * 64 * 4)
+        assert s["slot_row_bytes"] == 2 * 2 * 128 * 4
+        # every slot's state a step and state layer, live or parked
+        assert s["state_slots_updated"] == 2 * 4 * s["steps"]
+        assert s["slot_steps"] * 4 <= s["state_slots_updated"]
+        assert s["requests_failed"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(params):
+    eng = engine_of(params)
+    try:
+        p, q = np.asarray(tokens_of(17, 7)), np.asarray(tokens_of(11, 8))
+        eng.generate(p, max_new_tokens=9)  # slot 0, then freed and parked
+        eng.generate(p, max_new_tokens=3)  # parked lanes step meanwhile
+        again = eng.generate(q, max_new_tokens=8)  # a used slot
+    finally:
+        eng.shutdown()
+    fresh = engine_of(params)
+    try:
+        assert again == fresh.generate(q, max_new_tokens=8)
+    finally:
+        fresh.shutdown()
+    assert worst_margin(params, q, again) < TOL
+
+
+# -- what a comparison must refuse -----------------------------------------
+
+@pytest.mark.parametrize("ablate", [
+    {"state_bf16": True}, {"state_at_bucket_end": (21, 32)},
+    {"drop_conv_tail": 21}, {"residual_one": True},
+    {"usual_attn_scale": True},
+], ids=lambda a: next(iter(a)))
+def test_each_ablation_fails_the_comparison(params, ablate):
+    """The served path (prefill of 21 tokens in a bucket of 32, then 12
+    decode steps) equals the reference and differs from each wrong one,
+    in the last logits or in the first state-space layer's state."""
+    n, n_new = 21, 12
+    toks = tokens_of(n + n_new, seed=11)
+    cache = gen.init_kv_cache(CFG, 1, 64)
+    _, cache = prefill(params, cache, 0, toks[:n], 32)
+    pos = jnp.array([n], jnp.int32)
+    for t in range(n, n + n_new):
+        lg, cache = gen.decode_step_multi(
+            params, toks[t][None], cache, pos, CFG)
+        pos = pos + 1
+    state = gen.cache_state(cache)["ssm"][0, 0]
+
+    def distance(**kw):
+        want, states = ref_logits(params, toks, **kw)
+        return max(float(ref.vector_distance(lg[0], want[-1])[1]),
+                   float(ref.state_distance(state, states[0])))
+
+    assert distance() < TOL < 1e-3 < distance(ablate=ablate)
+
+
+def test_reference_copies_are_identical_below_their_headers():
+    marker = "# ---- below this line the two copies are identical ----\n"
+
+    def body(path):
+        with open(os.path.join(ROOT, path)) as f:
+            text = f.read()
+        assert text.count(marker) == 1
+        return text.split(marker)[1]
+
+    mine = body("ray_tpu/models/reference_ssm.py")
+    assert mine == body("benchmarks/reference_ssm.py")
+    for name in ("ray_tpu", "generation", "transformer", "ops."):
+        assert name not in mine  # none of the program's code
+
+
+# -- the benchmark resolves and rehearses the new cell ---------------------
+
+CELL = "serve-granite-agent-saturated"
+
+
+def test_the_list_resolves_the_new_cell():
+    import json
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--list"], cwd=ROOT,
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rows = [json.loads(line) for line in out.stdout.splitlines()]
+    row = next(r for r in rows if r["cell"] == CELL)
+    assert (row["runner"], row["traffic"], row["chips"]) == (
+        "serve_ssm", "agent-saturated", 1)
+    assert row["end_to_end"] == ["tpot_p50_ms", "setup_s"]
+    for name in ("model.ssm_time_share", "model.prefill_ssm_scan_share",
+                 "engine.state_live_share", "kernel.decode_hbm_share.ssm",
+                 "model.decode_step_ms", "device.idle_share.serve",
+                 "engine.kv_read_share"):
+        assert name in row["per_layer"]
+
+
+def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
+    import json
+    import subprocess
+    import sys
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    mine = [m["name"] for m in doc["per_layer"]
+            if CELL in m.get("workloads", ())]
+    out = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
+         str(2 ** 31 + 7), "--seconds", "6", "--trace", "1",
+         "--rehearse-cpu"],
+        cwd=ROOT, capture_output=True, text=True, timeout=900,
+        # the suite's eight virtual host devices are not the cell's one
+        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
+    assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]
+    walked = next(line for line in out.stdout.splitlines()
+                  if line.startswith("readers walked"))
+    values = json.loads(walked.split(": ", 1)[1])
+    assert sorted(values) == sorted(mine)
+    # the rehearsal's engine: 4 slots, 4 state layers (the metric's scale
+    # is the cell's 36), so a whole share reads 100 x 36 / 4
+    share = values["engine.state_live_share"]
+    assert share is not None and 0 < share <= 900
+    note = json.loads(next(line for line in out.stdout.splitlines()
+                           if line.startswith('{"note"')))
+    end = note["note"]["backlog"]["end"]
+    assert end["slot_state_bytes"] > 0 and end["slot_row_bytes"] > 0
+    assert end["state_slots_updated"] == 4 * 4 * end["steps"]

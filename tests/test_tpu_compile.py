@@ -124,6 +124,68 @@ def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert not _copies(hlo, "bf16[8,32,4096,")
 
 
+@pytest.mark.parametrize("program", ["decode_block", "prefill_2048"])
+def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """granite-4.0-h-micro whole (40 layers, every width as published,
+    bf16) at the benchmark's engine sizes: 48 slots x 4,096 rows. The two
+    kinds of layer run as scans that index the WHOLE parameter stacks (no
+    copy of a run's slice of one); the slots' 3.6 GB of float32 state and
+    the K/V rows are updated in place (ISSUE 35: no copy of a state leaf
+    or of a layer's slice of it, and none of the K/V cache, which the
+    chip would lay out rows-minor were its 64-wide heads an axis); the
+    four attention layers' rows are read by the decode attention's
+    kernel; and the program leaves room on a 16 GB chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.granite4_h_micro(param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 48, 4096)))
+    assert cache["k"].shape == (4, 48, 4096, 512)  # the heads lie flat
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if program == "decode_block":
+        low = gen.decode_block.lower(
+            params, cache, arr((48,)), arr((48,)), arr((48,), jnp.float32),
+            arr((48,)), arr((48,)), cfg, 2)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, 2048)), arr(()), arr(()), cache, cfg)
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    foot = gen.slot_footprint(cache)
+    cache_bytes = 48 * (foot["state_bytes"] + 4096 * foot["row_bytes"])
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.5 * 2 ** 30
+    hlo = compiled.as_text()
+    for of in ("f32[36,48,64,", "f32[48,64,64,128", "f32[1,48,64,",
+               "bf16[4,48,4096,", "bf16[36,48,13056",
+               "bf16[36,2048,4", "bf16[36,2048,8192", "bf16[36,8192,",
+               "bf16[100352,"):
+        assert not _copies(hlo, of), of
+    for scope in ("raytpu.ssm.project", "raytpu.ssm.conv", "raytpu.ssm.gate"):
+        assert scope in hlo
+    if program == "decode_block":
+        assert "raytpu.ssm.update" in hlo
+        attends = [line for line in hlo.splitlines()
+                   if "tpu_custom_call" in line and "decode_attention" in line]
+        assert len(attends) == 1  # one attention layer a period
+    else:
+        assert "raytpu.ssm.scan" in hlo
+
+
 @pytest.fixture(scope="module")
 def as_on_the_chip():
     """Here the backend is the CPU, where a Pallas kernel would be
