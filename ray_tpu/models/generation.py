@@ -61,7 +61,7 @@ from ray_tpu.ops.decode_attention import (
     decode_attention,
     slot_schedule,
 )
-from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_step
+from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_update
 
 
 def prepare_for_inference(params, config: TransformerConfig):
@@ -832,10 +832,12 @@ def _decode_recur(cache, li, c: TransformerConfig):
     """One decode layer's ``attn_fn`` for a state-space layer (the
     counterpart of ``_decode_attn``; ``transformer._ssm_mixer`` calls its
     ``recur``): every lane's convolution window moves on one token and its
-    state one step (``ops/ssm.ssm_step``), parked lanes too (their slots
-    are overwritten whole by the next prefill). Layer ``li``'s states are
-    taken out of the whole [layers, B, ...] leaves and put back where
-    they were, so the update is in place. Returns (y, the cache)."""
+    state one step, parked lanes too (their slots are overwritten whole by
+    the next prefill). The states are stepped by ``ops/ssm.ssm_update``,
+    a kernel that takes the WHOLE [layers, B, ...] leaf, aliased, and
+    reads and writes layer ``li``'s tiles where they lie: a state moves
+    once each way and nothing slices a layer out. The window's layer is
+    taken out of its leaf and put back in place. Returns (y, the cache)."""
     def recur(xbc, dt, wp):
         state = cache_state(cache)
         k1 = c.ssm_conv - 1
@@ -848,10 +850,8 @@ def _decode_recur(cache, li, c: TransformerConfig):
                 state["conv"], tail.reshape(tail.shape[0], -1), li, 0)
         with jax.named_scope("raytpu.ssm.update"):
             x, B, C, A = _ssm_scan_inputs(out, wp, c)
-            y, new = ssm_step(
-                lax.dynamic_index_in_dim(state["ssm"], li, 0, False),
-                x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], wp["d"])
-            ssm = lax.dynamic_update_index_in_dim(state["ssm"], new, li, 0)
+            y, ssm = ssm_update(state["ssm"], li, x[:, 0], dt[:, 0], A,
+                                B[:, 0], C[:, 0], wp["d"])
         return y[:, None], {**cache, "state": {"ssm": ssm, "conv": conv}}
 
     return _recurrence(recur)

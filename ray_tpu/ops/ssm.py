@@ -9,26 +9,42 @@ needs:
   chip is fast at); between chunks the state is carried by a ``lax.scan``
   over the chunks. Linear in the sequence, and the same numbers as the
   recurrence in another order of sums.
-- ``ssm_step``: the recurrence once, for a decode step's one token a lane.
+- ``ssm_step``: the recurrence once, for a decode step's one token a lane,
+  in the plain form: what the tests hold the other two to.
+- ``ssm_update``: the same step as the served path runs it, a Pallas TPU
+  kernel over the slots' WHOLE stacked state leaf [layers, B, H, P, N],
+  aliased to its output, with the layer a prefetched scalar: each tile of
+  states is read once, written back to its own place, and ``y`` is formed
+  from the tile while it is in fast memory. XLA's own code for
+  ``ssm_step`` read every new state a second time for ``y`` (PERF.md,
+  PR 36).
 
 Shapes: ``x`` [B, S, H, P] (``ssm_step``: no S), ``dt`` [B, S, H] (after
 its softplus), ``A`` [H] (negative), ``B`` and ``C`` [B, S, G, N] with G
 groups of H / G heads sharing one B and C, ``D`` [H]; a state is
 [B, H, P, N] in float32. Decays, cumulative sums and every accumulation
 are float32; the operands of the large products stay in ``x``'s type
-(bf16 on the chip, float32 in the tests). No Pallas kernel: XLA's own
-fusions (PERF.md has their share of the roofline).
+(bf16 on the chip, float32 in the tests). The chunked scan is XLA's own
+fusions (PERF.md has their share of a prefill).
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+
+# What one grid step of ``ssm_update`` reads of the states (and writes
+# back): the step's fixed cost hides under the tile's DMA, and four tiles
+# (in and out, double-buffered) sit well inside fast memory.
+_TILE_BYTES = 2 ** 20
 
 
 def ssm_chunked(x, dt, A, B, C, D, chunk: int,
@@ -103,8 +119,11 @@ def ssm_chunked(x, dt, A, B, C, D, chunk: int,
 def ssm_step(state, x, dt, A, B, C, D) -> Tuple[jax.Array, jax.Array]:
     """One token a lane: ``state`` [B,H,P,N] float32, ``x`` [B,H,P], ``dt``
     [B,H], ``B`` and ``C`` [B,G,N]. Returns ``(y [B,H,P] in x's type, the
-    new state)``. Elementwise over the state plus one reduction over N: a
-    step reads and writes every lane's state once, which is all it costs."""
+    new state)``. Elementwise over the state plus one reduction over N.
+    The plain form: compiled by XLA it is two fusions, the update and a
+    reduce that reads the new state AGAIN for ``y``, so around a layer
+    taken out of a stacked leaf it moves every state three times; the
+    served path runs ``ssm_update``, which moves it twice."""
     b, h, p = x.shape
     g, n = B.shape[1:]
     r = h // g
@@ -117,6 +136,98 @@ def ssm_step(state, x, dt, A, B, C, D) -> Tuple[jax.Array, jax.Array]:
     y = (new * C.astype(F32)[:, :, None, None, :]).sum(-1)
     y = y + xg * D.astype(F32).reshape(g, r)[:, :, None]
     return y.reshape(b, h, p).astype(x.dtype), new.reshape(b, h, p, n)
+
+
+def ssm_update(states, layer, x, dt, A, B, C, D, *,
+               tile_bytes: int = _TILE_BYTES) -> Tuple[jax.Array, jax.Array]:
+    """``ssm_step`` on layer ``layer`` of the stacked states [layers, B,
+    H, P, N] float32, in place: returns ``(y [B,H,P] in x's type, the
+    whole leaf with that layer's states stepped)``; ``x``, ``dt``, ``A``,
+    ``B``, ``C``, ``D`` as ``ssm_step`` takes them. One Pallas kernel: the
+    leaf is aliased to the output and a tile is indexed by (layer, slots,
+    group, rows) where it lies, so nothing slices a layer out and every
+    other layer's bytes are not touched. The same float32 products and
+    sum as ``ssm_step``.
+
+    A group's heads and their P rows lie flat as row blocks of S = P x
+    (heads a block) rows, 128 where the shapes allow, so that ``dt x``
+    goes in and ``y`` comes out in rows a whole number of lanes wide; a
+    tile is ``tile_bytes`` of whole row blocks of one group, over slots
+    where a slot's group is smaller. The decay of a (slot, head) is a
+    scalar in SMEM; ``dt x`` is turned from lanes to sublanes and ``y``
+    back in the kernel, 1/N of the data each. Off the TPU the kernel runs
+    in the Pallas interpreter, handed the one layer it touches (the
+    interpreter copies every operand whole at every grid step)."""
+    n_slots, h, p = x.shape
+    g, n = B.shape[1:]
+    hg = h // g  # heads a group
+    hs = math.gcd(hg, max(128 // p, 1))  # heads a row block
+    s, qg = hs * p, hg // hs  # rows a block, blocks a group
+    per = max(tile_bytes // (s * n * 4), 1)  # blocks a tile
+    tq = qg if qg <= per else max(per // 8 * 8, 8)
+    tb = min(max(per // tq, 1), n_slots)
+
+    dt = dt.astype(F32)
+    keep = jnp.exp(dt * A.astype(F32))  # [B,H]
+    dtx = (dt[..., None] * x.astype(F32)).reshape(n_slots, g, qg, s)
+    per_group = [a.astype(F32).reshape(n_slots, g, 1, n) for a in (B, C)]
+
+    def kernel(_layer, keep, dtx_ref, b_ref, c_ref, h_ref, y_ref, o_ref):
+        slot0, grp, blk0 = (pl.program_id(0) * tb, pl.program_id(1),
+                            pl.program_id(2) * tq)
+        for b in range(tb):
+            # a tile past the last slot or block: its results are dropped
+            slot = jnp.minimum(slot0 + b, n_slots - 1)
+            for q in range(tq):
+                head = grp * hg + jnp.minimum(blk0 + q, qg - 1) * hs
+                kept = jnp.concatenate(
+                    [keep[slot * h + head + e] * h_ref[b, q, e * p:(e + 1) * p]
+                     for e in range(hs)], 0)  # [S, N]
+                new = kept + dtx_ref[b, q:q + 1].reshape(s, 1) * b_ref[b]
+                o_ref[b, q] = new
+                y_ref[b, q:q + 1] = (new * c_ref[b]).sum(-1).reshape(1, s)
+
+    interpret = jax.default_backend() != "tpu"
+    stack, at = states, layer
+    if interpret:
+        stack, at = lax.dynamic_index_in_dim(states, layer, 0), 0
+    n_layers = stack.shape[0]
+
+    def tile(i, grp, j, layer, keep):
+        return layer[0], i, grp, j, 0, 0
+
+    def rows(i, grp, j, layer, keep):
+        return i, grp, j, 0
+
+    def group(i, grp, j, layer, keep):
+        return i, grp, 0, 0
+
+    state_spec = pl.BlockSpec((None, tb, None, tq, s, n), tile)
+    rows_spec = pl.BlockSpec((tb, None, tq, s), rows)
+    group_spec = pl.BlockSpec((tb, None, 1, n), group)
+    y, new = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((n_slots, g, qg, s), F32),
+                   jax.ShapeDtypeStruct((n_layers, n_slots, g, qg, s, n),
+                                        F32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(n_slots, tb), g, pl.cdiv(qg, tq)),
+            in_specs=[rows_spec, group_spec, group_spec, state_spec],
+            out_specs=[rows_spec, state_spec],
+        ),
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3),
+        interpret=interpret,
+        name="ssm_update",
+    )(jnp.asarray(at, jnp.int32).reshape(1), keep.reshape(-1), dtx,
+      *per_group, stack.reshape(n_layers, n_slots, g, qg, s, n))
+    new = new.reshape(stack.shape)
+    if interpret:
+        new = lax.dynamic_update_index_in_dim(states, new[0], layer, 0)
+    y = y.reshape(n_slots, h, p) + x.astype(F32) * D.astype(F32)[:, None]
+    return y.astype(x.dtype), new
 
 
 def causal_conv(x, w, bias, tail: Optional[jax.Array] = None):

@@ -23,7 +23,7 @@ from ray_tpu.models.transformer import (
     init_params,
     param_logical_axes,
 )
-from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_step
+from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_step, ssm_update
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # six layers, ssm ssm attention twice over; chunks of 8 tokens
@@ -115,6 +115,37 @@ def test_ssm_step_equals_the_references_token():
                 + (a["dt"][0, t][:, None] * a["x"][0, t])[:, :, None]
                 * a["B"][0, t, 0][None, None, :])
     np.testing.assert_allclose(state[0], want, atol=TOL, rtol=TOL)
+
+
+# slots, heads, head dim, groups, state, layers, the layer stepped, bytes a tile
+@pytest.mark.parametrize("b,h,p,g,n,layers,layer,tile", [
+    (4, 4, 8, 1, 16, 3, 1, 2 ** 20),  # one group, one tile
+    (4, 4, 8, 2, 16, 3, 2, 2 ** 20),  # two groups, two heads a row block
+    (5, 24, 8, 3, 16, 2, 0, 2 * 8 * 8 * 16 * 4),  # 2 slots a tile, of 5
+    (3, 320, 8, 1, 16, 2, 1, 8 * 128 * 16 * 4),  # 8 row blocks a tile, of 20
+    (2, 3, 16, 3, 8, 4, 3, 2 ** 20),  # a head a group, a block
+], ids=["one_group", "two_groups", "slots_undivided", "heads_undivided",
+        "head_a_group"])
+def test_the_update_kernel_equals_the_step_on_one_layer_in_place(
+        b, h, p, g, n, layers, layer, tile):
+    """``ssm_update`` (the served path's kernel, here in the Pallas
+    interpreter) against ``ssm_step``, the plain form: the stepped
+    layer's states and ``y`` to rounding, every OTHER layer's states bit
+    for bit, and a lane whose ``dt`` is 0 keeps its state exactly."""
+    a = scan_inputs(layer + 7, b, 1, h=h, p=p, g=g, n=n)
+    a = {k: (v[:, 0] if v.ndim > 1 else v) for k, v in a.items()}
+    a["dt"] = a["dt"].at[0].set(0.0)  # slot 0: nothing decays, nothing adds
+    states = jax.random.normal(jax.random.key(11), (layers, b, h, p, n))
+    want_y, want = ssm_step(states[layer], **a)
+    y, new = jax.jit(ssm_update, static_argnames="tile_bytes")(
+        states, jnp.int32(layer), **a, tile_bytes=tile)
+    assert new.shape == states.shape and new.dtype == jnp.float32
+    np.testing.assert_allclose(new[layer], want, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(y, want_y, atol=1e-4, rtol=1e-5)
+    others = np.arange(layers) != layer
+    np.testing.assert_array_equal(new[others], states[others])
+    np.testing.assert_array_equal(new[layer, 0], states[layer, 0])
+    assert float(jnp.abs(new[layer, 1] - states[layer, 1]).max()) > 1e-2
 
 
 def test_a_padded_buckets_end_state_is_the_state_at_prompt_len():

@@ -10,6 +10,7 @@ compile that passes is not a chip run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -182,6 +183,23 @@ def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         attends = [line for line in hlo.splitlines()
                    if "tpu_custom_call" in line and "decode_attention" in line]
         assert len(attends) == 1  # one attention layer a period
+        # ISSUE 36: every state moves once each way. The two runs of
+        # state-space layers in the period of ten each step their states
+        # with ``ops/ssm.ssm_update`` on the whole leaf, under the scope
+        # ``readers/scope_time.py`` looks for, and nothing else reads a
+        # layer's states: a fused computation that read them would have
+        # the leaf, a layer of it or the kernel's view of either as a
+        # parameter, and the only such parameter is the program's own
+        # argument, so no second reader forms ``y``
+        kernels = [line for line in hlo.splitlines()
+                   if "tpu_custom_call" in line and "ssm_update" in line]
+        assert len(kernels) == 2
+        assert all("raytpu.ssm.update" in line for line in kernels)
+        assert all("f32[36,48,1,32,128,128]" in line for line in kernels)
+        readers = re.findall(
+            r"(%\S+) = f32\[(?:36,|1,)?48,(?:64,64|1,32,128),128\]\S* "
+            r"parameter\(", hlo)
+        assert len(readers) == 1 and readers[0].startswith("%cache"), readers
     else:
         assert "raytpu.ssm.scan" in hlo
 
