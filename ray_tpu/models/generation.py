@@ -138,15 +138,12 @@ def lay_out_for_decode(params, config: TransformerConfig, slots: int,
     moves = [f.layout is not None and f.layout != x.format.layout
              for x, f in zip(leaves, asked)]
     nbytes = sum(x.nbytes for x, move in zip(leaves, moves) if move)
-    with jax.profiler.TraceAnnotation(
-            "raytpu.engine.layout", weights_relaid=sum(moves),
-            weights_relaid_bytes=nbytes):
-        # a move waits, so that the next leaf's copy is allocated after
-        # this leaf's donated buffer is free again
-        placed = [
-            jax.block_until_ready(jax.device_put(x, f, donate=True))
-            if move else jax.device_put(x, x.sharding)
-            for x, f, move in zip(leaves, asked, moves)]
+    # a move waits, so that the next leaf's copy is allocated after this
+    # leaf's donated buffer is free again
+    placed = [
+        jax.block_until_ready(jax.device_put(x, f, donate=True))
+        if move else jax.device_put(x, x.sharding)
+        for x, f, move in zip(leaves, asked, moves)]
     return treedef.unflatten(placed), sum(moves), nbytes
 
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
 import itertools
 import queue
 import threading
@@ -53,11 +54,17 @@ _COUNTERS = (
     "slot_steps", "capacity_steps", "attn_rows_read", "attn_rows_capacity",
     "state_slots_updated", "weights_relaid", "weights_relaid_bytes",
     "slot_state_bytes", "slot_row_bytes",
+    "blocks_chained", "block_interval_steps", "block_interval_clean_steps",
+    "decode_gap_tokens",
 )
 _PHASES = (
-    "admit_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
+    "admit_s", "admit_stage_s", "admit_launch_s", "admit_first_s",
+    "admit_lanes_s", "dispatch_s", "firsts_sync_s", "firsts_emit_s",
     "block_sync_s", "block_emit_s", "idle_wait_s", "loop_s",
 )
+# Seconds between events rather than inside a phase: the lanes' cadence
+# (block to block) and the requests' token gaps (first token to last).
+_INTERVALS = ("block_interval_s", "block_interval_clean_s", "decode_gap_s")
 
 
 class _Histogram:
@@ -139,8 +146,12 @@ class LLMEngine:
         # Host spans in the profiler's own trace (same file and clock as
         # the device plane; a no-op while no trace runs). Rule for cost:
         # a span's arguments are integers the loop already holds or counts
-        # in O(max_slots); no span takes a lock or reads the device; one
-        # span per phase per iteration.
+        # in O(max_slots); no span or counter takes a lock, reads the
+        # device or allocates per token; one span per phase per iteration
+        # and four more per admission (``_stretch``); the clock is read at
+        # the phases' edges and four times per admission, never per token,
+        # and the cadence counters reuse the block's one read after its
+        # ``device_get``.
         self._span = jax.profiler.TraceAnnotation
         params, config = prepare_for_inference(params, config)
         self.config = config
@@ -210,8 +221,15 @@ class LLMEngine:
         self._n["slot_state_bytes"] = foot["state_bytes"]
         self._n["slot_row_bytes"] = foot["row_bytes"]
         self._state_layers = foot["state_layers"]
-        self._last_block_stats: Dict[str, int] = {}
-        self._t: Dict[str, float] = dict.fromkeys(_PHASES, 0.0)
+        self._t: Dict[str, float] = dict.fromkeys(_PHASES + _INTERVALS, 0.0)
+        self._block_seq = 0  # blocks dispatched since the engine started
+        # perf_counter() when the last block's device_get returned; None
+        # while no block has been retired since the engine last idled
+        self._t_block: Optional[float] = None
+        # fetches to come that an admission has put off the device's own
+        # cadence (see _retire_block)
+        self._unsettled = 0
+        self._mark = 0.0  # where the admission's current stretch began
         self._blocks_by_steps: Dict[int, int] = dict.fromkeys(
             (self.burst_block_steps, self.block_steps), 0)
         self._hist: Dict[str, _Histogram] = {
@@ -326,7 +344,45 @@ class LLMEngine:
           ``firsts_sync_s`` and ``block_sync_s`` (blocked on the device),
           ``firsts_emit_s``, ``block_emit_s``, ``idle_wait_s`` (nothing to
           do), and ``loop_s``, the sum of whole iterations: what no phase
-          covers is the difference.
+          covers is the difference. Of ``admit_s``, per admission, from
+          the pop to the last dispatch: ``admit_stage_s`` (the prompt
+          padded and handed to the device with the scalar arguments),
+          ``admit_launch_s`` (the ``prefill_into_slot`` call, which
+          donates the cache the block in flight still writes),
+          ``admit_first_s`` (the first token's sample: a ``device_put``
+          and a jitted call), ``admit_lanes_s`` (the slot's entries set in
+          the five per-slot vectors). Their sum is at most ``admit_s``:
+          the rest is the lock and the scan for a free slot.
+        - The lanes' cadence, per retired block: ``block_interval_s`` from
+          the instant the previous block's tokens reached the host to the
+          instant this block's did, and ``block_interval_steps`` those
+          blocks' steps, over the ``blocks_chained`` blocks that have a
+          predecessor since the engine last idled (the first block after
+          an idle spell has no interval, so no interval holds a wait for
+          work). An interval is the block's device time plus whatever the
+          device ran or waited for between the two blocks plus however
+          late the host came to fetch: seconds over steps is the wall
+          time a token of every live lane takes. The sums telescope: a
+          late fetch lengthens one interval and shortens the next.
+          ``block_interval_clean_s`` / ``_clean_steps``: the same over the
+          intervals whose two fetches both stood where a block ended on
+          the device: no request admitted in the interval's iteration nor
+          in the two before it. That split does NOT telescope, which is
+          why it leaves three intervals out for an admission: a block's
+          tokens are held behind the first tokens' sync of the same
+          iteration, which waits out the prefill AND the block dispatched
+          just before the sync (that interval is the one the clients
+          feel); the next fetch finds its block already done, a block's
+          time early; the third starts from that catch-up fetch. All
+          minus clean, per step, is what admission costs each token.
+        - The token gap, per request that ended (ran out, EOS or
+          cancelled) with two tokens or more: ``decode_gap_s`` from its
+          first token's emission to the instant its last token's block
+          reached the host, ``decode_gap_tokens`` its tokens after the
+          first. Seconds over tokens is the mean gap, weighted by tokens;
+          a client's median over requests of 16 tokens or more
+          (``tpot_p50_ms``) has on top the path from the request's queue
+          through the replica's ``stream`` to the handle.
         - Per dispatched block: ``blocks_by_steps`` ``{"2": n, "8": n}``,
           ``slot_steps`` (live slots x steps), ``capacity_steps``
           (``max_slots`` x steps); ``attn_rows_read``, the cache rows the
@@ -419,7 +475,7 @@ class LLMEngine:
                 if req.cancelled:
                     self._n["requests_cancelled"] += 1
                     continue
-                req.t_admit = time.perf_counter()
+                req.t_admit = self._mark = time.perf_counter()
                 n = len(req.prompt)
                 bucket = self._bucket_for(n)
                 self._n["requests_admitted"] += 1
@@ -429,22 +485,43 @@ class LLMEngine:
                     (req.t_admit - req.t_submit) * 1e3)
                 with self._span("raytpu.engine.prefill", rid=req.rid,
                                 tokens=n, bucket=bucket, slot=free):
-                    padded = np.zeros((1, bucket), np.int32)
-                    padded[0, :n] = req.prompt
-                    logits, self.cache = prefill_into_slot(
-                        self.params, jnp.asarray(padded), jnp.int32(n),
-                        jnp.int32(free), self.cache, self.config,
-                    )
-                    first = self._first_token(
-                        logits, req.temperature, req.seed)
-                    self.tok = self.tok.at[free].set(first)
-                    self.pos = self.pos.at[free].set(n)
-                    self.temps = self.temps.at[free].set(req.temperature)
-                    self.seeds = self.seeds.at[free].set(req.seed)
-                    self.counts = self.counts.at[free].set(1)
+                    with self._stretch("stage"):
+                        padded = np.zeros((1, bucket), np.int32)
+                        padded[0, :n] = req.prompt
+                        args = (jnp.asarray(padded), jnp.int32(n),
+                                jnp.int32(free))
+                    with self._stretch("launch"):
+                        logits, self.cache = prefill_into_slot(
+                            self.params, *args, self.cache, self.config)
+                    with self._stretch("first"):
+                        first = self._first_token(
+                            logits, req.temperature, req.seed)
+                    with self._stretch("lanes"):
+                        self.tok = self.tok.at[free].set(first)
+                        self.pos = self.pos.at[free].set(n)
+                        self.temps = self.temps.at[free].set(
+                            req.temperature)
+                        self.seeds = self.seeds.at[free].set(req.seed)
+                        self.counts = self.counts.at[free].set(1)
                 self.slot_req[free] = req
                 self._rows[free] = n
                 self._pending_first.append((req, first))
+                # its first token is retired in this same iteration: the
+                # block interval that ends next holds the admission
+                self._unsettled = 3
+
+    @contextlib.contextmanager
+    def _stretch(self, name: str):
+        """One stretch of an admission, from where the last one ended
+        (``self._mark``; the first begins at ``t_admit``) to one clock
+        read at its end: the child span ``raytpu.engine.prefill.<name>``
+        and the same seconds in ``admit_<name>_s``, from one place so that
+        the two cannot drift."""
+        with self._span("raytpu.engine.prefill." + name):
+            yield
+            now = time.perf_counter()
+        self._t["admit_" + name + "_s"] += now - self._mark
+        self._mark = now
 
     def _first_token(self, logits, temperature, seed):
         """On-device first-token sample (scalar int32, not synced)."""
@@ -487,15 +564,18 @@ class LLMEngine:
             req.finished = True
             self._n["requests_finished" if complete
                     else "requests_cancelled"] += 1
+            if req.produced > 1:  # its last token came with a block
+                self._t["decode_gap_s"] += self._t_block - req.t_first
+                self._n["decode_gap_tokens"] += req.produced - 1
             req.out.put(_END)
         return done
 
     def _dispatch_block(self):
         """Launch one K-step compiled decode block (async); returns the
-        device token array, a snapshot of which request owned each slot at
-        dispatch time, and the not-yet-emitted first tokens of requests
-        admitted since the previous dispatch. K adapts to load (see
-        __init__): light load -> short blocks -> short admission waits."""
+        device token array with the model's counters, a snapshot of which
+        request owned each slot at dispatch time, and the block's ordinal
+        since the engine started. K adapts to load (see __init__): light
+        load -> short blocks -> short admission waits."""
         from ray_tpu.models.generation import (
             attn_rows_walked,
             decode_attn_chunk,
@@ -511,15 +591,16 @@ class LLMEngine:
             else self.burst_block_steps
         )
         bound = max(self._rows)  # the block's first step walks up to here
+        seq = self._block_seq
+        self._block_seq += 1
+        # seq is on the block's retire_block span too: on the device's
+        # line the n-th decode_block execution after a span with seq k is
+        # block k + n, whatever the two clocks' offset
         with self._span(
             "raytpu.engine.dispatch", steps=steps, live=active,
             kv_rows=sum(len(r.prompt) + r.produced for r in live),
             firsts=len(self._pending_first), pending=len(self.pending),
-            bound=bound,
-            # of the last block retired (the one in flight is not read)
-            experts_touched=self._last_block_stats.get(
-                "moe_experts_touched", 0),
-            selected=self._last_block_stats.get("dsa_rows_selected", 0),
+            bound=bound, seq=seq,
         ):
             toks, self.cache, self.tok, self.pos, self.counts, stats = (
                 decode_block(
@@ -546,13 +627,18 @@ class LLMEngine:
         # as decode_block leaves pos: a step on, parked lanes stay at 0
         self._rows = [r + steps if r else 0 for r in self._rows]
         snapshot = list(self.slot_req)  # slot -> req at dispatch
-        return (toks, stats), snapshot
+        return (toks, stats), snapshot, seq
 
     def _retire_firsts(self):
         """Emit admitted requests' first tokens. Called right after the
-        next block is dispatched: the firsts were computed BEFORE it in
-        program order, so this sync waits only on the prefills — the block
-        keeps the device busy underneath (async dispatch)."""
+        next block is dispatched, so that the device has work queued while
+        the host waits. The first tokens themselves were computed BEFORE
+        that block in program order, but the stack fetched here is
+        dispatched after it, and the device runs programs in order: the
+        sync returns when the block has finished, not when the prefills
+        have (kept traces, PERF.md section 5). Every first token waits one
+        block more than it needs, and the loop comes out a block behind
+        the device."""
         firsts, self._pending_first = self._pending_first, []
         if not firsts:
             return
@@ -569,21 +655,38 @@ class LLMEngine:
             self._t["firsts_sync_s"] += t1 - t0
             self._t["firsts_emit_s"] += time.perf_counter() - t1
 
-    def _retire_block(self, block_dev, snapshot):
+    def _retire_block(self, block_dev, snapshot, seq):
         """Host-sync one block's tokens (and the model's counters, which
         left the device with them) and deliver them in step order."""
+        steps = block_dev[0].shape[1]
         with self._span(
-            "raytpu.engine.retire_block", steps=block_dev[0].shape[1],
+            "raytpu.engine.retire_block", steps=steps, seq=seq,
             live=sum(r is not None and not r.finished for r in snapshot),
         ):
             t0 = time.perf_counter()
             # [B, K] — THE one sync per block
             toks, stats = self._jax.device_get(block_dev)
             t1 = time.perf_counter()
-            self._last_block_stats = {k: int(v) for k, v in stats.items()}
-            for k, v in self._last_block_stats.items():
-                self._n[k] += v
-            for k in range(toks.shape[1]):
+            # the lanes' cadence: from the last block's tokens to these.
+            # An admission unsettles three fetches: its own iteration's
+            # comes after the first tokens' sync, which waits out the
+            # block dispatched just before it (their stack is queued
+            # behind that block), so it is a block late; the next finds
+            # its block done already; the one after starts from that
+            # catch-up fetch, a dispatch after its block began.
+            last, self._t_block = self._t_block, t1
+            settled = not self._unsettled
+            self._unsettled = max(0, self._unsettled - 1)
+            if last is not None:
+                self._n["blocks_chained"] += 1
+                self._n["block_interval_steps"] += steps
+                self._t["block_interval_s"] += t1 - last
+                if settled:
+                    self._n["block_interval_clean_steps"] += steps
+                    self._t["block_interval_clean_s"] += t1 - last
+            for k, v in stats.items():
+                self._n[k] += int(v)
+            for k in range(steps):
                 for slot, req in enumerate(snapshot):
                     if req is None or req.finished:
                         continue
@@ -623,6 +726,8 @@ class LLMEngine:
                     self._retire_firsts()  # sync waits on prefills only
                 while len(inflight) > (1 if active else 0):
                     self._retire_block(*inflight.popleft())
+                if not active:
+                    self._t_block = None  # the next block starts a chain
                 if not active and not self.pending and not inflight:
                     t2 = clock()
                     with self._span("raytpu.engine.idle"):

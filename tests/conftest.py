@@ -97,6 +97,47 @@ def pytest_configure(config):
 
 
 @pytest.fixture
+def rehearsal_manifest(tmp_path):
+    """``make(traffic, rate_rps)`` -> a ``--manifest`` for ``benchmarks/
+    run.py --rehearse-cpu``: the repository's benchmark, file for file
+    (links), but for one traffic mix's arrival rate. A cell offers what
+    its chip serves. The host serves that alone, and a fraction of it with
+    six test workers on its cores: the engine then falls behind, the
+    backlog is still there when the window ends (a request that waits in
+    ``pending`` is not even cancelled before it has been prefilled), and
+    the rehearsal fails on ``none_failed`` or on its drain, whatever the
+    code under test does. A rehearsal walks control flow: it offers a
+    rate that a loaded host serves too."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def link_all(src, dst, but=()):
+        os.makedirs(dst)
+        for name in os.listdir(src):
+            if name not in but:
+                os.symlink(os.path.join(src, name), os.path.join(dst, name))
+
+    def make(traffic, rate_rps):
+        bench, mix_file = os.path.join(root, "benchmarks"), traffic + ".json"
+        copy = tmp_path / "benchmarks"
+        link_all(bench, str(copy), but=("traffic",))
+        link_all(os.path.join(bench, "traffic"), str(copy / "traffic"),
+                 but=(mix_file,))
+        with open(os.path.join(bench, "traffic", mix_file)) as f:
+            mix = json.load(f)
+        assert rate_rps < mix["rate_rps"]
+        mix["rate_rps"] = rate_rps
+        with open(copy / "traffic" / mix_file, "w") as f:
+            json.dump(mix, f)
+        os.symlink(os.path.join(root, "BENCHMARK.json"),
+                   tmp_path / "BENCHMARK.json")
+        return str(tmp_path / "BENCHMARK.json")
+
+    return make
+
+
+@pytest.fixture
 def tmp_store(tmp_path):
     from ray_tpu._private.object_store import SharedMemoryStore
 

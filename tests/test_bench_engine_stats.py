@@ -1,6 +1,6 @@
 """The benchmark's readers of ``LLMEngine.stats()`` snapshots
 (``benchmarks/readers/engine_stats.py``) on hand-made snapshots, and the
-six per-layer metrics that name them. No JAX in this process."""
+per-layer metrics that name them. No JAX in this process."""
 
 import json
 import os
@@ -20,16 +20,19 @@ percentile = common.READERS["stats_delta_hist_percentile"]
 ratio = common.READERS["stats_delta_ratio"]
 
 BOUNDS = [1.0, 10.0, 100.0, 1000.0]  # five buckets: <1, 1-10, ..., >=1000
+CADENCE = ["engine.step_interval_ms", "engine.clean_step_interval_ms",
+           "engine.tpot_mean_ms"]
+ADMIT = ["engine.admit_ms_per_request", "engine.admit_after_launch_share"]
 NEW_METRICS = {
     "serve-chat-steady": [
         "engine.queue_wait_p50_ms", "engine.queue_wait_p90_ms.chat",
         "engine.admit_to_first_p50_ms", "engine.admit_to_first_p90_ms.chat",
-        "engine.prefill_pad_share", "engine.kv_read_share"],
+        "engine.prefill_pad_share", "engine.kv_read_share"] + CADENCE + ADMIT,
     "serve-doc-burst": [
         "engine.queue_wait_p50_ms", "engine.admit_to_first_p50_ms",
-        "engine.prefill_pad_share"],
+        "engine.prefill_pad_share"] + ADMIT,
     "serve-chat-saturated": ["engine.loop_host_share",
-                             "engine.kv_read_share"],
+                             "engine.kv_read_share"] + CADENCE,
     "train4-gptj-seq2048": [],
 }
 
@@ -96,6 +99,64 @@ def test_ratio_of_summed_differences_with_subtracted_keys():
     assert ratio(facts, {"num": ["real", "nope"], "den": ["padded"]}) is None
 
 
+def _metric(name):
+    with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["name"] == name
+    return common.READERS[spec["reader"]], spec["params"]
+
+
+# (mid, end) of each key a metric reads, and what it makes of them
+@pytest.mark.parametrize("name,scalars,want", [
+    ("engine.step_interval_ms",
+     {"block_interval_s": (2.0, 14.5), "block_interval_steps": (100, 1100)},
+     12.5),
+    ("engine.clean_step_interval_ms",
+     {"block_interval_clean_s": (1.0, 6.7),
+      "block_interval_clean_steps": (80, 680)}, 9.5),
+    ("engine.tpot_mean_ms",
+     {"decode_gap_s": (10.0, 59.0), "decode_gap_tokens": (1000, 5000)},
+     12.25),
+    ("engine.admit_ms_per_request",
+     {"admit_s": (3.0, 6.08), "requests_admitted": (100, 210)}, 28.0),
+    ("engine.admit_after_launch_share",
+     {"admit_first_s": (0.5, 0.94), "admit_lanes_s": (1.0, 2.1),
+      "admit_s": (3.0, 6.08)}, 50.0),
+])
+def test_cadence_and_admission_metrics_on_hand_made_snapshots(
+        name, scalars, want):
+    read, params = _metric(name)
+    assert read(_facts([0] * 5, [0] * 5, **scalars), params) == (
+        pytest.approx(want))
+    # nothing of the divisor's between the two snapshots: no number
+    den = params["den"][0]
+    still = dict(scalars, **{den: (scalars[den][1],) * 2})
+    assert read(_facts([0] * 5, [0] * 5, **still), params) is None
+    # a program without the counters: the metric is left out
+    old = {k: v for k, v in scalars.items() if k != params["num"][0]}
+    assert read(_facts([0] * 5, [0] * 5, **old), params) is None
+
+
+def test_each_engine_metric_lists_only_cells_that_report_what_it_moves():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    cells = [w["name"] for w in doc["workloads"]]
+    reports = {m["name"]: set(m.get("workloads", cells))
+               for m in doc["end_to_end"]}
+    mine = [m for m in doc["per_layer"] if m["name"] in CADENCE + ADMIT]
+    assert [m["name"] for m in mine] == CADENCE + ADMIT
+    assert doc["per_layer"][-len(mine):] == mine  # appended, nothing moved
+    for m in mine:
+        assert set(m["workloads"]) <= reports[m["moves"]], m["name"]
+        assert (m["layer"], m["source"]) == (
+            "serving engine", "program_counter")
+        assert _metric(m["name"])[0] is ratio
+    on = {m["name"]: m["workloads"] for m in mine}
+    assert all(on[n] == on[CADENCE[0]] and len(on[n]) == 4 for n in CADENCE)
+    assert all(on[n] == on[ADMIT[0]] and len(on[n]) == 3 for n in ADMIT)
+
+
 def test_the_engine_stats_metrics_resolve_in_their_cells():
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
@@ -108,21 +169,29 @@ def test_the_engine_stats_metrics_resolve_in_their_cells():
         assert got == names, cell
 
 
-def test_cpu_rehearsal_walks_the_new_readers():
+@pytest.mark.phase_limit(600)  # half a minute alone
+def test_cpu_rehearsal_walks_the_new_readers(rehearsal_manifest):
     """The whole control flow on the host at rehearsal sizes: the engine's
     counters reach the readers through the runner's two snapshots. Exit
-    code 10: never a result."""
+    code 10: never a result. A third of the cell's 4.55 requests/s for
+    twice the seconds: as many requests in the window, at a rate the host
+    serves with the suite's other workers on its cores."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
+         "--manifest", rehearsal_manifest("chat-steady", 1.5),
          "--workload", "serve-chat-steady", "--seed", "2147483999",
-         "--seconds", "6", "--trace", "1", "--rehearse-cpu"],
-        capture_output=True, text=True, timeout=600, env=env)
+         "--seconds", "12", "--trace", "1", "--rehearse-cpu"],
+        capture_output=True, text=True, timeout=570, env=env)
     assert out.returncode == common.REHEARSAL_RC, out.stdout[-3000:]
     walked = next(json.loads(ln.split(": ", 1)[1])
                   for ln in out.stdout.splitlines()
                   if ln.startswith("readers walked on the host"))
-    for name in NEW_METRICS["serve-chat-steady"]:
+    # the cadence and admission metrics are walked too; whether they find
+    # a block, an ended request or an admission in the window's second
+    # half is the host's pace, and no assertion hangs on it
+    assert set(NEW_METRICS["serve-chat-steady"]) <= set(walked)
+    for name in set(NEW_METRICS["serve-chat-steady"]) - set(CADENCE + ADMIT):
         assert isinstance(walked[name], float), (name, walked[name])
     assert 0.0 <= walked["engine.prefill_pad_share"] < 100.0
     assert 0.0 < walked["engine.kv_read_share"] <= 100.0

@@ -332,6 +332,99 @@ def test_engine_stats_lifecycle_and_block_counters():
     assert json.loads(json.dumps(s)) == s
 
 
+_CADENCE_KEYS = (
+    "blocks_chained", "block_interval_s", "block_interval_steps",
+    "block_interval_clean_s", "block_interval_clean_steps",
+    "decode_gap_s", "decode_gap_tokens",
+    "admit_stage_s", "admit_launch_s", "admit_first_s", "admit_lanes_s")
+
+
+def _drain(req, timeout_s=120):
+    """Every token of a submitted request, as its consumer would see."""
+    out = []
+    while isinstance(tok := req.out.get(timeout=timeout_s), int):
+        out.append(tok)
+    return out
+
+
+def test_engine_counts_cadence_token_gaps_and_admission_stretches():
+    """Overlapping requests over two slots, one of a single token and one
+    cancelled mid-decode: the token gap counts every token after the first
+    of each request that ended with two or more, the block intervals stay
+    inside what was dispatched, the clean ones inside all, and the four
+    stretches of an admission inside ``admit_s``."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=2, max_len=64,
+                    prefill_buckets=(16, 32))
+    try:
+        s0 = eng.stats()
+        assert all(s0[k] == 0 for k in _CADENCE_KEYS), s0
+        prompt = np.arange(1, 9, dtype=np.int32)
+        reqs = [eng.submit(prompt, max_new_tokens=n)
+                for n in (12, 1, 7, 20, 2)]
+        gone = eng.submit(prompt, max_new_tokens=40)
+        for _ in range(3):
+            assert isinstance(gone.out.get(timeout=120), int)
+        gone.cancelled = True  # its consumer went away after three tokens
+        outs = [_drain(r) for r in reqs]
+        s = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    assert [len(o) for o in outs] == [12, 1, 7, 20, 2]
+    assert 3 <= gone.produced < 40 and gone.finished
+    ended = reqs + [gone]
+    assert s["requests_finished"] == 5 and s["requests_cancelled"] == 1
+    assert s["decode_gap_tokens"] == sum(
+        r.produced - 1 for r in ended if r.produced >= 2)
+    assert s["decode_gap_s"] > 0.0
+    blocks = sum(s["blocks_by_steps"].values())
+    assert 0 < s["blocks_chained"] < blocks
+    assert 0 < s["block_interval_steps"] <= s["steps"]
+    assert 0.0 < s["block_interval_s"] <= s["loop_s"]
+    # an interval without an admission is an interval
+    assert 0 <= s["block_interval_clean_steps"] <= s["block_interval_steps"]
+    assert 0.0 <= s["block_interval_clean_s"] <= s["block_interval_s"]
+    # six admissions through two slots: some interval held one
+    assert s["block_interval_clean_steps"] < s["block_interval_steps"]
+    stretches = [s[k] for k in ("admit_stage_s", "admit_launch_s",
+                                "admit_first_s", "admit_lanes_s")]
+    assert all(x > 0.0 for x in stretches), stretches
+    assert sum(stretches) <= s["admit_s"]
+    assert json.loads(json.dumps(s)) == s
+
+
+def test_engine_block_chain_breaks_when_the_engine_idles():
+    """One request, an idle spell, a second request: the first block after
+    each start has no predecessor, so no interval holds the wait for work:
+    their sum stays under the time the engine was live."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=2, max_len=64,
+                    prefill_buckets=(16,))
+    try:
+        prompt = np.arange(1, 9, dtype=np.int32)
+        live = []
+        for _ in range(2):
+            t0 = time.monotonic()
+            assert len(eng.generate(prompt, max_new_tokens=12)) == 12
+            s = _settled_stats(eng)
+            live.append(time.monotonic() - t0)
+            idle = s["idle_wait_s"]
+            while eng.stats()["idle_wait_s"] == idle:  # the loop idles
+                time.sleep(0.01)
+            time.sleep(1.0 + 2 * live[0])  # longer than the work took
+    finally:
+        eng.shutdown()
+    blocks = sum(s["blocks_by_steps"].values())
+    assert blocks >= 6
+    assert 0 < s["blocks_chained"] <= blocks - 2
+    assert s["block_interval_steps"] <= s["steps"] - 2 * 2
+    assert 0.0 < s["block_interval_s"] < sum(live)
+
+
 def _blocks_fetched(pos, s_max, chunk):
     """Cache blocks the decode attention's kernel fetches for lanes at
     ``pos``: its grid's visits in order, a fetch wherever the block a
@@ -566,27 +659,36 @@ for plane in ProfileData.from_file(path).planes:
         for e in line.events:
             if e.name.startswith("raytpu.engine."):
                 events.append({"name": e.name, "line": line.name,
+                               "start": e.start_ns, "end": e.end_ns,
                                "stats": {k: v for k, v in e.stats}})
 print(json.dumps({"rids": [r.rid for r in reqs], "events": events}))
 """
 
 
-def test_engine_spans_land_in_the_profilers_trace(tmp_path):
-    """A short host trace (Python tracer off) around two requests holds
-    ``raytpu.engine.prefill`` spans with the two rids and
-    ``raytpu.engine.dispatch`` spans with their block's length. Runs in a
-    process of its own, under its own time limit."""
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """A short host trace (Python tracer off) around two requests, taken
+    in a process of its own, under its own time limit: the rids and the
+    ``raytpu.engine.*`` events by name."""
     import subprocess
     import sys
 
     out = subprocess.run(
-        [sys.executable, "-c", _TRACE_SCRIPT, str(tmp_path)],
+        [sys.executable, "-c", _TRACE_SCRIPT,
+         str(tmp_path_factory.mktemp("trace"))],
         capture_output=True, text=True, timeout=240)
     assert out.returncode == 0, out.stderr[-2000:]
     got = json.loads(out.stdout.strip().splitlines()[-1])
     by_name = {}
     for e in got["events"]:
         by_name.setdefault(e["name"], []).append(e)
+    return got, by_name
+
+
+def test_engine_spans_land_in_the_profilers_trace(traced):
+    """The trace holds ``raytpu.engine.prefill`` spans with the two rids
+    and ``raytpu.engine.dispatch`` spans with their block's length."""
+    got, by_name = traced
     prefills = by_name["raytpu.engine.prefill"]
     assert sorted(int(e["stats"]["rid"]) for e in prefills) == sorted(
         got["rids"])
@@ -607,3 +709,38 @@ def test_engine_spans_land_in_the_profilers_trace(tmp_path):
     assert by_name["raytpu.engine.admit"]
     # one thread writes them all: the engine's
     assert len({e["line"] for e in got["events"]}) == 1
+
+
+def test_engine_spans_number_the_blocks_and_split_the_admission(traced):
+    """``seq`` rises by one a block and is the same on a block's dispatch
+    and on its retire_block (which comes later); each prefill span holds
+    its four stretches, in order, one after another."""
+    _got, by_name = traced
+    dispatched = sorted(by_name["raytpu.engine.dispatch"],
+                        key=lambda e: e["start"])
+    seqs = [int(e["stats"]["seq"]) for e in dispatched]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs))) and seqs
+    retired = {int(e["stats"]["seq"]): e
+               for e in by_name["raytpu.engine.retire_block"]}
+    assert len(retired) == len(by_name["raytpu.engine.retire_block"])
+    assert set(retired) - set(seqs) <= {seqs[0] - 1}  # in flight at the start
+    for d in dispatched:
+        r = retired.get(int(d["stats"]["seq"]))
+        if r is not None:  # the last block may be retired after the trace
+            assert r["start"] >= d["end"]
+            assert int(r["stats"]["steps"]) == int(d["stats"]["steps"])
+    # two 8-step blocks carry the 11 later tokens; the trace stops while
+    # the second is being retired
+    assert any(int(d["stats"]["seq"]) in retired for d in dispatched)
+    kinds = ("stage", "launch", "first", "lanes")
+    children = {k: sorted(by_name["raytpu.engine.prefill." + k],
+                          key=lambda e: e["start"]) for k in kinds}
+    prefills = sorted(by_name["raytpu.engine.prefill"],
+                      key=lambda e: e["start"])
+    assert len(prefills) == 2
+    for i, p in enumerate(prefills):
+        inner = [children[k][i] for k in kinds]
+        assert all(len(children[k]) == len(prefills) for k in kinds)
+        edges = [p["start"]] + [t for e in inner
+                                for t in (e["start"], e["end"])] + [p["end"]]
+        assert edges == sorted(edges), (p, inner)
