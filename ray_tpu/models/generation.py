@@ -3,7 +3,9 @@
 The inference half of the flagship model. ONE cached forward, as two
 compiled programs with static shapes and no per-token Python:
 ``prefill_into_slot`` runs one prompt through the stack and writes its
-rows into one slot of a shared batch cache, and ``decode_block`` runs
+rows into one slot of a shared batch cache (and, handed the engine's
+lanes, samples the first token and fills the slot's lane entries: one
+program an admission), and ``decode_block`` runs
 ``steps`` decode iterations for every slot at per-slot positions with
 on-device sampling. The serving engine (``serve/llm.py``) schedules
 requests over them; ``generate`` is their straight-line use (row ``b`` in
@@ -1030,9 +1032,10 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
     return toks.T, cache, token, pos, counts, stats
 
 
-@partial(jax.jit, static_argnames=("config",), donate_argnums=(4,))
+@partial(jax.jit, static_argnames=("config",), donate_argnums=(4, 6))
 def prefill_into_slot(params, prompt, prompt_len, slot, cache,
-                      config: TransformerConfig):
+                      config: TransformerConfig, lanes=None,
+                      temperature=None, seed=None):
     """Run ONE padded prompt [1, Sb] and write its rows into ``slot`` of
     the shared batch cache (static shapes: Sb is a bucket size; compile
     count = number of buckets). Positions past prompt_len write junk rows
@@ -1053,7 +1056,17 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     ``prompt_len`` (``_prefill_recur``): a reused slot starts from an
     empty state, whatever the lane did while it was parked.
 
-    Returns (last-valid-token logits [V], cache)."""
+    Returns (last-valid-token logits [V], cache).
+
+    Given ``lanes``, the engine's five per-slot vectors (token, pos,
+    temps, seeds, counts: ``decode_block``'s arguments, donated here with
+    the cache), and the request's ``temperature`` and ``seed``, the same
+    program is a whole admission: it samples the first token from those
+    logits (``_sample_vec``, one row, count 0), writes the slot's entry of
+    each vector (the token, ``prompt_len``, the temperature, the seed, 1)
+    and returns (first token, cache, lanes). Hand every scalar over as a
+    numpy value of one dtype (``np.int32``, ``np.float32``): a Python
+    number is weakly typed, which is another program."""
     c = config
     single = jax.tree.map(lambda a: jnp.zeros_like(a[:, :1]), cache)
     s_max = jax.tree.leaves(cache_rows(cache))[0].shape[2]
@@ -1163,10 +1176,17 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     logits = last @ head.astype(c.dtype)
     if c.logit_scale != 1.0:
         logits = logits * c.logit_scale
-    return logits, jax.tree.map(
+    cache = jax.tree.map(
         lambda big, one: lax.dynamic_update_slice(
             big, one, (0, slot) + (0,) * (big.ndim - 2)),
         cache, single)
+    if lanes is None:
+        return logits, cache
+    first = _sample_vec(logits[None], temperature[None], seed[None],
+                        jnp.zeros(1, jnp.int32))[0]
+    lanes = tuple(lane.at[slot].set(v) for lane, v in zip(
+        lanes, (first, prompt_len, temperature, seed, 1)))
+    return first, cache, lanes
 
 
 def generate(

@@ -10,13 +10,18 @@ the sequence (K/V rows, latent rows, index keys) and, for layers that
 carry a recurrent state, a state of fixed size; the engine sees slots and
 never looks inside one.
 
-- a FIXED pool of decode slots (static shapes — XLA compiles exactly two
-  programs: bucketed prefill-insert and one multi-position decode step);
-- the engine thread loops: admit pending requests into free slots
-  (per-slot prefill writes straight into the shared cache), run ONE decode
-  step for all active slots, ship each slot's token to its consumer;
-- a request arriving mid-decode waits one step + its prefill, not a whole
-  batch completion — that is the TTFT property the BASELINE north star
+- a FIXED pool of decode slots (static shapes: XLA compiles one
+  admission program a prefill bucket and one decode block a block length);
+- the engine thread loops: admit pending requests into free slots (ONE
+  program an admission, ``generation.prefill_into_slot`` with the lanes:
+  it writes the prompt's rows straight into the shared cache, samples the
+  first token and fills the slot's lane entries), dispatch ONE decode
+  block for all active slots, emit each admitted request's first token as
+  its own prefill ends, then ship the previous block's tokens to their
+  consumers (one block stays in flight);
+- a request arriving mid-decode waits for the block in flight and its own
+  prefill, not for a batch to complete nor for the block dispatched
+  behind its prefill: that is the TTFT property the BASELINE north star
   (Llama-class p50 TTFT) asks for;
 - finished slots free immediately and the next pending request takes the
   slot on the following iteration (continuous, not batch-synchronous).
@@ -55,7 +60,7 @@ _COUNTERS = (
     "state_slots_updated", "weights_relaid", "weights_relaid_bytes",
     "slot_state_bytes", "slot_row_bytes",
     "blocks_chained", "block_interval_steps", "block_interval_clean_steps",
-    "decode_gap_tokens",
+    "decode_gap_tokens", "firsts_ahead",
 )
 _PHASES = (
     "admit_s", "admit_stage_s", "admit_launch_s", "admit_first_s",
@@ -149,9 +154,9 @@ class LLMEngine:
         # in O(max_slots); no span or counter takes a lock, reads the
         # device or allocates per token; one span per phase per iteration
         # and four more per admission (``_stretch``); the clock is read at
-        # the phases' edges and four times per admission, never per token,
-        # and the cadence counters reuse the block's one read after its
-        # ``device_get``.
+        # the phases' edges, four times per admission and twice per first
+        # token, never per decoded token, and the cadence counters reuse
+        # the block's one read after its ``device_get``.
         self._span = jax.profiler.TraceAnnotation
         params, config = prepare_for_inference(params, config)
         self.config = config
@@ -200,8 +205,11 @@ class LLMEngine:
         # the decode attention reads each slot's cache up to its entry.
         self._rows: List[int] = [0] * max_slots
         self.pending: "collections.deque[_Request]" = collections.deque()
-        self._pending_first: List = []  # (req, device first-token scalar)
-        self._first_fn = None  # lazily-jitted first-token sampler
+        # (req, device first-token scalar): admitted, first token not yet
+        # emitted; filled by _admit, emptied by _retire_firsts
+        self._pending_first: List = []
+        self._first_fn = None  # lazily-jitted plain sampler (_first_token)
+        self._newest = None  # token array of the block dispatched last
         self._lock = threading.Lock()
         self._work = threading.Event()
         self._stop = False
@@ -324,7 +332,12 @@ class LLMEngine:
           ``pending`` requests waiting for a slot.
         - Requests at each boundary: ``requests_submitted``, ``_admitted``
           (popped from ``pending``, prefill dispatched),
-          ``_first_emitted``, ``_finished`` (ran to ``max_new_tokens`` or
+          ``_first_emitted`` and, of those, ``firsts_ahead`` (emitted
+          while the block dispatched after their admission had not
+          finished on the device: the first token left when its own
+          prefill ended, not a block later; asked of the block's token
+          array, ``is_ready()``, without a sync),
+          ``_finished`` (ran to ``max_new_tokens`` or
           EOS), ``_cancelled`` (consumer gone: dropped at admission or
           freed mid-decode), ``_failed`` (ended by the loop's exit:
           device error or shutdown). ``prefill_tokens`` (unpadded) and
@@ -338,21 +351,24 @@ class LLMEngine:
           slot; the prefill is then dispatched, not started),
           ``admit_to_first_ms`` popped -> first token put on the
           request's queue (the device's queue ahead of the prefill, the
-          prefill, the hold until the next block is dispatched, the
-          sync), ``submit_to_first_ms`` the two together.
+          prefill, and the first tokens admitted ahead of it in the same
+          pass), ``submit_to_first_ms`` the two together.
         - Seconds the loop spent per phase: ``admit_s``, ``dispatch_s``,
           ``firsts_sync_s`` and ``block_sync_s`` (blocked on the device),
           ``firsts_emit_s``, ``block_emit_s``, ``idle_wait_s`` (nothing to
           do), and ``loop_s``, the sum of whole iterations: what no phase
-          covers is the difference. Of ``admit_s``, per admission, from
-          the pop to the last dispatch: ``admit_stage_s`` (the prompt
-          padded and handed to the device with the scalar arguments),
-          ``admit_launch_s`` (the ``prefill_into_slot`` call, which
-          donates the cache the block in flight still writes),
-          ``admit_first_s`` (the first token's sample: a ``device_put``
-          and a jitted call), ``admit_lanes_s`` (the slot's entries set in
-          the five per-slot vectors). Their sum is at most ``admit_s``:
-          the rest is the lock and the scan for a free slot.
+          covers is the difference. ``firsts_sync_s`` is the wait for
+          each first token's own prefill, token by token. Of ``admit_s``,
+          per admission, from the pop on: ``admit_stage_s`` (the prompt
+          padded on the host, the scalar arguments made),
+          ``admit_launch_s`` (the ONE dispatch: the ``prefill_into_slot``
+          call, which takes the prompt to the device, samples the first
+          token and fills the slot's lane entries, and donates the cache
+          and the lanes the block in flight still uses),
+          ``admit_first_s`` (starting the first token's copy to the
+          host), ``admit_lanes_s`` (the host's own slot table). Their sum
+          is at most ``admit_s``: the rest is the lock and the scan for a
+          free slot.
         - The lanes' cadence, per retired block: ``block_interval_s`` from
           the instant the previous block's tokens reached the host to the
           instant this block's did, and ``block_interval_steps`` those
@@ -367,14 +383,15 @@ class LLMEngine:
           ``block_interval_clean_s`` / ``_clean_steps``: the same over the
           intervals whose two fetches both stood where a block ended on
           the device: no request admitted in the interval's iteration nor
-          in the two before it. That split does NOT telescope, which is
-          why it leaves three intervals out for an admission: a block's
+          in the two before it. That split does NOT telescope. A block's
           tokens are held behind the first tokens' sync of the same
-          iteration, which waits out the prefill AND the block dispatched
-          just before the sync (that interval is the one the clients
-          feel); the next fetch finds its block already done, a block's
-          time early; the third starts from that catch-up fetch. All
-          minus clean, per step, is what admission costs each token.
+          iteration, which waits out the prefill (that interval is the
+          one the clients feel). The two fetches after it stand where
+          their blocks end again (the sync no longer waits out a block,
+          so the loop is not left behind the device); they are left out
+          all the same, as when the split was defined, so that it counts
+          what it counted. All minus clean, per step, is what admission
+          costs each token.
         - The token gap, per request that ended (ran out, EOS or
           cancelled) with two tokens or more: ``decode_gap_s`` from its
           first token's emission to the instant its last token's block
@@ -454,13 +471,17 @@ class LLMEngine:
         raise ValueError(f"prompt length {n} exceeds buckets")
 
     def _admit(self):
-        """Fill free slots from the pending queue (one prefill each).
-        NOTHING here syncs the host<->device link: the first token is
-        sampled on device and emitted with the next block retire, so an
-        admission burst chains prefills on the device back-to-back."""
+        """Fill free slots from the pending queue: ONE device program an
+        admission (``prefill_into_slot`` with the lanes: it prefills the
+        slot, samples the first token and writes the slot's entry of the
+        five per-slot vectors), whose every scalar is a numpy value of a
+        fixed dtype, so that nothing else is dispatched, converted or
+        retraced. NOTHING here syncs the host<->device link: the token's
+        copy to the host is started and ``_retire_firsts`` reads it, so an
+        admission burst chains prefills on the device back-to-back, and
+        the runtime's limit on programs in flight stays out of reach."""
         from ray_tpu.models.generation import prefill_into_slot
 
-        jnp = self._jnp
         with self._span("raytpu.engine.admit", pending=len(self.pending)):
             while True:
                 with self._lock:
@@ -488,27 +509,26 @@ class LLMEngine:
                     with self._stretch("stage"):
                         padded = np.zeros((1, bucket), np.int32)
                         padded[0, :n] = req.prompt
-                        args = (jnp.asarray(padded), jnp.int32(n),
-                                jnp.int32(free))
+                        lanes = (self.tok, self.pos, self.temps,
+                                 self.seeds, self.counts)
                     with self._stretch("launch"):
-                        logits, self.cache = prefill_into_slot(
-                            self.params, *args, self.cache, self.config)
+                        first, self.cache, lanes = prefill_into_slot(
+                            self.params, padded, np.int32(n),
+                            np.int32(free), self.cache, self.config, lanes,
+                            np.float32(req.temperature), np.int32(req.seed))
                     with self._stretch("first"):
-                        first = self._first_token(
-                            logits, req.temperature, req.seed)
+                        # lands on the host when THIS prefill ends,
+                        # whatever is queued behind it
+                        first.copy_to_host_async()
                     with self._stretch("lanes"):
-                        self.tok = self.tok.at[free].set(first)
-                        self.pos = self.pos.at[free].set(n)
-                        self.temps = self.temps.at[free].set(
-                            req.temperature)
-                        self.seeds = self.seeds.at[free].set(req.seed)
-                        self.counts = self.counts.at[free].set(1)
-                self.slot_req[free] = req
-                self._rows[free] = n
-                self._pending_first.append((req, first))
-                # its first token is retired in this same iteration: the
-                # block interval that ends next holds the admission
-                self._unsettled = 3
+                        (self.tok, self.pos, self.temps, self.seeds,
+                         self.counts) = lanes
+                        self.slot_req[free] = req
+                        self._rows[free] = n
+                        self._pending_first.append((req, first))
+                        # the block interval that ends next holds this
+                        # prefill (see _retire_block)
+                        self._unsettled = 3
 
     @contextlib.contextmanager
     def _stretch(self, name: str):
@@ -524,7 +544,13 @@ class LLMEngine:
         self._mark = now
 
     def _first_token(self, logits, temperature, seed):
-        """On-device first-token sample (scalar int32, not synced)."""
+        """The plain first-token sampler, on device (scalar int32, not
+        synced): what the admission's program computes inside itself, as
+        a program of its own. The loop no longer calls it: the tests hold
+        the fused program's token to it, and the benchmark's runners call
+        it once to warm a stack that nothing builds any more; the
+        ``benchmark`` issue that drops that warm-up (ROADMAP S1b / D12)
+        lets it go."""
         from ray_tpu.models.generation import _sample_vec
 
         jnp = self._jnp
@@ -608,6 +634,7 @@ class LLMEngine:
                     self.temps, self.seeds, self.counts, self.config,
                     steps,
                 ))
+        self._newest = toks
         self._steps += steps
         self._blocks_by_steps[steps] += 1
         self._n["slot_steps"] += active * steps
@@ -630,15 +657,17 @@ class LLMEngine:
         return (toks, stats), snapshot, seq
 
     def _retire_firsts(self):
-        """Emit admitted requests' first tokens. Called right after the
+        """Emit admitted requests' first tokens, in admission order, each
+        as soon as ITS value has reached the host. Called right after the
         next block is dispatched, so that the device has work queued while
-        the host waits. The first tokens themselves were computed BEFORE
-        that block in program order, but the stack fetched here is
-        dispatched after it, and the device runs programs in order: the
-        sync returns when the block has finished, not when the prefills
-        have (kept traces, PERF.md section 5). Every first token waits one
-        block more than it needs, and the loop comes out a block behind
-        the device."""
+        the host waits. Each token is an output of its own admission's
+        program and its copy was started there, so reading it waits for
+        that prefill alone: no program is dispatched here, and none stands
+        between the token and the host (a program dispatched now would be
+        queued behind the block; a finished buffer's copy is not). The
+        first of several prefills answers while the others still run, and
+        the loop stays a block ahead of the device. ``firsts_ahead``
+        counts the tokens emitted while that block had not finished."""
         firsts, self._pending_first = self._pending_first, []
         if not firsts:
             return
@@ -646,14 +675,15 @@ class LLMEngine:
         # profiler's "name#k=v,k=v#" encoding, so they are space-separated
         with self._span("raytpu.engine.retire_firsts", n=len(firsts),
                         rids=" ".join(str(r.rid) for r, _ in firsts)):
-            stacked = self._jnp.stack([t for _, t in firsts])
             t0 = time.perf_counter()
-            vals = np.asarray(stacked)
-            t1 = time.perf_counter()
-            for (req, _), v in zip(firsts, vals):
-                self._emit(req, int(v))
-            self._t["firsts_sync_s"] += t1 - t0
-            self._t["firsts_emit_s"] += time.perf_counter() - t1
+            for req, first in firsts:
+                token = int(first)
+                t1 = time.perf_counter()
+                self._n["firsts_ahead"] += not self._newest.is_ready()
+                self._emit(req, token)
+                self._t["firsts_sync_s"] += t1 - t0
+                t0 = time.perf_counter()
+                self._t["firsts_emit_s"] += t0 - t1
 
     def _retire_block(self, block_dev, snapshot, seq):
         """Host-sync one block's tokens (and the model's counters, which
@@ -670,10 +700,9 @@ class LLMEngine:
             # the lanes' cadence: from the last block's tokens to these.
             # An admission unsettles three fetches: its own iteration's
             # comes after the first tokens' sync, which waits out the
-            # block dispatched just before it (their stack is queued
-            # behind that block), so it is a block late; the next finds
-            # its block done already; the one after starts from that
-            # catch-up fetch, a dispatch after its block began.
+            # prefill, so it is a prefill late; the two after it keep the
+            # place they had in the split when the sync still waited out
+            # a block as well (see stats()).
             last, self._t_block = self._t_block, t1
             settled = not self._unsettled
             self._unsettled = max(0, self._unsettled - 1)
@@ -723,7 +752,9 @@ class LLMEngine:
                 if active:
                     inflight.append(self._dispatch_block())
                     spent["dispatch_s"] += clock() - t1
-                    self._retire_firsts()  # sync waits on prefills only
+                    # each first token's sync waits for its own prefill
+                    # only, and the block just dispatched runs behind it
+                    self._retire_firsts()
                 while len(inflight) > (1 if active else 0):
                     self._retire_block(*inflight.popleft())
                 if not active:

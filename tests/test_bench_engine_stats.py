@@ -22,7 +22,8 @@ ratio = common.READERS["stats_delta_ratio"]
 BOUNDS = [1.0, 10.0, 100.0, 1000.0]  # five buckets: <1, 1-10, ..., >=1000
 CADENCE = ["engine.step_interval_ms", "engine.clean_step_interval_ms",
            "engine.tpot_mean_ms"]
-ADMIT = ["engine.admit_ms_per_request", "engine.admit_after_launch_share"]
+ADMIT = ["engine.admit_ms_per_request", "engine.admit_after_launch_share",
+         "engine.first_ahead_share"]
 NEW_METRICS = {
     "serve-chat-steady": [
         "engine.queue_wait_p50_ms", "engine.queue_wait_p90_ms.chat",
@@ -123,6 +124,9 @@ def _metric(name):
     ("engine.admit_after_launch_share",
      {"admit_first_s": (0.5, 0.94), "admit_lanes_s": (1.0, 2.1),
       "admit_s": (3.0, 6.08)}, 50.0),
+    ("engine.first_ahead_share",
+     {"firsts_ahead": (40, 139), "requests_first_emitted": (100, 210)},
+     90.0),
 ])
 def test_cadence_and_admission_metrics_on_hand_made_snapshots(
         name, scalars, want):

@@ -39,11 +39,13 @@ def _tiny_model():
 
 
 def test_engine_matches_generate():
-    """The engine's slot bookkeeping (interleaved greedy requests over two
-    slots, padded prompts, short and long blocks) must reproduce
-    generate(): the straight-line use of the same two programs, one
-    request alone in a fresh cache."""
+    """The engine's slot bookkeeping (interleaved requests over two slots,
+    greedy and sampled, two of them admitted in ONE pass of the loop,
+    padded prompts, short and long blocks) must reproduce generate(): the
+    straight-line use of the plain programs (prefill, the sampler on its
+    logits, one block), one request alone in a fresh cache."""
     import jax
+    import jax.numpy as jnp
 
     from ray_tpu.models.generation import generate, prepare_for_inference
     from ray_tpu.serve.llm import LLMEngine
@@ -54,31 +56,56 @@ def test_engine_matches_generate():
         (np.arange(3, 15, dtype=np.int32) % cfg.vocab_size).astype(np.int32),
         np.full(5, 7, np.int32),
     ]
+    temps = [0.0, 0.7, 1.3]
+    rngs = [jax.random.key(11 + i) for i in range(len(prompts))]
+    # generate() draws each row's seed from its rng: the engine gets it
+    seeds = [int(jax.random.randint(
+        r, (1,), 0, jnp.iinfo(jnp.int32).max, jnp.int32)[0]) for r in rngs]
     ip, icfg = prepare_for_inference(params, cfg)
     ref = [
         np.asarray(
-            generate(ip, p[None], icfg, max_new_tokens=10, max_len=64)
+            generate(ip, p[None], icfg, max_new_tokens=10, max_len=64,
+                     temperature=t, rng=r)
         )[0]
-        for p in prompts
+        for p, t, r in zip(prompts, temps, rngs)
     ]
+    assert not np.array_equal(ref[1], np.asarray(generate(
+        ip, prompts[1][None], icfg, max_new_tokens=10, max_len=64))[0])
     eng = LLMEngine(params, cfg, max_slots=2, max_len=64,
                     prefill_buckets=(16, 32))
     try:
-        res = [None] * len(prompts)
-
-        def run(i):
-            res[i] = eng.generate(prompts[i], max_new_tokens=10)
-
-        ts = [threading.Thread(target=run, args=(i,))
-              for i in range(len(prompts))]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=180)
+        gate, firsts = _gate_admissions(eng)
+        gate.clear()
+        reqs = [eng.submit(p, max_new_tokens=10, temperature=t, seed=s)
+                for p, t, s in zip(prompts, temps, seeds)]
+        gate.set()  # both slots are filled in one pass, the third waits
+        res = [_drain(r, 180) for r in reqs]
+        assert firsts[:2] == [2, 1], firsts
         for i in range(len(prompts)):
             assert res[i] == ref[i].tolist(), (i, res[i], ref[i].tolist())
     finally:
         eng.shutdown()
+
+
+def _gate_admissions(eng):
+    """Holds the loop at the door of ``_admit`` while the returned event
+    is clear, so that what is submitted meanwhile is admitted in one pass;
+    the list fills with the count of first tokens each pass retires."""
+    gate, firsts = threading.Event(), []
+    gate.set()
+    admit, retire_firsts = eng._admit, eng._retire_firsts
+
+    def gated_admit():
+        gate.wait(timeout=60)
+        admit()
+
+    def counted_retire_firsts():
+        if eng._pending_first:
+            firsts.append(len(eng._pending_first))
+        retire_firsts()
+
+    eng._admit, eng._retire_firsts = gated_admit, counted_retire_firsts
+    return gate, firsts
 
 
 def test_engine_mid_decode_admission_ttft():
@@ -556,6 +583,237 @@ def test_decode_block_parks_lanes_at_pos_zero():
     assert pos_g == [12, 0, 9] and pos_s == [12, 51, 9]
     for other in (toks_g, toks_s):
         np.testing.assert_array_equal(other[[0, 2]], toks[[0, 2]])
+
+
+@pytest.mark.parametrize("temperature,seed", [(0.0, 0), (0.9, 4321)])
+@pytest.mark.parametrize(
+    "kind", ["tiny", "tiny_mla_moe", "tiny_dsa_moe", "tiny_ssm_hybrid"])
+def test_fused_admission_is_prefill_then_sampler_then_scatters(
+        kind, temperature, seed):
+    """``prefill_into_slot`` handed the lanes is the plain form followed
+    by ``_first_token`` on its logits and the five scatters, for each kind
+    of cache (K/V rows, latent rows, index keys, a recurrent state): the
+    same token, the same lanes, the same cache."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generation import (
+        init_kv_cache,
+        prefill_into_slot,
+        prepare_for_inference,
+    )
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = getattr(TransformerConfig, kind)()
+    params, icfg = prepare_for_inference(
+        init_params(cfg, jax.random.key(0)), cfg)
+    slots, s_max, bucket, n, slot = 4, 64, 32, 19, 2
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :n] = np.random.default_rng(5).integers(
+        1, cfg.vocab_size, n, dtype=np.int32)
+
+    def lanes():  # other slots hold what a running engine's would
+        return (jnp.arange(10, 10 + slots, dtype=jnp.int32),
+                jnp.arange(20, 20 + slots, dtype=jnp.int32),
+                jnp.linspace(0.1, 0.4, slots, dtype=jnp.float32),
+                jnp.arange(30, 30 + slots, dtype=jnp.int32),
+                jnp.arange(40, 40 + slots, dtype=jnp.int32))
+
+    args = (params, padded, np.int32(n), np.int32(slot))
+    logits, cache = prefill_into_slot(
+        *args, init_kv_cache(icfg, slots, s_max), icfg)
+    sampler = types.SimpleNamespace(
+        _first_fn=None, _jax=jax, _jnp=jnp,
+        _home=jax.tree.leaves(params)[0].sharding)
+    want = LLMEngine._first_token(sampler, logits, temperature, seed)
+    want_lanes = [lane.at[slot].set(v) for lane, v in zip(
+        lanes(), (want, n, temperature, seed, 1))]
+    first, got_cache, got_lanes = prefill_into_slot(
+        *args, init_kv_cache(icfg, slots, s_max), icfg, lanes(),
+        np.float32(temperature), np.int32(seed))
+    assert first.shape == () and first.dtype == jnp.int32
+    assert int(first) == int(want)
+    for got, lane in zip(got_lanes, want_lanes):
+        assert got.dtype == lane.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(lane))
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), got_cache, cache)
+
+
+class _Late:
+    """An array as a slow device would hand it over: not ready before
+    ``ready_at``, and whoever reads it waits until then. It has what the
+    engine asks of a first token and of a block's tokens."""
+
+    def __init__(self, array, ready_at):
+        self._array, self.ready_at = array, ready_at
+        self.shape = array.shape
+
+    def is_ready(self):
+        return time.perf_counter() >= self.ready_at
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, *_a, **_kw):
+        time.sleep(max(0.0, self.ready_at - time.perf_counter()))
+        return np.asarray(self._array)
+
+    def __int__(self):
+        return int(self.__array__())
+
+
+def test_first_tokens_leave_one_by_one_ahead_of_the_next_block(monkeypatch):
+    """A device that runs its programs in the order they were dispatched
+    and takes ``hold`` seconds for each, prefill or block: of two requests
+    admitted in one pass, mid-decode, each gets its first token when ITS
+    prefill has ended, the second a prefill after the first, and both
+    before the block dispatched after them has finished, which
+    ``firsts_ahead`` counts."""
+    from ray_tpu.models import generation
+    from ray_tpu.serve.llm import LLMEngine
+
+    hold = 0.15
+    params, cfg = _tiny_model()
+    eng = LLMEngine(params, cfg, max_slots=4, max_len=64,
+                    prefill_buckets=(16,))
+    free_at, block_ends, block_firsts = [0.0], [], []
+    prefill, block = generation.prefill_into_slot, generation.decode_block
+
+    def ends():  # the device's one queue
+        free_at[0] = max(free_at[0], time.perf_counter()) + hold
+        return free_at[0]
+
+    def slow_prefill(*a):
+        first, cache, lanes = prefill(*a)
+        return _Late(first, ends()), cache, lanes
+
+    def slow_block(*a):
+        toks, *rest = block(*a)
+        block_firsts.append(len(eng._pending_first))
+        block_ends.append(ends())
+        return (_Late(toks, block_ends[-1]), *rest)
+
+    try:
+        monkeypatch.setattr(generation, "prefill_into_slot", slow_prefill)
+        monkeypatch.setattr(generation, "decode_block", slow_block)
+        gate, firsts = _gate_admissions(eng)
+        prompt = np.arange(1, 9, dtype=np.int32)
+        a = eng.submit(prompt, max_new_tokens=12)
+        assert isinstance(a.out.get(timeout=60), int)
+        assert isinstance(a.out.get(timeout=60), int)  # a is decoding
+        gate.clear()
+        b = eng.submit(prompt[:6], max_new_tokens=3, temperature=0.8,
+                       seed=3)
+        c = eng.submit(prompt[:7], max_new_tokens=3)
+        gate.set()
+        outs = [_drain(r) for r in (b, c)]
+        _drain(a)
+        s = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    assert [len(o) for o in outs] == [3, 3] and firsts == [1, 2]
+    # one by one: c's prefill ran after b's, and c's token waited for it
+    assert c.t_first - b.t_first > 0.5 * hold, (b.t_first, c.t_first)
+    # the block dispatched with both first tokens pending: they were out
+    # before it had finished, and the engine saw that it had not
+    after = block_ends[block_firsts.index(2)]
+    assert b.t_first < c.t_first < after - 0.5 * hold, (
+        b.t_first, c.t_first, after)
+    assert s["firsts_ahead"] == s["requests_first_emitted"] == 3
+
+
+def test_an_admission_is_one_program_and_its_second_compiles_nothing(
+        monkeypatch):
+    """Between the pop and the next block the engine dispatches
+    ``prefill_into_slot`` and nothing else: the lanes and the cache that
+    program returns are, object for object, what the next program takes,
+    the token it returns is what ``_retire_firsts`` reads, every scalar
+    goes in as a numpy value of one dtype, and a second admission at the
+    same bucket (other slot, length, temperature and seed) neither traces
+    nor compiles anything."""
+    import jax
+
+    from ray_tpu.models import generation
+    from ray_tpu.serve.llm import LLMEngine
+
+    built = []
+
+    def on_event(event, *_a, **_kw):
+        if event.endswith(("backend_compile_duration",
+                           "jaxpr_trace_duration")):
+            built.append(event.rsplit("/", 1)[-1])
+
+    params, cfg = _tiny_model()
+    # sizes no other test of this file uses: the first admission compiles
+    eng = LLMEngine(params, cfg, max_slots=3, max_len=48,
+                    prefill_buckets=(24,))
+    events = []
+    prefill, block = generation.prefill_into_slot, generation.decode_block
+
+    def seen_prefill(*a):
+        out = prefill(*a)
+        events.append(("prefill", a, out))
+        return out
+
+    def seen_block(params, cache, *lanes_config_steps):
+        events.append(("block", cache, lanes_config_steps[:5]))
+        return block(params, cache, *lanes_config_steps)
+
+    def no_sampler(*_a):
+        raise AssertionError("the loop called _first_token")
+
+    retire_firsts = eng._retire_firsts
+
+    def seen_retire_firsts():
+        if eng._pending_first:
+            events.append(("firsts", [t for _r, t in eng._pending_first]))
+        retire_firsts()
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        monkeypatch.setattr(generation, "prefill_into_slot", seen_prefill)
+        monkeypatch.setattr(generation, "decode_block", seen_block)
+        eng._first_token = no_sampler
+        eng._retire_firsts = seen_retire_firsts
+        del built[:]
+        one = eng.submit(np.arange(1, 9, dtype=np.int32),
+                         max_new_tokens=40)
+        assert isinstance(one.out.get(timeout=120), int)
+        assert built.count("backend_compile_duration") == 1, built
+        del built[:]
+        two = eng.submit(np.arange(2, 15, dtype=np.int32),
+                         max_new_tokens=4, temperature=0.7, seed=99)
+        assert isinstance(two.out.get(timeout=120), int)
+        assert built == []
+        one.cancelled = True
+        _drain(two)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+        eng.shutdown()
+    admissions = [e for e in events if e[0] == "prefill"]
+    assert len(admissions) == 2
+    slots = set()
+    for _kind, a, _out in admissions:
+        _params, prompt, n, slot, _cache, _config, lanes, temp, seed = a
+        assert isinstance(prompt, np.ndarray) and prompt.dtype == np.int32
+        assert prompt.shape == (1, 24) and len(lanes) == 5
+        assert [type(x) for x in (n, slot, temp, seed)] == [
+            np.int32, np.int32, np.float32, np.int32]
+        slots.add(int(slot))
+    assert len(slots) == 2
+    for i, event in enumerate(events):
+        if event[0] != "prefill":
+            continue
+        first, cache, lanes = event[2]
+        nxt, then = events[i + 1], events[i + 2]
+        assert nxt[0] == "block" and nxt[1] is cache
+        assert all(x is y for x, y in zip(nxt[2], lanes))
+        assert then[0] == "firsts" and then[1][0] is first
 
 
 def test_engine_stats_count_cancelled_requests():

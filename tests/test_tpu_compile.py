@@ -319,3 +319,77 @@ def test_gptj_serving_programs_copy_no_stacked_weight(
             program.split("_")[1]]
     before = _lower(program, as_made, cache, cfg, v5e).compile().as_text()
     assert len(_copies(before, "s8[")) == 3  # what the layouts took away
+
+
+def _admission_forms(params, cache, cfg, slots, bucket, v5e):
+    """``prefill_into_slot`` compiled both ways at one bucket: the plain
+    form, and the engine's admission (with the five lanes, the request's
+    temperature and its seed)."""
+    from ray_tpu.models import generation as gen
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    head = (params, arr((1, bucket)), arr(()), arr(()), cache, cfg)
+    lanes = (arr((slots,)), arr((slots,)), arr((slots,), jnp.float32),
+             arr((slots,)), arr((slots,)))
+    return (gen.prefill_into_slot.lower(*head).compile(),
+            gen.prefill_into_slot.lower(
+                *head, lanes, arr((), jnp.float32), arr(())).compile())
+
+
+def _check_admission(plain, fused, cache, cfg, slots, cache_leaves_of):
+    """ISSUE 38: the admission is the prefill with a tail on its logits.
+    The whole cache and the five lanes are updated in place (each an
+    argument aliased to an output), no cache leaf is copied, and the
+    program needs no more room than the plain form but for the logits it
+    now keeps to itself and the lanes."""
+    hlo = fused.as_text()
+    leaves = jax.tree.leaves(cache)
+    aliased = re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)",
+                         hlo.split("\n", 1)[0])
+    assert len(aliased) == len(leaves) + 5, hlo.split("\n", 1)[0][-600:]
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    lane_bytes = 5 * slots * 4
+    mem, was = fused.memory_analysis(), plain.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes + lane_bytes
+    assert was.alias_size_in_bytes >= cache_bytes
+    for of in cache_leaves_of:
+        assert not _copies(hlo, of), of
+    logits_bytes = cfg.vocab_size * 4
+    room = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    room_was = was.argument_size_in_bytes + was.temp_size_in_bytes
+    assert room <= room_was + logits_bytes + lane_bytes + 4096, (
+        room, room_was)
+    # one token leaves the program where the logits did
+    assert mem.output_size_in_bytes <= (
+        was.output_size_in_bytes + lane_bytes + 4096)
+
+
+def test_gptj_admission_is_the_prefill_in_place(gptj_served, v5e):
+    """The GPT-J cells' admission at the documents' bucket (8 slots x
+    1,024 rows, bucket 1,024)."""
+    cfg, _made, as_served, _asked, cache = gptj_served
+    plain, fused = _admission_forms(as_served, cache, cfg, 8, 1024, v5e)
+    _check_admission(plain, fused, cache, cfg, 8, ("bf16[28,8,1024,",))
+
+
+def test_ssm_hybrid_admission_is_the_prefill_in_place(v5e, as_on_the_chip):
+    """granite-4.0-h-micro's admission at the cell's sizes (48 slots x
+    4,096 rows, bucket 2,048): the state leaves and the K/V rows alike."""
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.granite4_h_micro(param_dtype=jnp.bfloat16)
+
+    def described(make):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), jax.eval_shape(make))
+
+    params = described(lambda: init_params(cfg, jax.random.key(0)))
+    cache = described(lambda: gen.init_kv_cache(cfg, 48, 4096))
+    plain, fused = _admission_forms(params, cache, cfg, 48, 2048, v5e)
+    _check_admission(
+        plain, fused, cache, cfg, 48,
+        ("f32[36,48,64,", "f32[48,64,64,128", "f32[1,48,64,",
+         "bf16[4,48,4096,", "bf16[36,48,13056"))
