@@ -21,8 +21,14 @@ owns one. A layer whose mixer is a state-space recurrence keeps no rows
 at all: its slot holds a STATE of fixed size (the recurrent state and the
 convolution's last inputs, the cache's ``"state"`` subtree), which
 ``prefill_into_slot`` hands over as it stands after the prompt's last
-real token and ``decode_block`` updates in place once a token. Both
-programs run every block the config can describe.
+real token and ``decode_block`` updates in place once a token. A WINDOW
+layer (``TransformerConfig.window``) attends its last ``window`` rows
+alone, so those are all its slot keeps: a ring of ``window`` rows, a STATE
+leaf too, row ``pos mod window`` overwritten once a token; a prefill hands
+over the prompt's last ``window`` rows, attends block by block and never
+touches the rows a window does not reach (``ops/attention.
+window_attention``). Both programs run every block the config can
+describe.
 Decode is bound by HBM reads, and a masked cache row is read like a live
 one: the mask only discards what was already streamed. So the decode
 attention of both dense caches (``_attend_prefix_plus_self``,
@@ -57,7 +63,13 @@ from ray_tpu.models.transformer import (
     scan_stack,
     ssm_split,
 )
-from ray_tpu.ops.attention import NEG_INF, causal_attention, repeat_kv
+from ray_tpu.ops.attention import (
+    NEG_INF,
+    blocked_causal_attention,
+    causal_attention,
+    repeat_kv,
+    window_attention,
+)
 from ray_tpu.ops.decode_attention import (
     chunk_rows,
     decode_attention,
@@ -182,7 +194,7 @@ def init_kv_cache(config: TransformerConfig, batch: int,
     above attend (the choice itself lives one step, in the layer scan's
     carry).
 
-    Heads narrower than a 128-lane (``_kv_row``) lie flat in their row,
+    Heads narrower than a 128-lane (``_kv_rows``) lie flat in their row,
     ``k`` and ``v`` of [L, .., Hkv x D]: the chip lays [.., Hkv, 64] out
     rows-minor, and the decode attention's kernel, which takes its
     operands row-major, was handed two copies of the whole cache a block
@@ -195,7 +207,13 @@ def init_kv_cache(config: TransformerConfig, batch: int,
     ``conv``, the convolution's last ``ssm_conv - 1`` inputs, of [those
     layers, B, (ssm_conv - 1) x width] in the compute dtype: the taps side
     by side in ONE minor axis, a whole number of 128-lanes, because
-    [.., 3, width] would be tiled with its 3 rows padded to 16."""
+    [.., 3, width] would be tiled with its 3 rows padded to 16.
+
+    A model with window layers keeps ``k`` / ``v`` rows for the layers
+    that attend every row alone (``v`` as wide as the values are) and,
+    for its "window" layers, ``state``: the ring ``wk`` of [those layers,
+    B, window, Hkv_w x D] and ``wv`` of [.., Hkv_w x Dv], the KV heads
+    flat in their row; the row of position p is ``p mod window``."""
     c = config
     if c.mixer == "mla" and c.index_topk:
         rows = (batch, max_len)
@@ -207,11 +225,17 @@ def init_kv_cache(config: TransformerConfig, batch: int,
         rows = (c.n_layers, batch, max_len)
         return {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
                 "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}
-    shape = (c.n_attn_layers, batch, max_len) + _kv_row(c)
+    rows = (c.n_attn_layers, batch, max_len)
+    k_row, v_row = _kv_rows(c)
     cache = {
-        "k": jnp.zeros(shape, c.dtype),
-        "v": jnp.zeros(shape, c.dtype),
+        "k": jnp.zeros(rows + k_row, c.dtype),
+        "v": jnp.zeros(rows + v_row, c.dtype),
     }
+    if c.n_window_layers:
+        ring, h_kv = (c.n_window_layers, batch, c.window), c.mha_kind(True)[0]
+        cache["state"] = {
+            "wk": jnp.zeros(ring + (h_kv * c.d_head,), c.dtype),
+            "wv": jnp.zeros(ring + (h_kv * c.v_dim,), c.dtype)}
     if c.n_ssm_layers:
         slots = (c.n_ssm_layers, batch)
         cache["state"] = {
@@ -222,13 +246,14 @@ def init_kv_cache(config: TransformerConfig, batch: int,
     return cache
 
 
-def _kv_row(c: TransformerConfig) -> Tuple[int, ...]:
-    """The shape of one token's key (or value) in a layer of the MHA/GQA
-    cache: (Hkv, D), or flat (Hkv x D,) where D alone is no whole number
-    of 128-lanes and the heads together are."""
+def _kv_rows(c: TransformerConfig) -> Tuple[Tuple[int, ...], ...]:
+    """The shapes of one token's key and value in a layer of the MHA/GQA
+    cache: (Hkv, D) and (Hkv, Dv), or both flat, (Hkv x D,) and
+    (Hkv x Dv,), where D alone is no whole number of 128-lanes and the
+    heads together are."""
     if c.d_head % 128 and (c.kv_heads * c.d_head) % 128 == 0:
-        return (c.kv_heads * c.d_head,)
-    return (c.kv_heads, c.d_head)
+        return (c.kv_heads * c.d_head,), (c.kv_heads * c.v_dim,)
+    return (c.kv_heads, c.d_head), (c.kv_heads, c.v_dim)
 
 
 def cache_rows(cache: Dict) -> Dict:
@@ -377,31 +402,82 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
 def _attend_flat_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer,
                                   schedule=None):
     """``_attend_prefix_plus_self`` over a cache whose rows hold their KV
-    heads flat, ck / cv [L,B,S_max,Hkv x D] (``_kv_row``). To the kernel
+    heads flat, ck / cv [L,B,S_max,Hkv x D] (``_kv_rows``). To the kernel
     this is one key Hkv x D wide that all heads share: a query head's own
     D numbers sit in its KV head's place and zeros elsewhere, so its score
     is its own head's; it gathers all Hkv heads' values and keeps its own.
     The kernel streams the same rows either way, and the rows are all a
     decode step's attention costs."""
     B, _, n_heads, d = q.shape
-    h_kv = k_new.shape[2]
+    h_kv, d_v = k_new.shape[2], v_new.shape[-1]
     n_rep = n_heads // h_kv
     scale = d ** -0.5
     f32 = jnp.float32
-    own = (jnp.arange(n_heads)[:, None] // n_rep
-           == jnp.arange(h_kv)[None, :])  # [H,Hkv]
-    q_wide = (q[:, 0, :, None, :] * own[None, :, :, None].astype(q.dtype)
-              ).reshape(B, n_heads, h_kv * d)
+    q_wide, own = _wide_queries(q, h_kv)
     m = jnp.einsum("bqhd,bqhd->bh", q, repeat_kv(k_new, n_rep),
                    preferred_element_type=f32) * scale
-    acc = jnp.broadcast_to(v_new.reshape(B, 1, h_kv * d).astype(f32),
-                           (B, n_heads, h_kv * d))
+    acc = jnp.broadcast_to(v_new.reshape(B, 1, h_kv * d_v).astype(f32),
+                           (B, n_heads, h_kv * d_v))
     out = decode_attention(
         (q_wide,), (ck,), cv, m, acc, pos,
         schedule or _visits(pos, (ck, cv)), layer=layer, scale=scale)
-    out = jnp.einsum("bhgd,hg->bhd", out.reshape(B, n_heads, h_kv, d),
+    return _own_values(out, own)
+
+
+def _wide_queries(q, h_kv: int):
+    """q [B,1,H,D] as [B,H,Hkv x D], each head's D numbers in its KV
+    head's place and zeros elsewhere, and ``own`` [H,Hkv]: whose place
+    that is."""
+    B, _, n_heads, d = q.shape
+    own = (jnp.arange(n_heads)[:, None] // (n_heads // h_kv)
+           == jnp.arange(h_kv)[None, :])  # [H,Hkv]
+    return (q[:, 0, :, None, :] * own[None, :, :, None].astype(q.dtype)
+            ).reshape(B, n_heads, h_kv * d), own
+
+
+def _own_values(out, own):
+    """The other half of ``_wide_queries``: of the values of all Hkv heads
+    that each query head gathered, out [B,H,Hkv x Dv], its own KV head's:
+    [B,1,H,Dv]."""
+    B, n_heads, wide = out.shape
+    h_kv = own.shape[1]
+    out = jnp.einsum("bhgd,hg->bhd",
+                     out.reshape(B, n_heads, h_kv, wide // h_kv),
                      own.astype(out.dtype))
     return out[:, None]
+
+
+def ring_rows(pos, window: int):
+    """Rows of each lane's ring that hold a position its token attends
+    ONCE THE TOKEN'S OWN ROW IS WRITTEN: min(pos + 1, window), none for a
+    parked lane (``pos`` 0)."""
+    return jnp.where(pos > 0, jnp.minimum(pos + 1, window), 0)
+
+
+def _attend_ring(q, wk, wv, sink, rows, *, layer, schedule):
+    """A window layer's decode attention: q [B,1,H,D] over the first
+    ``rows`` [B] rows of each lane's ring, wk [Lw,B,W,Hkv x D] / wv
+    [Lw,B,W,Hkv x Dv], which already holds the token's own row: a ring
+    keeps the last W positions whatever their order (softmax does not
+    ask; every key carries its rotary), and the row a token overwrites is
+    the one that just left its window. The same kernel as the rows'
+    (``ops/decode_attention``; to it a ring is a cache of W rows, read
+    where it lies in the stacked leaf), its online softmax seeded not by
+    the token's own position but by the SINK: a logit ``sink`` [H] of
+    weight exp(sink) that carries no value (none: a seed of no weight).
+    A parked lane reads nothing and returns zeros."""
+    B, _, n_heads, d = q.shape
+    h_kv = wk.shape[-1] // d
+    d_v = wv.shape[-1] // h_kv
+    f32 = jnp.float32
+    q_wide, own = _wide_queries(q, h_kv)
+    m = jnp.broadcast_to(
+        jnp.full((n_heads,), NEG_INF, f32) if sink is None
+        else sink.astype(f32), (B, n_heads))
+    acc = jnp.zeros((B, n_heads, h_kv * d_v), f32)
+    out = decode_attention((q_wide,), (wk,), wv, m, acc, rows, schedule,
+                           layer=layer, scale=d ** -0.5)
+    return _own_values(out, own)
 
 
 def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
@@ -803,9 +879,63 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig,
             k[:, 0].reshape((-1,) + row).astype(ck_all.dtype)
         )
         cv2 = cv_all.at[li, b_idx, pos].set(
-            v[:, 0].reshape((-1,) + row).astype(cv_all.dtype)
+            v[:, 0].reshape((-1,) + cv_all.shape[3:]).astype(cv_all.dtype)
         )
         return out, {**cache, "k": ck2, "v": cv2}
+
+    return cached_attn
+
+
+def _decode_window_attn(cache, li, pos, b_idx, c: TransformerConfig,
+                        schedule):
+    """One decode layer's ``attn_fn`` for a window layer (the counterpart
+    of ``_decode_attn``): the token's key and value go into row ``pos mod
+    window`` of the slot's ring FIRST (the row they replace left the
+    window with this token), then the ring is attended (``_attend_ring``;
+    ``schedule``: the step's visits of the rings, one a lane). Returns
+    (output, the cache)."""
+    def cached_attn(q, k, v, sink):
+        state = cache_state(cache)
+        B = q.shape[0]
+        with jax.named_scope("raytpu.swa.ring"):
+            at = pos % c.window
+            wk = state["wk"].at[li, b_idx, at].set(
+                k[:, 0].reshape(B, -1).astype(state["wk"].dtype))
+            wv = state["wv"].at[li, b_idx, at].set(
+                v[:, 0].reshape(B, -1).astype(state["wv"].dtype))
+        with jax.named_scope("raytpu.swa.attend"):
+            out = _attend_ring(q, wk, wv, sink, ring_rows(pos, c.window),
+                               layer=li, schedule=schedule)
+        return out, {**cache, "state": {**state, "wk": wk, "wv": wv}}
+
+    return cached_attn
+
+
+def _prefill_window_attn(single, li, prompt_len, c: TransformerConfig):
+    """One prefill layer's ``attn_fn`` for a window layer: the prompt is
+    attended block by block, each block against the rows its windows
+    reach alone (``ops/attention.window_attention``), and the slot's ring
+    is handed the prompt's last ``window`` rows, position p in row ``p
+    mod window`` (rows no position fills yet hold whatever: nothing
+    attends them before a token overwrites them). ``single`` is one
+    slot's cache. Returns (output, single with this layer's ring)."""
+    def cached_attn(q, k, v, sink):
+        state = cache_state(single)
+        with jax.named_scope("raytpu.swa.ring"):
+            j = jnp.arange(c.window)
+            last = prompt_len - 1
+            src = jnp.clip(last - (last - j) % c.window, 0, k.shape[1] - 1)
+
+            def put(name, rows):
+                ring = jnp.take(rows[0], src, axis=0).reshape(c.window, -1)
+                return lax.dynamic_update_slice(
+                    state[name], ring[None, None].astype(state[name].dtype),
+                    (li, 0, 0, 0))
+
+            new = {**state, "wk": put("wk", k), "wv": put("wv", v)}
+        with jax.named_scope("raytpu.swa.attend"):
+            out = window_attention(q, k, v, sink, window=c.window)
+        return out, {**single, "state": new}
 
     return cached_attn
 
@@ -897,13 +1027,17 @@ def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     (index keys scored), ``dsa_rows_selected`` (rows attended:
     min(pos + 1, index_topk) a live lane a layer) and ``dsa_rows_live``
     (rows a walk over every live row would attend), summed over lanes,
-    layers and steps. None for the other models."""
+    layers and steps; a model with window layers': ``window_rows_read``,
+    the ring rows its decode attention read (min(pos + 1, window) a live
+    lane a window layer). None for the other models."""
     keys = ()
     if config.moe_experts and config.moe_impl == "dropless":
         keys += ("moe_assignments", "moe_experts_touched",
                  "moe_experts_capacity", "moe_max_load", "moe_weight_visits")
     if config.index_topk:
         keys += ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_live")
+    if config.n_window_layers:
+        keys += ("window_rows_read",)
     return keys
 
 
@@ -939,7 +1073,9 @@ def _decode_forward_multi(params, token, cache, pos,
     live = (pos > 0)[:, None] if routed else None
     B = token.shape[0]
     b_idx = jnp.arange(B)
-    choice = schedule = None
+    choice = schedule = rings = None
+    if c.n_window_layers:  # the rings' visits: one a lane, every layer's
+        rings = slot_schedule(ring_rows(pos, c.window), c.window, c.window)
     if c.index_topk:  # a block with an indexer: what the layers hand on
         choice = {"mask": jnp.zeros((B, cache["ik"].shape[2]), bool),
                   "k": jnp.zeros((B, c.index_head_dim), c.dtype)}
@@ -951,6 +1087,8 @@ def _decode_forward_multi(params, token, cache, pos,
             x, cache, total, choice = carry
             if "ssm" in lp:
                 attn = _decode_recur(cache, li, lc)
+            elif "swa" in lp:
+                attn = _decode_window_attn(cache, li, pos, b_idx, lc, rings)
             elif choice is None:
                 attn = _decode_attn(cache, li, pos, b_idx, lc, schedule)
             else:
@@ -967,6 +1105,10 @@ def _decode_forward_multi(params, token, cache, pos,
     if c.index_topk:
         stats = _add_stats(stats, _dsa_stats(
             pos, c, cache["ik"].shape[2]))
+    if c.n_window_layers:
+        stats = _add_stats(stats, {
+            "window_rows_read": c.n_window_layers * ring_rows(
+                pos, c.window).sum()})
     return lm_logits(params, x, c)[:, 0, :], cache, stats
 
 
@@ -1112,6 +1254,11 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
                 cv_all, v_rows[None].astype(cv_all.dtype),
                 (li,) + (0,) * (cv_all.ndim - 1)
             )
+            if c.window:
+                # as below, and without the prompt's [S, S] scores: tile by
+                # tile (float32 scores of 64 heads at 16,384 are 68 GB)
+                return blocked_causal_attention(q, k, v), {
+                    **single, "k": ck2, "v": cv2}
             if c.layer_types:
                 # the prompt alone, as the latent form above: a real token
                 # attends nothing past itself, so no padding and none of
@@ -1157,6 +1304,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
             x, single, choice = carry
             if "ssm" in lp:
                 attn = _prefill_recur(single, li, prompt_len, lc)
+            elif "swa" in lp:
+                attn = _prefill_window_attn(single, li, prompt_len, lc)
             elif choice is None:
                 attn = slot_attn(single, li)
             else:

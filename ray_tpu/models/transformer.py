@@ -22,8 +22,11 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   share of its experts (`moe_experts_held`); and a model may hold layers
   of two KINDS (`layer_types`): beside the attention layers, layers whose
   mixer is a state-space recurrence (`ops/ssm.py`: a fixed-size state a
-  head and a short convolution), two stacks of parameters run in the
-  order the list gives;
+  head and a short convolution), or layers of a second ATTENTION kind
+  that attend a window of the last rows and a learned sink ("window":
+  their own KV head count and rotary base; such a model may route its
+  FFNs and lead with dense layers), one stack of parameters a kind, run
+  in the order the list gives;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -34,6 +37,7 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -41,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.attention import causal_attention, window_attention
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked
 
 
@@ -143,6 +147,24 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256  # tokens a chunk of the prefill's scan
+    # A second kind of attention layer, "window" in layer_types (beside
+    # "attention"; not beside "ssm"): query t attends the rows t - window
+    # < s <= t alone (``window`` rows, itself among them), through
+    # window_kv_heads KV heads (0: as the other layers) with rotary base
+    # window_rope_theta (0: rope_theta) and, with window_sink, a learned
+    # logit b a head that joins the softmax's denominator and carries no
+    # value: p[t,s] = exp(a[t,s]) / (exp(b) + sum_s' exp(a[t,s'])). What
+    # such a layer keeps of a sequence is its last ``window`` rows
+    # (generation.init_kv_cache: a ring, a STATE leaf). Its parameters
+    # are params["window_layers"] (the mixer's under "swa"); such a model
+    # may route its FFNs (moe_impl "dropless"), its leading dense layers
+    # all of kind "attention". For every "mha" layer: values of width
+    # v_head_dim (0: d_head, as the keys) and multiplied by value_scale.
+    window: int = 0
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_sink: bool = False
+    value_scale: float = 1.0
     # Four scalars a block may multiply by (1 / None: the usual forms).
     # x_0 = embed_scale * E[token]; x += residual_scale * Mixer(..) and
     # x += residual_scale * FFN(..); logits = logit_scale * (x E^T);
@@ -156,17 +178,32 @@ class TransformerConfig:
         if self.layer_types:
             kinds = tuple(self.layer_types)
             if len(kinds) != self.n_layers or set(kinds) - {
-                    "attention", "ssm"} or self.mixer != "mha" or (
-                    self.residual != "sequential") or self.moe_experts or (
+                    "attention", "ssm", "window"} or self.mixer != "mha" or (
+                    self.residual != "sequential") or (
                     "ssm" in kinds and (
-                        not self.ssm_heads * self.ssm_head_dim
+                        self.moe_experts or "window" in kinds
+                        or not self.ssm_heads * self.ssm_head_dim
                         * self.ssm_state or self.ssm_heads
                         % self.ssm_groups)):
                 raise ValueError(
                     "layer_types needs one entry a layer ('attention' | "
-                    "'ssm'), mixer 'mha', a sequential block with a dense "
-                    "FFN, and the ssm_* sizes (heads a multiple of groups)")
+                    "'ssm' | 'window'), mixer 'mha', a sequential block; "
+                    "beside 'ssm' layers a dense FFN, no 'window' layer, "
+                    "and the ssm_* sizes (heads a multiple of groups)")
+            n_dense = self.n_dense_layers if self.moe_experts else 0
+            if "window" in kinds and (
+                    self.window < 1 or self.n_heads % (
+                        self.window_kv_heads or self.kv_heads)
+                    or set(kinds[:n_dense]) - {"attention"} or (
+                        self.moe_experts and self.moe_impl != "dropless")):
+                raise ValueError(
+                    "a 'window' layer needs window >= 1, n_heads a multiple "
+                    "of window_kv_heads, dropless experts where the FFNs "
+                    "are routed, and leading dense layers of kind "
+                    "'attention'")
             object.__setattr__(self, "layer_types", kinds)
+        elif self.window:
+            raise ValueError("window needs 'window' layers in layer_types")
         if self.index_topk:
             kinds = tuple(self.indexer_types)
             if self.mixer != "mla" or len(kinds) != self.n_layers or (
@@ -203,9 +240,26 @@ class TransformerConfig:
         return sum(k == "ssm" for k in self.layer_types)
 
     @property
+    def n_window_layers(self) -> int:
+        return sum(k == "window" for k in self.layer_types)
+
+    @property
     def n_attn_layers(self) -> int:
-        """Layers whose mixer attends: the layers the K/V cache holds."""
-        return self.n_layers - self.n_ssm_layers
+        """Layers that attend every row: the layers the K/V cache holds
+        rows for."""
+        return self.n_layers - self.n_ssm_layers - self.n_window_layers
+
+    @property
+    def v_dim(self) -> int:
+        """Width of an "mha" head's value."""
+        return self.v_head_dim or self.d_head
+
+    def mha_kind(self, window: bool = False):
+        """(KV heads, rotary base) of an "mha" layer of either kind."""
+        if window:
+            return (self.window_kv_heads or self.kv_heads,
+                    self.window_rope_theta or self.rope_theta)
+        return self.kv_heads, self.rope_theta
 
     @property
     def ssm_inner(self) -> int:
@@ -252,7 +306,10 @@ class TransformerConfig:
                                                + self.v_head_dim)
                     + h * self.v_head_dim * d)
         else:
-            attn = d * dh * (h + 2 * kv) + h * dh * d
+            attn = d * dh * (h + kv) + d * self.v_dim * kv + h * self.v_dim * d
+        wkv = self.mha_kind(True)[0]
+        window = (d * dh * (h + wkv) + d * self.v_dim * wkv
+                  + h * self.v_dim * d + h * self.window_sink)
         norms = d * (2 if self.residual == "sequential" else 1)
         n_dense = self.n_dense_layers if self.moe_experts else 0
         indexer = self.n_index_layers * (
@@ -263,7 +320,7 @@ class TransformerConfig:
         ssm = (d * (inner + width + self.ssm_heads) + inner * d
                + width * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner)
         layers = (self.n_attn_layers * attn + self.n_ssm_layers * ssm
-                  + self.n_layers * norms + n_dense * dense_ffn
+                  + self.n_window_layers * window + self.n_layers * norms + n_dense * dense_ffn
                   + (self.n_layers - n_dense) * ffn + indexer)
         head = 0 if self.tie_embeddings else d * self.vocab_size
         return self.vocab_size * d + layers + d + head
@@ -399,6 +456,56 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     @staticmethod
+    def mimo_v2_flash(n_layers: int = 48, **kw) -> "TransformerConfig":
+        """MiMo-V2-Flash (XiaomiMiMo/MiMo-V2-Flash config.json, model_type
+        mimo_v2_flash) at its published widths: 64 query heads with keys
+        192 wide (rotary on the first 64) and values 128 wide (x 0.707);
+        layers 0, 5, 11, 17, ... attend every row through 4 KV heads
+        (rotary base 5e6), the others a window of 128 rows through 8 KV
+        heads (base 1e4) with a learned sink a head; one dense layer,
+        then layers of 256 routed experts (8 a token, sigmoid scores, no
+        shared expert). A cut passes its own ``layer_types`` /
+        ``moe_experts_held``. The three multi-token-prediction layers are
+        not part of the block."""
+        base = dict(
+            vocab_size=152576, d_model=4096, n_layers=n_layers, n_heads=64,
+            n_kv_heads=4, d_head=192, v_head_dim=128, d_ff=16384,
+            rotary_dim=64, max_seq_len=262144, residual="sequential",
+            activation="silu", gated_ffn=True, norm_eps=1e-5,
+            rope_theta=5e6, value_scale=0.707, window=128,
+            window_kv_heads=8, window_rope_theta=1e4, window_sink=True,
+            layer_types=tuple(
+                "attention" if i == 0 or i % 6 == 5 else "window"
+                for i in range(n_layers)),
+            moe_experts=256, moe_top_k=8, moe_impl="dropless",
+            moe_d_ff=2048, n_dense_layers=1,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_swa_moe(**kw) -> "TransformerConfig":
+        """The same kind of model at test size (CPU): a dense full layer,
+        then ``window window full window`` with 8 routed experts (2 a
+        token); a window of 8 rows; 4 query heads over 2 KV heads (full)
+        or 4 (window); keys 64 wide, which lie flat in their cache row as
+        the model's 192 do (``generation._kv_rows``), values 32."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=5, n_heads=4, n_kv_heads=2,
+            d_head=64, v_head_dim=32, d_ff=96, rotary_dim=16,
+            max_seq_len=1024, residual="sequential", activation="silu",
+            gated_ffn=True, norm_eps=1e-5, rope_theta=5e6, value_scale=0.707,
+            window=8, window_kv_heads=4, window_rope_theta=1e4,
+            window_sink=True,
+            layer_types=("attention", "window", "window", "attention",
+                         "window"),
+            moe_experts=8, moe_top_k=2, moe_impl="dropless", moe_d_ff=48,
+            n_dense_layers=1,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny_mla_moe(**kw) -> "TransformerConfig":
         """The same block at test size (CPU)."""
         base = dict(
@@ -453,10 +560,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                 } if c.gated_ffn else {}
 
     def stack(lc: TransformerConfig, L: int, salt: int, first: int,
-              attends: bool = True) -> Dict:
+              attends: bool = True, window: bool = False) -> Dict:
         """L layers of ``lc``'s block (the model's layers from ``first``
         on), stacked on a leading axis; without ``attends`` the norms and
-        the FFN alone (another mixer's weights are the caller's)."""
+        the FFN alone (another mixer's weights are the caller's); with
+        ``window`` the mixer is a window layer's, under "swa"."""
         kq, kk, kv, ko, kwi, kwo = (
             (k_q, k_k, k_v, k_o, k_wi, k_wo) if not salt else
             [jax.random.fold_in(k, salt) for k in (k_q, k_k, k_v, k_o,
@@ -498,13 +606,24 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                                      (n_own, d, nI), d),
                 }
         else:
-            layers["attn"] = {
+            h_kv = lc.mha_kind(window)[0]
+            layers["swa" if window else "attn"] = {
                 "wq": dense_init(kq, (L, d, lc.n_heads, lc.d_head), d),
-                "wk": dense_init(kk, (L, d, lc.kv_heads, lc.d_head), d),
-                "wv": dense_init(kv, (L, d, lc.kv_heads, lc.d_head), d),
-                "wo": dense_init(ko, (L, lc.n_heads, lc.d_head, d),
-                                 lc.n_heads * lc.d_head),
+                "wk": dense_init(kk, (L, d, h_kv, lc.d_head), d),
+                "wv": dense_init(kv, (L, d, h_kv, lc.v_dim), d),
+                "wo": dense_init(ko, (L, lc.n_heads, lc.v_dim, d),
+                                 lc.n_heads * lc.v_dim),
             }
+            if window and lc.window_sink:
+                # trained in a published model; here seeded around
+                # ln(window), where the sink weighs about what the whole
+                # window does: around 0 it would hold 1 part in window + 1
+                # of the mass, which bf16 rounding hides, and no comparison
+                # with a reference could tell a sink from none
+                layers["swa"]["sink"] = (
+                    math.log(lc.window) + jax.random.normal(
+                        jax.random.fold_in(kq, 3), (L, lc.n_heads))
+                ).astype(pd)
         if lc.moe_experts and lc.moe_impl == "dropless":
             E, f = lc.moe_experts, lc.moe_d_ff or lc.d_ff
             k_rt = jax.random.fold_in(kwi, 1)
@@ -587,6 +706,9 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     }
     if c.n_ssm_layers:
         params["ssm_layers"] = ssm_stack(c.n_ssm_layers)
+    if c.n_window_layers:
+        params["window_layers"] = stack(c, c.n_window_layers, 17, 0,
+                                        window=True)
     if n_dense:
         params["dense_layers"] = stack(c.dense_variant(), n_dense, 7, 0)
     if not c.tie_embeddings:
@@ -676,6 +798,12 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
             "wo": ("layers", "mlp", "embed"),
         }
         axes["ssm_layers"] = ssm
+    if config.n_window_layers:
+        swa = stack(config, 0, config.n_window_layers)
+        swa["swa"] = swa.pop("attn")
+        if config.window_sink:
+            swa["swa"]["sink"] = ("layers", "heads")
+        axes["window_layers"] = swa
     if n_dense:
         axes["dense_layers"] = stack(config.dense_variant(), 0, n_dense)
     if not config.tie_embeddings:
@@ -690,12 +818,21 @@ def layer_groups(params: Dict, config: TransformerConfig):
     stack is one ``lax.scan``. A model with layers of two kinds
     (``config.layer_types``) is ONE group whose "stack" holds both kinds'
     stacks, ``{"attention": .., "ssm": ..}``: ``scan_stack`` runs them in
-    the listed order."""
-    if config.layer_types:
+    the listed order. With "window" layers the stacks are ``{"attention":
+    .., "window": ..}`` and the leading dense layers, all of kind
+    "attention", a group of their own before them."""
+    n_dense = config.n_dense_layers if config.moe_experts else 0
+    if config.n_ssm_layers:
         return [({"attention": params["layers"],
                   "ssm": params["ssm_layers"]}, config, 0)]
+    if config.layer_types:
+        lead = [({"attention": params["dense_layers"]},
+                 config.dense_variant(), 0)] if n_dense else []
+        rest = {"attention": params["layers"]}
+        if config.n_window_layers:
+            rest["window"] = params["window_layers"]
+        return lead + [(rest, config, n_dense)]
     groups = []
-    n_dense = config.n_dense_layers if config.moe_experts else 0
     if n_dense:
         groups.append((params["dense_layers"], config.dense_variant(), 0))
     groups.append((params["layers"], config, n_dense))
@@ -727,18 +864,21 @@ def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
 
     Layers of two kinds with different parameters (``lc.layer_types``)
     are two stacks, ``stack[kind]`` (``layer_groups``), run in the listed
-    order by ``_scan_kinds``; ``li`` then counts within the layer's own
-    kind (its place in that kind's stack, and in whatever cache leaf
-    that kind keeps)."""
+    order by ``_scan_kinds``; ``li`` then counts the MODEL's layers of the
+    layer's own kind (its place in whatever cache leaf that kind keeps;
+    its place in its kind's stack is that less the kind's layers before
+    ``first``)."""
     if lc.layer_types:
-        return _scan_kinds(body, carry, stack, lc.layer_types)
+        n = sum(s["ln1"]["scale"].shape[0] for s in stack.values())
+        before = lc.layer_types[:first]
+        return _scan_kinds(
+            body, carry, stack, lc.layer_types[first:first + n],
+            {kind: before.count(kind) for kind in stack},
+            lc.moe_experts and lc.moe_impl == "dropless")
     n = stack["ln1"]["scale"].shape[0]
     held, kinds = {}, None
     if lc.moe_experts and lc.moe_impl == "dropless":
-        held = {k: stack["moe"][k] for k in _EXPERT_WEIGHTS
-                if k in stack["moe"]}
-        stack = {**stack, "moe": {k: v for k, v in stack["moe"].items()
-                                  if k not in held}}
+        held, stack = _hold_experts(stack)
     if lc.index_topk:
         types = lc.indexer_types
         own = [k == "full" for k in types[first:first + n]]
@@ -765,10 +905,22 @@ def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
     return carry
 
 
-def _scan_kinds(body, carry, stacks: Dict, kinds: Tuple[str, ...]):
+def _hold_experts(stack: Dict):
+    """(the experts' weights of a routed stack, whole; the stack without
+    them, for a scan to slice)."""
+    held = {k: stack["moe"][k] for k in _EXPERT_WEIGHTS if k in stack["moe"]}
+    return held, {**stack, "moe": {k: v for k, v in stack["moe"].items()
+                                   if k not in held}}
+
+
+def _scan_kinds(body, carry, stacks: Dict, kinds: Tuple[str, ...],
+                offsets: Dict[str, int], routed: bool = False):
     """``body(carry, lp, li) -> carry`` over layers whose kind, one of
     ``stacks``' keys, is listed in ``kinds``; ``lp`` is the layer's slice
-    of its kind's stack and ``li`` its index there. The list is cut into
+    of its kind's stack and ``li`` its index there plus ``offsets[kind]``
+    (the kind's layers that ran before these stacks). Where the layers
+    are ``routed`` the experts' weights stay whole in ``lp`` beside
+    ``lp["moe"]["layer"]``, as in ``scan_stack``. The list is cut into
     its shortest repeating period (ten layers, four times) and a period
     into runs of one kind: a ``lax.scan`` over the periods holds one
     ``lax.scan`` a run (a run of one layer: the body itself), so the
@@ -787,16 +939,30 @@ def _scan_kinds(body, carry, stacks: Dict, kinds: Tuple[str, ...]):
         else:
             runs.append([kind, kinds[:i].count(kind), 1])
 
+    held = {}
+    if routed:
+        split = {kind: _hold_experts(stack)
+                 for kind, stack in stacks.items() if "moe" in stack}
+        held = {kind: h for kind, (h, _rest) in split.items()}
+        stacks = {**stacks, **{kind: rest
+                               for kind, (_h, rest) in split.items()}}
+
     def one_period(carry, rep):
         for kind, before, length in runs:
             stack = stacks[kind]
             base = rep * kinds[:period].count(kind) + before
 
-            def one(carry, j, stack=stack, base=base):
+            def one(carry, j, stack=stack, base=base, kind=kind):
                 li = base + j
                 lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
                     a, li, 0, keepdims=False), stack)
-                return body(carry, lp, li), None
+                if kind in held:
+                    lp = {**lp, "moe": {**lp["moe"], **held[kind],
+                                        "layer": li}}
+                # no "+ 0": where the stacks hold all of a kind's layers
+                # the program's text stays what it was before offsets
+                at = li + offsets[kind] if offsets[kind] else li
+                return body(carry, lp, at), None
 
             if length == 1:
                 carry, _ = one(carry, 0)
@@ -880,22 +1046,37 @@ def select_attn_fn(config: TransformerConfig,
     raise ValueError(f"unknown attn_impl {c.attn_impl!r}")
 
 
-def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
-    q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, wp["wk"].astype(c.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, wp["wv"].astype(c.dtype))
-    if c.rotary_dim:  # 0: no positional term at all
-        q, k = _rotary(q, k, c.rotary_dim, positions, c.rope_theta)
-    if c.attn_scale is not None:
-        # every attention (dense, flash, the decode kernel) scales its
-        # scores by 1/sqrt(d_head) itself: the queries carry the rest
-        q = q * (c.attn_scale * c.d_head ** 0.5)
-    attn_out = attn_fn(q, k, v)
+def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn,
+               window: bool = False):
+    """MHA / GQA. A ``window`` layer (``TransformerConfig.window``) has
+    its own KV heads (the weights' shapes) and rotary base, and its
+    ``attn_fn`` takes the layer's sink logits as well: ``attn_fn(q, k, v,
+    sink)``, ``sink`` [H] or None."""
+    scope = "raytpu.swa" if window else "raytpu.attn"
+    with jax.named_scope(scope + ".project"):
+        q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, wp["wk"].astype(c.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, wp["wv"].astype(c.dtype))
+        if c.value_scale != 1.0:
+            v = v * c.value_scale
+        if c.rotary_dim:  # 0: no positional term at all
+            q, k = _rotary(q, k, c.rotary_dim, positions,
+                           c.mha_kind(window)[1])
+        if c.attn_scale is not None:
+            # every attention (dense, flash, the decode kernel) scales its
+            # scores by 1/sqrt(d_head) itself: the queries carry the rest
+            q = q * (c.attn_scale * c.d_head ** 0.5)
+    if window:  # marks raytpu.swa.attend, and .ring where it keeps one
+        attn_out = attn_fn(q, k, v, wp.get("sink"))
+    else:
+        with jax.named_scope("raytpu.attn.attend"):
+            attn_out = attn_fn(q, k, v)
     extra = None
     if isinstance(attn_out, tuple):
         attn_out, extra = attn_out
-    return jnp.einsum("bshk,hkd->bsd", attn_out,
-                      wp["wo"].astype(c.dtype)), extra
+    with jax.named_scope(scope + ".project"):
+        out = jnp.einsum("bshk,hkd->bsd", attn_out, wp["wo"].astype(c.dtype))
+    return out, extra
 
 
 def ssm_split(xbc, c: TransformerConfig):
@@ -1059,6 +1240,8 @@ def apply_block(
     h = _rms_norm(x, lp["ln1"]["scale"], c.norm_eps)
     if "ssm" in lp:  # a layer of the other kind (TransformerConfig.layer_types)
         a, extra = _ssm_mixer(h, lp["ssm"], c, positions, attn_fn)
+    elif "swa" in lp:  # a window layer: ``attn_fn`` is a window's
+        a, extra = _mha_mixer(h, lp["swa"], c, positions, attn_fn, True)
     else:
         a, extra = _MIXERS[c.mixer](h, lp["attn"], c, positions, attn_fn)
     if c.residual_scale != 1.0:
@@ -1132,11 +1315,17 @@ def forward(
     positions = jnp.arange(tokens.shape[1])
     attn_fn = select_attn_fn(c, mesh)
 
+    def window_fn(q, k, v, sink):
+        with jax.named_scope("raytpu.swa.attend"):
+            return window_attention(q, k, v, sink, window=c.window)
+
     carry = (x, jnp.zeros((), jnp.float32))
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, lc=lc):
             x, aux = carry
-            y, a, _ = apply_layer(x, lp, lc, positions, attn_fn, mesh=mesh)
+            y, a, _ = apply_layer(
+                x, lp, lc, positions,
+                window_fn if "swa" in lp else attn_fn, mesh=mesh)
             return (y, aux + a), None
 
         layer = remat_wrap(layer, c)

@@ -12,7 +12,9 @@ lies in the stacked [L, B, S_max, ...] cache, indexed by (layer, slot,
 first row): nothing slices a layer out.
 
 One body serves both dense caches, told apart by shapes alone. MHA / GQA:
-one key array [L, B, S, Hkv, D] and a value array like it. Latent
+one key array [L, B, S, Hkv, D] and a value array like it, as wide or
+narrower ([.., Dv]; a cache whose rows hold their heads flat is one KV
+head to the kernel, its caller's business). Latent
 attention: one key all heads share, in two arrays [L, B, S, R] and
 [L, B, S, rope], whose first is the value too: the grouped-query form
 with one KV head, a key in parts and no value array. The chunk's rows of
@@ -24,8 +26,10 @@ beside them.
 
 The mathematics is the plain form's: bf16 operands, float32 scores,
 float32 running max / sum / accumulator seeded by the token's own
-position, the strict mask ``row < pos[b]``. Off the TPU the kernel runs in
-the Pallas interpreter.
+position (or, for a window layer's ring, which already holds the token's
+row, by the layer's sink: a logit of sum 1 that carries no value,
+``generation._attend_ring``), the strict mask ``row < pos[b]``. Off the
+TPU the kernel runs in the Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -111,8 +115,9 @@ def decode_attention(
 ) -> jax.Array:
     """softmax over each slot's rows below ``pos`` and the token itself:
     [B, H, Dv] in ``q``'s type. ``m0`` and ``acc0`` seed the online
-    softmax with the token's own position (sum 1), so a slot without rows
-    returns ``acc0``. A key part marked in ``rows_last`` is handed over as
+    softmax with the token's own position (sum 1; a caller whose rows hold
+    the token already seeds it with a sink's logit and a zero value), so
+    a slot without rows returns ``acc0``. A key part marked in ``rows_last`` is handed over as
     [L, B, Dk_i, S] (how the chip keeps a narrow array: see the caller)."""
     n_slots, n_heads, _ = q[0].shape
     s_max = keys[0].shape[2]

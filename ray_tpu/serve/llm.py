@@ -440,7 +440,11 @@ class LLMEngine:
           layer that owns an indexer), ``dsa_rows_selected`` (rows
           attended: min(pos + 1, index_topk) a lane a layer) and
           ``dsa_rows_live`` (rows a walk over every live row would have
-          attended: pos + 1).
+          attended: pos + 1). For a model with window layers:
+          ``window_rows_read`` (ring rows the decode attention read:
+          min(pos + 1, window) a live lane a window layer, beside
+          ``attn_rows_read``, which counts the rows of the layers that
+          keep every row).
         """
         with self._lock:
             out = {

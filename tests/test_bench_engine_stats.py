@@ -150,14 +150,16 @@ def test_each_engine_metric_lists_only_cells_that_report_what_it_moves():
                for m in doc["end_to_end"]}
     mine = [m for m in doc["per_layer"] if m["name"] in CADENCE + ADMIT]
     assert [m["name"] for m in mine] == CADENCE + ADMIT
-    assert doc["per_layer"][-len(mine):] == mine  # appended, nothing moved
+    at = doc["per_layer"].index(mine[0])  # appended together, none moved
+    assert doc["per_layer"][at:at + len(mine)] == mine
     for m in mine:
         assert set(m["workloads"]) <= reports[m["moves"]], m["name"]
         assert (m["layer"], m["source"]) == (
             "serving engine", "program_counter")
         assert _metric(m["name"])[0] is ratio
     on = {m["name"]: m["workloads"] for m in mine}
-    assert all(on[n] == on[CADENCE[0]] and len(on[n]) == 4 for n in CADENCE)
+    # later cells join (PR 39: the window / full attention model's)
+    assert all(on[n] == on[CADENCE[0]] and len(on[n]) >= 4 for n in CADENCE)
     assert all(on[n] == on[ADMIT[0]] and len(on[n]) == 3 for n in ADMIT)
 
 

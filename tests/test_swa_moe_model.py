@@ -1,0 +1,520 @@
+"""A model of full attention layers and window layers under a share of
+routed experts (MiMo-V2-Flash's kind) against its plain reference, at test
+size on the CPU with seeded random weights: the uncached forward, the
+engine's two programs through a slot's rows and ring for prompts below, at
+and above the window and decoding across wraps of the ring, two requests
+of unlike lengths in one engine batch, the blocked attentions and the
+ring's kernel (interpreter) against the plain forms, the shares of a
+routed layer against the uncut layer, the ablations a comparison must
+refuse, ill-formed ``layer_types``, the two copies of the reference, and
+the benchmark's new cell resolved and rehearsed."""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import generation as gen
+from ray_tpu.models import reference_swa as ref
+from ray_tpu.models.transformer import (
+    TransformerConfig,
+    forward,
+    init_params,
+    param_logical_axes,
+)
+from ray_tpu.ops.attention import (
+    NEG_INF,
+    blocked_causal_attention,
+    causal_attention,
+    window_attention,
+)
+from ray_tpu.ops.decode_attention import slot_schedule
+from ray_tpu.ops.moe import routed_ffn
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# F(dense) | W W F W, a window of 8 rows, 8 experts (2 a token)
+CFG = TransformerConfig.tiny_swa_moe(dtype=jnp.float32)
+TOL = 2e-4  # float32 against float32: rounding order only
+
+
+def hp_of(cfg):
+    return {
+        "n_heads": cfg.n_heads,
+        "kv_heads": {"F": cfg.mha_kind(False)[0], "W": cfg.mha_kind(True)[0]},
+        "theta": {"F": cfg.mha_kind(False)[1], "W": cfg.mha_kind(True)[1]},
+        "d_head": cfg.d_head, "rotary_dim": cfg.rotary_dim,
+        "window": cfg.window, "value_scale": cfg.value_scale,
+        "eps": cfg.norm_eps, "top_k": cfg.moe_top_k,
+        "route_scale": cfg.moe_route_scale,
+        "first_expert": cfg.moe_first_expert,
+        "layer_types": cfg.layer_types, "n_dense_layers": cfg.n_dense_layers}
+
+
+HP = hp_of(CFG)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(CFG, jax.random.key(0))
+
+
+def tokens_of(n, seed=1):
+    return jax.random.randint(jax.random.key(seed), (n,), 0, CFG.vocab_size)
+
+
+def ref_logits(params, tokens, **kw):
+    with jax.default_matmul_precision("highest"):
+        return ref.forward_logits(params, tokens, HP, **kw)
+
+
+def prefill(params, cache, slot, prompt, bucket, cfg=CFG):
+    padded = jnp.zeros((1, bucket), jnp.int32).at[0, :len(prompt)].set(prompt)
+    return gen.prefill_into_slot(
+        params, padded, jnp.int32(len(prompt)), jnp.int32(slot), cache, cfg)
+
+
+# -- the description ---------------------------------------------------------
+
+def test_config_follows_the_published_numbers():
+    kinds = ("attention",) + ("window",) * 4 + ("attention", "window")
+    cut = TransformerConfig.mimo_v2_flash(
+        7, layer_types=kinds, vocab_size=19072, moe_experts_held=16)
+    assert cut.param_count() == 3_429_955_392  # ISSUE 39's arithmetic
+    assert (cut.n_attn_layers, cut.n_window_layers) == (2, 5)
+    whole = TransformerConfig.mimo_v2_flash()
+    assert whole.layer_types.count("attention") == 9
+    assert whole.layer_types[:7] == kinds
+    assert whole.mha_kind(False) == (4, 5e6)
+    assert whole.mha_kind(True) == (8, 1e4)
+    cache = jax.eval_shape(lambda: gen.init_kv_cache(cut, 48, 17408))
+    assert cache["k"].shape == (2, 48, 17408, 768)  # the heads lie flat
+    assert cache["v"].shape == (2, 48, 17408, 512)
+    assert cache["state"]["wk"].shape == (5, 48, 128, 1536)
+    assert cache["state"]["wv"].shape == (5, 48, 128, 1024)
+    foot = gen.slot_footprint(cache)
+    assert (foot["row_bytes"], foot["state_bytes"]) == (5120, 3_276_800)
+    assert gen.block_stat_keys(cut)[-1] == "window_rows_read"
+
+
+def test_params_axes_and_count_agree(params):
+    axes = param_logical_axes(CFG)
+    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(
+        x, tuple)) == jax.tree.structure(params)
+    for a, p in zip(jax.tree.leaves(axes, is_leaf=lambda x: isinstance(
+            x, tuple)), jax.tree.leaves(params)):
+        assert len(a) == p.ndim
+    assert CFG.param_count() == sum(p.size for p in jax.tree.leaves(params))
+    assert params["window_layers"]["swa"]["sink"].shape == (3, 4)
+    assert params["window_layers"]["swa"]["wk"].shape == (3, 64, 4, 64)
+    assert params["layers"]["attn"]["wv"].shape == (1, 64, 2, 32)
+    assert "sink" not in params["layers"]["attn"]
+
+
+@pytest.mark.parametrize("bad", [
+    dict(layer_types=("attention", "window")),  # one entry a layer
+    dict(layer_types=("attention", "window", "window", "local", "window")),
+    dict(window=0),
+    dict(window_kv_heads=3),  # 4 heads over 3 KV heads
+    dict(layer_types=("window", "attention", "window", "attention",
+                      "window")),  # the leading dense layer attends all
+    dict(moe_impl="capacity"),
+    dict(layer_types=("attention", "window", "ssm", "attention", "window"),
+         ssm_heads=4, ssm_head_dim=8, ssm_state=16),
+    dict(layer_types=()),  # a window and no window layer
+    dict(mixer="mla"),
+], ids=["length", "kind", "no_window", "heads", "dense_window", "capacity",
+        "beside_ssm", "no_layer", "mla"])
+def test_ill_formed_layer_types_are_refused(bad):
+    with pytest.raises(ValueError):
+        TransformerConfig.tiny_swa_moe(**bad)
+
+
+# -- the forward and the two programs against the reference ------------------
+
+def test_the_uncached_forward_matches_the_reference(params):
+    toks = tokens_of(37)
+    got = forward(params, toks[None], CFG)[0]
+    assert float(jnp.abs(got - ref_logits(params, toks)).max()) < TOL
+
+
+@pytest.mark.parametrize("prompt_len,bucket", [
+    (5, 8), (8, 8), (13, 16), (29, 32), (100, 128)],
+    ids=["below", "at", "above", "far_above", "many_windows"])
+def test_prefill_and_decode_through_rows_and_ring_match_the_reference(
+        params, prompt_len, bucket):
+    """A padded prompt into slot 1 of 2, then 20 decode steps (the ring of
+    8 rows wraps at least twice), slot 0 parked: every step's logits
+    against the reference's full forward over prompt + answer."""
+    n_new = 20
+    toks = tokens_of(prompt_len + n_new, seed=3)
+    want = ref_logits(params, toks)
+    cache = gen.init_kv_cache(CFG, 2, 160)
+    lg, cache = prefill(params, cache, 1, toks[:prompt_len], bucket)
+    worst = float(jnp.abs(lg - want[prompt_len - 1]).max())
+    for t in range(prompt_len, prompt_len + n_new):
+        tok = jnp.zeros(2, jnp.int32).at[1].set(toks[t])
+        pos = jnp.zeros(2, jnp.int32).at[1].set(t)
+        lg, cache = gen.decode_step_multi(params, tok, cache, pos, CFG)
+        worst = max(worst, float(jnp.abs(lg[1] - want[t]).max()))
+    assert worst < TOL
+
+
+def test_a_window_layer_keeps_a_ring_and_counts_what_it_reads(params):
+    """What the cache holds after a prefill of 13 tokens and two steps,
+    and ``window_rows_read``: min(pos + 1, 8) a live lane a window layer,
+    nothing for the parked lane."""
+    cache = gen.init_kv_cache(CFG, 2, 64)
+    assert cache["state"]["wk"].shape == (3, 2, 8, 4 * 64)
+    assert cache["k"].shape == (2, 2, 64, 2 * 64)
+    assert cache["v"].shape == (2, 2, 64, 2 * 32)
+    _, cache = prefill(params, cache, 0, tokens_of(13, 2), 16)
+    ring = np.asarray(cache["state"]["wk"])
+    assert ring[:, 0].any(axis=-1).all() and not ring[:, 1].any()
+    zeros = jnp.zeros(2, jnp.int32)
+    _t, cache, _tok, pos, _c, stats = gen.decode_block(
+        params, cache, jnp.array([3, 5], jnp.int32),
+        jnp.array([13, 0], jnp.int32), jnp.zeros(2), zeros, zeros, CFG, 2)
+    assert pos.tolist() == [15, 0]
+    assert int(stats["window_rows_read"]) == 3 * 8 * 2
+    assert not np.asarray(cache["state"]["wk"])[:, 1, 1:].any()
+    short = gen.init_kv_cache(CFG, 1, 64)
+    _, short = prefill(params, short, 0, tokens_of(3, 2), 8)
+    *_x, stats = gen.decode_block(
+        params, short, jnp.array([3], jnp.int32), jnp.array([3], jnp.int32),
+        jnp.zeros(1), zeros[:1], zeros[:1], CFG, 2)
+    assert int(stats["window_rows_read"]) == 3 * (4 + 5)
+
+
+def test_prefill_leaves_the_other_slots_bit_identical(params):
+    cache = gen.init_kv_cache(CFG, 2, 64)
+    _, cache = prefill(params, cache, 0, tokens_of(19, 2), 32)
+    before = jax.tree.map(lambda a: np.asarray(a[:, 0]), cache)
+    _, cache = prefill(params, cache, 1, tokens_of(11, 3), 16)
+    after = jax.tree.map(lambda a: np.asarray(a[:, 0]), cache)
+    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+        assert (a == b).all()
+
+
+# -- the engine ---------------------------------------------------------------
+
+def engine_of(params, **kw):
+    from ray_tpu.serve.llm import LLMEngine
+
+    return LLMEngine(
+        jax.tree.map(jnp.array, params), CFG, max_slots=2, max_len=64,
+        prefill_buckets=(8, 16, 32), **kw)
+
+
+def worst_margin(params, prompt, ids):
+    """How far the served tokens' logits lie under the reference's
+    largest, teacher-forced on the served tokens (0: the same tokens)."""
+    seq = jnp.asarray(list(prompt) + list(ids[:-1]), jnp.int32)
+    logits = ref_logits(params, seq)
+    return float(ref.served_token_margin(
+        logits[len(prompt) - 1:], jnp.asarray(ids, jnp.int32)).max())
+
+
+def test_engine_serves_two_requests_of_unlike_lengths_in_one_batch(params):
+    """One below the window (5 tokens) and one far above it (27), the
+    second admitted while the first decodes: both decode in the same
+    blocks, each through its own rows and its own rings."""
+    from ray_tpu.serve.llm import _END
+
+    eng = engine_of(params)
+    try:
+        a, b = np.asarray(tokens_of(5, 5)), np.asarray(tokens_of(27, 6))
+        first = eng.submit(a, max_new_tokens=24)
+        got_a = [first.out.get(timeout=120)]  # decoding when b arrives
+        got_b = eng.generate(b, max_new_tokens=12)
+        while (item := first.out.get(timeout=120)) is not _END:
+            assert not isinstance(item, BaseException), item
+            got_a.append(item)
+        assert len(got_a) == 24 and len(got_b) == 12
+        assert worst_margin(params, a, got_a) < TOL
+        assert worst_margin(params, b, got_b) < TOL
+        s = eng.stats()
+        assert s["slot_state_bytes"] == 3 * 8 * (4 * 64 + 4 * 32) * 4
+        assert s["slot_row_bytes"] == 2 * (2 * 64 + 2 * 32) * 4
+        assert 0 < s["window_rows_read"] <= 3 * 8 * s["slot_steps"]
+        assert s["attn_rows_read"] > 0 and s["moe_assignments"] > 0
+        assert s["requests_failed"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(params):
+    eng = engine_of(params)
+    try:
+        p, q = np.asarray(tokens_of(17, 7)), np.asarray(tokens_of(6, 8))
+        eng.generate(p, max_new_tokens=9)  # slot 0's ring full, then freed
+        again = eng.generate(q, max_new_tokens=8)  # a prompt under a window
+    finally:
+        eng.shutdown()
+    assert worst_margin(params, q, again) < TOL
+
+
+def test_generate_runs_the_served_programs(params):
+    prompt = jnp.stack([tokens_of(11, 3), tokens_of(11, 4)])
+    ids = gen.generate(params, prompt, CFG, max_new_tokens=12, max_len=32)
+    for b in range(2):
+        assert worst_margin(params, np.asarray(prompt[b]),
+                            np.asarray(ids[b]).tolist()) < TOL
+
+
+# -- the blocked attentions and the ring's kernel against the plain forms ----
+
+def _qkv(seed, b, s, h, g, d, dv):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, h, d)),
+            jax.random.normal(ks[1], (b, s, g, d)),
+            jax.random.normal(ks[2], (b, s, g, dv)),
+            jax.random.normal(ks[3], (h,)) + 1.0)
+
+
+def _plain_window(q, k, v, sink, window):
+    """Every score of every head, a mask, the sink in the denominator."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    t = jnp.arange(q.shape[1])
+    seen = (t[None, :] <= t[:, None]) & (t[None, :] > t[:, None] - window)
+    e = jnp.where(seen, jnp.exp(s), 0.0)
+    total = e.sum(-1, keepdims=True)
+    if sink is not None:
+        total = total + jnp.exp(sink)[None, :, None, None]
+    return jnp.einsum("bhqk,bkhd->bqhd", e / total, v)
+
+
+@pytest.mark.parametrize("s,window,block,sink", [
+    (37, 8, 256, True), (64, 8, 16, True), (64, 24, 16, False),
+    (48, 64, 16, True), (16, 1, 8, True)],
+    ids=["one_block", "blocks", "no_sink", "window_over_s", "window_1"])
+def test_window_attention_equals_the_masked_plain_form(s, window, block, sink):
+    q, k, v, b = _qkv(s, 2, s, 4, 2, 16, 8)
+    b = b if sink else None
+    with jax.default_matmul_precision("highest"):
+        got = window_attention(q, k, v, b, window=window, block=block)
+        want = _plain_window(q, k, v, b, window)
+    assert got.shape == (2, s, 4, 8)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("s,block,g", [(40, 1024, 2), (64, 16, 1),
+                                       (96, 32, 4)],
+                         ids=["one_tile", "tiles_one_kv_head", "tiles_mha"])
+def test_blocked_causal_attention_equals_the_dense_form(s, block, g):
+    q, k, v, _ = _qkv(s + 1, 2, s, 4, g, 16, 8)  # values narrower than keys
+    with jax.default_matmul_precision("highest"):
+        got = blocked_causal_attention(q, k, v, block=block)
+        want = causal_attention(q, k, v)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("sink", [True, False], ids=["sink", "no_sink"])
+def test_the_rings_kernel_equals_the_plain_softmax(sink):
+    """Lanes at 0 (parked), under a window, at it and far past it: the
+    kernel (interpreter) over each lane's first ``ring_rows`` rows,
+    seeded by the sink, against every score materialised."""
+    B, H, G, D, Dv, W, L = 5, 4, 2, 16, 8, 8, 3
+    ks = jax.random.split(jax.random.key(4), 4)
+    q = jax.random.normal(ks[0], (B, 1, H, D))
+    wk = jax.random.normal(ks[1], (L, B, W, G * D))
+    wv = jax.random.normal(ks[2], (L, B, W, G * Dv))
+    b = jax.random.normal(ks[3], (H,)) + 1.0 if sink else None
+    pos = jnp.array([0, 3, 7, 8, 1000], jnp.int32)
+    rows = gen.ring_rows(pos, W)
+    assert rows.tolist() == [0, 4, 8, 8, 8]
+    with jax.default_matmul_precision("highest"):
+        got = gen._attend_ring(q, wk, wv, b, rows, layer=1,
+                               schedule=slot_schedule(rows, W, W))[:, 0]
+        kk = jnp.repeat(wk[1].reshape(B, W, G, D), H // G, 2)
+        vv = jnp.repeat(wv[1].reshape(B, W, G, Dv), H // G, 2)
+        s = jnp.einsum("bhd,bwhd->bhw", q[:, 0], kk) * D ** -0.5
+        e = jnp.where(jnp.arange(W)[None, None, :] < rows[:, None, None],
+                      jnp.exp(s), 0.0)
+        total = e.sum(-1, keepdims=True) + (
+            jnp.exp(b)[None, :, None] if sink else 0.0)
+        want = jnp.einsum("bhw,bwhd->bhd", e / jnp.maximum(total, 1e-30), vv)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert not np.asarray(got[0]).any()  # the parked lane: nothing read
+    assert NEG_INF < -1e29
+
+
+# -- a chip's share of a routed layer ----------------------------------------
+
+def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(params):
+    """Every share of 2 of the 8 experts, each run as the chip that holds
+    it would (``routed_ffn``, no shared expert, scale 1), summed over all
+    four shares equals the uncut layer; each share alone equals the
+    reference's share."""
+    moe = jax.tree.map(lambda a: a[0], params["window_layers"]["moe"])
+    x = jax.random.normal(jax.random.key(8), (40, CFG.d_model))
+    hp = {"top_k": CFG.moe_top_k, "route_scale": CFG.moe_route_scale}
+    with jax.default_matmul_precision("highest"):
+        whole = ref.routed_experts(x, moe, hp, {})
+    total = jnp.zeros_like(x)
+    for first in range(0, CFG.moe_experts, 2):
+        share = {**moe, **{k: moe[k][first:first + 2]
+                           for k in ("wg", "wi", "wo")}}
+        got, stats = routed_ffn(x, share, top_k=CFG.moe_top_k,
+                                route_scale=CFG.moe_route_scale,
+                                first_expert=first)
+        assert int(stats["moe_experts_capacity"]) == 2
+        with jax.default_matmul_precision("highest"):
+            want = ref.routed_experts(
+                x, share, {**hp, "first_expert": first}, {})
+        assert float(jnp.abs(got - want).max()) < TOL
+        total = total + got
+    assert float(jnp.abs(total - whole).max()) < TOL
+
+
+def test_a_model_that_holds_a_share_matches_the_reference_of_that_share():
+    cfg = dataclasses.replace(CFG, moe_experts_held=4, moe_first_expert=2)
+    params = init_params(cfg, jax.random.key(5))
+    assert params["window_layers"]["moe"]["wi"].shape[:2] == (3, 4)
+    assert params["window_layers"]["moe"]["router"].shape == (3, 64, 8)
+    toks = tokens_of(21, 9)
+    with jax.default_matmul_precision("highest"):
+        want = ref.forward_logits(params, toks, hp_of(cfg))
+    cache = gen.init_kv_cache(cfg, 1, 32)
+    lg, cache = prefill(params, cache, 0, toks[:15], 16, cfg)
+    assert float(jnp.abs(lg - want[14]).max()) < TOL
+    pos = jnp.array([15], jnp.int32)
+    for t in range(15, 21):
+        lg, cache = gen.decode_step_multi(
+            params, toks[t][None], cache, pos, cfg)
+        assert float(jnp.abs(lg[0] - want[t]).max()) < TOL
+        pos = pos + 1
+
+
+# -- what a comparison must refuse -------------------------------------------
+
+@pytest.mark.parametrize("ablate", [
+    {"window": 7}, {"window": 9}, {"no_sink": True}, {"sink_on_full": True},
+    {"no_value_scale": True}, {"swap_theta": True}, {"rotary_all": True},
+    {"window_grouping": True}, {"window_attends_all": True},
+    {"fp8_weights": True},
+], ids=lambda a: "%s_%s" % next(iter(a.items())))
+def test_each_ablation_fails_the_comparison(params, ablate):
+    """The served path (prefill of 21 tokens in a bucket of 32, then 12
+    decode steps) equals the reference and differs from each wrong one."""
+    n, n_new = 21, 12
+    toks = tokens_of(n + n_new, seed=11)
+    cache = gen.init_kv_cache(CFG, 1, 64)
+    _, cache = prefill(params, cache, 0, toks[:n], 32)
+    pos = jnp.array([n], jnp.int32)
+    for t in range(n, n + n_new):
+        lg, cache = gen.decode_step_multi(
+            params, toks[t][None], cache, pos, CFG)
+        pos = pos + 1
+
+    def distance(**kw):
+        want = ref_logits(params, toks, **kw)
+        return float(ref.vector_distance(lg[0], want[-1])[1])
+
+    assert distance() < TOL < 1e-3 < distance(ablate=ablate)
+
+
+def test_reference_copies_are_identical_below_their_headers():
+    marker = "# ---- below this line the two copies are identical ----\n"
+
+    def body(path):
+        with open(os.path.join(ROOT, path)) as f:
+            text = f.read()
+        assert text.count(marker) == 1
+        return text.split(marker)[1]
+
+    mine = body("ray_tpu/models/reference_swa.py")
+    assert mine == body("benchmarks/reference_swa_moe.py")
+    for name in ("ray_tpu", "generation", "transformer", "ops."):
+        assert name not in mine  # none of the program's code
+
+
+# -- the benchmark resolves and rehearses the new cell -----------------------
+
+CELL = "serve-mimo-codeagent-saturated"
+NEW = ("model.window_attn_time_share", "model.full_attn_time_share",
+       "model.prefill_window_attn_share", "model.prefill_full_attn_share",
+       "engine.window_rows_share", "kernel.decode_hbm_share.swa_moe")
+
+
+def test_the_benchmarks_arithmetic_agrees_with_the_program():
+    sys.path.insert(0, ROOT)
+    try:
+        from benchmarks import swa_moe_model
+    finally:
+        sys.path.remove(ROOT)
+    with open(os.path.join(
+            ROOT, "benchmarks/configs/mimo-v2-flash-l7-e16-bf16-serve.json"
+    )) as f:
+        model = json.load(f)
+    cfg = swa_moe_model.transformer_config(model)
+    dims = swa_moe_model.dims(cfg)
+    n = swa_moe_model.param_count(dims)
+    assert n["total"] == cfg.param_count() == 3_429_955_392
+    assert (n["attn_full"], n["attn_window"]) == (89_128_960, 94_371_904)
+    assert n["routed"] == 403_702_016 and n["dense_ffn"] == 201_326_592
+    assert swa_moe_model.slot_bytes(dims) == {"row": 5120, "state": 3_276_800}
+    assert cfg.layer_types == ("attention",) + ("window",) * 4 + (
+        "attention", "window")
+    assert (cfg.rotary_dim, cfg.window, cfg.value_scale) == (64, 128, 0.707)
+    # every weight once, nothing touched, nothing read
+    fixed = swa_moe_model.decode_step_bytes(dims, 0, 0, 0)
+    assert fixed == 2 * (n["total"] - 6 * 16 * n["expert"]
+                         - 19072 * 4096)  # less the experts, the embedding
+    flops = swa_moe_model.prefill_attention_flops(dims, 16384)
+    assert flops["window"] < 0.02 * flops["full"]
+    tiny = swa_moe_model.transformer_config(
+        {**model, **model["rehearsal"]})
+    assert tiny.layer_types.count("window") == 3 and tiny.window == 8
+
+
+def test_the_list_resolves_the_new_cell():
+    out = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--list"], cwd=ROOT,
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rows = [json.loads(line) for line in out.stdout.splitlines()]
+    assert len(rows) >= 8  # later PRs add cells
+    row = next(r for r in rows if r["cell"] == CELL)
+    assert (row["runner"], row["traffic"], row["chips"]) == (
+        "serve_swa_moe", "codeagent-saturated", 1)
+    assert row["end_to_end"] == ["tpot_p50_ms", "setup_s"]
+    for name in NEW + ("model.decode_step_ms", "device.idle_share.serve",
+                       "engine.kv_read_share", "model.moe_time_share"):
+        assert name in row["per_layer"]
+
+
+@pytest.mark.phase_limit(900)
+def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    mine = [m["name"] for m in doc["per_layer"]
+            if CELL in m.get("workloads", ())]
+    out = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
+         str(2 ** 31 + 7), "--seconds", "6", "--trace", "1",
+         "--rehearse-cpu"],
+        cwd=ROOT, capture_output=True, text=True, timeout=900,
+        # the suite's eight virtual host devices are not the cell's one
+        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
+    assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]
+    walked = next(line for line in out.stdout.splitlines()
+                  if line.startswith("readers walked"))
+    values = json.loads(walked.split(": ", 1)[1])
+    assert sorted(values) == sorted(mine)
+    share = values["engine.window_rows_share"]
+    assert share is not None and 0 < share < 100
+    note = json.loads(next(line for line in out.stdout.splitlines()
+                           if line.startswith('{"note"')))
+    end = note["note"]["backlog"]["end"]
+    assert end["slot_state_bytes"] == 3 * 8 * (4 * 64 + 4 * 32) * 2
+    assert 0 < end["window_rows_read"] <= 3 * 8 * end["slot_steps"]
+    probe = note["note"]["probe"]
+    assert probe["replayed"] and probe["window_layer"]["ring_median"] < 0.05

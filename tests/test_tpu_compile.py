@@ -204,6 +204,81 @@ def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "raytpu.ssm.scan" in hlo
 
 
+@pytest.mark.parametrize("program", ["decode_block", "prefill_12288"])
+def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """MiMo-V2-Flash's first pipeline stage (layers 0-6, ``F(dense) | W W
+    W W F W``, 16 of 256 experts, 1/8 vocabulary, every width as
+    published, bf16) at the benchmark's engine sizes: 48 slots x 17,408
+    rows. ISSUE 39: the two full layers' rows (ROW leaves, the KV heads
+    flat, values narrower than keys) and the five window layers' rings
+    (STATE leaves) are updated in place, no cache leaf, ring or parameter
+    stack is copied (a run of like layers is a scan that indexes the WHOLE
+    stacks), both kinds' decode attention is the one kernel, a window
+    layer's over its ring, and a prefill holds no [S, S] array: the
+    12,288 bucket, because the dense FFN's ``d_ff`` is 16,384."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.mimo_v2_flash(
+        7, layer_types=("attention",) + ("window",) * 4 + (
+            "attention", "window"),
+        vocab_size=19072, moe_experts_held=16, param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 48, 17408)))
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if program == "decode_block":
+        low = gen.decode_block.lower(
+            params, cache, arr((48,)), arr((48,)), arr((48,), jnp.float32),
+            arr((48,)), arr((48,)), cfg, 2)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, 12288)), arr(()), arr(()), cache, cfg)
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    foot = gen.slot_footprint(cache)
+    assert (foot["row_bytes"], foot["state_bytes"]) == (5120, 3_276_800)
+    cache_bytes = 48 * (foot["state_bytes"] + 17408 * foot["row_bytes"])
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.5 * 2 ** 30
+    hlo = compiled.as_text()
+    for of in ("bf16[2,48,17408,", "bf16[1,48,17408,",  # the rows
+               "bf16[5,48,128,", "bf16[1,48,128,",  # the rings
+               "bf16[5,16,", "bf16[5,4096,", "bf16[5,64,",  # window stack
+               "bf16[1,16,", "bf16[1,4096,64,192", "bf16[1,4096,4,",
+               "bf16[1,64,128,4096", "bf16[1,4096,256",  # the other stacks
+               "bf16[16,4096,2048", "bf16[16,2048,4096",  # a layer's experts
+               "bf16[19072,", "bf16[4096,19072"):
+        assert not _copies(hlo, of), of
+    for scope in ("raytpu.swa.project", "raytpu.swa.attend",
+                  "raytpu.swa.ring", "raytpu.attn.project",
+                  "raytpu.attn.attend", "raytpu.moe.experts"):
+        assert scope in hlo
+    attends = [line for line in hlo.splitlines()
+               if "tpu_custom_call" in line and "decode_attention" in line]
+    if program == "decode_block":
+        # the dense layer and the period's full layer; the run of four
+        # window layers (one scan) and the period's last
+        assert sum("raytpu.attn.attend" in a for a in attends) == 2
+        assert sum("raytpu.swa.attend" in a for a in attends) == 2
+        assert len(attends) == 4
+    else:
+        assert not attends
+        assert "12288,12288" not in hlo  # no [S, S] array of any type
+
+
 @pytest.fixture(scope="module")
 def as_on_the_chip():
     """Here the backend is the CPU, where a Pallas kernel would be
