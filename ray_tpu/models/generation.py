@@ -1016,8 +1016,22 @@ def _prefill_recur(single, li, prompt_len, c: TransformerConfig):
     return _recurrence(recur)
 
 
+def prefill_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
+    """Names of the int32 counters the admission form of
+    ``prefill_into_slot`` returns with its first token: for dropless
+    routed experts ``prefill_moe_assignments`` and
+    ``prefill_moe_pair_rows`` (``ops/moe.routed_ffn``'s
+    ``moe_assignments`` and ``moe_pair_rows``, summed over the prompt's
+    routed layers). None for the other models."""
+    if config.moe_experts and config.moe_impl == "dropless":
+        return ("prefill_moe_assignments", "prefill_moe_pair_rows")
+    return ()
+
+
 def _add_stats(total, stats):
-    return {**total, **{k: total[k] + v for k, v in stats.items()}}
+    """``total`` with the counters of ``stats`` that it names added."""
+    return {**total, **{k: total[k] + v for k, v in stats.items()
+                       if k in total}}
 
 
 def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
@@ -1206,9 +1220,14 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     program is a whole admission: it samples the first token from those
     logits (``_sample_vec``, one row, count 0), writes the slot's entry of
     each vector (the token, ``prompt_len``, the temperature, the seed, 1)
-    and returns (first token, cache, lanes). Hand every scalar over as a
-    numpy value of one dtype (``np.int32``, ``np.float32``): a Python
-    number is weakly typed, which is another program."""
+    and returns (first token, cache, lanes, stats). Hand every scalar over
+    as a numpy value of one dtype (``np.int32``, ``np.float32``): a Python
+    number is weakly typed, which is another program. ``stats`` is empty
+    (no output at all) but for dropless routed experts: int32 scalars
+    ``prefill_moe_assignments`` (the (token, expert) pairs the prompt's
+    routed layers computed) and ``prefill_moe_pair_rows`` (the sorted-pair
+    rows they moved around the kernel: ``ops/moe.routed_ffn``), summed
+    over the layers; they leave the device as the token does."""
     c = config
     single = jax.tree.map(lambda a: jnp.zeros_like(a[:, :1]), cache)
     s_max = jax.tree.leaves(cache_rows(cache))[0].shape[2]
@@ -1298,10 +1317,13 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     if c.index_topk:
         choice = {"mask": jnp.zeros((S, S), bool),
                   "k": jnp.zeros((S, c.index_head_dim), c.dtype)}
-    carry = (x, single, choice)
+    # what an admission reports of its routed layers (none: an empty dict)
+    routed_stats = {k: jnp.zeros((), jnp.int32)
+                    for k in prefill_stat_keys(c)}
+    carry = (x, single, choice, routed_stats)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
-            x, single, choice = carry
+            x, single, choice, total = carry
             if "ssm" in lp:
                 attn = _prefill_recur(single, li, prompt_len, lc)
             elif "swa" in lp:
@@ -1311,14 +1333,15 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
             else:
                 attn = _prefill_attn_chosen(
                     single, li, lp["attn"], choice, lc)
-            y, _aux, single, _stats = apply_block(
+            y, _aux, single, stats = apply_block(
                 x, lp, lc, positions, attn, token_mask=real)
             if choice is not None:
                 single, choice = single
-            return y, single, choice
+            return y, single, choice, _add_stats(total, {
+                "prefill_" + k: v for k, v in stats.items()})
 
         carry = scan_stack(layer, carry, stack, lc, first)
-    x, single, _choice = carry
+    x, single, _choice, routed_stats = carry
     x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     last = x[0, prompt_len - 1]  # [D] — last REAL token's features
@@ -1335,7 +1358,7 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
                         jnp.zeros(1, jnp.int32))[0]
     lanes = tuple(lane.at[slot].set(v) for lane, v in zip(
         lanes, (first, prompt_len, temperature, seed, 1)))
-    return first, cache, lanes
+    return first, cache, lanes, routed_stats
 
 
 def generate(

@@ -127,6 +127,14 @@ def moe_ffn(
 # 8 experts a token and 6,144 wide would be 2.4 GB, four times over).
 ROUTED_TOKENS_A_PASS = 4096
 
+# Sorted-pair rows one turn of a share's loop gathers, computes and adds
+# back (``routed_ffn``): four of the kernel's 128-row tiles. From a
+# micro-run of one layer at the served widths on a v5e (PERF.md section 6,
+# PR 43): 384-512 rows read fastest at every prompt length (XLA's
+# scatter-add costs more a row above 512), and half a tile of dead rows a
+# pass is what the rounding leaves.
+ROUTED_ROWS_A_TILE = 512
+
 
 def routed_ffn(
     x: jax.Array,  # [..., D]
@@ -174,6 +182,23 @@ def routed_ffn(
     they lie and nothing slices (copies) a layer's experts out of the
     stack (``transformer.scan_stack``).
 
+    Such a share moves only the pairs it computes. Of ``tokens x top_k``
+    sorted pairs, the live ones (``held / E`` of them: 1 in 16 where a
+    chip holds 16 of 256 experts) sort in front, and a loop walks their
+    tiles of ``ROUTED_ROWS_A_TILE`` rows, ``ceil(live / tile)`` turns, a
+    device value: a turn gathers its rows of ``x``, runs the two grouped
+    products over them (the groups cut at the tile's edges) and adds
+    ``weight x result`` to its tokens' rows of the float32 output
+    (scatter-add, so a token's choices are summed in the experts' order:
+    float32 rounding of at most ``top_k`` terms apart from the other
+    form). No array of all the pairs is gathered, selected or brought
+    back to token order, there is no cap and nothing is dropped: with
+    every pick held the loop runs over all the pairs. The rule, from
+    shapes alone: the loop is taken where the weights hold a share AND
+    the pairs outnumber one tile; a whole layer (every pair is live) and
+    a decode step's few hundred pairs (one tile) go as one fused pass,
+    the programs they always were.
+
     More than ``ROUTED_TOKENS_A_PASS`` tokens (a long prompt) go through
     in passes of that many, each sorted and computed on its own.
 
@@ -183,7 +208,11 @@ def routed_ffn(
     here, E for a whole layer), ``moe_max_load`` (the fullest expert's
     pairs), ``moe_weight_visits`` (the (expert, 128-row tile) pairs the
     kernel's schedule visits: ``moe_experts_touched`` where every
-    expert's rows sit in one tile, as a decode step's 128 rows do)."""
+    expert's rows sit in one tile, as a decode step's 128 rows do; an
+    expert cut by a turn's edge is visited, and read, in both turns),
+    ``moe_pair_rows`` (the sorted-pair rows gathered, selected and brought
+    back around the kernel: ``tokens x top_k`` in one pass, the live pairs
+    rounded up to whole tiles in the loop)."""
     f32 = jnp.float32
     d, E = x.shape[-1], wp["wi"].shape[-3]  # E: the experts held here
     whole = E == wp["router"].shape[-1]
@@ -226,23 +255,53 @@ def routed_ffn(
             expert[order], jnp.arange(E + 1), side="left",
             method="compare_all")
         sizes = jnp.diff(edges).astype(jnp.int32)  # pairs per expert
+    rows = n * top_k
+    # every pair in one tile, or every pair live: one fused pass over them
+    looped = not whole and rows > ROUTED_ROWS_A_TILE
     with jax.named_scope("raytpu.moe.experts"):
-        schedule = group_schedule(sizes, n * top_k)
-
-        def experts(rows, *names, act=None):
+        def experts(rows, schedule, *names, act=None):
             stacks = [wp[k].astype(x.dtype).reshape((-1, E) + wp[k].shape[-2:])
                       for k in names]  # [E, k, n]: a stack of one layer
             return grouped_matmul(rows, stacks, schedule,
                                   layer=wp.get("layer", 0), act=act)
 
-        xs = x2[order // top_k]  # [n*k, D], sorted by expert
-        h = experts(xs, *(("wg", "wi") if gated else ("wi",)), act=act)
-        ys = experts(h, "wo")
-        # rows behind the last group hold nothing defined: select, then
-        # back to token order and the weighted sum over a token's choices
-        ys = jnp.where((jnp.arange(n * top_k) < edges[E])[:, None], ys, 0)
-        ys = ys[jnp.argsort(order)].reshape(n, top_k, d)
-        y = jnp.einsum("nkd,nk->nd", ys.astype(f32), w)
+        gate_up = ("wg", "wi") if gated else ("wi",)
+        if not looped:
+            schedule = group_schedule(sizes, rows)
+            xs = x2[order // top_k]  # [n*k, D], sorted by expert
+            ys = experts(experts(xs, schedule, *gate_up, act=act),
+                         schedule, "wo")
+            # rows behind the last group hold nothing defined: select, then
+            # back to token order and the weighted sum over a token's choices
+            ys = jnp.where((jnp.arange(rows) < edges[E])[:, None], ys, 0)
+            ys = ys[jnp.argsort(order)].reshape(n, top_k, d)
+            y = jnp.einsum("nkd,nk->nd", ys.astype(f32), w)
+            visits, moved = schedule.visits, jnp.int32(rows)
+        else:
+            tile = ROUTED_ROWS_A_TILE
+            turns = (edges[E] + tile - 1) // tile  # the live pairs' tiles
+            pairs = jnp.pad(order, (0, -rows % tile))
+            w_pair = w.reshape(-1)
+
+            def turn(i, carry):
+                y, visits = carry
+                lo = i * tile
+                pair = jax.lax.dynamic_slice(pairs, (lo,), (tile,))
+                token = pair // top_k
+                cut = jnp.clip(edges, lo, lo + tile)  # the groups, cut here
+                schedule = group_schedule(
+                    jnp.diff(cut).astype(jnp.int32), tile)
+                ys = experts(experts(x2[token], schedule, *gate_up, act=act),
+                             schedule, "wo")
+                # a row behind the last group holds nothing defined
+                ys = jnp.where(
+                    (lo + jnp.arange(tile) < edges[E])[:, None],
+                    ys.astype(f32) * w_pair[pair][:, None], 0)
+                return y.at[token].add(ys), visits + schedule.visits
+
+            y, visits = jax.lax.fori_loop(
+                0, turns, turn, (jnp.zeros((n, d), f32), jnp.int32(0)))
+            moved = (turns * tile).astype(jnp.int32)
     if "shared" in wp:
         with jax.named_scope("raytpu.moe.shared"):
             sp = wp["shared"]
@@ -254,6 +313,7 @@ def routed_ffn(
         "moe_experts_touched": (sizes > 0).sum().astype(jnp.int32),
         "moe_experts_capacity": jnp.int32(E),
         "moe_max_load": sizes.max(),
-        "moe_weight_visits": schedule.visits,
+        "moe_weight_visits": visits,
+        "moe_pair_rows": moved,
     }
     return y.astype(x.dtype).reshape(x.shape), stats

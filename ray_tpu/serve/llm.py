@@ -142,6 +142,7 @@ class LLMEngine:
             block_stat_keys,
             init_kv_cache,
             lay_out_for_decode,
+            prefill_stat_keys,
             prepare_for_inference,
             slot_footprint,
         )
@@ -205,8 +206,9 @@ class LLMEngine:
         # the decode attention reads each slot's cache up to its entry.
         self._rows: List[int] = [0] * max_slots
         self.pending: "collections.deque[_Request]" = collections.deque()
-        # (req, device first-token scalar): admitted, first token not yet
-        # emitted; filled by _admit, emptied by _retire_firsts
+        # (req, device first-token scalar, the admission's device
+        # counters): admitted, first token not yet emitted; filled by
+        # _admit, emptied by _retire_firsts
         self._pending_first: List = []
         self._first_fn = None  # lazily-jitted plain sampler (_first_token)
         self._newest = None  # token array of the block dispatched last
@@ -220,9 +222,11 @@ class LLMEngine:
         # exists from the start, so a reader's copy never sees a resize.
         # The model's own per-block counters (generation.block_stat_keys:
         # e.g. a routed layer's experts touched) come back from the device
-        # with each block's tokens and are summed under their names.
+        # with each block's tokens and are summed under their names; an
+        # admission's (generation.prefill_stat_keys) with its first token.
         self._n: Dict[str, int] = dict.fromkeys(
-            _COUNTERS + block_stat_keys(config), 0)
+            _COUNTERS + block_stat_keys(config) + prefill_stat_keys(config),
+            0)
         self._n["weights_relaid"] = relaid
         self._n["weights_relaid_bytes"] = relaid_bytes
         foot = slot_footprint(self.cache)
@@ -445,6 +449,17 @@ class LLMEngine:
           min(pos + 1, window) a live lane a window layer, beside
           ``attn_rows_read``, which counts the rows of the layers that
           keep every row).
+        - Whatever counters the model's ADMISSION program returns with
+          its first token (``generation.prefill_stat_keys``), summed over
+          first tokens read (``_retire_firsts``; their copies to the host
+          start with the token's and wait for nothing of their own). For
+          dropless routed experts, over the prompt's routed layers:
+          ``prefill_moe_assignments`` ((token, expert) pairs computed:
+          the real tokens' picks that went to an expert held here) and
+          ``prefill_moe_pair_rows`` (sorted-pair rows gathered, selected
+          and brought back to token order around the grouped product,
+          ``ops/moe.routed_ffn``: every pair of the bucket for a whole
+          layer, the live pairs rounded up to tiles for a share).
         """
         with self._lock:
             out = {
@@ -516,7 +531,7 @@ class LLMEngine:
                         lanes = (self.tok, self.pos, self.temps,
                                  self.seeds, self.counts)
                     with self._stretch("launch"):
-                        first, self.cache, lanes = prefill_into_slot(
+                        first, self.cache, lanes, stats = prefill_into_slot(
                             self.params, padded, np.int32(n),
                             np.int32(free), self.cache, self.config, lanes,
                             np.float32(req.temperature), np.int32(req.seed))
@@ -524,12 +539,14 @@ class LLMEngine:
                         # lands on the host when THIS prefill ends,
                         # whatever is queued behind it
                         first.copy_to_host_async()
+                        for counter in stats.values():
+                            counter.copy_to_host_async()
                     with self._stretch("lanes"):
                         (self.tok, self.pos, self.temps, self.seeds,
                          self.counts) = lanes
                         self.slot_req[free] = req
                         self._rows[free] = n
-                        self._pending_first.append((req, first))
+                        self._pending_first.append((req, first, stats))
                         # the block interval that ends next holds this
                         # prefill (see _retire_block)
                         self._unsettled = 3
@@ -678,11 +695,13 @@ class LLMEngine:
         # rids as one string; a comma would end the value in the
         # profiler's "name#k=v,k=v#" encoding, so they are space-separated
         with self._span("raytpu.engine.retire_firsts", n=len(firsts),
-                        rids=" ".join(str(r.rid) for r, _ in firsts)):
+                        rids=" ".join(str(r.rid) for r, *_ in firsts)):
             t0 = time.perf_counter()
-            for req, first in firsts:
+            for req, first, stats in firsts:
                 token = int(first)
                 t1 = time.perf_counter()
+                for k, v in stats.items():  # landed with the token
+                    self._n[k] += int(v)
                 self._n["firsts_ahead"] += not self._newest.is_ready()
                 self._emit(req, token)
                 self._t["firsts_sync_s"] += t1 - t0
@@ -781,7 +800,7 @@ class LLMEngine:
                     collections.deque()
                 )
             for req in list(self.slot_req) + [
-                    r for r, _ in self._pending_first] + pending:
+                    r for r, *_ in self._pending_first] + pending:
                 if req is not None and not req.finished:
                     req.finished = True
                     self._n["requests_failed"] += 1

@@ -127,6 +127,10 @@ def _metric(name):
     ("engine.first_ahead_share",
      {"firsts_ahead": (40, 139), "requests_first_emitted": (100, 210)},
      90.0),
+    # a share's admissions: 8,100 live pairs in 9,216 rows of whole tiles
+    ("engine.prefill_live_pair_share",
+     {"prefill_moe_assignments": (52_000, 60_100),
+      "prefill_moe_pair_rows": (61_440, 70_656)}, 100.0 * 8100 / 9216),
 ])
 def test_cadence_and_admission_metrics_on_hand_made_snapshots(
         name, scalars, want):
@@ -140,6 +144,28 @@ def test_cadence_and_admission_metrics_on_hand_made_snapshots(
     # a program without the counters: the metric is left out
     old = {k: v for k, v in scalars.items() if k != params["num"][0]}
     assert read(_facts([0] * 5, [0] * 5, **old), params) is None
+
+
+def test_the_live_pair_share_lists_the_routed_cells_and_comes_last():
+    """ISSUE 43: one entry, appended: the cell that holds a share (the
+    mechanism) and the cell that holds every expert (the control), both
+    judged on ``tpot_p50_ms``; a data file for the reader that is there."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    entry = next(m for m in doc["per_layer"]
+                 if m["name"] == "engine.prefill_live_pair_share")
+    assert entry == {
+        "name": "engine.prefill_live_pair_share", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": "serving engine", "moves": "tpot_p50_ms",
+        "workloads": ["serve-glm-reason-saturated",
+                      "serve-mimo-codeagent-saturated"]}
+    judged = next(m for m in doc["end_to_end"] if m["name"] == "tpot_p50_ms")
+    assert set(entry["workloads"]) <= set(judged["workloads"])
+    read, params = _metric(entry["name"])
+    assert read is ratio and params == {
+        "num": ["prefill_moe_assignments"], "den": ["prefill_moe_pair_rows"],
+        "scale": 100.0}
 
 
 def test_each_engine_metric_lists_only_cells_that_report_what_it_moves():

@@ -587,13 +587,15 @@ def test_decode_block_parks_lanes_at_pos_zero():
 
 @pytest.mark.parametrize("temperature,seed", [(0.0, 0), (0.9, 4321)])
 @pytest.mark.parametrize(
-    "kind", ["tiny", "tiny_mla_moe", "tiny_dsa_moe", "tiny_ssm_hybrid"])
+    "kind", ["tiny", "tiny_mla_moe", "tiny_dsa_moe", "tiny_ssm_hybrid",
+             "tiny_swa_moe"])
 def test_fused_admission_is_prefill_then_sampler_then_scatters(
         kind, temperature, seed):
     """``prefill_into_slot`` handed the lanes is the plain form followed
     by ``_first_token`` on its logits and the five scatters, for each kind
-    of cache (K/V rows, latent rows, index keys, a recurrent state): the
-    same token, the same lanes, the same cache."""
+    of cache (K/V rows, latent rows, index keys, a recurrent state, rings):
+    the same token, the same lanes, the same cache; beside them the
+    routed layers' counters, and nothing for a model without any."""
     import types
 
     import jax
@@ -602,6 +604,7 @@ def test_fused_admission_is_prefill_then_sampler_then_scatters(
     from ray_tpu.models.generation import (
         init_kv_cache,
         prefill_into_slot,
+        prefill_stat_keys,
         prepare_for_inference,
     )
     from ray_tpu.models.transformer import TransformerConfig, init_params
@@ -631,9 +634,16 @@ def test_fused_admission_is_prefill_then_sampler_then_scatters(
     want = LLMEngine._first_token(sampler, logits, temperature, seed)
     want_lanes = [lane.at[slot].set(v) for lane, v in zip(
         lanes(), (want, n, temperature, seed, 1))]
-    first, got_cache, got_lanes = prefill_into_slot(
+    first, got_cache, got_lanes, stats = prefill_into_slot(
         *args, init_kv_cache(icfg, slots, s_max), icfg, lanes(),
         np.float32(temperature), np.int32(seed))
+    assert tuple(stats) == prefill_stat_keys(icfg)
+    assert bool(stats) == (kind not in ("tiny", "tiny_ssm_hybrid"))
+    if stats:  # the padding picks no expert; a whole layer moves all pairs
+        routed = icfg.n_layers - icfg.n_dense_layers
+        pairs = routed * icfg.moe_top_k
+        assert int(stats["prefill_moe_assignments"]) == n * pairs
+        assert int(stats["prefill_moe_pair_rows"]) == bucket * pairs
     assert first.shape == () and first.dtype == jnp.int32
     assert int(first) == int(want)
     for got, lane in zip(got_lanes, want_lanes):
@@ -689,8 +699,8 @@ def test_first_tokens_leave_one_by_one_ahead_of_the_next_block(monkeypatch):
         return free_at[0]
 
     def slow_prefill(*a):
-        first, cache, lanes = prefill(*a)
-        return _Late(first, ends()), cache, lanes
+        first, cache, lanes, stats = prefill(*a)
+        return _Late(first, ends()), cache, lanes, stats
 
     def slow_block(*a):
         toks, *rest = block(*a)
@@ -771,7 +781,8 @@ def test_an_admission_is_one_program_and_its_second_compiles_nothing(
 
     def seen_retire_firsts():
         if eng._pending_first:
-            events.append(("firsts", [t for _r, t in eng._pending_first]))
+            events.append(
+                ("firsts", [t for _r, t, _s in eng._pending_first]))
         retire_firsts()
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
@@ -809,11 +820,58 @@ def test_an_admission_is_one_program_and_its_second_compiles_nothing(
     for i, event in enumerate(events):
         if event[0] != "prefill":
             continue
-        first, cache, lanes = event[2]
+        first, cache, lanes, stats = event[2]
+        assert stats == {}  # no routed layer: no output, nothing to copy
         nxt, then = events[i + 1], events[i + 2]
         assert nxt[0] == "block" and nxt[1] is cache
         assert all(x is y for x, y in zip(nxt[2], lanes))
         assert then[0] == "firsts" and then[1][0] is first
+
+
+@pytest.mark.parametrize("kind", ["share", "whole", "unrouted"])
+def test_engine_publishes_what_its_admissions_routed(kind, monkeypatch):
+    """``stats()`` sums what each admission's program counted of its
+    routed layers, read when the first token is: the pairs computed and
+    the sorted-pair rows moved. A whole layer moves every pair of the
+    bucket; a share (here with a tile small enough for the loop) its live
+    pairs rounded up to tiles; a model without routed layers has neither
+    key."""
+    import jax
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.ops import moe
+    from ray_tpu.serve.llm import LLMEngine
+
+    tile = 16
+    monkeypatch.setattr(moe, "ROUTED_ROWS_A_TILE", tile)
+    cfg = {"share": lambda: TransformerConfig.tiny_swa_moe(
+               moe_experts_held=4, moe_first_expert=2),
+           "whole": TransformerConfig.tiny_mla_moe,
+           "unrouted": TransformerConfig.tiny}[kind]()
+    eng = LLMEngine(init_params(cfg, jax.random.key(0)), cfg, max_slots=2,
+                    max_len=64, prefill_buckets=(16, 32))
+    lengths = (9, 20, 30)
+    try:
+        for n in lengths:
+            eng.generate(np.arange(1, n + 1, dtype=np.int32),
+                         max_new_tokens=3)
+        s = _settled_stats(eng)
+    finally:
+        eng.shutdown()
+    keys = ("prefill_moe_assignments", "prefill_moe_pair_rows")
+    if kind == "unrouted":
+        assert not any(k in s for k in keys)
+        return
+    layers = cfg.n_layers - cfg.n_dense_layers
+    pairs, rows = (s[k] for k in keys)
+    if kind == "whole":
+        assert pairs == sum(lengths) * cfg.moe_top_k * layers
+        assert rows == (16 + 32 + 32) * cfg.moe_top_k * layers
+    else:
+        assert 0 < pairs < sum(lengths) * cfg.moe_top_k * layers
+        assert pairs <= rows <= pairs + len(lengths) * layers * tile
+        assert rows % tile == 0 and rows < (16 + 32 + 32) * cfg.moe_top_k * (
+            layers)
 
 
 def test_engine_stats_count_cancelled_requests():

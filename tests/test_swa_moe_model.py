@@ -374,6 +374,76 @@ def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(params):
     assert float(jnp.abs(total - whole).max()) < TOL
 
 
+# name: tokens, experts held, first held, bias on the held experts, real
+# tokens (None: all), tokens a pass, with a shared expert
+SHARE_CASES = {
+    "no_pick_is_held": (40, 4, 4, -100.0, None, 4096, False),
+    "every_pick_is_held": (40, 4, 4, 100.0, None, 4096, False),
+    "live_count_off_the_tile": (50, 4, 0, 0.0, None, 4096, False),
+    "padding_picks_no_expert": (64, 4, 0, 0.5, 41, 4096, False),
+    "first_expert_above_zero": (48, 6, 9, 0.5, None, 4096, False),
+    "last_experts_and_a_shared_one": (48, 3, 13, 0.5, 45, 4096, True),
+    "prompt_over_a_pass": (128, 4, 2, 0.5, 100, 32, False),
+    "rows_of_one_tile": (8, 4, 4, 0.5, None, 4096, False),
+    "whole_layer": (40, 16, 0, 0.0, 33, 4096, False),
+}
+
+
+@pytest.mark.parametrize("case", SHARE_CASES)
+def test_a_share_moves_its_live_pairs_and_equals_the_zeroed_layer(
+        case, monkeypatch):
+    """A share's ``routed_ffn`` (the loop over the live pairs' tiles)
+    equals the whole layer's with the absent experts' weights zeroed, and
+    ``moe_pair_rows`` says how many sorted-pair rows it moved: every pair
+    of a whole layer and of a share whose pairs fit one tile, the live
+    pairs rounded up to tiles otherwise."""
+    from ray_tpu.ops import moe
+
+    n, held, first, bias, real, a_pass, shared = SHARE_CASES[case]
+    d, E, f, k, tile = 32, 16, 16, 4, 32
+    monkeypatch.setattr(moe, "ROUTED_ROWS_A_TILE", tile)
+    monkeypatch.setattr(moe, "ROUTED_TOKENS_A_PASS", a_pass)
+    ks = jax.random.split(jax.random.key(len(case)), 8)
+    mine = (jnp.arange(E) >= first) & (jnp.arange(E) < first + held)
+    wp = {"router": jax.random.normal(ks[0], (d, E)) * 0.3,
+          "bias": jax.random.normal(ks[1], (E,)) * 0.02 + bias * mine}
+    for i, (name, shape) in enumerate(
+            (("wg", (E, d, f)), ("wi", (E, d, f)), ("wo", (E, f, d)))):
+        wp[name] = jax.random.normal(ks[2 + i], shape) * 0.2
+    if shared:
+        wp["shared"] = {"wg": wp["wg"][0], "wi": wp["wi"][1],
+                        "wo": wp["wo"][2]}
+    x = jax.random.normal(ks[5], (n, d))
+    mask = None if real is None else jnp.arange(n) < real
+    kw = dict(top_k=k, route_scale=1.5, token_mask=mask)
+    zeroed = {**wp, **{name: jnp.where(mine[:, None, None], wp[name], 0)
+                       for name in ("wg", "wi", "wo")}}
+    want, all_pairs = routed_ffn(x, zeroed, **kw)
+    share = {**wp, **{name: wp[name][first:first + held]
+                      for name in ("wg", "wi", "wo")}}
+    got, stats = routed_ffn(x, share, first_expert=first, **kw)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    # the pairs, counted from the router alone
+    s = jax.nn.sigmoid(jnp.dot(x, wp["router"], precision="highest"))
+    picks = np.asarray(jax.lax.top_k(s + wp["bias"], k)[1])
+    counted = np.asarray(mine)[picks] & (
+        np.ones(n, bool) if real is None else np.arange(n) < real)[:, None]
+    live, moved = int(counted.sum()), int(stats["moe_pair_rows"])
+    assert int(stats["moe_assignments"]) == live
+    assert int(all_pairs["moe_pair_rows"]) == n * k  # a whole layer: all
+    if held == E or min(n, a_pass) * k <= tile:
+        assert moved == n * k
+    else:
+        assert live <= moved <= live + (held + 1) * tile * -(-n // a_pass)
+        assert moved % tile == 0
+    if case == "no_pick_is_held":
+        assert live == moved == 0 and not float(jnp.abs(got).max())
+    if case == "every_pick_is_held":
+        assert live == n * k
+    if case == "live_count_off_the_tile":
+        assert live % tile and live % 128
+
+
 def test_a_model_that_holds_a_share_matches_the_reference_of_that_share():
     cfg = dataclasses.replace(CFG, moe_experts_held=4, moe_first_expert=2)
     params = init_params(cfg, jax.random.key(5))

@@ -468,3 +468,108 @@ def test_ssm_hybrid_admission_is_the_prefill_in_place(v5e, as_on_the_chip):
         plain, fused, cache, cfg, 48,
         ("f32[36,48,64,", "f32[48,64,64,128", "f32[1,48,64,",
          "bf16[4,48,4096,", "bf16[36,48,13056"))
+
+
+def test_mimo_admission_moves_no_array_of_all_the_sorted_pairs(
+        v5e, as_on_the_chip):
+    """ISSUE 43: MiMo-V2-Flash's admission program of the 5,120 bucket (the
+    mean prompt's) at the benchmark's engine sizes. Its six routed layers
+    hold 16 of 256 experts, so 15 of 16 of the 5,120 x 8 sorted (token,
+    expert) pairs go to experts another chip holds: ``routed_ffn`` walks
+    the live pairs' tiles in a loop, and the program holds no
+    ``[40960, 4096]`` array at all (the parent's held 66 lines of them: the
+    gather, the two kernels' rows, the select, the un-sort, the sum); it
+    needs less memory than the parent's 11.378 GiB, and the routed
+    layers' two counters leave with the first token."""
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.mimo_v2_flash(
+        7, layer_types=("attention",) + ("window",) * 4 + (
+            "attention", "window"),
+        vocab_size=19072, moe_experts_held=16, param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 48, 17408)))
+    lanes = (arr((48,)), arr((48,)), arr((48,), jnp.float32), arr((48,)),
+             arr((48,)))
+    low = gen.prefill_into_slot.lower(
+        params, arr((1, 5120)), arr(()), arr(()), cache, cfg, lanes,
+        arr((), jnp.float32), arr(()))
+    assert list(low.out_info[3]) == list(gen.prefill_stat_keys(cfg))
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 11.378 * 2 ** 30
+    hlo = compiled.as_text()
+    assert "[40960,4096]" not in hlo and "[40960,2048]" not in hlo
+    # every grouped product sits in the loop's body (two a run of like
+    # layers: W W W W, F, W), beside the scatter back to token order
+    kernels = [line for line in hlo.splitlines()
+               if "tpu_custom_call" in line and "raytpu.moe.experts" in line]
+    assert len(kernels) == 6
+    assert all("raytpu.moe.experts/while/body" in line for line in kernels)
+    assert any(" scatter(" in line and "raytpu.moe.experts/while/body" in line
+               for line in hlo.splitlines())
+
+
+# sha256 of ``lower(...).as_text()`` on the CPU (where a kernel is its
+# interpreter's jaxpr: the text carries no source line) of GLM-4.7-Flash's
+# decode programs at the benchmark's engine sizes, as the parent of
+# ISSUE 43 (727df70) lowers them
+GLM47_DECODE_TEXTS = {
+    "decode_block_2": (
+        "8be9d0b8da1d94f44a5f4abd668b6124"
+        "07f85d97e2f5bf368875c503cae91b65"),
+    "decode_block_8": (
+        "ad3b4c4e66036bd7353b13a610956e0a"
+        "aab40b1e2844beb9577183f0167bafaf"),
+    "decode_step_multi": (
+        "5ba2ec6eac817df76e512c2e33175c7d"
+        "1bff40835a6d701fc897297a0c3e6442"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(GLM47_DECODE_TEXTS))
+def test_a_whole_routed_layer_keeps_its_decode_programs(
+        program, monkeypatch, request):
+    """ISSUE 43 changes what ``routed_ffn`` does for a SHARE of a layer's
+    experts. GLM-4.7-Flash holds every expert: every pair is live, and its
+    decode programs lower to the text they lowered to before (the counter
+    ``routed_ffn`` gained is dropped before it reaches them)."""
+    import hashlib
+
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    # whatever ``as_on_the_chip`` has steered for the tests above; and a
+    # trace one of them made of this program holds the kernels
+    # uninterpreted (as this one's would hold them interpreted)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    jax.clear_caches()
+    request.addfinalizer(jax.clear_caches)
+    cfg = TransformerConfig.glm47_flash(8, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: gen.init_kv_cache(cfg, 32, 4096))
+
+    def arr(dtype=jnp.int32):
+        return jax.ShapeDtypeStruct((32,), dtype)
+
+    if program == "decode_step_multi":
+        low = gen.decode_step_multi.lower(params, arr(), cache, arr(), cfg)
+    else:
+        low = gen.decode_block.lower(
+            params, cache, arr(), arr(), arr(jnp.float32), arr(), arr(),
+            cfg, int(program[-1]))
+    text = hashlib.sha256(low.as_text().encode()).hexdigest()
+    assert text == GLM47_DECODE_TEXTS[program]
