@@ -310,6 +310,7 @@ class _GeneratorStream:
         self.total: Optional[int] = None  # yield count once finished
         self.error: Optional[BaseException] = None
         self.consumed = 0
+        self.reports = 0  # report RPCs that carried the yields so far
         self.cancelled = False  # consumer abandoned the stream
         self._cond = threading.Condition()
         self._bp_waiters: List = []  # asyncio futures (on worker.io.loop)
@@ -379,6 +380,8 @@ class _GeneratorStream:
         self._wake_bp()
 
     def _wake_bp(self):
+        if not self._bp_waiters:  # no report's reply is held back
+            return
         loop = self._worker.io.loop
 
         def wake():
@@ -394,18 +397,99 @@ class _GeneratorStream:
 
     async def backpressure_wait(self, limit: int):
         """Await (on the IO loop) until the consumer drains below limit."""
-        while (
-            self.reported - self.consumed >= limit
-            and self.error is None and self.total is None
-            and not self.cancelled
-        ):
+        def behind():
+            return (self.reported - self.consumed >= limit
+                    and self.error is None and self.total is None
+                    and not self.cancelled)
+
+        while behind():
             fut = asyncio.get_running_loop().create_future()
             self._bp_waiters.append(fut)
-            await fut
+            # the consumer wakes only registered waiters (_wake_bp): look
+            # again now that this one is, in case it moved meanwhile
+            if behind():
+                await fut
 
     def __repr__(self):
         return (f"stream(reported={self.reported}, consumed={self.consumed},"
                 f" total={self.total})")
+
+
+class _YieldReporter:
+    """Executor-side sender of ONE streaming generator's yields. One
+    report is on its way at a time; what the generator yields meanwhile
+    waits here and rides together in the next report, as many yields as
+    the caller's last reply had room for (a token stream that yields a
+    block's tokens in a row costs one round trip, not one a token). The
+    first yield after a quiet stretch leaves at once. The generator's
+    thread blocks in ``put`` while ``limit`` yields wait unsent: with the
+    caller's delayed reply that is the backpressure."""
+
+    def __init__(self, worker, spec):
+        self._worker = worker
+        self._spec = spec
+        self._limit = GLOBAL_CONFIG.streaming_generator_backpressure_items
+        self._cond = threading.Condition()
+        self._waiting: List[Dict] = []  # encoded yields not yet sent
+        self._room = self._limit  # yields the next report may carry
+        self._sending = False  # a report is on its way (or about to be)
+        self._ok = True  # the caller still takes yields
+        self._error: Optional[BaseException] = None
+
+    def put(self, item: Dict) -> bool:
+        """Queue one encoded yield; False once the caller is gone."""
+        with self._cond:
+            while self._ok and len(self._waiting) >= self._limit:
+                self._cond.wait()
+            if self._error is not None:
+                raise self._error
+            if not self._ok:
+                return False
+            self._waiting.append(item)
+            if self._sending:
+                return True
+            self._sending = True
+        asyncio.run_coroutine_threadsafe(self._send(), self._worker.io.loop)
+        return True
+
+    async def _send(self):
+        """On the IO loop: report what waits, a reply at a time, until
+        nothing does."""
+        try:
+            conn = await self._worker._conn_to(self._spec.owner[1])
+            while True:
+                with self._cond:
+                    items = self._waiting[:self._room]
+                    del self._waiting[:self._room]
+                    if not items or not self._ok:
+                        self._sending = False
+                        self._cond.notify_all()
+                        return
+                    self._cond.notify_all()
+                # no timeout: the caller delays the reply as backpressure
+                reply = await conn.call_async(
+                    "report_generator_items",
+                    {"task_id": self._spec.task_id, "items": items},
+                    timeout=None)
+                with self._cond:
+                    self._ok = bool(reply.get("ok"))
+                    self._room = int(reply.get("room", 1))
+        except BaseException as e:  # raised again in put / flush
+            with self._cond:
+                self._error, self._ok, self._sending = e, False, False
+                self._cond.notify_all()
+            if not isinstance(e, Exception):
+                raise  # cancelled at shutdown: the thread is woken first
+
+    def flush(self) -> bool:
+        """Block until every queued yield was acknowledged; False if the
+        caller stopped taking them."""
+        with self._cond:
+            while self._sending:
+                self._cond.wait()
+            if self._error is not None:
+                raise self._error
+            return self._ok
 
 
 class _LeaseState:
@@ -1139,41 +1223,48 @@ class CoreWorker:
         self.io.submit(self._submit_async(spec))
         return True
 
-    async def rpc_report_generator_item(self, conn, data: Dict):
-        """Executor -> caller: one streaming-generator yield (parity:
-        reference ReportGeneratorItemReturns, core_worker.proto). The CALLER
-        stores the object under its deterministic id and owns it from here
-        (lineage registered, so a lost yield resubmits the task). The reply
-        is delayed while the consumer is behind — that delay IS the
+    async def rpc_report_generator_items(self, conn, data: Dict):
+        """Executor -> caller: a run of streaming-generator yields, in
+        order (parity: reference ReportGeneratorItemReturns,
+        core_worker.proto; there one yield a report, here every yield the
+        generator made while the report before was on its way). The CALLER
+        stores each object under its deterministic id and owns it from
+        here (lineage registered, so a lost yield resubmits the task). The
+        reply is delayed while the consumer is behind — that delay IS the
         backpressure on the executing generator."""
         task_id = bytes(data["task_id"])
-        index = int(data["index"])
         stream = self._gen_streams.get(task_id)
         from ray_tpu._private.protocol import yield_object_id
 
-        oid = yield_object_id(TaskID(task_id), index)
-        if data["kind"] == "v":
-            value = serialization.unpack(bytes(data["payload"]))
-            if isinstance(value, exc.ErrorObject):
-                self.memory_store.put_error(oid, value.error)
+        if stream is not None:
+            stream.reports += 1
+        for item in data["items"]:
+            index = int(item["index"])
+            oid = yield_object_id(TaskID(task_id), index)
+            if item["kind"] == "v":
+                value = serialization.unpack(bytes(item["payload"]))
+                if isinstance(value, exc.ErrorObject):
+                    self.memory_store.put_error(oid, value.error)
+                else:
+                    self.memory_store.put_value(oid, value)
             else:
-                self.memory_store.put_value(oid, value)
-        else:
-            self.memory_store.put_plasma(oid, [bytes(data["node"])])
-        self._owned.add(oid)
-        if stream is None:
-            # stream record already drained/dropped: this is a lineage
+                self.memory_store.put_plasma(oid, [bytes(item["node"])])
+            self._owned.add(oid)
+            # no stream record (already drained/dropped): a lineage
             # re-execution recreating lost yields — store and ack, no
             # consumer bookkeeping needed
-            return {"ok": True}
-        if GLOBAL_CONFIG.lineage_pinning_enabled:
-            self._lineage[oid] = stream.spec
-        stream.on_item(index)
-        await stream.backpressure_wait(
-            GLOBAL_CONFIG.streaming_generator_backpressure_items
-        )
-        # a cancelled stream NACKs so the executor stops generating
-        return {"ok": not stream.cancelled}
+            if stream is not None:
+                if GLOBAL_CONFIG.lineage_pinning_enabled:
+                    self._lineage[oid] = stream.spec
+                stream.on_item(index)
+        limit = GLOBAL_CONFIG.streaming_generator_backpressure_items
+        if stream is None:
+            return {"ok": True, "room": limit}
+        await stream.backpressure_wait(limit)
+        # a cancelled stream NACKs so the executor stops generating; the
+        # next report may carry as many yields as the consumer is short of
+        return {"ok": not stream.cancelled,
+                "room": max(1, limit - (stream.reported - stream.consumed))}
 
     async def rpc_get_object(self, conn, oid_bytes: bytes):
         """Serve an owned object's value to a borrower."""
@@ -3406,7 +3497,7 @@ class CoreWorker:
 
     # ---- streaming generator execution (parity: reference streaming
     # generator returns, core_worker.proto ReportGeneratorItemReturns;
-    # the CALLER owns every yield — see rpc_report_generator_item) ----
+    # the CALLER owns every yield — see rpc_report_generator_items) ----
 
     def _encode_yield(self, spec: TaskSpec, index: int, item) -> Dict:
         """Pack one yield: big values go into the local store under the
@@ -3432,8 +3523,7 @@ class CoreWorker:
                 # re-execution on the same node: bytes already sealed
                 self.gcs.call("add_object_location",
                               [oid.binary(), self.node_id])
-                return {"task_id": spec.task_id, "index": index,
-                        "kind": "p", "node": self.node_id}
+                return {"index": index, "kind": "p", "node": self.node_id}
             try:
                 serialization.pack_into(meta, views, buf)
             except BaseException:
@@ -3444,24 +3534,16 @@ class CoreWorker:
             self.store.seal(oid)
             self.store.release(oid)
             self.gcs.call("add_object_location", [oid.binary(), self.node_id])
-            return {"task_id": spec.task_id, "index": index,
-                    "kind": "p", "node": self.node_id}
+            return {"index": index, "kind": "p", "node": self.node_id}
         out = bytearray(total)
         serialization.pack_into(meta, views, memoryview(out))
-        return {"task_id": spec.task_id, "index": index,
-                "kind": "v", "payload": bytes(out)}
-
-    async def _send_gen_report(self, owner_wire, msg: Dict) -> Dict:
-        conn = await self._conn_to(owner_wire[1])
-        # no timeout: the caller delays the reply as backpressure
-        return await conn.call_async("report_generator_item", msg,
-                                     timeout=None)
+        return {"index": index, "kind": "v", "payload": bytes(out)}
 
     def _stream_generator_returns(self, spec: TaskSpec, result) -> Dict:
-        """Drive a (sync) generator, reporting each yield to the caller and
-        blocking this executing thread on the caller's ack — the ack delay
-        is the backpressure. Runs on the execution thread, never the IO
-        loop."""
+        """Drive a (sync) generator, handing each yield to the stream's
+        reporter and blocking this executing thread while the caller is
+        behind (``_YieldReporter``: the delayed reply is the
+        backpressure). Runs on the execution thread, never the IO loop."""
         import inspect
 
         if not inspect.isgenerator(result) and not hasattr(
@@ -3471,36 +3553,38 @@ class CoreWorker:
                 f"num_returns='streaming' task {spec.name} must return a "
                 f"generator/iterable, got {type(result).__name__}"
             )
+        reporter = _YieldReporter(self, spec)
         n = 0
-        for item in result:
-            msg = self._encode_yield(spec, n, item)
-            fut = asyncio.run_coroutine_threadsafe(
-                self._send_gen_report(spec.owner, msg), self.io.loop
-            )
-            reply = fut.result()
-            if not reply.get("ok"):
-                break  # caller gone: stop generating
-            n += 1
+        try:
+            for item in result:
+                if not reporter.put(self._encode_yield(spec, n, item)):
+                    break  # caller gone: stop generating
+                n += 1
+        finally:  # the yields before an error reach the caller before it
+            reporter.flush()
         count_packed = serialization.pack(n)
         serialization.take_contained_refs()
         return {"returns": [["v", count_packed]], "num_yields": n}
 
     async def _stream_async_generator_returns(self, spec: TaskSpec,
                                               agen) -> Dict:
-        """Async-generator variant (async actor methods): awaits the report
-        ack without blocking the actor's asyncio loop."""
+        """Async-generator variant (async actor methods): encodes and
+        hands over each yield off the actor's asyncio loop."""
+        reporter = _YieldReporter(self, spec)
+
+        def report(index, item):
+            # contained-ref tracking is thread-local and consumed inside
+            # _encode_yield itself (R7)
+            return reporter.put(self._encode_yield(spec, index, item))
+
         n = 0
-        async for item in agen:
-            # serialize off the actor loop; contained-ref tracking is
-            # thread-local and consumed inside _encode_yield itself (R7)
-            msg = await asyncio.to_thread(self._encode_yield, spec, n, item)
-            fut = asyncio.run_coroutine_threadsafe(
-                self._send_gen_report(spec.owner, msg), self.io.loop
-            )
-            reply = await asyncio.wrap_future(fut)
-            if not reply.get("ok"):
-                break
-            n += 1
+        try:
+            async for item in agen:
+                if not await asyncio.to_thread(report, n, item):
+                    break
+                n += 1
+        finally:
+            await asyncio.to_thread(reporter.flush)
         count_packed = serialization.pack(n)
         serialization.take_contained_refs()
         return {"returns": [["v", count_packed]], "num_yields": n}

@@ -27,8 +27,10 @@ alone, so those are all its slot keeps: a ring of ``window`` rows, a STATE
 leaf too, row ``pos mod window`` overwritten once a token; a prefill hands
 over the prompt's last ``window`` rows, attends block by block and never
 touches the rows a window does not reach (``ops/attention.
-window_attention``). Both programs run every block the config can
-describe.
+window_attention``). A "kda" layer (a gated delta rule, ``ops/kda.py``)
+keeps a matrix state a head and the tail of its convolution, STATE leaves
+like the state-space layer's, beside whichever rows the model's attention
+layers keep. Both programs run every block the config can describe.
 Decode is bound by HBM reads, and a masked cache row is read like a live
 one: the mask only discards what was already streamed. So the decode
 attention of both dense caches (``_attend_prefix_plus_self``,
@@ -57,6 +59,7 @@ from ray_tpu.models.transformer import (
     _rms_norm,
     apply_block,
     embed_tokens,
+    kda_split,
     layer_groups,
     lm_logits,
     mla_expand,
@@ -75,6 +78,7 @@ from ray_tpu.ops.decode_attention import (
     decode_attention,
     slot_schedule,
 )
+from ray_tpu.ops.kda import kda_chunked, kda_update
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_update
 
 
@@ -213,7 +217,14 @@ def init_kv_cache(config: TransformerConfig, batch: int,
     that attend every row alone (``v`` as wide as the values are) and,
     for its "window" layers, ``state``: the ring ``wk`` of [those layers,
     B, window, Hkv_w x D] and ``wv`` of [.., Hkv_w x Dv], the KV heads
-    flat in their row; the row of position p is ``p mod window``."""
+    flat in their row; the row of position p is ``p mod window``.
+
+    A model with "kda" layers keeps its attention layers' rows (``ckv`` /
+    ``kr`` where they are latent, else ``k`` / ``v``) for those layers
+    alone and, for its "kda" layers, ``state``: ``kda`` of [those layers,
+    B, H, D, D] in float32 (a head's keys x values) and ``conv``, the last
+    ``kda_conv - 1`` inputs of the convolution over the three streams,
+    [those layers, B, (kda_conv - 1) x 3 x H x D], flat as above."""
     c = config
     if c.mixer == "mla" and c.index_topk:
         rows = (batch, max_len)
@@ -221,16 +232,23 @@ def init_kv_cache(config: TransformerConfig, batch: int,
                                  c.dtype),
                 "ik": jnp.zeros((c.n_index_layers,) + rows
                                 + (c.index_head_dim,), c.dtype)}
-    if c.mixer == "mla":
-        rows = (c.n_layers, batch, max_len)
-        return {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
-                "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}
     rows = (c.n_attn_layers, batch, max_len)
-    k_row, v_row = _kv_rows(c)
-    cache = {
-        "k": jnp.zeros(rows + k_row, c.dtype),
-        "v": jnp.zeros(rows + v_row, c.dtype),
-    }
+    if c.mixer == "mla":
+        cache = {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
+                 "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}
+    else:
+        k_row, v_row = _kv_rows(c)
+        cache = {
+            "k": jnp.zeros(rows + k_row, c.dtype),
+            "v": jnp.zeros(rows + v_row, c.dtype),
+        }
+    if c.n_kda_layers:
+        slots = (c.n_kda_layers, batch)
+        cache["state"] = {
+            "kda": jnp.zeros(slots + (c.kda_heads, c.kda_head_dim,
+                                      c.kda_head_dim), jnp.float32),
+            "conv": jnp.zeros(slots + ((c.kda_conv - 1) * 3 * c.kda_inner,),
+                              c.dtype)}
     if c.n_window_layers:
         ring, h_kv = (c.n_window_layers, batch, c.window), c.mha_kind(True)[0]
         cache["state"] = {
@@ -535,6 +553,11 @@ DSA_QUERY_BLOCK = 128
 # ran 119 TFLOP/s at 24,576 tokens where 512 ran 105, my chip run, PR 32).
 PREFILL_ATTN_BLOCK = 1024
 PREFILL_HEAD_GROUP = 16
+# A latent prefill whose float32 scores [heads, S, S] would be larger than
+# this attends tile by tile (``blocked_causal_attention``) and never makes
+# them: 32 heads at 8,192 tokens would be 8.6 GB, at 3,072 1.2 GB; 20 heads
+# at 2,048 are 0.34 GB and go as one product.
+PREFILL_SCORE_BYTES = 1 << 30
 
 
 def _index_scores(q, w, k):
@@ -862,9 +885,15 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig,
                 layer=li, scale=_mla_scale(c), schedule=schedule)
             out = jnp.einsum("bshc,chk->bshk", o_lat[:, None],
                              wp["wuv"].astype(c.dtype))
+            # a parked lane (pos 0) keeps its rows as they are: its write
+            # goes past the last row and is dropped
+            at = jnp.where(pos > 0, pos, ckv.shape[2])
             return out, {
-                "ckv": ckv.at[li, b_idx, pos].set(c_new.astype(ckv.dtype)),
-                "kr": kr.at[li, b_idx, pos].set(r_new.astype(kr.dtype))}
+                **cache,
+                "ckv": ckv.at[li, b_idx, at].set(
+                    c_new.astype(ckv.dtype), mode="drop"),
+                "kr": kr.at[li, b_idx, at].set(
+                    r_new.astype(kr.dtype), mode="drop")}
 
         return cached_attn
 
@@ -1016,6 +1045,64 @@ def _prefill_recur(single, li, prompt_len, c: TransformerConfig):
     return _recurrence(recur)
 
 
+def _decode_kda(cache, li, pos, c: TransformerConfig):
+    """One decode layer's ``attn_fn`` for a "kda" layer (the counterpart
+    of ``_decode_recur``; ``transformer._kda_mixer`` calls its ``recur``):
+    every live lane's convolution window moves on one token and its state
+    one step of the delta rule; a PARKED lane (``pos`` 0) keeps its state
+    and its window as they were. The states are stepped by
+    ``ops/kda.kda_update`` on the WHOLE [layers, B, ...] leaf, aliased, at
+    layer ``li``. Returns (o, the cache)."""
+    def recur(qkv, g, beta, wp):
+        state = cache_state(cache)
+        k1, live = c.kda_conv - 1, pos > 0
+        with jax.named_scope("raytpu.kda.conv"):
+            tail = lax.dynamic_index_in_dim(state["conv"], li, 0, False)
+            tail = tail.reshape(tail.shape[0], k1, -1)
+            out = causal_conv(qkv, wp["conv_w"], None, tail)
+            moved = jnp.concatenate([tail[:, 1:], qkv.astype(tail.dtype)], 1)
+            tail = jnp.where(live[:, None, None], moved, tail)
+            conv = lax.dynamic_update_index_in_dim(
+                state["conv"], tail.reshape(tail.shape[0], -1), li, 0)
+        with jax.named_scope("raytpu.kda.update"):
+            q, k, v = kda_split(out[:, 0], c)
+            o, kda = kda_update(state["kda"], li, q, k, v, g[:, 0],
+                                beta[:, 0], live)
+        return o[:, None], {**cache, "state": {"kda": kda, "conv": conv}}
+
+    return _recurrence(recur)
+
+
+def _prefill_kda(single, li, prompt_len, c: TransformerConfig):
+    """One prefill layer's ``attn_fn`` for a "kda" layer: the convolution
+    and the chunked delta rule (``ops/kda.kda_chunked``) over the padded
+    prompt from an empty state. What the slot is handed is the state AT
+    ``prompt_len`` (the padding neither decays nor writes) and the last
+    ``kda_conv - 1`` REAL inputs of the convolution (zeros where the
+    prompt is shorter). ``single`` is one slot's cache. Returns (o, single
+    with this layer's state)."""
+    def recur(qkv, g, beta, wp):
+        state = cache_state(single)
+        k1, S = c.kda_conv - 1, qkv.shape[1]
+        with jax.named_scope("raytpu.kda.conv"):
+            out = causal_conv(qkv, wp["conv_w"], None)
+            at = prompt_len - k1 + jnp.arange(k1)
+            tail = jnp.where((at >= 0)[None, :, None], jnp.take(
+                qkv, jnp.clip(at, 0, S - 1), axis=1), 0)
+            conv = lax.dynamic_update_index_in_dim(
+                state["conv"], tail.reshape(1, -1).astype(
+                    state["conv"].dtype), li, 0)
+            q, k, v = kda_split(out, c)
+        with jax.named_scope("raytpu.kda.chunk"):
+            o, end = kda_chunked(
+                q, k, v, g, beta, c.kda_chunk,
+                valid=(jnp.arange(S) < prompt_len)[None])
+            kda = lax.dynamic_update_index_in_dim(state["kda"], end, li, 0)
+        return o, {**single, "state": {"kda": kda, "conv": conv}}
+
+    return _recurrence(recur)
+
+
 def prefill_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     """Names of the int32 counters the admission form of
     ``prefill_into_slot`` returns with its first token: for dropless
@@ -1101,6 +1188,8 @@ def _decode_forward_multi(params, token, cache, pos,
             x, cache, total, choice = carry
             if "ssm" in lp:
                 attn = _decode_recur(cache, li, lc)
+            elif "kda" in lp:
+                attn = _decode_kda(cache, li, pos, lc)
             elif "swa" in lp:
                 attn = _decode_window_attn(cache, li, pos, b_idx, lc, rings)
             elif choice is None:
@@ -1244,6 +1333,7 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
             @_latent
             def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
                 new = {
+                    **single,
                     "ckv": lax.dynamic_update_slice(
                         single["ckv"], c_kv[None].astype(
                             single["ckv"].dtype), (li, 0, 0, 0)),
@@ -1254,6 +1344,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
                 # nope + rope wide: the scale is causal_attention's own)
                 k, v = mla_expand(c_kv, k_r, wp, c)
                 q = jnp.concatenate([q_nope, q_rope], -1)
+                if 4 * q.shape[2] * S * S > PREFILL_SCORE_BYTES:
+                    return blocked_causal_attention(q, k, v), new
                 return causal_attention(q, k, v), new
 
             return cached_attn
@@ -1326,6 +1418,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
             x, single, choice, total = carry
             if "ssm" in lp:
                 attn = _prefill_recur(single, li, prompt_len, lc)
+            elif "kda" in lp:
+                attn = _prefill_kda(single, li, prompt_len, lc)
             elif "swa" in lp:
                 attn = _prefill_window_attn(single, li, prompt_len, lc)
             elif choice is None:

@@ -25,8 +25,11 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   head and a short convolution), or layers of a second ATTENTION kind
   that attend a window of the last rows and a learned sink ("window":
   their own KV head count and rotary base; such a model may route its
-  FFNs and lead with dense layers), one stack of parameters a kind, run
-  in the order the list gives;
+  FFNs and lead with dense layers), or layers whose mixer is a gated
+  delta rule ("kda", ``ops/kda.py``: a matrix state a head under a decay
+  a channel, beside attention layers of either mixer, every FFN routed
+  but the leading ones'), one stack of parameters a kind, run in the
+  order the list gives;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -46,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import causal_attention, window_attention
+from ray_tpu.ops.kda import kda_chunked
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked
 
 
@@ -173,12 +177,37 @@ class TransformerConfig:
     residual_scale: float = 1.0
     logit_scale: float = 1.0
     attn_scale: Optional[float] = None
+    # A linear-attention kind of layer, "kda" in layer_types (beside
+    # "attention" layers of either mixer; not beside "ssm" or "window"):
+    # kda_heads heads whose keys AND values are kda_head_dim wide (D).
+    # [q | k | v] = silu(conv(h W_qkv)): one causal depthwise convolution
+    # of kda_conv taps over the three streams, no bias; q / ||q|| / sqrt(D)
+    # and k / ||k|| a head. Log-decay a channel g = -exp(a_log) softplus(
+    # (h W_fa) W_fb + dt_bias) (a_log a head, the low-rank pair kda_head_dim
+    # wide), write strength beta = sigmoid(h W_b) a head. The state S
+    # [D, D] a head in float32: S <- Diag(exp g) S; S <- S + beta k (v -
+    # S^T k)^T; o = S^T q (ops/kda.py). out = W_o (RMSNorm_w(o) a head x
+    # sigmoid((h W_ga) W_gb)). Its parameters are params["kda_layers"]
+    # (the mixer's under "kda"); such a model may route its FFNs (moe_impl
+    # "dropless"), and its leading dense layers, all of ONE kind, may be
+    # "kda" layers (params["dense_layers"] then holds a "kda" mixer).
+    # For a latent mixer beside them or alone: q_lora_rank 0 projects the
+    # queries directly (W_q [d, H, nope + rope], no bottleneck, no norm),
+    # and without mla_rope the qk_rope_dim dims all heads share are kept
+    # and NOT rotated (no positional term).
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 64  # tokens a chunk of the prefill's delta rule
+    mla_rope: bool = True
 
     def __post_init__(self):
         if self.layer_types:
             kinds = tuple(self.layer_types)
             if len(kinds) != self.n_layers or set(kinds) - {
-                    "attention", "ssm", "window"} or self.mixer != "mha" or (
+                    "attention", "ssm", "window", "kda"} or (
+                    self.mixer != "mha" and set(kinds) - {
+                        "attention", "kda"}) or (
                     self.residual != "sequential") or (
                     "ssm" in kinds and (
                         self.moe_experts or "window" in kinds
@@ -187,10 +216,21 @@ class TransformerConfig:
                         % self.ssm_groups)):
                 raise ValueError(
                     "layer_types needs one entry a layer ('attention' | "
-                    "'ssm' | 'window'), mixer 'mha', a sequential block; "
+                    "'ssm' | 'window' | 'kda'), mixer 'mha' (beside 'kda' "
+                    "layers alone: either mixer), a sequential block; "
                     "beside 'ssm' layers a dense FFN, no 'window' layer, "
                     "and the ssm_* sizes (heads a multiple of groups)")
             n_dense = self.n_dense_layers if self.moe_experts else 0
+            if "kda" in kinds and (
+                    set(kinds) - {"attention", "kda"}
+                    or not self.kda_heads * self.kda_head_dim
+                    or self.kda_conv < 2 or len(set(kinds[:n_dense])) > 1
+                    or (self.moe_experts and self.moe_impl != "dropless")):
+                raise ValueError(
+                    "a 'kda' layer stands beside 'attention' layers alone "
+                    "and needs kda_heads, kda_head_dim, kda_conv >= 2, "
+                    "dropless experts where the FFNs are routed, and "
+                    "leading dense layers of one kind")
             if "window" in kinds and (
                     self.window < 1 or self.n_heads % (
                         self.window_kv_heads or self.kv_heads)
@@ -244,10 +284,20 @@ class TransformerConfig:
         return sum(k == "window" for k in self.layer_types)
 
     @property
+    def n_kda_layers(self) -> int:
+        return sum(k == "kda" for k in self.layer_types)
+
+    @property
     def n_attn_layers(self) -> int:
         """Layers that attend every row: the layers the K/V cache holds
         rows for."""
-        return self.n_layers - self.n_ssm_layers - self.n_window_layers
+        return (self.n_layers - self.n_ssm_layers - self.n_window_layers
+                - self.n_kda_layers)
+
+    @property
+    def kda_inner(self) -> int:
+        """Width of one of a "kda" layer's three streams."""
+        return self.kda_heads * self.kda_head_dim
 
     @property
     def v_dim(self) -> int:
@@ -298,8 +348,10 @@ class TransformerConfig:
             ffn = d * self.moe_experts + 2 * self.moe_experts * d * f
         if self.mixer == "mla":
             qk = self.qk_nope_dim + self.qk_rope_dim
-            attn = (d * self.q_lora_rank + self.q_lora_rank
-                    + self.q_lora_rank * h * qk
+            queries = (d * self.q_lora_rank + self.q_lora_rank
+                       + self.q_lora_rank * h * qk
+                       ) if self.q_lora_rank else d * h * qk
+            attn = (queries
                     + d * (self.kv_lora_rank + self.qk_rope_dim)
                     + self.kv_lora_rank
                     + self.kv_lora_rank * h * (self.qk_nope_dim
@@ -319,8 +371,12 @@ class TransformerConfig:
         inner, width = self.ssm_inner, self.ssm_conv_width
         ssm = (d * (inner + width + self.ssm_heads) + inner * d
                + width * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner)
+        ki, kd = self.kda_inner, self.kda_head_dim
+        kda = (d * 3 * ki + self.kda_conv * 3 * ki + self.kda_heads + ki
+               + 2 * (d * kd + kd * ki) + d * self.kda_heads + kd + ki * d)
         layers = (self.n_attn_layers * attn + self.n_ssm_layers * ssm
-                  + self.n_window_layers * window + self.n_layers * norms + n_dense * dense_ffn
+                  + self.n_window_layers * window + self.n_kda_layers * kda
+                  + self.n_layers * norms + n_dense * dense_ffn
                   + (self.n_layers - n_dense) * ffn + indexer)
         head = 0 if self.tie_embeddings else d * self.vocab_size
         return self.vocab_size * d + layers + d + head
@@ -506,6 +562,55 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     @staticmethod
+    def kimi_linear(n_layers: int = 27, **kw) -> "TransformerConfig":
+        """Kimi-Linear-48B-A3B (moonshotai/Kimi-Linear-48B-A3B-Instruct
+        config.json, model_type kimi_linear) at its published widths: 27
+        layers in a period of four, three "kda" layers (32 heads of 128,
+        4 taps) and then a latent-attention layer (32 heads, direct
+        queries, 512 + 64 wide rows, no rotation: no positional term
+        anywhere); the first layer's FFN dense, the others 256 routed
+        experts (8 a token, sigmoid scores, bias-corrected choice) and a
+        shared one. A cut passes its own ``layer_types`` /
+        ``moe_experts_held``."""
+        base = dict(
+            vocab_size=163840, d_model=2304, n_layers=n_layers, n_heads=32,
+            d_ff=9216, max_seq_len=1048576, mixer="mla", q_lora_rank=0,
+            kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+            v_head_dim=128, mla_rope=False, residual="sequential",
+            activation="silu", gated_ffn=True, norm_eps=1e-5,
+            layer_types=tuple(
+                "attention" if i % 4 == 3 or i == n_layers - 1 else "kda"
+                for i in range(n_layers)),
+            kda_heads=32, kda_head_dim=128, kda_conv=4, kda_chunk=64,
+            moe_experts=256, moe_top_k=8, moe_impl="dropless", moe_d_ff=1024,
+            moe_shared_experts=1, moe_route_scale=2.446, n_dense_layers=1,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_kda_moe(**kw) -> "TransformerConfig":
+        """The same kind of model at test size (CPU): six layers ``kda
+        (dense) kda kda attention kda attention``, 2 kda heads of 16 in
+        chunks of 8; latent attention as ``tiny_mla_moe``'s with direct
+        queries and no rotation; 8 routed experts (2 a token) and a shared
+        one."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=6, n_heads=4, d_ff=160,
+            max_seq_len=1024, mixer="mla", q_lora_rank=0, kv_lora_rank=16,
+            qk_nope_dim=12, qk_rope_dim=8, v_head_dim=16, mla_rope=False,
+            residual="sequential", activation="silu", gated_ffn=True,
+            norm_eps=1e-5,
+            layer_types=("kda", "kda", "kda", "attention", "kda",
+                         "attention"),
+            kda_heads=2, kda_head_dim=16, kda_conv=4, kda_chunk=8,
+            moe_experts=8, moe_top_k=2, moe_impl="dropless", moe_d_ff=48,
+            moe_shared_experts=1, moe_route_scale=2.446, n_dense_layers=1,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny_mla_moe(**kw) -> "TransformerConfig":
         """The same block at test size (CPU)."""
         base = dict(
@@ -579,10 +684,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
             h, r_q, r_kv = lc.n_heads, lc.q_lora_rank, lc.kv_lora_rank
             qk = lc.qk_nope_dim + lc.qk_rope_dim
             layers["attn"] = {
-                "wdq": dense_init(kq, (L, d, r_q), d),
-                "q_norm": jnp.ones((L, r_q), pd),
-                "wuq": dense_init(jax.random.fold_in(kq, 1),
-                                  (L, r_q, h, qk), r_q),
+                **({"wdq": dense_init(kq, (L, d, r_q), d),
+                    "q_norm": jnp.ones((L, r_q), pd),
+                    "wuq": dense_init(jax.random.fold_in(kq, 1),
+                                      (L, r_q, h, qk), r_q)} if r_q else
+                   {"wq": dense_init(kq, (L, d, h, qk), d)}),
                 "wdkv": dense_init(kk, (L, d, r_kv + lc.qk_rope_dim), d),
                 "kv_norm": jnp.ones((L, r_kv), pd),
                 "wuk": dense_init(jax.random.fold_in(kk, 1),
@@ -697,11 +803,49 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
         }
         return layers
 
+    def kda_stack(lc: TransformerConfig, L: int, salt: int) -> Dict:
+        """L "kda" layers of ``lc``'s block: norms and FFN as ``stack``
+        draws them, and the mixer's own parameters. The decay's are the
+        family's published ones (``ssm_stack``): exp(a_log) ~ U(1, 16) a
+        head, dt_bias the inverse softplus of a step log-uniform in
+        0.001-0.1 a channel, and the low-rank pair's second matrix drawn
+        at a quarter of the usual spread so that softplus(. + dt_bias)
+        stays near that step: a token's decay a channel then lies in about
+        0.2-0.999 and differs by channel. At normal(0, 1/sqrt(fan_in))
+        throughout the state forgets within a few tokens."""
+        layers = stack(lc, L, salt, 0, attends=False)
+        d, inner, nh, dk = lc.d_model, lc.kda_inner, lc.kda_heads, \
+            lc.kda_head_dim
+        ks = jax.random.split(jax.random.fold_in(k_q, salt + 2), 10)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (L, inner), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+        bound = lc.kda_conv ** -0.5
+        layers["kda"] = {
+            "wqkv": dense_init(ks[0], (L, d, 3 * inner), d),
+            "conv_w": jax.random.uniform(
+                ks[1], (L, lc.kda_conv, 3 * inner), minval=-bound,
+                maxval=bound).astype(pd),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[2], (L, nh), minval=1.0, maxval=16.0)).astype(pd),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+            "wfa": dense_init(ks[3], (L, d, dk), d),
+            "wfb": 0.25 * dense_init(ks[4], (L, dk, inner), dk),
+            "wb": dense_init(ks[6], (L, d, nh), d),
+            "wga": dense_init(ks[7], (L, d, dk), d),
+            "wgb": dense_init(ks[8], (L, dk, inner), dk),
+            "norm": jnp.ones((L, dk), pd),
+            "wo": dense_init(ks[9], (L, inner, d), inner),
+        }
+        return layers
+
     n_dense = c.n_dense_layers if c.moe_experts else 0
+    # the leading dense layers are all of one kind (__post_init__)
+    dense_kda = n_dense if c.layer_types[:1] == ("kda",) else 0
     params = {
         "embed": (jax.random.normal(k_emb, (c.vocab_size, c.d_model)) * 0.02
                   ).astype(pd),
-        "layers": stack(c, c.n_attn_layers - n_dense, 0, n_dense),
+        "layers": stack(c, c.n_attn_layers - n_dense + dense_kda, 0,
+                        n_dense),
         "final_ln": {"scale": jnp.ones((c.d_model,), pd)},
     }
     if c.n_ssm_layers:
@@ -709,7 +853,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     if c.n_window_layers:
         params["window_layers"] = stack(c, c.n_window_layers, 17, 0,
                                         window=True)
-    if n_dense:
+    if c.n_kda_layers - dense_kda:
+        params["kda_layers"] = kda_stack(c, c.n_kda_layers - dense_kda, 19)
+    if dense_kda:
+        params["dense_layers"] = kda_stack(c.dense_variant(), n_dense, 23)
+    elif n_dense:
         params["dense_layers"] = stack(c.dense_variant(), n_dense, 7, 0)
     if not c.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (c.d_model, c.vocab_size),
@@ -729,9 +877,11 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
             layers["ln2"] = {"scale": ("layers", "embed")}
         if lc.mixer == "mla":
             layers["attn"] = {
-                "wdq": ("layers", "embed", None),
-                "q_norm": ("layers", None),
-                "wuq": ("layers", None, "heads", "head_dim"),
+                **({"wdq": ("layers", "embed", None),
+                    "q_norm": ("layers", None),
+                    "wuq": ("layers", None, "heads", "head_dim")}
+                   if lc.q_lora_rank else
+                   {"wq": ("layers", "embed", "heads", "head_dim")}),
                 "wdkv": ("layers", "embed", None),
                 "kv_norm": ("layers", None),
                 "wuk": ("layers", None, "heads", "head_dim"),
@@ -804,8 +954,30 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
         if config.window_sink:
             swa["swa"]["sink"] = ("layers", "heads")
         axes["window_layers"] = swa
+    def as_kda(layers: Dict) -> Dict:
+        del layers["attn"]
+        layers["kda"] = {
+            "wqkv": ("layers", "embed", "mlp"),
+            "conv_w": ("layers", None, "mlp"),
+            "a_log": ("layers", None),
+            "dt_bias": ("layers", "mlp"),
+            "wfa": ("layers", "embed", None),
+            "wfb": ("layers", None, "mlp"),
+            "wb": ("layers", "embed", None),
+            "wga": ("layers", "embed", None),
+            "wgb": ("layers", None, "mlp"),
+            "norm": ("layers", None),
+            "wo": ("layers", "mlp", "embed"),
+        }
+        return layers
+
+    dense_kda = n_dense and config.layer_types[:1] == ("kda",)
+    if config.n_kda_layers - (n_dense if dense_kda else 0):
+        axes["kda_layers"] = as_kda(stack(config, 0, config.n_kda_layers))
     if n_dense:
         axes["dense_layers"] = stack(config.dense_variant(), 0, n_dense)
+        if dense_kda:
+            axes["dense_layers"] = as_kda(axes["dense_layers"])
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -820,17 +992,21 @@ def layer_groups(params: Dict, config: TransformerConfig):
     stacks, ``{"attention": .., "ssm": ..}``: ``scan_stack`` runs them in
     the listed order. With "window" layers the stacks are ``{"attention":
     .., "window": ..}`` and the leading dense layers, all of kind
-    "attention", a group of their own before them."""
+    "attention", a group of their own before them; with "kda" layers
+    ``{"attention": .., "kda": ..}`` and the leading dense layers, of
+    whichever ONE kind they are, a group of their own."""
     n_dense = config.n_dense_layers if config.moe_experts else 0
     if config.n_ssm_layers:
         return [({"attention": params["layers"],
                   "ssm": params["ssm_layers"]}, config, 0)]
     if config.layer_types:
-        lead = [({"attention": params["dense_layers"]},
+        lead = [({config.layer_types[0]: params["dense_layers"]},
                  config.dense_variant(), 0)] if n_dense else []
         rest = {"attention": params["layers"]}
         if config.n_window_layers:
             rest["window"] = params["window_layers"]
+        if "kda_layers" in params:
+            rest["kda"] = params["kda_layers"]
         return lead + [(rest, config, n_dense)]
     groups = []
     if n_dense:
@@ -1130,6 +1306,71 @@ def _ssm_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     return out, extra
 
 
+def kda_split(qkv, c: TransformerConfig):
+    """The convolved streams [..., 3 x kda_inner] as a "kda" layer's q, k
+    and v [..., H, D], in the compute dtype: SiLU, then q / ||q|| /
+    sqrt(D) and k / ||k|| a head (float32 norms, eps 1e-6 under the
+    root)."""
+    lead, nh, dk = qkv.shape[:-1], c.kda_heads, c.kda_head_dim
+    q, k, v = jnp.split(jax.nn.silu(qkv), 3, axis=-1)
+
+    def unit(x, scale):
+        x32 = x.reshape(lead + (nh, dk)).astype(jnp.float32)
+        return (x32 * (scale * lax.rsqrt(
+            (x32 * x32).sum(-1, keepdims=True) + 1e-6))).astype(c.dtype)
+
+    return unit(q, dk ** -0.5), unit(k, 1.0), v.reshape(lead + (nh, dk))
+
+
+def _kda_whole_sequence(qkv, g, beta, wp, c: TransformerConfig):
+    """A "kda" layer's recurrence over whole sequences from an empty
+    state (the uncached forward): the convolution, then the chunked delta
+    rule. qkv [B,S,3 x inner] before its convolution, g [B,S,H,D] and
+    beta [B,S,H] in float32. Returns (o [B,S,H,D], None)."""
+    with jax.named_scope("raytpu.kda.conv"):
+        q, k, v = kda_split(causal_conv(qkv, wp["conv_w"], None), c)
+    with jax.named_scope("raytpu.kda.chunk"):
+        o, _state = kda_chunked(q, k, v, g, beta, c.kda_chunk)
+    return o, None
+
+
+def _kda_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """The gated-delta-rule mixer of a "kda" layer (``TransformerConfig.
+    layer_types``; the equations are in the config's comment). The
+    recurrence itself, convolution and delta rule, is ``attn_fn.recur(
+    qkv, g, beta, wp) -> (o, extra)`` where the serving paths bring one
+    (they keep the state and the convolution's tail in a slot:
+    ``generation.py``), else the whole sequence from an empty state."""
+    f32 = jnp.float32
+    nh, dk = c.kda_heads, c.kda_head_dim
+    with jax.named_scope("raytpu.kda.project"):
+        qkv = jnp.einsum("bsd,df->bsf", h, wp["wqkv"].astype(c.dtype))
+
+        def low_rank(a, b):
+            return jnp.einsum(
+                "bsr,rf->bsf",
+                jnp.einsum("bsd,dr->bsr", h, wp[a].astype(c.dtype)),
+                wp[b].astype(c.dtype), preferred_element_type=f32)
+
+        step = jax.nn.softplus(
+            low_rank("wfa", "wfb") + wp["dt_bias"].astype(f32))
+        g = -jnp.exp(wp["a_log"].astype(f32))[:, None] * step.reshape(
+            step.shape[:2] + (nh, dk))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", h, wp["wb"].astype(c.dtype),
+            preferred_element_type=f32))
+        gate = low_rank("wga", "wgb")
+    recur = getattr(attn_fn, "recur", None) or partial(
+        _kda_whole_sequence, c=c)
+    o, extra = recur(qkv, g, beta, wp)
+    with jax.named_scope("raytpu.kda.gate"):
+        o = _rms_norm(o, wp["norm"], c.norm_eps)  # over each head's D
+        o = o.reshape(gate.shape) * jax.nn.sigmoid(gate).astype(o.dtype)
+    with jax.named_scope("raytpu.kda.project"):
+        out = jnp.einsum("bsf,fd->bsd", o, wp["wo"].astype(c.dtype))
+    return out, extra
+
+
 def mla_expand(c_kv, k_r, wp, c: TransformerConfig):
     """The plain form's keys and values from latents: c_kv [B,S,r] and the
     shared rotary key k_r [B,S,1,rope] -> k [B,S,H,nope+rope], v
@@ -1176,14 +1417,19 @@ def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     that is how the serving paths keep and walk a cache of latents."""
     r, nope = c.kv_lora_rank, c.qk_nope_dim
     with jax.named_scope("raytpu.mla.project"):
-        c_q = _rms_norm(
-            jnp.einsum("bsd,dr->bsr", h, wp["wdq"].astype(c.dtype)),
-            wp["q_norm"], c.norm_eps)
-        q = jnp.einsum("bsr,rhk->bshk", c_q, wp["wuq"].astype(c.dtype))
+        if c.q_lora_rank:
+            c_q = _rms_norm(
+                jnp.einsum("bsd,dr->bsr", h, wp["wdq"].astype(c.dtype)),
+                wp["q_norm"], c.norm_eps)
+            q = jnp.einsum("bsr,rhk->bshk", c_q, wp["wuq"].astype(c.dtype))
+        else:  # no bottleneck: the queries straight from the input
+            q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
         kv = jnp.einsum("bsd,dr->bsr", h, wp["wdkv"].astype(c.dtype))
         c_kv = _rms_norm(kv[..., :r], wp["kv_norm"], c.norm_eps)
-        q_rope, k_r = _rotary(q[..., nope:], kv[:, :, None, r:],
-                              c.qk_rope_dim, positions, c.rope_theta)
+        q_rope, k_r = q[..., nope:], kv[:, :, None, r:]
+        if c.mla_rope:  # else the shared dims are kept as they are
+            q_rope, k_r = _rotary(q_rope, k_r, c.qk_rope_dim, positions,
+                                  c.rope_theta)
         q_nope = q[..., :nope]
     chosen = ()
     if c.index_topk:
@@ -1240,6 +1486,8 @@ def apply_block(
     h = _rms_norm(x, lp["ln1"]["scale"], c.norm_eps)
     if "ssm" in lp:  # a layer of the other kind (TransformerConfig.layer_types)
         a, extra = _ssm_mixer(h, lp["ssm"], c, positions, attn_fn)
+    elif "kda" in lp:
+        a, extra = _kda_mixer(h, lp["kda"], c, positions, attn_fn)
     elif "swa" in lp:  # a window layer: ``attn_fn`` is a window's
         a, extra = _mha_mixer(h, lp["swa"], c, positions, attn_fn, True)
     else:

@@ -135,6 +135,41 @@ ROUTED_TOKENS_A_PASS = 4096
 # pass is what the rounding leaves.
 ROUTED_ROWS_A_TILE = 512
 
+# A share of at least one expert in this many brings a turn's rows back to
+# their tokens as a product with the turn's 0/1 matrix of (token, row); a
+# smaller share by XLA's scatter-add. Two ways, because neither serves
+# both (my chip runs, PR 44, PERF.md section 6): at a quarter share (64 of
+# 256, 8 a token, rows 2,304 wide) the scatter-add did not return on a v5e
+# for some prompts, whichever turn it was given alone, where the loop
+# without it and the product do; at a sixteenth (rows 4,096 and 6,144
+# wide), where the scatter-add has run every prefill since PR 43, the
+# product reads and writes all of the [tokens, D] output a turn and costs a
+# layer -7 % at 1,024 tokens, nothing at 2,048, +46 % and +13 % at 4,096.
+# Why the scatter-add hangs is open, so the share is a stand-in for the
+# cause; one way for both needs a kernel that adds rows in place.
+ROUTED_SHARE_BY_PRODUCT = 8
+
+
+def _rows_to_tokens(token: jax.Array, rows: jax.Array, n: int,
+                    exact: bool) -> jax.Array:
+    """[n, d] float32 with row r of ``rows`` [R, d] float32 added into row
+    ``token[r]``, as one product with the 0/1 matrix [n, R]: the float32
+    rows split into two bf16 halves, one MXU pass each with float32
+    accumulation (what is lost is under 2^-16 of a row), or, ``exact``,
+    a true float32 product (a model that computes in float32). It costs
+    n x R x d twice and a pass over all n rows of the output, whatever
+    the rows (``ROUTED_SHARE_BY_PRODUCT``)."""
+    f32 = jnp.float32
+    hit = jnp.arange(n)[:, None] == token[None, :]
+    if exact:
+        return jnp.dot(hit.astype(f32), rows,
+                       precision=jax.lax.Precision.HIGHEST)
+    hi = rows.astype(jnp.bfloat16)
+    lo = (rows - hi.astype(f32)).astype(jnp.bfloat16)
+    hit = hit.astype(jnp.bfloat16)
+    return (jnp.dot(hit, hi, preferred_element_type=f32)
+            + jnp.dot(hit, lo, preferred_element_type=f32))
+
 
 def routed_ffn(
     x: jax.Array,  # [..., D]
@@ -191,8 +226,11 @@ def routed_ffn(
     ``weight x result`` to its tokens' rows of the float32 output
     (scatter-add, so a token's choices are summed in the experts' order:
     float32 rounding of at most ``top_k`` terms apart from the other
-    form). No array of all the pairs is gathered, selected or brought
-    back to token order, there is no cap and nothing is dropped: with
+    form; for a share of an eighth of the layer or more a product with
+    the turn's 0/1 matrix of (token, row) instead:
+    ``ROUTED_SHARE_BY_PRODUCT``). No array of all the pairs is gathered,
+    selected or brought back to token order, there is no cap and nothing
+    is dropped: with
     every pick held the loop runs over all the pairs. The rule, from
     shapes alone: the loop is taken where the weights hold a share AND
     the pairs outnumber one tile; a whole layer (every pair is live) and
@@ -279,6 +317,8 @@ def routed_ffn(
             visits, moved = schedule.visits, jnp.int32(rows)
         else:
             tile = ROUTED_ROWS_A_TILE
+            by_product = (E * ROUTED_SHARE_BY_PRODUCT
+                          >= wp["router"].shape[-1])
             turns = (edges[E] + tile - 1) // tile  # the live pairs' tiles
             pairs = jnp.pad(order, (0, -rows % tile))
             w_pair = w.reshape(-1)
@@ -297,7 +337,11 @@ def routed_ffn(
                 ys = jnp.where(
                     (lo + jnp.arange(tile) < edges[E])[:, None],
                     ys.astype(f32) * w_pair[pair][:, None], 0)
-                return y.at[token].add(ys), visits + schedule.visits
+                if by_product:
+                    y = y + _rows_to_tokens(token, ys, n, x.dtype == f32)
+                else:
+                    y = y.at[token].add(ys)
+                return y, visits + schedule.visits
 
             y, visits = jax.lax.fori_loop(
                 0, turns, turn, (jnp.zeros((n, d), f32), jnp.int32(0)))

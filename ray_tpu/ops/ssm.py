@@ -232,7 +232,7 @@ def ssm_update(states, layer, x, dt, A, B, C, D, *,
 
 def causal_conv(x, w, bias, tail: Optional[jax.Array] = None):
     """Depthwise causal convolution over a sequence: ``x`` [B,S,C], ``w``
-    [K,C] (tap K-1 is the token's own), ``bias`` [C]; the K-1 inputs
+    [K,C] (tap K-1 is the token's own), ``bias`` [C] or None; the K-1 inputs
     before the first token are ``tail`` [B,K-1,C] (zeros where None).
     Returns the convolved [B,S,C] in x's type (float32 sums)."""
     k = w.shape[0]
@@ -240,7 +240,7 @@ def causal_conv(x, w, bias, tail: Optional[jax.Array] = None):
     if tail is None:
         tail = jnp.zeros((x.shape[0], k - 1, x.shape[2]), x.dtype)
     window = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    out = bias.astype(F32)
+    out = 0.0 if bias is None else bias.astype(F32)
     for j in range(k):
         out = out + window[:, j:j + s].astype(F32) * w[j].astype(F32)
     return out.astype(x.dtype)

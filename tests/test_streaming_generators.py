@@ -206,3 +206,87 @@ def test_streaming_yield_with_nested_ref_raises(rt):
     g = gen.remote()
     with pytest.raises(Exception, match="ObjectRef"):
         next(iter(g))
+
+
+def test_streaming_burst_rides_in_few_reports(rt):
+    """Yields made in a row while a report is on its way ride together in
+    the next one: a generator that yields its items in bursts (a token
+    stream yields a decode block's tokens so) reaches the consumer whole
+    and in order, in fewer report round trips than yields."""
+
+    @ray_tpu.remote(num_returns="streaming")
+    def bursts(n, burst):
+        for i in range(n):
+            if i % burst == 0:
+                time.sleep(0.05)
+            yield i
+
+    g = bursts.remote(64, 8)
+    assert [ray_tpu.get(r) for r in g] == list(range(64))
+    # 8 bursts: the first yield of each leaves alone, the seven behind it
+    # wait for its reply; one report a yield would be 64
+    assert g._stream.reports <= 40, g._stream.reports
+
+
+def test_yield_reporter_batches_by_the_callers_room():
+    """``_YieldReporter`` alone, against a caller that holds each reply
+    back: one report on its way at a time, the next carries what was put
+    meanwhile, never more than the last reply had room for; a NACK stops
+    ``put``."""
+    import asyncio
+    import threading
+    from types import SimpleNamespace
+
+    from ray_tpu._private.core_worker import _YieldReporter
+
+    loop = asyncio.new_event_loop()
+    threading.Thread(target=loop.run_forever, daemon=True).start()
+    calls, release = [], []
+
+    class Conn:
+        async def call_async(self, method, msg, timeout=None):
+            assert method == "report_generator_items"
+            calls.append([it["index"] for it in msg["items"]])
+            fut = loop.create_future()
+            release.append(fut)
+            return await fut
+
+    async def conn_to(addr):
+        return Conn()
+
+    worker = SimpleNamespace(io=SimpleNamespace(loop=loop), _conn_to=conn_to)
+    spec = SimpleNamespace(owner=(b"", "addr"), task_id=b"t")
+    rep = _YieldReporter(worker, spec)
+
+    def reply(**kw):
+        fut = release.pop(0)
+        loop.call_soon_threadsafe(fut.set_result, kw)
+
+    def wait_calls(n):
+        deadline = time.monotonic() + 10
+        while len(calls) < n:
+            assert time.monotonic() < deadline, calls
+            time.sleep(0.005)
+
+    try:
+        assert rep.put({"index": 0})
+        wait_calls(1)
+        assert calls == [[0]]  # the first leaves at once
+        for i in range(1, 6):  # its reply is held back: these wait
+            assert rep.put({"index": i})
+        reply(ok=True, room=2)
+        wait_calls(2)
+        assert calls[1] == [1, 2]  # what waited, as far as there is room
+        reply(ok=True, room=8)
+        wait_calls(3)
+        assert calls[2] == [3, 4, 5]
+        reply(ok=True, room=8)
+        assert rep.flush() is True
+        assert rep.put({"index": 6})
+        wait_calls(4)
+        reply(ok=False, room=1)  # the consumer abandoned the stream
+        assert rep.flush() is False
+        assert rep.put({"index": 7}) is False
+        assert len(calls) == 4
+    finally:
+        loop.call_soon_threadsafe(loop.stop)

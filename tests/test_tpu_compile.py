@@ -279,6 +279,82 @@ def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "12288,12288" not in hlo  # no [S, S] array of any type
 
 
+@pytest.mark.parametrize("program", ["decode_block", "admission_8192"])
+def test_kda_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """Kimi-Linear's first eight layers (K dense, K K F, K K K F; every
+    width as published, 64 of 256 experts and a shared one, 1/4 of the
+    vocabulary, bf16) at the benchmark's engine sizes: 96 slots x 10,240
+    rows. The runs of like layers are scans that index the WHOLE parameter
+    stacks; no layer's experts, no state leaf and no layer's slice of one
+    is copied (ISSUE 44); the slots' 1.2 GB of float32 matrix states, the
+    convolutions' tails and the latent rows are updated in place; a
+    decode step steps the states with ``ops/kda.kda_update`` on the whole
+    leaf, once a run of the period, and reads the two full layers' rows
+    with the decode attention's kernel; the admission is the engine's
+    fused form at the largest bucket and leaves room on a 16 GB chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.kimi_linear(
+        8, layer_types=("kda", "kda", "kda", "attention") * 2,
+        vocab_size=40960, moe_experts_held=64, param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 96, 10240)))
+    lanes = (arr((96,)), arr((96,)), arr((96,), jnp.float32), arr((96,)),
+             arr((96,)))
+    if program == "decode_block":
+        low = gen.decode_block.lower(params, cache, *lanes, cfg, 2)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, 8192)), arr(()), arr(()), cache, cfg, lanes,
+            arr((), jnp.float32), arr(()))
+        assert list(low.out_info[3]) == list(gen.prefill_stat_keys(cfg))
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    foot = gen.slot_footprint(cache)
+    assert (foot["state_bytes"], foot["row_bytes"]) == (13_025_280, 2304)
+    cache_bytes = 96 * (foot["state_bytes"] + 10240 * foot["row_bytes"])
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 13.0 * 2 ** 30
+    hlo = compiled.as_text()
+    for of in ("f32[6,96,32,128,", "f32[96,32,128,128", "f32[1,96,32,128,",
+               "bf16[6,96,36864", "bf16[2,96,10240,", "bf16[96,10240,",
+               "bf16[5,64,", "bf16[2,64,", "bf16[64,2304,", "bf16[64,1024,",
+               "bf16[5,2304,12288", "bf16[40960,", "bf16[2304,40960"):
+        assert not _copies(hlo, of), of
+    for scope in ("raytpu.kda.project", "raytpu.kda.conv", "raytpu.kda.gate",
+                  "raytpu.mla.project", "raytpu.mla.attend",
+                  "raytpu.moe.route", "raytpu.moe.experts",
+                  "raytpu.moe.shared"):
+        assert scope in hlo, scope
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    if program == "decode_block":
+        # one body a run of the period: K(dense) | K K, F, K K K, F
+        updates = [line for line in calls if "kda_update" in line]
+        assert len(updates) == 3
+        assert all("raytpu.kda.update" in line for line in updates)
+        assert all("f32[6,96,32,128,128]" in line for line in updates)
+        assert sum("decode_attention" in line for line in calls) == 2
+        assert sum("raytpu.moe.experts" in line for line in calls) == 8
+    else:
+        assert "raytpu.kda.chunk" in hlo
+        assert not any("kda_update" in line for line in calls)
+        assert "[8192,8192]" not in hlo  # no prompt's scores whole
+
+
 @pytest.fixture(scope="module")
 def as_on_the_chip():
     """Here the backend is the CPU, where a Pallas kernel would be
@@ -525,18 +601,20 @@ def test_mimo_admission_moves_no_array_of_all_the_sorted_pairs(
 
 # sha256 of ``lower(...).as_text()`` on the CPU (where a kernel is its
 # interpreter's jaxpr: the text carries no source line) of GLM-4.7-Flash's
-# decode programs at the benchmark's engine sizes, as the parent of
-# ISSUE 43 (727df70) lowers them
+# decode programs at the benchmark's engine sizes. Until PR 44 they were
+# what the parent of ISSUE 43 (727df70) lowers; PR 44 changed one thing in
+# them: a parked lane's latent row is written past the last row and
+# dropped (``_decode_attn``), a select over the lanes' positions a layer
 GLM47_DECODE_TEXTS = {
     "decode_block_2": (
-        "8be9d0b8da1d94f44a5f4abd668b6124"
-        "07f85d97e2f5bf368875c503cae91b65"),
+        "c611aa7f2907d1f6a87196172b779c64"
+        "e98f2e9fb5025e3e00ae333e4f9b9f2b"),
     "decode_block_8": (
-        "ad3b4c4e66036bd7353b13a610956e0a"
-        "aab40b1e2844beb9577183f0167bafaf"),
+        "a12604c86c8ccee7ac1d379a0d308953"
+        "5d9fbaa56208e052d721c20a615eb1e5"),
     "decode_step_multi": (
-        "5ba2ec6eac817df76e512c2e33175c7d"
-        "1bff40835a6d701fc897297a0c3e6442"),
+        "f08946530c248b5eae65707c46f66381"
+        "e5aa617340d416690e8ff62f5317373b"),
 }
 
 

@@ -2,6 +2,8 @@
 ``tpot_p50_ms`` moves with the seed, before any chip time is spent on it.
 
     python3 tools/saturated_cell_model.py codeagent-saturated [rate ...]
+    python3 tools/saturated_cell_model.py longreason-saturated 6.5 \
+        --slots=96 --step=15.5,0.02 --prefill=6,24,1.2
 
 ``benchmarks/loadgen.quantile_open_loop`` offers every seed the same cycle
 of requests, entered at a place the seed picks, so a cell has only n
@@ -15,7 +17,10 @@ traced run of the cell (PR 39, `serve-mimo-codeagent-saturated`: a step
 8.0 ms + 0.067 ms a live lane; a prefill 4 ms + 25 ms a thousand padded
 tokens + 0.45 ms a thousand squared); with them the model gave that
 cell's per-seed readings to +-0.3 ms after a +0.5 ms offset (PERF.md
-section 6, PR 39). Change them for another cell. No JAX, no chip.
+section 6, PR 39). Another cell gives its own: ``--slots=`` the engine's
+slots, ``--step=`` a step's fixed and per-live-lane ms, ``--prefill=``
+a prefill's fixed ms, ms a thousand padded tokens and ms a thousand
+squared. No JAX, no chip.
 """
 
 from __future__ import annotations
@@ -90,7 +95,20 @@ def tpot_p50_ms(mix, k: int) -> float:
 
 
 def main() -> int:
-    name, rates = sys.argv[1], [float(r) for r in sys.argv[2:]]
+    global SLOTS, STEP_MS, PREFILL_MS
+    args = [a for a in sys.argv[1:] if not a.startswith("--")]
+    for opt in (a for a in sys.argv[1:] if a.startswith("--")):
+        key, _, value = opt[2:].partition("=")
+        times = tuple(float(x) for x in value.split(","))
+        if key == "slots":
+            SLOTS = int(times[0])
+        elif key == "step" and len(times) == 2:
+            STEP_MS = times
+        elif key == "prefill" and len(times) == 3:
+            PREFILL_MS = times
+        else:
+            raise SystemExit(f"unknown or ill-formed option {opt!r}")
+    name, rates = args[0], [float(r) for r in args[1:]]
     with open(os.path.join(ROOT, "benchmarks", "traffic", name + ".json")) as f:
         mix = json.load(f)
     for rate in rates or [mix["rate_rps"]]:
