@@ -9,6 +9,7 @@ simulation on one host = N raylets with faked resources against one GCS
 
 from __future__ import annotations
 
+import errno
 import json
 import os
 import socket
@@ -103,6 +104,39 @@ def worker_env(tpu: bool) -> Dict[str, str]:
     return env
 
 
+def wait_chips_free(root: str = "/dev/vfio", timeout_s: float = 45.0) -> bool:
+    """Wait until every chip's device file under ``root``
+    (``/dev/vfio/<n>``: one group a chip, one opener at a time) can be
+    opened, and close it again. A process that held four chips is still
+    giving them back ~10 s after it has stopped showing in ``/proc`` by
+    name (its memory goes first, then its files, each chip reset in
+    turn), and whoever opens the runtime meanwhile dies of
+    ``open(/dev/vfio/1): Device or resource busy``: the second of two
+    four-chip runs on one host did (PERF.md, PR 46). So a TPU-flavour
+    worker waits here before it touches JAX (``worker_main``), and a
+    cluster that had chips waits here before ``shutdown()`` returns. Any
+    other error, or no such files (no chip, or another driver), is not
+    this function's to judge. Returns whether the chips were free in
+    time; the caller goes on either way and JAX says what is wrong."""
+    try:
+        chips = [os.path.join(root, n) for n in os.listdir(root) if n.isdigit()]
+    except OSError:
+        return True
+    deadline = time.monotonic() + timeout_s
+    for path in chips:
+        while True:
+            try:
+                os.close(os.open(path, os.O_RDWR))
+                break
+            except OSError as e:
+                if e.errno != errno.EBUSY:
+                    break
+                if time.monotonic() >= deadline:
+                    return False
+                time.sleep(0.25)
+    return True
+
+
 def _spawn(cmd, log_path) -> subprocess.Popen:
     out = open(log_path, "wb")
     proc = subprocess.Popen(
@@ -192,6 +226,7 @@ class Cluster:
         self._standby_n = 0
         self.nodes: Dict[bytes, NodeProcs] = {}
         self.head_node: Optional[NodeProcs] = None
+        self._had_chips = False  # a node of this cluster was given TPUs
 
     @property
     def gcs_primary_addr(self):
@@ -329,6 +364,7 @@ class Cluster:
         store_path = os.path.join(_SHM_DIR, f"raytpu_{os.getpid()}_{hexid}")
         resources = dict(resources or {})
         resources.setdefault("CPU", float(os.cpu_count() or 4))
+        self._had_chips = self._had_chips or bool(resources.get("TPU"))
         cfg = dict(GLOBAL_CONFIG.dump())
         if object_store_memory:
             cfg["object_store_memory_bytes"] = int(object_store_memory)
@@ -359,6 +395,11 @@ class Cluster:
         for node in list(self.nodes.values()):
             node.kill()
         self.nodes.clear()
+        if self._had_chips:
+            # the workers die with their raylet; the chips are free a
+            # while after that, and the next process may want them at once
+            wait_chips_free(timeout_s=30.0)
+            self._had_chips = False
         if self.gcs_proc is not None and self.gcs_proc.poll() is None:
             self.gcs_proc.kill()
             self.gcs_proc.wait()

@@ -9,7 +9,9 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 import sys
+import time
 
 
 def main():
@@ -33,6 +35,14 @@ def main():
         format="[worker %(asctime)s] %(levelname)s %(message)s",
         stream=sys.stderr,
     )
+    if os.environ.get("JAX_PLATFORMS") == "tpu":  # node.worker_env's pin
+        from ray_tpu._private.node import wait_chips_free
+
+        t0 = time.monotonic()
+        free = wait_chips_free()
+        if not free or time.monotonic() - t0 > 1.0:
+            logging.warning("waited %.1f s for the chips' device files "
+                            "(free: %s)", time.monotonic() - t0, free)
 
     from ray_tpu._private.core_worker import MODE_WORKER, CoreWorker
     from ray_tpu._private import worker as worker_mod

@@ -986,16 +986,18 @@ def _recurrence(recur):
     return no_attention
 
 
-def _decode_recur(cache, li, c: TransformerConfig):
+def _decode_recur(cache, li, pos, c: TransformerConfig):
     """One decode layer's ``attn_fn`` for a state-space layer (the
     counterpart of ``_decode_attn``; ``transformer._ssm_mixer`` calls its
-    ``recur``): every lane's convolution window moves on one token and its
-    state one step, parked lanes too (their slots are overwritten whole by
-    the next prefill). The states are stepped by ``ops/ssm.ssm_update``,
-    a kernel that takes the WHOLE [layers, B, ...] leaf, aliased, and
-    reads and writes layer ``li``'s tiles where they lie: a state moves
-    once each way and nothing slices a layer out. The window's layer is
-    taken out of its leaf and put back in place. Returns (y, the cache)."""
+    ``recur``): every lane's convolution window moves on one token (a
+    parked lane's too: 26 KB a slot and layer, overwritten whole by the
+    next prefill) and every LIVE lane's state one step; a PARKED lane's
+    state (``pos`` 0) belongs to nobody and is neither read nor written.
+    The states are stepped by ``ops/ssm.ssm_update``, a kernel that takes
+    the WHOLE [layers, B, ...] leaf, aliased, and reads and writes the
+    live lanes' tiles of layer ``li`` where they lie: a state moves once
+    each way and nothing slices a layer out. The window's layer is taken
+    out of its leaf and put back in place. Returns (y, the cache)."""
     def recur(xbc, dt, wp):
         state = cache_state(cache)
         k1 = c.ssm_conv - 1
@@ -1009,7 +1011,7 @@ def _decode_recur(cache, li, c: TransformerConfig):
         with jax.named_scope("raytpu.ssm.update"):
             x, B, C, A = _ssm_scan_inputs(out, wp, c)
             y, ssm = ssm_update(state["ssm"], li, x[:, 0], dt[:, 0], A,
-                                B[:, 0], C[:, 0], wp["d"])
+                                B[:, 0], C[:, 0], wp["d"], pos > 0)
         return y[:, None], {**cache, "state": {"ssm": ssm, "conv": conv}}
 
     return _recurrence(recur)
@@ -1050,9 +1052,10 @@ def _decode_kda(cache, li, pos, c: TransformerConfig):
     of ``_decode_recur``; ``transformer._kda_mixer`` calls its ``recur``):
     every live lane's convolution window moves on one token and its state
     one step of the delta rule; a PARKED lane (``pos`` 0) keeps its state
-    and its window as they were. The states are stepped by
-    ``ops/kda.kda_update`` on the WHOLE [layers, B, ...] leaf, aliased, at
-    layer ``li``. Returns (o, the cache)."""
+    and its window as they were, and its state is neither read nor
+    written. The states are stepped by ``ops/kda.kda_update`` on the WHOLE
+    [layers, B, ...] leaf, aliased, at layer ``li``. Returns (o, the
+    cache)."""
     def recur(qkv, g, beta, wp):
         state = cache_state(cache)
         k1, live = c.kda_conv - 1, pos > 0
@@ -1187,7 +1190,7 @@ def _decode_forward_multi(params, token, cache, pos,
         def layer(carry, lp, li, lc=lc):
             x, cache, total, choice = carry
             if "ssm" in lp:
-                attn = _decode_recur(cache, li, lc)
+                attn = _decode_recur(cache, li, pos, lc)
             elif "kda" in lp:
                 attn = _decode_kda(cache, li, pos, lc)
             elif "swa" in lp:

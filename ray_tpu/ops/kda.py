@@ -50,6 +50,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops.ssm import live_tiles_first, tile_at
+
 F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -202,9 +204,12 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
     whole leaf with that layer's states stepped)``. One Pallas kernel: the
     leaf is aliased to the output and a tile is indexed (layer, slots,
     heads) where it lies, so nothing slices a layer out and no other
-    layer's bytes are touched. A lane outside ``live`` [B] bool (a parked
-    lane) is read and written back as it was: its decay is taken as 1 and
-    its ``beta`` as 0.
+    layer's bytes are touched. A lane outside ``live`` [B] bool (None:
+    every lane is live) is PARKED: its state is bit for bit what it was
+    and its ``o`` is zeros. A tile none of whose slots is live is neither
+    read nor written and its body does not run (``ops/ssm.
+    live_tiles_first``); a parked slot inside a live tile goes through
+    with its decay taken as 1 and its ``beta`` as 0.
 
     A tile is ``tile_bytes`` of whole heads of one slot, over slots where
     a slot's heads are fewer. What a (slot, head) needs beside its state
@@ -236,8 +241,10 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
     per = max(tile_bytes // (dk * dv * 4), 1)  # heads a tile
     th = _divisor_at_most(h, per)
     tb = _divisor_at_most(n_slots, per // th)
+    order, tiles = live_tiles_first(live, n_slots, tb)
 
-    def kernel(_layer, k_ref, v_ref, s_ref, o_ref, new_ref):
+    def kernel(_layer, *refs):
+        k_ref, v_ref, s_ref, o_ref, new_ref = refs[len(order):]
         for b in range(tb):
             for e in range(th):
                 old = s_ref[b, e]  # [Dk, Dv]
@@ -257,32 +264,36 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
     if interpret:
         stack, at = lax.dynamic_index_in_dim(states, layer, 0), 0
 
-    def tile(i, j, layer):
-        return layer[0], i, j, 0, 0
+    def tile(i, j, layer, *order):
+        return layer[0], tile_at(i, *order), j, 0, 0
 
-    def rows(i, j, layer):
-        return i, j, 0, 0
+    def rows(i, j, layer, *order):
+        return tile_at(i, *order), j, 0, 0
+
+    def heads(i, j, layer, *order):
+        return tile_at(i, *order), j, 0
 
     o, new = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((n_slots, h, dv), F32),
                    jax.ShapeDtypeStruct(stack.shape, F32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_slots // tb, h // th),
+            num_scalar_prefetch=1 + len(order),
+            grid=(tiles, h // th),
             in_specs=[pl.BlockSpec((tb, th, 4, dk), rows),
                       pl.BlockSpec((tb, th, 4, dv), rows),
                       pl.BlockSpec((None, tb, th, dk, dv), tile)],
-            out_specs=[pl.BlockSpec((tb, th, dv), lambda i, j, layer:
-                                    (i, j, 0)),
+            out_specs=[pl.BlockSpec((tb, th, dv), heads),
                        pl.BlockSpec((None, tb, th, dk, dv), tile)],
         ),
-        input_output_aliases={3: 1},
+        input_output_aliases={3 + len(order): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 2),
         interpret=interpret,
         name="kda_update",
-    )(jnp.asarray(at, jnp.int32).reshape(1), over_k, over_v, stack)
+    )(jnp.asarray(at, jnp.int32).reshape(1), *order, over_k, over_v, stack)
     if interpret:
         new = lax.dynamic_update_index_in_dim(states, new[0], layer, 0)
+    if live is not None:  # an unvisited tile's o is whatever the buffer held
+        o = jnp.where(live[:, None, None], o, 0.0)
     return o.astype(v.dtype), new

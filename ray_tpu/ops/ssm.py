@@ -17,7 +17,8 @@ needs:
   states is read once, written back to its own place, and ``y`` is formed
   from the tile while it is in fast memory. XLA's own code for
   ``ssm_step`` read every new state a second time for ``y`` (PERF.md,
-  PR 36).
+  PR 36). Told which lanes are live it visits their tiles alone
+  (``live_tiles_first``: shared with ``ops/kda.kda_update``).
 
 Shapes: ``x`` [B, S, H, P] (``ssm_step``: no S), ``dt`` [B, S, H] (after
 its softplus), ``A`` [H] (negative), ``B`` and ``C`` [B, S, G, N] with G
@@ -138,7 +139,36 @@ def ssm_step(state, x, dt, A, B, C, D) -> Tuple[jax.Array, jax.Array]:
     return y.reshape(b, h, p).astype(x.dtype), new.reshape(b, h, p, n)
 
 
-def ssm_update(states, layer, x, dt, A, B, C, D, *,
+def live_tiles_first(live, n_slots: int, tb: int):
+    """The schedule of a state kernel whose slot axis visits LIVE lanes
+    only: ``live`` [B] bool or None, ``tb`` slots a tile. Returns
+    ``(prefetch, count)``: ``prefetch`` holds ``order`` [tiles] int32, the
+    tiles that hold a live slot first, in their own order, and ``count``
+    is how many they are. The kernel's grid has ``count`` steps along its
+    slot axis (a grid bound read on the device) and step ``i`` names tile
+    ``order[i]`` (scalar-prefetched: ``tile_at``), so a tile none of
+    whose slots is live is neither read nor written and its body does not
+    run; with no lane live the kernel makes no step at all. Without
+    ``live`` nothing is prefetched and ``count`` is every tile."""
+    if live is None:
+        return (), pl.cdiv(n_slots, tb)
+    tiles = jnp.pad(live, (0, -n_slots % tb)).reshape(-1, tb).any(1)
+    before = jnp.cumsum(tiles)  # live tiles up to and including each
+    # the i-th live tile is the first with i + 1 live up to it: as many
+    # tiles lie before it as have i or fewer (no sort on the chip)
+    order = (before[None, :] <= jnp.arange(tiles.shape[0])[:, None]).sum(1)
+    order = jnp.minimum(order, tiles.shape[0] - 1).astype(jnp.int32)
+    return (order,), before[-1].astype(jnp.int32)
+
+
+def tile_at(i, order=None):
+    """The tile that step ``i`` of a state kernel's slot axis names:
+    ``order[i]`` under ``live_tiles_first``'s schedule (an index map's or
+    a kernel's prefetched ref), ``i`` itself without one."""
+    return i if order is None else order[i]
+
+
+def ssm_update(states, layer, x, dt, A, B, C, D, live=None, *,
                tile_bytes: int = _TILE_BYTES) -> Tuple[jax.Array, jax.Array]:
     """``ssm_step`` on layer ``layer`` of the stacked states [layers, B,
     H, P, N] float32, in place: returns ``(y [B,H,P] in x's type, the
@@ -148,6 +178,13 @@ def ssm_update(states, layer, x, dt, A, B, C, D, *,
     group, rows) where it lies, so nothing slices a layer out and every
     other layer's bytes are not touched. The same float32 products and
     sum as ``ssm_step``.
+
+    ``live`` [B] bool names the lanes that count (None: all of them). A
+    lane outside it is PARKED: its state is bit for bit what it was and
+    its ``y`` is zeros. A tile none of whose slots is live is neither
+    read nor written and its body does not run (``live_tiles_first``); a
+    parked slot inside a live tile goes through with decay 1 and nothing
+    added.
 
     A group's heads and their P rows lie flat as row blocks of S = P x
     (heads a block) rows, 128 where the shapes allow, so that ``dt x``
@@ -166,15 +203,20 @@ def ssm_update(states, layer, x, dt, A, B, C, D, *,
     per = max(tile_bytes // (s * n * 4), 1)  # blocks a tile
     tq = qg if qg <= per else max(per // 8 * 8, 8)
     tb = min(max(per // tq, 1), n_slots)
+    order, tiles = live_tiles_first(live, n_slots, tb)
 
     dt = dt.astype(F32)
     keep = jnp.exp(dt * A.astype(F32))  # [B,H]
     dtx = (dt[..., None] * x.astype(F32)).reshape(n_slots, g, qg, s)
     per_group = [a.astype(F32).reshape(n_slots, g, 1, n) for a in (B, C)]
+    if live is not None:  # a parked slot inside a live tile
+        keep = jnp.where(live[:, None], keep, 1.0)
+        dtx = jnp.where(live[:, None, None, None], dtx, 0.0)
 
-    def kernel(_layer, keep, dtx_ref, b_ref, c_ref, h_ref, y_ref, o_ref):
-        slot0, grp, blk0 = (pl.program_id(0) * tb, pl.program_id(1),
-                            pl.program_id(2) * tq)
+    def kernel(_layer, keep, *refs):
+        *order, dtx_ref, b_ref, c_ref, h_ref, y_ref, o_ref = refs
+        slot0, grp, blk0 = (tile_at(pl.program_id(0), *order) * tb,
+                            pl.program_id(1), pl.program_id(2) * tq)
         for b in range(tb):
             # a tile past the last slot or block: its results are dropped
             slot = jnp.minimum(slot0 + b, n_slots - 1)
@@ -193,14 +235,14 @@ def ssm_update(states, layer, x, dt, A, B, C, D, *,
         stack, at = lax.dynamic_index_in_dim(states, layer, 0), 0
     n_layers = stack.shape[0]
 
-    def tile(i, grp, j, layer, keep):
-        return layer[0], i, grp, j, 0, 0
+    def tile(i, grp, j, layer, keep, *order):
+        return layer[0], tile_at(i, *order), grp, j, 0, 0
 
-    def rows(i, grp, j, layer, keep):
-        return i, grp, j, 0
+    def rows(i, grp, j, layer, keep, *order):
+        return tile_at(i, *order), grp, j, 0
 
-    def group(i, grp, j, layer, keep):
-        return i, grp, 0, 0
+    def group(i, grp, j, layer, keep, *order):
+        return tile_at(i, *order), grp, 0, 0
 
     state_spec = pl.BlockSpec((None, tb, None, tq, s, n), tile)
     rows_spec = pl.BlockSpec((tb, None, tq, s), rows)
@@ -211,22 +253,24 @@ def ssm_update(states, layer, x, dt, A, B, C, D, *,
                    jax.ShapeDtypeStruct((n_layers, n_slots, g, qg, s, n),
                                         F32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(pl.cdiv(n_slots, tb), g, pl.cdiv(qg, tq)),
+            num_scalar_prefetch=2 + len(order),
+            grid=(tiles, g, pl.cdiv(qg, tq)),
             in_specs=[rows_spec, group_spec, group_spec, state_spec],
             out_specs=[rows_spec, state_spec],
         ),
-        input_output_aliases={5: 1},
+        input_output_aliases={5 + len(order): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         interpret=interpret,
         name="ssm_update",
-    )(jnp.asarray(at, jnp.int32).reshape(1), keep.reshape(-1), dtx,
+    )(jnp.asarray(at, jnp.int32).reshape(1), keep.reshape(-1), *order, dtx,
       *per_group, stack.reshape(n_layers, n_slots, g, qg, s, n))
     new = new.reshape(stack.shape)
     if interpret:
         new = lax.dynamic_update_index_in_dim(states, new[0], layer, 0)
     y = y.reshape(n_slots, h, p) + x.astype(F32) * D.astype(F32)[:, None]
+    if live is not None:  # an unvisited tile's y is whatever the buffer held
+        y = jnp.where(live[:, None, None], y, 0.0)
     return y.astype(x.dtype), new
 
 

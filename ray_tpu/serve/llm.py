@@ -57,7 +57,8 @@ _COUNTERS = (
     "requests_finished", "requests_cancelled", "requests_failed",
     "prefill_tokens", "prefill_padded_tokens", "tokens_emitted",
     "slot_steps", "capacity_steps", "attn_rows_read", "attn_rows_capacity",
-    "state_slots_updated", "weights_relaid", "weights_relaid_bytes",
+    "state_slots_updated", "state_slots_skipped", "weights_relaid",
+    "weights_relaid_bytes",
     "slot_state_bytes", "slot_row_bytes",
     "blocks_chained", "block_interval_steps", "block_interval_clean_steps",
     "decode_gap_tokens", "firsts_ahead",
@@ -414,10 +415,12 @@ class LLMEngine:
           ``max_slots`` x steps), what reading the whole cache would have
           read (rows of whatever the mixer caches: K and V rows, or
           latent rows); ``state_slots_updated``, the slot states a block
-          read and wrote: EVERY slot's, parked or live, once a step and
-          layer that keeps a state (``max_slots`` x state layers x steps;
-          0 for a model whose slots keep rows only), so ``slot_steps`` x
-          state layers over it is the share that belonged to a live lane.
+          read and wrote: those of the lanes it found at ``pos`` > 0, once
+          a step and layer that keeps a state (0 for a model whose slots
+          keep rows only), and ``state_slots_skipped``, the parked lanes'
+          states, which its kernels neither read nor wrote. The two add
+          up to ``max_slots`` x state layers x steps, and skipped over
+          that sum is how often the skip engages.
         - Set once, at set-up, from the cache's shapes: ``slot_state_bytes``
           what a slot keeps whatever its length (recurrent states; 0 for
           most models) and ``slot_row_bytes`` what one cached token costs
@@ -669,9 +672,12 @@ class LLMEngine:
             attn_rows_walked(r + k, self.max_len, chunk)
             for r in lanes for k in range(steps))
         self._n["attn_rows_capacity"] += self.max_slots * self.max_len * steps
-        # decode_block steps every lane's state, a parked lane's too
-        self._n["state_slots_updated"] += (
-            self.max_slots * self._state_layers * steps)
+        # decode_block moves the states of the lanes it finds at pos > 0;
+        # a parked lane's stays where it lies
+        moved = sum(1 for r in self._rows if r)
+        self._n["state_slots_updated"] += moved * self._state_layers * steps
+        self._n["state_slots_skipped"] += (
+            (self.max_slots - moved) * self._state_layers * steps)
         # as decode_block leaves pos: a step on, parked lanes stay at 0
         self._rows = [r + steps if r else 0 for r in self._rows]
         snapshot = list(self.slot_req)  # slot -> req at dispatch

@@ -133,6 +133,48 @@ def test_detect_pinned_to_cpu_never_probes(monkeypatch):
 # one TPU-flavour worker at a time on a one-chip node
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("busy_opens,timeout_s,free", [
+    (None, 5.0, True),  # no such directory: no chip, or another driver
+    (0, 5.0, True),  # every chip's file opens at once
+    (3, 5.0, True),  # a chip still being given back, then free
+    (10 ** 6, 0.6, False),  # held for good: the caller goes on, JAX reports
+    (-1, 5.0, True),  # another error than EBUSY is not this function's
+], ids=["no_files", "free", "busy_then_free", "held", "other_error"])
+def test_a_tpu_worker_waits_for_the_chips_device_files(
+        tmp_path, monkeypatch, busy_opens, timeout_s, free):
+    """ISSUE 46's second four-chip run died of ``open(/dev/vfio/1): Device
+    or resource busy`` 15 s after the first had ended: a TPU-flavour
+    worker now waits, before it touches JAX, until each chip's file can be
+    opened, and ``Cluster.shutdown()`` of a cluster that had chips returns
+    only when they are (``node.wait_chips_free``)."""
+    import errno
+
+    root = tmp_path / "vfio"
+    if busy_opens is not None:
+        root.mkdir()
+        for name in ("0", "1", "vfio"):  # the last is no chip's file
+            (root / name).write_bytes(b"")
+    left, real_open, opened = [busy_opens or 0], os.open, []
+
+    def chip_open(path, flags, *a):
+        if str(path) != str(root / "1"):
+            opened.append(os.path.basename(str(path)))
+            return real_open(path, flags, *a)
+        if left[0] < 0:
+            raise OSError(errno.EPERM, "not permitted")
+        if left[0] > 0:
+            left[0] -= 1
+            raise OSError(errno.EBUSY, "Device or resource busy")
+        opened.append("1")
+        return real_open(path, flags, *a)
+
+    monkeypatch.setattr(node.os, "open", chip_open)
+    assert node.wait_chips_free(str(root), timeout_s) is free
+    assert "vfio" not in opened
+    if busy_opens == 3:
+        assert left[0] == 0 and "1" in opened
+
+
 def test_one_chip_node_runs_one_tpu_worker_at_a_time():
     """Half-chip tasks share ONE worker process instead of a second one
     being spawned beside it, and a TPU actor's worker starts only after

@@ -15,6 +15,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -266,6 +267,43 @@ def test_the_update_kernel_equals_the_step_on_one_layer_in_place(
     assert np.array_equal(new[jnp.array(others)], states[jnp.array(others)])
 
 
+LIVE = {"all": [1] * 6, "none": [0] * 6,
+        "leading_parked": [0, 0, 0, 1, 1, 1],
+        "trailing_parked": [1, 1, 1, 0, 0, 0],
+        "alternating": [1, 0, 1, 0, 1, 0], "one_live": [0, 0, 0, 0, 1, 0]}
+
+
+@pytest.mark.parametrize("tile", [16 * 16 * 4, 2 * 16 * 16 * 4,
+                                  4 * 16 * 16 * 4],
+                         ids=["a_head_a_tile", "a_slot_a_tile",
+                              "two_slots_a_tile"])
+@pytest.mark.parametrize("lanes", list(LIVE))
+def test_the_update_kernel_visits_the_live_lanes_only(lanes, tile):
+    """``kda_update`` told which lanes are live (the Pallas interpreter
+    here), 6 slots of 2 heads: a live lane's ``o`` and new state equal
+    ``kda_step``'s, a parked lane's state is bit for bit the input's and
+    its ``o`` zeros (whether its tile is skipped whole or shared with a
+    live lane), every other layer is bit for bit the input's, and no
+    ``live`` at all is every lane live."""
+    layers, layer, live = 3, 2, np.array(LIVE[lanes], bool)
+    states = jax.random.normal(jax.random.key(3), (layers, 6, 2, 16, 16))
+    q, k, v, g, beta = (a[:, 0] for a in _delta_inputs(4, 6, 1, 2, 16, 16))
+    update = jax.jit(kda_update, static_argnames=("tile_bytes",))
+    o, new = update(states, layer, q, k, v, g, beta, jnp.asarray(live),
+                    tile_bytes=tile)
+    want_o, want = kda_step(states[layer], q, k, v, g, beta)
+    np.testing.assert_allclose(new[layer][live], want[live], atol=1e-5)
+    np.testing.assert_allclose(o[live], want_o[live], atol=1e-5)
+    np.testing.assert_array_equal(new[layer][~live], states[layer][~live])
+    assert not np.asarray(o)[~live].any()
+    np.testing.assert_array_equal(new[:layer], states[:layer])
+    if live.all():
+        plain_o, plain = update(states, layer, q, k, v, g, beta,
+                                tile_bytes=tile)
+        np.testing.assert_array_equal(o, plain_o)
+        np.testing.assert_array_equal(new, plain)
+
+
 def test_a_padded_buckets_end_state_is_the_state_at_prompt_len():
     q, k, v, g, beta = _delta_inputs(5, 1, 32, 2, 16, 16)
     valid = (jnp.arange(32) < 21)[None]
@@ -397,8 +435,11 @@ def test_engine_serves_mixed_lanes_end_to_end(params):
         s = eng.stats()
         assert s["slot_state_bytes"] == 4 * (2 * 16 * 16 * 4 + 3 * 3 * 32 * 4)
         assert s["slot_row_bytes"] == 2 * (16 + 8) * 4
-        assert s["state_slots_updated"] == 4 * 3 * (
-            s["capacity_steps"] // 3)
+        # every slot's state a step and kda layer is moved or skipped
+        assert (s["state_slots_updated"] + s["state_slots_skipped"]
+                == 4 * s["capacity_steps"])
+        assert s["slot_steps"] * 4 <= s["state_slots_updated"]
+        assert s["state_slots_skipped"] > 0  # a decoded alone at first
         assert s["attn_rows_read"] > 0 and s["moe_assignments"] > 0
         assert s["prefill_moe_assignments"] > 0
         assert s["requests_failed"] == 0
@@ -415,6 +456,38 @@ def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(params):
     finally:
         eng.shutdown()
     assert worst_margin(params, q, again) < TOL
+
+
+def test_a_slot_skipped_for_several_blocks_serves_as_a_fresh_one(params):
+    """Slot 1 holds what a request left, then stays parked (its states
+    skipped) while slot 0 decodes for several blocks; the request then
+    admitted into it gets the tokens a fresh engine gives."""
+    from ray_tpu.serve.llm import _END
+
+    eng = engine_of(params)
+    try:
+        p, q, r = (np.asarray(tokens_of(n, s))
+                   for n, s in ((17, 7), (6, 8), (13, 9)))
+        long = eng.submit(p, max_new_tokens=40)  # slot 0
+        eng.generate(q, max_new_tokens=3)  # slot 1, then freed and parked
+        before, until = eng.stats(), time.time() + 120
+        while eng.stats()["steps"] < before["steps"] + 12:
+            assert time.time() < until
+            time.sleep(0.01)  # slot 0 alone: the others' states are skipped
+        after = eng.stats()
+        again = eng.generate(r, max_new_tokens=8)  # into the skipped slot
+        while long.out.get(timeout=120) is not _END:
+            pass
+    finally:
+        eng.shutdown()
+    assert (after["state_slots_skipped"] - before["state_slots_skipped"]
+            >= 4 * 8 * 2)
+    fresh = engine_of(params)
+    try:
+        assert again == fresh.generate(r, max_new_tokens=8)
+    finally:
+        fresh.shutdown()
+    assert worst_margin(params, r, again) < TOL
 
 
 def test_generate_runs_the_served_programs(params):
@@ -654,14 +727,19 @@ def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
                   if line.startswith("readers walked"))
     values = json.loads(walked.split(": ", 1)[1])
     assert sorted(values) == sorted(mine)
+    # the rehearsal's engine: 4 kda layers (the metric's scale is the
+    # cell's 6), and the states moved are the live lanes': 100 x 6 / 4
     share = values["engine.state_live_share.kda"]
-    assert share is not None and 0 < share < 100
+    assert share is not None and abs(share - 150) < 1e-6
+    assert 0 < values["engine.state_skip_share"] < 100
     note = json.loads(next(line for line in out.stdout.splitlines()
                            if line.startswith('{"note"')))
     end = note["note"]["backlog"]["end"]
     assert end["slot_state_bytes"] == 4 * (2 * 16 * 16 * 4 + 3 * 3 * 32 * 2)
     assert end["slot_row_bytes"] == 2 * (16 + 8) * 2
-    assert end["state_slots_updated"] == 4 * end["capacity_steps"]
+    assert (end["state_slots_updated"] + end["state_slots_skipped"]
+            == 4 * end["capacity_steps"])
+    assert end["slot_steps"] * 4 <= end["state_slots_updated"]
     assert end["attn_rows_read"] > 0 and end["moe_assignments"] > 0
     probe = note["note"]["probe"]
     assert probe["replayed"] and probe["refused_by"] == []

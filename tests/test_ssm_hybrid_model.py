@@ -9,6 +9,7 @@ under the new defaults."""
 
 import dataclasses
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +149,48 @@ def test_the_update_kernel_equals_the_step_on_one_layer_in_place(
     assert float(jnp.abs(new[layer, 1] - states[layer, 1]).max()) > 1e-2
 
 
+LIVE = {"all": [1, 1, 1, 1, 1], "none": [0, 0, 0, 0, 0],
+        "leading_parked": [0, 0, 1, 1, 1], "trailing_parked": [1, 1, 1, 0, 0],
+        "alternating": [1, 0, 1, 0, 1], "one_live": [0, 0, 0, 1, 0]}
+
+
+# heads, groups, bytes a tile: (slots a tile, row blocks a tile) of 5 slots
+@pytest.mark.parametrize("h,g,tile", [
+    (8, 2, 32 * 16 * 4),  # a slot's group a tile: tb = 1, two groups
+    (8, 2, 2 * 32 * 16 * 4),  # two slots a tile: tb = 2, the last half full
+    (320, 1, 8 * 128 * 16 * 4),  # 8 of a slot's 20 row blocks a tile
+], ids=["a_slot_a_tile", "two_slots_a_tile", "row_blocks_a_tile"])
+@pytest.mark.parametrize("lanes", list(LIVE))
+def test_the_update_kernel_visits_the_live_lanes_only(lanes, h, g, tile):
+    """``ssm_update`` told which lanes are live (the Pallas interpreter
+    here): a live lane's ``y`` and new state equal ``ssm_step``'s, a
+    parked lane's state is bit for bit the input's and its ``y`` zeros
+    (whether its tile is skipped whole or shared with a live lane), every
+    other layer is bit for bit the input's, and no ``live`` at all is
+    every lane live."""
+    layers, layer, live = 3, 1, np.array(LIVE[lanes], bool)
+    a = scan_inputs(5, 5, 1, h=h, p=8, g=g, n=16)
+    a = {k: (v[:, 0] if v.ndim > 1 else v) for k, v in a.items()}
+    states = jax.random.normal(jax.random.key(12), (layers, 5, h, 8, 16))
+    want_y, want = ssm_step(states[layer], **a)
+    update = jax.jit(ssm_update, static_argnames="tile_bytes")
+    y, new = update(states, jnp.int32(layer), **a, live=jnp.asarray(live),
+                    tile_bytes=tile)
+    assert new.shape == states.shape and new.dtype == jnp.float32
+    np.testing.assert_allclose(new[layer][live], want[live],
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(y[live], want_y[live], atol=1e-4, rtol=1e-5)
+    np.testing.assert_array_equal(new[layer][~live], states[layer][~live])
+    assert not np.asarray(y)[~live].any()
+    others = np.arange(layers) != layer
+    np.testing.assert_array_equal(new[others], states[others])
+    if live.all():
+        plain_y, plain = update(states, jnp.int32(layer), **a,
+                                tile_bytes=tile)
+        np.testing.assert_array_equal(y, plain_y)
+        np.testing.assert_array_equal(new, plain)
+
+
 def test_a_padded_buckets_end_state_is_the_state_at_prompt_len():
     a = scan_inputs(4, 1, 32)
     n = 21
@@ -267,20 +310,23 @@ def test_prefill_leaves_the_other_slots_bit_identical(params):
     assert float(jnp.abs(gen.cache_state(cache)["ssm"][:, 1]).max()) > 0
 
 
-def test_decode_block_steps_a_parked_lanes_state_too(params):
-    """What ``state_slots_updated`` counts: every slot's state is read
-    and written a step, the parked lane's as well (its slot is
-    overwritten whole by the next prefill)."""
+def test_decode_block_leaves_a_parked_lanes_state_where_it_lies(params):
+    """What ``state_slots_skipped`` counts: a parked lane's state is
+    neither read nor written (ISSUE 46; it used to be stepped with the
+    others), whatever it held: here what a request left behind."""
     cache = gen.init_kv_cache(CFG, 2, 64)
     _, cache = prefill(params, cache, 0, tokens_of(9, 2), 16)
+    _, cache = prefill(params, cache, 1, tokens_of(7, 3), 16)
     parked = np.asarray(gen.cache_state(cache)["ssm"][:, 1])
-    assert not parked.any()
+    assert parked.any()
     zeros = jnp.zeros(2, jnp.int32)
     _t, cache, _tok, pos, _c, stats = gen.decode_block(
         params, cache, jnp.array([3, 5], jnp.int32),
         jnp.array([9, 0], jnp.int32), jnp.zeros(2), zeros, zeros, CFG, 2)
     assert pos.tolist() == [11, 0] and stats == {}
-    assert np.asarray(gen.cache_state(cache)["ssm"][:, 1]).any()
+    state = np.asarray(gen.cache_state(cache)["ssm"])
+    np.testing.assert_array_equal(state[:, 1], parked)
+    assert np.abs(state[:, 0]).max() > 0
 
 
 # -- the engine ---------------------------------------------------------------
@@ -320,9 +366,11 @@ def test_engine_serves_two_requests_admitted_at_different_times(params):
         s = eng.stats()
         assert s["slot_state_bytes"] == 4 * (4 * 8 * 16 * 4 + 3 * 64 * 4)
         assert s["slot_row_bytes"] == 2 * 2 * 128 * 4
-        # every slot's state a step and state layer, live or parked
-        assert s["state_slots_updated"] == 2 * 4 * s["steps"]
+        # every slot's state a step and state layer is moved or skipped
+        assert (s["state_slots_updated"] + s["state_slots_skipped"]
+                == 2 * 4 * s["steps"])
         assert s["slot_steps"] * 4 <= s["state_slots_updated"]
+        assert s["state_slots_skipped"] > 0  # b came late and left early
         assert s["requests_failed"] == 0
     finally:
         eng.shutdown()
@@ -343,6 +391,38 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(params):
     finally:
         fresh.shutdown()
     assert worst_margin(params, q, again) < TOL
+
+
+def test_a_slot_skipped_for_several_blocks_serves_as_a_fresh_one(params):
+    """Slot 1 holds what a request left, then stays parked (its states
+    skipped) while slot 0 decodes for several blocks; the request then
+    admitted into it gets the tokens a fresh engine gives."""
+    from ray_tpu.serve.llm import _END
+
+    eng = engine_of(params)
+    try:
+        p, q, r = (np.asarray(tokens_of(n, s))
+                   for n, s in ((17, 7), (11, 8), (13, 9)))
+        long = eng.submit(p, max_new_tokens=40)  # slot 0
+        eng.generate(q, max_new_tokens=3)  # slot 1, then freed and parked
+        before, until = eng.stats(), time.time() + 120
+        while eng.stats()["steps"] < before["steps"] + 12:
+            assert time.time() < until
+            time.sleep(0.01)  # slot 0 alone: slot 1's states are skipped
+        after = eng.stats()
+        again = eng.generate(r, max_new_tokens=8)  # into the skipped slot
+        while long.out.get(timeout=120) is not _END:
+            pass
+    finally:
+        eng.shutdown()
+    assert (after["state_slots_skipped"] - before["state_slots_skipped"]
+            >= 4 * 8)
+    fresh = engine_of(params)
+    try:
+        assert again == fresh.generate(r, max_new_tokens=8)
+    finally:
+        fresh.shutdown()
+    assert worst_margin(params, r, again) < TOL
 
 
 # -- what a comparison must refuse -----------------------------------------
@@ -438,11 +518,15 @@ def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
     values = json.loads(walked.split(": ", 1)[1])
     assert sorted(values) == sorted(mine)
     # the rehearsal's engine: 4 slots, 4 state layers (the metric's scale
-    # is the cell's 36), so a whole share reads 100 x 36 / 4
+    # is the cell's 36), and the states moved are the live lanes' alone
+    # (ISSUE 46): a whole share, 100 x 36 / 4
     share = values["engine.state_live_share"]
-    assert share is not None and 0 < share <= 900
+    assert share is not None and abs(share - 900) < 1e-6
     note = json.loads(next(line for line in out.stdout.splitlines()
                            if line.startswith('{"note"')))
     end = note["note"]["backlog"]["end"]
     assert end["slot_state_bytes"] > 0 and end["slot_row_bytes"] > 0
-    assert end["state_slots_updated"] == 4 * 4 * end["steps"]
+    assert (end["state_slots_updated"] + end["state_slots_skipped"]
+            == 4 * 4 * end["steps"])
+    assert end["slot_steps"] * 4 <= end["state_slots_updated"]
+    assert 0 < values["engine.state_skip_share"] < 100

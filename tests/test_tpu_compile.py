@@ -196,6 +196,10 @@ def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert len(kernels) == 2
         assert all("raytpu.ssm.update" in line for line in kernels)
         assert all("f32[36,48,1,32,128,128]" in line for line in kernels)
+        # ISSUE 46: each is handed the live lanes' tiles (the order and,
+        # as a grid bound, their count) and still writes into the leaf
+        assert all("s32[48]" in line for line in kernels)
+        assert all("output_to_operand_aliasing" in line for line in kernels)
         readers = re.findall(
             r"(%\S+) = f32\[(?:36,|1,)?48,(?:64,64|1,32,128),128\]\S* "
             r"parameter\(", hlo)
@@ -347,12 +351,58 @@ def test_kda_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert len(updates) == 3
         assert all("raytpu.kda.update" in line for line in updates)
         assert all("f32[6,96,32,128,128]" in line for line in updates)
+        # ISSUE 46: the live lanes' tiles alone, still in place
+        assert all("s32[96]" in line for line in updates)
+        assert all("output_to_operand_aliasing" in line for line in updates)
         assert sum("decode_attention" in line for line in calls) == 2
         assert sum("raytpu.moe.experts" in line for line in calls) == 8
     else:
         assert "raytpu.kda.chunk" in hlo
         assert not any("kda_update" in line for line in calls)
         assert "[8192,8192]" not in hlo  # no prompt's scores whole
+
+
+@pytest.mark.parametrize("kernel", ["ssm_update", "kda_update"])
+def test_a_state_kernel_told_the_live_lanes_compiles_in_place(
+        v5e, kernel, monkeypatch):
+    """ISSUE 46: ``ops/ssm.ssm_update`` at granite's shapes (48 slots of
+    64 heads x 64 x 128, 36 layers) and ``ops/kda.kda_update`` at Kimi's
+    (96 slots of 32 heads x 128 x 128, 6 layers), handed ``live``: Mosaic
+    takes the grid whose slot axis is as long as the live tiles are many
+    (a bound read on the device) with the tiles' order prefetched; it is
+    one custom call, the donated leaf is its output, and no copy of the
+    leaf appears in the compiled text."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.ops.kda import kda_update
+    from ray_tpu.ops.ssm import ssm_update
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    bf16 = jnp.bfloat16
+    if kernel == "ssm_update":
+        b, leaf = 48, (36, 48, 64, 64, 128)
+        args = (arr(leaf), arr((), jnp.int32), arr((b, 64, 64), bf16),
+                arr((b, 64)), arr((64,)), arr((b, 1, 128), bf16),
+                arr((b, 1, 128), bf16), arr((64,)), arr((b,), jnp.bool_))
+        fn = ssm_update
+    else:
+        b, leaf = 96, (6, 96, 32, 128, 128)
+        args = (arr(leaf), arr((), jnp.int32), arr((b, 32, 128), bf16),
+                arr((b, 32, 128), bf16), arr((b, 32, 128), bf16),
+                arr((b, 32, 128)), arr((b, 32)), arr((b,), jnp.bool_))
+        fn = kda_update
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    size = 4
+    for n in leaf:
+        size *= n
+    assert compiled.memory_analysis().alias_size_in_bytes >= size
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and kernel in calls[0]
+    assert f"s32[{b}]" in calls[0]  # the live tiles' order
+    assert "output_to_operand_aliasing" in calls[0]
+    assert not _copies(hlo, "f32[%d,%d," % leaf[:2])
 
 
 @pytest.fixture(scope="module")
