@@ -30,8 +30,11 @@ v^T``), in the three forms ``ops/ssm.py`` has for its scalar decay:
 - ``kda_update``: the same step as the served path runs it, a Pallas TPU
   kernel over the slots' WHOLE stacked state leaf [layers, B, H, Dk, Dv],
   aliased to its output, the layer a prefetched scalar: each tile of
-  states is read once and written once, and ``k^T S``, the update and
-  ``S^T q`` are formed while the tile is in fast memory.
+  states is read once and written once, and the decay, the read ``sum_k
+  k S``, the update and ``o`` are formed while the tile is in fast
+  memory, in ``kda_step``'s order. It takes ``alpha``, ``k``, ``q`` as
+  rows over Dk and turns them to the columns a state's tile needs once
+  a slot for all the tile's heads.
 
 Shapes: ``q``, ``k``, ``g`` [B, S, H, Dk], ``v`` [B, S, H, Dv], ``beta``
 [B, S, H] (``kda_step`` / ``kda_update``: no S); a state is [B, H, Dk, Dv]
@@ -213,14 +216,18 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
 
     A tile is ``tile_bytes`` of whole heads of one slot, over slots where
     a slot's heads are fewer. What a (slot, head) needs beside its state
-    goes in as rows a whole number of lanes wide: ``alpha k``, ``alpha q``,
-    ``alpha`` and ``k`` over Dk (turned from lanes to sublanes in the
-    kernel, 1/Dv of the data each), ``beta v``, ``beta`` and ``q . k``
-    over Dv. With ``S`` the state as it was: ``S^T (alpha k)`` is the read
-    of the decayed state, ``u = beta v - beta S^T (alpha k)``, the new
-    state ``alpha S + k u^T``, and ``o = S^T (alpha q) + (q . k) u``: the
-    same float32 products and sums as ``kda_step`` in another order. Off
-    the TPU the kernel runs in the Pallas interpreter, handed the one
+    goes in as rows a whole number of lanes wide: ``alpha``, ``k`` and
+    ``q`` over Dk, [B, 3, H, Dk], and ``v`` and ``beta`` over Dv, [B, 2,
+    H, Dv]. A state's rows lie along the sublanes, so what is over Dk has
+    to multiply it as COLUMNS: the kernel turns a slot's rows of a tile,
+    [3 x heads, Dk], to [Dk, 3 x heads] ONCE for all the tile's heads (one
+    transpose of 1/Dv of the data; a turn a head and a vector held the
+    kernel at 57 % of its bytes' time where a plain copy reaches 77 %:
+    PERF.md, PR 47), and a head takes its three columns out of that. Then
+    ``kda_step``'s own float32 products and sums in ``kda_step``'s own
+    order: ``decayed = alpha S``, ``read = sum_k k decayed``, ``u = beta
+    (v - read)``, the new state ``decayed + k u^T``, ``o = sum_k q new``.
+    Off the TPU the kernel runs in the Pallas interpreter, handed the one
     layer it touches (the interpreter copies every operand whole at every
     grid step)."""
     n_slots, h, dk = k.shape
@@ -230,13 +237,9 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
     if live is not None:
         alpha = jnp.where(live[:, None, None], alpha, 1.0)
         beta = jnp.where(live[:, None], beta, 0.0)
-    over_k = jnp.stack([alpha * k32, alpha * q32, alpha, k32], 2)
-    wide = jnp.broadcast_to(
-        jnp.stack([beta, (q32 * k32).sum(-1)], -1)[..., None],
-        (n_slots, h, 2, dv))
-    over_v = jnp.concatenate(
-        [(beta[..., None] * v32)[:, :, None], wide,
-         jnp.zeros((n_slots, h, 1, dv), F32)], 2)  # [B,H,4,Dv]
+    over_k = jnp.stack([alpha, k32, q32], 1)  # [B,3,H,Dk]
+    over_v = jnp.stack(
+        [v32, jnp.broadcast_to(beta[..., None], v32.shape)], 1)  # [B,2,H,Dv]
 
     per = max(tile_bytes // (dk * dv * 4), 1)  # heads a tile
     th = _divisor_at_most(h, per)
@@ -246,18 +249,17 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
     def kernel(_layer, *refs):
         k_ref, v_ref, s_ref, o_ref, new_ref = refs[len(order):]
         for b in range(tb):
+            # column c * th + e: the decay (c = 0), k, q of the tile's head e
+            turned = k_ref[b].reshape(3 * th, dk).T  # [Dk, 3 th]
             for e in range(th):
-                old = s_ref[b, e]  # [Dk, Dv]
-
-                def column(j, b=b, e=e):
-                    return k_ref[b, e, j:j + 1, :].reshape(dk, 1)
-
-                per_v = v_ref[b, e]  # [4, Dv]: beta v, beta, q . k, 0
-                read = (column(0) * old).sum(0, keepdims=True)  # [1, Dv]
-                u = per_v[0:1] - per_v[1:2] * read
-                new_ref[b, e] = column(2) * old + column(3) * u
-                o_ref[b, e:e + 1, :] = (
-                    (column(1) * old).sum(0, keepdims=True) + per_v[2:3] * u)
+                decay, key, query = (
+                    turned[:, c * th + e:c * th + e + 1] for c in range(3))
+                decayed = decay * s_ref[b, e]  # [Dk, Dv]
+                read = (key * decayed).sum(0, keepdims=True)  # [1, Dv]
+                u = v_ref[b, 1, e:e + 1] * (v_ref[b, 0, e:e + 1] - read)
+                new = decayed + key * u
+                new_ref[b, e] = new
+                o_ref[b, e:e + 1, :] = (query * new).sum(0, keepdims=True)
 
     interpret = jax.default_backend() != "tpu"
     stack, at = states, layer
@@ -268,7 +270,7 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
         return layer[0], tile_at(i, *order), j, 0, 0
 
     def rows(i, j, layer, *order):
-        return tile_at(i, *order), j, 0, 0
+        return tile_at(i, *order), 0, j, 0
 
     def heads(i, j, layer, *order):
         return tile_at(i, *order), j, 0
@@ -280,8 +282,8 @@ def kda_update(states, layer, q, k, v, g, beta, live=None, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1 + len(order),
             grid=(tiles, h // th),
-            in_specs=[pl.BlockSpec((tb, th, 4, dk), rows),
-                      pl.BlockSpec((tb, th, 4, dv), rows),
+            in_specs=[pl.BlockSpec((tb, 3, th, dk), rows),
+                      pl.BlockSpec((tb, 2, th, dv), rows),
                       pl.BlockSpec((None, tb, th, dk, dv), tile)],
             out_specs=[pl.BlockSpec((tb, th, dv), heads),
                        pl.BlockSpec((None, tb, th, dk, dv), tile)],

@@ -244,25 +244,37 @@ def test_kda_step_equals_the_references_token(params):
     assert float(jnp.abs(got_state[0] - want_state).max()) < TOL
 
 
-@pytest.mark.parametrize("b,h,dk,dv,layers,layer,tile", [
-    (4, 4, 16, 8, 3, 1, 2 * 16 * 8 * 4),  # two heads a tile
-    (3, 2, 16, 16, 2, 0, 16 * 16 * 4),  # one head a tile
-    (4, 2, 8, 16, 2, 1, 2 ** 20),  # every slot and head in one tile
-], ids=["heads", "head", "whole"])
+@pytest.mark.parametrize("b,h,dk,dv,layers,layer,tile,parked", [
+    (4, 4, 16, 8, 3, 1, 2 * 16 * 8 * 4, 1),  # two heads a tile
+    (3, 2, 16, 16, 2, 0, 16 * 16 * 4, 1),  # one head a tile
+    (4, 2, 8, 16, 2, 1, 2 ** 20, 1),  # every slot and head in one tile
+    # the chip's widths (whole 128-lane rows turned to columns): four
+    # heads and two slots a tile, the parked slot inside a live tile
+    (4, 4, 128, 128, 2, 1, 8 * 128 * 128 * 4, 1),
+    (2, 8, 128, 128, 2, 0, 4 * 128 * 128 * 4, 1),  # half a slot's heads
+    (2, 4, 128, 128, 2, 1, 8 * 128 * 128 * 4, None),  # no live at all
+    (3, 2, 128, 256, 2, 0, 2 * 128 * 256 * 4, 2),  # Dv is not Dk
+    (4, 4, 16, 8, 3, 2, 2 * 16 * 8 * 4, None),
+], ids=["heads", "head", "whole", "lanes128_slots", "lanes128_heads",
+        "lanes128_no_live", "lanes128_wide_values", "heads_no_live"])
 def test_the_update_kernel_equals_the_step_on_one_layer_in_place(
-        b, h, dk, dv, layers, layer, tile):
+        b, h, dk, dv, layers, layer, tile, parked):
     """``kda_update`` (the Pallas interpreter here) against ``kda_step``:
     the layer it is told, the other layers' bytes untouched, a parked
-    lane's state bit for bit what it was."""
+    lane's state bit for bit what it was (``parked`` None: called without
+    ``live``, every lane stepped)."""
     states = jax.random.normal(jax.random.key(1), (layers, b, h, dk, dv))
     q, k, v, g, beta = (a[:, 0] for a in _delta_inputs(2, b, 1, h, dk, dv))
-    live = jnp.arange(b) != 1
+    live = None if parked is None else jnp.arange(b) != parked
     o, new = jax.jit(kda_update, static_argnames=("tile_bytes",))(
         states, layer, q, k, v, g, beta, live, tile_bytes=tile)
     want_o, want_s = kda_step(states[layer], q, k, v, g, beta)
-    assert float(jnp.abs(o - want_o)[live].max()) < 1e-5
-    assert float(jnp.abs(new[layer] - want_s)[live].max()) < 1e-5
-    assert np.array_equal(new[layer, 1], states[layer, 1])
+    stepped = jnp.ones(b, bool) if parked is None else live
+    assert float(jnp.abs(o - want_o)[stepped].max()) < 1e-5
+    assert float(jnp.abs(new[layer] - want_s)[stepped].max()) < 1e-5
+    if parked is not None:
+        assert np.array_equal(new[layer, parked], states[layer, parked])
+        assert not np.asarray(o[parked]).any()
     others = [i for i in range(layers) if i != layer]
     assert np.array_equal(new[jnp.array(others)], states[jnp.array(others)])
 
@@ -273,21 +285,23 @@ LIVE = {"all": [1] * 6, "none": [0] * 6,
         "alternating": [1, 0, 1, 0, 1, 0], "one_live": [0, 0, 0, 0, 1, 0]}
 
 
-@pytest.mark.parametrize("tile", [16 * 16 * 4, 2 * 16 * 16 * 4,
-                                  4 * 16 * 16 * 4],
+@pytest.mark.parametrize("d", [16, 128], ids=["d16", "d128"])
+@pytest.mark.parametrize("heads_a_tile", [1, 2, 4],
                          ids=["a_head_a_tile", "a_slot_a_tile",
                               "two_slots_a_tile"])
 @pytest.mark.parametrize("lanes", list(LIVE))
-def test_the_update_kernel_visits_the_live_lanes_only(lanes, tile):
+def test_the_update_kernel_visits_the_live_lanes_only(lanes, heads_a_tile, d):
     """``kda_update`` told which lanes are live (the Pallas interpreter
-    here), 6 slots of 2 heads: a live lane's ``o`` and new state equal
-    ``kda_step``'s, a parked lane's state is bit for bit the input's and
-    its ``o`` zeros (whether its tile is skipped whole or shared with a
-    live lane), every other layer is bit for bit the input's, and no
-    ``live`` at all is every lane live."""
+    here), 6 slots of 2 heads, d x d a head (16, and the chip's 128): a
+    live lane's ``o`` and new state equal ``kda_step``'s, a parked lane's
+    state is bit for bit the input's and its ``o`` zeros (whether its
+    tile is skipped whole or shared with a live lane), every other layer
+    is bit for bit the input's, and no ``live`` at all is every lane
+    live."""
     layers, layer, live = 3, 2, np.array(LIVE[lanes], bool)
-    states = jax.random.normal(jax.random.key(3), (layers, 6, 2, 16, 16))
-    q, k, v, g, beta = (a[:, 0] for a in _delta_inputs(4, 6, 1, 2, 16, 16))
+    tile = heads_a_tile * d * d * 4
+    states = jax.random.normal(jax.random.key(3), (layers, 6, 2, d, d))
+    q, k, v, g, beta = (a[:, 0] for a in _delta_inputs(4, 6, 1, 2, d, d))
     update = jax.jit(kda_update, static_argnames=("tile_bytes",))
     o, new = update(states, layer, q, k, v, g, beta, jnp.asarray(live),
                     tile_bytes=tile)
