@@ -42,13 +42,24 @@ attends fewer rows still: it scores the prefix's index keys, picks
 ``_attend_latent_chosen``: a masked walk up to the longest lane, see
 there); its prefill attends block by block under the mask of each query's
 chosen rows (``_prefill_choice``, ``_attend_masked``).
+
+What differs by the KIND of a layer is in one table, ``_KINDS``, a row a
+kind (``transformer.layer_kind``: "attn", "ssm", "swa", "kda"; a latent
+block with an indexer has its own "attn" row): what a slot keeps for the
+kind's layers (``init_kv_cache`` merges the rows), how a token decodes
+through one and how a prompt fills it (``_decode_forward_multi`` and
+``prefill_into_slot`` look the layer's form up, once each), the kind's
+counters (``block_stat_keys``) and the host's count of what its decode
+attention reads (``attn_rows_read``). Nothing else here, and nothing in
+the engine, asks what kind a layer is: a new kind is a row and the
+functions it names.
 """
 
 from __future__ import annotations
 
 import math
 from functools import lru_cache, partial
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +72,7 @@ from ray_tpu.models.transformer import (
     embed_tokens,
     kda_split,
     layer_groups,
+    layer_kind,
     lm_logits,
     mla_expand,
     scan_stack,
@@ -178,90 +190,108 @@ def _ckr_rows(c_kv, k_r, width: int):
 
 def init_kv_cache(config: TransformerConfig, batch: int,
                   max_len: int) -> Dict[str, jax.Array]:
-    """The cache is a pytree that the mixers define. Its leaves are of
-    two kinds, told apart by where they sit (``cache_rows``,
-    ``cache_state``): ROW leaves, [layers that keep it, B, S_max, ...],
-    one row a cached token, at the top level; and STATE leaves, [layers
-    that keep it, B, ...] with no S_max axis, under ``"state"``: what a
-    slot keeps whatever its length. A slot is index ``b`` of axis 1 of
-    every leaf. MHA/GQA: ``k`` and ``v`` of [L, .., Hkv, D]. Latent
-    attention: the row ``[c_kv | rot(k_r)]`` a token a layer, as ``ckv`` of
-    [L, .., kv_lora_rank] (key and value at once) and ``kr`` of
-    [L, .., qk_rope_dim] (two arrays: see _attend_latent_prefix_plus_self).
-    A latent block with an indexer GATHERS its rows, and a gather wants
-    rows that lie whole: its slot keeps the same row as ONE array ``ckr``
-    of [L, .., kv_lora_rank + qk_rope_dim, padded with zeros to whole
-    lanes of 128] (576 -> 640; the chip lays a 64- or 576-wide array out
-    rows-minor, and gathering from that costs a copy of the cache a
-    layer), and ``ik`` of [layers that own an indexer, .., index_head_dim]:
-    the index key a token, the rows kept for CHOOSING what the layers
-    above attend (the choice itself lives one step, in the layer scan's
-    carry).
+    """The cache is a pytree that the mixers define: the merge of what
+    each kind of layer the model has keeps of a slot (``_KINDS``, a row a
+    kind; each row's ``keeps`` says what and why). Its leaves are of two
+    kinds, told apart by where they sit (``cache_rows``, ``cache_state``):
+    ROW leaves, [layers that keep it, B, S_max, ...], one row a cached
+    token, at the top level; and STATE leaves, [layers that keep it, B,
+    ...] with no S_max axis, under ``"state"``: what a slot keeps whatever
+    its length. A slot is index ``b`` of axis 1 of every leaf."""
+    cache, state = {}, {}
+    for _kind, row, n in _kinds_of(config):
+        rows, kept = row.keeps(config, n, batch, max_len)
+        cache.update(rows)
+        state.update(kept)
+    return {**cache, "state": state} if state else cache
 
+
+def _attn_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """What the layers that attend every row keep: rows alone. MHA/GQA:
+    ``k`` and ``v`` of [L, .., Hkv, D] (``v`` as wide as the values are).
     Heads narrower than a 128-lane (``_kv_rows``) lie flat in their row,
     ``k`` and ``v`` of [L, .., Hkv x D]: the chip lays [.., Hkv, 64] out
     rows-minor, and the decode attention's kernel, which takes its
     operands row-major, was handed two copies of the whole cache a block
-    (compiled for a described v5e, PR 35).
-
-    A model with state-space layers (``layer_types``) keeps ``k`` / ``v``
-    for its attention layers alone and, for its "ssm" layers, ``state``:
-    ``ssm`` of [those layers, B, H, P, N] in float32 (rounded to bf16 at
-    every one of thousands of steps it would be another result) and
-    ``conv``, the convolution's last ``ssm_conv - 1`` inputs, of [those
-    layers, B, (ssm_conv - 1) x width] in the compute dtype: the taps side
-    by side in ONE minor axis, a whole number of 128-lanes, because
-    [.., 3, width] would be tiled with its 3 rows padded to 16.
-
-    A model with window layers keeps ``k`` / ``v`` rows for the layers
-    that attend every row alone (``v`` as wide as the values are) and,
-    for its "window" layers, ``state``: the ring ``wk`` of [those layers,
-    B, window, Hkv_w x D] and ``wv`` of [.., Hkv_w x Dv], the KV heads
-    flat in their row; the row of position p is ``p mod window``.
-
-    A model with "kda" layers keeps its attention layers' rows (``ckv`` /
-    ``kr`` where they are latent, else ``k`` / ``v``) for those layers
-    alone and, for its "kda" layers, ``state``: ``kda`` of [those layers,
-    B, H, D, D] in float32 (a head's keys x values) and ``conv``, the last
-    ``kda_conv - 1`` inputs of the convolution over the three streams,
-    [those layers, B, (kda_conv - 1) x 3 x H x D], flat as above."""
-    c = config
-    if c.mixer == "mla" and c.index_topk:
-        rows = (batch, max_len)
-        return {"ckr": jnp.zeros((c.n_layers,) + rows + (_ckr_width(c),),
-                                 c.dtype),
-                "ik": jnp.zeros((c.n_index_layers,) + rows
-                                + (c.index_head_dim,), c.dtype)}
-    rows = (c.n_attn_layers, batch, max_len)
+    (compiled for a described v5e, PR 35). Latent attention: the row
+    ``[c_kv | rot(k_r)]`` a token a layer, as ``ckv`` of [L, ..,
+    kv_lora_rank] (key and value at once) and ``kr`` of [L, ..,
+    qk_rope_dim] (two arrays: see _attend_latent_prefix_plus_self)."""
+    rows = (n, batch, max_len)
     if c.mixer == "mla":
-        cache = {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
-                 "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}
-    else:
-        k_row, v_row = _kv_rows(c)
-        cache = {
-            "k": jnp.zeros(rows + k_row, c.dtype),
-            "v": jnp.zeros(rows + v_row, c.dtype),
-        }
-    if c.n_kda_layers:
-        slots = (c.n_kda_layers, batch)
-        cache["state"] = {
-            "kda": jnp.zeros(slots + (c.kda_heads, c.kda_head_dim,
-                                      c.kda_head_dim), jnp.float32),
-            "conv": jnp.zeros(slots + ((c.kda_conv - 1) * 3 * c.kda_inner,),
-                              c.dtype)}
-    if c.n_window_layers:
-        ring, h_kv = (c.n_window_layers, batch, c.window), c.mha_kind(True)[0]
-        cache["state"] = {
-            "wk": jnp.zeros(ring + (h_kv * c.d_head,), c.dtype),
-            "wv": jnp.zeros(ring + (h_kv * c.v_dim,), c.dtype)}
-    if c.n_ssm_layers:
-        slots = (c.n_ssm_layers, batch)
-        cache["state"] = {
-            "ssm": jnp.zeros(slots + (c.ssm_heads, c.ssm_head_dim,
-                                      c.ssm_state), jnp.float32),
-            "conv": jnp.zeros(slots + ((c.ssm_conv - 1) * c.ssm_conv_width,),
-                              c.dtype)}
-    return cache
+        return {"ckv": jnp.zeros(rows + (c.kv_lora_rank,), c.dtype),
+                "kr": jnp.zeros(rows + (c.qk_rope_dim,), c.dtype)}, {}
+    k_row, v_row = _kv_rows(c)
+    return {"k": jnp.zeros(rows + k_row, c.dtype),
+            "v": jnp.zeros(rows + v_row, c.dtype)}, {}
+
+
+def _chosen_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """A latent block with an indexer GATHERS its rows, and a gather wants
+    rows that lie whole: its slot keeps the latent row as ONE array
+    ``ckr`` of [L, .., kv_lora_rank + qk_rope_dim, padded with zeros to
+    whole lanes of 128] (576 -> 640; the chip lays a 64- or 576-wide array
+    out rows-minor, and gathering from that costs a copy of the cache a
+    layer), and ``ik`` of [layers that own an indexer, .., index_head_dim]:
+    the index key a token, the rows kept for CHOOSING what the layers
+    above attend (the choice itself lives one step, in the layer scan's
+    carry)."""
+    rows = (batch, max_len)
+    return {"ckr": jnp.zeros((n,) + rows + (_ckr_width(c),), c.dtype),
+            "ik": jnp.zeros((c.n_index_layers,) + rows
+                            + (c.index_head_dim,), c.dtype)}, {}
+
+
+def _ssm_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """A state-space layer keeps no rows: ``ssm`` of [those layers, B, H,
+    P, N] in float32 (rounded to bf16 at every one of thousands of steps
+    it would be another result) and ``conv``, the convolution's last
+    ``ssm_conv - 1`` inputs, of [those layers, B, (ssm_conv - 1) x width]
+    in the compute dtype: the taps side by side in ONE minor axis, a whole
+    number of 128-lanes, because [.., 3, width] would be tiled with its 3
+    rows padded to 16."""
+    return {}, {
+        "ssm": jnp.zeros((n, batch, c.ssm_heads, c.ssm_head_dim,
+                          c.ssm_state), jnp.float32),
+        "conv": jnp.zeros((n, batch, (c.ssm_conv - 1) * c.ssm_conv_width),
+                          c.dtype)}
+
+
+def _window_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """A window layer keeps the last ``window`` rows: the ring ``wk`` of
+    [those layers, B, window, Hkv_w x D] and ``wv`` of [.., Hkv_w x Dv],
+    the KV heads flat in their row; the row of position p is ``p mod
+    window``."""
+    ring, h_kv = (n, batch, c.window), c.mha_kind(True)[0]
+    return {}, {"wk": jnp.zeros(ring + (h_kv * c.d_head,), c.dtype),
+                "wv": jnp.zeros(ring + (h_kv * c.v_dim,), c.dtype)}
+
+
+def _kda_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """A "kda" layer keeps ``kda`` of [those layers, B, H, D, D] in
+    float32 (a head's keys x values) and ``conv``, the last ``kda_conv -
+    1`` inputs of the convolution over the three streams, [those layers,
+    B, (kda_conv - 1) x 3 x H x D], flat as a state-space layer's."""
+    return {}, {
+        "kda": jnp.zeros((n, batch, c.kda_heads, c.kda_head_dim,
+                          c.kda_head_dim), jnp.float32),
+        "conv": jnp.zeros((n, batch, (c.kda_conv - 1) * 3 * c.kda_inner),
+                          c.dtype)}
+
+
+def _put_layer(leaf, rows, li):
+    """``leaf`` (one slot's, [layers, 1, S, ...]) with layer ``li``'s rows
+    overwritten from their start by ``rows`` [1, 1, S', ...]."""
+    return lax.dynamic_update_slice(
+        leaf, rows.astype(leaf.dtype), (li,) + (0,) * (leaf.ndim - 1))
+
+
+def _put_token(leaf, li, b_idx, at, x):
+    """``leaf`` [layers, B, S, ...] with row ``at`` [B] of layer ``li``
+    of every lane overwritten by the lanes' ``x`` [B, ...], shaped as the
+    leaf keeps a row."""
+    return leaf.at[li, b_idx, at].set(
+        x.reshape((-1,) + leaf.shape[3:]).astype(leaf.dtype))
 
 
 def _kv_rows(c: TransformerConfig) -> Tuple[Tuple[int, ...], ...]:
@@ -317,21 +347,21 @@ def dense_attn_chunk(arrays) -> int:
         arrays[0].shape[2])
 
 
-def _visits(pos, arrays, chunk: Optional[int] = None):
+def _visits(pos, arrays):
     """One decode step's schedule for the kernel over the cache ``arrays``
-    (``ops/decode_attention.slot_schedule``); ``chunk`` only where a test
-    names its own."""
+    (``ops/decode_attention.slot_schedule``)."""
     s_max = arrays[0].shape[2]
-    return slot_schedule(
-        pos, s_max, min(chunk or dense_attn_chunk(arrays), s_max))
+    return slot_schedule(pos, s_max, min(dense_attn_chunk(arrays), s_max))
 
 
 @lru_cache(maxsize=None)
 def decode_attn_chunk(config: TransformerConfig, s_max: int) -> int:
     """Rows of cache one iteration of ``config``'s decode attention reads
     of a slot of ``s_max`` rows."""
-    if config.index_topk:
-        return min(DSA_CHUNK, s_max)
+    return _row("attn", config).chunk(config, s_max)
+
+
+def _dense_chunk(config: TransformerConfig, s_max: int) -> int:
     cache = jax.eval_shape(lambda: init_kv_cache(config, 1, s_max))
     return dense_attn_chunk(jax.tree.leaves(cache_rows(cache)))
 
@@ -340,44 +370,28 @@ def attn_rows_walked(rows: int, s_max: int, chunk: int) -> int:
     """Rows of ONE slot's cache that one decode step reads when the slot's
     ``pos`` is ``rows``: whole chunks of ``chunk`` (``decode_attn_chunk``
     of the model) up to it, at most ``s_max``; none for a parked slot.
-    Plain integers: the engine's host-side count of what the decode
-    attention's kernel reads on the device, slot by slot. (The block with
-    an indexer walks every slot up to the longest lane: there ``rows`` is
-    the largest ``pos``, for every slot.)"""
+    Plain integers: the host-side count of what the decode attention's
+    kernel reads on the device, slot by slot."""
     return min(-(-min(rows, s_max) // chunk) * chunk, s_max)
 
 
-def _walk_rows(body, m, acc, pos, s_max: int, chunk: int, l):
-    """The schedule of the indexed block's decode attention
-    (``_attend_latent_chosen``; the dense caches are read slot by slot by
-    ``ops/decode_attention``): an online softmax (running max ``m``, sum
-    ``l``, accumulator ``acc``) carried through ``state = body(start,
-    attended, state)`` for each chunk of ``chunk`` cache rows up to the
-    longest live sequence, ceil(max(pos) / chunk) iterations: the trip
-    count is data, so one compiled program serves every length.
-    ``start`` is the chunk's first row; ``attended()`` gives the mask
-    [B, chunk] of the rows a lane attends (row < pos, strict). The last
-    chunk of an S_max that chunk does not divide starts early (a slice
-    must stay in bounds) and masks what it re-reads. Returns (l, acc)."""
-    def walk(c, state):
-        lo = c * chunk
-        start = jnp.minimum(lo, s_max - chunk)
-
-        def attended():
-            k_pos = start + jnp.arange(chunk)
-            return (k_pos >= lo)[None, :] & (k_pos[None, :] < pos[:, None])
-
-        return body(start, attended, state)
-
-    bound = jnp.minimum(jnp.max(pos), s_max)
-    _, l, acc = lax.fori_loop(
-        0, (bound + chunk - 1) // chunk, walk, (m, l, acc)
-    )
-    return l, acc
+def attn_rows_read(config: TransformerConfig, rows, steps: int,
+                   s_max: int) -> int:
+    """Rows of cache the decode attention of one ``decode_block`` of
+    ``steps`` steps reads, the slots' ``pos`` being ``rows`` (plain
+    integers, 0: parked) when it starts: the engine's count. Each live
+    slot's chunks up to its own length, step by step; a block with an
+    indexer walks EVERY slot up to the longest lane."""
+    attn = _row("attn", config)
+    lanes = ([max(rows)] * len(rows) if attn.walks_longest
+             else [r for r in rows if r])
+    chunk = decode_attn_chunk(config, s_max)
+    return sum(attn_rows_walked(r + k, s_max, chunk)
+               for r in lanes for k in range(steps))
 
 
-def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
-                             chunk: Optional[int] = None, schedule=None):
+def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer,
+                             schedule):
     """q [B,1,H,D] against the UNWRITTEN cache prefix (k_pos < pos,
     strict — the row at ``pos`` may hold stale garbage) plus the fresh
     (k_new, v_new) [B,1,Hkv,D] as one extra logical position. Exactly
@@ -389,36 +403,37 @@ def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer=None,
     decode step's attention costs. So each slot's rows are read in chunks
     of ``chunk`` up to the slot's OWN ``pos`` by one Pallas kernel
     (``ops/decode_attention``: its ``schedule`` of (slot, chunk) visits is
-    made once a step and shared by the layers; made here where none is
-    given) with an online softmax in float32 (running max, sum and
-    accumulator, seeded here by the self position). Within a chunk the
-    strict mask stays, so this is the same attention over the same rows
-    (bf16 operands, float32 scores and accumulation). A lane at ``pos`` 0
-    attends itself alone and reads nothing: that is where the engine
-    parks its free slots.
+    made once a step and shared by the layers) with an online softmax in
+    float32 (running max, sum and accumulator, seeded here by the self
+    position). Within a chunk the strict mask stays, so this is the same
+    attention over the same rows (bf16 operands, float32 scores and
+    accumulation). A lane at ``pos`` 0 attends itself alone and reads
+    nothing: that is where the engine parks its free slots.
 
-    ck/cv are one layer's [B,S_max,Hkv,D], or with ``layer`` the whole
-    [L,B,S_max,Hkv,D] cache: the kernel reads a chunk where it lies in
-    the big buffer (a layer sliced out of it would be copied whole)."""
-    if layer is None:
-        ck, cv, layer = ck[None], cv[None], 0
-    h_kv, d = ck.shape[3:]
-    n_rep = q.shape[2] // h_kv
-    scale = d ** -0.5
-    f32 = jnp.float32
-    m = jnp.einsum(
-        "bqhd,bqhd->bh", q, repeat_kv(k_new, n_rep),
-        preferred_element_type=f32,
-    ) * scale  # [B,H]: the self position's score
-    acc = repeat_kv(v_new, n_rep)[:, 0].astype(f32)
+    ck/cv are the whole [L,B,S_max,Hkv,D] cache and ``layer`` the one to
+    read: the kernel reads a chunk where it lies in the big buffer (a
+    layer sliced out of it would be copied whole)."""
+    m, scale = _self_score(q, k_new)
+    acc = repeat_kv(v_new, q.shape[2] // ck.shape[3])[:, 0].astype(
+        jnp.float32)
     out = decode_attention(
-        (q[:, 0],), (ck,), cv, m, acc, pos,
-        schedule or _visits(pos, (ck, cv), chunk), layer=layer, scale=scale)
+        (q[:, 0],), (ck,), cv, m, acc, pos, schedule, layer=layer,
+        scale=scale)
     return out[:, None]
 
 
+def _self_score(q, k_new):
+    """The self position's scores, q [B,1,H,D] . k_new [B,1,Hkv,D] as
+    [B,H] in float32, and the scale they carry: what seeds the decode
+    attention's running max."""
+    scale = q.shape[-1] ** -0.5
+    return jnp.einsum(
+        "bqhd,bqhd->bh", q, repeat_kv(k_new, q.shape[2] // k_new.shape[2]),
+        preferred_element_type=jnp.float32) * scale, scale
+
+
 def _attend_flat_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer,
-                                  schedule=None):
+                                  schedule):
     """``_attend_prefix_plus_self`` over a cache whose rows hold their KV
     heads flat, ck / cv [L,B,S_max,Hkv x D] (``_kv_rows``). To the kernel
     this is one key Hkv x D wide that all heads share: a query head's own
@@ -426,19 +441,16 @@ def _attend_flat_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer,
     is its own head's; it gathers all Hkv heads' values and keeps its own.
     The kernel streams the same rows either way, and the rows are all a
     decode step's attention costs."""
-    B, _, n_heads, d = q.shape
+    B, _, n_heads, _d = q.shape
     h_kv, d_v = k_new.shape[2], v_new.shape[-1]
-    n_rep = n_heads // h_kv
-    scale = d ** -0.5
-    f32 = jnp.float32
     q_wide, own = _wide_queries(q, h_kv)
-    m = jnp.einsum("bqhd,bqhd->bh", q, repeat_kv(k_new, n_rep),
-                   preferred_element_type=f32) * scale
-    acc = jnp.broadcast_to(v_new.reshape(B, 1, h_kv * d_v).astype(f32),
-                           (B, n_heads, h_kv * d_v))
+    m, scale = _self_score(q, k_new)
+    acc = jnp.broadcast_to(
+        v_new.reshape(B, 1, h_kv * d_v).astype(jnp.float32),
+        (B, n_heads, h_kv * d_v))
     out = decode_attention(
-        (q_wide,), (ck,), cv, m, acc, pos,
-        schedule or _visits(pos, (ck, cv)), layer=layer, scale=scale)
+        (q_wide,), (ck,), cv, m, acc, pos, schedule, layer=layer,
+        scale=scale)
     return _own_values(out, own)
 
 
@@ -499,9 +511,7 @@ def _attend_ring(q, wk, wv, sink, rows, *, layer, schedule):
 
 
 def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
-                                    pos, *, layer, scale: float,
-                                    chunk: Optional[int] = None,
-                                    schedule=None):
+                                    pos, *, layer, scale: float, schedule):
     """``_attend_prefix_plus_self`` for a latent cache: ONE key that all
     heads share, in two parts, the latent ``ckv`` [L,B,S_max,R] (which is
     the value as well) and the rotary key ``kr`` [L,B,S_max,rope]. q_lat
@@ -530,8 +540,7 @@ def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
     acc = jnp.broadcast_to(c_new.astype(f32)[:, None], q_lat.shape)
     return decode_attention(
         (q_lat, q_rope), (ckv, kr.swapaxes(2, 3)), None, m, acc, pos,
-        schedule or _visits(pos, (ckv, kr), chunk), layer=layer,
-        scale=scale, rows_last=(False, True))
+        schedule, layer=layer, scale=scale, rows_last=(False, True))
 
 
 # ---------------- learned sparse attention over the latent cache ----------------
@@ -678,7 +687,7 @@ def _decode_choice(q, w, ik, slot, k_new, pos, topk: int):
     ``pos`` plus the token's own ``k_new`` [B, dI] as the row at ``pos``
     (a candidate like any other; its cache row is not written yet). The
     keys are scored ``DSA_CHUNK`` rows at a time up to the longest lane,
-    like ``_walk_rows``. Returns the mask [B, S_max] of the
+    like ``_attend_latent_chosen``'s walk. Returns the mask [B, S_max] of the
     min(pos + 1, topk) best rows of each lane (``select_rows``: exactly
     ``lax.top_k``'s set)."""
     B, s_max, d_i = ik.shape[1:]
@@ -708,10 +717,15 @@ def _attend_latent_chosen(q_lat, q_rope, ckr, row_new, pos, chosen, *,
     [B,H,R] and q_rope [B,H,rope] attend the rows of ``chosen`` [B,S_max]
     alone. The cache is ``ckr`` [L,B,S_max,W], rows [c | rot(k_r) | zeros];
     the token's own ``row_new`` [B,W] stands for the row at ``pos`` (not
-    written yet) and counts only where ``chosen`` has it. The same walk
-    (``_walk_rows``: whole chunks up to the longest lane) with the online
-    softmax over the chosen rows; one product gives a chunk's scores (the
-    query laid out like a row). Returns o_lat [B,H,R].
+    written yet) and counts only where ``chosen`` has it. An online
+    softmax in float32 (running max ``m``, sum ``l``, accumulator ``acc``)
+    over the chosen rows, carried through whole chunks of ``chunk`` cache
+    rows up to the longest live lane, ceil(max(pos) / chunk) iterations:
+    the trip count is data, so one compiled program serves every length.
+    A lane attends a chunk's rows below its ``pos`` (strict); the last
+    chunk of an S_max that chunk does not divide starts early (a slice
+    must stay in bounds) and masks what it re-reads. One product gives a
+    chunk's scores (the query laid out like a row). Returns o_lat [B,H,R].
 
     Why a masked walk and not a gather of the chosen rows: timed alone on
     a v5e, XLA's gather of 2,048 rows x 640 numbers for 12 lanes read 1.02
@@ -732,12 +746,15 @@ def _attend_latent_chosen(q_lat, q_rope, ckr, row_new, pos, chosen, *,
     acc = has_own[:, :, None] * jnp.broadcast_to(
         row_new[:, None, :r_lat].astype(f32), q_lat.shape)
 
-    def body(start, attended, state):
+    def walk(i, state):
         m, l, acc = state  # [B,1,H], [B,1,H], [B,H,R]
+        lo = i * chunk
+        start = jnp.minimum(lo, s_max - chunk)
         rows = lax.optimization_barrier(lax.dynamic_slice(
             ckr, (layer, 0, start, 0), (1, B, chunk, width))[0])
-        take = attended() & lax.dynamic_slice(chosen, (0, start),
-                                              (B, chunk))
+        k_pos = start + jnp.arange(chunk)
+        take = ((k_pos >= lo)[None, :] & (k_pos[None, :] < pos[:, None])
+                ) & lax.dynamic_slice(chosen, (0, start), (B, chunk))
         s = jnp.einsum("bkd,bhd->bkh", rows, q,
                        preferred_element_type=f32) * scale
         s = jnp.where(take[:, :, None], s, NEG_INF)
@@ -750,7 +767,9 @@ def _attend_latent_chosen(q_lat, q_rope, ckr, row_new, pos, chosen, *,
             preferred_element_type=f32)
         return m_new, l, acc
 
-    l, acc = _walk_rows(body, m, acc, pos, s_max, chunk, l=l)
+    bound = jnp.minimum(jnp.max(pos), s_max)
+    _, l, acc = lax.fori_loop(
+        0, (bound + chunk - 1) // chunk, walk, (m, l, acc))
     return (acc / l[:, 0, :, None]).astype(q_lat.dtype)
 
 
@@ -764,24 +783,16 @@ def _prefill_attn_chosen(single, li, wp, choice, c: TransformerConfig):
     layer's rows written, choice))."""
     S = choice["mask"].shape[0]
 
-    def choose(project):
-        def afresh(_):
-            q, k, w = project(_own_indexer(wp))
-            return {"mask": _prefill_choice(q[0], k[0], w[0],
-                                            c.index_topk), "k": k[0]}
-
-        return lax.cond(wp["index_own"], afresh, lambda _: choice, None)
+    def afresh(q, k, w):
+        return {"mask": _prefill_choice(q[0], k[0], w[0], c.index_topk),
+                "k": k[0]}
 
     @_latent
     def cached_attn(q_nope, q_rope, c_kv, k_r, wp, chosen):
-        def put(name, rows, layer):
-            return lax.dynamic_update_slice(
-                single[name], rows[None].astype(single[name].dtype),
-                (layer, 0, 0, 0))
-
-        new = {"ckr": put("ckr", _ckr_rows(
-                   c_kv, k_r[:, :, 0], single["ckr"].shape[-1]), li),
-               "ik": put("ik", chosen["k"][None], wp["index_slot"])}
+        new = {"ckr": _put_layer(single["ckr"], _ckr_rows(
+                   c_kv, k_r[:, :, 0], single["ckr"].shape[-1])[None], li),
+               "ik": _put_layer(single["ik"], chosen["k"][None][None],
+                                wp["index_slot"])}
         q = jnp.concatenate([q_nope, q_rope], -1)[0]  # [S,H,D]
         n_h = q.shape[1]
         g = PREFILL_HEAD_GROUP if n_h % PREFILL_HEAD_GROUP == 0 else n_h
@@ -798,13 +809,28 @@ def _prefill_attn_chosen(single, li, wp, choice, c: TransformerConfig):
         out = out.transpose(1, 0, 2, 3).reshape(S, n_h, -1)
         return out[None], (new, chosen)
 
-    cached_attn.choose = choose
+    cached_attn.choose = _choose(wp, choice, afresh)
     return cached_attn
 
 
 def _own_indexer(wp):
     """This layer's indexer out of its stack's (transformer.scan_stack)."""
     return jax.tree.map(lambda a: a[wp["index_local"]], wp["indexer"])
+
+
+def _choose(wp, choice, afresh):
+    """What a block with an indexer hands its mixer as ``attn_fn.choose``
+    (``transformer._mla_mixer`` calls it with ``project``, which makes an
+    indexer's queries, keys and weights): a layer that owns an indexer
+    chooses ``afresh(q, k, w)``, the others attend the ``choice`` the
+    layer scan carries and run nothing of it (a ``lax.cond``)."""
+    def choose(project):
+        return lax.cond(
+            wp["index_own"],
+            lambda _: afresh(*project(_own_indexer(wp))),
+            lambda _: choice, None)
+
+    return choose
 
 
 def _mla_scale(c: TransformerConfig) -> float:
@@ -815,6 +841,20 @@ def _latent(fn):
     """Marks an ``attn_fn`` that takes latents (transformer._mla_mixer)."""
     fn.latent = True
     return fn
+
+
+def _absorbed(q_nope, wp, c: TransformerConfig):
+    """The absorbed form of a latent decode attention: W_uk goes into the
+    query and W_uv onto the output, so the walk sees one key that all
+    heads share, whose latent part is the value as well. q_nope
+    [B,1,H,nope] -> (q_lat [B,1,H,R], ``onto_heads``: o_lat [B,H,R] ->
+    the heads' outputs [B,1,H,v])."""
+    def onto_heads(o_lat):
+        return jnp.einsum("bshc,chk->bshk", o_lat[:, None],
+                          wp["wuv"].astype(c.dtype))
+
+    return jnp.einsum("bshk,chk->bshc", q_nope,
+                      wp["wuk"].astype(c.dtype)), onto_heads
 
 
 def _decode_attn_chosen(cache, li, pos, b_idx, c: TransformerConfig, wp,
@@ -830,61 +870,47 @@ def _decode_attn_chosen(cache, li, pos, b_idx, c: TransformerConfig, wp,
     written after). Returns (output, (cache, choice))."""
     ckr, ik = cache["ckr"], cache["ik"]
 
-    def choose(project):
-        def afresh(_):
-            q, k, w = project(_own_indexer(wp))  # [B,1,nI,dI] [B,1,dI] ..
-            return {"mask": _decode_choice(
-                q[:, 0], w[:, 0], ik, wp["index_slot"], k[:, 0], pos,
-                c.index_topk), "k": k[:, 0]}
-
-        return lax.cond(wp["index_own"], afresh, lambda _: choice, None)
+    def afresh(q, k, w):  # [B,1,nI,dI], [B,1,dI], [B,1,nI]
+        return {"mask": _decode_choice(
+            q[:, 0], w[:, 0], ik, wp["index_slot"], k[:, 0], pos,
+            c.index_topk), "k": k[:, 0]}
 
     @_latent
     def cached_attn(q_nope, q_rope, c_kv, k_r, wp, chosen):
-        q_lat = jnp.einsum("bshk,chk->bshc", q_nope,
-                           wp["wuk"].astype(c.dtype))
+        q_lat, onto_heads = _absorbed(q_nope, wp, c)
         row = _ckr_rows(c_kv[:, 0], k_r[:, 0, 0], ckr.shape[-1]).astype(
             ckr.dtype)  # [B,W]: the token's own
         o_lat = _attend_latent_chosen(
             q_lat[:, 0], q_rope[:, 0], ckr, row, pos, chosen["mask"],
             layer=li, scale=_mla_scale(c))
-        out = jnp.einsum("bshc,chk->bshk", o_lat[:, None],
-                         wp["wuv"].astype(c.dtype))
         # the index key is written by every layer that attends its choice:
         # the same row, the same value
-        return out, ({
-            "ckr": ckr.at[li, b_idx, pos].set(row),
-            "ik": ik.at[wp["index_slot"], b_idx, pos].set(
-                chosen["k"].astype(ik.dtype))}, chosen)
+        return onto_heads(o_lat), ({
+            "ckr": _put_token(ckr, li, b_idx, pos, row),
+            "ik": _put_token(ik, wp["index_slot"], b_idx, pos, chosen["k"])
+        }, chosen)
 
-    cached_attn.choose = choose
+    cached_attn.choose = _choose(wp, choice, afresh)
     return cached_attn
 
 
-def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig,
-                 schedule=None):
+def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig, schedule):
     """One decode layer's ``attn_fn`` for ``c.mixer``: attends the
     cache's prefix plus the token itself WITHOUT a pre-write (see
     _attend_prefix_plus_self) and returns (output, the cache with the
     token's row written at ``pos``): the write only feeds LATER steps, so
     it stays off the attention's critical path. ``b_idx`` is
-    arange(B); ``schedule`` the step's visits (``slot_schedule``), made
-    by the attention where none is given."""
+    arange(B); ``schedule`` the step's visits (``_visits``)."""
     if c.mixer == "mla":
         @_latent
         def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
-            # Absorbed form: W_uk goes into the query and W_uv onto the
-            # output, so the walk sees one key that all heads share, whose
-            # latent part is the value as well.
             ckv, kr = cache["ckv"], cache["kr"]
-            q_lat = jnp.einsum("bshk,chk->bshc", q_nope,
-                               wp["wuk"].astype(c.dtype))
+            q_lat, onto_heads = _absorbed(q_nope, wp, c)
             c_new, r_new = c_kv[:, 0], k_r[:, 0, 0]  # [B,R], [B,rope]
             o_lat = _attend_latent_prefix_plus_self(
                 q_lat[:, 0], q_rope[:, 0], ckv, kr, c_new, r_new, pos,
                 layer=li, scale=_mla_scale(c), schedule=schedule)
-            out = jnp.einsum("bshc,chk->bshk", o_lat[:, None],
-                             wp["wuv"].astype(c.dtype))
+            out = onto_heads(o_lat)
             # a parked lane (pos 0) keeps its rows as they are: its write
             # goes past the last row and is dropped
             at = jnp.where(pos > 0, pos, ckv.shape[2])
@@ -903,16 +929,83 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig,
                   else _attend_prefix_plus_self)
         out = attend(
             q, ck_all, cv_all, k, v, pos, layer=li, schedule=schedule)
-        row = ck_all.shape[3:]  # (Hkv, D), or flat
-        ck2 = ck_all.at[li, b_idx, pos].set(
-            k[:, 0].reshape((-1,) + row).astype(ck_all.dtype)
-        )
-        cv2 = cv_all.at[li, b_idx, pos].set(
-            v[:, 0].reshape((-1,) + cv_all.shape[3:]).astype(cv_all.dtype)
-        )
-        return out, {**cache, "k": ck2, "v": cv2}
+        return out, {**cache,
+                     "k": _put_token(ck_all, li, b_idx, pos, k[:, 0]),
+                     "v": _put_token(cv_all, li, b_idx, pos, v[:, 0])}
 
     return cached_attn
+
+
+def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid):
+    """One prefill layer's ``attn_fn`` for ``c.mixer`` (the counterpart of
+    ``_decode_attn``). ``single`` is one slot's cache, ``positions`` the
+    padded prompt's [S], ``kv_valid`` [1, S_max] the slot's rows the
+    prompt fills. Latent attention takes the plain form: per-head keys
+    and values are expanded from the prompt's latents and attended
+    causally over the prompt; what the slot keeps is the latent rows.
+    Returns (output, single with this layer's rows written)."""
+    if c.mixer == "mla":
+        @_latent
+        def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
+            new = {**single,
+                   "ckv": _put_layer(single["ckv"], c_kv[None], li),
+                   "kr": _put_layer(single["kr"], k_r[None, :, :, 0], li)}
+            # the plain form over the prompt alone (q and k are both
+            # nope + rope wide: the scale is causal_attention's own)
+            k, v = mla_expand(c_kv, k_r, wp, c)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            if 4 * q.shape[2] * q.shape[1] ** 2 > PREFILL_SCORE_BYTES:
+                return blocked_causal_attention(q, k, v), new
+            return causal_attention(q, k, v), new
+
+        return cached_attn
+
+    def cached_attn(q, k, v):
+        ck_all, cv_all = single["k"], single["v"]
+        # a row as the cache keeps it: (Hkv, D), or the heads flat
+        k_rows, v_rows = (x.reshape(x.shape[:2] + leaf.shape[3:])
+                          for leaf, x in ((ck_all, k), (cv_all, v)))
+        ck2 = _put_layer(ck_all, k_rows[None], li)
+        cv2 = _put_layer(cv_all, v_rows[None], li)
+        new = {**single, "k": ck2, "v": cv2}
+        if c.window:
+            # as below, and without the prompt's [S, S] scores: tile by
+            # tile (float32 scores of 64 heads at 16,384 are 68 GB)
+            return blocked_causal_attention(q, k, v), new
+        if c.layer_types:
+            # the prompt alone, as the latent form above: a real token
+            # attends nothing past itself, so no padding and none of
+            # the slot's other S_max - S rows (the form below scores
+            # all S_max rows of the slot: 1 GB of scores a layer at 32
+            # heads x 2,048 x 4,096)
+            return causal_attention(q, k, v), new
+        # the layer's rows, the heads as an axis (again, where they lie flat)
+        ck, cv = (lax.dynamic_index_in_dim(leaf, li, 0, keepdims=False
+                                           ).reshape((1, -1) + x.shape[2:])
+                  for leaf, x in ((ck2, k), (cv2, v)))
+        return _attend_prefill(q, ck, cv, positions, kv_valid), new
+
+    return cached_attn
+
+
+def _attend_prefill(q, ck, cv, q_pos, kv_valid):
+    """The prompt's queries q [1,S,H,D] at ``q_pos`` [S] against ALL rows
+    of the slot, ck / cv [1,S_max,Hkv,D], under the causal mask and
+    ``kv_valid`` [1,S_max]: one product, one softmax."""
+    n_rep = q.shape[2] // ck.shape[2]
+    k = repeat_kv(ck, n_rep)
+    v = repeat_kv(cv, n_rep)
+    scale = q.shape[-1] ** -0.5
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * scale
+    k_pos = jnp.arange(k.shape[1])
+    mask = (q_pos[:, None] >= k_pos[None, :])[None] & (
+        kv_valid[:, None, :]
+    )
+    scores = jnp.where(mask[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def _decode_window_attn(cache, li, pos, b_idx, c: TransformerConfig,
@@ -925,13 +1018,10 @@ def _decode_window_attn(cache, li, pos, b_idx, c: TransformerConfig,
     (output, the cache)."""
     def cached_attn(q, k, v, sink):
         state = cache_state(cache)
-        B = q.shape[0]
         with jax.named_scope("raytpu.swa.ring"):
             at = pos % c.window
-            wk = state["wk"].at[li, b_idx, at].set(
-                k[:, 0].reshape(B, -1).astype(state["wk"].dtype))
-            wv = state["wv"].at[li, b_idx, at].set(
-                v[:, 0].reshape(B, -1).astype(state["wv"].dtype))
+            wk = _put_token(state["wk"], li, b_idx, at, k[:, 0])
+            wv = _put_token(state["wv"], li, b_idx, at, v[:, 0])
         with jax.named_scope("raytpu.swa.attend"):
             out = _attend_ring(q, wk, wv, sink, ring_rows(pos, c.window),
                                layer=li, schedule=schedule)
@@ -986,6 +1076,38 @@ def _recurrence(recur):
     return no_attention
 
 
+def _conv_step(conv, li, x, w, b, moves):
+    """One token through a layer's causal convolution on decode: layer
+    ``li``'s tail (the last inputs of every lane, flat in ``conv``
+    [layers, B, (taps - 1) x width]) is taken out of its leaf, convolved
+    with the token ``x`` [B,1,width] under ``w`` [taps, width] and bias
+    ``b`` (or None), moved on one token for the lanes of ``moves`` [B]
+    (None: all) and put back in place. Returns (the convolution's output
+    [B,1,width], ``conv``)."""
+    tail = lax.dynamic_index_in_dim(conv, li, 0, False)
+    tail = tail.reshape(tail.shape[0], w.shape[0] - 1, -1)
+    out = causal_conv(x, w, b, tail)
+    moved = jnp.concatenate([tail[:, 1:], x.astype(tail.dtype)], 1)
+    if moves is not None:
+        moved = jnp.where(moves[:, None, None], moved, tail)
+    return out, lax.dynamic_update_index_in_dim(
+        conv, moved.reshape(moved.shape[0], -1), li, 0)
+
+
+def _conv_prompt(conv, li, x, w, b, prompt_len):
+    """A padded prompt ``x`` [1,S,width] through a layer's causal
+    convolution from an empty tail; what layer ``li`` of ``conv`` is
+    handed is the last ``taps - 1`` REAL inputs (zeros where the prompt
+    is shorter). Returns (the output [1,S,width], ``conv``)."""
+    k1, S = w.shape[0] - 1, x.shape[1]
+    out = causal_conv(x, w, b)
+    at = prompt_len - k1 + jnp.arange(k1)
+    tail = jnp.where((at >= 0)[None, :, None], jnp.take(
+        x, jnp.clip(at, 0, S - 1), axis=1), 0)
+    return out, lax.dynamic_update_index_in_dim(
+        conv, tail.reshape(1, -1).astype(conv.dtype), li, 0)
+
+
 def _decode_recur(cache, li, pos, c: TransformerConfig):
     """One decode layer's ``attn_fn`` for a state-space layer (the
     counterpart of ``_decode_attn``; ``transformer._ssm_mixer`` calls its
@@ -997,17 +1119,13 @@ def _decode_recur(cache, li, pos, c: TransformerConfig):
     the WHOLE [layers, B, ...] leaf, aliased, and reads and writes the
     live lanes' tiles of layer ``li`` where they lie: a state moves once
     each way and nothing slices a layer out. The window's layer is taken
-    out of its leaf and put back in place. Returns (y, the cache)."""
+    out of its leaf and put back in place (``_conv_step``). Returns (y,
+    the cache)."""
     def recur(xbc, dt, wp):
         state = cache_state(cache)
-        k1 = c.ssm_conv - 1
         with jax.named_scope("raytpu.ssm.conv"):
-            tail = lax.dynamic_index_in_dim(state["conv"], li, 0, False)
-            tail = tail.reshape(tail.shape[0], k1, -1)
-            out = causal_conv(xbc, wp["conv_w"], wp["conv_b"], tail)
-            tail = jnp.concatenate([tail[:, 1:], xbc.astype(tail.dtype)], 1)
-            conv = lax.dynamic_update_index_in_dim(
-                state["conv"], tail.reshape(tail.shape[0], -1), li, 0)
+            out, conv = _conv_step(state["conv"], li, xbc, wp["conv_w"],
+                                   wp["conv_b"], None)
         with jax.named_scope("raytpu.ssm.update"):
             x, B, C, A = _ssm_scan_inputs(out, wp, c)
             y, ssm = ssm_update(state["ssm"], li, x[:, 0], dt[:, 0], A,
@@ -1027,20 +1145,14 @@ def _prefill_recur(single, li, prompt_len, c: TransformerConfig):
     one slot's cache. Returns (y, single with this layer's state)."""
     def recur(xbc, dt, wp):
         state = cache_state(single)
-        k1, S = c.ssm_conv - 1, xbc.shape[1]
         with jax.named_scope("raytpu.ssm.conv"):
-            out = causal_conv(xbc, wp["conv_w"], wp["conv_b"])
-            at = prompt_len - k1 + jnp.arange(k1)
-            tail = jnp.where((at >= 0)[None, :, None], jnp.take(
-                xbc, jnp.clip(at, 0, S - 1), axis=1), 0)
-            conv = lax.dynamic_update_index_in_dim(
-                state["conv"], tail.reshape(1, -1).astype(
-                    state["conv"].dtype), li, 0)
+            out, conv = _conv_prompt(state["conv"], li, xbc, wp["conv_w"],
+                                     wp["conv_b"], prompt_len)
         with jax.named_scope("raytpu.ssm.scan"):
             x, B, C, A = _ssm_scan_inputs(out, wp, c)
             y, end = ssm_chunked(
                 x, dt, A, B, C, wp["d"], c.ssm_chunk,
-                valid=(jnp.arange(S) < prompt_len)[None])
+                valid=(jnp.arange(xbc.shape[1]) < prompt_len)[None])
             ssm = lax.dynamic_update_index_in_dim(state["ssm"], end, li, 0)
         return y, {**single, "state": {"ssm": ssm, "conv": conv}}
 
@@ -1057,16 +1169,10 @@ def _decode_kda(cache, li, pos, c: TransformerConfig):
     [layers, B, ...] leaf, aliased, at layer ``li``. Returns (o, the
     cache)."""
     def recur(qkv, g, beta, wp):
-        state = cache_state(cache)
-        k1, live = c.kda_conv - 1, pos > 0
+        state, live = cache_state(cache), pos > 0
         with jax.named_scope("raytpu.kda.conv"):
-            tail = lax.dynamic_index_in_dim(state["conv"], li, 0, False)
-            tail = tail.reshape(tail.shape[0], k1, -1)
-            out = causal_conv(qkv, wp["conv_w"], None, tail)
-            moved = jnp.concatenate([tail[:, 1:], qkv.astype(tail.dtype)], 1)
-            tail = jnp.where(live[:, None, None], moved, tail)
-            conv = lax.dynamic_update_index_in_dim(
-                state["conv"], tail.reshape(tail.shape[0], -1), li, 0)
+            out, conv = _conv_step(state["conv"], li, qkv, wp["conv_w"],
+                                   None, live)
         with jax.named_scope("raytpu.kda.update"):
             q, k, v = kda_split(out[:, 0], c)
             o, kda = kda_update(state["kda"], li, q, k, v, g[:, 0],
@@ -1086,20 +1192,14 @@ def _prefill_kda(single, li, prompt_len, c: TransformerConfig):
     with this layer's state)."""
     def recur(qkv, g, beta, wp):
         state = cache_state(single)
-        k1, S = c.kda_conv - 1, qkv.shape[1]
         with jax.named_scope("raytpu.kda.conv"):
-            out = causal_conv(qkv, wp["conv_w"], None)
-            at = prompt_len - k1 + jnp.arange(k1)
-            tail = jnp.where((at >= 0)[None, :, None], jnp.take(
-                qkv, jnp.clip(at, 0, S - 1), axis=1), 0)
-            conv = lax.dynamic_update_index_in_dim(
-                state["conv"], tail.reshape(1, -1).astype(
-                    state["conv"].dtype), li, 0)
+            out, conv = _conv_prompt(state["conv"], li, qkv, wp["conv_w"],
+                                     None, prompt_len)
             q, k, v = kda_split(out, c)
         with jax.named_scope("raytpu.kda.chunk"):
             o, end = kda_chunked(
                 q, k, v, g, beta, c.kda_chunk,
-                valid=(jnp.arange(S) < prompt_len)[None])
+                valid=(jnp.arange(qkv.shape[1]) < prompt_len)[None])
             kda = lax.dynamic_update_index_in_dim(state["kda"], end, li, 0)
         return o, {**single, "state": {"kda": kda, "conv": conv}}
 
@@ -1138,29 +1238,147 @@ def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     if config.moe_experts and config.moe_impl == "dropless":
         keys += ("moe_assignments", "moe_experts_touched",
                  "moe_experts_capacity", "moe_max_load", "moe_weight_visits")
-    if config.index_topk:
-        keys += ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_live")
-    if config.n_window_layers:
-        keys += ("window_rows_read",)
-    return keys
+    return keys + sum(
+        (row.counters for _kind, row, _n in _kinds_of(config)), ())
 
 
-def _dsa_stats(pos, c: TransformerConfig, s_max: int):
+def _dsa_stats(pos, c: TransformerConfig, n: int, cache):
     """One decode step's selection counters, from the lanes' positions."""
+    s_max = cache["ik"].shape[2]
     live, rows = pos > 0, pos + 1
     chunk = min(DSA_CHUNK, s_max)  # whole chunks, as _decode_choice walks
     bound = jnp.minimum(jnp.max(pos), s_max)
     return {
         "dsa_rows_scored": c.n_index_layers * pos.shape[0] * jnp.minimum(
             (bound + chunk - 1) // chunk * chunk, s_max),
-        "dsa_rows_selected": c.n_layers * jnp.where(
+        "dsa_rows_selected": n * jnp.where(
             live, jnp.minimum(rows, c.index_topk), 0).sum(),
-        "dsa_rows_live": c.n_layers * jnp.where(live, rows, 0).sum(),
+        "dsa_rows_live": n * jnp.where(live, rows, 0).sum(),
     }
+
+
+def _window_stats(pos, c: TransformerConfig, n: int, cache):
+    """The ring rows one decode step's window layers read."""
+    return {"window_rows_read": n * ring_rows(pos, c.window).sum()}
 
 
 def _zero_stats(config: TransformerConfig):
     return {k: jnp.zeros((), jnp.int32) for k in block_stat_keys(config)}
+
+
+# ---------------- the table of layer kinds ----------------
+
+class _Step(NamedTuple):
+    """What one decode step holds for every layer: the lanes' positions
+    ``pos`` [B], ``b_idx`` (arange(B)), and ``visits``: for each kind the
+    model has, the step's schedule for that kind's kernel (``_Kind.
+    visits``), made once and shared by the kind's layers."""
+    pos: jax.Array
+    b_idx: jax.Array
+    visits: Dict
+
+
+class _Prompt(NamedTuple):
+    """What one prefill holds for every layer: the prompt's real length,
+    the padded prompt's ``positions`` [S], and ``kv_valid`` [1, S_max],
+    the slot's rows it fills."""
+    prompt_len: jax.Array
+    positions: jax.Array
+    kv_valid: jax.Array
+
+
+class _Kind(NamedTuple):
+    """One row of ``_KINDS``: all that this module knows of one kind of
+    layer (``transformer.layer_kind``: the key its mixer's weights sit
+    under). A new kind is a row here and the functions it names."""
+    # c -> how many of the model's layers are of this kind
+    layers: Callable
+    # (c, n, batch, max_len) -> (row leaves, state leaves) its n layers
+    # keep for ``batch`` slots (``init_kv_cache``)
+    keeps: Callable
+    # (cache, li, lp, c, step, choice) -> a decode layer's ``attn_fn``
+    decode: Callable
+    # (single, li, lp, c, prompt, choice) -> a prefill layer's ``attn_fn``
+    prefill: Callable
+    # (pos, c, cache) -> the step's schedule for its decode kernel
+    visits: Optional[Callable] = None
+    # its int32 counters (``block_stat_keys``), and (pos, c, n, cache) ->
+    # what one decode step adds to them, from the lanes' positions
+    counters: Tuple[str, ...] = ()
+    counts: Optional[Callable] = None
+    # the layers that attend every row only. (c, queries, rows) -> what
+    # the layer scan's carry hands from layer to layer, or None;
+    # (c, s_max) -> rows of a slot one visit of the decode attention
+    # reads; whether it walks EVERY slot up to the longest lane
+    # (``attn_rows_read``: the host's count)
+    hands_on: Optional[Callable] = None
+    chunk: Optional[Callable] = None
+    walks_longest: bool = False
+
+
+# In the order a decode step makes the kinds' visits (a window model's
+# programs make the rings' before the rows').
+_KINDS = {
+    "ssm": _Kind(
+        layers=lambda c: c.n_ssm_layers, keeps=_ssm_keeps,
+        decode=lambda cache, li, lp, c, s, choice: _decode_recur(
+            cache, li, s.pos, c),
+        prefill=lambda single, li, lp, c, p, choice: _prefill_recur(
+            single, li, p.prompt_len, c)),
+    "kda": _Kind(
+        layers=lambda c: c.n_kda_layers, keeps=_kda_keeps,
+        decode=lambda cache, li, lp, c, s, choice: _decode_kda(
+            cache, li, s.pos, c),
+        prefill=lambda single, li, lp, c, p, choice: _prefill_kda(
+            single, li, p.prompt_len, c)),
+    "swa": _Kind(
+        layers=lambda c: c.n_window_layers, keeps=_window_keeps,
+        decode=lambda cache, li, lp, c, s, choice: _decode_window_attn(
+            cache, li, s.pos, s.b_idx, c, s.visits["swa"]),
+        prefill=lambda single, li, lp, c, p, choice: _prefill_window_attn(
+            single, li, p.prompt_len, c),
+        # the rings' visits: one a lane
+        visits=lambda pos, c, cache: slot_schedule(
+            ring_rows(pos, c.window), c.window, c.window),
+        counters=("window_rows_read",), counts=_window_stats),
+    "attn": _Kind(
+        layers=lambda c: c.n_attn_layers, keeps=_attn_keeps,
+        decode=lambda cache, li, lp, c, s, choice: _decode_attn(
+            cache, li, s.pos, s.b_idx, c, s.visits["attn"]),
+        prefill=lambda single, li, lp, c, p, choice: _prefill_attn(
+            single, li, c, p.positions, p.kv_valid),
+        visits=lambda pos, c, cache: _visits(
+            pos, jax.tree.leaves(cache_rows(cache))),
+        hands_on=lambda c, queries, rows: None, chunk=_dense_chunk),
+}
+# the "attn" row of a latent block with an indexer (``c.index_topk``)
+_CHOSEN = _Kind(
+    layers=lambda c: c.n_attn_layers, keeps=_chosen_keeps,
+    decode=lambda cache, li, lp, c, s, choice: _decode_attn_chosen(
+        cache, li, s.pos, s.b_idx, c, lp["attn"], choice),
+    prefill=lambda single, li, lp, c, p, choice: _prefill_attn_chosen(
+        single, li, lp["attn"], choice, c),
+    counters=("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_live"),
+    counts=_dsa_stats,
+    hands_on=lambda c, queries, rows: {
+        "mask": jnp.zeros((queries, rows), bool),
+        "k": jnp.zeros((queries, c.index_head_dim), c.dtype)},
+    chunk=lambda c, s_max: min(DSA_CHUNK, s_max), walks_longest=True)
+
+
+def _row(kind: str, c: TransformerConfig) -> _Kind:
+    """The table's row for a layer of ``kind`` in ``c``'s model."""
+    return _CHOSEN if kind == "attn" and c.index_topk else _KINDS[kind]
+
+
+def _kinds_of(c: TransformerConfig):
+    """(kind, its row, how many of the model's layers are of it) for the
+    kinds ``c``'s model has, in the table's order. The layers that attend
+    every row are always among them: their row leaves give a slot its
+    length."""
+    found = ((kind, _row(kind, c)) for kind in _KINDS)
+    return [(kind, row, row.layers(c)) for kind, row in found
+            if kind == "attn" or row.layers(c)]
 
 
 def _decode_forward_multi(params, token, cache, pos,
@@ -1176,30 +1394,20 @@ def _decode_forward_multi(params, token, cache, pos,
     routed = c.moe_experts and c.moe_impl == "dropless"
     live = (pos > 0)[:, None] if routed else None
     B = token.shape[0]
-    b_idx = jnp.arange(B)
-    choice = schedule = rings = None
-    if c.n_window_layers:  # the rings' visits: one a lane, every layer's
-        rings = slot_schedule(ring_rows(pos, c.window), c.window, c.window)
-    if c.index_topk:  # a block with an indexer: what the layers hand on
-        choice = {"mask": jnp.zeros((B, cache["ik"].shape[2]), bool),
-                  "k": jnp.zeros((B, c.index_head_dim), c.dtype)}
-    else:  # the attention's visits, the same for every layer of the step
-        schedule = _visits(pos, jax.tree.leaves(cache_rows(cache)))
+    kinds = _kinds_of(c)
+    # each kind's visits of its kernel, the same for every layer of the
+    # step, and what a block with an indexer's layers hand on
+    step = _Step(pos, jnp.arange(B), {
+        kind: row.visits and row.visits(pos, c, cache)
+        for kind, row, _n in kinds})
+    choice = _row("attn", c).hands_on(
+        c, B, jax.tree.leaves(cache_rows(cache))[0].shape[2])
     carry = (x, cache, _zero_stats(c), choice)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
             x, cache, total, choice = carry
-            if "ssm" in lp:
-                attn = _decode_recur(cache, li, pos, lc)
-            elif "kda" in lp:
-                attn = _decode_kda(cache, li, pos, lc)
-            elif "swa" in lp:
-                attn = _decode_window_attn(cache, li, pos, b_idx, lc, rings)
-            elif choice is None:
-                attn = _decode_attn(cache, li, pos, b_idx, lc, schedule)
-            else:
-                attn = _decode_attn_chosen(
-                    cache, li, pos, b_idx, lc, lp["attn"], choice)
+            attn = _row(layer_kind(lp), lc).decode(
+                cache, li, lp, lc, step, choice)
             y, _aux, cache, stats = apply_block(
                 x, lp, lc, pos[:, None], attn, token_mask=live)
             if choice is not None:
@@ -1208,13 +1416,9 @@ def _decode_forward_multi(params, token, cache, pos,
 
         carry = scan_stack(layer, carry, stack, lc, first)
     x, cache, stats, _choice = carry
-    if c.index_topk:
-        stats = _add_stats(stats, _dsa_stats(
-            pos, c, cache["ik"].shape[2]))
-    if c.n_window_layers:
-        stats = _add_stats(stats, {
-            "window_rows_read": c.n_window_layers * ring_rows(
-                pos, c.window).sum()})
+    for _kind, row, n in kinds:
+        if row.counts:
+            stats = _add_stats(stats, row.counts(pos, c, n, cache))
     return lm_logits(params, x, c)[:, 0, :], cache, stats
 
 
@@ -1331,87 +1535,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     routed = c.moe_experts and c.moe_impl == "dropless"
     real = (positions < prompt_len)[None] if routed else None
 
-    def slot_attn(single, li):
-        if c.mixer == "mla":
-            @_latent
-            def cached_attn(q_nope, q_rope, c_kv, k_r, wp):
-                new = {
-                    **single,
-                    "ckv": lax.dynamic_update_slice(
-                        single["ckv"], c_kv[None].astype(
-                            single["ckv"].dtype), (li, 0, 0, 0)),
-                    "kr": lax.dynamic_update_slice(
-                        single["kr"], k_r[None, :, :, 0].astype(
-                            single["kr"].dtype), (li, 0, 0, 0))}
-                # the plain form over the prompt alone (q and k are both
-                # nope + rope wide: the scale is causal_attention's own)
-                k, v = mla_expand(c_kv, k_r, wp, c)
-                q = jnp.concatenate([q_nope, q_rope], -1)
-                if 4 * q.shape[2] * S * S > PREFILL_SCORE_BYTES:
-                    return blocked_causal_attention(q, k, v), new
-                return causal_attention(q, k, v), new
-
-            return cached_attn
-
-        def cached_attn(q, k, v):
-            ck_all, cv_all = single["k"], single["v"]
-            if ck_all.ndim == 4:  # the heads lie flat in their row
-                k_rows, v_rows = (x.reshape(x.shape[:2] + (-1,))
-                                  for x in (k, v))
-            else:
-                k_rows, v_rows = k, v
-            ck2 = lax.dynamic_update_slice(
-                ck_all, k_rows[None].astype(ck_all.dtype),
-                (li,) + (0,) * (ck_all.ndim - 1)
-            )
-            cv2 = lax.dynamic_update_slice(
-                cv_all, v_rows[None].astype(cv_all.dtype),
-                (li,) + (0,) * (cv_all.ndim - 1)
-            )
-            if c.window:
-                # as below, and without the prompt's [S, S] scores: tile by
-                # tile (float32 scores of 64 heads at 16,384 are 68 GB)
-                return blocked_causal_attention(q, k, v), {
-                    **single, "k": ck2, "v": cv2}
-            if c.layer_types:
-                # the prompt alone, as the latent form above: a real token
-                # attends nothing past itself, so no padding and none of
-                # the slot's other S_max - S rows (the form below scores
-                # all S_max rows of the slot: 1 GB of scores a layer at 32
-                # heads x 2,048 x 4,096)
-                return causal_attention(q, k, v), {
-                    **single, "k": ck2, "v": cv2}
-            ck = lax.dynamic_index_in_dim(ck2, li, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cv2, li, 0, keepdims=False)
-            if ck.ndim == 3:  # flat rows: the heads as an axis again
-                ck, cv = (x.reshape(x.shape[:2] + k.shape[2:])
-                          for x in (ck, cv))
-            return _attend_prefill(q, ck, cv, positions, kv_valid), {
-                "k": ck2, "v": cv2
-            }
-
-        return cached_attn
-
-    def _attend_prefill(q, ck, cv, q_pos, kv_valid_b):
-        n_rep = q.shape[2] // ck.shape[2]
-        k = repeat_kv(ck, n_rep)
-        v = repeat_kv(cv, n_rep)
-        scale = q.shape[-1] ** -0.5
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-        ) * scale
-        k_pos = jnp.arange(k.shape[1])
-        mask = (q_pos[:, None] >= k_pos[None, :])[None] & (
-            kv_valid_b[:, None, :]
-        )
-        scores = jnp.where(mask[:, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-
-    choice = None
-    if c.index_topk:
-        choice = {"mask": jnp.zeros((S, S), bool),
-                  "k": jnp.zeros((S, c.index_head_dim), c.dtype)}
+    prompt_holds = _Prompt(prompt_len, positions, kv_valid)
+    choice = _row("attn", c).hands_on(c, S, S)
     # what an admission reports of its routed layers (none: an empty dict)
     routed_stats = {k: jnp.zeros((), jnp.int32)
                     for k in prefill_stat_keys(c)}
@@ -1419,17 +1544,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
             x, single, choice, total = carry
-            if "ssm" in lp:
-                attn = _prefill_recur(single, li, prompt_len, lc)
-            elif "kda" in lp:
-                attn = _prefill_kda(single, li, prompt_len, lc)
-            elif "swa" in lp:
-                attn = _prefill_window_attn(single, li, prompt_len, lc)
-            elif choice is None:
-                attn = slot_attn(single, li)
-            else:
-                attn = _prefill_attn_chosen(
-                    single, li, lp["attn"], choice, lc)
+            attn = _row(layer_kind(lp), lc).prefill(
+                single, li, lp, lc, prompt_holds, choice)
             y, _aux, single, stats = apply_block(
                 x, lp, lc, positions, attn, token_mask=real)
             if choice is not None:

@@ -983,36 +983,29 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
     return axes
 
 
+# where ``init_params`` puts the stack of each kind ``layer_types`` names
+_KIND_STACKS = {"attention": "layers", "ssm": "ssm_layers",
+                "window": "window_layers", "kda": "kda_layers"}
+
+
 def layer_groups(params: Dict, config: TransformerConfig):
     """The stacks of layers in the order they run, each as (stacked
     weights, the config of that stack's block, index of its first layer):
     the leading dense layers where the model has them, then the rest. Each
-    stack is one ``lax.scan``. A model with layers of two kinds
-    (``config.layer_types``) is ONE group whose "stack" holds both kinds'
-    stacks, ``{"attention": .., "ssm": ..}``: ``scan_stack`` runs them in
-    the listed order. With "window" layers the stacks are ``{"attention":
-    .., "window": ..}`` and the leading dense layers, all of kind
-    "attention", a group of their own before them; with "kda" layers
-    ``{"attention": .., "kda": ..}`` and the leading dense layers, of
-    whichever ONE kind they are, a group of their own."""
+    stack is one ``lax.scan``. A model with layers of several kinds
+    (``config.layer_types``) is ONE group whose "stack" holds each kind's
+    stack under its name there, ``{"attention": .., "ssm": ..}``
+    (``_KIND_STACKS``): ``scan_stack`` runs them in the listed order. Its
+    leading dense layers, of whichever ONE kind they are, are a group of
+    their own before it."""
     n_dense = config.n_dense_layers if config.moe_experts else 0
-    if config.n_ssm_layers:
-        return [({"attention": params["layers"],
-                  "ssm": params["ssm_layers"]}, config, 0)]
+    dense, rest = params.get("dense_layers"), params["layers"]
     if config.layer_types:
-        lead = [({config.layer_types[0]: params["dense_layers"]},
-                 config.dense_variant(), 0)] if n_dense else []
-        rest = {"attention": params["layers"]}
-        if config.n_window_layers:
-            rest["window"] = params["window_layers"]
-        if "kda_layers" in params:
-            rest["kda"] = params["kda_layers"]
-        return lead + [(rest, config, n_dense)]
-    groups = []
-    if n_dense:
-        groups.append((params["dense_layers"], config.dense_variant(), 0))
-    groups.append((params["layers"], config, n_dense))
-    return groups
+        dense = {config.layer_types[0]: dense}
+        rest = {kind: params[name] for kind, name in _KIND_STACKS.items()
+                if name in params}
+    lead = [(dense, config.dense_variant(), 0)] if n_dense else []
+    return lead + [(rest, config, n_dense)]
 
 
 _EXPERT_WEIGHTS = ("wg", "wi", "wo")
@@ -1453,7 +1446,26 @@ def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     return a, extra
 
 
-_MIXERS = {"mha": _mha_mixer, "mla": _mla_mixer}
+def _attn_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """An "attention" layer's mixer: ``c.mixer``'s."""
+    return {"mha": _mha_mixer, "mla": _mla_mixer}[c.mixer](
+        h, wp, c, positions, attn_fn)
+
+
+# A layer's KIND as the program sees it is the key its mixer's weights sit
+# under in ``lp`` (``init_params``); a "swa" layer's ``attn_fn`` is a
+# window's.
+_MIXERS = {"attn": _attn_mixer, "ssm": _ssm_mixer, "kda": _kda_mixer,
+           "swa": partial(_mha_mixer, window=True)}
+
+
+def layer_kind(lp: Dict) -> str:
+    """The kind of the layer whose weights are ``lp``: the one place that
+    tells the kinds apart (``_MIXERS``, and ``generation``'s table of
+    what each keeps of a slot and how it decodes and prefills)."""
+    return next(kind for kind in _MIXERS if kind in lp)
+
+
 _ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
 
 
@@ -1484,14 +1496,8 @@ def apply_block(
     int32 scalars from a dropless routed layer (else empty)."""
     c = config
     h = _rms_norm(x, lp["ln1"]["scale"], c.norm_eps)
-    if "ssm" in lp:  # a layer of the other kind (TransformerConfig.layer_types)
-        a, extra = _ssm_mixer(h, lp["ssm"], c, positions, attn_fn)
-    elif "kda" in lp:
-        a, extra = _kda_mixer(h, lp["kda"], c, positions, attn_fn)
-    elif "swa" in lp:  # a window layer: ``attn_fn`` is a window's
-        a, extra = _mha_mixer(h, lp["swa"], c, positions, attn_fn, True)
-    else:
-        a, extra = _MIXERS[c.mixer](h, lp["attn"], c, positions, attn_fn)
+    kind = layer_kind(lp)
+    a, extra = _MIXERS[kind](h, lp[kind], c, positions, attn_fn)
     if c.residual_scale != 1.0:
         a = a * c.residual_scale
     if c.residual == "sequential":
@@ -1573,7 +1579,8 @@ def forward(
             x, aux = carry
             y, a, _ = apply_layer(
                 x, lp, lc, positions,
-                window_fn if "swa" in lp else attn_fn, mesh=mesh)
+                window_fn if layer_kind(lp) == "swa" else attn_fn,
+                mesh=mesh)
             return (y, aux + a), None
 
         layer = remat_wrap(layer, c)

@@ -626,11 +626,7 @@ class LLMEngine:
         request owned each slot at dispatch time, and the block's ordinal
         since the engine started. K adapts to load (see __init__): light
         load -> short blocks -> short admission waits."""
-        from ray_tpu.models.generation import (
-            attn_rows_walked,
-            decode_attn_chunk,
-            decode_block,
-        )
+        from ray_tpu.models.generation import attn_rows_read, decode_block
 
         live = [r for r in self.slot_req
                 if r is not None and not r.finished]
@@ -663,14 +659,8 @@ class LLMEngine:
         self._blocks_by_steps[steps] += 1
         self._n["slot_steps"] += active * steps
         self._n["capacity_steps"] += self.max_slots * steps
-        # what the attention reads: each live slot's chunks up to its own
-        # length (a block with an indexer: every slot's, up to the longest)
-        chunk = decode_attn_chunk(self.config, self.max_len)
-        lanes = ([bound] * self.max_slots if self.config.index_topk
-                 else [r for r in self._rows if r])
-        self._n["attn_rows_read"] += sum(
-            attn_rows_walked(r + k, self.max_len, chunk)
-            for r in lanes for k in range(steps))
+        self._n["attn_rows_read"] += attn_rows_read(
+            self.config, self._rows, steps, self.max_len)
         self._n["attn_rows_capacity"] += self.max_slots * self.max_len * steps
         # decode_block moves the states of the lanes it finds at pos > 0;
         # a parked lane's stays where it lies

@@ -96,6 +96,30 @@ def pytest_configure(config):
     )
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """``--dist loadfile`` (the driver's tier-1 command) makes a file one
+    work unit. ``tests/test_served_models.py`` holds one body a claim for
+    ALL served models, and every model compiles programs of its own: as
+    one unit it would take one worker as long as the five files it took
+    the place of took five. Its cases are units by (file, model) instead:
+    a case's id begins with its model's name. Every other file, and
+    every other ``--dist``, is scheduled as xdist does."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class ByFileAndServedModel(LoadFileScheduling):
+        def _split_scope(self, nodeid):
+            scope = super()._split_scope(nodeid)
+            if scope.endswith("test_served_models.py") and "[" in nodeid:
+                case = nodeid.split("[", 1)[1]
+                scope += "[" + case.replace("]", "-").split("-", 1)[0] + "]"
+            return scope
+
+    return ByFileAndServedModel(config, log)
+
+
 @pytest.fixture
 def rehearsal_manifest(tmp_path):
     """``make(traffic, rate_rps)`` -> a ``--manifest`` for ``benchmarks/

@@ -40,11 +40,18 @@ def _masked_full_row_attention(q, ck, cv, k_new, v_new, pos, scale=None):
 S_MAX, CHUNK = 72, 16  # 72 = 4 x 16 + 8: the last chunk starts early
 
 
+def _schedule(pos):
+    """The step's visits in chunks of this test's own size."""
+    from ray_tpu.ops.decode_attention import slot_schedule
+
+    return slot_schedule(pos, S_MAX, CHUNK)
+
+
 def _dense_case(kv_heads, pos):
-    """(attend, plain): MHA / GQA over k and v [B,S_MAX,Hkv,D]. ``attend``
-    takes the cache's arrays (``stacked``: each [L,B,S_MAX,...] with
-    ``layer`` naming the one to read, else one layer's) and returns the
-    kernel's output; ``plain`` is what it must equal."""
+    """(attend, cache, plain): MHA / GQA over k and v [B,S_MAX,Hkv,D].
+    ``attend`` takes the cache's arrays, each stacked [L,B,S_MAX,...],
+    and ``layer``, the one to read, and returns the kernel's output;
+    ``plain`` is what it must equal."""
     from ray_tpu.models.generation import _attend_prefix_plus_self
 
     B, H, D = len(pos), 4, 8
@@ -56,9 +63,10 @@ def _dense_case(kv_heads, pos):
     k_new = jax.random.normal(ks[3], (B, 1, kv_heads, D), bf)
     v_new = jax.random.normal(ks[4], (B, 1, kv_heads, D), bf)
 
-    def attend(ck, cv, layer=None):
+    def attend(ck, cv, layer):
         return _attend_prefix_plus_self(
-            q, ck, cv, k_new, v_new, pos, layer=layer, chunk=CHUNK)
+            q, ck, cv, k_new, v_new, pos, layer=layer,
+            schedule=_schedule(pos))
 
     return attend, cache, _masked_full_row_attention(
         q, *cache, k_new, v_new, pos)
@@ -82,12 +90,10 @@ def _latent_case(pos):
     r_new = jax.random.normal(ks[5], (B, P), bf)
     scale = 0.2
 
-    def attend(ckv, kr, layer=None):
-        if layer is None:
-            ckv, kr, layer = ckv[None], kr[None], 0
+    def attend(ckv, kr, layer):
         return _attend_latent_prefix_plus_self(
             q_lat, q_rope, ckv, kr, c_new, r_new, pos, layer=layer,
-            scale=scale, chunk=CHUNK)[:, None]
+            scale=scale, schedule=_schedule(pos))[:, None]
 
     def one_head(c, r):  # [..., R], [..., P] -> key [..., 1, R + P]
         return jnp.concatenate([c, r], -1)[..., None, :]
@@ -110,7 +116,7 @@ def test_chunked_attention_equals_masked_full_rows(form, pos):
     attend, cache, want = (
         _latent_case(pos) if form == "latent"
         else _dense_case({"mha": 4, "gqa": 2}[form], pos))
-    got = attend(*cache)
+    got = attend(*(a[None] for a in cache), layer=0)
     assert got.dtype == jnp.bfloat16 and got.shape == want.shape
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want), atol=2e-2, rtol=2e-2)
@@ -124,7 +130,8 @@ def test_chunked_attention_equals_masked_full_rows(form, pos):
     past = jnp.arange(S_MAX)[None, :] >= pos[:, None]
     got_g = attend(*(
         jnp.where(past.reshape(past.shape + (1,) * (a.ndim - 2)), g, a
-                  ).astype(a.dtype) for a, g in zip(cache, (1e4, -1e4))))
+                  ).astype(a.dtype)[None]
+        for a, g in zip(cache, (1e4, -1e4))), layer=0)
     np.testing.assert_array_equal(np.asarray(got_g), np.asarray(got))
 
 

@@ -1,19 +1,20 @@
 """A model of gated-delta-rule linear-attention layers ("kda") and latent
 attention layers under a share of routed experts and a shared one
 (Kimi-Linear's kind) against its plain reference, at test size on the CPU
-with seeded random weights: the uncached forward, the engine's two
-programs through a slot's state, convolution tails and latent rows for
-prompts below, at and above a chunk and a bucket, the three forms of the
-delta rule against each other, a padded bucket's state, a parked lane,
-mixed lanes in one engine batch, the quarter shares of a routed layer
-against the uncut layer, the ablations a comparison must refuse,
-ill-formed ``layer_types``, the two copies of the reference, and the
-benchmark's new cell resolved and rehearsed."""
+with seeded random weights, what is this model's own: the three forms of
+the delta rule against each other, a padded bucket's state, a parked
+lane, mixed lanes in one engine batch, a slot skipped for several blocks,
+the quarter shares of a routed layer against the uncut layer, ill-formed
+``layer_types``, and the benchmark's arithmetic.
+
+What it shares with the other served models
+(the parameter tree, the uncached forward, the two programs through a
+slot, ``generate``, the ablations, the reference's two copies, the cell's
+listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -26,9 +27,7 @@ from ray_tpu.models import generation as gen
 from ray_tpu.models import reference_kda as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    forward,
     init_params,
-    param_logical_axes,
 )
 from ray_tpu.ops.kda import kda_chunked, kda_step, kda_update
 from ray_tpu.ops.moe import routed_ffn
@@ -100,23 +99,6 @@ def test_config_follows_the_published_numbers():
         "state_layers": 6}
     keys = gen.block_stat_keys(cut)
     assert "moe_experts_touched" in keys and "window_rows_read" not in keys
-
-
-def test_params_axes_and_count_agree(params):
-    axes = param_logical_axes(CFG)
-    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(
-        x, tuple)) == jax.tree.structure(params)
-    for a, p in zip(jax.tree.leaves(axes, is_leaf=lambda x: isinstance(
-            x, tuple)), jax.tree.leaves(params)):
-        assert len(a) == p.ndim
-    assert CFG.param_count() == sum(p.size for p in jax.tree.leaves(params))
-    assert set(params["dense_layers"]) == {"ln1", "ln2", "kda", "mlp"}
-    assert set(params["kda_layers"]) == {"ln1", "ln2", "kda", "moe"}
-    assert set(params["layers"]) == {"ln1", "ln2", "attn", "moe"}
-    assert params["kda_layers"]["kda"]["wqkv"].shape == (3, 64, 3 * 32)
-    assert params["layers"]["attn"]["wq"].shape == (2, 64, 4, 20)
-    assert "wdq" not in params["layers"]["attn"]
-    assert "shared" in params["layers"]["moe"]
 
 
 def test_the_decay_is_drawn_to_differ_by_channel(params):
@@ -329,57 +311,7 @@ def test_a_padded_buckets_end_state_is_the_state_at_prompt_len():
     assert float(jnp.abs(at_end - exact).max()) > 1e-2
 
 
-# -- the forward and the two programs against the reference ------------------
-
-def test_the_uncached_forward_matches_the_reference(params):
-    toks = tokens_of(37)
-    got = forward(params, toks[None], CFG)[0]
-    assert float(jnp.abs(got - ref_logits(params, toks)[0]).max()) < TOL
-
-
-@pytest.mark.parametrize("prompt_len,bucket", [
-    (2, 8), (5, 8), (8, 8), (13, 16), (21, 32), (32, 32), (43, 64),
-    (37, 48)],
-    ids=["under_the_taps", "below_a_chunk", "a_chunk", "above", "chunks",
-         "a_bucket", "many", "scores_too_large_for_one_product"])
-def test_admission_and_decode_through_a_slot_match_the_reference(
-        params, prompt_len, bucket, monkeypatch):
-    """A padded prompt into slot 1 of 2, then 12 decode steps, slot 0
-    parked: every step's logits against the reference's full forward over
-    prompt + answer, and every "kda" layer's state at the end against the
-    reference's. The last bucket's full layers attend tile by tile, as a
-    prompt whose scores pass ``PREFILL_SCORE_BYTES`` does."""
-    if bucket == 48:
-        monkeypatch.setattr(gen, "PREFILL_SCORE_BYTES", 0)
-    n_new = 12
-    toks = tokens_of(prompt_len + n_new, seed=3)
-    want, want_states = ref_logits(params, toks)
-    cache = gen.init_kv_cache(CFG, 2, 96)
-    lg, cache = prefill(params, cache, 1, toks[:prompt_len], bucket)
-    worst = float(jnp.abs(lg - want[prompt_len - 1]).max())
-    for t in range(prompt_len, prompt_len + n_new):
-        tok = jnp.zeros(2, jnp.int32).at[1].set(toks[t])
-        pos = jnp.zeros(2, jnp.int32).at[1].set(t)
-        lg, cache = gen.decode_step_multi(params, tok, cache, pos, CFG)
-        worst = max(worst, float(jnp.abs(lg[1] - want[t]).max()))
-    assert worst < TOL
-    got_states = gen.cache_state(cache)["kda"][:, 1]
-    for got, state in zip(got_states, want_states):
-        assert float(ref.state_distance(got, state)) < 1e-4
-    assert not np.asarray(gen.cache_state(cache)["kda"][:, 0]).any()
-
-
-def test_prefill_leaves_the_other_slots_bit_identical(params):
-    cache = gen.init_kv_cache(CFG, 3, 64)
-    _, cache = prefill(params, cache, 1, tokens_of(9, 2), 16)
-    before = jax.tree.map(lambda a: np.asarray(a[:, 1]), cache)
-    _, cache = prefill(params, cache, 0, tokens_of(14, 3), 16)
-    _, cache = prefill(params, cache, 2, tokens_of(5, 4), 16)
-    after = jax.tree.map(lambda a: np.asarray(a[:, 1]), cache)
-    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
-        assert np.array_equal(a, b)
-    assert float(jnp.abs(gen.cache_state(cache)["kda"][:, 1]).max()) > 0
-
+# -- a parked lane ----------------------------------------------------------
 
 def test_a_parked_lanes_state_and_rows_do_not_change_while_others_step(
         params):
@@ -461,17 +393,6 @@ def test_engine_serves_mixed_lanes_end_to_end(params):
         eng.shutdown()
 
 
-def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(params):
-    eng = engine_of(params)
-    try:
-        p, q = np.asarray(tokens_of(17, 7)), np.asarray(tokens_of(6, 8))
-        eng.generate(p, max_new_tokens=9)  # slot 0's state full, then freed
-        again = eng.generate(q, max_new_tokens=8)
-    finally:
-        eng.shutdown()
-    assert worst_margin(params, q, again) < TOL
-
-
 def test_a_slot_skipped_for_several_blocks_serves_as_a_fresh_one(params):
     """Slot 1 holds what a request left, then stays parked (its states
     skipped) while slot 0 decodes for several blocks; the request then
@@ -502,14 +423,6 @@ def test_a_slot_skipped_for_several_blocks_serves_as_a_fresh_one(params):
     finally:
         fresh.shutdown()
     assert worst_margin(params, r, again) < TOL
-
-
-def test_generate_runs_the_served_programs(params):
-    prompt = jnp.stack([tokens_of(11, 3), tokens_of(11, 4)])
-    ids = gen.generate(params, prompt, CFG, max_new_tokens=12, max_len=32)
-    for b in range(2):
-        assert worst_margin(params, np.asarray(prompt[b]),
-                            np.asarray(ids[b]).tolist()) < TOL
 
 
 # -- a chip's share of a routed layer ----------------------------------------
@@ -614,59 +527,7 @@ def test_a_quarter_shares_loop_brings_rows_back_by_product(monkeypatch,
     assert text.count("scatter") == scatters_s
 
 
-# -- what a comparison must refuse -------------------------------------------
-
-@pytest.mark.parametrize("ablate", [
-    {"head_decay": True}, {"no_delta": True}, {"decay_after": True},
-    {"beta_one": True}, {"no_l2norm": True}, {"silu_gate": True},
-    {"state_bf16": True}, {"state_at_bucket_end": (21, 32)},
-    {"drop_conv_tail": 21}, {"rotate_kr": True}, {"no_scale": True},
-    {"no_shared": True}, {"fp8_weights": True},
-], ids=lambda a: next(iter(a)))
-def test_each_ablation_fails_the_comparison(params, ablate):
-    """The served path (prefill of 21 tokens in a bucket of 32, then 12
-    decode steps) equals the reference and differs from each wrong one:
-    by the logits, or (a state kept in bf16) by the last layer's state."""
-    n, n_new = 21, 12
-    toks = tokens_of(n + n_new, seed=11)
-    cache = gen.init_kv_cache(CFG, 1, 64)
-    _, cache = prefill(params, cache, 0, toks[:n], 32)
-    pos = jnp.array([n], jnp.int32)
-    for t in range(n, n + n_new):
-        lg, cache = gen.decode_step_multi(
-            params, toks[t][None], cache, pos, CFG)
-        pos = pos + 1
-    got_state = gen.cache_state(cache)["kda"][-1, 0]
-
-    def distance(**kw):
-        want, states = ref_logits(params, toks, **kw)
-        return max(float(ref.vector_distance(lg[0], want[-1])[1]),
-                   float(ref.state_distance(got_state, states[-1])))
-
-    assert distance() < TOL < 1e-3 < distance(ablate=ablate)
-
-
-def test_reference_copies_are_identical_below_their_headers():
-    marker = "# ---- below this line the two copies are identical ----\n"
-
-    def body(path):
-        with open(os.path.join(ROOT, path)) as f:
-            text = f.read()
-        assert text.count(marker) == 1
-        return text.split(marker)[1]
-
-    mine = body("ray_tpu/models/reference_kda.py")
-    assert mine == body("benchmarks/reference_kda_moe.py")
-    for name in ("ray_tpu", "generation", "transformer", "ops."):
-        assert name not in mine  # none of the program's code
-
-
-# -- the benchmark resolves and rehearses the new cell -----------------------
-
-CELL = "serve-kimi-longreason-saturated"
-NEW = ("model.kda_time_share", "model.prefill_kda_chunk_share",
-       "engine.state_live_share.kda", "kernel.decode_hbm_share.kda_moe",
-       "kernel.kda_update_roofline_share")
+# -- the benchmark's arithmetic ----------------------------------------------
 
 
 def test_the_benchmarks_arithmetic_agrees_with_the_program():
@@ -704,57 +565,3 @@ def test_the_benchmarks_arithmetic_agrees_with_the_program():
     assert tiny.layer_types == ("kda", "kda", "kda", "attention", "kda",
                                 "attention")
     assert (tiny.moe_experts, tiny.experts_held) == (8, 2)
-
-
-def test_the_list_resolves_the_new_cell():
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--list"], cwd=ROOT,
-        capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(line) for line in out.stdout.splitlines()]
-    assert len(rows) >= 9  # later PRs add cells
-    row = next(r for r in rows if r["cell"] == CELL)
-    assert (row["runner"], row["traffic"], row["chips"]) == (
-        "serve_kda_moe", "longreason-saturated", 1)
-    assert row["end_to_end"] == ["tpot_p50_ms", "setup_s"]
-    for name in NEW + ("model.decode_step_ms", "device.idle_share.serve",
-                       "engine.kv_read_share", "model.moe_time_share",
-                       "model.mla_time_share"):
-        assert name in row["per_layer"]
-
-
-@pytest.mark.phase_limit(900)
-def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    mine = [m["name"] for m in doc["per_layer"]
-            if CELL in m.get("workloads", ())]
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
-         str(2 ** 31 + 7), "--seconds", "6", "--trace", "1",
-         "--rehearse-cpu"],
-        cwd=ROOT, capture_output=True, text=True, timeout=900,
-        # the suite's eight virtual host devices are not the cell's one
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
-    assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]
-    walked = next(line for line in out.stdout.splitlines()
-                  if line.startswith("readers walked"))
-    values = json.loads(walked.split(": ", 1)[1])
-    assert sorted(values) == sorted(mine)
-    # the rehearsal's engine: 4 kda layers (the metric's scale is the
-    # cell's 6), and the states moved are the live lanes': 100 x 6 / 4
-    share = values["engine.state_live_share.kda"]
-    assert share is not None and abs(share - 150) < 1e-6
-    assert 0 < values["engine.state_skip_share"] < 100
-    note = json.loads(next(line for line in out.stdout.splitlines()
-                           if line.startswith('{"note"')))
-    end = note["note"]["backlog"]["end"]
-    assert end["slot_state_bytes"] == 4 * (2 * 16 * 16 * 4 + 3 * 3 * 32 * 2)
-    assert end["slot_row_bytes"] == 2 * (16 + 8) * 2
-    assert (end["state_slots_updated"] + end["state_slots_skipped"]
-            == 4 * end["capacity_steps"])
-    assert end["slot_steps"] * 4 <= end["state_slots_updated"]
-    assert end["attn_rows_read"] > 0 and end["moe_assignments"] > 0
-    probe = note["note"]["probe"]
-    assert probe["replayed"] and probe["refused_by"] == []
-    assert probe["kda_layer"]["step_median"] < 0.05

@@ -1,14 +1,14 @@
 """The latent-attention / routed-experts block (GLM-4.7-Flash's) against
 its plain reference, at test size on the CPU with seeded random weights:
-the engine's prefill + decode through the latent cache, the absorbed
-decode attention, the dropless routed layer, the omissions a comparison
-must refuse, the two copies of the reference, the engine's new counters
-and the new cell's rehearsal."""
+what is this block's own: the engine serving the reference's tokens, the
+absorbed decode attention, the dropless routed layer, a bf16 router told
+by the layer alone, and the engine's counters.
 
-import json
-import os
-import subprocess
-import sys
+What it shares with the other served models
+(the parameter tree, the uncached forward, the two programs through a
+slot, ``generate``, the ablations, the reference's two copies, the cell's
+listing and rehearsal) is ``tests/test_served_models.py``'s."""
+
 import time
 
 import jax
@@ -20,13 +20,10 @@ from ray_tpu.models import generation as gen
 from ray_tpu.models import reference as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    forward,
     init_params,
-    param_logical_axes,
 )
 from ray_tpu.ops.moe import routed_ffn
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = TransformerConfig.tiny_mla_moe(dtype=jnp.float32)
 HP = {"n_heads": CFG.n_heads, "qk_nope": CFG.qk_nope_dim,
       "qk_rope": CFG.qk_rope_dim, "kv_rank": CFG.kv_lora_rank,
@@ -70,18 +67,6 @@ def test_config_follows_the_published_numbers():
         "ckv": (8, 32, 4096, 512), "kr": (8, 32, 4096, 64)}
 
 
-def test_params_axes_and_count_agree(params):
-    axes = param_logical_axes(CFG)
-    assert jax.tree.structure(params) == jax.tree.structure(
-        axes, is_leaf=lambda x: isinstance(x, tuple))
-    for w, a in zip(jax.tree.leaves(params), jax.tree.leaves(
-            axes, is_leaf=lambda x: isinstance(x, tuple))):
-        assert w.ndim == len(a)
-    assert sum(w.size for w in jax.tree.leaves(params)) == CFG.param_count()
-    assert params["dense_layers"]["mlp"]["wi"].shape[0] == 1
-    assert params["layers"]["moe"]["wi"].shape[:2] == (2, CFG.moe_experts)
-
-
 def test_gptj_block_keeps_its_parameters_and_cache():
     c = TransformerConfig.tiny()
     p = init_params(c, jax.random.key(0))
@@ -92,46 +77,7 @@ def test_gptj_block_keeps_its_parameters_and_cache():
     assert gen.block_stat_keys(c) == ()
 
 
-# -- (a) prefill + decode through the cache against the full forward -------
-
-def test_uncached_forward_matches_reference(params):
-    toks = prompt(1, 70)
-    got = forward(params, jnp.asarray(toks)[None], CFG)[0]
-    _max, rms = ref.vector_distance(got, ref_logits(params, toks))
-    assert rms < TOL
-
-
-def test_prefill_and_decode_logits_match_reference(params):
-    """Three slots at different depths, one of them crossing a chunk edge
-    of the decode walk (256 rows), a parked lane among them."""
-    s_max, lens = 320, {0: 250, 2: 31, 3: 120}
-    cache = gen.init_kv_cache(CFG, 4, s_max)
-    seqs = {}
-    tok = np.zeros(4, np.int32)
-    for slot, n in lens.items():
-        p = prompt(10 + slot, n)
-        padded = np.zeros((1, 256), np.int32)
-        padded[0, :n] = p
-        logits, cache = gen.prefill_into_slot(
-            params, jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
-            cache, CFG)
-        want = ref_logits(params, p)[-1]
-        assert ref.vector_distance(logits, want)[1] < TOL
-        tok[slot] = int(jnp.argmax(logits))
-        seqs[slot] = list(p) + [int(tok[slot])]
-    pos = np.array([lens.get(i, 0) for i in range(4)], np.int32)
-    for _ in range(9):  # slot 0 walks from row 250 past row 256
-        logits, cache = gen.decode_step_multi(
-            params, jnp.asarray(tok), cache, jnp.asarray(pos), CFG)
-        for slot in lens:
-            want = ref_logits(params, seqs[slot])[-1]
-            assert ref.vector_distance(logits[slot], want)[1] < TOL, slot
-            tok[slot] = int(jnp.argmax(logits[slot]))
-            seqs[slot].append(int(tok[slot]))
-            pos[slot] += 1
-    assert cache["ckv"].shape == (CFG.n_layers, 4, s_max, CFG.kv_lora_rank)
-    assert cache["kr"].shape == (CFG.n_layers, 4, s_max, CFG.qk_rope_dim)
-
+# -- (a) the engine against the full forward ------------------------------
 
 def test_engine_serves_the_reference_tokens(params):
     from ray_tpu.serve.llm import LLMEngine
@@ -156,22 +102,6 @@ def test_engine_serves_the_reference_tokens(params):
         eng.shutdown()
 
 
-def test_generate_gives_the_reference_tokens(params):
-    """``generate()`` runs the engine's two programs, so it generates this
-    block too (latent cache, a leading dense layer, dropless experts):
-    two rows of one length, 270 prompt tokens of a 320-row cache (the
-    decode attention walks a second chunk)."""
-    prompts = np.stack([prompt(30, 270), prompt(31, 270)])
-    out = np.asarray(gen.generate(
-        params, jnp.asarray(prompts), CFG, max_new_tokens=12, max_len=320))
-    assert out.shape == (2, 12)
-    for p, ids in zip(prompts, out):
-        logits = ref_logits(
-            params, list(p) + list(ids[:-1]))[len(p) - 1:]
-        margin = ref.served_token_margin(logits, jnp.asarray(ids, jnp.int32))
-        assert float(margin.max()) < TOL
-
-
 # -- (b) absorbed decode attention against the plain form ------------------
 
 def test_absorbed_decode_attention_matches_plain_form():
@@ -187,7 +117,8 @@ def test_absorbed_decode_attention_matches_plain_form():
     c_kv = jax.random.normal(ks[5], (B, 1, r))
     k_r = jax.random.normal(ks[6], (B, 1, 1, rope))
     pos = jnp.asarray([290, 0, 17], jnp.int32)  # lane 1 is parked
-    out, new = gen._decode_attn(cache, 1, pos, jnp.arange(B), c)(
+    schedule = gen._visits(pos, (cache["ckv"], cache["kr"]))
+    out, new = gen._decode_attn(cache, 1, pos, jnp.arange(B), c, schedule)(
         q_nope, q_rope, c_kv, k_r, wp)
     row = jnp.concatenate([c_kv, k_r[:, :, 0]], -1)[:, 0]
     kv = jnp.concatenate([cache["ckv"], cache["kr"]], -1)
@@ -289,20 +220,6 @@ def test_no_token_dropped_when_all_pick_one_expert():
 
 # -- (d) what the comparison must refuse ------------------------------------
 
-@pytest.mark.parametrize("ablate", [
-    {"top_k": 3}, {"no_shared": True}, {"no_scale": True},
-    {"select_without_bias": True}, {"weights_with_bias": True},
-    {"unrotated_k": True}, {"fp8_weights": True},
-], ids=lambda a: next(iter(a)))
-def test_each_omission_fails_the_comparison(params, ablate):
-    toks = prompt(1, 200)
-    got = forward(params, jnp.asarray(toks)[None], CFG)[0]
-    _max, rms = ref.vector_distance(got, ref_logits(params, toks))
-    _max, rms_bad = ref.vector_distance(
-        got, ref_logits(params, toks, ablate=ablate))
-    assert rms < TOL < rms_bad / 3
-
-
 def test_a_bf16_router_fails_the_routed_layer_alone():
     """Router logits rounded to bf16 flip a near-tie for a few tokens in a
     hundred and move whole logit vectors too little to be told from other
@@ -326,22 +243,7 @@ def test_a_bf16_router_fails_the_routed_layer_alone():
     assert share_off({"router_bf16": True}) > 0.002
 
 
-# -- (e) the two copies of the reference -------------------------------------
-
-def test_reference_copies_are_identical_below_their_headers():
-    marker = "# ---- below this line the two copies are identical ----\n"
-    texts = []
-    for path in ("ray_tpu/models/reference.py",
-                 "benchmarks/reference_mla_moe.py"):
-        with open(os.path.join(ROOT, path)) as f:
-            text = f.read()
-        assert text.count(marker) == 1
-        texts.append(text.split(marker)[1])
-    assert texts[0] == texts[1]
-    assert "ray_tpu" not in texts[0] and "pallas" not in texts[0]
-
-
-# -- (f) counters and the cell's rehearsal -----------------------------------
+# -- (e) the engine's counters ---------------------------------------------
 
 def test_block_counters_add_up_on_a_scripted_run(params):
     from ray_tpu.serve.llm import LLMEngine
@@ -384,39 +286,3 @@ def test_block_counters_add_up_on_a_scripted_run(params):
         s["moe_assignments"], s["moe_experts_capacity"])
     assert s["moe_max_load"] * CFG.moe_experts >= s["moe_assignments"]
     assert s["attn_rows_read"] > 0
-
-
-@pytest.mark.phase_limit(600)  # a minute alone; six workers share the cores
-def test_new_cell_rehearses_on_the_host_with_every_reader_walked(
-        rehearsal_manifest):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    cell = "serve-glm-reason-saturated"
-    mine = [m["name"] for m in doc["per_layer"]
-            if cell in m.get("workloads", ())]
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py",
-         # 0.8 requests/s where the cell offers 4.55: each finds a slot,
-         # so that none is left to prefill after the window on a host
-         # where an admission takes a second (tests/conftest.py)
-         "--manifest", rehearsal_manifest("reason-saturated", 0.8),
-         "--workload", cell, "--seed",
-         # a window whose second half holds several decode blocks even
-         # with six test workers on the cores: the counters' readers
-         # divide what was retired between its middle and its end
-         str(2 ** 31 + 7), "--seconds", "16", "--trace", "1",
-         "--rehearse-cpu"],
-        cwd=ROOT, capture_output=True, text=True, timeout=570,
-        # the suite's eight virtual host devices are not the cell's one
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
-    assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]
-    walked = next(line for line in out.stdout.splitlines()
-                  if line.startswith("readers walked"))
-    values = json.loads(walked.split(": ", 1)[1])
-    assert sorted(values) == sorted(mine)
-    for name in ("engine.moe_expert_read_share", "model.moe_load_imbalance",
-                 "kernel.decode_hbm_share.mla_moe", "model.moe_time_share",
-                 "model.mla_time_share", "model.prefill_expert_time_share"):
-        assert name in mine
-    assert values["engine.moe_expert_read_share"] is not None
-    assert values["model.moe_load_imbalance"] is not None

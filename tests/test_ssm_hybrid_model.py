@@ -1,14 +1,17 @@
 """A model of state-space layers and attention layers (granite-4.0-h-micro's
 kind) against its plain reference, at test size on the CPU with seeded
-random weights: the chunked scan against the token-by-token recurrence,
-a padded bucket's state, the engine's two programs through a slot for
-both kinds of layer in one model, slots reused and slots left alone, the
-engine end to end with its new counters, the ablations a comparison must
-refuse, the two copies of the reference, and the existing models' configs
-under the new defaults."""
+random weights, what is this model's own: the chunked scan against the
+token-by-token recurrence, the update kernel, a padded bucket's state, a
+parked lane's state, slots left alone for several blocks, the engine end
+to end with its counters, and the existing models' configs under the new
+defaults.
+
+What it shares with the other served models
+(the parameter tree, the uncached forward, the two programs through a
+slot, ``generate``, the ablations, the reference's two copies, the cell's
+listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
-import os
 import time
 
 import jax
@@ -20,13 +23,10 @@ from ray_tpu.models import generation as gen
 from ray_tpu.models import reference_ssm as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    forward,
     init_params,
-    param_logical_axes,
 )
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_step, ssm_update
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # six layers, ssm ssm attention twice over; chunks of 8 tokens
 CFG = TransformerConfig.tiny_ssm_hybrid(dtype=jnp.float32)
 TOL = 2e-4  # float32 against float32: rounding order only
@@ -231,17 +231,6 @@ def test_config_follows_the_published_numbers():
                     "state_layers": 36}
 
 
-def test_params_axes_and_count_agree(params):
-    assert sum(x.size for x in jax.tree.leaves(params)) == CFG.param_count()
-    axes = param_logical_axes(CFG)
-    shapes = jax.tree.map(lambda a: a.ndim, params)
-    assert jax.tree.map(len, axes, is_leaf=lambda x: isinstance(
-        x, tuple)) == shapes
-    assert params["layers"]["ln1"]["scale"].shape[0] == 2
-    assert params["ssm_layers"]["ln1"]["scale"].shape[0] == 4
-    assert "attn" not in params["ssm_layers"]
-
-
 def test_layer_types_are_checked():
     with pytest.raises(ValueError):
         TransformerConfig.tiny_ssm_hybrid(layer_types=("ssm",) * 5)
@@ -265,49 +254,6 @@ def test_existing_models_are_untouched_by_the_new_defaults():
         28, 8, 1024, 16, 256)
     assert gen.slot_footprint(cache) == {
         "state_bytes": 0, "row_bytes": 458_752, "state_layers": 0}
-
-
-def test_the_uncached_forward_matches_the_reference(params):
-    toks = tokens_of(37)
-    got = forward(params, toks[None], CFG)[0]
-    want, _ = ref_logits(params, toks)
-    assert float(ref.vector_distance(got, want)[0]) < TOL
-
-
-@pytest.mark.parametrize("prompt_len,bucket", [(13, 16), (21, 32), (32, 32)])
-def test_prefill_and_decode_through_a_slot_match_the_reference(
-        params, prompt_len, bucket):
-    """Both kinds of layer in one model: a chunked, padded prefill into a
-    slot, then N decode steps through the slot's rows and state, against
-    the reference's full forward over prompt + answer: logits at every
-    position, and every state-space layer's state at the end."""
-    n_new = 11
-    toks = tokens_of(prompt_len + n_new, seed=prompt_len)
-    want, want_states = ref_logits(params, toks)
-    cache = gen.init_kv_cache(CFG, 3, 64)
-    logits, cache = prefill(params, cache, 1, toks[:prompt_len], bucket)
-    assert float(ref.vector_distance(logits, want[prompt_len - 1])[0]) < TOL
-    pos = jnp.array([0, prompt_len, 0], jnp.int32)
-    for t in range(prompt_len, prompt_len + n_new):
-        tok = jnp.array([0, toks[t], 0], jnp.int32)
-        lg, cache = gen.decode_step_multi(params, tok, cache, pos, CFG)
-        assert float(ref.vector_distance(lg[1], want[t])[0]) < TOL
-        pos = pos + (pos > 0)
-    for i, state in enumerate(want_states):
-        got = gen.cache_state(cache)["ssm"][i, 1]
-        assert float(ref.state_distance(got, state)) < TOL
-
-
-def test_prefill_leaves_the_other_slots_bit_identical(params):
-    cache = gen.init_kv_cache(CFG, 3, 64)
-    _, cache = prefill(params, cache, 1, tokens_of(9, 2), 16)
-    before = jax.tree.map(lambda a: np.asarray(a[:, 1]), cache)
-    _, cache = prefill(params, cache, 0, tokens_of(14, 3), 16)
-    _, cache = prefill(params, cache, 2, tokens_of(5, 4), 16)
-    after = jax.tree.map(lambda a: np.asarray(a[:, 1]), cache)
-    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
-        assert np.array_equal(a, b)
-    assert float(jnp.abs(gen.cache_state(cache)["ssm"][:, 1]).max()) > 0
 
 
 def test_decode_block_leaves_a_parked_lanes_state_where_it_lies(params):
@@ -376,23 +322,6 @@ def test_engine_serves_two_requests_admitted_at_different_times(params):
         eng.shutdown()
 
 
-def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(params):
-    eng = engine_of(params)
-    try:
-        p, q = np.asarray(tokens_of(17, 7)), np.asarray(tokens_of(11, 8))
-        eng.generate(p, max_new_tokens=9)  # slot 0, then freed and parked
-        eng.generate(p, max_new_tokens=3)  # parked lanes step meanwhile
-        again = eng.generate(q, max_new_tokens=8)  # a used slot
-    finally:
-        eng.shutdown()
-    fresh = engine_of(params)
-    try:
-        assert again == fresh.generate(q, max_new_tokens=8)
-    finally:
-        fresh.shutdown()
-    assert worst_margin(params, q, again) < TOL
-
-
 def test_a_slot_skipped_for_several_blocks_serves_as_a_fresh_one(params):
     """Slot 1 holds what a request left, then stays parked (its states
     skipped) while slot 0 decodes for several blocks; the request then
@@ -423,110 +352,3 @@ def test_a_slot_skipped_for_several_blocks_serves_as_a_fresh_one(params):
     finally:
         fresh.shutdown()
     assert worst_margin(params, r, again) < TOL
-
-
-# -- what a comparison must refuse -----------------------------------------
-
-@pytest.mark.parametrize("ablate", [
-    {"state_bf16": True}, {"state_at_bucket_end": (21, 32)},
-    {"drop_conv_tail": 21}, {"residual_one": True},
-    {"usual_attn_scale": True},
-], ids=lambda a: next(iter(a)))
-def test_each_ablation_fails_the_comparison(params, ablate):
-    """The served path (prefill of 21 tokens in a bucket of 32, then 12
-    decode steps) equals the reference and differs from each wrong one,
-    in the last logits or in the first state-space layer's state."""
-    n, n_new = 21, 12
-    toks = tokens_of(n + n_new, seed=11)
-    cache = gen.init_kv_cache(CFG, 1, 64)
-    _, cache = prefill(params, cache, 0, toks[:n], 32)
-    pos = jnp.array([n], jnp.int32)
-    for t in range(n, n + n_new):
-        lg, cache = gen.decode_step_multi(
-            params, toks[t][None], cache, pos, CFG)
-        pos = pos + 1
-    state = gen.cache_state(cache)["ssm"][0, 0]
-
-    def distance(**kw):
-        want, states = ref_logits(params, toks, **kw)
-        return max(float(ref.vector_distance(lg[0], want[-1])[1]),
-                   float(ref.state_distance(state, states[0])))
-
-    assert distance() < TOL < 1e-3 < distance(ablate=ablate)
-
-
-def test_reference_copies_are_identical_below_their_headers():
-    marker = "# ---- below this line the two copies are identical ----\n"
-
-    def body(path):
-        with open(os.path.join(ROOT, path)) as f:
-            text = f.read()
-        assert text.count(marker) == 1
-        return text.split(marker)[1]
-
-    mine = body("ray_tpu/models/reference_ssm.py")
-    assert mine == body("benchmarks/reference_ssm.py")
-    for name in ("ray_tpu", "generation", "transformer", "ops."):
-        assert name not in mine  # none of the program's code
-
-
-# -- the benchmark resolves and rehearses the new cell ---------------------
-
-CELL = "serve-granite-agent-saturated"
-
-
-def test_the_list_resolves_the_new_cell():
-    import json
-    import subprocess
-    import sys
-
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--list"], cwd=ROOT,
-        capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(line) for line in out.stdout.splitlines()]
-    row = next(r for r in rows if r["cell"] == CELL)
-    assert (row["runner"], row["traffic"], row["chips"]) == (
-        "serve_ssm", "agent-saturated", 1)
-    assert row["end_to_end"] == ["tpot_p50_ms", "setup_s"]
-    for name in ("model.ssm_time_share", "model.prefill_ssm_scan_share",
-                 "engine.state_live_share", "kernel.decode_hbm_share.ssm",
-                 "model.decode_step_ms", "device.idle_share.serve",
-                 "engine.kv_read_share"):
-        assert name in row["per_layer"]
-
-
-def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
-    import json
-    import subprocess
-    import sys
-
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    mine = [m["name"] for m in doc["per_layer"]
-            if CELL in m.get("workloads", ())]
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
-         str(2 ** 31 + 7), "--seconds", "6", "--trace", "1",
-         "--rehearse-cpu"],
-        cwd=ROOT, capture_output=True, text=True, timeout=900,
-        # the suite's eight virtual host devices are not the cell's one
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
-    assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]
-    walked = next(line for line in out.stdout.splitlines()
-                  if line.startswith("readers walked"))
-    values = json.loads(walked.split(": ", 1)[1])
-    assert sorted(values) == sorted(mine)
-    # the rehearsal's engine: 4 slots, 4 state layers (the metric's scale
-    # is the cell's 36), and the states moved are the live lanes' alone
-    # (ISSUE 46): a whole share, 100 x 36 / 4
-    share = values["engine.state_live_share"]
-    assert share is not None and abs(share - 900) < 1e-6
-    note = json.loads(next(line for line in out.stdout.splitlines()
-                           if line.startswith('{"note"')))
-    end = note["note"]["backlog"]["end"]
-    assert end["slot_state_bytes"] > 0 and end["slot_row_bytes"] > 0
-    assert (end["state_slots_updated"] + end["state_slots_skipped"]
-            == 4 * 4 * end["steps"])
-    assert end["slot_steps"] * 4 <= end["state_slots_updated"]
-    assert 0 < values["engine.state_skip_share"] < 100

@@ -1,18 +1,19 @@
 """A model of full attention layers and window layers under a share of
 routed experts (MiMo-V2-Flash's kind) against its plain reference, at test
-size on the CPU with seeded random weights: the uncached forward, the
-engine's two programs through a slot's rows and ring for prompts below, at
-and above the window and decoding across wraps of the ring, two requests
-of unlike lengths in one engine batch, the blocked attentions and the
-ring's kernel (interpreter) against the plain forms, the shares of a
-routed layer against the uncut layer, the ablations a comparison must
-refuse, ill-formed ``layer_types``, the two copies of the reference, and
-the benchmark's new cell resolved and rehearsed."""
+size on the CPU with seeded random weights, what is this model's own: what
+a window layer keeps and counts, two requests of unlike lengths in one
+engine batch, the blocked attentions and the ring's kernel (interpreter)
+against the plain forms, the shares of a routed layer against the uncut
+layer, ill-formed ``layer_types``, and the benchmark's arithmetic.
+
+What it shares with the other served models
+(the parameter tree, the uncached forward, the two programs through a
+slot, ``generate``, the ablations, the reference's two copies, the cell's
+listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -24,9 +25,7 @@ from ray_tpu.models import generation as gen
 from ray_tpu.models import reference_swa as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    forward,
     init_params,
-    param_logical_axes,
 )
 from ray_tpu.ops.attention import (
     NEG_INF,
@@ -102,20 +101,6 @@ def test_config_follows_the_published_numbers():
     assert gen.block_stat_keys(cut)[-1] == "window_rows_read"
 
 
-def test_params_axes_and_count_agree(params):
-    axes = param_logical_axes(CFG)
-    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(
-        x, tuple)) == jax.tree.structure(params)
-    for a, p in zip(jax.tree.leaves(axes, is_leaf=lambda x: isinstance(
-            x, tuple)), jax.tree.leaves(params)):
-        assert len(a) == p.ndim
-    assert CFG.param_count() == sum(p.size for p in jax.tree.leaves(params))
-    assert params["window_layers"]["swa"]["sink"].shape == (3, 4)
-    assert params["window_layers"]["swa"]["wk"].shape == (3, 64, 4, 64)
-    assert params["layers"]["attn"]["wv"].shape == (1, 64, 2, 32)
-    assert "sink" not in params["layers"]["attn"]
-
-
 @pytest.mark.parametrize("bad", [
     dict(layer_types=("attention", "window")),  # one entry a layer
     dict(layer_types=("attention", "window", "window", "local", "window")),
@@ -135,35 +120,7 @@ def test_ill_formed_layer_types_are_refused(bad):
         TransformerConfig.tiny_swa_moe(**bad)
 
 
-# -- the forward and the two programs against the reference ------------------
-
-def test_the_uncached_forward_matches_the_reference(params):
-    toks = tokens_of(37)
-    got = forward(params, toks[None], CFG)[0]
-    assert float(jnp.abs(got - ref_logits(params, toks)).max()) < TOL
-
-
-@pytest.mark.parametrize("prompt_len,bucket", [
-    (5, 8), (8, 8), (13, 16), (29, 32), (100, 128)],
-    ids=["below", "at", "above", "far_above", "many_windows"])
-def test_prefill_and_decode_through_rows_and_ring_match_the_reference(
-        params, prompt_len, bucket):
-    """A padded prompt into slot 1 of 2, then 20 decode steps (the ring of
-    8 rows wraps at least twice), slot 0 parked: every step's logits
-    against the reference's full forward over prompt + answer."""
-    n_new = 20
-    toks = tokens_of(prompt_len + n_new, seed=3)
-    want = ref_logits(params, toks)
-    cache = gen.init_kv_cache(CFG, 2, 160)
-    lg, cache = prefill(params, cache, 1, toks[:prompt_len], bucket)
-    worst = float(jnp.abs(lg - want[prompt_len - 1]).max())
-    for t in range(prompt_len, prompt_len + n_new):
-        tok = jnp.zeros(2, jnp.int32).at[1].set(toks[t])
-        pos = jnp.zeros(2, jnp.int32).at[1].set(t)
-        lg, cache = gen.decode_step_multi(params, tok, cache, pos, CFG)
-        worst = max(worst, float(jnp.abs(lg[1] - want[t]).max()))
-    assert worst < TOL
-
+# -- what a window layer keeps ----------------------------------------------
 
 def test_a_window_layer_keeps_a_ring_and_counts_what_it_reads(params):
     """What the cache holds after a prefill of 13 tokens and two steps,
@@ -189,16 +146,6 @@ def test_a_window_layer_keeps_a_ring_and_counts_what_it_reads(params):
         params, short, jnp.array([3], jnp.int32), jnp.array([3], jnp.int32),
         jnp.zeros(1), zeros[:1], zeros[:1], CFG, 2)
     assert int(stats["window_rows_read"]) == 3 * (4 + 5)
-
-
-def test_prefill_leaves_the_other_slots_bit_identical(params):
-    cache = gen.init_kv_cache(CFG, 2, 64)
-    _, cache = prefill(params, cache, 0, tokens_of(19, 2), 32)
-    before = jax.tree.map(lambda a: np.asarray(a[:, 0]), cache)
-    _, cache = prefill(params, cache, 1, tokens_of(11, 3), 16)
-    after = jax.tree.map(lambda a: np.asarray(a[:, 0]), cache)
-    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
-        assert (a == b).all()
 
 
 # -- the engine ---------------------------------------------------------------
@@ -246,25 +193,6 @@ def test_engine_serves_two_requests_of_unlike_lengths_in_one_batch(params):
         assert s["requests_failed"] == 0
     finally:
         eng.shutdown()
-
-
-def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(params):
-    eng = engine_of(params)
-    try:
-        p, q = np.asarray(tokens_of(17, 7)), np.asarray(tokens_of(6, 8))
-        eng.generate(p, max_new_tokens=9)  # slot 0's ring full, then freed
-        again = eng.generate(q, max_new_tokens=8)  # a prompt under a window
-    finally:
-        eng.shutdown()
-    assert worst_margin(params, q, again) < TOL
-
-
-def test_generate_runs_the_served_programs(params):
-    prompt = jnp.stack([tokens_of(11, 3), tokens_of(11, 4)])
-    ids = gen.generate(params, prompt, CFG, max_new_tokens=12, max_len=32)
-    for b in range(2):
-        assert worst_margin(params, np.asarray(prompt[b]),
-                            np.asarray(ids[b]).tolist()) < TOL
 
 
 # -- the blocked attentions and the ring's kernel against the plain forms ----
@@ -463,55 +391,7 @@ def test_a_model_that_holds_a_share_matches_the_reference_of_that_share():
         pos = pos + 1
 
 
-# -- what a comparison must refuse -------------------------------------------
-
-@pytest.mark.parametrize("ablate", [
-    {"window": 7}, {"window": 9}, {"no_sink": True}, {"sink_on_full": True},
-    {"no_value_scale": True}, {"swap_theta": True}, {"rotary_all": True},
-    {"window_grouping": True}, {"window_attends_all": True},
-    {"fp8_weights": True},
-], ids=lambda a: "%s_%s" % next(iter(a.items())))
-def test_each_ablation_fails_the_comparison(params, ablate):
-    """The served path (prefill of 21 tokens in a bucket of 32, then 12
-    decode steps) equals the reference and differs from each wrong one."""
-    n, n_new = 21, 12
-    toks = tokens_of(n + n_new, seed=11)
-    cache = gen.init_kv_cache(CFG, 1, 64)
-    _, cache = prefill(params, cache, 0, toks[:n], 32)
-    pos = jnp.array([n], jnp.int32)
-    for t in range(n, n + n_new):
-        lg, cache = gen.decode_step_multi(
-            params, toks[t][None], cache, pos, CFG)
-        pos = pos + 1
-
-    def distance(**kw):
-        want = ref_logits(params, toks, **kw)
-        return float(ref.vector_distance(lg[0], want[-1])[1])
-
-    assert distance() < TOL < 1e-3 < distance(ablate=ablate)
-
-
-def test_reference_copies_are_identical_below_their_headers():
-    marker = "# ---- below this line the two copies are identical ----\n"
-
-    def body(path):
-        with open(os.path.join(ROOT, path)) as f:
-            text = f.read()
-        assert text.count(marker) == 1
-        return text.split(marker)[1]
-
-    mine = body("ray_tpu/models/reference_swa.py")
-    assert mine == body("benchmarks/reference_swa_moe.py")
-    for name in ("ray_tpu", "generation", "transformer", "ops."):
-        assert name not in mine  # none of the program's code
-
-
-# -- the benchmark resolves and rehearses the new cell -----------------------
-
-CELL = "serve-mimo-codeagent-saturated"
-NEW = ("model.window_attn_time_share", "model.full_attn_time_share",
-       "model.prefill_window_attn_share", "model.prefill_full_attn_share",
-       "engine.window_rows_share", "kernel.decode_hbm_share.swa_moe")
+# -- the benchmark's arithmetic ----------------------------------------------
 
 
 def test_the_benchmarks_arithmetic_agrees_with_the_program():
@@ -543,48 +423,3 @@ def test_the_benchmarks_arithmetic_agrees_with_the_program():
     tiny = swa_moe_model.transformer_config(
         {**model, **model["rehearsal"]})
     assert tiny.layer_types.count("window") == 3 and tiny.window == 8
-
-
-def test_the_list_resolves_the_new_cell():
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--list"], cwd=ROOT,
-        capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(line) for line in out.stdout.splitlines()]
-    assert len(rows) >= 8  # later PRs add cells
-    row = next(r for r in rows if r["cell"] == CELL)
-    assert (row["runner"], row["traffic"], row["chips"]) == (
-        "serve_swa_moe", "codeagent-saturated", 1)
-    assert row["end_to_end"] == ["tpot_p50_ms", "setup_s"]
-    for name in NEW + ("model.decode_step_ms", "device.idle_share.serve",
-                       "engine.kv_read_share", "model.moe_time_share"):
-        assert name in row["per_layer"]
-
-
-@pytest.mark.phase_limit(900)
-def test_new_cell_rehearses_on_the_host_with_every_reader_walked():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    mine = [m["name"] for m in doc["per_layer"]
-            if CELL in m.get("workloads", ())]
-    out = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
-         str(2 ** 31 + 7), "--seconds", "6", "--trace", "1",
-         "--rehearse-cpu"],
-        cwd=ROOT, capture_output=True, text=True, timeout=900,
-        # the suite's eight virtual host devices are not the cell's one
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"})
-    assert out.returncode == 10, out.stdout[-3000:] + out.stderr[-3000:]
-    walked = next(line for line in out.stdout.splitlines()
-                  if line.startswith("readers walked"))
-    values = json.loads(walked.split(": ", 1)[1])
-    assert sorted(values) == sorted(mine)
-    share = values["engine.window_rows_share"]
-    assert share is not None and 0 < share < 100
-    note = json.loads(next(line for line in out.stdout.splitlines()
-                           if line.startswith('{"note"')))
-    end = note["note"]["backlog"]["end"]
-    assert end["slot_state_bytes"] == 3 * 8 * (4 * 64 + 4 * 32) * 2
-    assert 0 < end["window_rows_read"] <= 3 * 8 * end["slot_steps"]
-    probe = note["note"]["probe"]
-    assert probe["replayed"] and probe["window_layer"]["ring_median"] < 0.05
