@@ -43,9 +43,16 @@ attends fewer rows still: it scores the prefix's index keys, picks
 there); its prefill attends block by block under the mask of each query's
 chosen rows (``_prefill_choice``, ``_attend_masked``).
 
+A decoder-hybrid-decoder's upper layers keep NOTHING: a "cross" layer
+attends the rows the one "attention" layer below keeps (the token's own
+row, which that layer wrote earlier in the same step, is handed up in the
+layer scan's carry, as the last "mamba" layer's output is for the "gmu"
+layers), so a prefill runs them on the prompt's LAST REAL token alone.
+
 What differs by the KIND of a layer is in one table, ``_KINDS``, a row a
-kind (``transformer.layer_kind``: "attn", "ssm", "swa", "kda"; a latent
-block with an indexer has its own "attn" row): what a slot keeps for the
+kind (``transformer.layer_kind``: "attn", "ssm", "swa", "kda", "mamba",
+"gmu", "cross"; a latent block with an indexer has its own "attn" row, a
+model whose layers hand things on too): what a slot keeps for the
 kind's layers (``init_kv_cache`` merges the rows), how a token decodes
 through one and how a prompt fills it (``_decode_forward_multi`` and
 ``prefill_into_slot`` look the layer's form up, once each), the kind's
@@ -58,6 +65,7 @@ functions it names.
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from functools import lru_cache, partial
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -67,14 +75,16 @@ from jax import lax
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    _rms_norm,
+    _norm,
     apply_block,
     embed_tokens,
     kda_split,
     layer_groups,
     layer_kind,
     lm_logits,
+    mamba_inputs,
     mla_expand,
+    recalling,
     scan_stack,
     ssm_split,
 )
@@ -91,6 +101,7 @@ from ray_tpu.ops.decode_attention import (
     slot_schedule,
 )
 from ray_tpu.ops.kda import kda_chunked, kda_update
+from ray_tpu.ops.mamba import mamba_scan, mamba_update
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_update
 
 
@@ -279,6 +290,25 @@ def _kda_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
                           c.dtype)}
 
 
+def _mamba_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """A "mamba" layer keeps ``mamba`` of [those layers, B, state,
+    channels] in float32, the 16 state dims in the sublanes and the
+    channels in the lanes (the chip lays a 16-minor array out badly), and
+    ``conv``, the last ``mamba_conv - 1`` inputs of its convolution, flat
+    as a state-space layer's."""
+    return {}, {
+        "mamba": jnp.zeros((n, batch, c.mamba_state, c.mamba_inner),
+                           jnp.float32),
+        "conv": jnp.zeros((n, batch, (c.mamba_conv - 1) * c.mamba_inner),
+                          c.dtype)}
+
+
+def _nothing_kept(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """A "gmu" or a "cross" layer keeps nothing: it reads what a layer
+    below keeps or hands on."""
+    return {}, {}
+
+
 def _put_layer(leaf, rows, li):
     """``leaf`` (one slot's, [layers, 1, S, ...]) with layer ``li``'s rows
     overwritten from their start by ``rows`` [1, 1, S', ...]."""
@@ -297,9 +327,11 @@ def _put_token(leaf, li, b_idx, at, x):
 def _kv_rows(c: TransformerConfig) -> Tuple[Tuple[int, ...], ...]:
     """The shapes of one token's key and value in a layer of the MHA/GQA
     cache: (Hkv, D) and (Hkv, Dv), or both flat, (Hkv x D,) and
-    (Hkv x Dv,), where D alone is no whole number of 128-lanes and the
+    (Hkv x Dv,), where D alone is no whole number of 128-lanes (or the
+    heads pair: ``transformer._diff_pairs`` reads two as one) and the
     heads together are."""
-    if c.d_head % 128 and (c.kv_heads * c.d_head) % 128 == 0:
+    if (c.d_head % 128 or c.diff_attn) and (
+            c.kv_heads * c.d_head) % 128 == 0:
         return (c.kv_heads * c.d_head,), (c.kv_heads * c.v_dim,)
     return (c.kv_heads, c.d_head), (c.kv_heads, c.v_dim)
 
@@ -1206,6 +1238,111 @@ def _prefill_kda(single, li, prompt_len, c: TransformerConfig):
     return _recurrence(recur)
 
 
+def _decode_mamba(cache, li, pos, c: TransformerConfig, handed):
+    """One decode layer's ``attn_fn`` for a "mamba" layer (the counterpart
+    of ``_decode_kda``; ``transformer._mamba_mixer`` calls its ``recur``):
+    every live lane's convolution window moves on one token and its state
+    one step (``ops/mamba.mamba_update`` on the stacked leaf at layer
+    ``li``); a PARKED lane (``pos`` 0) keeps both as they were. The
+    recurrence's output goes up in ``handed`` as ``m``. Returns (y, (the
+    cache, handed))."""
+    def recur(x, wp):
+        state, live = cache_state(cache), pos > 0
+        with jax.named_scope("raytpu.mamba1.conv"):
+            out, conv = _conv_step(state["conv"], li, x, wp["conv_w"],
+                                   wp["conv_b"], live)
+            x = jax.nn.silu(out[:, 0])
+        with jax.named_scope("raytpu.mamba1.update"):
+            dt, B, C, A = mamba_inputs(x, wp, c)
+            y, new = mamba_update(state["mamba"], li, x, dt, A, B, C,
+                                  wp["d"], live)
+        return y[:, None], (
+            {**cache, "state": {**state, "mamba": new, "conv": conv}},
+            {**handed, "m": y})
+
+    return _recurrence(recur)
+
+
+def _prefill_mamba(single, li, prompt_len, c: TransformerConfig, handed):
+    """One prefill layer's ``attn_fn`` for a "mamba" layer: the
+    convolution and the scan (``ops/mamba.mamba_scan``) over the padded
+    prompt from an empty state. What the slot is handed is the state AT
+    ``prompt_len`` (the padding neither decays nor adds) and the last
+    ``mamba_conv - 1`` REAL inputs of the convolution. Returns (y, (single
+    with this layer's state, handed with every token's ``m``))."""
+    def recur(x, wp):
+        state = cache_state(single)
+        with jax.named_scope("raytpu.mamba1.conv"):
+            out, conv = _conv_prompt(state["conv"], li, x, wp["conv_w"],
+                                     wp["conv_b"], prompt_len)
+            x = jax.nn.silu(out)
+        with jax.named_scope("raytpu.mamba1.scan"):
+            dt, B, C, A = mamba_inputs(x, wp, c)
+            y, end = mamba_scan(
+                x, dt, A, B, C, wp["d"],
+                valid=(jnp.arange(x.shape[1]) < prompt_len)[None])
+            new = lax.dynamic_update_index_in_dim(state["mamba"], end, li, 0)
+        return y, (
+            {**single, "state": {**state, "mamba": new, "conv": conv}},
+            {**handed, "m": y[0]})
+
+    return _recurrence(recur)
+
+
+def _decode_attn_handing(cache, li, s, c: TransformerConfig, handed):
+    """``_decode_attn`` for the "attention" layer whose rows the "cross"
+    layers above attend too: the token's own key and value, which the
+    step's later layers cannot read from the cache's prefix, go up in
+    ``handed``. Returns (output, (the cache, handed))."""
+    attend = _decode_attn(cache, li, s.pos, s.b_idx, c, s.visits["attn"])
+
+    def cached_attn(q, k, v):
+        out, new = attend(q, k, v)
+        return out, (new, {**handed, "k": k[:, 0], "v": v[:, 0]})
+
+    return cached_attn
+
+
+def _decode_cross(cache, s, c: TransformerConfig, handed):
+    """One decode layer's ``attn_fn`` for a "cross" layer: its queries
+    [B,1,H,D] against the rows of the LAST "attention" layer below, the
+    prefix where it lies in that layer's cache and the token's own row
+    from ``handed`` (``_attend_flat_prefix_plus_self``; the step's schedule
+    of that layer's visits serves these layers too). Nothing is written:
+    returns (output, the cache)."""
+    def attend(q):
+        return _attend_flat_prefix_plus_self(
+            q, cache["k"], cache["v"], handed["k"][:, None],
+            handed["v"][:, None], s.pos, layer=c.n_attn_layers - 1,
+            schedule=s.visits["attn"]), cache
+
+    return attend
+
+
+def _prefill_cross(single, c: TransformerConfig, p):
+    """One prefill layer's ``attn_fn`` for a "cross" layer, which runs on
+    the prompt's last real token alone: its one query [1,1,H,D] against
+    the rows the "attention" layer below wrote into ``single`` for the
+    bucket, those below ``prompt_len`` (the token's own among them).
+    Returns (output [1,1,H,Dv], single)."""
+    def attend(q):
+        layer, f32 = c.n_attn_layers - 1, jnp.float32
+        n_heads, width = q.shape[2:]
+        bucket = p.positions.shape[0]
+        k, v = (single[name][layer, 0, :bucket] for name in ("k", "v"))
+        groups = k.shape[-1] // width
+        k, v = (x.reshape(bucket, groups, -1) for x in (k, v))
+        qg = q[0, 0].reshape(groups, n_heads // groups, width)
+        scores = jnp.einsum("grd,sgd->grs", qg, k,
+                            preferred_element_type=f32) * width ** -0.5
+        scores = jnp.where(p.positions < p.prompt_len, scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out = jnp.einsum("grs,sgd->grd", probs, v)
+        return out.reshape(1, 1, n_heads, -1), single
+
+    return attend
+
+
 def prefill_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     """Names of the int32 counters the admission form of
     ``prefill_into_slot`` returns with its first token: for dropless
@@ -1233,7 +1370,11 @@ def block_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     (rows a walk over every live row would attend), summed over lanes,
     layers and steps; a model with window layers': ``window_rows_read``,
     the ring rows its decode attention read (min(pos + 1, window) a live
-    lane a window layer). None for the other models."""
+    lane a window layer); a model with "cross" layers': ``cross_rows_read``,
+    the rows of ANOTHER layer's cache their decode attention walked (the
+    "attention" layer's chunks up to each live lane's length, once a
+    "cross" layer: what ``attn_rows_read`` counts for the owner, times
+    those layers). None for the other models."""
     keys = ()
     if config.moe_experts and config.moe_impl == "dropless":
         keys += ("moe_assignments", "moe_experts_touched",
@@ -1260,6 +1401,16 @@ def _dsa_stats(pos, c: TransformerConfig, n: int, cache):
 def _window_stats(pos, c: TransformerConfig, n: int, cache):
     """The ring rows one decode step's window layers read."""
     return {"window_rows_read": n * ring_rows(pos, c.window).sum()}
+
+
+def _cross_stats(pos, c: TransformerConfig, n: int, cache):
+    """The rows one decode step's "cross" layers walk in the cache of the
+    layer they read: whole chunks up to each live lane's length."""
+    arrays = jax.tree.leaves(cache_rows(cache))
+    s_max = arrays[0].shape[2]
+    chunk = min(dense_attn_chunk(arrays), s_max)
+    walked = jnp.minimum(-(-jnp.minimum(pos, s_max) // chunk) * chunk, s_max)
+    return {"cross_rows_read": n * walked.sum()}
 
 
 def _zero_stats(config: TransformerConfig):
@@ -1306,11 +1457,15 @@ class _Kind(NamedTuple):
     # what one decode step adds to them, from the lanes' positions
     counters: Tuple[str, ...] = ()
     counts: Optional[Callable] = None
-    # the layers that attend every row only. (c, queries, rows) -> what
-    # the layer scan's carry hands from layer to layer, or None;
-    # (c, s_max) -> rows of a slot one visit of the decode attention
-    # reads; whether it walks EVERY slot up to the longest lane
-    # (``attn_rows_read``: the host's count)
+    # whether it keeps nothing and reads what layers below keep or hand on
+    # for the SAME token: a prefill runs such layers, where they are the
+    # model's last, on the prompt's last real token alone
+    last_token: bool = False
+    # the layers that attend every row only. (c, queries, rows, prompt) ->
+    # what the layer scan's carry hands from layer to layer (``prompt``:
+    # in a prefill), or None; (c, s_max) -> rows of a slot one visit of
+    # the decode attention reads; whether it walks EVERY slot up to the
+    # longest lane (``attn_rows_read``: the host's count)
     hands_on: Optional[Callable] = None
     chunk: Optional[Callable] = None
     walks_longest: bool = False
@@ -1331,6 +1486,12 @@ _KINDS = {
             cache, li, s.pos, c),
         prefill=lambda single, li, lp, c, p, choice: _prefill_kda(
             single, li, p.prompt_len, c)),
+    "mamba": _Kind(
+        layers=lambda c: c.n_of("mamba"), keeps=_mamba_keeps,
+        decode=lambda cache, li, lp, c, s, handed: _decode_mamba(
+            cache, li, s.pos, c, handed),
+        prefill=lambda single, li, lp, c, p, handed: _prefill_mamba(
+            single, li, p.prompt_len, c, handed)),
     "swa": _Kind(
         layers=lambda c: c.n_window_layers, keeps=_window_keeps,
         decode=lambda cache, li, lp, c, s, choice: _decode_window_attn(
@@ -1349,7 +1510,23 @@ _KINDS = {
             single, li, c, p.positions, p.kv_valid),
         visits=lambda pos, c, cache: _visits(
             pos, jax.tree.leaves(cache_rows(cache))),
-        hands_on=lambda c, queries, rows: None, chunk=_dense_chunk),
+        hands_on=lambda c, queries, rows, prompt=False: None,
+        chunk=_dense_chunk),
+    "gmu": _Kind(
+        layers=lambda c: c.n_of("gmu"), keeps=_nothing_kept,
+        decode=lambda cache, li, lp, c, s, handed: recalling(
+            handed["m"][:, None], cache),
+        prefill=lambda single, li, lp, c, p, handed: recalling(
+            handed["m"][None], single),
+        last_token=True),
+    "cross": _Kind(
+        layers=lambda c: c.n_of("cross"), keeps=_nothing_kept,
+        decode=lambda cache, li, lp, c, s, handed: _decode_cross(
+            cache, s, c, handed),
+        prefill=lambda single, li, lp, c, p, handed: _prefill_cross(
+            single, c, p),
+        counters=("cross_rows_read",), counts=_cross_stats,
+        last_token=True),
 }
 # the "attn" row of a latent block with an indexer (``c.index_topk``)
 _CHOSEN = _Kind(
@@ -1360,25 +1537,75 @@ _CHOSEN = _Kind(
         single, li, lp["attn"], choice, c),
     counters=("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_live"),
     counts=_dsa_stats,
-    hands_on=lambda c, queries, rows: {
+    hands_on=lambda c, queries, rows, prompt=False: {
         "mask": jnp.zeros((queries, rows), bool),
         "k": jnp.zeros((queries, c.index_head_dim), c.dtype)},
     chunk=lambda c, s_max: min(DSA_CHUNK, s_max), walks_longest=True)
 
 
+def _handed_up(c: TransformerConfig, queries: int, rows: int,
+               prompt: bool = False):
+    """What the layers of a decoder-hybrid-decoder hand on for each of
+    ``queries`` tokens: ``m`` [queries, inner], the last "mamba" layer's
+    recurrence output (for the "gmu" layers), and on decode ``k`` and
+    ``v``, the token's own row as the "attention" layer made it (for the
+    "cross" layers, heads paired as the attentions see them; a prefill's
+    one query reads every row back from the slot)."""
+    m = {"m": jnp.zeros((queries, c.mamba_inner), c.dtype)}
+    if prompt:
+        return m
+    r = 2 if c.diff_attn else 1
+    return {**m, **{name: jnp.zeros((queries, c.kv_heads // r, r * d),
+                                    c.dtype)
+                    for name, d in (("k", c.d_head), ("v", c.v_dim))}}
+
+
+# the "attn" row of a model whose upper layers read what the lower hand on
+_SHARED = _KINDS["attn"]._replace(
+    decode=lambda cache, li, lp, c, s, handed: _decode_attn_handing(
+        cache, li, s, c, handed),
+    hands_on=_handed_up)
+
+
 def _row(kind: str, c: TransformerConfig) -> _Kind:
     """The table's row for a layer of ``kind`` in ``c``'s model."""
-    return _CHOSEN if kind == "attn" and c.index_topk else _KINDS[kind]
+    if kind != "attn":
+        return _KINDS[kind]
+    return (_CHOSEN if c.index_topk else
+            _SHARED if c.n_of("gmu") or c.n_of("cross") else _KINDS[kind])
 
 
 def _kinds_of(c: TransformerConfig):
     """(kind, its row, how many of the model's layers are of it) for the
     kinds ``c``'s model has, in the table's order. The layers that attend
-    every row are always among them: their row leaves give a slot its
-    length."""
+    every row AND keep them are always among them: their row leaves give
+    a slot its length (a "cross" layer attends every row too and keeps
+    none: it reads theirs)."""
     found = ((kind, _row(kind, c)) for kind in _KINDS)
     return [(kind, row, row.layers(c)) for kind, row in found
             if kind == "attn" or row.layers(c)]
+
+
+def _prompt_parts(stack, lc: TransformerConfig, first: int):
+    """One group of ``layer_groups`` as a prefill runs it: ``(stacks,
+    index of their first layer, whether on the last real token alone)``.
+    One part, but where the group's LAST layers are all of kinds that keep
+    nothing (``_Kind.last_token``: "gmu", "cross"): nothing of the
+    prompt's other tokens outlives such layers, so the layers below them
+    run over the bucket and they on the prompt's last real token alone."""
+    if not lc.layer_types:
+        return [(stack, first, False)]
+    n = sum(s["ln1"]["scale"].shape[0] for s in stack.values())
+    kinds = lc.layer_types[first:first + n]
+    upper = {kind for kind in stack
+             if _KINDS[layer_kind(stack[kind])].last_token}
+    cut = next((i for i in range(n) if set(kinds[i:]) <= upper), n)
+    if cut in (0, n) or upper & set(kinds[:cut]):
+        return [(stack, first, False)]
+    return [({k: v for k, v in stack.items() if k not in upper}, first,
+             False),
+            ({k: v for k, v in stack.items() if k in upper}, first + cut,
+             True)]
 
 
 def _decode_forward_multi(params, token, cache, pos,
@@ -1410,7 +1637,7 @@ def _decode_forward_multi(params, token, cache, pos,
                 cache, li, lp, lc, step, choice)
             y, _aux, cache, stats = apply_block(
                 x, lp, lc, pos[:, None], attn, token_mask=live)
-            if choice is not None:
+            if isinstance(cache, tuple):  # a layer that hands something on
                 cache, choice = cache
             return y, cache, _add_stats(total, stats), choice
 
@@ -1536,28 +1763,46 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     real = (positions < prompt_len)[None] if routed else None
 
     prompt_holds = _Prompt(prompt_len, positions, kv_valid)
-    choice = _row("attn", c).hands_on(c, S, S)
+    choice = _row("attn", c).hands_on(c, S, S, prompt=True)
     # what an admission reports of its routed layers (none: an empty dict)
     routed_stats = {k: jnp.zeros((), jnp.int32)
                     for k in prefill_stat_keys(c)}
     carry = (x, single, choice, routed_stats)
+    narrowed = False
     for stack, lc, first in layer_groups(params, c):
-        def layer(carry, lp, li, lc=lc):
-            x, single, choice, total = carry
-            attn = _row(layer_kind(lp), lc).prefill(
-                single, li, lp, lc, prompt_holds, choice)
-            y, _aux, single, stats = apply_block(
-                x, lp, lc, positions, attn, token_mask=real)
-            if choice is not None:
-                single, choice = single
-            return y, single, choice, _add_stats(total, {
-                "prefill_" + k: v for k, v in stats.items()})
+        for part, at, last_token in _prompt_parts(stack, lc, first):
+            if last_token:  # from here on: the last real token alone
+                narrowed = True
+                x, single, choice, total = carry
+                carry = (lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, 1),
+                         single, jax.tree.map(
+                             lambda a: lax.dynamic_slice_in_dim(
+                                 a, prompt_len - 1, 1, 0), choice), total)
+                positions, real = (
+                    lax.dynamic_slice_in_dim(a, prompt_len - 1, 1, -1)
+                    if a is not None else None for a in (positions, real))
 
-        carry = scan_stack(layer, carry, stack, lc, first)
+            def layer(carry, lp, li, lc=lc, positions=positions, real=real):
+                x, single, choice, total = carry
+                attn = _row(layer_kind(lp), lc).prefill(
+                    single, li, lp, lc, prompt_holds, choice)
+                y, _aux, single, stats = apply_block(
+                    x, lp, lc, positions, attn, token_mask=real)
+                if isinstance(single, tuple):  # it hands something on
+                    single, choice = single
+                return y, single, choice, _add_stats(total, {
+                    "prefill_" + k: v for k, v in stats.items()})
+
+            # (a scope of its own: what the last-token rule leaves of them)
+            with jax.named_scope("raytpu.upper.last_token"
+                                 ) if last_token else nullcontext():
+                carry = scan_stack(layer, carry, part, lc, at)
     x, single, _choice, routed_stats = carry
-    x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
+    x = _norm(x, params["final_ln"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    last = x[0, prompt_len - 1]  # [D] — last REAL token's features
+    # [D] — last REAL token's features (all that is left where the upper
+    # layers ran on that token alone)
+    last = x[0, 0] if narrowed else x[0, prompt_len - 1]
     logits = last @ head.astype(c.dtype)
     if c.logit_scale != 1.0:
         logits = logits * c.logit_scale

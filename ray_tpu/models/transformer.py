@@ -28,8 +28,13 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   FFNs and lead with dense layers), or layers whose mixer is a gated
   delta rule ("kda", ``ops/kda.py``: a matrix state a head under a decay
   a channel, beside attention layers of either mixer, every FFN routed
-  but the leading ones'), one stack of parameters a kind, run in the
-  order the list gives;
+  but the leading ones'), or a decoder-hybrid-decoder's five kinds in
+  one list ("mamba": a recurrence with a decay a (channel, state) pair,
+  ``ops/mamba.py``; "window" and "attention" layers with differential
+  attention; and two kinds that keep NOTHING of their own: "cross" layers
+  that attend the one "attention" layer's rows and "gmu" layers gated by
+  the last "mamba" layer's output), one stack of parameters a kind, run
+  in the order the list gives;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -50,6 +55,7 @@ from jax import lax
 
 from ray_tpu.ops.attention import causal_attention, window_attention
 from ray_tpu.ops.kda import kda_chunked
+from ray_tpu.ops.mamba import mamba_scan
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked
 
 
@@ -200,12 +206,44 @@ class TransformerConfig:
     kda_conv: int = 4
     kda_chunk: int = 64  # tokens a chunk of the prefill's delta rule
     mla_rope: bool = True
+    # A decoder-hybrid-decoder (SambaY, Phi-4-mini-flash): three more kinds
+    # in layer_types (mixer "mha", sequential residual, dense FFN), beside
+    # "attention" and "window" layers. "mamba": a Mamba-1 mixer over
+    # mamba_inner channels and mamba_state state dims: [x | z] = W h; x =
+    # silu(conv(x)), causal depthwise, mamba_conv taps, with bias; [dt_low |
+    # B | C] = W_x x (mamba_dt_rank + 2 x mamba_state); dt = softplus(W_dt
+    # dt_low + b) a channel; A = -exp(a_log) [state, channel]; h_t[n, c] =
+    # exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]; y_t[c] =
+    # sum_n h_t[n, c] C_t[n] + D[c] x_t[c]; out = W_out (y * silu(z))
+    # (ops/mamba.py). "gmu", a gated memory unit: out = W_2 (m * silu(W_1
+    # h)), m the y (before its gate) of the nearest "mamba" layer below:
+    # no state, no cache. "cross": queries and an output projection alone,
+    # attending the K/V rows of the LAST "attention" layer below, every row
+    # s <= t: no K/V weights, no cache of its own. Their parameters are
+    # params["mamba_layers" | "gmu_layers" | "cross_layers"]. For every
+    # "mha" layer of any model: norm "layer" is LayerNorm with a scale and a
+    # bias (mean subtracted) where "rms" is RMSNorm; attn_bias puts a bias
+    # on the q, k, v and output projections; diff_attn pairs the heads:
+    # query heads (2i, 2i + 1) and KV heads (2j, 2j + 1), j = i // (n_heads
+    # / kv_heads), V_j = [v_2j | v_2j+1]; o_i = A(q_2i, k_2j, V_j) - lambda
+    # A(q_2i+1, k_2j+1, V_j), lambda = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    # lambda_init, lambda_init = 0.8 - 0.6 exp(-0.3 depth) by the layer's
+    # index in the model; then RMSNorm_w(o_i) over its 2 x d_head channels
+    # times (1 - lambda_init); W_o takes the n_heads / 2 outputs.
+    norm: str = "rms"
+    attn_bias: bool = False
+    diff_attn: bool = False
+    mamba_inner: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_dt_rank: int = 0
 
     def __post_init__(self):
         if self.layer_types:
             kinds = tuple(self.layer_types)
             if len(kinds) != self.n_layers or set(kinds) - {
-                    "attention", "ssm", "window", "kda"} or (
+                    "attention", "ssm", "window", "kda", "mamba", "gmu",
+                    "cross"} or (
                     self.mixer != "mha" and set(kinds) - {
                         "attention", "kda"}) or (
                     self.residual != "sequential") or (
@@ -216,11 +254,33 @@ class TransformerConfig:
                         % self.ssm_groups)):
                 raise ValueError(
                     "layer_types needs one entry a layer ('attention' | "
-                    "'ssm' | 'window' | 'kda'), mixer 'mha' (beside 'kda' "
-                    "layers alone: either mixer), a sequential block; "
-                    "beside 'ssm' layers a dense FFN, no 'window' layer, "
-                    "and the ssm_* sizes (heads a multiple of groups)")
+                    "'ssm' | 'window' | 'kda' | 'mamba' | 'gmu' | 'cross'), "
+                    "mixer 'mha' (beside 'kda' layers alone: either "
+                    "mixer), a sequential block; 'attention' stands beside "
+                    "every kind, 'window' beside all but 'ssm' and 'kda', "
+                    "'mamba', 'gmu' and 'cross' beside each other, "
+                    "'attention' and 'window'; beside 'ssm' layers a dense "
+                    "FFN and the ssm_* sizes (heads a multiple of groups)")
             n_dense = self.n_dense_layers if self.moe_experts else 0
+            upper = set(kinds) & {"mamba", "gmu", "cross"}
+            if upper and (
+                    set(kinds) & {"ssm", "kda"} or self.moe_experts
+                    or ("mamba" in kinds and (
+                        not self.mamba_inner * self.mamba_state
+                        * self.mamba_dt_rank or self.mamba_inner % 8
+                        or self.mamba_conv < 2))
+                    or ("gmu" in kinds and "mamba" not in
+                        kinds[:kinds.index("gmu")])
+                    or ("cross" in kinds and (
+                        "attention" not in kinds[:kinds.index("cross")]
+                        or "attention" in kinds[kinds.index("cross"):]))):
+                raise ValueError(
+                    "'mamba', 'gmu' and 'cross' layers stand beside "
+                    "'attention' and 'window' layers alone, under a dense "
+                    "FFN; a 'mamba' layer needs mamba_inner (a multiple of "
+                    "8), mamba_state, mamba_dt_rank and mamba_conv >= 2, a "
+                    "'gmu' layer a 'mamba' layer below it, and a 'cross' "
+                    "layer an 'attention' layer below it and none above")
             if "kda" in kinds and (
                     set(kinds) - {"attention", "kda"}
                     or not self.kda_heads * self.kda_head_dim
@@ -244,6 +304,14 @@ class TransformerConfig:
             object.__setattr__(self, "layer_types", kinds)
         elif self.window:
             raise ValueError("window needs 'window' layers in layer_types")
+        if self.norm not in ("rms", "layer") or (
+                (self.diff_attn or self.attn_bias) and self.mixer != "mha"
+                ) or (self.diff_attn and (
+                    self.n_heads % 2 or self.kv_heads % 2
+                    or (self.window_kv_heads or 2) % 2)):
+            raise ValueError(
+                "norm is 'rms' or 'layer'; attn_bias and diff_attn need "
+                "mixer 'mha', diff_attn even counts of heads")
         if self.index_topk:
             kinds = tuple(self.indexer_types)
             if self.mixer != "mla" or len(kinds) != self.n_layers or (
@@ -287,12 +355,18 @@ class TransformerConfig:
     def n_kda_layers(self) -> int:
         return sum(k == "kda" for k in self.layer_types)
 
+    def n_of(self, kind: str) -> int:
+        """How many of the model's layers ``layer_types`` lists as
+        ``kind``."""
+        return sum(k == kind for k in self.layer_types)
+
     @property
     def n_attn_layers(self) -> int:
-        """Layers that attend every row: the layers the K/V cache holds
-        rows for."""
+        """Layers that attend every row AND keep them: the layers the K/V
+        cache holds rows for."""
         return (self.n_layers - self.n_ssm_layers - self.n_window_layers
-                - self.n_kda_layers)
+                - self.n_kda_layers - self.n_of("mamba") - self.n_of("gmu")
+                - self.n_of("cross"))
 
     @property
     def kda_inner(self) -> int:
@@ -362,7 +436,22 @@ class TransformerConfig:
         wkv = self.mha_kind(True)[0]
         window = (d * dh * (h + wkv) + d * self.v_dim * wkv
                   + h * self.v_dim * d + h * self.window_sink)
-        norms = d * (2 if self.residual == "sequential" else 1)
+        # a "cross" layer's queries and output projection
+        cross = d * dh * h + h * self.v_dim * d
+        if self.attn_bias:
+            attn += dh * (h + kv) + self.v_dim * kv + d
+            window += dh * (h + wkv) + self.v_dim * wkv + d
+            cross += dh * h + d
+        if self.diff_attn:  # four lambda vectors and the pair's norm
+            attn, window, cross = (n + 4 * dh + 2 * self.v_dim
+                                   for n in (attn, window, cross))
+        mi, ms, mr = self.mamba_inner, self.mamba_state, self.mamba_dt_rank
+        mamba = (d * 2 * mi + mi * (self.mamba_conv + 1)
+                 + mi * (mr + 2 * ms) + mr * mi + mi + ms * mi + mi + mi * d)
+        upper = (self.n_of("mamba") * mamba + self.n_of("gmu") * 2 * d * mi
+                 + self.n_of("cross") * cross)
+        norms = d * (2 if self.residual == "sequential" else 1) * (
+            2 if self.norm == "layer" else 1)
         n_dense = self.n_dense_layers if self.moe_experts else 0
         indexer = self.n_index_layers * (
             self.q_lora_rank * self.index_n_heads * self.index_head_dim
@@ -376,10 +465,11 @@ class TransformerConfig:
                + 2 * (d * kd + kd * ki) + d * self.kda_heads + kd + ki * d)
         layers = (self.n_attn_layers * attn + self.n_ssm_layers * ssm
                   + self.n_window_layers * window + self.n_kda_layers * kda
-                  + self.n_layers * norms + n_dense * dense_ffn
+                  + upper + self.n_layers * norms + n_dense * dense_ffn
                   + (self.n_layers - n_dense) * ffn + indexer)
         head = 0 if self.tie_embeddings else d * self.vocab_size
-        return self.vocab_size * d + layers + d + head
+        final = d * (2 if self.norm == "layer" else 1)
+        return self.vocab_size * d + layers + final + head
 
     # ---- canonical sizes ----
     @staticmethod
@@ -611,6 +701,52 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     @staticmethod
+    def phi4_mini_flash(**kw) -> "TransformerConfig":
+        """Phi-4-mini-flash-reasoning (microsoft/Phi-4-mini-flash-reasoning
+        config.json, model_type phi4flash; arXiv:2507.06607) as published:
+        32 layers ``(mamba window) x 8, mamba attention, (gmu cross) x 7``.
+        Mamba-1 over 5,120 channels (state 16, 4 taps, dt rank 160);
+        differential attention with 40 query and 20 KV heads of 64, a
+        window of 512 rows on the odd layers below the middle, every row
+        at layer 17, whose rows the seven "cross" layers above attend too;
+        the seven "gmu" layers are gated by layer 16's recurrence output.
+        LayerNorm with a bias, projections with a bias, no positional
+        term, gated SiLU FFNs 10,240 wide, a tied head."""
+        base = dict(
+            vocab_size=200064, d_model=2560, n_layers=32, n_heads=40,
+            n_kv_heads=20, d_head=64, d_ff=10240, rotary_dim=0,
+            max_seq_len=262144, residual="sequential", activation="silu",
+            gated_ffn=True, norm_eps=1e-5, tie_embeddings=True,
+            norm="layer", attn_bias=True, diff_attn=True, window=512,
+            layer_types=("mamba", "window") * 8 + ("mamba", "attention")
+            + ("gmu", "cross") * 7,
+            mamba_inner=5120, mamba_state=16, mamba_conv=4,
+            mamba_dt_rank=160,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_sambay(**kw) -> "TransformerConfig":
+        """The same kind of model at test size (CPU): eight layers ``mamba
+        window mamba window | mamba attention | gmu cross``, 128 channels
+        over a state of 16 (dt rank 4), a window of 8 rows, 8 query and 4
+        KV heads of 64 (four differential heads over two pairs of KV
+        heads)."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=8, n_heads=8, n_kv_heads=4,
+            d_head=64, d_ff=96, rotary_dim=0, max_seq_len=1024,
+            residual="sequential", activation="silu", gated_ffn=True,
+            norm_eps=1e-5, tie_embeddings=True, norm="layer",
+            attn_bias=True, diff_attn=True, window=8,
+            layer_types=("mamba", "window") * 2 + ("mamba", "attention",
+                                                   "gmu", "cross"),
+            mamba_inner=128, mamba_state=16, mamba_conv=4, mamba_dt_rank=4,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny_mla_moe(**kw) -> "TransformerConfig":
         """The same block at test size (CPU)."""
         base = dict(
@@ -660,6 +796,36 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     def dense_init(key, shape, fan_in):
         return (jax.random.normal(key, shape) * (fan_in ** -0.5)).astype(pd)
 
+    def norm_init(shape, key):
+        # a LayerNorm's bias: 0 in a fresh model, here drawn so that a
+        # comparison with a reference sees it
+        return {"scale": jnp.ones(shape, pd),
+                **({"bias": (0.02 * jax.random.normal(key, shape)
+                             ).astype(pd)} if c.norm == "layer" else {})}
+
+    def mha_extras(key, L, h_kv, queries_only=False):
+        """What ``attn_bias`` and ``diff_attn`` add to an "mha" mixer (a
+        "cross" layer's: the queries' and the output's alone). Biases are
+        drawn, not 0, so that a comparison sees them; the four lambda
+        vectors normal(0, 0.1) as the differential transformer draws
+        them, the pair's norm 1."""
+        ks = jax.random.split(jax.random.fold_in(key, 9), 5)
+        out = {}
+        if c.attn_bias:
+            out["bq"] = 0.02 * jax.random.normal(
+                ks[0], (L, c.n_heads, c.d_head))
+            out["bo"] = 0.02 * jax.random.normal(ks[3], (L, c.d_model))
+            if not queries_only:
+                out["bk"] = 0.02 * jax.random.normal(
+                    ks[1], (L, h_kv, c.d_head))
+                out["bv"] = 0.02 * jax.random.normal(
+                    ks[2], (L, h_kv, c.v_dim))
+        if c.diff_attn:
+            out["lambda"] = 0.1 * jax.random.normal(
+                ks[4], (L, 4, c.d_head))
+            out["subln"] = jnp.ones((L, 2 * c.v_dim))
+        return {k: v.astype(pd) for k, v in out.items()}
+
     def gate(key, shape, fan_in):  # the third matrix of a gated FFN
         return {"wg": dense_init(jax.random.fold_in(key, 2), shape, fan_in)
                 } if c.gated_ffn else {}
@@ -675,9 +841,9 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
             [jax.random.fold_in(k, salt) for k in (k_q, k_k, k_v, k_o,
                                                    k_wi, k_wo)])
         d = lc.d_model
-        layers = {"ln1": {"scale": jnp.ones((L, d), pd)}}
+        layers = {"ln1": norm_init((L, d), jax.random.fold_in(kq, 21))}
         if lc.residual == "sequential":
-            layers["ln2"] = {"scale": jnp.ones((L, d), pd)}
+            layers["ln2"] = norm_init((L, d), jax.random.fold_in(kq, 22))
         if not attends:
             pass
         elif lc.mixer == "mla":
@@ -717,8 +883,9 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                 "wq": dense_init(kq, (L, d, lc.n_heads, lc.d_head), d),
                 "wk": dense_init(kk, (L, d, h_kv, lc.d_head), d),
                 "wv": dense_init(kv, (L, d, h_kv, lc.v_dim), d),
-                "wo": dense_init(ko, (L, lc.n_heads, lc.v_dim, d),
+                "wo": dense_init(ko, (L,) + wo_shape(lc),
                                  lc.n_heads * lc.v_dim),
+                **mha_extras(kq, L, h_kv),
             }
             if window and lc.window_sink:
                 # trained in a published model; here seeded around
@@ -770,6 +937,64 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                 "wo": dense_init(kwo, (L, lc.d_ff, d), lc.d_ff),
                 **gate(kwi, (L, d, lc.d_ff), d),
             }
+        return layers
+
+    def wo_shape(lc):  # a differential pair's outputs lie side by side
+        r = 2 if lc.diff_attn else 1
+        return (lc.n_heads // r, r * lc.v_dim, lc.d_model)
+
+    def mamba_stack(L: int) -> Dict:
+        """L "mamba" layers: norms and FFN as ``stack`` draws them, and
+        the mixer's own parameters with Mamba-1's own initialisers: A =
+        -exp(a_log) with exp(a_log) = 1..N along the state dims of every
+        channel, dt_bias the inverse softplus of a step log-uniform in
+        0.001-0.1 a channel, the step's projection U(+-1/sqrt(dt_rank)),
+        D = 1, the convolution and its bias U(+-1/sqrt(taps))."""
+        layers = stack(c, L, 29, 0, attends=False)
+        d, inner, n, r = (c.d_model, c.mamba_inner, c.mamba_state,
+                          c.mamba_dt_rank)
+        ks = jax.random.split(jax.random.fold_in(k_q, 31), 8)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (L, inner), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+        bound = c.mamba_conv ** -0.5
+
+        def uniform(key, shape, bound):
+            return jax.random.uniform(
+                key, shape, minval=-bound, maxval=bound).astype(pd)
+
+        layers["mamba"] = {
+            "wx": dense_init(ks[0], (L, d, inner), d),
+            "wz": dense_init(ks[1], (L, d, inner), d),
+            "conv_w": uniform(ks[2], (L, c.mamba_conv, inner), bound),
+            "conv_b": uniform(ks[3], (L, inner), bound),
+            "wxp": dense_init(ks[4], (L, inner, r + 2 * n), inner),
+            "wdt": uniform(ks[6], (L, r, inner), r ** -0.5),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+            "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1.0, n + 1))[None, :, None], (L, n, inner)).astype(pd),
+            "d": jnp.ones((L, inner), pd),
+            "wo": dense_init(ks[7], (L, inner, d), inner),
+        }
+        return layers
+
+    def gmu_stack(L: int) -> Dict:
+        layers = stack(c, L, 37, 0, attends=False)
+        kg = jax.random.fold_in(k_q, 41)
+        layers["gmu"] = {
+            "wi": dense_init(kg, (L, c.d_model, c.mamba_inner), c.d_model),
+            "wo": dense_init(jax.random.fold_in(kg, 1),
+                             (L, c.mamba_inner, c.d_model), c.mamba_inner)}
+        return layers
+
+    def cross_stack(L: int) -> Dict:
+        layers = stack(c, L, 43, 0, attends=False)
+        kc = jax.random.fold_in(k_q, 47)
+        layers["cross"] = {
+            "wq": dense_init(kc, (L, c.d_model, c.n_heads, c.d_head),
+                             c.d_model),
+            "wo": dense_init(jax.random.fold_in(kc, 1), (L,) + wo_shape(c),
+                             c.n_heads * c.v_dim),
+            **mha_extras(kc, L, 0, queries_only=True)}
         return layers
 
     def ssm_stack(L: int) -> Dict:
@@ -846,8 +1071,12 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                   ).astype(pd),
         "layers": stack(c, c.n_attn_layers - n_dense + dense_kda, 0,
                         n_dense),
-        "final_ln": {"scale": jnp.ones((c.d_model,), pd)},
+        "final_ln": norm_init((c.d_model,), jax.random.fold_in(k_emb, 1)),
     }
+    for kind, make in (("mamba", mamba_stack), ("gmu", gmu_stack),
+                       ("cross", cross_stack)):
+        if c.n_of(kind):
+            params[_KIND_STACKS[kind]] = make(c.n_of(kind))
     if c.n_ssm_layers:
         params["ssm_layers"] = ssm_stack(c.n_ssm_layers)
     if c.n_window_layers:
@@ -871,10 +1100,27 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
     def gate(axes):
         return {"wg": axes} if config.gated_ffn else {}
 
+    def norm_axes(axes):
+        return {"scale": axes,
+                **({"bias": axes} if config.norm == "layer" else {})}
+
+    def mha_extras(queries_only=False):
+        out = {}
+        if config.attn_bias:
+            out.update(bq=("layers", "heads", "head_dim"),
+                       bo=("layers", "embed"))
+            if not queries_only:
+                out.update(bk=("layers", "kv_heads", "head_dim"),
+                           bv=("layers", "kv_heads", "head_dim"))
+        if config.diff_attn:
+            out.update({"lambda": ("layers", None, None),
+                        "subln": ("layers", None)})
+        return out
+
     def stack(lc: TransformerConfig, first: int, L: int) -> Dict:
-        layers = {"ln1": {"scale": ("layers", "embed")}}
+        layers = {"ln1": norm_axes(("layers", "embed"))}
         if lc.residual == "sequential":
-            layers["ln2"] = {"scale": ("layers", "embed")}
+            layers["ln2"] = norm_axes(("layers", "embed"))
         if lc.mixer == "mla":
             layers["attn"] = {
                 **({"wdq": ("layers", "embed", None),
@@ -902,6 +1148,7 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
                 "wk": ("layers", "embed", "kv_heads", "head_dim"),
                 "wv": ("layers", "embed", "kv_heads", "head_dim"),
                 "wo": ("layers", "heads", "head_dim", "embed"),
+                **mha_extras(),
             }
         if lc.moe_experts:
             wi = ("layers", "experts", "embed", "mlp")
@@ -930,8 +1177,30 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
     axes = {
         "embed": ("vocab", "embed"),
         "layers": stack(config, n_dense, config.n_layers - n_dense),
-        "final_ln": {"scale": ("embed",)},
+        "final_ln": norm_axes(("embed",)),
     }
+    for kind, mixer in (
+            ("mamba", {
+                "wx": ("layers", "embed", "mlp"),
+                "wz": ("layers", "embed", "mlp"),
+                "conv_w": ("layers", None, "mlp"),
+                "conv_b": ("layers", "mlp"),
+                "wxp": ("layers", "mlp", None),
+                "wdt": ("layers", None, "mlp"),
+                "dt_bias": ("layers", "mlp"),
+                "a_log": ("layers", None, "mlp"),
+                "d": ("layers", "mlp"),
+                "wo": ("layers", "mlp", "embed")}),
+            ("gmu", {"wi": ("layers", "embed", "mlp"),
+                     "wo": ("layers", "mlp", "embed")}),
+            ("cross", {"wq": ("layers", "embed", "heads", "head_dim"),
+                       "wo": ("layers", "heads", "head_dim", "embed"),
+                       **mha_extras(queries_only=True)})):
+        if config.n_of(kind):
+            layers = stack(config, 0, config.n_of(kind))
+            del layers["attn"]
+            layers[kind] = mixer
+            axes[_KIND_STACKS[kind]] = layers
     if config.n_ssm_layers:
         ssm = stack(config, 0, config.n_ssm_layers)
         del ssm["attn"]
@@ -985,7 +1254,9 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
 
 # where ``init_params`` puts the stack of each kind ``layer_types`` names
 _KIND_STACKS = {"attention": "layers", "ssm": "ssm_layers",
-                "window": "window_layers", "kda": "kda_layers"}
+                "window": "window_layers", "kda": "kda_layers",
+                "mamba": "mamba_layers", "gmu": "gmu_layers",
+                "cross": "cross_layers"}
 
 
 def layer_groups(params: Dict, config: TransformerConfig):
@@ -1031,19 +1302,28 @@ def scan_stack(body, carry, stack: Dict, lc: TransformerConfig, first: int):
     (``index_own``), which of the stack's it is (``index_local``) and
     which of the model's choices it attends (``index_slot``).
 
-    Layers of two kinds with different parameters (``lc.layer_types``)
-    are two stacks, ``stack[kind]`` (``layer_groups``), run in the listed
-    order by ``_scan_kinds``; ``li`` then counts the MODEL's layers of the
-    layer's own kind (its place in whatever cache leaf that kind keeps;
-    its place in its kind's stack is that less the kind's layers before
-    ``first``)."""
+    Layers of several kinds with different parameters
+    (``lc.layer_types``) are a stack a kind, ``stack[kind]``
+    (``layer_groups``), run in the listed order by ``_scan_kinds``, which
+    cuts the list into SEGMENTS of one period each (``(mamba window) x 8,
+    mamba attention, (gmu cross) x 7``: three scans); ``li`` then counts
+    the MODEL's layers of the layer's own kind (its place in whatever
+    cache leaf that kind keeps; its place in its kind's stack is that
+    less the kind's layers before ``first``). ``stack`` may hold some of
+    the kinds only: the layers from ``first`` on that are of those kinds
+    (a prefill runs the layers that keep nothing apart from the others).
+    A differential attention's layer is told its index in the model,
+    ``depth`` in its mixer's weights: its lambda_init follows from it."""
     if lc.layer_types:
         n = sum(s["ln1"]["scale"].shape[0] for s in stack.values())
         before = lc.layer_types[:first]
+        depths = {kind: jnp.array(
+            [i for i, k in enumerate(lc.layer_types) if k == kind],
+            jnp.float32) for kind in stack} if lc.diff_attn else {}
         return _scan_kinds(
             body, carry, stack, lc.layer_types[first:first + n],
             {kind: before.count(kind) for kind in stack},
-            lc.moe_experts and lc.moe_impl == "dropless")
+            lc.moe_experts and lc.moe_impl == "dropless", depths)
     n = stack["ln1"]["scale"].shape[0]
     held, kinds = {}, None
     if lc.moe_experts and lc.moe_impl == "dropless":
@@ -1082,32 +1362,55 @@ def _hold_experts(stack: Dict):
                                    if k not in held}}
 
 
+def _segments(kinds: Tuple[str, ...]):
+    """``kinds`` cut into segments ``(start, period, repeats)``, each a
+    period of layers repeated, so that the periods' runs of one kind are
+    as few as can be in all (the bodies ``_scan_kinds`` makes), then the
+    segments as few, then the periods as short. ``(ssm x 5, attention,
+    ssm x 4) x 4`` is one segment of three runs; ``(mamba window) x 8,
+    mamba attention, (gmu cross) x 7`` has no period of its own and is
+    three segments of two runs each."""
+    n = len(kinds)
+
+    def runs(period):
+        return 1 + sum(a != b for a, b in zip(period, period[1:]))
+
+    best = {n: (0, 0, ())}  # from layer i on: (bodies, segments, the cut)
+    for i in range(n - 1, -1, -1):
+        for p in range(1, n - i + 1):
+            period, reps = kinds[i:i + p], 1
+            while kinds[i + reps * p:i + (reps + 1) * p] == period:
+                reps += 1
+            for r in range(reps, 0, -1):
+                bodies, segments, cut = best[i + r * p]
+                found = (bodies + runs(period), segments + 1,
+                         ((i, period, r),) + cut)
+                if i not in best or found[:2] < best[i][:2]:
+                    best[i] = found
+    return best[0][2]
+
+
 def _scan_kinds(body, carry, stacks: Dict, kinds: Tuple[str, ...],
-                offsets: Dict[str, int], routed: bool = False):
+                offsets: Dict[str, int], routed: bool = False,
+                depths: Optional[Dict] = None):
     """``body(carry, lp, li) -> carry`` over layers whose kind, one of
     ``stacks``' keys, is listed in ``kinds``; ``lp`` is the layer's slice
     of its kind's stack and ``li`` its index there plus ``offsets[kind]``
     (the kind's layers that ran before these stacks). Where the layers
     are ``routed`` the experts' weights stay whole in ``lp`` beside
-    ``lp["moe"]["layer"]``, as in ``scan_stack``. The list is cut into
-    its shortest repeating period (ten layers, four times) and a period
-    into runs of one kind: a ``lax.scan`` over the periods holds one
-    ``lax.scan`` a run (a run of one layer: the body itself), so the
-    program has one body a RUN OF THE PERIOD, not one a layer. Every scan
+    ``lp["moe"]["layer"]``, as in ``scan_stack``; ``depths[kind]``, where
+    given, is the index in the model of each of the kind's layers, and
+    the layer's own goes into its mixer's weights as ``depth``. The list
+    is cut into SEGMENTS, each one period repeated (``_segments``: ten
+    layers four times is one segment; ``(mamba window) x 8, mamba
+    attention, (gmu cross) x 7`` three), and a period into runs of one
+    kind: a ``lax.scan`` over a segment's periods holds one ``lax.scan``
+    a run (a run of one layer: the body itself), so the program has one
+    body a RUN OF A PERIOD, not one a layer. Every scan
     counts indices and the body takes its layer out of the WHOLE stack
     with a dynamic index, which the compiler fuses into the products that
     read it: a scan handed a run's slice of a stack as its ``xs`` would
     copy the slice first (hundreds of MB a run)."""
-    n = len(kinds)
-    period = next(p for p in range(1, n + 1)
-                  if n % p == 0 and kinds == kinds[:p] * (n // p))
-    runs = []  # (kind, layers of that kind before it in the period, length)
-    for i, kind in enumerate(kinds[:period]):
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, kinds[:i].count(kind), 1])
-
     held = {}
     if routed:
         split = {kind: _hold_experts(stack)
@@ -1116,32 +1419,50 @@ def _scan_kinds(body, carry, stacks: Dict, kinds: Tuple[str, ...],
         stacks = {**stacks, **{kind: rest
                                for kind, (_h, rest) in split.items()}}
 
-    def one_period(carry, rep):
-        for kind, before, length in runs:
-            stack = stacks[kind]
-            base = rep * kinds[:period].count(kind) + before
-
-            def one(carry, j, stack=stack, base=base, kind=kind):
-                li = base + j
-                lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
-                    a, li, 0, keepdims=False), stack)
-                if kind in held:
-                    lp = {**lp, "moe": {**lp["moe"], **held[kind],
-                                        "layer": li}}
-                # no "+ 0": where the stacks hold all of a kind's layers
-                # the program's text stays what it was before offsets
-                at = li + offsets[kind] if offsets[kind] else li
-                return body(carry, lp, at), None
-
-            if length == 1:
-                carry, _ = one(carry, 0)
+    def segment(carry, start: int, period: Tuple[str, ...], repeats: int):
+        runs = []  # (kind, the kind's layers before it, length)
+        for i, kind in enumerate(period):
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
             else:
-                carry, _ = lax.scan(one, carry, jnp.arange(length))
-        return carry, None
+                runs.append([kind, kinds[:start].count(kind)
+                             + period[:i].count(kind), 1])
 
-    if n == period:
-        return one_period(carry, 0)[0]
-    return lax.scan(one_period, carry, jnp.arange(n // period))[0]
+        def one_period(carry, rep):
+            for kind, before, length in runs:
+                stack = stacks[kind]
+                base = rep * period.count(kind) + before
+
+                def one(carry, j, stack=stack, base=base, kind=kind):
+                    li = base + j
+                    lp = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                        a, li, 0, keepdims=False), stack)
+                    if kind in held:
+                        lp = {**lp, "moe": {**lp["moe"], **held[kind],
+                                            "layer": li}}
+                    # no "+ 0": where the stacks hold all of a kind's
+                    # layers the program's text stays what it was before
+                    # offsets
+                    at = li + offsets[kind] if offsets[kind] else li
+                    if depths:
+                        mixer = layer_kind(lp)
+                        lp = {**lp, mixer: {**lp[mixer],
+                                            "depth": depths[kind][at]}}
+                    return body(carry, lp, at), None
+
+                if length == 1:
+                    carry, _ = one(carry, 0)
+                else:
+                    carry, _ = lax.scan(one, carry, jnp.arange(length))
+            return carry, None
+
+        if repeats == 1:
+            return one_period(carry, 0)[0]
+        return lax.scan(one_period, carry, jnp.arange(repeats))[0]
+
+    for start, period, repeats in _segments(kinds):
+        carry = segment(carry, start, period, repeats)
+    return carry
 
 
 # ---------------------------------------------------------------------------
@@ -1152,6 +1473,19 @@ def _rms_norm(x, scale, eps=1e-6):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def _norm(x, p, c: "TransformerConfig"):
+    """The block's norm under its parameters ``p``: RMSNorm with a scale,
+    or (``c.norm`` "layer") LayerNorm with a scale and a bias, the mean
+    subtracted; float32 statistics either way."""
+    if c.norm != "layer":
+        return _rms_norm(x, p["scale"], c.norm_eps)
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return ((x32 * lax.rsqrt(var + c.norm_eps)).astype(x.dtype)
+            * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype))
 
 
 def _rotary(q, k, rotary_dim, positions, base=10000.0):
@@ -1215,17 +1549,72 @@ def select_attn_fn(config: TransformerConfig,
     raise ValueError(f"unknown attn_impl {c.attn_impl!r}")
 
 
+def _diff_pairs(q, k=None, v=None):
+    """Differential attention read as plain grouped-query attention. A
+    pair of query heads (2i, 2i + 1) attends a pair of KV heads (2j, 2j +
+    1): ``A(q_2i, k_2j, V_j)`` and ``A(q_2i+1, k_2j+1, V_j)``, ``V_j =
+    [v_2j | v_2j+1]``. With K_j = [k_2j | k_2j+1] (the two heads as they
+    lie side by side in a row: a reshape) and the queries padded with
+    zeros, [q_2i | 0] and [0 | q_2i+1], that is H query heads over Hkv / 2
+    KV heads of twice the width, and every attention the program has
+    (tiles, windows, the decode kernel over flat rows) computes it as it
+    stands, reading each cached row once. The scale is the narrow head's:
+    every attention scales by 1 / sqrt(its q's width), now twice d_head,
+    so the queries carry sqrt(2). q [B,S,H,D], k [B,S,Hkv,D], v
+    [B,S,Hkv,Dv] -> q [B,S,H,2D], k [B,S,Hkv/2,2D], v [B,S,Hkv/2,2Dv]."""
+    B, S, H, D = q.shape
+    side = jnp.eye(2, dtype=q.dtype) * (2.0 ** 0.5)  # head 2i+e: side e
+    q = (q.reshape(B, S, H // 2, 2, 1, D) * side[:, :, None]
+         ).reshape(B, S, H, 2 * D)
+    if k is None:
+        return q
+    return (q, k.reshape(B, S, k.shape[2] // 2, 2 * D),
+            v.reshape(B, S, v.shape[2] // 2, 2 * v.shape[-1]))
+
+
+def diff_lambdas(wp, c: "TransformerConfig"):
+    """(lambda, lambda_init) of a differential attention layer whose
+    weights ``wp`` hold the four lambda vectors and the layer's ``depth``
+    (its index in the model: ``scan_stack``), float32 scalars."""
+    f32 = jnp.float32
+    init = 0.8 - 0.6 * jnp.exp(-0.3 * wp["depth"].astype(f32))
+    lam = wp["lambda"].astype(f32)
+    return (jnp.exp((lam[0] * lam[1]).sum()) - jnp.exp(
+        (lam[2] * lam[3]).sum()) + init, init)
+
+
+def _diff_combine(out, wp, c: "TransformerConfig"):
+    """The other half of ``_diff_pairs``: the pairs' two attentions out
+    [B,S,H,2Dv] -> o_i = out_2i - lambda out_2i+1, RMSNorm_w over its 2Dv
+    channels, x (1 - lambda_init): [B,S,H/2,2Dv] in out's type (float32
+    inside)."""
+    B, S, H, W = out.shape
+    with jax.named_scope("raytpu.diff.combine"):
+        lam, init = diff_lambdas(wp, c)
+        o = out.astype(jnp.float32).reshape(B, S, H // 2, 2, W)
+        o = o[:, :, :, 0] - lam * o[:, :, :, 1]
+        o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + c.norm_eps)
+        o = o * wp["subln"].astype(jnp.float32) * (1.0 - init)
+    return o.astype(out.dtype)
+
+
 def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn,
                window: bool = False):
     """MHA / GQA. A ``window`` layer (``TransformerConfig.window``) has
     its own KV heads (the weights' shapes) and rotary base, and its
     ``attn_fn`` takes the layer's sink logits as well: ``attn_fn(q, k, v,
-    sink)``, ``sink`` [H] or None."""
+    sink)``, ``sink`` [H] or None. With ``c.diff_attn`` the heads pair
+    (``_diff_pairs``: ``attn_fn`` sees grouped-query attention over heads
+    twice as wide) and the pairs' outputs are subtracted and normed
+    (``_diff_combine``)."""
     scope = "raytpu.swa" if window else "raytpu.attn"
     with jax.named_scope(scope + ".project"):
         q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
         k = jnp.einsum("bsd,dhk->bshk", h, wp["wk"].astype(c.dtype))
         v = jnp.einsum("bsd,dhk->bshk", h, wp["wv"].astype(c.dtype))
+        if c.attn_bias:
+            q, k, v = (x + wp[b].astype(c.dtype)
+                       for x, b in ((q, "bq"), (k, "bk"), (v, "bv")))
         if c.value_scale != 1.0:
             v = v * c.value_scale
         if c.rotary_dim:  # 0: no positional term at all
@@ -1235,6 +1624,8 @@ def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn,
             # every attention (dense, flash, the decode kernel) scales its
             # scores by 1/sqrt(d_head) itself: the queries carry the rest
             q = q * (c.attn_scale * c.d_head ** 0.5)
+        if c.diff_attn:
+            q, k, v = _diff_pairs(q, k, v)
     if window:  # marks raytpu.swa.attend, and .ring where it keeps one
         attn_out = attn_fn(q, k, v, wp.get("sink"))
     else:
@@ -1243,8 +1634,113 @@ def _mha_mixer(h, wp, c: TransformerConfig, positions, attn_fn,
     extra = None
     if isinstance(attn_out, tuple):
         attn_out, extra = attn_out
+    if c.diff_attn:
+        attn_out = _diff_combine(attn_out, wp, c)
     with jax.named_scope(scope + ".project"):
         out = jnp.einsum("bshk,hkd->bsd", attn_out, wp["wo"].astype(c.dtype))
+        if c.attn_bias:
+            out = out + wp["bo"].astype(c.dtype)
+    return out, extra
+
+
+def _cross_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """A "cross" layer's mixer: queries of its own against the K/V rows
+    ANOTHER layer keeps (the last "attention" layer below), every row s <=
+    t: ``attn_fn(q)`` knows where they are (the uncached forward hands the
+    rows on in its carry; the serving paths read that layer's cache:
+    ``generation.py``). No K/V weights, nothing kept."""
+    with jax.named_scope("raytpu.cross.project"):
+        q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
+        if c.attn_bias:
+            q = q + wp["bq"].astype(c.dtype)
+        if c.attn_scale is not None:
+            q = q * (c.attn_scale * c.d_head ** 0.5)
+        if c.diff_attn:
+            q = _diff_pairs(q)
+    with jax.named_scope("raytpu.cross.attend"):
+        attn_out, extra = attn_fn(q)
+    if c.diff_attn:
+        attn_out = _diff_combine(attn_out, wp, c)
+    with jax.named_scope("raytpu.cross.project"):
+        out = jnp.einsum("bshk,hkd->bsd", attn_out, wp["wo"].astype(c.dtype))
+        if c.attn_bias:
+            out = out + wp["bo"].astype(c.dtype)
+    return out, extra
+
+
+def mamba_inputs(x, wp, c: TransformerConfig):
+    """From a "mamba" layer's convolved channels ``x`` [..., inner] (after
+    their SiLU) what the recurrence takes beside them: the step ``dt``
+    [..., inner] (after its softplus) and ``B``, ``C`` [..., state], all
+    float32; and A [state, inner]."""
+    f32 = jnp.float32
+    r, n = c.mamba_dt_rank, c.mamba_state
+    low = jnp.einsum("...f,fr->...r", x, wp["wxp"].astype(c.dtype),
+                     preferred_element_type=f32)
+    dt = jax.nn.softplus(
+        jnp.einsum("...r,rf->...f", low[..., :r].astype(c.dtype),
+                   wp["wdt"].astype(c.dtype), preferred_element_type=f32)
+        + wp["dt_bias"].astype(f32))
+    return (dt, low[..., r:r + n], low[..., r + n:],
+            -jnp.exp(wp["a_log"].astype(f32)))
+
+
+def _mamba_whole_sequence(x, wp, c: TransformerConfig):
+    """A "mamba" layer's recurrence over whole sequences from an empty
+    state (the uncached forward): the convolution, then the scan. x
+    [B,S,inner] before its convolution. Returns (y [B,S,inner], what the
+    layer hands on: its ``y`` as ``m``)."""
+    with jax.named_scope("raytpu.mamba1.conv"):
+        x = jax.nn.silu(causal_conv(x, wp["conv_w"], wp["conv_b"]))
+    with jax.named_scope("raytpu.mamba1.scan"):
+        dt, B, C, A = mamba_inputs(x, wp, c)
+        y, _state = mamba_scan(x, dt, A, B, C, wp["d"])
+    return y, {"m": y}
+
+
+def _mamba_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """The Mamba-1 mixer of a "mamba" layer (``TransformerConfig.
+    layer_types``; the equations are in the config's comment). The
+    recurrence itself, convolution, projections and scan, is
+    ``attn_fn.recur(x, wp) -> (y, extra)`` where the serving paths bring
+    one (they keep the state and the convolution's tail in a slot:
+    ``generation.py``), else the whole sequence from an empty state. ``y``
+    BEFORE its gate is what the "gmu" layers above are gated by: whoever
+    runs the recurrence hands it on in ``extra``."""
+    with jax.named_scope("raytpu.mamba1.project"):
+        x = jnp.einsum("bsd,df->bsf", h, wp["wx"].astype(c.dtype))
+        z = jnp.einsum("bsd,df->bsf", h, wp["wz"].astype(c.dtype))
+    recur = getattr(attn_fn, "recur", None) or partial(
+        _mamba_whole_sequence, c=c)
+    y, extra = recur(x, wp)
+    with jax.named_scope("raytpu.mamba1.gate"):
+        y = y * jax.nn.silu(z)
+    with jax.named_scope("raytpu.mamba1.project"):
+        out = jnp.einsum("bsf,fd->bsd", y, wp["wo"].astype(c.dtype))
+    return out, extra
+
+
+def recalling(m, extra):
+    """A "gmu" layer's ``attn_fn`` (``_gmu_mixer`` calls its ``recall``):
+    ``m`` [B,S,inner] as the last "mamba" layer handed it on, and what the
+    caller wants back beside the mixer's output (a cache as it is)."""
+    def recall():
+        return m, extra
+
+    recall.recall = recall
+    return recall
+
+
+def _gmu_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """A gated memory unit: ``W_2 (m * silu(W_1 h))``, ``m`` the
+    recurrence output of the nearest "mamba" layer below for the same
+    tokens, which ``attn_fn.recall() -> (m [B,S,inner], extra)`` brings.
+    No state, no cache."""
+    m, extra = attn_fn.recall()
+    with jax.named_scope("raytpu.gmu.gate"):
+        g = jnp.einsum("bsd,df->bsf", h, wp["wi"].astype(c.dtype))
+        g = m.astype(c.dtype) * jax.nn.silu(g)
+        out = jnp.einsum("bsf,fd->bsd", g, wp["wo"].astype(c.dtype))
     return out, extra
 
 
@@ -1456,7 +1952,8 @@ def _attn_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
 # under in ``lp`` (``init_params``); a "swa" layer's ``attn_fn`` is a
 # window's.
 _MIXERS = {"attn": _attn_mixer, "ssm": _ssm_mixer, "kda": _kda_mixer,
-           "swa": partial(_mha_mixer, window=True)}
+           "swa": partial(_mha_mixer, window=True), "mamba": _mamba_mixer,
+           "gmu": _gmu_mixer, "cross": _cross_mixer}
 
 
 def layer_kind(lp: Dict) -> str:
@@ -1495,14 +1992,14 @@ def apply_block(
     what ``attn_fn`` returned beside its output, ``moe_stats`` a dict of
     int32 scalars from a dropless routed layer (else empty)."""
     c = config
-    h = _rms_norm(x, lp["ln1"]["scale"], c.norm_eps)
+    h = _norm(x, lp["ln1"], c)
     kind = layer_kind(lp)
     a, extra = _MIXERS[kind](h, lp[kind], c, positions, attn_fn)
     if c.residual_scale != 1.0:
         a = a * c.residual_scale
     if c.residual == "sequential":
         x = x + a
-        h = _rms_norm(x, lp["ln2"]["scale"], c.norm_eps)
+        h = _norm(x, lp["ln2"], c)
     aux, stats = jnp.zeros((), jnp.float32), {}
     if c.moe_experts and c.moe_impl == "dropless":
         from ray_tpu.ops.moe import routed_ffn
@@ -1573,15 +2070,44 @@ def forward(
         with jax.named_scope("raytpu.swa.attend"):
             return window_attention(q, k, v, sink, window=c.window)
 
-    carry = (x, jnp.zeros((), jnp.float32))
+    def handing(kind, handed):
+        """``attn_fn`` of a layer of a model whose layers hand things on
+        (``handed``: the last "mamba" layer's ``m`` and the last
+        "attention" layer's keys and values, for the "gmu" and "cross"
+        layers above). Each returns what it adds to ``handed`` as its
+        extra."""
+        if kind == "swa":
+            return window_fn
+        if kind == "attn":
+            def rows_kept(q, k, v):
+                return attn_fn(q, k, v), {"k": k, "v": v}
+            return rows_kept
+        if kind == "cross":
+            return lambda q: (attn_fn(q, handed["k"], handed["v"]), None)
+        # "gmu"; a "mamba" layer runs its own recurrence
+        return recalling(handed["m"], None)
+
+    hands = {"mamba", "gmu", "cross"} & set(c.layer_types)
+    carry = (x, jnp.zeros((), jnp.float32), None)
+    if hands:  # keys and values as the attentions see them: pairs as one
+        r = 2 if c.diff_attn else 1
+        lead = tokens.shape + (c.kv_heads // r,)
+        carry = carry[:2] + ({
+            "m": jnp.zeros(tokens.shape + (c.mamba_inner,), c.dtype),
+            "k": jnp.zeros(lead + (r * c.d_head,), c.dtype),
+            "v": jnp.zeros(lead + (r * c.v_dim,), c.dtype)},)
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, lc=lc):
-            x, aux = carry
-            y, a, _ = apply_layer(
+            x, aux, handed = carry
+            kind = layer_kind(lp)
+            y, a, extra = apply_layer(
                 x, lp, lc, positions,
-                window_fn if layer_kind(lp) == "swa" else attn_fn,
+                handing(kind, handed) if hands else
+                window_fn if kind == "swa" else attn_fn,
                 mesh=mesh)
-            return (y, aux + a), None
+            if hands and extra:
+                handed = {**handed, **extra}
+            return (y, aux + a, handed), None
 
         layer = remat_wrap(layer, c)
         if lc.layer_types:  # two kinds of layer: scan_stack orders them
@@ -1589,7 +2115,7 @@ def forward(
                                carry, stack, lc, first)
         else:
             carry, _ = lax.scan(layer, carry, stack)
-    x, aux = carry
+    x, aux = carry[:2]
     logits = lm_logits(params, x, c)
     return (logits, aux) if return_aux else logits
 
@@ -1603,7 +2129,7 @@ def embed_tokens(params, tokens, c: TransformerConfig):
 def lm_logits(params, x, c: TransformerConfig):
     """Hidden states [B, S, D] -> logits [B, S, V]: the final norm and
     the head (the embedding's transpose where tied)."""
-    x = _rms_norm(x, params["final_ln"]["scale"], c.norm_eps)
+    x = _norm(x, params["final_ln"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
     return logits * c.logit_scale if c.logit_scale != 1.0 else logits

@@ -29,6 +29,7 @@ from ray_tpu.models import (
     reference,
     reference_dsa,
     reference_kda,
+    reference_sambay,
     reference_ssm,
     reference_swa,
 )
@@ -143,6 +144,25 @@ def _kda_rehearsed(values, note):
     assert probe["kda_layer"]["step_median"] < 0.05
 
 
+def _sambay_rehearsed(values, note):
+    # the rehearsal's engine: one "attention" layer's rows read by it and
+    # by the one "cross" layer above, two rings
+    share = values["engine.shared_rows_share"]
+    assert share is not None and 0 < share < 100
+    end = note["backlog"]["end"]
+    # one cross layer walks what the full layer walks (the host's count
+    # and the device's differ by the blocks in which a lane is retired)
+    assert 0 < abs(end["cross_rows_read"] / end["attn_rows_read"] - 1
+                   ) + 1 < 1.1
+    assert end["window_rows_read"] > 0
+    # three mamba states and tails, two rings of 8 rows
+    assert end["slot_state_bytes"] == (
+        3 * (16 * 256 * 4 + 3 * 256 * 2) + 2 * 8 * 2 * 128 * 2)
+    assert end["slot_row_bytes"] == 2 * 128 * 2
+    probe = note["probe"]
+    assert probe["replayed"] and probe["refused_by"] == []
+
+
 def _latent_hp(cfg):
     return {"n_heads": cfg.n_heads, "qk_nope": cfg.qk_nope_dim,
             "qk_rope": cfg.qk_rope_dim, "kv_rank": cfg.kv_lora_rank,
@@ -161,6 +181,9 @@ SSM = TransformerConfig.tiny_ssm_hybrid(dtype=F32)
 SWA = TransformerConfig.tiny_swa_moe(dtype=F32)
 # K(dense) K K F K F, chunks of 8, 8 experts (2 a token) and a shared one
 KDA = TransformerConfig.tiny_kda_moe(dtype=F32)
+# M W M W | M F | G X: 128 channels over a state of 16, a window of 8 rows,
+# four differential heads over two pairs of KV heads
+SAMBAY = TransformerConfig.tiny_sambay(dtype=F32)
 _SERVED = ("model.decode_step_ms", "device.idle_share.serve",
            "engine.kv_read_share")
 # a prefill of 21 tokens in a bucket of 32, then 12 decode steps (the
@@ -359,6 +382,60 @@ MODELS = {
              "kernel.decode_hbm_share.kda_moe",
              "kernel.kda_update_roofline_share", "model.moe_time_share",
              "model.mla_time_share") + _SERVED, _kda_rehearsed)),
+    "sambay": Model(
+        cfg=SAMBAY, ref=reference_sambay, hp={
+            "n_heads": SAMBAY.n_heads, "n_kv_heads": SAMBAY.kv_heads,
+            "d_head": SAMBAY.d_head, "eps": SAMBAY.norm_eps,
+            "window": SAMBAY.window, "layer_types": SAMBAY.layer_types,
+            "mamba_state": SAMBAY.mamba_state,
+            "mamba_dt_rank": SAMBAY.mamba_dt_rank},
+        copy="benchmarks/reference_sambay.py", foreign=PROGRAM,
+        tol=2e-4, metric=0,
+        stacks={"layers": {"ln1", "ln2", "attn", "mlp"},
+                "mamba_layers": {"ln1", "ln2", "mamba", "mlp"},
+                "window_layers": {"ln1", "ln2", "swa", "mlp"},
+                "gmu_layers": {"ln1", "ln2", "gmu", "mlp"},
+                "cross_layers": {"ln1", "ln2", "cross", "mlp"}},
+        shapes={"mamba_layers/mamba/a_log": (3, 16, 128),
+                "mamba_layers/mamba/wxp": (3, 128, 4 + 32),
+                "window_layers/swa/wo": (2, 4, 128, 64),
+                "window_layers/swa/bk": (2, 4, 64),
+                "layers/attn/lambda": (1, 4, 64),
+                "cross_layers/cross/wq": (1, 64, 8, 64),
+                "gmu_layers/gmu/wi": (1, 64, 128),
+                "layers/ln1/bias": (1, 64)},
+        counters=("cross_rows_read",), state=("mamba", 2e-4),
+        # 20 decode steps: the ring of 8 rows wraps at least twice; the
+        # prompts' lengths lie on both sides of the convolution's taps,
+        # the window and the scan's time block
+        through={
+            **{name: Through({1: n}, 3, 160, bucket, 20)
+               for name, n, bucket in (
+                   ("under_the_taps", 2, 8), ("below_the_window", 5, 8),
+                   ("at_the_window", 8, 8), ("above", 13, 16),
+                   ("a_padded_bucket", 21, 32), ("a_bucket", 32, 32),
+                   ("many_windows", 100, 128))},
+            # lanes at different positions and a parked one between them
+            "lanes_at_different_depths": Through(
+                {0: 70, 2: 9, 3: 31}, 4, 160, 128, 12)},
+        generated=(11, 32), refused=_REFUSED,
+        ablations=(
+            {"lambda_zero": True}, {"m_after_gate": True},
+            {"state_bf16": True}, {"keep_lambda_init": True},
+            {"cross_strict": True}, {"window": 7}, {"window": 9},
+            {"rms_norm": True}, {"state_at_bucket_end": (21, 32)},
+            {"drop_conv_tail": 21}),
+        floor=1e-3, ablated_state=-1,
+        cell=Cell(
+            "serve-phi4flash-reason-saturated", "serve_sambay",
+            "histreason-saturated", ("tpot_p50_ms", "setup_s"),
+            ("model.mamba1_time_share", "model.gmu_time_share",
+             "model.shared_attn_time_share", "engine.shared_rows_share",
+             "model.prefill_mamba1_scan_share", "model.prefill_upper_share",
+             "kernel.decode_hbm_share.sambay",
+             "kernel.mamba_scan_roofline_share",
+             "model.window_attn_time_share") + _SERVED,
+            _sambay_rehearsed)),
 }
 
 

@@ -362,6 +362,80 @@ def test_kda_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "[8192,8192]" not in hlo  # no prompt's scores whole
 
 
+@pytest.mark.parametrize("program", ["decode_block", "admission_16384"])
+def test_sambay_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """Phi-4-mini-flash-reasoning WHOLE (32 layers, the whole vocabulary,
+    bf16) at the benchmark's engine sizes: 48 slots x 16,384 rows. The
+    list of layers has no period: three segments, ``(mamba window) x 8``
+    and ``(gmu cross) x 7`` as scans and ``mamba attention`` inline, so a
+    program holds six layer bodies, not 32 (ISSUE 49); the ONE full
+    layer's rows, the eight rings and the nine [16, 5120] float32 states
+    are updated in place; a decode step reads the rings, the full layer's
+    rows and, from the cross layers, THE SAME rows with the decode
+    attention's kernel; the admission is the engine's fused form at the
+    largest bucket, runs the Mamba-1 recurrence as ``mamba_scan``, never
+    makes a prompt's scores whole and leaves room on a 16 GB chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.phi4_mini_flash(param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 48, 16384)))
+    lanes = (arr((48,)), arr((48,)), arr((48,), jnp.float32), arr((48,)),
+             arr((48,)))
+    if program == "decode_block":
+        low = gen.decode_block.lower(params, cache, *lanes, cfg, 8)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, 16384)), arr(()), arr(()), cache, cfg, lanes,
+            arr((), jnp.float32), arr(()))
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    foot = gen.slot_footprint(cache)
+    assert (foot["state_bytes"], foot["row_bytes"]) == (24_197_120, 5120)
+    cache_bytes = 48 * (foot["state_bytes"] + 16384 * foot["row_bytes"])
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.0 * 2 ** 30
+    hlo = compiled.as_text()
+    for of in ("f32[9,48,16,5120", "bf16[9,48,15360", "bf16[8,48,512,",
+               "bf16[1,48,16384,", "bf16[48,16384,", "bf16[9,2560,",
+               "bf16[7,2560,", "bf16[200064,", "bf16[2560,200064"):
+        assert not _copies(hlo, of), of
+    for scope in ("raytpu.mamba1.project", "raytpu.mamba1.conv",
+                  "raytpu.mamba1.gate", "raytpu.swa.project",
+                  "raytpu.attn.project", "raytpu.diff.combine"):
+        assert scope in hlo, scope
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    if program == "decode_block":
+        # one body a run of a segment's period: the rings', the full
+        # layer's and the cross layers' attentions
+        assert len(calls) == 3
+        assert all("decode_attention" in line for line in calls)
+        assert sum("raytpu.swa.attend" in line for line in calls) == 1
+        assert sum("raytpu.attn.attend" in line for line in calls) == 1
+        assert sum("raytpu.cross.attend" in line for line in calls) == 1
+        assert "raytpu.gmu.gate" in hlo and "raytpu.mamba1.update" in hlo
+    else:
+        scans = [line for line in calls if "mamba_scan" in line]
+        assert len(scans) == 2 and len(calls) == 2  # (M W) x 8 and M F
+        assert all("raytpu.mamba1.scan" in line for line in scans)
+        assert "raytpu.upper.last_token" in hlo
+        assert "[16384,16384]" not in hlo  # no prompt's scores whole
+
+
 @pytest.mark.parametrize("kernel", ["ssm_update", "kda_update"])
 def test_a_state_kernel_told_the_live_lanes_compiles_in_place(
         v5e, kernel, monkeypatch):

@@ -11,8 +11,8 @@ is compiled and no weight is made (shapes alone), so it runs anywhere.
 A PR that means to leave a configuration's programs alone shows it with
 an empty diff against its parent (``git archive`` of the parent into a
 scratch directory); where lines differ they name the programs to measure.
-Names: gptj glm47 glm52 granite mimo kimi train (default: all the tree
-has).
+Names: gptj glm47 glm52 granite mimo kimi phi4flash train (default: all
+the tree has).
 """
 
 from __future__ import annotations
@@ -33,6 +33,7 @@ SERVED = {  # name -> (module of benchmarks/, configuration)
     "granite": ("ssm_model", "granite4-h-micro-bf16-serve"),
     "mimo": ("swa_moe_model", "mimo-v2-flash-l7-e16-bf16-serve"),
     "kimi": ("kda_moe_model", "kimi-linear-l8-e64-bf16-serve"),
+    "phi4flash": ("sambay_model", "phi4-mini-flash-bf16-serve"),
 }
 
 
