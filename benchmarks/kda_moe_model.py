@@ -210,12 +210,13 @@ def slot_bytes(c: Dict, itemsize: int = 2) -> Dict[str, int]:
 def kda_update_cost(c: Dict, slot_layers: float) -> Dict[str, float]:
     """The least ``ops/kda.kda_update`` moves and computes for
     ``slot_layers`` (slot, layer) states stepped once: each float32 state
-    read once and written once, and 8 operations a state element (the
-    decay, two reads' multiply-adds, the write's multiply-add, the
-    output's). The vectors beside a state are 1/32 of it and left out."""
+    read once and written once, and 7 operations a state element (four
+    multiplies: the decay, ``k decayed``, ``k u^T``, ``q new``; three
+    adds: the two sums over Dk and ``decayed + k u^T``). The vectors
+    beside a state are 1/32 of it and left out."""
     elements = c["kda_heads"] * c["kda_head_dim"] ** 2
     return {"bytes": slot_layers * 2 * 4 * elements,
-            "flops": slot_layers * 8 * elements}
+            "flops": slot_layers * 7 * elements}
 
 
 def kda_chunk_flops(c: Dict, tokens: int) -> float:
@@ -246,9 +247,8 @@ def decode_step_bytes(c: Dict, experts_touched: float, latent_rows: float,
       ``moe_experts_touched`` per step);
     - the float32 matrix state of every LIVE lane in every "kda" layer,
       read once and written once (``live_slots``: the engine's
-      ``slot_steps`` per step; the kernel moves a parked lane's as well,
-      which is its own business, and the convolutions' tails, 1/28 of a
-      state, are left out);
+      ``slot_steps`` per step; a parked lane's state is not moved, and
+      the convolutions' tails, 1/28 of a state, are left out);
     - the latent rows the decode attention read, ``latent_rows`` (the
       engine's ``attn_rows_read`` per step: rows of a slot, each
       ``n_full_layers`` x (kv_lora_rank + qk_rope_dim) numbers).

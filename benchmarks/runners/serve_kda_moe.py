@@ -479,35 +479,6 @@ def _make_deployment_class():
                     "largest": float(err.max()),
                     "share_over_5pct": float((err > 0.05).mean())}
 
-        def _compiled_texts(self):
-            """The compiled text of the traced programs AS THE ENGINE
-            RUNS THEM, for their scopes (``readers/scope_time.py`` pairs a
-            traced operation with its scope by instruction name): the
-            decode blocks, and of ``prefill_into_slot`` the fused
-            admission form (lanes, a temperature and a seed), every
-            scalar a numpy value of one dtype as ``LLMEngine._admit``
-            hands them over. Compiled again after the window; the compile
-            cache answers."""
-            import jax.numpy as jnp
-
-            from ray_tpu.models.generation import (
-                decode_block,
-                prefill_into_slot,
-            )
-
-            eng = self.engine
-            lanes = (eng.tok, eng.pos, eng.temps, eng.seeds, eng.counts)
-            blocks = [decode_block.lower(
-                eng.params, eng.cache, *lanes, eng.config, steps)
-                for steps in {eng.burst_block_steps, eng.block_steps}]
-            prefills = [prefill_into_slot.lower(
-                eng.params, jnp.zeros((1, b), jnp.int32), np.int32(1),
-                np.int32(0), eng.cache, eng.config, lanes, np.float32(0.0),
-                np.int32(0)) for b in eng.buckets]
-            return {"decode_block": [x.compile().as_text() for x in blocks],
-                    "prefill_into_slot": [x.compile().as_text()
-                                          for x in prefills]}
-
         def _cmd_trace_reduce(self, keep_copy, rehearsal=False):
             """``runners/serve_mla_moe.py``'s, and the device seconds and
             the calls of the ``kda_update`` kernel inside the traced
@@ -655,7 +626,9 @@ def trace_scalars(tr: Dict, model_dims: Dict, eng: Dict) -> Dict:
     experts, the latent rows and the live lanes' states that the engine's
     counters say a step of that stretch touched, read and stepped; and
     the ``kda_update`` kernel's device time beside the bytes its calls
-    moved (every slot's state of one layer a call, in and out)."""
+    had to move (a call the states of one layer that the stretch's
+    ``state_slots_updated`` says it stepped, in and out: the live lanes',
+    never ``max_slots``)."""
     out = base.trace_scalars(tr, mla._NO_GPTJ_BYTES, eng)
     st = tr.get("stretch_stats") or {}
     need = ("moe_experts_touched", "attn_rows_read", "slot_steps")
@@ -673,8 +646,15 @@ def trace_scalars(tr: Dict, model_dims: Dict, eng: Dict) -> Dict:
     if calls:
         out["kda_update_device_s"] = tr["kernel_s"][KERNEL]
         out["kda_update_calls"] = calls
-        out["kda_update_bytes"] = kda_moe_model.kda_update_cost(
-            model_dims, calls * eng["max_slots"])["bytes"]
+        # a call steps the live lanes' states alone (ops/kda.kda_update,
+        # since PR 46): charge what the stretch's counters say it stepped,
+        # and nothing where they are missing (the share is then left out)
+        if st.get("steps") and "state_slots_updated" in st:
+            lanes = (st["state_slots_updated"] / st["steps"]
+                     / model_dims["n_kda_layers"])
+            out["kda_update_states_per_call"] = lanes
+            out["kda_update_bytes"] = kda_moe_model.kda_update_cost(
+                model_dims, calls * lanes)["bytes"]
     return out
 
 
@@ -731,8 +711,11 @@ def run(ctx) -> Dict:
                         for q in (50, 90, 99)} if s["ttft_ms"] else None,
             "tpot_ms": {q: common.percentile(s["tpot_ms"], q)
                         for q in (50, 90)} if s["tpot_ms"] else None,
-            # traced runs: device seconds by scope, per program
+            # traced runs: device seconds by scope, per program, and
+            # what the kernel's share was made of
             "scope_s": (facts.get("trace") or {}).get("scope_s"),
+            "kda_update": {k: v for k, v in facts["scalars"].items()
+                           if k.startswith("kda_update_")},
         })
     return facts
 

@@ -180,33 +180,43 @@ def _make_deployment_class():
                     "share_over_5pct": float((err > 0.05).mean())}
 
         def _cmd_trace_start(self, trace_dir):
+            # the counters are read INSIDE the traced stretch, next to its
+            # edges: the profiler takes seconds to start and to hand its
+            # trace over, the engine runs on meanwhile, and a house that
+            # fills or empties in those seconds is not the traced one
+            t = super()._cmd_trace_start(trace_dir)
             self._stretch["start"] = self.engine.stats()
-            return super()._cmd_trace_start(trace_dir)
-
-        def _cmd_trace_stop(self):
-            t = super()._cmd_trace_stop()
-            self._stretch["stop"] = self.engine.stats()
             return t
 
+        def _cmd_trace_stop(self):
+            self._stretch["stop"] = self.engine.stats()
+            return super()._cmd_trace_stop()
+
         def _compiled_texts(self):
-            """The compiled text of the traced programs, for their scopes
-            (``readers/scope_time.py``): compiled again from the engine's
-            own arguments, after the window."""
+            """The compiled text of the traced programs AS THE ENGINE
+            RUNS THEM, for their scopes (``readers/scope_time.py`` pairs a
+            traced operation with its scope by instruction name): the
+            decode blocks, and of ``prefill_into_slot`` the fused
+            admission form (lanes, a temperature and a seed), every
+            scalar a numpy value of one dtype as ``LLMEngine._admit``
+            hands them over. Compiled again after the window; the compile
+            cache answers."""
+            import jax.numpy as jnp
+
             from ray_tpu.models.generation import (
                 decode_block,
                 prefill_into_slot,
             )
 
-            import jax.numpy as jnp
-
             eng = self.engine
+            lanes = (eng.tok, eng.pos, eng.temps, eng.seeds, eng.counts)
             blocks = [decode_block.lower(
-                eng.params, eng.cache, eng.tok, eng.pos, eng.temps,
-                eng.seeds, eng.counts, eng.config, steps)
+                eng.params, eng.cache, *lanes, eng.config, steps)
                 for steps in {eng.burst_block_steps, eng.block_steps}]
             prefills = [prefill_into_slot.lower(
-                eng.params, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
-                jnp.int32(0), eng.cache, eng.config) for b in eng.buckets]
+                eng.params, jnp.zeros((1, b), jnp.int32), np.int32(1),
+                np.int32(0), eng.cache, eng.config, lanes, np.float32(0.0),
+                np.int32(0)) for b in eng.buckets]
             return {"decode_block": [x.compile().as_text() for x in blocks],
                     "prefill_into_slot": [x.compile().as_text()
                                           for x in prefills]}

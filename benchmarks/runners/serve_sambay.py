@@ -279,35 +279,6 @@ def _make_deployment_class():
                 "top2_gap": np.asarray(top2[:, 1] - top2[:, 0]).tolist(),
                 "replayed": got["replayed"], "tokens": len(tokens)}
 
-        def _compiled_texts(self):
-            """The compiled text of the traced programs AS THE ENGINE
-            RUNS THEM, for their scopes (``readers/scope_time.py`` pairs a
-            traced operation with its scope by instruction name): the
-            decode blocks, and of ``prefill_into_slot`` the fused
-            admission form (lanes, a temperature and a seed), every
-            scalar a numpy value of one dtype as ``LLMEngine._admit``
-            hands them over. Compiled again after the window; the compile
-            cache answers."""
-            import jax.numpy as jnp
-
-            from ray_tpu.models.generation import (
-                decode_block,
-                prefill_into_slot,
-            )
-
-            eng = self.engine
-            lanes = (eng.tok, eng.pos, eng.temps, eng.seeds, eng.counts)
-            blocks = [decode_block.lower(
-                eng.params, eng.cache, *lanes, eng.config, steps)
-                for steps in {eng.burst_block_steps, eng.block_steps}]
-            prefills = [prefill_into_slot.lower(
-                eng.params, jnp.zeros((1, b), jnp.int32), np.int32(1),
-                np.int32(0), eng.cache, eng.config, lanes, np.float32(0.0),
-                np.int32(0)) for b in eng.buckets]
-            return {"decode_block": [x.compile().as_text() for x in blocks],
-                    "prefill_into_slot": [x.compile().as_text()
-                                          for x in prefills]}
-
         def _cmd_trace_reduce(self, keep_copy, rehearsal=False):
             """``runners/serve_mla_moe.py``'s, and the device seconds and
             the calls of the ``mamba_scan`` kernel inside the traced

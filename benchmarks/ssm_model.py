@@ -155,9 +155,10 @@ def decode_step_bytes(c: Dict, slots_updated: float, kv_rows: float,
       the tied embedding as the output head (the embedding's own gather
       of a few rows is left out);
     - for every slot the program updates, ``slots_updated`` (the engine's
-      ``state_slots_updated`` per step and state layer: every slot,
-      parked or live), its state READ AND WRITTEN: 2 x
-      ``slot_state_bytes``;
+      ``state_slots_updated`` per step and state layer: the states the
+      step moved, which since PR 46 are the live lanes' alone; a parked
+      lane's is counted under ``state_slots_skipped``), its state READ
+      AND WRITTEN: 2 x ``slot_state_bytes``;
     - the K/V rows the counters say the decode attention read,
       ``kv_rows`` (the engine's ``attn_rows_read`` per step: rows of a
       slot, each ``n_attn_layers`` x 2 x Hkv x D numbers).

@@ -99,23 +99,32 @@ def test_the_counts_are_the_issues_table():
         2 * kda_moe_model.kda_chunk_flops(d, 64)
 
 
-def _trace(steps, calls=None):
+def _trace(steps, calls=None, live=96):
+    """A traced stretch of ``steps`` steps with ``live`` of the 96 lanes
+    live: the engine counts the live lanes' states alone, and the kernel,
+    which steps no other, takes their share of a full house's time."""
     tr = {
         "busy_s": 2.99, "window_s": 3.0,
         "programs": {"decode_block": [
             {"id": "jit_decode_block(1)", "start": t, "end": t + 0.13}
             for t in (0.0, 0.2, 0.4)]},
         "marks": [{"name": "bench.dispatch", "stats": {
-            "steps": 8, "live": 96, "kv_rows": 240000}}] * 3,
+            "steps": 8, "live": live, "kv_rows": 240000}}] * 3,
         "stretch_stats": {"steps": steps, "moe_experts_touched": 426 * steps,
                           "attn_rows_read": 240000 * steps,
-                          "slot_steps": 96 * steps,
-                          "state_slots_updated": 96 * 6 * steps},
+                          "slot_steps": live * steps,
+                          "state_slots_updated": live * 6 * steps},
     }
     if calls:
         tr["kernel_calls"] = {"kda_update": calls}
-        tr["kernel_s"] = {"kda_update": calls * 0.00065}
+        tr["kernel_s"] = {"kda_update": calls * 0.00065 * live / 96}
     return tr
+
+
+def _roofline(scalars):
+    spec = _metric("kernel.kda_update_roofline_share")
+    facts = {"scalars": scalars, "peaks": common.PEAKS["TPU v5 lite"]}
+    return common.READERS[spec["reader"]](facts, spec["params"])
 
 
 def test_trace_scalars_charge_what_the_counters_say():
@@ -125,21 +134,53 @@ def test_trace_scalars_charge_what_the_counters_say():
     assert out["decode_steps"] == 24
     assert out["decode_bytes"] == 24 * kda_moe_model.decode_step_bytes(
         d, 426, 240000, 96)
+    # a call is charged the states the stretch's counters say it stepped
+    assert out["kda_update_states_per_call"] == 96
     assert out["kda_update_bytes"] == 144 * 96 * 2 * 2_097_152
     facts = {"scalars": out, "peaks": common.PEAKS["TPU v5 lite"]}
     spec = _metric("kernel.decode_hbm_share.kda_moe")
     share = common.READERS[spec["reader"]](facts, spec["params"])
     assert 70 < share < 80  # 10.0 GB in 16.25 ms
-    spec = _metric("kernel.kda_update_roofline_share")
-    roof = common.READERS[spec["reader"]](facts, spec["params"])
-    assert abs(roof - 100 * 96 * 2 * 2_097_152 / (0.00065 * 819e9)) < 1e-6
+    full = _roofline(out)
+    assert abs(full - 100 * 96 * 2 * 2_097_152 / (0.00065 * 819e9)) < 1e-6
     # a trace without the kernel, a program without the counters: left out
     bare = runner.trace_scalars(_trace(24), d, eng)
-    facts = {"scalars": bare, "peaks": common.PEAKS["TPU v5 lite"]}
-    assert common.READERS[spec["reader"]](facts, spec["params"]) is None
+    assert _roofline(bare) is None
     tr = _trace(24)
     del tr["stretch_stats"]["slot_steps"]
     assert "decode_bytes" not in runner.trace_scalars(tr, d, eng)
+
+
+def test_the_kernels_share_does_not_follow_the_lanes_live():
+    """A quarter of the house parked (72 of 96 lanes live, whatever
+    ``max_slots`` says): the kernel steps 72 states a call in 72/96 of
+    the time and reads the full house's share, not 96/72 of it."""
+    d = _dims()
+    eng = _config()["run"]["engine"]
+    full = runner.trace_scalars(_trace(24, calls=144), d, eng)
+    thin = runner.trace_scalars(_trace(24, calls=144, live=72), d, eng)
+    assert thin["kda_update_states_per_call"] == 72
+    assert thin["kda_update_bytes"] == 144 * 72 * 2 * 2_097_152
+    assert abs(_roofline(thin) - _roofline(full)) < 1e-9
+    assert _roofline(thin) < 100
+
+
+def test_the_kernels_share_is_left_out_without_the_counters():
+    """A program whose engine does not count the states it stepped: no
+    ``kda_update_bytes``, so the share is left out of the line (never a
+    fall back to ``max_slots``); the kernel's own time and calls stay."""
+    d = _dims()
+    eng = _config()["run"]["engine"]
+    for gone in ("state_slots_updated", "steps"):
+        tr = _trace(24, calls=144, live=72)
+        del tr["stretch_stats"][gone]
+        out = runner.trace_scalars(tr, d, eng)
+        assert "kda_update_bytes" not in out
+        assert out["kda_update_calls"] == 144
+        assert _roofline(out) is None
+    tr = _trace(24, calls=144)
+    del tr["stretch_stats"]
+    assert "kda_update_bytes" not in runner.trace_scalars(tr, d, eng)
 
 
 def test_state_live_share_reads_the_engines_counters():
