@@ -203,6 +203,42 @@ def test_the_chunked_form_survives_a_channel_that_forgets_at_once():
     assert float(jnp.abs(got_s - want_s).max()) < 2e-5
 
 
+@pytest.mark.parametrize("heads,length", [
+    (8, 1), (8, 63), (8, 64), (8, 65), (8, 200), (32, 65)])
+def test_the_chunk_kernel_at_the_served_heads_equals_the_recurrence(
+        heads, length):
+    """The served geometry's structure through the interpreter: heads of
+    128 x 128 (whole lanes: four heads a grid step, two of them side by
+    side in the solve), chunks of 64 in four sub-blocks, a batch of two,
+    a state to start from; lengths below, at and above a chunk."""
+    q, k, v, g, beta = _delta_inputs(length, 2, length, heads, 128, 128)
+    state0 = jax.random.normal(jax.random.key(8), (2, heads, 128, 128))
+    want_o, want_s = _token_by_token(q, k, v, g, beta, state0)
+    got_o, got_s = jax.jit(kda_chunked, static_argnums=5)(
+        q, k, v, g, beta, 64, state0)
+    assert float(jnp.abs(got_o - want_o).max()) < 2e-5
+    assert float(jnp.abs(got_s - want_s).max()) < 2e-5
+
+
+def test_the_chunk_kernel_in_bf16_stays_near_the_float32_recurrence():
+    """The operands the chip runs (q, k, v in bf16, g and beta float32)
+    against the recurrence in float32 on the same values: the kernel
+    rounds the large products' operands and nothing else, so ``o`` and the
+    end state stay within 1.5 % of their largest value over 130 tokens
+    (read here: 0.46 % and 0.22 %)."""
+    q, k, v, g, beta = _delta_inputs(6, 1, 130, 4, 128, 128)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    want_o, want_s = _token_by_token(
+        *(a.astype(jnp.float32) for a in (q, k, v)), g, beta)
+    got_o, got_s = kda_chunked(q, k, v, g, beta, 64)
+    assert got_o.dtype == jnp.bfloat16 and got_s.dtype == jnp.float32
+    off = float(jnp.abs(got_o.astype(jnp.float32) - want_o).max()
+                / jnp.abs(want_o).max())
+    assert off < 0.015, off
+    off = float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max())
+    assert off < 0.015, off
+
+
 def test_kda_step_equals_the_references_token(params):
     """One layer's mixer over a sequence through ``kda_step``'s recurrence
     is the reference's, state and all."""
@@ -309,6 +345,25 @@ def test_a_padded_buckets_end_state_is_the_state_at_prompt_len():
     assert float(jnp.abs(padded - exact).max()) < 1e-6
     _, at_end = kda_chunked(q, k, v, g, beta, 8)
     assert float(jnp.abs(at_end - exact).max()) > 1e-2
+
+
+def test_a_chunk_wholly_past_the_prompt_leaves_the_state_bit_for_bit():
+    """Three chunks of 64, the prompt ends with the first: the kernel
+    neither reads nor computes the other two (their ``o`` is zeros) and
+    the end state is, bit for bit, the state after the first chunk alone;
+    a prompt of no tokens hands ``state0`` back bit for bit."""
+    q, k, v, g, beta = _delta_inputs(9, 2, 192, 4, 128, 128)
+    state0 = jax.random.normal(jax.random.key(10), (2, 4, 128, 128))
+    valid = jnp.broadcast_to(jnp.arange(192) < 64, (2, 192))
+    o, padded = kda_chunked(q, k, v, g, beta, 64, state0, valid)
+    first_o, first = kda_chunked(
+        *(a[:, :64] for a in (q, k, v, g, beta)), 64, state0)
+    np.testing.assert_array_equal(padded, first)
+    np.testing.assert_array_equal(o[:, :64], first_o)
+    assert not np.asarray(o[:, 64:]).any()
+    _, untouched = kda_chunked(q, k, v, g, beta, 64, state0,
+                               jnp.zeros((2, 192), bool))
+    np.testing.assert_array_equal(untouched, state0)
 
 
 # -- a parked lane ----------------------------------------------------------

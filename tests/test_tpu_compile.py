@@ -357,7 +357,15 @@ def test_kda_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert sum("decode_attention" in line for line in calls) == 2
         assert sum("raytpu.moe.experts" in line for line in calls) == 8
     else:
-        assert "raytpu.kda.chunk" in hlo
+        # ISSUE 52: the chunked delta rule is ONE kernel a run of the
+        # period under its scope, fed the prompt's live chunks as a
+        # prefetched scalar, and nothing walks the chunks outside it
+        chunks = [line for line in calls if "kda_chunk" in line]
+        assert len(chunks) == 3
+        assert all("raytpu.kda.chunk" in line for line in chunks)
+        assert all("s32[1]" in line for line in chunks)
+        assert not [line for line in hlo.splitlines()
+                    if "raytpu.kda.chunk" in line and " while(" in line]
         assert not any("kda_update" in line for line in calls)
         assert "[8192,8192]" not in hlo  # no prompt's scores whole
 
