@@ -595,9 +595,10 @@ DSA_QUERY_BLOCK = 128
 PREFILL_ATTN_BLOCK = 1024
 PREFILL_HEAD_GROUP = 16
 # A latent prefill whose float32 scores [heads, S, S] would be larger than
-# this attends tile by tile (``blocked_causal_attention``) and never makes
-# them: 32 heads at 8,192 tokens would be 8.6 GB, at 3,072 1.2 GB; 20 heads
-# at 2,048 are 0.34 GB and go as one product.
+# this attends block by block inside one kernel
+# (``blocked_causal_attention``) and never makes them: 32 heads at 8,192
+# tokens would be 8.6 GB, at 3,072 1.2 GB; 20 heads at 2,048 are 0.34 GB
+# and go as one product.
 PREFILL_SCORE_BYTES = 1 << 30
 
 
@@ -968,13 +969,15 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig, schedule):
     return cached_attn
 
 
-def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid):
+def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid,
+                  prompt_len):
     """One prefill layer's ``attn_fn`` for ``c.mixer`` (the counterpart of
     ``_decode_attn``). ``single`` is one slot's cache, ``positions`` the
     padded prompt's [S], ``kv_valid`` [1, S_max] the slot's rows the
-    prompt fills. Latent attention takes the plain form: per-head keys
-    and values are expanded from the prompt's latents and attended
-    causally over the prompt; what the slot keeps is the latent rows.
+    prompt fills, ``prompt_len`` its real tokens. Latent attention takes
+    the plain form: per-head keys and values are expanded from the
+    prompt's latents and attended causally over the prompt; what the slot
+    keeps is the latent rows.
     Returns (output, single with this layer's rows written)."""
     if c.mixer == "mla":
         @_latent
@@ -987,7 +990,7 @@ def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid):
             k, v = mla_expand(c_kv, k_r, wp, c)
             q = jnp.concatenate([q_nope, q_rope], -1)
             if 4 * q.shape[2] * q.shape[1] ** 2 > PREFILL_SCORE_BYTES:
-                return blocked_causal_attention(q, k, v), new
+                return blocked_causal_attention(q, k, v, prompt_len), new
             return causal_attention(q, k, v), new
 
         return cached_attn
@@ -1001,9 +1004,10 @@ def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid):
         cv2 = _put_layer(cv_all, v_rows[None], li)
         new = {**single, "k": ck2, "v": cv2}
         if c.window:
-            # as below, and without the prompt's [S, S] scores: tile by
-            # tile (float32 scores of 64 heads at 16,384 are 68 GB)
-            return blocked_causal_attention(q, k, v), new
+            # as below, and without the prompt's [S, S] scores (float32
+            # scores of 64 heads at 16,384 are 68 GB): one kernel that
+            # keeps a block's scores in fast memory and skips the padding
+            return blocked_causal_attention(q, k, v, prompt_len), new
         if c.layer_types:
             # the prompt alone, as the latent form above: a real token
             # attends nothing past itself, so no padding and none of
@@ -1507,7 +1511,7 @@ _KINDS = {
         decode=lambda cache, li, lp, c, s, choice: _decode_attn(
             cache, li, s.pos, s.b_idx, c, s.visits["attn"]),
         prefill=lambda single, li, lp, c, p, choice: _prefill_attn(
-            single, li, c, p.positions, p.kv_valid),
+            single, li, c, p.positions, p.kv_valid, p.prompt_len),
         visits=lambda pos, c, cache: _visits(
             pos, jax.tree.leaves(cache_rows(cache))),
         hands_on=lambda c, queries, rows, prompt=False: None,

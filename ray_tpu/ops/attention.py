@@ -1,18 +1,27 @@
-"""Dense causal multi-head attention (reference implementation).
+"""Causal multi-head attention: the dense reference form, the blockwise
+online-softmax primitives, and the two prefill forms of the served path.
 
-The all-jnp path: XLA fuses the softmax chain and tiles the two matmuls onto
-the MXU. Used when the sequence axis is unsharded; `ring_attention` (sp>1) and
-the Pallas flash kernel (long single-device sequences) build on the same
-blockwise log-sum-exp accumulation primitives defined here.
+``causal_attention`` is the all-jnp path: XLA fuses the softmax chain and
+tiles the two matmuls onto the MXU. Used when the sequence axis is
+unsharded; `ring_attention` (sp>1) builds on the blockwise log-sum-exp
+accumulation primitives defined here. ``blocked_causal_attention`` (a full
+layer's prefill over a whole prompt, without its [S, S] scores) is one
+Pallas TPU kernel, forward only, with values narrower than keys and the
+prompt's length a prefetched scalar; the training kernel with a backward
+pass is `ops/flash_attention.py`. ``window_attention`` (a window layer's
+prefill) is blocked ``jnp``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -90,64 +99,171 @@ def block_of(n: int, most: int) -> int:
     return blk
 
 
+# Rows of one product of the prefill kernel: a KV head's query heads x a
+# block's queries (16 heads x 128 queries where the heads are grouped, one
+# head x a whole block of rows where they are not), and what the kernel
+# may hold in fast memory (a v5e has 128 MiB; 2,048 x 1,024 float32 scores
+# are 8 MiB, their exponentials beside them).
+_PREFILL_ROWS = 2048
+_PREFILL_VMEM = 100 * 2 ** 20
+
+
+def prefill_blocks(s: int, heads_a_kv_head: int, block: int):
+    """(queries, rows) of a block of ``blocked_causal_attention``: at most
+    ``block`` rows of K / V, and queries halved from there while the
+    heads' queries are more than ``_PREFILL_ROWS`` rows of a product (the
+    queries' block divides the rows')."""
+    bk = bq = block_of(s, block)
+    while heads_a_kv_head * bq > _PREFILL_ROWS and bq % 32 == 0:
+        bq //= 2
+    return bq, bk
+
+
+@functools.partial(jax.jit, static_argnames="block")
 def blocked_causal_attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,  # [B, S, Hkv, D]
     v: jax.Array,  # [B, S, Hkv, Dv]
+    length: Optional[jax.Array] = None,  # [B] or a scalar: the real tokens
     *,
     block: int = 1024,
 ) -> jax.Array:
     """``causal_attention`` over whole sequences without its [S, S]
-    scores: one KV head's queries at a time (no key or value is repeated
-    for the heads that share it), tile by tile with an online softmax in
-    float32 (operands in their own type), the rows' tiles only up to the
-    queries' own. Memory grows with S, not with S squared; values may be
-    narrower than keys. Returns [B, S, H, Dv] in q's type."""
+    scores: grouped heads share their KV head's rows unrepeated, values
+    may be narrower than keys, operands in their own type, scores and the
+    online softmax in float32. Returns [B, S, H, Dv] in q's type; with
+    ``length`` the rows at and past it are ZEROS (a padded bucket's tail:
+    never read, and never uninitialised memory either).
+
+    One Pallas TPU kernel, forward only: grid (B, KV heads, blocks of
+    queries, blocks of rows), the rows' axis innermost. One KV head's
+    query heads ride one fetch of a K / V block as the ROWS of one product
+    (heads x queries), read from q where it lies ([S, H x D]: a KV head's
+    heads are a slice of lanes) and stacked once a block of queries; the
+    scores, the running maxima and sums and the accumulator stay in fast
+    memory for the block's whole sweep over the rows and the output is
+    written once, where it lies ([S, H x Dv]). A block of rows wholly
+    above the diagonal and a block of queries wholly past ``length`` are
+    neither fetched nor computed (the index maps ask for the block they
+    already hold); only the block the diagonal crosses builds a mask. K
+    and V are read where they lie too ([S, Hkv x D]); where one KV head's
+    keys are not whole lanes wide (192) a grid step takes the fewest KV
+    heads that are, one after the other. Blocks: ``prefill_blocks``. Off
+    the TPU the kernel runs in the Pallas interpreter. Jitted, so that a
+    program's call sites of one shape trace and lower the body once (every
+    admission program does both before the compile cache is asked: the
+    body is kept short)."""
     B, S, H, D = q.shape
     G, Dv = k.shape[2], v.shape[-1]
     R = H // G
-    blk = block_of(S, block)
     f32 = jnp.float32
     scale = D ** -0.5
-    at = jnp.arange(blk)
+    bq, bk = prefill_blocks(S, R, block)
+    nq, nk, rows = S // bq, S // bk, R * bq
+    # KV heads a grid step: the fewest whose keys and values are whole
+    # lanes wide where they lie (192-wide keys go two heads a step); all of
+    # them where no count is (the tests' narrow heads)
+    hs = next((n for n in range(1, G) if G % n == 0
+               and n * D % 128 == 0 and n * Dv % 128 == 0), G)
+    length = jnp.broadcast_to(
+        jnp.asarray(S if length is None else length, jnp.int32), (B,))
 
-    def group(g):
-        def of(x):
-            return lax.dynamic_index_in_dim(x, g, 2, keepdims=False)
+    def kernel(len_ref, q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref,
+               acc_ref):
+        i, j = pl.program_id(2), pl.program_id(3)
+        n = len_ref[pl.program_id(0)]
+        first = i * bq  # the block's first query
+        here = lax.div(first, jnp.int32(bk))  # the rows the diagonal crosses
+        live = first < n
 
-        qh, kh, vh = of(q.reshape(B, S, G, R, D)), of(k), of(v)
+        def query_of(shape, axis):  # a row of the product: (head, query)
+            return first + lax.rem(
+                lax.broadcasted_iota(jnp.int32, shape, axis), jnp.int32(bq))
 
-        def queries(i):
-            qb = lax.dynamic_slice_in_dim(qh, i * blk, blk, 1)  # [B,blk,R,D]
+        @pl.when(live & (j == 0))
+        def _start():
+            for e in range(hs * R):  # head e's queries under head e - 1's
+                qs_ref[e // R, (e % R) * bq:(e % R + 1) * bq, :] = (
+                    q_ref[:, e * D:(e + 1) * D])
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-            def rows(j, state):
-                m, l, acc = state  # [B,R,blk,1] twice, [B,R,blk,Dv]
-                kb = lax.dynamic_slice_in_dim(kh, j * blk, blk, 1)
-                vb = lax.dynamic_slice_in_dim(vh, j * blk, blk, 1)
-                s = jnp.einsum("bqrd,bkd->brqk", qb, kb,
-                               preferred_element_type=f32) * scale
-                seen = (i * blk + at)[:, None] >= (j * blk + at)[None, :]
-                s = jnp.where(seen, s, NEG_INF)
-                m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-                p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-                shrink = jnp.exp(m - m_new)
-                l = shrink * l + p.sum(-1, keepdims=True)
-                acc = shrink * acc + jnp.einsum(
-                    "brqk,bkd->brqd", p.astype(q.dtype), vb,
-                    preferred_element_type=f32)
-                return m_new, l, acc
+        def sweep(diagonal: bool):
+            if diagonal:
+                seen = query_of((rows, bk), 0) >= j * bk + lax.broadcasted_iota(
+                    jnp.int32, (rows, bk), 1)
+            for e in range(hs):
+                s = lax.dot_general(
+                    qs_ref[e], k_ref[:, e * D:(e + 1) * D],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=f32) * scale  # [rows, bk]
+                if diagonal:
+                    s = lax.select(seen, s, lax.full_like(s, NEG_INF))
+                # every query sees the block's first row (bq divides bk):
+                # the maximum is a real score and a masked exponential is 0
+                m = m_ref[e]
+                m_new = lax.max(m, lax.reduce_max(s, (1,))[:, None])
+                p = lax.exp(s - m_new)
+                shrink = lax.exp(m - m_new)
+                l_ref[e] = shrink * l_ref[e] + lax.reduce_sum(p, (1,))[:, None]
+                acc_ref[e] = shrink * acc_ref[e] + lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[:, e * Dv:(e + 1) * Dv],
+                    (((1,), (0,)), ((), ())), preferred_element_type=f32)
+                m_ref[e] = m_new
 
-            m0 = jnp.full((B, R, blk, 1), NEG_INF, f32)
-            _, l, acc = lax.fori_loop(
-                0, i + 1, rows,
-                (m0, jnp.zeros_like(m0), jnp.zeros((B, R, blk, Dv), f32)))
-            return (acc / l).transpose(0, 2, 1, 3).astype(q.dtype)
+        @pl.when(live & (j < here))
+        def _below():
+            sweep(False)
 
-        out = lax.map(queries, jnp.arange(S // blk))  # [S/blk,B,blk,R,Dv]
-        return out.transpose(1, 0, 2, 3, 4).reshape(B, S, R, Dv)
+        @pl.when(live & (j == here))
+        def _diagonal():
+            sweep(True)
 
-    out = lax.map(group, jnp.arange(G))  # [G,B,S,R,Dv]
-    return out.transpose(1, 2, 0, 3, 4).reshape(B, S, H, Dv)
+        @pl.when(live & (j == nk - 1))
+        def _write():
+            out = acc_ref[...] / l_ref[...]
+            out = lax.select(query_of(out.shape, 1) < n, out,
+                             lax.full_like(out, 0)).astype(o_ref.dtype)
+            for e in range(hs * R):
+                o_ref[:, e * Dv:(e + 1) * Dv] = (
+                    out[e // R, (e % R) * bq:(e % R + 1) * bq])
+
+        @pl.when(jnp.logical_not(live) & (j == nk - 1))
+        def _skipped():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    def queries(b, g, i, j, n):  # a skipped block fetches nothing new
+        return b, jnp.minimum(i, jnp.maximum(n[b] - 1, 0) // bq), g
+
+    def keys(b, g, i, j, n):  # nor does a block above the diagonal
+        return b, jnp.minimum(j, queries(b, g, i, j, n)[1] * bq // bk), g
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, S, H * Dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, G // hs, nq, nk),
+            in_specs=[pl.BlockSpec((None, bq, hs * R * D), queries),
+                      pl.BlockSpec((None, bk, hs * D), keys),
+                      pl.BlockSpec((None, bk, hs * Dv), keys)],
+            out_specs=pl.BlockSpec((None, bq, hs * R * Dv),
+                                   lambda b, g, i, j, n: (b, i, g)),
+            scratch_shapes=[pltpu.VMEM((hs, rows, D), q.dtype),
+                            pltpu.VMEM((hs, rows, 1), f32),
+                            pltpu.VMEM((hs, rows, 1), f32),
+                            pltpu.VMEM((hs, rows, Dv), f32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM),
+        interpret=jax.default_backend() != "tpu",
+        name="prefill_attention",
+    )(length, q.reshape(B, S, H * D), k.reshape(B, S, G * D),
+      v.reshape(B, S, G * Dv))
+    return out.reshape(B, S, H, Dv)
 
 
 def window_attention(

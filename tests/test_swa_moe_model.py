@@ -244,6 +244,80 @@ def test_blocked_causal_attention_equals_the_dense_form(s, block, g):
     assert float(jnp.abs(got - want).max()) < 1e-5
 
 
+# query heads, KV heads, key and value widths of the three served models
+# whose admissions reach the kernel: MiMo-V2-Flash's full layers,
+# Phi-4-mini-flash's one (differential pairs as heads), Kimi-Linear's latent
+# layers as ``mla_expand`` hands them over
+SERVED_HEADS = {"mimo": (64, 4, 192, 128), "phi4flash": (40, 10, 128, 128),
+                "latent": (32, 32, 192, 128)}
+
+
+@pytest.mark.parametrize("geometry", sorted(SERVED_HEADS))
+@pytest.mark.parametrize("s,block,length", [
+    (80, 512, None), (96, 64, 96), (96, 64, 70), (128, 32, 33),
+    (64, 16, 1)],
+    ids=["one_block", "block_halved_full", "short_of_the_bucket",
+         "ends_a_block_in", "one_token"])
+def test_the_prefill_kernel_at_the_served_heads(geometry, s, block, length):
+    """The served geometries cut in length only, through the interpreter:
+    a bucket of one block, a length 64 does not divide (blocks of 32),
+    blocks of queries smaller than the rows' where heads are grouped, a
+    prompt that ends inside a block, at a block's first row, of one token.
+    The real rows are the dense form's; the rows past them are ZEROS."""
+    h, g, d, dv = SERVED_HEADS[geometry]
+    q, k, v, _ = _qkv(s + block, 1, s, h, g, d, dv)
+    with jax.default_matmul_precision("highest"):
+        got = blocked_causal_attention(q, k, v, length, block=block)
+        want = causal_attention(q, k, v)
+    n = s if length is None else length
+    assert got.shape == (1, s, h, dv) and got.dtype == q.dtype
+    assert float(jnp.abs(got - want)[:, :n].max()) < 1e-5
+    assert not np.asarray(got[:, n:]).any()
+
+
+def test_the_prefill_kernel_takes_a_length_a_sequence():
+    q, k, v, _ = _qkv(11, 2, 64, 8, 2, 16, 8)
+    length = jnp.array([64, 19], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got = blocked_causal_attention(q, k, v, length, block=16)
+        want = causal_attention(q, k, v)
+    assert float(jnp.abs(got[0] - want[0]).max()) < 1e-5
+    assert float(jnp.abs(got[1, :19] - want[1, :19]).max()) < 1e-5
+    assert not np.asarray(got[1, 19:]).any()
+
+
+@pytest.mark.parametrize("geometry", sorted(SERVED_HEADS))
+def test_the_prefill_kernel_in_bf16_stays_beside_float32(geometry):
+    """bf16 operands, float32 scores and sums: against every score in
+    float32 over the same rounded operands, the error is the output's own
+    rounding and the probabilities' (a hundredth of the largest value is
+    four times what it reads)."""
+    h, g, d, dv = SERVED_HEADS[geometry]
+    q, k, v = (a.astype(jnp.bfloat16)
+               for a in _qkv(5, 1, 96, h, g, d, dv)[:3])
+    got = blocked_causal_attention(q, k, v, jnp.int32(81), block=32)
+    with jax.default_matmul_precision("highest"):
+        want = causal_attention(*(a.astype(jnp.float32) for a in (q, k, v)))
+    assert got.dtype == jnp.bfloat16
+    err = jnp.abs(got.astype(jnp.float32) - want)[:, :81].max()
+    assert float(err / jnp.abs(want).max()) < 1e-2
+    assert not np.asarray(got[:, 81:]).any()
+
+
+def test_the_prefill_kernel_never_hands_on_what_padding_holds():
+    """Whatever a padded query holds, its row of the output is zeros: the
+    next layer projects its padded keys and values from that row, and a
+    masked probability of 0 times a NaN there would be NaN in a real row.
+    A block of queries wholly past the length is not even read."""
+    q, k, v, _ = _qkv(3, 1, 64, 4, 2, 16, 8)
+    q = q.at[:, 40:].set(jnp.nan)
+    got = blocked_causal_attention(q, k, v, jnp.int32(40), block=16)
+    with jax.default_matmul_precision("highest"):
+        want = causal_attention(q[:, :40], k[:, :40], v[:, :40])
+    assert float(jnp.abs(got[:, :40] - want).max()) < 1e-5
+    assert not np.asarray(got[:, 40:]).any()  # and no NaN: zeros
+
+
 @pytest.mark.parametrize("sink", [True, False], ids=["sink", "no_sink"])
 def test_the_rings_kernel_equals_the_plain_softmax(sink):
     """Lanes at 0 (parked), under a window, at it and far past it: the

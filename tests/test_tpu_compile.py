@@ -123,6 +123,10 @@ def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert all("decode_attention" in line for line in attends)
         assert "dynamic-slice_bitcast_fusion" not in hlo
         assert not _copies(hlo, "bf16[8,32,4096,")
+    else:
+        # ISSUE 53: 20 heads at 2,048 tokens are one product under
+        # ``PREFILL_SCORE_BYTES``: the prefill kernel is not in the program
+        assert "prefill_attention" not in hlo
 
 
 @pytest.mark.parametrize("program", ["decode_block", "prefill_2048"])
@@ -208,7 +212,7 @@ def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "raytpu.ssm.scan" in hlo
 
 
-@pytest.mark.parametrize("program", ["decode_block", "prefill_12288"])
+@pytest.mark.parametrize("program", ["decode_block", "prefill_14336"])
 def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
     """MiMo-V2-Flash's first pipeline stage (layers 0-6, ``F(dense) | W W
     W W F W``, 16 of 256 experts, 1/8 vocabulary, every width as
@@ -219,7 +223,8 @@ def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
     stack is copied (a run of like layers is a scan that indexes the WHOLE
     stacks), both kinds' decode attention is the one kernel, a window
     layer's over its ring, and a prefill holds no [S, S] array: the
-    12,288 bucket, because the dense FFN's ``d_ff`` is 16,384."""
+    14,336 bucket, because the dense FFN's ``d_ff`` is 16,384 and the
+    64 query heads' 192-wide keys lie 12,288 wide in a row."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from ray_tpu.models import generation as gen
     from ray_tpu.models.transformer import TransformerConfig, init_params
@@ -247,7 +252,7 @@ def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
             arr((48,)), arr((48,)), cfg, 2)
     else:
         low = gen.prefill_into_slot.lower(
-            params, arr((1, 12288)), arr(()), arr(()), cache, cfg)
+            params, arr((1, 14336)), arr(()), arr(()), cache, cfg)
     compiled = low.compile()
     mem = compiled.memory_analysis()
     foot = gen.slot_footprint(cache)
@@ -280,7 +285,7 @@ def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert len(attends) == 4
     else:
         assert not attends
-        assert "12288,12288" not in hlo  # no [S, S] array of any type
+        assert "14336,14336" not in hlo  # no [S, S] array of any type
 
 
 @pytest.mark.parametrize("program", ["decode_block", "admission_8192"])
@@ -438,8 +443,14 @@ def test_sambay_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "raytpu.gmu.gate" in hlo and "raytpu.mamba1.update" in hlo
     else:
         scans = [line for line in calls if "mamba_scan" in line]
-        assert len(scans) == 2 and len(calls) == 2  # (M W) x 8 and M F
+        assert len(scans) == 2  # (M W) x 8 and M F
         assert all("raytpu.mamba1.scan" in line for line in scans)
+        # ISSUE 53: the ONE full layer's attention over the prompt is the
+        # prefill kernel (ten pairs of KV heads, four heads a pair), told
+        # the prompt's length; no other kernel is in the program
+        attends = [line for line in calls if "prefill_attention" in line]
+        assert len(attends) == 1 and len(calls) == 3
+        assert "raytpu.attn.attend" in attends[0] and "s32[1]" in attends[0]
         assert "raytpu.upper.last_token" in hlo
         assert "[16384,16384]" not in hlo  # no prompt's scores whole
 
@@ -729,6 +740,17 @@ def test_mimo_admission_moves_no_array_of_all_the_sorted_pairs(
     assert all("raytpu.moe.experts/while/body" in line for line in kernels)
     assert any(" scatter(" in line and "raytpu.moe.experts/while/body" in line
                for line in hlo.splitlines())
+    # ISSUE 53: each full layer's causal attention over the prompt is ONE
+    # kernel under its scope (the dense layer's and the period's), fed the
+    # prompt's length as a prefetched scalar, and nothing walks tiles
+    # outside it
+    attends = [line for line in hlo.splitlines()
+               if "tpu_custom_call" in line and "prefill_attention" in line]
+    assert len(attends) == 2
+    assert all("raytpu.attn.attend" in line for line in attends)
+    assert all("s32[1]" in line for line in attends)
+    assert not [line for line in hlo.splitlines()
+                if "raytpu.attn.attend" in line and " while(" in line]
 
 
 # sha256 of ``lower(...).as_text()`` on the CPU (where a kernel is its

@@ -1,0 +1,171 @@
+"""``ops/attention.blocked_causal_attention`` ALONE at the shapes an
+admission calls it with, on the chip: one sequence, bf16 operands, MiMo's
+full layers (64 query heads over 4 KV heads, keys 192 wide, values 128) and
+Kimi's latent layers as ``mla_expand`` hands them over (32 heads of their
+own), the prompt's length a traced scalar as ``generation._prefill_attn``
+passes it.
+
+    chiprun -- python3 tools/prefill_attention_micro.py --check   # the table
+    chiprun -- python3 tools/prefill_attention_micro.py --dense   # one product
+    python3 tools/prefill_attention_micro.py --tiny                # here
+
+A layer-call's time is the host's clock over ``--calls`` calls dispatched
+back to back and waited out once (a call outlasts its dispatch), best of
+three. Beside it two rates: the operations of the TILES THE FORM COMPUTES
+(``2 (D + Dv)`` a head, query and row of every block it multiplies) and of
+the CAUSAL WORK the prompt needs (rows s <= t < length alone), each over
+that time, against the chip's peak. ``--dense`` times ``causal_attention``
+(every score of the bucket in one product: the form a prefill under
+``generation.PREFILL_SCORE_BYTES`` takes) at 1,024 and 2,048 tokens.
+``--check`` compares the form at 1,024 tokens with ``causal_attention`` in
+float32 (largest error over the largest value; rows past the length must be
+zeros where the form takes one). One JSON line a measurement and the lot in
+``chiprun_out/prefill_attention_micro.json``. ``--tiny`` walks the same
+code at a toy size through the Pallas interpreter and reports no rate: a
+time off the chip is no device number. A tool: no cell and no metric reads
+it. It runs from the parent's tree too (``PYTHONPATH=<tree>``, run from
+that tree's root): where the function takes no ``length`` it is the tile
+loop, which computes 1,024 x 1,024 tiles up to the diagonal over the whole
+bucket.
+"""
+
+from __future__ import annotations
+
+import argparse
+import inspect
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.common import peaks_for
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import blocked_causal_attention, causal_attention
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+# heads, KV heads, key width, value width; the share of a bucket a cell's
+# mean prompt fills (codeagent-saturated.json, longreason-saturated.json)
+GEOMETRIES = {"mimo": (64, 4, 192, 128, 0.913),
+              "kimi": (32, 32, 192, 128, 0.837)}
+TOKENS = (1024, 4096, 5120, 8192, 16384)
+DENSE_TOKENS = (1024, 2048)  # where a prefill goes as one product
+TAKES_LENGTH = "length" in inspect.signature(
+    blocked_causal_attention).parameters
+
+
+def inputs(seed, tokens, heads, kv_heads, d, dv, dtype=BF16):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (1, tokens, heads, d), dtype),
+            jax.random.normal(ks[1], (1, tokens, kv_heads, d), dtype),
+            jax.random.normal(ks[2], (1, tokens, kv_heads, dv), dtype))
+
+
+@jax.jit
+def layer_call(q, k, v, prompt_len):
+    if TAKES_LENGTH:
+        return blocked_causal_attention(q, k, v, prompt_len)
+    return blocked_causal_attention(q, k, v)
+
+
+def pairs_computed(tokens, prompt_len, heads_a_kv_head):
+    """(query, row) pairs a head multiplies: the kernel's blocks of queries
+    up to the prompt's last, each against its blocks of rows up to the
+    diagonal's; the tile loop's 1,024 tiles over the whole bucket."""
+    if not TAKES_LENGTH:
+        blk = attention.block_of(tokens, 1024)
+        n = tokens // blk
+        return blk * blk * n * (n + 1) // 2
+    bq, bk = attention.prefill_blocks(tokens, heads_a_kv_head, 1024)
+    return sum(bq * (i * bq // bk + 1) * bk
+               for i in range(-(-prompt_len // bq)))
+
+
+def timed(fn, *args, calls):
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        jax.block_until_ready([fn(*args) for _ in range(calls)])
+        best = min(best, (time.perf_counter() - t) / calls)
+    return best
+
+
+def main():
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--geometry", nargs="*", default=list(GEOMETRIES))
+    p.add_argument("--tokens", type=int, nargs="*", default=list(TOKENS))
+    p.add_argument("--calls", type=int, default=10)
+    p.add_argument("--seed", type=int, default=1)
+    p.add_argument("--check", action="store_true")
+    p.add_argument("--dense", action="store_true")
+    p.add_argument("--tiny", action="store_true")
+    args = p.parse_args()
+    dev = jax.devices()[0]
+    if not args.tiny and dev.platform != "tpu":
+        raise SystemExit("a rate needs the chip; --tiny walks the code here")
+    form = "kernel" if TAKES_LENGTH else "tiles"
+    rows = []
+
+    def say(row):
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    for name in args.geometry:
+        heads, kv_heads, d, dv, fill = GEOMETRIES[name]
+        if args.tiny:
+            heads, kv_heads, d, dv = heads // 8, max(kv_heads // 8, 1), 24, 16
+        flop = 2 * (d + dv) * heads  # a (query, row) pair, all heads
+        peak = None if args.tiny else peaks_for(dev.device_kind)["flops_bf16"]
+        for n in ([48, 80] if args.tiny else args.tokens):
+            x = inputs(args.seed, n, heads, kv_heads, d, dv)
+            for filled in (1.0, fill):
+                length = max(int(n * filled), 1)
+                row = {"geometry": name, "form": form, "tokens": n,
+                       "prompt_len": length, "device": dev.device_kind}
+                best = timed(layer_call, *x, jnp.int32(length),
+                             calls=1 if args.tiny else args.calls)
+                if not args.tiny:
+                    done = flop * pairs_computed(n, length, heads // kv_heads)
+                    need = flop * length * (length + 1) // 2
+                    row.update(
+                        ms_a_call=1e3 * best,
+                        tflops_computed=done / best / 1e12,
+                        tflops_causal=need / best / 1e12,
+                        peak_share_computed=100 * done / best / peak,
+                        peak_share_causal=100 * need / best / peak)
+                say(row)
+        for n in (DENSE_TOKENS if args.dense and not args.tiny else ()):
+            x = inputs(args.seed, n, heads, kv_heads, d, dv)
+            best = timed(jax.jit(causal_attention), *x, calls=args.calls)
+            say({"geometry": name, "form": "one_product", "tokens": n,
+                 "prompt_len": n, "device": dev.device_kind,
+                 "ms_a_call": 1e3 * best,
+                 "tflops_computed": flop * n * n / best / 1e12,
+                 "tflops_causal": flop * n * (n + 1) // 2 / best / 1e12})
+        if args.check or args.tiny:
+            n = 48 if args.tiny else 1024
+            x = inputs(args.seed + 1, n, heads, kv_heads, d, dv)
+            with jax.default_matmul_precision("highest"):
+                want = causal_attention(*(a.astype(F32) for a in x))
+            for length in (n, n - n // 3):
+                got = layer_call(*x, jnp.int32(length)).astype(F32)
+                row = {"geometry": name, "form": form, "check_tokens": n,
+                       "prompt_len": length,
+                       "err": float(jnp.abs(got - want)[:, :length].max()
+                                    / jnp.abs(want).max())}
+                if TAKES_LENGTH:
+                    row["past_length_all_zero"] = not bool(
+                        got[:, length:].any())
+                say(row)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/prefill_attention_micro.json", "w") as f:
+        json.dump(rows, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()
