@@ -84,6 +84,7 @@ from ray_tpu.models.transformer import (
     lm_logits,
     mamba_inputs,
     mla_expand,
+    pred_logits,
     recalling,
     scan_stack,
     ssm_split,
@@ -100,6 +101,7 @@ from ray_tpu.ops.decode_attention import (
     decode_attention,
     slot_schedule,
 )
+from ray_tpu.ops.eva import eva_attention, eva_pool
 from ray_tpu.ops.kda import kda_chunked, kda_update
 from ray_tpu.ops.mamba import mamba_scan, mamba_update
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_update
@@ -303,6 +305,45 @@ def _mamba_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
                           c.dtype)}
 
 
+def eva_rows(c: TransformerConfig, length: int) -> int:
+    """Rows an "eva" layer's slot needs for a sequence of ``length``
+    tokens: ``eva_window / eva_chunk`` summaries for each window that can
+    have closed before its last token, and the open window's rows."""
+    return ((length - 1) // c.eva_window * (c.eva_window // c.eva_chunk)
+            + min(c.eva_window, length))
+
+
+def eva_read_len(c: TransformerConfig, pos):
+    """Rows of an "eva" layer's slot that hold something when the lane's
+    next token is at position ``pos`` (an integer or an array): the
+    summaries of the windows closed so far, then the open window's
+    tokens. What a decode step's attention reads, and the row the step's
+    token is written to; 0 for a parked lane."""
+    return (pos // c.eva_window * (c.eva_window // c.eva_chunk)
+            + pos % c.eva_window)
+
+
+def _eva_keeps(c: TransformerConfig, n: int, batch: int, max_len: int):
+    """An "eva" layer keeps rows, but not one a token: ``ek`` and ``ev``
+    of [those layers, B, eva_rows(max_len), H, D]. With n = eva_window /
+    eva_chunk (EvaByte: 2,048 / 16 = 128), rows [0, n w) hold the
+    summaries of the w windows closed so far and the rows from there on
+    the open window's keys (rotated) and values, one a token: ONE
+    contiguous prefix of ``eva_read_len(pos)`` rows, which the decode
+    attention's kernel reads as it reads any cache. When a window closes
+    its eva_window rows are pooled and written over their own first n
+    (``_eva_close``), and the next token's row lands after them."""
+    rows = (n, batch, eva_rows(c, max_len), c.n_heads, c.d_head)
+    return {"ek": jnp.zeros(rows, c.dtype),
+            "ev": jnp.zeros(rows, c.dtype)}, {}
+
+
+def _eva_closing(c: TransformerConfig, pos):
+    """The lanes [B] whose token at ``pos`` fills its window: it is the
+    window's last, and the lane is not parked."""
+    return (pos > 0) & (pos % c.eva_window == c.eva_window - 1)
+
+
 def _nothing_kept(c: TransformerConfig, n: int, batch: int, max_len: int):
     """A "gmu" or a "cross" layer keeps nothing: it reads what a layer
     below keeps or hands on."""
@@ -390,7 +431,7 @@ def _visits(pos, arrays):
 def decode_attn_chunk(config: TransformerConfig, s_max: int) -> int:
     """Rows of cache one iteration of ``config``'s decode attention reads
     of a slot of ``s_max`` rows."""
-    return _row("attn", config).chunk(config, s_max)
+    return _row(_length_kind(config), config).chunk(config, s_max)
 
 
 def _dense_chunk(config: TransformerConfig, s_max: int) -> int:
@@ -413,12 +454,16 @@ def attn_rows_read(config: TransformerConfig, rows, steps: int,
     ``steps`` steps reads, the slots' ``pos`` being ``rows`` (plain
     integers, 0: parked) when it starts: the engine's count. Each live
     slot's chunks up to its own length, step by step; a block with an
-    indexer walks EVERY slot up to the longest lane."""
-    attn = _row("attn", config)
+    indexer walks EVERY slot up to the longest lane. The chunks are
+    those of the rows a position leaves in a slot (``_Kind.read_len``)
+    out of the rows the slot has (``_Kind.slot_rows``): the position and
+    ``s_max`` themselves but for "eva" layers."""
+    attn = _row(_length_kind(config), config)
     lanes = ([max(rows)] * len(rows) if attn.walks_longest
              else [r for r in rows if r])
     chunk = decode_attn_chunk(config, s_max)
-    return sum(attn_rows_walked(r + k, s_max, chunk)
+    return sum(attn_rows_walked(attn.read_len(config, r + k),
+                                attn.slot_rows(config, s_max), chunk)
                for r in lanes for k in range(steps))
 
 
@@ -1347,6 +1392,112 @@ def _prefill_cross(single, c: TransformerConfig, p):
     return attend
 
 
+def _decode_eva(cache, li, s, c: TransformerConfig):
+    """One decode layer's ``attn_fn`` for an "eva" layer: the token
+    attends the slot's first ``eva_read_len(pos)`` rows, the closed
+    windows' summaries and then its own window's tokens, plus itself
+    (``_attend_prefix_plus_self``: the kernel every dense cache uses, told
+    that length and not the position; ``s.visits["eva"]`` is made from
+    it), and its row is written there. A parked lane (``pos`` 0) reads
+    nothing and its write is dropped. The window the token may fill is
+    folded after the step's layers (``_eva_close``). Returns (output, the
+    cache)."""
+    def attend(q, k, v, wp):
+        ek, ev = cache["ek"], cache["ev"]
+        rows = eva_read_len(c, s.pos)
+        with jax.named_scope("raytpu.eva.attend"):
+            out = _attend_prefix_plus_self(
+                q, ek, ev, k, v, rows, layer=li, schedule=s.visits["eva"])
+            at = jnp.where(s.pos > 0, rows, ek.shape[2])
+            new = {**cache,
+                   "ek": ek.at[li, s.b_idx, at].set(
+                       k[:, 0].astype(ek.dtype), mode="drop"),
+                   "ev": ev.at[li, s.b_idx, at].set(
+                       v[:, 0].astype(ev.dtype), mode="drop")}
+        return out, new
+
+    return attend
+
+
+def _eva_close(params, cache, pos, c: TransformerConfig):
+    """What a decode step does to the "eva" layers' slots once its layers
+    have run: a lane whose token filled its window (``pos mod eva_window``
+    = ``eva_window`` - 1; the token attended the window's rows, its own
+    among them now) has that window's rows pooled, layer by layer, and
+    the summaries written over the rows' own first ``eva_window /
+    eva_chunk`` (``ops/eva.eva_pool`` under the layer's ``phi`` and
+    ``mu``). One loop over the (closing lane, layer) pairs there ARE: on
+    a step at which no lane closes a window, all but one in
+    ``eva_window`` a lane, it runs no iteration and reads nothing; the
+    other lanes never pay. Returns the cache."""
+    W, per = c.eva_window, c.eva_window // c.eva_chunk
+    ek, ev = cache["ek"], cache["ev"]
+    n_layers, _b, _rows, H, D = ek.shape
+    closing = _eva_closing(c, pos)
+    lanes = jnp.argsort(~closing)  # stable: the closing lanes first
+    mixer = params["eva_layers"]["eva"]
+    phi, mu = mixer["phi"], mixer["mu"]
+
+    def one(i, kv):
+        ek, ev = kv
+        b, li = lanes[i // n_layers], i % n_layers
+        at = (li, b, pos[b] // W * per, 0, 0)
+        ks, vs = eva_pool(
+            lax.dynamic_slice(ek, at, (1, 1, W, H, D)),
+            lax.dynamic_slice(ev, at, (1, 1, W, H, D)),
+            phi[li], mu[li], c.eva_chunk)
+        return (lax.dynamic_update_slice(ek, ks, at),
+                lax.dynamic_update_slice(ev, vs, at))
+
+    with jax.named_scope("raytpu.eva.pool"):
+        ek, ev = lax.fori_loop(
+            0, closing.sum(dtype=jnp.int32) * n_layers, one, (ek, ev))
+    return {**cache, "ek": ek, "ev": ev}
+
+
+def _prefill_eva(single, li, p, c: TransformerConfig):
+    """One prefill layer's ``attn_fn`` for an "eva" layer: the bucket's
+    complete windows are pooled, the prompt attends as the layer is
+    written (``ops/eva.eva_attention``: block by block, its own window
+    and the summaries before it; a real token's window never sees a
+    summary that holds padding, which only windows past the prompt's last
+    can), and the slot is handed what a decode step expects to find: the
+    summaries of the prompt's ``prompt_len // eva_window`` complete
+    windows, then the rows of the tokens after them (rows past
+    ``eva_read_len(prompt_len)`` hold whatever: nothing attends them
+    before a token overwrites them). ``single`` is one slot's cache.
+    Returns (output, single with this layer's rows)."""
+    def attend(q, k, v, wp):
+        S, W = q.shape[1], c.eva_window
+        per = W // c.eva_chunk
+        whole = S // W * W
+        with jax.named_scope("raytpu.eva.pool"):
+            # a window at a time: the float32 copies of a whole bucket's
+            # keys and values would be 0.9 GB at 28,672 tokens
+            ks, vs = (x.reshape((1, -1) + x.shape[2:]) for x in lax.map(
+                lambda kv: eva_pool(*kv, wp["phi"], wp["mu"], c.eva_chunk),
+                tuple(x[0, :whole].reshape((-1, W) + x.shape[2:])
+                      for x in (k, v))))
+        with jax.named_scope("raytpu.eva.attend"):
+            out = eva_attention(q, k, v, ks, vs, window=W,
+                                chunk=c.eva_chunk)
+        with jax.named_scope("raytpu.eva.pool"):
+            closed = p.prompt_len // W
+            rows = jnp.arange(min(single["ek"].shape[2], eva_rows(c, S)))
+            new = dict(single)
+            for name, raw, pooled in (("ek", k, ks), ("ev", v, vs)):
+                kept = jnp.take(raw[0], rows + closed * (W - per), axis=0,
+                                mode="clip")
+                if whole:  # a bucket shorter than a window closes none
+                    kept = jnp.where(
+                        (rows < closed * per)[:, None, None],
+                        jnp.take(pooled[0], rows, axis=0, mode="clip"), kept)
+                new[name] = _put_layer(single[name], kept[None, None], li)
+        return out, new
+
+    return attend
+
+
 def prefill_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     """Names of the int32 counters the admission form of
     ``prefill_into_slot`` returns with its first token: for dropless
@@ -1417,6 +1568,17 @@ def _cross_stats(pos, c: TransformerConfig, n: int, cache):
     return {"cross_rows_read": n * walked.sum()}
 
 
+def _eva_stats(pos, c: TransformerConfig, n: int, cache):
+    """What one decode step's "eva" layers read and fold, from the lanes'
+    positions: the (row, layer) pairs of the open windows' tokens and of
+    the closed windows' summaries their attention read (a parked lane:
+    none), and the (window, layer) pairs the step closed."""
+    W, per = c.eva_window, c.eva_window // c.eva_chunk
+    return {"eva_window_rows_read": n * (pos % W).sum(),
+            "eva_summary_rows_read": n * (pos // W * per).sum(),
+            "eva_windows_closed": n * _eva_closing(c, pos).sum()}
+
+
 def _zero_stats(config: TransformerConfig):
     return {k: jnp.zeros((), jnp.int32) for k in block_stat_keys(config)}
 
@@ -1465,14 +1627,24 @@ class _Kind(NamedTuple):
     # for the SAME token: a prefill runs such layers, where they are the
     # model's last, on the prompt's last real token alone
     last_token: bool = False
+    # (params, cache, pos, c) -> the cache: what a decode step does to
+    # its slots once the step's layers have run
+    closes: Optional[Callable] = None
     # the layers that attend every row only. (c, queries, rows, prompt) ->
     # what the layer scan's carry hands from layer to layer (``prompt``:
-    # in a prefill), or None; (c, s_max) -> rows of a slot one visit of
-    # the decode attention reads; whether it walks EVERY slot up to the
-    # longest lane (``attn_rows_read``: the host's count)
+    # in a prefill), or None
     hands_on: Optional[Callable] = None
+    # the kind whose row leaves give a slot its length (``_length_kind``).
+    # (c, s_max) -> rows of a slot one visit of the decode attention
+    # reads; whether it walks EVERY slot up to the longest lane; (c, pos)
+    # -> the rows of a slot its decode attention reads when the lane's
+    # token is at ``pos`` (a row a token: ``pos`` itself), and (c,
+    # max_len) -> the rows its slot has (``attn_rows_read``: the host's
+    # count)
     chunk: Optional[Callable] = None
     walks_longest: bool = False
+    read_len: Callable = lambda c, pos: pos
+    slot_rows: Callable = lambda c, max_len: max_len
 
 
 # In the order a decode step makes the kinds' visits (a window model's
@@ -1516,6 +1688,18 @@ _KINDS = {
             pos, jax.tree.leaves(cache_rows(cache))),
         hands_on=lambda c, queries, rows, prompt=False: None,
         chunk=_dense_chunk),
+    "eva": _Kind(
+        layers=lambda c: c.n_of("eva"), keeps=_eva_keeps,
+        decode=lambda cache, li, lp, c, s, choice: _decode_eva(
+            cache, li, s, c),
+        prefill=lambda single, li, lp, c, p, choice: _prefill_eva(
+            single, li, p, c),
+        visits=lambda pos, c, cache: _visits(
+            eva_read_len(c, pos), [cache["ek"], cache["ev"]]),
+        counters=("eva_window_rows_read", "eva_summary_rows_read",
+                  "eva_windows_closed"),
+        counts=_eva_stats, closes=_eva_close, chunk=_dense_chunk,
+        read_len=eva_read_len, slot_rows=eva_rows),
     "gmu": _Kind(
         layers=lambda c: c.n_of("gmu"), keeps=_nothing_kept,
         decode=lambda cache, li, lp, c, s, handed: recalling(
@@ -1579,15 +1763,26 @@ def _row(kind: str, c: TransformerConfig) -> _Kind:
             _SHARED if c.n_of("gmu") or c.n_of("cross") else _KINDS[kind])
 
 
+def _length_kind(c: TransformerConfig) -> str:
+    """The kind whose ROW leaves give a slot its length, and whose decode
+    attention the host counts the reads of (``attn_rows_read``): "eva"
+    where the model's layers are of that kind (what such a slot holds
+    grows by a row a token inside a window and shrinks when one closes),
+    else "attn", the layers that attend every row and keep them, a row a
+    token."""
+    return "eva" if c.n_of("eva") else "attn"
+
+
 def _kinds_of(c: TransformerConfig):
     """(kind, its row, how many of the model's layers are of it) for the
-    kinds ``c``'s model has, in the table's order. The layers that attend
-    every row AND keep them are always among them: their row leaves give
-    a slot its length (a "cross" layer attends every row too and keeps
-    none: it reads theirs)."""
+    kinds ``c``'s model has, in the table's order. The kind that gives a
+    slot its length (``_length_kind``) is always among them: every cache
+    has row leaves, [.., B, S, ..], whose third axis is the slot's room
+    (a "cross" layer attends every row too and keeps none: it reads the
+    "attn" layers'). A model of "eva" layers has no "attn" row at all."""
     found = ((kind, _row(kind, c)) for kind in _KINDS)
     return [(kind, row, row.layers(c)) for kind, row in found
-            if kind == "attn" or row.layers(c)]
+            if kind == _length_kind(c) or row.layers(c)]
 
 
 def _prompt_parts(stack, lc: TransformerConfig, first: int):
@@ -1650,6 +1845,8 @@ def _decode_forward_multi(params, token, cache, pos,
     for _kind, row, n in kinds:
         if row.counts:
             stats = _add_stats(stats, row.counts(pos, c, n, cache))
+        if row.closes:
+            cache = row.closes(params, cache, pos, c)
     return lm_logits(params, x, c)[:, 0, :], cache, stats
 
 
@@ -1660,13 +1857,18 @@ def decode_step_multi(params, token, cache, pos, config: TransformerConfig):
     token [B] int32, pos [B] int32 (position each slot's token occupies).
     Inactive slots simply decode garbage into their own lane — they attend
     only their own cache row, so active slots are unaffected; the engine
-    ignores their outputs. Returns (logits [B, V], cache)."""
+    ignores their outputs. Returns (logits [B, V], cache); of a model
+    with several prediction heads logits [B, n_pred_heads, V]."""
     return _decode_forward_multi(params, token, cache, pos, config)[:2]
 
 
 def _sample_vec(logits, temps, seeds, counts):
     """Per-slot on-device sampling: greedy where temps==0, Gumbel-max
-    categorical elsewhere, deterministic per (seed, count)."""
+    categorical elsewhere, deterministic per (seed, count). Of several
+    prediction heads' logits [B, n_pred_heads, V] the NEXT token's are
+    sampled, head 0's."""
+    if logits.ndim == 3:
+        logits = logits[:, 0]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def one(lg, t, s, c):
@@ -1739,7 +1941,8 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     ``prompt_len`` (``_prefill_recur``): a reused slot starts from an
     empty state, whatever the lane did while it was parked.
 
-    Returns (last-valid-token logits [V], cache).
+    Returns (last-valid-token logits [V], cache); of a model with
+    several prediction heads logits [n_pred_heads, V].
 
     Given ``lanes``, the engine's five per-slot vectors (token, pos,
     temps, seeds, counts: ``decode_block``'s arguments, donated here with
@@ -1807,9 +2010,12 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     # [D] — last REAL token's features (all that is left where the upper
     # layers ran on that token alone)
     last = x[0, 0] if narrowed else x[0, prompt_len - 1]
-    logits = last @ head.astype(c.dtype)
-    if c.logit_scale != 1.0:
-        logits = logits * c.logit_scale
+    if c.n_pred_heads > 1:
+        logits = pred_logits(last, head, c)
+    else:
+        logits = last @ head.astype(c.dtype)
+        if c.logit_scale != 1.0:
+            logits = logits * c.logit_scale
     cache = jax.tree.map(
         lambda big, one: lax.dynamic_update_slice(
             big, one, (0, slot) + (0,) * (big.ndim - 2)),

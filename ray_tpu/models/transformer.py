@@ -33,8 +33,11 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   ``ops/mamba.py``; "window" and "attention" layers with differential
   attention; and two kinds that keep NOTHING of their own: "cross" layers
   that attend the one "attention" layer's rows and "gmu" layers gated by
-  the last "mamba" layer's output), one stack of parameters a kind, run
-  in the order the list gives;
+  the last "mamba" layer's output), or layers that attend their own
+  window of rows exactly and every earlier window through pooled chunk
+  summaries ("eva", ``ops/eva.py``: such a model has no layer that keeps
+  every row), one stack of parameters a kind, run in the order the list
+  gives;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -54,6 +57,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import causal_attention, window_attention
+from ray_tpu.ops.eva import eva_attention, eva_pool
 from ray_tpu.ops.kda import kda_chunked
 from ray_tpu.ops.mamba import mamba_scan
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked
@@ -237,13 +241,37 @@ class TransformerConfig:
     mamba_state: int = 0
     mamba_conv: int = 4
     mamba_dt_rank: int = 0
+    # EVA layers (EvaByte; arXiv:2302.04542), "eva" in layer_types, which
+    # then lists nothing else: MHA projections (rotary as the other "mha"
+    # layers') and two learned d_head-vectors a head, phi and mu. Token t
+    # lies in window t // eva_window, chunk c holds eva_chunk tokens; a
+    # chunk's summary is k~_c = sum_j softmax_j(k_j . phi / sqrt(d_head))
+    # k_j over its tokens' ROTATED keys and v~_c the same under mu over
+    # its values (float32 softmax). Query t attends, in ONE softmax, the
+    # summaries of every chunk of every window before its own and the
+    # tokens of its own window up to itself (ops/eva.py). What a slot keeps
+    # is the closed windows' summaries and the open window's rows
+    # (generation._eva_keeps). Its parameters are params["eva_layers"] (the
+    # mixer's under "eva"). Three more things such a model does, each a
+    # field any model may set: n_pred_heads linear heads side by side in
+    # lm_head [d, n_pred_heads x vocab_size], logits [.., n_pred_heads,
+    # vocab_size] in float32 (head i scores token t + 1 + i; the served
+    # path samples from head 0); residual_f32 carries the residual stream
+    # in float32 (each branch's output is added to it unrounded; the norms
+    # hand the branches the compute dtype); norm_unit_offset scales an
+    # RMSNorm by 1 + w, w the stored parameter.
+    eva_window: int = 0
+    eva_chunk: int = 0
+    n_pred_heads: int = 1
+    residual_f32: bool = False
+    norm_unit_offset: bool = False
 
     def __post_init__(self):
         if self.layer_types:
             kinds = tuple(self.layer_types)
             if len(kinds) != self.n_layers or set(kinds) - {
                     "attention", "ssm", "window", "kda", "mamba", "gmu",
-                    "cross"} or (
+                    "cross", "eva"} or (
                     self.mixer != "mha" and set(kinds) - {
                         "attention", "kda"}) or (
                     self.residual != "sequential") or (
@@ -254,13 +282,24 @@ class TransformerConfig:
                         % self.ssm_groups)):
                 raise ValueError(
                     "layer_types needs one entry a layer ('attention' | "
-                    "'ssm' | 'window' | 'kda' | 'mamba' | 'gmu' | 'cross'), "
+                    "'ssm' | 'window' | 'kda' | 'mamba' | 'gmu' | 'cross' | "
+                    "'eva'), "
                     "mixer 'mha' (beside 'kda' layers alone: either "
                     "mixer), a sequential block; 'attention' stands beside "
                     "every kind, 'window' beside all but 'ssm' and 'kda', "
                     "'mamba', 'gmu' and 'cross' beside each other, "
                     "'attention' and 'window'; beside 'ssm' layers a dense "
                     "FFN and the ssm_* sizes (heads a multiple of groups)")
+            if "eva" in kinds and (
+                    set(kinds) != {"eva"} or self.moe_experts
+                    or self.eva_chunk < 1 or self.eva_window < self.eva_chunk
+                    or self.eva_window % self.eva_chunk or self.diff_attn
+                    or self.n_kv_heads not in (None, self.n_heads)
+                    or self.v_head_dim not in (0, self.d_head)):
+                raise ValueError(
+                    "'eva' layers stand alone, under a dense FFN: MHA with "
+                    "values as wide as keys, eva_window a multiple of "
+                    "eva_chunk >= 1")
             n_dense = self.n_dense_layers if self.moe_experts else 0
             upper = set(kinds) & {"mamba", "gmu", "cross"}
             if upper and (
@@ -304,6 +343,12 @@ class TransformerConfig:
             object.__setattr__(self, "layer_types", kinds)
         elif self.window:
             raise ValueError("window needs 'window' layers in layer_types")
+        if self.n_pred_heads < 1 or (self.n_pred_heads > 1
+                                     and self.tie_embeddings) or (
+                self.norm_unit_offset and self.norm != "rms"):
+            raise ValueError(
+                "n_pred_heads >= 1 (several: an untied head); "
+                "norm_unit_offset needs norm 'rms'")
         if self.norm not in ("rms", "layer") or (
                 (self.diff_attn or self.attn_bias) and self.mixer != "mha"
                 ) or (self.diff_attn and (
@@ -366,7 +411,7 @@ class TransformerConfig:
         cache holds rows for."""
         return (self.n_layers - self.n_ssm_layers - self.n_window_layers
                 - self.n_kda_layers - self.n_of("mamba") - self.n_of("gmu")
-                - self.n_of("cross"))
+                - self.n_of("cross") - self.n_of("eva"))
 
     @property
     def kda_inner(self) -> int:
@@ -449,7 +494,8 @@ class TransformerConfig:
         mamba = (d * 2 * mi + mi * (self.mamba_conv + 1)
                  + mi * (mr + 2 * ms) + mr * mi + mi + ms * mi + mi + mi * d)
         upper = (self.n_of("mamba") * mamba + self.n_of("gmu") * 2 * d * mi
-                 + self.n_of("cross") * cross)
+                 + self.n_of("cross") * cross
+                 + self.n_of("eva") * (attn + 2 * h * dh))
         norms = d * (2 if self.residual == "sequential" else 1) * (
             2 if self.norm == "layer" else 1)
         n_dense = self.n_dense_layers if self.moe_experts else 0
@@ -467,7 +513,8 @@ class TransformerConfig:
                   + self.n_window_layers * window + self.n_kda_layers * kda
                   + upper + self.n_layers * norms + n_dense * dense_ffn
                   + (self.n_layers - n_dense) * ffn + indexer)
-        head = 0 if self.tie_embeddings else d * self.vocab_size
+        head = (0 if self.tie_embeddings
+                else d * self.vocab_size * self.n_pred_heads)
         final = d * (2 if self.norm == "layer" else 1)
         return self.vocab_size * d + layers + final + head
 
@@ -747,6 +794,42 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     @staticmethod
+    def evabyte(n_layers: int = 32, **kw) -> "TransformerConfig":
+        """EvaByte 6.5B (EvaByte/EvaByte config.json, model_type evabyte,
+        attention_class eva) at its published widths: a byte-level model,
+        vocabulary 320 (256 bytes and 64 specials), 32 layers of EVA
+        attention (32 heads of 128, rotary over all 128 dims at base 1e5,
+        windows of 2,048 bytes exact, every earlier window as 128 pooled
+        summaries of 16-byte chunks) under gated SiLU FFNs 11,008 wide;
+        RMSNorm scaled by 1 + w, the residual stream in float32, eight
+        linear prediction heads whose logits stay float32."""
+        base = dict(
+            vocab_size=320, d_model=4096, n_layers=n_layers, n_heads=32,
+            d_head=128, d_ff=11008, rotary_dim=128, max_seq_len=32768,
+            residual="sequential", activation="silu", gated_ffn=True,
+            norm_eps=1e-5, rope_theta=1e5,
+            layer_types=("eva",) * n_layers, eva_window=2048, eva_chunk=16,
+            n_pred_heads=8, residual_f32=True, norm_unit_offset=True,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_eva(**kw) -> "TransformerConfig":
+        """The same kind of model at test size (CPU): three layers, 4
+        heads of 16, windows of 32 tokens in chunks of 4 (three windows
+        close within a hundred tokens), 3 prediction heads."""
+        base = dict(
+            vocab_size=64, d_model=64, n_layers=3, n_heads=4, d_head=16,
+            d_ff=96, rotary_dim=16, max_seq_len=256, residual="sequential",
+            activation="silu", gated_ffn=True, norm_eps=1e-5, rope_theta=1e5,
+            layer_types=("eva",) * 3, eva_window=32, eva_chunk=4,
+            n_pred_heads=3, residual_f32=True, norm_unit_offset=True,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
     def tiny_mla_moe(**kw) -> "TransformerConfig":
         """The same block at test size (CPU)."""
         base = dict(
@@ -798,7 +881,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
 
     def norm_init(shape, key):
         # a LayerNorm's bias: 0 in a fresh model, here drawn so that a
-        # comparison with a reference sees it
+        # comparison with a reference sees it; so is the w of a norm
+        # scaled by 1 + w
+        if c.norm_unit_offset:
+            return {"scale": (0.02 * jax.random.normal(key, shape)
+                              ).astype(pd)}
         return {"scale": jnp.ones(shape, pd),
                 **({"bias": (0.02 * jax.random.normal(key, shape)
                              ).astype(pd)} if c.norm == "layer" else {})}
@@ -997,6 +1084,28 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
             **mha_extras(kc, L, 0, queries_only=True)}
         return layers
 
+    def eva_stack(L: int) -> Dict:
+        """L "eva" layers: norms and FFN as ``stack`` draws them, MHA
+        projections, and the two pooling vectors a head, ``phi`` (keys)
+        and ``mu`` (values), clip(normal, -1, 1) / sqrt(d_head): a chunk's
+        pooling logits k . phi / sqrt(d_head) then spread by about a
+        tenth of the key's norm, and a summary is no plain mean."""
+        layers = stack(c, L, 53, 0, attends=False)
+        ks = jax.random.split(jax.random.fold_in(k_q, 59), 6)
+        d, h, dh = c.d_model, c.n_heads, c.d_head
+
+        def pooling(key):
+            return (jnp.clip(jax.random.normal(key, (L, h, dh)), -1, 1)
+                    * dh ** -0.5).astype(pd)
+
+        layers["eva"] = {
+            "wq": dense_init(ks[0], (L, d, h, dh), d),
+            "wk": dense_init(ks[1], (L, d, h, dh), d),
+            "wv": dense_init(ks[2], (L, d, h, dh), d),
+            "wo": dense_init(ks[3], (L, h, dh, d), h * dh),
+            "phi": pooling(ks[4]), "mu": pooling(ks[5])}
+        return layers
+
     def ssm_stack(L: int) -> Dict:
         """L state-space layers: the block's norms and FFN as ``stack``
         draws them, and the mixer's own parameters with the initialisers
@@ -1069,12 +1178,13 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     params = {
         "embed": (jax.random.normal(k_emb, (c.vocab_size, c.d_model)) * 0.02
                   ).astype(pd),
-        "layers": stack(c, c.n_attn_layers - n_dense + dense_kda, 0,
-                        n_dense),
         "final_ln": norm_init((c.d_model,), jax.random.fold_in(k_emb, 1)),
     }
+    n_rest = c.n_attn_layers - n_dense + dense_kda
+    if n_rest or not c.layer_types:  # a model may have no such layer
+        params["layers"] = stack(c, n_rest, 0, n_dense)
     for kind, make in (("mamba", mamba_stack), ("gmu", gmu_stack),
-                       ("cross", cross_stack)):
+                       ("cross", cross_stack), ("eva", eva_stack)):
         if c.n_of(kind):
             params[_KIND_STACKS[kind]] = make(c.n_of(kind))
     if c.n_ssm_layers:
@@ -1089,8 +1199,8 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     elif n_dense:
         params["dense_layers"] = stack(c.dense_variant(), n_dense, 7, 0)
     if not c.tie_embeddings:
-        params["lm_head"] = dense_init(k_head, (c.d_model, c.vocab_size),
-                                       c.d_model)
+        params["lm_head"] = dense_init(
+            k_head, (c.d_model, c.n_pred_heads * c.vocab_size), c.d_model)
     return params
 
 
@@ -1176,9 +1286,10 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
     n_dense = config.n_dense_layers if config.moe_experts else 0
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": stack(config, n_dense, config.n_layers - n_dense),
         "final_ln": norm_axes(("embed",)),
     }
+    if config.n_attn_layers or not config.layer_types:
+        axes["layers"] = stack(config, n_dense, config.n_layers - n_dense)
     for kind, mixer in (
             ("mamba", {
                 "wx": ("layers", "embed", "mlp"),
@@ -1195,7 +1306,13 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
                      "wo": ("layers", "mlp", "embed")}),
             ("cross", {"wq": ("layers", "embed", "heads", "head_dim"),
                        "wo": ("layers", "heads", "head_dim", "embed"),
-                       **mha_extras(queries_only=True)})):
+                       **mha_extras(queries_only=True)}),
+            ("eva", {"wq": ("layers", "embed", "heads", "head_dim"),
+                     "wk": ("layers", "embed", "heads", "head_dim"),
+                     "wv": ("layers", "embed", "heads", "head_dim"),
+                     "wo": ("layers", "heads", "head_dim", "embed"),
+                     "phi": ("layers", "heads", "head_dim"),
+                     "mu": ("layers", "heads", "head_dim")})):
         if config.n_of(kind):
             layers = stack(config, 0, config.n_of(kind))
             del layers["attn"]
@@ -1256,7 +1373,7 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
 _KIND_STACKS = {"attention": "layers", "ssm": "ssm_layers",
                 "window": "window_layers", "kda": "kda_layers",
                 "mamba": "mamba_layers", "gmu": "gmu_layers",
-                "cross": "cross_layers"}
+                "cross": "cross_layers", "eva": "eva_layers"}
 
 
 def layer_groups(params: Dict, config: TransformerConfig):
@@ -1270,7 +1387,7 @@ def layer_groups(params: Dict, config: TransformerConfig):
     leading dense layers, of whichever ONE kind they are, are a group of
     their own before it."""
     n_dense = config.n_dense_layers if config.moe_experts else 0
-    dense, rest = params.get("dense_layers"), params["layers"]
+    dense, rest = params.get("dense_layers"), params.get("layers")
     if config.layer_types:
         dense = {config.layer_types[0]: dense}
         rest = {kind: params[name] for kind, name in _KIND_STACKS.items()
@@ -1476,16 +1593,23 @@ def _rms_norm(x, scale, eps=1e-6):
 
 
 def _norm(x, p, c: "TransformerConfig"):
-    """The block's norm under its parameters ``p``: RMSNorm with a scale,
-    or (``c.norm`` "layer") LayerNorm with a scale and a bias, the mean
-    subtracted; float32 statistics either way."""
-    if c.norm != "layer":
-        return _rms_norm(x, p["scale"], c.norm_eps)
-    x32 = x.astype(jnp.float32)
-    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return ((x32 * lax.rsqrt(var + c.norm_eps)).astype(x.dtype)
-            * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype))
+    """The block's norm under its parameters ``p``: RMSNorm with a scale
+    (``c.norm_unit_offset``: the scale is 1 + w, formed in float32), or
+    (``c.norm`` "layer") LayerNorm with a scale and a bias, the mean
+    subtracted; float32 statistics either way. Returns x's type, but of a
+    float32 residual stream (``c.residual_f32``) the compute dtype: what
+    the branches' products take."""
+    if c.norm_unit_offset:
+        y = _rms_norm(x, 1.0 + p["scale"].astype(jnp.float32), c.norm_eps)
+    elif c.norm != "layer":
+        y = _rms_norm(x, p["scale"], c.norm_eps)
+    else:
+        x32 = x.astype(jnp.float32)
+        x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        y = ((x32 * lax.rsqrt(var + c.norm_eps)).astype(x.dtype)
+             * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype))
+    return y.astype(c.dtype) if c.residual_f32 else y
 
 
 def _rotary(q, k, rotary_dim, positions, base=10000.0):
@@ -1942,6 +2066,41 @@ def _mla_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     return a, extra
 
 
+def _eva_whole_sequence(q, k, v, wp, c: TransformerConfig):
+    """An "eva" layer's attention over whole sequences with nothing kept
+    (the uncached forward): every window the sequence reaches is pooled
+    (``ops/eva.eva_pool``; the last, where it is not whole, is padded with
+    rows that no query sees as a summary) and the queries attend their
+    own window and the summaries before it (``ops/eva.eva_attention``)."""
+    S, W = q.shape[1], c.eva_window
+    q, k, v = (jnp.pad(x, ((0, 0), (0, -S % W), (0, 0), (0, 0)))
+               for x in (q, k, v))
+    with jax.named_scope("raytpu.eva.pool"):
+        ks, vs = eva_pool(k, v, wp["phi"], wp["mu"], c.eva_chunk)
+    with jax.named_scope("raytpu.eva.attend"):
+        out = eva_attention(q, k, v, ks, vs, window=W, chunk=c.eva_chunk)
+    return out[:, :S], None
+
+
+def _eva_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
+    """An "eva" layer's mixer (``TransformerConfig.eva_window``): MHA
+    projections with the model's rotary, then the layer's attention,
+    ``attn_fn(q, k, v, wp) -> (out, extra)``: ``_eva_whole_sequence``
+    with nothing kept (``forward``), or the serving paths' over the open
+    window's rows and the closed windows' summaries that a slot keeps
+    (``generation.py``)."""
+    with jax.named_scope("raytpu.eva.project"):
+        q = jnp.einsum("bsd,dhk->bshk", h, wp["wq"].astype(c.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, wp["wk"].astype(c.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, wp["wv"].astype(c.dtype))
+        if c.rotary_dim:
+            q, k = _rotary(q, k, c.rotary_dim, positions, c.rope_theta)
+    out, extra = attn_fn(q, k, v, wp)
+    with jax.named_scope("raytpu.eva.project"):
+        out = jnp.einsum("bshk,hkd->bsd", out, wp["wo"].astype(c.dtype))
+    return out, extra
+
+
 def _attn_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     """An "attention" layer's mixer: ``c.mixer``'s."""
     return {"mha": _mha_mixer, "mla": _mla_mixer}[c.mixer](
@@ -1953,7 +2112,7 @@ def _attn_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
 # window's.
 _MIXERS = {"attn": _attn_mixer, "ssm": _ssm_mixer, "kda": _kda_mixer,
            "swa": partial(_mha_mixer, window=True), "mamba": _mamba_mixer,
-           "gmu": _gmu_mixer, "cross": _cross_mixer}
+           "gmu": _gmu_mixer, "cross": _cross_mixer, "eva": _eva_mixer}
 
 
 def layer_kind(lp: Dict) -> str:
@@ -2070,6 +2229,8 @@ def forward(
         with jax.named_scope("raytpu.swa.attend"):
             return window_attention(q, k, v, sink, window=c.window)
 
+    own = {"swa": window_fn, "eva": partial(_eva_whole_sequence, c=c)}
+
     def handing(kind, handed):
         """``attn_fn`` of a layer of a model whose layers hand things on
         (``handed``: the last "mamba" layer's ``m`` and the last
@@ -2102,8 +2263,7 @@ def forward(
             kind = layer_kind(lp)
             y, a, extra = apply_layer(
                 x, lp, lc, positions,
-                handing(kind, handed) if hands else
-                window_fn if kind == "swa" else attn_fn,
+                handing(kind, handed) if hands else own.get(kind, attn_fn),
                 mesh=mesh)
             if hands and extra:
                 handed = {**handed, **extra}
@@ -2123,15 +2283,32 @@ def forward(
 def embed_tokens(params, tokens, c: TransformerConfig):
     """Token ids [...] -> the first hidden state [..., D]."""
     x = params["embed"].astype(c.dtype)[tokens]
+    if c.residual_f32:
+        x = x.astype(jnp.float32)
     return x * c.embed_scale if c.embed_scale != 1.0 else x
 
 
 def lm_logits(params, x, c: TransformerConfig):
     """Hidden states [B, S, D] -> logits [B, S, V]: the final norm and
-    the head (the embedding's transpose where tied)."""
+    the head (the embedding's transpose where tied). With
+    ``c.n_pred_heads`` > 1: [B, S, n_pred_heads, V] in float32."""
     x = _norm(x, params["final_ln"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
+    if c.n_pred_heads > 1:
+        return pred_logits(x, head, c)
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(c.dtype))
+    return logits * c.logit_scale if c.logit_scale != 1.0 else logits
+
+
+def pred_logits(x, head, c: TransformerConfig):
+    """Several prediction heads side by side in ``head`` [D, n_pred_heads
+    x V]: normed hidden states [..., D] -> logits [..., n_pred_heads, V]
+    in float32 (operands in the compute dtype, float32 products, never
+    rounded)."""
+    logits = jnp.einsum("...d,dv->...v", x, head.astype(c.dtype),
+                        preferred_element_type=jnp.float32)
+    logits = logits.reshape(
+        logits.shape[:-1] + (c.n_pred_heads, c.vocab_size))
     return logits * c.logit_scale if c.logit_scale != 1.0 else logits
 
 

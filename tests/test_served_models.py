@@ -28,6 +28,7 @@ from ray_tpu.models import generation as gen
 from ray_tpu.models import (
     reference,
     reference_dsa,
+    reference_eva,
     reference_kda,
     reference_sambay,
     reference_ssm,
@@ -163,6 +164,23 @@ def _sambay_rehearsed(values, note):
     assert probe["replayed"] and probe["refused_by"] == []
 
 
+def _eva_rehearsed(values, note):
+    # the rehearsal's engine: windows of 32 rows in chunks of 4, prompts of
+    # 16-128 tokens: both kinds of row are read, and windows close
+    share = values["engine.summary_rows_share"]
+    assert share is not None and 0 < share < 100
+    end = note["backlog"]["end"]
+    assert end["eva_windows_closed"] > 0 and end["eva_window_rows_read"] > 0
+    # the host's count walks whole chunks of the rows a position leaves
+    assert end["attn_rows_read"] > 0
+    assert end["slot_state_bytes"] == 0
+    assert end["slot_row_bytes"] == 2 * 2 * 128 * 2
+    probe = note["probe"]
+    assert probe["replayed"] and probe["refused_by"] == []
+    assert probe["summary_prefill"] is not None
+    assert probe["summary_decode"] is not None
+
+
 def _latent_hp(cfg):
     return {"n_heads": cfg.n_heads, "qk_nope": cfg.qk_nope_dim,
             "qk_rope": cfg.qk_rope_dim, "kv_rank": cfg.kv_lora_rank,
@@ -184,6 +202,8 @@ KDA = TransformerConfig.tiny_kda_moe(dtype=F32)
 # M W M W | M F | G X: 128 channels over a state of 16, a window of 8 rows,
 # four differential heads over two pairs of KV heads
 SAMBAY = TransformerConfig.tiny_sambay(dtype=F32)
+# three layers, all "eva": windows of 32 tokens in chunks of 4, 3 heads
+EVA = TransformerConfig.tiny_eva(dtype=F32)
 _SERVED = ("model.decode_step_ms", "device.idle_share.serve",
            "engine.kv_read_share")
 # a prefill of 21 tokens in a bucket of 32, then 12 decode steps (the
@@ -436,6 +456,47 @@ MODELS = {
              "kernel.mamba_scan_roofline_share",
              "model.window_attn_time_share") + _SERVED,
             _sambay_rehearsed)),
+    "eva": Model(
+        cfg=EVA, ref=reference_eva, hp={
+            "n_heads": EVA.n_heads, "d_head": EVA.d_head,
+            "eps": EVA.norm_eps, "theta": EVA.rope_theta,
+            "window": EVA.eva_window, "chunk": EVA.eva_chunk,
+            "n_pred_heads": EVA.n_pred_heads},
+        copy="benchmarks/reference_eva.py", foreign=PROGRAM,
+        tol=2e-4, metric=0,
+        stacks={"eva_layers": {"ln1", "ln2", "eva", "mlp"}},
+        shapes={"eva_layers/eva/phi": (3, 4, 16),
+                "eva_layers/eva/wk": (3, 64, 4, 16),
+                "lm_head": (64, 3 * 64)},
+        counters=("eva_window_rows_read", "eva_summary_rows_read",
+                  "eva_windows_closed"), state=None,
+        # 40 decode steps: a window of 32 rows closes at least once; the
+        # prompts lie on both sides of a chunk, of a window and of two
+        through={
+            **{name: Through({1: n}, 2, 192, bucket, 40)
+               for name, n, bucket in (
+                   ("below_a_chunk", 3, 8), ("below_the_window", 21, 32),
+                   ("a_window", 32, 32), ("above", 45, 64),
+                   ("many_windows", 100, 128))},
+            # lanes at different depths and a parked one between them: they
+            # close their windows at different steps
+            "lanes_at_different_depths": Through(
+                {0: 70, 2: 9, 3: 31}, 4, 192, 128, 30)},
+        generated=(27, 64),
+        # the last token lies deep in an open window, behind two closed
+        refused=Through({0: 70}, 1, 128, 80, 25),
+        ablations=(
+            {"pool_15_of_16": True}, {"swap_phi_mu": True},
+            {"open_summaries": True}, {"residual_bf16": True},
+            {"pool_unrotated": True}, {"pool_unscaled": True},
+            {"no_summaries": True}, {"fp8_weights": True}),
+        floor=1e-3, ablated_state=0,
+        cell=Cell(
+            "serve-evabyte-bytedoc-saturated", "serve_eva",
+            "bytedoc-saturated", ("tpot_p50_ms", "setup_s"),
+            ("model.eva_time_share", "model.prefill_eva_share",
+             "engine.summary_rows_share", "kernel.decode_hbm_share.eva")
+            + _SERVED, _eva_rehearsed)),
 }
 
 
@@ -506,6 +567,8 @@ def worst_margin(m: Model, params, prompt, ids):
     """How far the served tokens' logits lie under the reference's
     largest, teacher-forced on the served tokens (0: the same tokens)."""
     logits, _ = ref_logits(m, params, list(prompt) + list(ids[:-1]))
+    if logits.ndim == 3:  # several prediction heads: the next token's
+        logits = logits[:, 0]
     return float(reference.served_token_margin(
         logits[len(prompt) - 1:], jnp.asarray(ids, jnp.int32)).max())
 

@@ -455,6 +455,73 @@ def test_sambay_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "[16384,16384]" not in hlo  # no prompt's scores whole
 
 
+@pytest.mark.parametrize("program", ["decode_block", "admission_28672"])
+def test_eva_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """One pipeline stage of EvaByte (8 of 32 layers, every width as
+    published, the whole byte vocabulary and all eight heads, bf16) at
+    the benchmark's engine sizes: 16 slots of 32,768 positions, 3,968
+    rows a slot-layer. A model with no "attn" layer: the ONE pair of row
+    leaves is the "eva" layers', updated in place by the token's write,
+    by the loop that folds a closed window and by an admission; a decode
+    step reads a slot's summaries and open window with the decode
+    attention's kernel (32 heads of 128) and copies no layer of the
+    stacked weights; the admission at the largest bucket never makes a
+    prompt's scores whole and leaves room on a 16 GB chip (ISSUE 55)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.evabyte(8, param_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, 16, 32768)))
+    lanes = (arr((16,)), arr((16,)), arr((16,), jnp.float32), arr((16,)),
+             arr((16,)))
+    if program == "decode_block":
+        low = gen.decode_block.lower(params, cache, *lanes, cfg, 8)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, 28672)), arr(()), arr(()), cache, cfg, lanes,
+            arr((), jnp.float32), arr(()))
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    foot = gen.slot_footprint(cache)
+    assert (foot["state_bytes"], foot["row_bytes"]) == (0, 8 * 16384)
+    cache_bytes = 16 * 3968 * foot["row_bytes"]  # 8.3 GB
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 14.0 * 2 ** 30
+    hlo = compiled.as_text()
+    # (the q, k, v projections' stacks [8, 4096, 32, 128] are asked for in
+    # another layout by the decode step, as GPT-J's: the engine places
+    # them so once, ``generation.lay_out_for_decode``)
+    for of in ("bf16[8,16,3968,", "bf16[16,3968,", "bf16[8,4096,11008",
+               "bf16[4096,11008", "bf16[8,11008,", "bf16[11008,4096",
+               "bf16[8,32,128,4096"):
+        assert not _copies(hlo, of), of
+    for scope in ("raytpu.eva.project", "raytpu.eva.attend",
+                  "raytpu.eva.pool"):
+        assert scope in hlo, scope
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    if program == "decode_block":
+        # eight layers of one kind: one body, one kernel
+        assert len(calls) == 1 and "decode_attention" in calls[0]
+        assert "raytpu.eva.attend" in calls[0]
+    else:
+        assert not calls  # plain jnp, block by block
+        assert "[28672,28672]" not in hlo  # no prompt's scores whole
+
+
 @pytest.mark.parametrize("kernel", ["ssm_update", "kda_update"])
 def test_a_state_kernel_told_the_live_lanes_compiles_in_place(
         v5e, kernel, monkeypatch):

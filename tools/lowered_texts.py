@@ -11,7 +11,7 @@ is compiled and no weight is made (shapes alone), so it runs anywhere.
 A PR that means to leave a configuration's programs alone shows it with
 an empty diff against its parent (``git archive`` of the parent into a
 scratch directory); where lines differ they name the programs to measure.
-Names: gptj glm47 glm52 granite mimo kimi phi4flash train (default: all
+Names: gptj glm47 glm52 granite mimo kimi phi4flash evabyte train (default: all
 the tree has).
 """
 
@@ -34,6 +34,7 @@ SERVED = {  # name -> (module of benchmarks/, configuration)
     "mimo": ("swa_moe_model", "mimo-v2-flash-l7-e16-bf16-serve"),
     "kimi": ("kda_moe_model", "kimi-linear-l8-e64-bf16-serve"),
     "phi4flash": ("sambay_model", "phi4-mini-flash-bf16-serve"),
+    "evabyte": ("eva_model", "evabyte-l8-bf16-serve"),
 }
 
 
