@@ -40,8 +40,9 @@ slot's OWN length, and nothing of a parked slot. A block with an indexer
 attends fewer rows still: it scores the prefix's index keys, picks
 ``index_topk`` rows a lane, and attends those alone (``_decode_choice``,
 ``_attend_latent_chosen``: a masked walk up to the longest lane, see
-there); its prefill attends block by block under the mask of each query's
-chosen rows (``_prefill_choice``, ``_attend_masked``).
+there); its prefill attends under the mask of each query's chosen rows
+through the prefill kernel, a group of heads a call (``_prefill_choice``,
+``ops/attention.blocked_causal_attention`` with a ``mask``).
 
 A decoder-hybrid-decoder's upper layers keep NOTHING: a "cross" layer
 attends the rows the one "attention" layer below keeps (the token's own
@@ -91,8 +92,10 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops.attention import (
     NEG_INF,
+    block_of,
     blocked_causal_attention,
     causal_attention,
+    prefill_block_pairs,
     repeat_kv,
     window_attention,
 )
@@ -632,12 +635,10 @@ def _attend_latent_prefix_plus_self(q_lat, q_rope, ckv, kr, c_new, r_new,
 # (128 queries x 32 heads x 24,576 rows of float32: 0.4 GB).
 DSA_CHUNK = 2048
 DSA_QUERY_BLOCK = 128
-# Queries and rows one tile of the prefill attention holds, and the heads
-# it expands keys and values for at a time (16 heads x 1,024 x 1,024
-# float32 scores are 64 MB; keys and values of 16 heads over 24,576 tokens
-# 0.2 GB each, where all 64 at once would not fit beside the weights; 1,024
-# ran 119 TFLOP/s at 24,576 tokens where 512 ran 105, my chip run, PR 32).
-PREFILL_ATTN_BLOCK = 1024
+# The heads a prefill under a choice expands keys and values for at a time,
+# one call of the attention's kernel a group (keys and values of 16 heads
+# over 24,576 tokens are 0.2 GB each, where all 64 at once would not fit
+# beside the weights).
 PREFILL_HEAD_GROUP = 16
 # A latent prefill whose float32 scores [heads, S, S] would be larger than
 # this attends block by block inside one kernel
@@ -654,15 +655,6 @@ def _index_scores(q, w, k):
     s = jnp.einsum("...tjd,...sd->...tjs", q, k,
                    preferred_element_type=jnp.float32)
     return (jax.nn.relu(s) * w[..., None]).sum(-2)
-
-
-def _block_of(n: int, most: int) -> int:
-    """The largest block of at most ``most`` that divides ``n`` when halved
-    down from ``most`` (``n`` itself where it is smaller)."""
-    blk = min(most, n)
-    while n % blk:
-        blk //= 2
-    return blk
 
 
 def select_rows(scores, valid, k: int):
@@ -695,13 +687,13 @@ def select_rows(scores, valid, k: int):
                              <= room[..., None]))
 
 
-def _prefill_choice(q, k, w, topk: int):
+def _prefill_choice(q, k, w, topk: int, dtype=bool):
     """A prompt's choice: index queries q [S, nI, dI], keys k [S, dI],
-    weights w [S, nI] -> the mask [S, S] whose row t marks the
-    min(t + 1, topk) rows s <= t that query t attends. Scored and chosen
-    ``DSA_QUERY_BLOCK`` queries at a time against every row."""
+    weights w [S, nI] -> the mask [S, S] (of ``dtype``) whose row t marks
+    the min(t + 1, topk) rows s <= t that query t attends. Scored and
+    chosen ``DSA_QUERY_BLOCK`` queries at a time against every row."""
     S = q.shape[0]
-    blk = _block_of(S, DSA_QUERY_BLOCK)
+    blk = block_of(S, DSA_QUERY_BLOCK)
     rows = jnp.arange(S)
 
     def one(block):
@@ -709,54 +701,13 @@ def _prefill_choice(q, k, w, topk: int):
         with jax.named_scope("raytpu.dsa.index"):
             scores = _index_scores(qb, wb, k)  # [blk, S]
         with jax.named_scope("raytpu.dsa.select"):
-            return select_rows(scores, rows[None, :] <= tb[:, None], topk)
+            return select_rows(scores, rows[None, :] <= tb[:, None],
+                               topk).astype(dtype)
 
     masks = lax.map(one, (q.reshape((-1, blk) + q.shape[1:]),
                           w.reshape(-1, blk, w.shape[-1]),
                           rows.reshape(-1, blk)))
     return masks.reshape(S, S)
-
-
-def _attend_masked(q, k, v, mask, block: int = PREFILL_ATTN_BLOCK):
-    """Attention of one sequence under an arbitrary mask that is causal
-    at least: q, k [S, H, D], v [S, H, Dv], ``mask`` [S, S] (row t: the
-    rows query t attends, none beyond t, at least one). Tile by tile with
-    an online softmax in float32 (bf16 operands), the rows' tiles only up
-    to the queries' own: memory grows with S, not with S squared. Scores
-    are scaled by 1/sqrt(D). Returns [S, H, Dv] in q's type."""
-    S, H, D = q.shape
-    blk = _block_of(S, block)
-    f32 = jnp.float32
-    scale = D ** -0.5
-
-    def queries(i):
-        qb = lax.dynamic_slice_in_dim(q, i * blk, blk)
-
-        def rows(j, state):
-            m, l, acc = state  # [H,blk,1], [H,blk,1], [H,blk,Dv]
-            kb = lax.dynamic_slice_in_dim(k, j * blk, blk)
-            vb = lax.dynamic_slice_in_dim(v, j * blk, blk)
-            mb = lax.dynamic_slice(mask, (i * blk, j * blk), (blk, blk))
-            s = jnp.einsum("qhd,khd->hqk", qb, kb,
-                           preferred_element_type=f32) * scale
-            s = jnp.where(mb[None], s, NEG_INF)
-            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-            p = jnp.where(mb[None], jnp.exp(s - m_new), 0.0)
-            shrink = jnp.exp(m - m_new)
-            l = shrink * l + p.sum(-1, keepdims=True)
-            acc = shrink * acc + jnp.einsum(
-                "hqk,khd->hqd", p.astype(q.dtype), vb,
-                preferred_element_type=f32)
-            return m_new, l, acc
-
-        m0 = jnp.full((H, blk, 1), NEG_INF, f32)
-        _, l, acc = lax.fori_loop(
-            0, i + 1, rows,
-            (m0, jnp.zeros_like(m0), jnp.zeros((H, blk, v.shape[-1]), f32)))
-        return (acc / l).transpose(1, 0, 2).astype(q.dtype)
-
-    out = lax.map(queries, jnp.arange(S // blk))
-    return out.reshape(S, H, v.shape[-1])
 
 
 def _decode_choice(q, w, ik, slot, k_new, pos, topk: int):
@@ -851,18 +802,23 @@ def _attend_latent_chosen(q_lat, q_rope, ckr, row_new, pos, chosen, *,
     return (acc / l[:, 0, :, None]).astype(q_lat.dtype)
 
 
-def _prefill_attn_chosen(single, li, wp, choice, c: TransformerConfig):
+def _prefill_attn_chosen(single, li, wp, choice, c: TransformerConfig,
+                         prompt_len=None):
     """One prefill layer's ``attn_fn`` for a latent block with an indexer
     (the counterpart of ``_decode_attn_chosen``). ``single`` is one slot's
     cache, ``wp`` the layer's attention weights with its kind
     (``scan_stack``), ``choice`` what the layer scan carries: the mask
-    [S, S] of the nearest layer below that owns an indexer, and that
-    layer's index keys ``k`` [S, dI]. Returns (output, (single with this
-    layer's rows written, choice))."""
+    [S, S] of the nearest layer below that owns an indexer (bool, or int8
+    as the attention's kernel reads it: a layer that chooses afresh keeps
+    the type it was handed), and that layer's index keys ``k`` [S, dI];
+    ``prompt_len`` the prompt's real tokens (None: all S): the queries
+    past them are not attended and their output rows are zeros. Returns
+    (output, (single with this layer's rows written, choice))."""
     S = choice["mask"].shape[0]
 
     def afresh(q, k, w):
-        return {"mask": _prefill_choice(q[0], k[0], w[0], c.index_topk),
+        return {"mask": _prefill_choice(q[0], k[0], w[0], c.index_topk,
+                                        choice["mask"].dtype),
                 "k": k[0]}
 
     @_latent
@@ -881,7 +837,8 @@ def _prefill_attn_chosen(single, li, wp, choice, c: TransformerConfig):
 
             k, v = mla_expand(c_kv, k_r, {"wuk": of(wp["wuk"]),
                                           "wuv": of(wp["wuv"])}, c)
-            return _attend_masked(of(q), k[0], v[0], chosen["mask"])
+            return blocked_causal_attention(
+                of(q)[None], k, v, prompt_len, mask=chosen["mask"])[0]
 
         out = lax.map(heads, jnp.arange(n_h // g))  # [H/g,S,g,v]
         out = out.transpose(1, 0, 2, 3).reshape(S, n_h, -1)
@@ -1500,14 +1457,21 @@ def _prefill_eva(single, li, p, c: TransformerConfig):
 
 def prefill_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     """Names of the int32 counters the admission form of
-    ``prefill_into_slot`` returns with its first token: for dropless
-    routed experts ``prefill_moe_assignments`` and
-    ``prefill_moe_pair_rows`` (``ops/moe.routed_ffn``'s
-    ``moe_assignments`` and ``moe_pair_rows``, summed over the prompt's
-    routed layers). None for the other models."""
+    ``prefill_into_slot`` returns with its first token, in the order they
+    leave it (a dict's: sorted): for dropless routed experts
+    ``prefill_moe_assignments`` and ``prefill_moe_pair_rows``
+    (``ops/moe.routed_ffn``'s ``moe_assignments`` and ``moe_pair_rows``,
+    summed over the prompt's routed layers); for a block with an indexer
+    ``prefill_attn_blocks``, the (block of queries, block of rows) pairs
+    the attention's kernel computes a head for this prompt
+    (``ops/attention.prefill_block_pairs``), and
+    ``prefill_attn_blocks_bucket``, what the whole bucket's causal blocks
+    would be, summed over the prompt's layers. None for the other models."""
+    keys = sum((row.prefill_counters
+                for _kind, row, _n in _kinds_of(config)), ())
     if config.moe_experts and config.moe_impl == "dropless":
-        return ("prefill_moe_assignments", "prefill_moe_pair_rows")
-    return ()
+        keys += ("prefill_moe_assignments", "prefill_moe_pair_rows")
+    return tuple(sorted(keys))
 
 
 def _add_stats(total, stats):
@@ -1551,6 +1515,16 @@ def _dsa_stats(pos, c: TransformerConfig, n: int, cache):
             live, jnp.minimum(rows, c.index_topk), 0).sum(),
         "dsa_rows_live": n * jnp.where(live, rows, 0).sum(),
     }
+
+
+def _chosen_prefill_stats(n: int, bucket: int, prompt_len):
+    """What one admission's ``n`` layers that attend under a choice add
+    to its counters: the kernel's blocks for the prompt, and for a prompt
+    as long as its bucket (a group of heads a call: each its own KV head)."""
+    return {"prefill_attn_blocks": n * prefill_block_pairs(
+                bucket, prompt_len, 1),
+            "prefill_attn_blocks_bucket": n * prefill_block_pairs(
+                bucket, bucket, 1)}
 
 
 def _window_stats(pos, c: TransformerConfig, n: int, cache):
@@ -1623,6 +1597,10 @@ class _Kind(NamedTuple):
     # what one decode step adds to them, from the lanes' positions
     counters: Tuple[str, ...] = ()
     counts: Optional[Callable] = None
+    # an admission's int32 counters of the kind (``prefill_stat_keys``),
+    # and (n, bucket, prompt_len) -> what its n layers add to them
+    prefill_counters: Tuple[str, ...] = ()
+    prefill_counts: Optional[Callable] = None
     # whether it keeps nothing and reads what layers below keep or hand on
     # for the SAME token: a prefill runs such layers, where they are the
     # model's last, on the prompt's last real token alone
@@ -1722,11 +1700,15 @@ _CHOSEN = _Kind(
     decode=lambda cache, li, lp, c, s, choice: _decode_attn_chosen(
         cache, li, s.pos, s.b_idx, c, lp["attn"], choice),
     prefill=lambda single, li, lp, c, p, choice: _prefill_attn_chosen(
-        single, li, lp["attn"], choice, c),
+        single, li, lp["attn"], choice, c, p.prompt_len),
     counters=("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_live"),
     counts=_dsa_stats,
+    prefill_counters=("prefill_attn_blocks", "prefill_attn_blocks_bucket"),
+    prefill_counts=_chosen_prefill_stats,
+    # a prompt's choice as its attention's kernel reads it, a step's as
+    # the walk does
     hands_on=lambda c, queries, rows, prompt=False: {
-        "mask": jnp.zeros((queries, rows), bool),
+        "mask": jnp.zeros((queries, rows), jnp.int8 if prompt else bool),
         "k": jnp.zeros((queries, c.index_head_dim), c.dtype)},
     chunk=lambda c, s_max: min(DSA_CHUNK, s_max), walks_longest=True)
 
@@ -1932,9 +1914,11 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     prompt; what the slot keeps is the latent rows ``c_kv`` and ``rot(k_r)``
     of every layer and, where the block has an indexer, the index key of
     every layer that owns one. Such a block's queries attend their chosen
-    rows alone, tile by tile (``_prefill_choice``, ``_attend_masked``:
-    the choice, a mask [Sb, Sb], travels from the layer that makes it to
-    the layers that share it in the layer scan's carry).
+    rows alone, inside the prefill kernel (``_prefill_choice``,
+    ``blocked_causal_attention`` under the choice's mask: the choice, a
+    mask [Sb, Sb] in int8, travels from the layer that makes it to the
+    layers that share it in the layer scan's carry; the blocks of queries
+    past ``prompt_len`` are skipped).
 
     A state-space layer runs its chunked scan over the padded prompt and
     the slot's STATE leaves are overwritten whole with the state at
@@ -1953,11 +1937,10 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     and returns (first token, cache, lanes, stats). Hand every scalar over
     as a numpy value of one dtype (``np.int32``, ``np.float32``): a Python
     number is weakly typed, which is another program. ``stats`` is empty
-    (no output at all) but for dropless routed experts: int32 scalars
-    ``prefill_moe_assignments`` (the (token, expert) pairs the prompt's
-    routed layers computed) and ``prefill_moe_pair_rows`` (the sorted-pair
-    rows they moved around the kernel: ``ops/moe.routed_ffn``), summed
-    over the layers; they leave the device as the token does."""
+    (no output at all) but for the int32 scalars ``prefill_stat_keys``
+    names and describes (dropless routed experts' pairs, the blocks an
+    attention under a choice computes), summed over the layers; they leave
+    the device as the token does."""
     c = config
     single = jax.tree.map(lambda a: jnp.zeros_like(a[:, :1]), cache)
     s_max = jax.tree.leaves(cache_rows(cache))[0].shape[2]
@@ -1971,9 +1954,14 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
 
     prompt_holds = _Prompt(prompt_len, positions, kv_valid)
     choice = _row("attn", c).hands_on(c, S, S, prompt=True)
-    # what an admission reports of its routed layers (none: an empty dict)
+    # what an admission reports: of its routed layers, summed as they run,
+    # and of its kinds, from the prompt's length (none: an empty dict)
     routed_stats = {k: jnp.zeros((), jnp.int32)
                     for k in prefill_stat_keys(c)}
+    for _kind, row, n in _kinds_of(c):
+        if row.prefill_counts:
+            routed_stats = _add_stats(
+                routed_stats, row.prefill_counts(n, S, prompt_len))
     carry = (x, single, choice, routed_stats)
     narrowed = False
     for stack, lc, first in layer_groups(params, c):

@@ -6,9 +6,10 @@ tiles the two matmuls onto the MXU. Used when the sequence axis is
 unsharded; `ring_attention` (sp>1) builds on the blockwise log-sum-exp
 accumulation primitives defined here. ``blocked_causal_attention`` (a full
 layer's prefill over a whole prompt, without its [S, S] scores) is one
-Pallas TPU kernel, forward only, with values narrower than keys and the
-prompt's length a prefetched scalar; the training kernel with a backward
-pass is `ops/flash_attention.py`. ``window_attention`` (a window layer's
+Pallas TPU kernel, forward only, with values narrower than keys, the
+prompt's length a prefetched scalar and, for a block that chooses the rows
+a query attends, the choice's mask an operand; the training kernel with a
+backward pass is `ops/flash_attention.py`. ``window_attention`` (a window layer's
 prefill) is blocked ``jnp``.
 """
 
@@ -119,6 +120,18 @@ def prefill_blocks(s: int, heads_a_kv_head: int, block: int):
     return bq, bk
 
 
+def prefill_block_pairs(s: int, length, heads_a_kv_head: int,
+                        block: int = 1024):
+    """The (block of queries, block of rows) pairs that
+    ``blocked_causal_attention`` computes a KV head for ``length`` real
+    tokens (an int, or a traced scalar) in a sequence of ``s``: the blocks
+    of queries that hold a real token, each against its blocks of rows up
+    to the diagonal's."""
+    bq, bk = prefill_blocks(s, heads_a_kv_head, block)
+    first = jnp.arange(s // bq, dtype=jnp.int32) * bq  # a block's first query
+    return jnp.where(first < length, first // bk + 1, 0).sum()
+
+
 @functools.partial(jax.jit, static_argnames="block")
 def blocked_causal_attention(
     q: jax.Array,  # [B, S, H, D]
@@ -126,6 +139,7 @@ def blocked_causal_attention(
     v: jax.Array,  # [B, S, Hkv, Dv]
     length: Optional[jax.Array] = None,  # [B] or a scalar: the real tokens
     *,
+    mask: Optional[jax.Array] = None,  # [S, S] or [B, S, S]: chosen rows
     block: int = 1024,
 ) -> jax.Array:
     """``causal_attention`` over whole sequences without its [S, S]
@@ -133,7 +147,10 @@ def blocked_causal_attention(
     may be narrower than keys, operands in their own type, scores and the
     online softmax in float32. Returns [B, S, H, Dv] in q's type; with
     ``length`` the rows at and past it are ZEROS (a padded bucket's tail:
-    never read, and never uninitialised memory either).
+    never read, and never uninitialised memory either). With ``mask``
+    (bool or int8, one for all sequences or one each) query t attends the
+    rows the mask's row t marks and no other: a choice that is causal
+    already (none past t) and marks at least one row a query.
 
     One Pallas TPU kernel, forward only: grid (B, KV heads, blocks of
     queries, blocks of rows), the rows' axis innermost. One KV head's
@@ -145,7 +162,16 @@ def blocked_causal_attention(
     written once, where it lies ([S, H x Dv]). A block of rows wholly
     above the diagonal and a block of queries wholly past ``length`` are
     neither fetched nor computed (the index maps ask for the block they
-    already hold); only the block the diagonal crosses builds a mask. K
+    already hold); only the block the diagonal crosses builds a mask. A
+    ``mask`` is fetched in int8, the step's (queries, rows) block of it by
+    an index map clamped like K's, and rules every computed block, the
+    diagonal's with no compare of its own: -1e30 is ADDED to the
+    scores it rules out (a score is lost in it: exactly -1e30, and no
+    array of booleans to keep), and the running maximum starts above that,
+    at -5e29, so their exponentials are 0 even where a query has no chosen
+    row in a whole block (a select would also drop a score that is NaN:
+    the rows it rules out must be finite, as V's always had to be). Without
+    a mask nothing of this is traced: the kernel is what it was. K
     and V are read where they lie too ([S, Hkv x D]); where one KV head's
     keys are not whole lanes wide (192) a grid step takes the fewest KV
     heads that are, one after the other. Blocks: ``prefill_blocks``. Off
@@ -167,9 +193,11 @@ def blocked_causal_attention(
                and n * D % 128 == 0 and n * Dv % 128 == 0), G)
     length = jnp.broadcast_to(
         jnp.asarray(S if length is None else length, jnp.int32), (B,))
+    masked = mask is not None
 
-    def kernel(len_ref, q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref,
-               acc_ref):
+    def kernel(len_ref, q_ref, k_ref, v_ref, *refs):
+        mask_ref = refs[0] if masked else None
+        o_ref, qs_ref, m_ref, l_ref, acc_ref = refs[-5:]
         i, j = pl.program_id(2), pl.program_id(3)
         n = len_ref[pl.program_id(0)]
         first = i * bq  # the block's first query
@@ -185,12 +213,17 @@ def blocked_causal_attention(
             for e in range(hs * R):  # head e's queries under head e - 1's
                 qs_ref[e // R, (e % R) * bq:(e % R + 1) * bq, :] = (
                     q_ref[:, e * D:(e + 1) * D])
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            # under a choice above what rules a score out: see the sweep
+            m_ref[...] = jnp.full_like(m_ref,
+                                       NEG_INF / 2 if masked else NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         def sweep(diagonal: bool):
-            if diagonal:
+            if masked:  # causal already: the diagonal's block like the rest
+                unseen = (jnp.concatenate([mask_ref[...]] * R).astype(f32)
+                          - 1.0) * -NEG_INF  # 0 a chosen row, else NEG_INF
+            elif diagonal:
                 seen = query_of((rows, bk), 0) >= j * bk + lax.broadcasted_iota(
                     jnp.int32, (rows, bk), 1)
             for e in range(hs):
@@ -198,10 +231,16 @@ def blocked_causal_attention(
                     qs_ref[e], k_ref[:, e * D:(e + 1) * D],
                     (((1,), (1,)), ((), ())),
                     preferred_element_type=f32) * scale  # [rows, bk]
-                if diagonal:
+                if masked:
+                    # NEG_INF itself (a score is lost in it), under every
+                    # running maximum: a query with no chosen row in the
+                    # blocks so far has exponentials of 0 all the same
+                    s = s + unseen
+                elif diagonal:
                     s = lax.select(seen, s, lax.full_like(s, NEG_INF))
-                # every query sees the block's first row (bq divides bk):
-                # the maximum is a real score and a masked exponential is 0
+                # causal alone, every query sees the block's first row (bq
+                # divides bk): the maximum is a real score and a masked
+                # exponential is 0
                 m = m_ref[e]
                 m_new = lax.max(m, lax.reduce_max(s, (1,))[:, None])
                 p = lax.exp(s - m_new)
@@ -212,13 +251,16 @@ def blocked_causal_attention(
                     (((1,), (0,)), ((), ())), preferred_element_type=f32)
                 m_ref[e] = m_new
 
-        @pl.when(live & (j < here))
-        def _below():
-            sweep(False)
+        if masked:
+            pl.when(live & (j <= here))(lambda: sweep(False))
+        else:
+            @pl.when(live & (j < here))
+            def _below():
+                sweep(False)
 
-        @pl.when(live & (j == here))
-        def _diagonal():
-            sweep(True)
+            @pl.when(live & (j == here))
+            def _diagonal():
+                sweep(True)
 
         @pl.when(live & (j == nk - 1))
         def _write():
@@ -239,15 +281,26 @@ def blocked_causal_attention(
     def keys(b, g, i, j, n):  # nor does a block above the diagonal
         return b, jnp.minimum(j, queries(b, g, i, j, n)[1] * bq // bk), g
 
+    def chosen(b, g, i, j, n):  # the mask's block of a step, clamped alike
+        return (b if mask.shape[0] > 1 else 0,
+                queries(b, g, i, j, n)[1], keys(b, g, i, j, n)[1])
+
+    operands = (q.reshape(B, S, H * D), k.reshape(B, S, G * D),
+                v.reshape(B, S, G * Dv))
+    in_specs = [pl.BlockSpec((None, bq, hs * R * D), queries),
+                pl.BlockSpec((None, bk, hs * D), keys),
+                pl.BlockSpec((None, bk, hs * Dv), keys)]
+    if masked:
+        mask = mask.astype(jnp.int8).reshape(-1, S, S)
+        operands += (mask,)
+        in_specs.append(pl.BlockSpec((None, bq, bk), chosen))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, S, H * Dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, G // hs, nq, nk),
-            in_specs=[pl.BlockSpec((None, bq, hs * R * D), queries),
-                      pl.BlockSpec((None, bk, hs * D), keys),
-                      pl.BlockSpec((None, bk, hs * Dv), keys)],
+            in_specs=in_specs,
             out_specs=pl.BlockSpec((None, bq, hs * R * Dv),
                                    lambda b, g, i, j, n: (b, i, g)),
             scratch_shapes=[pltpu.VMEM((hs, rows, D), q.dtype),
@@ -261,8 +314,7 @@ def blocked_causal_attention(
             vmem_limit_bytes=_PREFILL_VMEM),
         interpret=jax.default_backend() != "tpu",
         name="prefill_attention",
-    )(length, q.reshape(B, S, H * D), k.reshape(B, S, G * D),
-      v.reshape(B, S, G * Dv))
+    )(length, *operands)
     return out.reshape(B, S, H, Dv)
 
 

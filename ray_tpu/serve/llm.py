@@ -462,7 +462,13 @@ class LLMEngine:
           ``prefill_moe_pair_rows`` (sorted-pair rows gathered, selected
           and brought back to token order around the grouped product,
           ``ops/moe.routed_ffn``: every pair of the bucket for a whole
-          layer, the live pairs rounded up to tiles for a share).
+          layer, the live pairs rounded up to tiles for a share). For a
+          block with an indexer, over the prompt's layers:
+          ``prefill_attn_blocks`` ((block of queries, block of rows) pairs
+          the attention's kernel computed a head: the blocks of queries
+          with a real token, each up to the diagonal) and
+          ``prefill_attn_blocks_bucket`` (what the whole bucket's would
+          have been).
         """
         with self._lock:
             out = {

@@ -26,6 +26,12 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     init_params,
 )
+from ray_tpu.ops.attention import (
+    blocked_causal_attention,
+    prefill_block_pairs,
+    prefill_blocks,
+    repeat_kv,
+)
 from ray_tpu.ops.moe import routed_ffn
 
 # six layers, full | shared shared shared full shared; 16 rows a query; the
@@ -230,19 +236,103 @@ def test_selection_is_top_ks_set_with_ties_to_the_lower_row():
         jnp.where(valid, scores, -jnp.inf), 64)) == want).all()
 
 
-def test_masked_prefill_attention_matches_a_dense_softmax():
-    S, H, D = 96, 3, 8
+# sequence, query heads, KV heads, key width, value width, block, length,
+# whether the late queries choose nothing in the first blocks of rows
+MASKED = {
+    "block_32": (96, 3, 3, 8, 8, 32, None, False),
+    "block_96": (96, 3, 3, 8, 8, 96, None, False),
+    "block_1024": (96, 3, 3, 8, 8, 1024, None, False),
+    "length_in_a_block": (96, 3, 3, 8, 8, 32, 70, False),
+    "no_chosen_row_in_a_block": (96, 3, 3, 8, 8, 32, None, True),
+    "no_chosen_row_and_a_length": (128, 2, 2, 8, 8, 32, 97, True),
+    "narrow_values": (96, 3, 3, 16, 8, 32, 50, False),
+    "grouped_heads": (96, 4, 2, 16, 8, 32, 81, True),
+    "queries_block_halved": (64, 128, 2, 8, 8, 64, 40, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MASKED))
+def test_masked_prefill_attention_matches_a_dense_softmax(case):
+    """The prefill kernel under a choice's mask (interpreter) against
+    every score materialised: blocks that divide the sequence, that are
+    it, that are larger; a prompt that ends inside a block (the rows past
+    it zeros, the blocks of queries past it never read: they hold NaN);
+    queries whose first chosen row lies past whole blocks of rows (their
+    running maximum is still NEG_INF there, and exp(NEG_INF - NEG_INF) is
+    1); values narrower than keys; heads that share a KV head, and so
+    many of them that a block of queries is half a block of rows."""
+    S, H, G, D, Dv, block, length, hole = MASKED[case]
     key = jax.random.key(5)
-    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (S, H, D))
-               for i in range(3))
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, S, h, d))
+               for i, (h, d) in enumerate(((H, D), (G, D), (G, Dv))))
     mask = jnp.tril(jax.random.uniform(key, (S, S)) < 0.3) | jnp.eye(
         S, dtype=bool)
-    scores = jnp.einsum("qhd,khd->hqk", q, k) * D ** -0.5
-    want = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(
-        jnp.where(mask[None], scores, -jnp.inf), -1), v)
-    for block in (32, 96, 1024):
-        got = gen._attend_masked(q, k, v, mask, block)
-        assert float(jnp.abs(got - want).max()) < 1e-5
+    if hole:  # the last third's queries choose nothing in the first half
+        mask = mask.at[2 * S // 3:, :S // 2].set(False)
+    n = S if length is None else length
+    bq, bk = prefill_blocks(S, H // G, block)
+    assert (H // G * bq <= 2048) and (bq < bk) == (case.startswith("queries"))
+    past = -(-n // bq) * bq  # the first query of the blocks never read
+    q = q.at[:, past:].set(jnp.nan)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, repeat_kv(k, H // G)) * D ** -0.5
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(
+        jnp.where(mask[None, None], scores, -jnp.inf), -1),
+        repeat_kv(v, H // G))
+    with jax.default_matmul_precision("highest"):
+        got = blocked_causal_attention(q, k, v, length, mask=mask,
+                                       block=block)
+        same = blocked_causal_attention(
+            q, k, v, length, mask=mask.astype(jnp.int8)[None], block=block)
+    assert got.shape == (1, S, H, Dv)
+    assert float(jnp.abs(got - want)[:, :n].max()) < 1e-5
+    assert not np.asarray(got[:, n:]).any()  # zeros, and no NaN
+    assert (np.asarray(got) == np.asarray(same)).all()
+    live = -(-n // bq)
+    assert int(prefill_block_pairs(S, n, H // G, block)) == sum(
+        i * bq // bk + 1 for i in range(live))
+
+
+def test_the_prefill_kernel_takes_a_choice_a_sequence():
+    """A mask [B, S, S] and a length a sequence: each sequence under its
+    own, as two calls of one sequence would."""
+    S = 64
+    key = jax.random.key(6)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (2, S, 2, 8))
+               for i in range(3))
+    mask = jnp.tril(jax.random.uniform(key, (2, S, S)) < 0.4) | jnp.eye(
+        S, dtype=bool)
+    length = jnp.array([64, 19], jnp.int32)
+    got = blocked_causal_attention(q, k, v, length, mask=mask, block=16)
+    for b in range(2):
+        one = blocked_causal_attention(
+            q[b:b + 1], k[b:b + 1], v[b:b + 1], length[b], mask=mask[b],
+            block=16)
+        assert (np.asarray(got[b]) == np.asarray(one[0])).all()
+    assert np.asarray(got[1, :19]).any()
+    assert not np.asarray(got[1, 19:]).any()
+
+
+def test_an_admission_skips_and_counts_the_blocks_of_its_padding():
+    """A prompt of 700 in a bucket of 2,048 (two blocks of 1,024): the
+    admission's logits are those of the 1,024 bucket, and its counters say
+    one of the bucket's three (queries, rows) blocks was computed, a layer."""
+    params = init_params(CFG, jax.random.key(0))
+    prompt = jax.random.randint(jax.random.key(1), (700,), 0, CFG.vocab_size)
+    got = {}
+    for bucket in (1024, 2048):
+        padded = jnp.zeros((1, bucket), jnp.int32).at[0, :700].set(prompt)
+        args = (params, padded, np.int32(700), np.int32(0))
+        got[bucket] = gen.prefill_into_slot(
+            *args, gen.init_kv_cache(CFG, 2, 2304), CFG)[0]
+        lanes = tuple(jnp.zeros((2,), t) for t in (  # donated
+            jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.int32))
+        stats = gen.prefill_into_slot(
+            *args, gen.init_kv_cache(CFG, 2, 2304), CFG, lanes,
+            np.float32(0), np.int32(0))[3]
+        assert int(stats["prefill_attn_blocks"]) == CFG.n_layers
+        assert int(stats["prefill_attn_blocks_bucket"]) == CFG.n_layers * (
+            1 if bucket == 1024 else 3)
+    assert float(jnp.abs(got[1024] - got[2048]).max()) < TOL
 
 
 # -- a chip's share of a layer's experts -----------------------------------

@@ -639,6 +639,9 @@ def test_fused_admission_is_prefill_then_sampler_then_scatters(
         np.float32(temperature), np.int32(seed))
     assert tuple(stats) == prefill_stat_keys(icfg)
     assert bool(stats) == (kind not in ("tiny", "tiny_ssm_hybrid"))
+    if kind == "tiny_dsa_moe":  # a bucket of one block, a layer
+        assert (int(stats["prefill_attn_blocks"]), int(
+            stats["prefill_attn_blocks_bucket"])) == (icfg.n_layers,) * 2
     if stats:  # the padding picks no expert; a whole layer moves all pairs
         routed = icfg.n_layers - icfg.n_dense_layers
         pairs = routed * icfg.moe_top_k

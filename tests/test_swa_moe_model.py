@@ -318,6 +318,52 @@ def test_the_prefill_kernel_never_hands_on_what_padding_holds():
     assert not np.asarray(got[:, 40:]).any()  # and no NaN: zeros
 
 
+def _kernel_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, its sub-jaxprs' too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_calls(sub)
+
+
+def test_the_prefill_kernel_without_a_choice_takes_no_mask(params):
+    """ISSUE 56 gave the kernel a mask of chosen rows for the block with an
+    indexer. Without one the call is what it was: the length and q, k, v
+    where they lie, the same grid and scratch; the mask is a fifth operand
+    in int8 with a block of its own, and a window model's admission (this
+    file's, which attends through the kernel) holds no such array."""
+    S, H, G, D, Dv, block = 256, 8, 2, 16, 8, 64
+    q, k, v, _ = _qkv(9, 1, S, H, G, D, Dv)
+    mask = jnp.tril(jnp.ones((S, S), bool))
+
+    def call(**kw):
+        (eqn,) = _kernel_calls(jax.make_jaxpr(
+            lambda q, k, v, n: blocked_causal_attention(
+                q, k, v, n, block=block, **kw))(q, k, v, jnp.int32(70)).jaxpr)
+        grid = eqn.params["grid_mapping"]
+        inner = [str(x.aval) for x in eqn.params["jaxpr"].invars]
+        return ([str(x.aval) for x in eqn.invars], grid.grid,
+                inner[-grid.num_scratch_operands:])
+
+    operands, grid, scratch = call()
+    assert operands == ["int32[1]", f"float32[1,{S},{H * D}]",
+                        f"float32[1,{S},{G * D}]", f"float32[1,{S},{G * Dv}]"]
+    assert grid == (1, 1, S // block, S // block)  # both KV heads a step
+    rows = H // G * block
+    assert scratch == [f"Ref<vmem>{{float32[2,{rows},{D}]}}",
+                       f"Ref<vmem>{{float32[2,{rows},1]}}",
+                       f"Ref<vmem>{{float32[2,{rows},1]}}",
+                       f"Ref<vmem>{{float32[2,{rows},{Dv}]}}"]
+    chosen = call(mask=mask)
+    assert chosen == (operands + [f"int8[1,{S},{S}]"], grid, scratch)
+    cache = gen.init_kv_cache(CFG, 2, 128)
+    text = gen.prefill_into_slot.lower(
+        params, jnp.zeros((1, 64), jnp.int32), jnp.int32(40), jnp.int32(0),
+        cache, CFG).as_text()
+    assert "xi8>" not in text
+
+
 @pytest.mark.parametrize("sink", [True, False], ids=["sink", "no_sink"])
 def test_the_rings_kernel_equals_the_plain_softmax(sink):
     """Lanes at 0 (parked), under a window, at it and far past it: the

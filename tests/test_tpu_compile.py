@@ -129,6 +129,68 @@ def test_glm47_flash_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "prefill_attention" not in hlo
 
 
+def test_glm52_admission_attends_its_chosen_rows_through_the_kernel(
+        v5e, monkeypatch):
+    """GLM-5.2's first pipeline stage as ``serve-glm52-longdoc-steady``
+    serves it (6 layers, 16 of 256 experts, 12 slots x 25,600 rows), the
+    admission of the 12,288 bucket. ISSUE 56: a group of 16 heads attends
+    under the choice's mask through ``blocked_causal_attention`` (Mosaic
+    has to take 1,024 x 1,024 blocks of an int8 mask beside 256-wide keys
+    and values), once in the dense layer's stack and once in the routed
+    layers', under the scope ``model.prefill_dsa_time_share`` reads; the
+    mask [S, S] is made in int8 and handed over where it lies; no float32
+    score tile exists outside the kernel; and the program is no larger
+    than the tile loop's was (13.84 GiB by this count)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import json
+
+    from benchmarks import dsa_moe_model
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import init_params
+
+    with open(os.path.join(
+            os.path.dirname(dsa_moe_model.__file__), "configs",
+            "glm52-l6-e16-bf16-serve.json")) as f:
+        spec = json.load(f)
+    cfg = dsa_moe_model.transformer_config(spec)
+    eng = spec["run"]["engine"]
+    slots, bucket = eng["max_slots"], 12288
+    assert bucket in eng["prefill_buckets"]
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, slots, eng["max_len"])))
+    lanes = (arr((slots,)), arr((slots,)), arr((slots,), jnp.float32),
+             arr((slots,)), arr((slots,)))
+    low = gen.prefill_into_slot.lower(
+        params, arr((1, bucket)), arr(()), arr(()), cache, cfg, lanes,
+        arr((), jnp.float32), arr(()))
+    assert list(low.out_info[3]) == list(gen.prefill_stat_keys(cfg))
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 13.84 * 2 ** 30
+    hlo = compiled.as_text()
+    attends = [line for line in hlo.splitlines()
+               if "tpu_custom_call" in line and "prefill_attention" in line]
+    assert len(attends) == 2
+    assert all("raytpu.mla.attend" in line for line in attends)
+    assert all("s32[1]" in line and f"s8[1,{bucket},{bucket}]" in line
+               for line in attends)
+    assert not _copies(hlo, f"s8[{bucket},") and not _copies(hlo, "s8[1,")
+    assert f"pred[{bucket},{bucket}]" not in hlo  # the choice is int8
+    assert "f32[16,1024,1024]" not in hlo
+
+
 @pytest.mark.parametrize("program", ["decode_block", "prefill_2048"])
 def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
     """granite-4.0-h-micro whole (40 layers, every width as published,

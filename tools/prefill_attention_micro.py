@@ -7,6 +7,7 @@ passes it.
 
     chiprun -- python3 tools/prefill_attention_micro.py --check   # the table
     chiprun -- python3 tools/prefill_attention_micro.py --dense   # one product
+    chiprun -- python3 tools/prefill_attention_micro.py --chosen  # under a mask
     python3 tools/prefill_attention_micro.py --tiny                # here
 
 A layer-call's time is the host's clock over ``--calls`` calls dispatched
@@ -20,7 +21,13 @@ that time, against the chip's peak. ``--dense`` times ``causal_attention``
 ``--check`` compares the form at 1,024 tokens with ``causal_attention`` in
 float32 (largest error over the largest value; rows past the length must be
 zeros where the form takes one). One JSON line a measurement and the lot in
-``chiprun_out/prefill_attention_micro.json``. ``--tiny`` walks the same
+``chiprun_out/prefill_attention_micro.json``. ``--chosen`` times the kernel under a
+choice's mask instead, at GLM-5.2's group of 16 heads (keys and values 256
+wide, as ``generation._prefill_attn_chosen`` calls it): a random causal mask
+of about 2,048 rows a query, buckets of 8,192 and 12,288, the second also
+with a prompt of 8,600; beside it the kernel without the mask, and from a
+tree that still has ``generation._attend_masked`` that tile loop in its
+place. ``--tiny`` walks the same
 code at a toy size through the Pallas interpreter and reports no rate: a
 time off the chip is no device number. A tool: no cell and no metric reads
 it. It runs from the parent's tree too (``PYTHONPATH=<tree>``, run from
@@ -85,6 +92,81 @@ def pairs_computed(tokens, prompt_len, heads_a_kv_head):
                for i in range(-(-prompt_len // bq)))
 
 
+# GLM-5.2's prefill under a choice: heads of a group, key and value width,
+# rows a query attends, (bucket, prompt) pairs
+CHOSEN = (16, 256, 256, 2048, ((8192, 8192), (12288, 8600), (12288, 12288)))
+TAKES_MASK = "mask" in inspect.signature(blocked_causal_attention).parameters
+
+
+def random_choice(seed, tokens, topk):
+    """A causal mask [tokens, tokens] (bool): every row s <= t where t <
+    ``topk``, else each with chance topk / (t + 1), and the query's own."""
+    t = jnp.arange(tokens)[:, None]
+    s = jnp.arange(tokens)[None, :]
+    u = jax.random.uniform(jax.random.key(seed), (tokens, tokens))
+    return (s == t) | ((s < t) & (u * (t + 1) < topk))
+
+
+def chosen(args, dev, say):
+    """The attention under a choice, one group of heads a call."""
+    from ray_tpu.models import generation as gen
+
+    heads, d, dv, topk, sizes = CHOSEN
+    if args.tiny:
+        heads, d, dv, topk, sizes = 2, 24, 16, 16, ((48, 48), (80, 50))
+    flop = 2 * (d + dv) * heads
+    peak = None if args.tiny else peaks_for(dev.device_kind)["flops_bf16"]
+    forms = {}
+    if TAKES_MASK:
+        forms["kernel_masked"] = jax.jit(
+            lambda q, k, v, n, m: blocked_causal_attention(
+                q, k, v, n, mask=m))
+        forms["kernel"] = lambda q, k, v, n, m: layer_call(q, k, v, n)
+    if hasattr(gen, "_attend_masked"):
+        forms["tiles_masked"] = jax.jit(
+            lambda q, k, v, n, m: gen._attend_masked(q[0], k[0], v[0], m))
+    for tokens, length in sizes:
+        x = inputs(args.seed, tokens, heads, heads, d, dv)
+        mask = random_choice(args.seed, tokens, topk)
+        for form, fn in forms.items():
+            m = mask if form == "tiles_masked" else mask.astype(jnp.int8)
+            best = timed(fn, *x, jnp.int32(length), m,
+                         calls=1 if args.tiny else args.calls)
+            row = {"geometry": "glm52", "form": form, "tokens": tokens,
+                   "prompt_len": length, "device": dev.device_kind}
+            if not args.tiny:
+                whole = attention.block_of(tokens, 1024)
+                pairs = (whole * whole * (tokens // whole)
+                         * (tokens // whole + 1) // 2
+                         if form == "tiles_masked" else
+                         pairs_computed(tokens, length, 1))
+                row.update(ms_a_call=1e3 * best,
+                           tflops_computed=flop * pairs / best / 1e12,
+                           peak_share_computed=100 * flop * pairs / best / peak)
+            say(row)
+    n, length = (48, 40) if args.tiny else (2048, 1500)
+    x = inputs(args.seed + 1, n, heads, heads, d, dv)
+    mask = random_choice(args.seed + 1, n, topk // 4)
+    q, k, v = (a.astype(F32) for a in x)
+    with jax.default_matmul_precision("highest"):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(
+            jnp.where(mask, scores, -jnp.inf), -1), v)
+    for form, fn in forms.items():
+        if form == "kernel":
+            continue
+        m = mask if form == "tiles_masked" else mask.astype(jnp.int8)
+        got = fn(*x, jnp.int32(length), m).astype(F32).reshape(want.shape)
+        upto = n if form == "tiles_masked" else length
+        row = {"geometry": "glm52", "form": form, "check_tokens": n,
+               "prompt_len": length,
+               "err": float(jnp.abs(got - want)[:, :upto].max()
+                            / jnp.abs(want).max())}
+        if form == "kernel_masked":
+            row["past_length_all_zero"] = not bool(got[:, length:].any())
+        say(row)
+
+
 def timed(fn, *args, calls):
     jax.block_until_ready(fn(*args))
     best = float("inf")
@@ -103,6 +185,7 @@ def main():
     p.add_argument("--seed", type=int, default=1)
     p.add_argument("--check", action="store_true")
     p.add_argument("--dense", action="store_true")
+    p.add_argument("--chosen", action="store_true")
     p.add_argument("--tiny", action="store_true")
     args = p.parse_args()
     dev = jax.devices()[0]
@@ -115,7 +198,9 @@ def main():
         rows.append(row)
         print(json.dumps(row), flush=True)
 
-    for name in args.geometry:
+    if args.chosen:
+        chosen(args, dev, say)
+    for name in () if args.chosen else args.geometry:
         heads, kv_heads, d, dv, fill = GEOMETRIES[name]
         if args.tiny:
             heads, kv_heads, d, dv = heads // 8, max(kv_heads // 8, 1), 24, 16
