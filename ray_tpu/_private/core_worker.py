@@ -520,6 +520,9 @@ class CoreWorker:
         self.node_id = node_id
         self.job_id = job_id
         self.session_dir = session_dir
+        # set by worker_main once a worker process is registered: when it
+        # started and what its boot took; a driver has none
+        self.boot_record: Optional[Dict[str, float]] = None
         self.io = rpc.EventLoopThread.get()
         self.store = SharedMemoryStore.attach(store_path)
         self.memory_store = MemoryStore()

@@ -15,6 +15,7 @@ import time
 
 
 def main():
+    began_unix, began = time.time(), time.perf_counter()
     from ray_tpu._private import chaos
     from ray_tpu._private.fate_share import fate_share_with_parent
 
@@ -35,14 +36,16 @@ def main():
         format="[worker %(asctime)s] %(levelname)s %(message)s",
         stream=sys.stderr,
     )
+    chips_wait_s = 0.0
     if os.environ.get("JAX_PLATFORMS") == "tpu":  # node.worker_env's pin
         from ray_tpu._private.node import wait_chips_free
 
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         free = wait_chips_free()
-        if not free or time.monotonic() - t0 > 1.0:
+        chips_wait_s = time.perf_counter() - t0
+        if not free or chips_wait_s > 1.0:
             logging.warning("waited %.1f s for the chips' device files "
-                            "(free: %s)", time.monotonic() - t0, free)
+                            "(free: %s)", chips_wait_s, free)
 
     from ray_tpu._private.core_worker import MODE_WORKER, CoreWorker
     from ray_tpu._private import worker as worker_mod
@@ -60,6 +63,13 @@ def main():
     worker_mod.global_worker.core_worker = cw
     worker_mod.global_worker.mode = MODE_WORKER
     worker_mod.global_worker.connected = True
+    # registered, and ready for the first task the loop below will take:
+    # what this process's start cost whoever waited for it
+    # (``RuntimeContext.get_worker_boot``)
+    cw.boot_record = {
+        "process_start_unix": began_unix, "chips_wait_s": chips_wait_s,
+        "boot_s": time.perf_counter() - began,
+    }
     try:
         cw.execution_loop()
     except KeyboardInterrupt:

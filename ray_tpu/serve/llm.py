@@ -43,6 +43,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util import jit_stats
 
 _END = object()
 _RID = itertools.count(1)  # process-unique request ids, shared by spans
@@ -61,7 +62,7 @@ _COUNTERS = (
     "weights_relaid_bytes",
     "slot_state_bytes", "slot_row_bytes",
     "blocks_chained", "block_interval_steps", "block_interval_clean_steps",
-    "decode_gap_tokens", "firsts_ahead",
+    "decode_gap_tokens", "firsts_ahead", "admission_programs_built",
 )
 _PHASES = (
     "admit_s", "admit_stage_s", "admit_launch_s", "admit_first_s",
@@ -71,6 +72,16 @@ _PHASES = (
 # Seconds between events rather than inside a phase: the lanes' cadence
 # (block to block) and the requests' token gaps (first token to last).
 _INTERVALS = ("block_interval_s", "block_interval_clean_s", "decode_gap_s")
+# What the engine spent before the first request: the stretches of its
+# constructor, in seconds, the instant it ended (``time.time()``, to
+# compare with other processes of the host), the buckets' first
+# admissions, and the jit's part of all those (``util/jit_stats``). What
+# came before the engine is added by whoever built it (``record_setup``).
+_SETUP = (
+    "setup_prepare_s", "setup_layout_s", "setup_cache_s",
+    "setup_warm_blocks_s", "setup_engine_s", "engine_ready_unix",
+    "admission_build_s", "engine_jit_trace_lower_s", "engine_jit_backend_s",
+)
 
 
 class _Histogram:
@@ -136,6 +147,9 @@ class LLMEngine:
                  prefill_buckets: tuple = (64, 128, 256, 512, 1024),
                  eos_id: Optional[int] = None, block_steps: int = 8,
                  burst_block_steps: int = 2):
+        # where the current stretch began: the constructor's (_setup),
+        # then an admission's (_stretch)
+        began = self._mark = time.perf_counter()
         import jax
         import jax.numpy as jnp
 
@@ -160,7 +174,16 @@ class LLMEngine:
         # token, never per decoded token, and the cadence counters reuse
         # the block's one read after its ``device_get``.
         self._span = jax.profiler.TraceAnnotation
-        params, config = prepare_for_inference(params, config)
+        # Set-up has no span (a trace covers seconds of a window, never
+        # set-up): its stretches are seconds in ``stats()``, one clock
+        # read at each one's end (``_setup``).
+        jit_stats.install()
+        # what this thread's jit had spent when the current stretch began
+        self._jit_mark = jit_stats.mine()
+        self._t: Dict[str, float] = dict.fromkeys(
+            _PHASES + _INTERVALS + _SETUP, 0.0)
+        with self._setup("prepare"):
+            params, config = prepare_for_inference(params, config)
         self.config = config
         self.max_slots = max_slots
         self.max_len = max_len
@@ -185,21 +208,23 @@ class LLMEngine:
         # short block: its copies would come round most often), before the
         # cache is allocated, so that set-up's peak (the weights + one
         # leaf) stays under serving's.
-        self.params, relaid, relaid_bytes = lay_out_for_decode(
-            params, config, max_slots, max_len, self.burst_block_steps)
+        with self._setup("layout"):
+            self.params, relaid, relaid_bytes = lay_out_for_decode(
+                params, config, max_slots, max_len, self.burst_block_steps)
         # Its own state is committed to the weights' device like them: an
         # output of a program with a committed argument is committed, a
         # fresh jnp.zeros is not, and jit compiles once for each. Committed
         # from the start, the programs warmed below are the ones that
         # serve, whatever the arrays' history.
         self._home = jax.tree.leaves(self.params)[0].sharding
-        self.cache = jax.device_put(
-            init_kv_cache(config, max_slots, max_len), self._home)
-        self.tok = self._lanes(jnp.int32)  # next token per slot
-        self.pos = self._lanes(jnp.int32)  # its absolute position
-        self.temps = self._lanes(jnp.float32)
-        self.seeds = self._lanes(jnp.int32)
-        self.counts = self._lanes(jnp.int32)  # sample counter
+        with self._setup("cache"):
+            self.cache = jax.device_put(
+                init_kv_cache(config, max_slots, max_len), self._home)
+            self.tok = self._lanes(jnp.int32)  # next token per slot
+            self.pos = self._lanes(jnp.int32)  # its absolute position
+            self.temps = self._lanes(jnp.float32)
+            self.seeds = self._lanes(jnp.int32)
+            self.counts = self._lanes(jnp.int32)  # sample counter
         # host-side slot table
         self.slot_req: List[Optional[_Request]] = [None] * max_slots
         # What ``self.pos`` holds on the device, kept in step on the host
@@ -234,7 +259,10 @@ class LLMEngine:
         self._n["slot_state_bytes"] = foot["state_bytes"]
         self._n["slot_row_bytes"] = foot["row_bytes"]
         self._state_layers = foot["state_layers"]
-        self._t: Dict[str, float] = dict.fromkeys(_PHASES + _INTERVALS, 0.0)
+        # None until a bucket's first admission; then the seconds its
+        # launch took where it built the bucket's program (traced, lowered,
+        # compiled or fetched), 0.0 where the process had the program
+        self._built: Dict[int, Optional[float]] = dict.fromkeys(self.buckets)
         self._block_seq = 0  # blocks dispatched since the engine started
         # perf_counter() when the last block's device_get returned; None
         # while no block has been retired since the engine last idled
@@ -242,7 +270,6 @@ class LLMEngine:
         # fetches to come that an admission has put off the device's own
         # cadence (see _retire_block)
         self._unsettled = 0
-        self._mark = 0.0  # where the admission's current stretch began
         self._blocks_by_steps: Dict[int, int] = dict.fromkeys(
             (self.burst_block_steps, self.block_steps), 0)
         self._hist: Dict[str, _Histogram] = {
@@ -255,10 +282,41 @@ class LLMEngine:
         # (see decode_block), so warm decode writes garbage to row 0 of
         # empty slots only; the state reset below and prefill's strict
         # masking make that invisible.
-        self._warm_blocks()
+        with self._setup("warm_blocks"):
+            self._warm_blocks()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
         self._thread.start()
+        self._t["setup_engine_s"] = time.perf_counter() - began
+        self._t["engine_ready_unix"] = time.time()
+
+    @contextlib.contextmanager
+    def _setup(self, name: str):
+        """One stretch of the constructor, from where the last one ended
+        (``self._mark`` and ``self._jit_mark``; the first begins at the
+        constructor's entry) to one clock read at its end: its seconds in
+        ``setup_<name>_s``, and what the jit took of them in
+        ``engine_jit_*``."""
+        yield
+        now = time.perf_counter()
+        self._t["setup_" + name + "_s"] += now - self._mark
+        self._mark = now
+        self._jit_mark = self._jit_since(self._jit_mark)
+
+    def _jit_since(self, before):
+        """Adds what this thread's jit has spent since ``before`` (a
+        ``jit_stats.mine()``) to ``engine_jit_*``; returns the reading
+        it took."""
+        now = jit_stats.mine()
+        self._t["engine_jit_trace_lower_s"] += now[0] - before[0]
+        self._t["engine_jit_backend_s"] += now[1] - before[1]
+        return now
+
+    def record_setup(self, **spent: float) -> None:
+        """For whoever built the engine (``LLMServer``): what it spent on
+        the way here, to be read with the engine's own record through
+        ``stats()``. Called before anybody reads ``stats()``."""
+        self._t.update(spent)
 
     def _lanes(self, dtype):
         """Zeros, one a slot, committed where the weights are."""
@@ -469,6 +527,64 @@ class LLMEngine:
           with a real token, each up to the diagonal) and
           ``prefill_attn_blocks_bucket`` (what the whole bucket's would
           have been).
+        - What was spent before the first request, each stretch written
+          where it happens, in seconds (no span: no trace covers set-up).
+          By this constructor, each from the previous one's end:
+          ``setup_prepare_s`` (``prepare_for_inference``),
+          ``setup_layout_s`` (``lay_out_for_decode``: the compile that
+          decides, and the moves, which wait for the device),
+          ``setup_cache_s`` (the cache and the lanes),
+          ``setup_warm_blocks_s`` (the two decode blocks built and
+          dispatched, not waited for); ``setup_engine_s``, the whole
+          constructor (the four sum to at most it), and
+          ``engine_ready_unix`` (``time.time()`` at its end: the window's
+          ``t0`` less it is what the deployment and its caller spent
+          after the engine stood). By ``LLMServer.__init__``, through
+          ``record_setup`` (absent for an engine built directly):
+          ``server_init_begin_unix`` (``time.time()`` at its entry: less
+          the worker's start and boot below, it is how long the ready
+          worker waited for the deployment's constructor to get here),
+          ``setup_backend_s`` (importing JAX where nobody had, and
+          ``jax.devices()``: opening the chip) and ``setup_weights_s``
+          (``model_factory()``: building its program and dispatching it).
+          All of these are the host's clock up to the DISPATCH: the
+          device may still be making the weights, or running the warm
+          blocks, when a stretch ends, and whoever waits for the device
+          first waits that out (``setup_layout_s`` for the weights;
+          nobody in this file for the warm blocks).
+        - A bucket's FIRST admission in this engine builds its program
+          unless the process has it already (a second engine of the same
+          shapes): ``admission_programs_built`` the buckets whose first
+          launch went through the jit (this thread's ``jit_stats.mine()``
+          moved across it), ``admission_build_s`` the ``admit_launch_s``
+          seconds of those admissions (the program traced and lowered,
+          compiled or fetched from the compilation cache, and dispatched)
+          and ``admission_build_by_bucket`` ``{"<bucket>": seconds}`` (the
+          buckets built so far). Taken from the launch stretch's own
+          clock reads: an admission pays one dict lookup for it.
+        - The jit's own count (``ray_tpu/util/jit_stats.py``, summed from
+          ``jax.monitoring``'s events by listeners that run only when
+          something is built). Of the whole PROCESS since the first
+          engine or server in it, whoever built the program (a
+          deployment's own programs and the benchmark's reference
+          forward are in it): ``jit_trace_lower_s`` (tracing and
+          lowering, paid before the compilation cache is asked, on every
+          start), ``jit_backend_s`` (the backend's compile, or the fetch
+          from the cache in its place) and ``jit_programs`` (how many: a
+          jump between two snapshots of a warmed engine names the
+          stretch that compiled); ``jit_cache_hits``, ``jit_cache_misses``
+          and ``jit_cache_retrieval_s`` say whether the persistent cache
+          served this start (0 hits: the directory was not there, or the
+          keys moved) and whether what a warm start still pays the
+          backend is reading executables back or compiling what the
+          cache never keeps. Of those, what the engine's own threads
+          spent inside this constructor's stretches and the buckets'
+          first launches: ``engine_jit_trace_lower_s`` and
+          ``engine_jit_backend_s``.
+        - Inside a worker process, copied by ``LLMServer`` from
+          ``RuntimeContext.get_worker_boot()`` (absent elsewhere):
+          ``worker_process_start_unix``, ``worker_chips_wait_s``,
+          ``worker_boot_s``.
         """
         with self._lock:
             out = {
@@ -478,8 +594,11 @@ class LLMEngine:
             }
         out.update(self._n)
         out.update(self._t)
+        out.update(jit_stats.snapshot())
         out["blocks_by_steps"] = {
             str(k): n for k, n in self._blocks_by_steps.items()}
+        out["admission_build_by_bucket"] = {
+            str(b): spent for b, spent in self._built.items() if spent}
         out["hist_bounds_ms"] = list(_HIST_BOUNDS_MS)
         for name, h in self._hist.items():
             out[name] = h.snapshot()
@@ -539,11 +658,22 @@ class LLMEngine:
                         padded[0, :n] = req.prompt
                         lanes = (self.tok, self.pos, self.temps,
                                  self.seeds, self.counts)
+                    first_launch = self._built[bucket] is None
+                    if first_launch:  # the launch below may build
+                        launched, jitted = (self._t["admit_launch_s"],
+                                            jit_stats.mine())
                     with self._stretch("launch"):
                         first, self.cache, lanes, stats = prefill_into_slot(
                             self.params, padded, np.int32(n),
                             np.int32(free), self.cache, self.config, lanes,
                             np.float32(req.temperature), np.int32(req.seed))
+                    if first_launch:
+                        self._built[bucket] = 0.0
+                        if self._jit_since(jitted) != jitted:  # built here
+                            self._built[bucket] = spent = (
+                                self._t["admit_launch_s"] - launched)
+                            self._n["admission_programs_built"] += 1
+                            self._t["admission_build_s"] += spent
                     with self._stretch("first"):
                         # lands on the host when THIS prefill ends,
                         # whatever is queued behind it
@@ -824,11 +954,32 @@ class LLMServer:
     def __init__(self, model_factory: Callable, *, max_slots: int = 8,
                  max_len: int = 1024, eos_id: Optional[int] = None,
                  prefill_buckets: tuple = (64, 128, 256, 512, 1024)):
+        began_unix, began = time.time(), time.perf_counter()
+        import jax
+
+        import ray_tpu
+
+        jit_stats.install()  # before the factory's programs
+        # the device is opened here, so that it is not charged to the
+        # weights
+        jax.devices()
+        opened = time.perf_counter()
         params, config = model_factory()
+        made = time.perf_counter()
         self.engine = LLMEngine(
             params, config, max_slots=max_slots, max_len=max_len,
             eos_id=eos_id, prefill_buckets=prefill_buckets,
         )
+        # what came before the engine, in the engine's record: stats() is
+        # the one path it is read by. The keys of this process's boot are
+        # there only inside a worker; nobody reads stats() before this
+        # constructor returns, so the new keys meet no reader's copy.
+        boot = (ray_tpu.get_runtime_context().get_worker_boot()
+                if ray_tpu.is_initialized() else None) or {}
+        self.engine.record_setup(
+            server_init_begin_unix=began_unix,
+            setup_backend_s=opened - began, setup_weights_s=made - opened,
+            **{"worker_" + k: v for k, v in boot.items()})
 
     def generate_stream(self, prompt_ids, max_new_tokens: int = 64,
                         temperature: float = 0.0, seed: int = 0):
