@@ -42,7 +42,10 @@ attends fewer rows still: it scores the prefix's index keys, picks
 ``_attend_latent_chosen``: a masked walk up to the longest lane, see
 there); its prefill attends under the mask of each query's chosen rows
 through the prefill kernel, a group of heads a call (``_prefill_choice``,
-``ops/attention.blocked_causal_attention`` with a ``mask``).
+``ops/attention.blocked_causal_attention`` with a ``mask``). Every other
+full layer's prefill attends the prompt alone and nothing else of the slot
+(``ops/attention.prefill_attention``, which takes one product or that
+kernel from the heads and the bucket).
 
 A decoder-hybrid-decoder's upper layers keep NOTHING: a "cross" layer
 attends the rows the one "attention" layer below keeps (the token's own
@@ -94,7 +97,7 @@ from ray_tpu.ops.attention import (
     NEG_INF,
     block_of,
     blocked_causal_attention,
-    causal_attention,
+    prefill_attention,
     prefill_block_pairs,
     repeat_kv,
     window_attention,
@@ -470,6 +473,17 @@ def attn_rows_read(config: TransformerConfig, rows, steps: int,
                for r in lanes for k in range(steps))
 
 
+def admission_rows(config: TransformerConfig, bucket: int,
+                   s_max: int) -> Tuple[int, int]:
+    """(rows of a slot an admission at ``bucket`` holds and writes, rows
+    the slot has), of the leaves that give a slot its length: the engine's
+    count, plain integers (``_admission_slot``: the bucket's rows where
+    they lie one a token, else all the slot has)."""
+    row = _row(_length_kind(config), config)
+    slot = row.slot_rows(config, s_max)
+    return (min(bucket, slot) if row.row_a_token else slot), slot
+
+
 def _attend_prefix_plus_self(q, ck, cv, k_new, v_new, pos, *, layer,
                              schedule):
     """q [B,1,H,D] against the UNWRITTEN cache prefix (k_pos < pos,
@@ -640,12 +654,6 @@ DSA_QUERY_BLOCK = 128
 # over 24,576 tokens are 0.2 GB each, where all 64 at once would not fit
 # beside the weights).
 PREFILL_HEAD_GROUP = 16
-# A latent prefill whose float32 scores [heads, S, S] would be larger than
-# this attends block by block inside one kernel
-# (``blocked_causal_attention``) and never makes them: 32 heads at 8,192
-# tokens would be 8.6 GB, at 3,072 1.2 GB; 20 heads at 2,048 are 0.34 GB
-# and go as one product.
-PREFILL_SCORE_BYTES = 1 << 30
 
 
 def _index_scores(q, w, k):
@@ -971,15 +979,15 @@ def _decode_attn(cache, li, pos, b_idx, c: TransformerConfig, schedule):
     return cached_attn
 
 
-def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid,
-                  prompt_len):
+def _prefill_attn(single, li, c: TransformerConfig, prompt_len):
     """One prefill layer's ``attn_fn`` for ``c.mixer`` (the counterpart of
-    ``_decode_attn``). ``single`` is one slot's cache, ``positions`` the
-    padded prompt's [S], ``kv_valid`` [1, S_max] the slot's rows the
-    prompt fills, ``prompt_len`` its real tokens. Latent attention takes
-    the plain form: per-head keys and values are expanded from the
-    prompt's latents and attended causally over the prompt; what the slot
-    keeps is the latent rows.
+    ``_decode_attn``). ``single`` is one slot's rows as the admission
+    holds them (the bucket's alone: ``prefill_into_slot``), ``prompt_len``
+    the prompt's real tokens. Every form attends the prompt alone
+    (``prefill_attention``): it starts at row 0 of the slot, so the slot's
+    other rows do not count. Latent attention takes the plain form:
+    per-head keys and values are expanded from the prompt's latents; what
+    the slot keeps is the latent rows.
     Returns (output, single with this layer's rows written)."""
     if c.mixer == "mla":
         @_latent
@@ -987,13 +995,11 @@ def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid,
             new = {**single,
                    "ckv": _put_layer(single["ckv"], c_kv[None], li),
                    "kr": _put_layer(single["kr"], k_r[None, :, :, 0], li)}
-            # the plain form over the prompt alone (q and k are both
-            # nope + rope wide: the scale is causal_attention's own)
+            # q and k are both nope + rope wide: the scale is the
+            # attention's own
             k, v = mla_expand(c_kv, k_r, wp, c)
             q = jnp.concatenate([q_nope, q_rope], -1)
-            if 4 * q.shape[2] * q.shape[1] ** 2 > PREFILL_SCORE_BYTES:
-                return blocked_causal_attention(q, k, v, prompt_len), new
-            return causal_attention(q, k, v), new
+            return prefill_attention(q, k, v, prompt_len), new
 
         return cached_attn
 
@@ -1002,48 +1008,11 @@ def _prefill_attn(single, li, c: TransformerConfig, positions, kv_valid,
         # a row as the cache keeps it: (Hkv, D), or the heads flat
         k_rows, v_rows = (x.reshape(x.shape[:2] + leaf.shape[3:])
                           for leaf, x in ((ck_all, k), (cv_all, v)))
-        ck2 = _put_layer(ck_all, k_rows[None], li)
-        cv2 = _put_layer(cv_all, v_rows[None], li)
-        new = {**single, "k": ck2, "v": cv2}
-        if c.window:
-            # as below, and without the prompt's [S, S] scores (float32
-            # scores of 64 heads at 16,384 are 68 GB): one kernel that
-            # keeps a block's scores in fast memory and skips the padding
-            return blocked_causal_attention(q, k, v, prompt_len), new
-        if c.layer_types:
-            # the prompt alone, as the latent form above: a real token
-            # attends nothing past itself, so no padding and none of
-            # the slot's other S_max - S rows (the form below scores
-            # all S_max rows of the slot: 1 GB of scores a layer at 32
-            # heads x 2,048 x 4,096)
-            return causal_attention(q, k, v), new
-        # the layer's rows, the heads as an axis (again, where they lie flat)
-        ck, cv = (lax.dynamic_index_in_dim(leaf, li, 0, keepdims=False
-                                           ).reshape((1, -1) + x.shape[2:])
-                  for leaf, x in ((ck2, k), (cv2, v)))
-        return _attend_prefill(q, ck, cv, positions, kv_valid), new
+        new = {**single, "k": _put_layer(ck_all, k_rows[None], li),
+               "v": _put_layer(cv_all, v_rows[None], li)}
+        return prefill_attention(q, k, v, prompt_len), new
 
     return cached_attn
-
-
-def _attend_prefill(q, ck, cv, q_pos, kv_valid):
-    """The prompt's queries q [1,S,H,D] at ``q_pos`` [S] against ALL rows
-    of the slot, ck / cv [1,S_max,Hkv,D], under the causal mask and
-    ``kv_valid`` [1,S_max]: one product, one softmax."""
-    n_rep = q.shape[2] // ck.shape[2]
-    k = repeat_kv(ck, n_rep)
-    v = repeat_kv(cv, n_rep)
-    scale = q.shape[-1] ** -0.5
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
-    k_pos = jnp.arange(k.shape[1])
-    mask = (q_pos[:, None] >= k_pos[None, :])[None] & (
-        kv_valid[:, None, :]
-    )
-    scores = jnp.where(mask[:, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def _decode_window_attn(cache, li, pos, b_idx, c: TransformerConfig,
@@ -1570,12 +1539,10 @@ class _Step(NamedTuple):
 
 
 class _Prompt(NamedTuple):
-    """What one prefill holds for every layer: the prompt's real length,
-    the padded prompt's ``positions`` [S], and ``kv_valid`` [1, S_max],
-    the slot's rows it fills."""
+    """What one prefill holds for every layer: the prompt's real length
+    and the padded prompt's ``positions`` [S]."""
     prompt_len: jax.Array
     positions: jax.Array
-    kv_valid: jax.Array
 
 
 class _Kind(NamedTuple):
@@ -1591,6 +1558,10 @@ class _Kind(NamedTuple):
     decode: Callable
     # (single, li, lp, c, prompt, choice) -> a prefill layer's ``attn_fn``
     prefill: Callable
+    # whether its row leaves hold ONE ROW A TOKEN from row 0 of the slot:
+    # an admission then holds, and writes, the bucket's rows of them alone
+    # (``_admission_slot``); of any other leaf the whole slot's
+    row_a_token: bool = True
     # (pos, c, cache) -> the step's schedule for its decode kernel
     visits: Optional[Callable] = None
     # its int32 counters (``block_stat_keys``), and (pos, c, n, cache) ->
@@ -1661,7 +1632,7 @@ _KINDS = {
         decode=lambda cache, li, lp, c, s, choice: _decode_attn(
             cache, li, s.pos, s.b_idx, c, s.visits["attn"]),
         prefill=lambda single, li, lp, c, p, choice: _prefill_attn(
-            single, li, c, p.positions, p.kv_valid, p.prompt_len),
+            single, li, c, p.prompt_len),
         visits=lambda pos, c, cache: _visits(
             pos, jax.tree.leaves(cache_rows(cache))),
         hands_on=lambda c, queries, rows, prompt=False: None,
@@ -1677,7 +1648,7 @@ _KINDS = {
         counters=("eva_window_rows_read", "eva_summary_rows_read",
                   "eva_windows_closed"),
         counts=_eva_stats, closes=_eva_close, chunk=_dense_chunk,
-        read_len=eva_read_len, slot_rows=eva_rows),
+        read_len=eva_read_len, slot_rows=eva_rows, row_a_token=False),
     "gmu": _Kind(
         layers=lambda c: c.n_of("gmu"), keeps=_nothing_kept,
         decode=lambda cache, li, lp, c, s, handed: recalling(
@@ -1765,6 +1736,29 @@ def _kinds_of(c: TransformerConfig):
     found = ((kind, _row(kind, c)) for kind in _KINDS)
     return [(kind, row, row.layers(c)) for kind, row in found
             if kind == _length_kind(c) or row.layers(c)]
+
+
+def _admission_slot(cache, c: TransformerConfig, bucket: int):
+    """One slot of ``cache`` as an admission at ``bucket`` holds it, all
+    zeros: of a leaf that keeps one row a token from row 0 (the row leaves
+    of a kind with ``_Kind.row_a_token``) the bucket's rows alone, [layers,
+    1, min(bucket, S_max), ...], which is all a prompt fills of it and all
+    the admission writes back; the rest of the slot stays as it lies (a
+    decode step reads a slot below its position and overwrites a cell
+    before reaching it, as with the rows of a bucket's padding). Every
+    other leaf whole, [layers, 1, ...]: states, convolution tails and a
+    window's ring are the prompt's to overwrite, and so are the rows of a
+    kind that does not keep one a token ("eva": where a row lies depends
+    on the windows closed before it)."""
+    short = {name for _kind, row, n in _kinds_of(c) if row.row_a_token
+             for name in row.keeps(c, n, 1, 1)[0]}
+
+    def held(name, a):
+        return jnp.zeros_like(a[:, :1, :bucket] if name in short
+                              else a[:, :1])
+
+    return {name: jax.tree.map(partial(held, name), leaf)
+            for name, leaf in cache.items()}
 
 
 def _prompt_parts(stack, lc: TransformerConfig, first: int):
@@ -1906,8 +1900,12 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     """Run ONE padded prompt [1, Sb] and write its rows into ``slot`` of
     the shared batch cache (static shapes: Sb is a bucket size; compile
     count = number of buckets). Positions past prompt_len write junk rows
-    that are never attended: the slot's kv_valid mask stops at its
-    position, and decode overwrites those cells before reaching them.
+    that are never attended: the prompt's own attention is causal, a decode
+    step reads a slot below its position, and decode overwrites those
+    cells before reaching them. For the same reason the admission works on
+    the bucket's rows of the slot and on no other (``_admission_slot``):
+    rows [Sb, S_max) of a leaf that keeps a row a token are neither zeroed
+    nor written, and hold what an earlier request left there.
 
     Latent attention takes the plain form here: per-head keys and values
     are expanded from the prompt's latents and attended causally over the
@@ -1942,17 +1940,15 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     attention under a choice computes), summed over the layers; they leave
     the device as the token does."""
     c = config
-    single = jax.tree.map(lambda a: jnp.zeros_like(a[:, :1]), cache)
-    s_max = jax.tree.leaves(cache_rows(cache))[0].shape[2]
     S = prompt.shape[1]
+    single = _admission_slot(cache, c, S)
     x = embed_tokens(params, prompt, c)
     positions = jnp.arange(S)
-    kv_valid = (jnp.arange(s_max) < prompt_len)[None]  # [1, S_max]
     # a prompt's padding picks no expert (only a routed layer asks)
     routed = c.moe_experts and c.moe_impl == "dropless"
     real = (positions < prompt_len)[None] if routed else None
 
-    prompt_holds = _Prompt(prompt_len, positions, kv_valid)
+    prompt_holds = _Prompt(prompt_len, positions)
     choice = _row("attn", c).hands_on(c, S, S, prompt=True)
     # what an admission reports: of its routed layers, summed as they run,
     # and of its kinds, from the prompt's length (none: an empty dict)
@@ -2004,6 +2000,7 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
         logits = last @ head.astype(c.dtype)
         if c.logit_scale != 1.0:
             logits = logits * c.logit_scale
+    # every leaf from its start: the bucket's rows, or the whole slot
     cache = jax.tree.map(
         lambda big, one: lax.dynamic_update_slice(
             big, one, (0, slot) + (0,) * (big.ndim - 2)),

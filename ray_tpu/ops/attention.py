@@ -4,8 +4,10 @@ online-softmax primitives, and the two prefill forms of the served path.
 ``causal_attention`` is the all-jnp path: XLA fuses the softmax chain and
 tiles the two matmuls onto the MXU. Used when the sequence axis is
 unsharded; `ring_attention` (sp>1) builds on the blockwise log-sum-exp
-accumulation primitives defined here. ``blocked_causal_attention`` (a full
-layer's prefill over a whole prompt, without its [S, S] scores) is one
+accumulation primitives defined here. ``prefill_attention`` is a full
+layer's attention over a whole prompt at an admission: ``causal_attention``
+while the float32 scores fit the chip's fast memory, from there
+``blocked_causal_attention`` (the same without its [S, S] scores), one
 Pallas TPU kernel, forward only, with values narrower than keys, the
 prompt's length a prefetched scalar and, for a block that chooses the rows
 a query attends, the choice's mask an operand; the training kernel with a
@@ -316,6 +318,41 @@ def blocked_causal_attention(
         name="prefill_attention",
     )(length, *operands)
     return out.reshape(B, S, H, Dv)
+
+
+# What one product's float32 scores [H, S, S] may take: two thirds of the
+# chip's fast memory (a v5e has 128 MiB). Under it the compiler keeps them
+# there between the product, the softmax and the second product, beside the
+# ~40 MiB of weights and activations a layer holds there anyway, and they
+# never cross HBM; the kernel, at the same time a call, then only adds the
+# copies that lay its operands out. Measured inside each family's admission
+# program on a v5e, product -> kernel, ms a program, readings repeat to
+# 0.003 ms (``tools/admission_profile.py``; PERF.md, section 6: PR 58's
+# table, timed again by PR 59 and repeated to 0.2 ms): 16 heads x 1,024
+# (64 MiB) 75.70 -> 80.61, 32 x 768 (72 MiB) 17.88 -> 17.85, 20 x 1,024
+# (80 MiB) 21.72 -> 22.17; 40 x 768 (90 MiB) 26.92 -> 26.43, 32 x 1,024
+# (128 MiB) 49.32 -> 48.49 and 21.25 -> 20.99, 20 x 2,048 (320 MiB) 36.32
+# -> 32.37.
+PREFILL_SCORE_BYTES = 2 * (128 * 2 ** 20) // 3
+
+
+def prefill_by_kernel(heads: int, s: int) -> bool:
+    """Whether ``prefill_attention`` takes the kernel for ``heads`` query
+    heads over ``s`` tokens: where their float32 scores at once would pass
+    ``PREFILL_SCORE_BYTES``."""
+    return 4 * heads * s * s > PREFILL_SCORE_BYTES
+
+
+def prefill_attention(q, k, v, length):
+    """A full layer's attention over one padded prompt alone, q [1,S,H,D]
+    against its own k / v [1,S,Hkv,D], ``length`` real tokens: ONE product
+    (``causal_attention``: a real token attends nothing past itself, so
+    the padding needs no mask of its own) while the scores stay in fast
+    memory, ``blocked_causal_attention`` above. The form follows from the
+    shapes alone (``prefill_by_kernel``)."""
+    if prefill_by_kernel(q.shape[2], q.shape[1]):
+        return blocked_causal_attention(q, k, v, length)
+    return causal_attention(q, k, v)
 
 
 def window_attention(

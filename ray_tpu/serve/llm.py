@@ -56,7 +56,8 @@ _HIST_BOUNDS_MS = tuple(round(10 ** (i / 10), 3) for i in range(41))
 _COUNTERS = (
     "requests_submitted", "requests_admitted", "requests_first_emitted",
     "requests_finished", "requests_cancelled", "requests_failed",
-    "prefill_tokens", "prefill_padded_tokens", "tokens_emitted",
+    "prefill_tokens", "prefill_padded_tokens", "admission_rows_written",
+    "admission_rows_slot", "tokens_emitted",
     "slot_steps", "capacity_steps", "attn_rows_read", "attn_rows_capacity",
     "state_slots_updated", "state_slots_skipped", "weights_relaid",
     "weights_relaid_bytes",
@@ -405,7 +406,12 @@ class LLMEngine:
           freed mid-decode), ``_failed`` (ended by the loop's exit:
           device error or shutdown). ``prefill_tokens`` (unpadded) and
           ``prefill_padded_tokens`` (the bucket's length) per admission;
-          ``tokens_emitted``.
+          ``admission_rows_written`` (rows of the slot the admission's
+          program held, filled and wrote back: the bucket's, of a model
+          that keeps a row a token) and ``admission_rows_slot`` (the rows
+          the slot has), summed over admissions from the bucket and
+          ``max_len`` (``generation.admission_rows``): their ratio is the
+          share of a slot an admission touches; ``tokens_emitted``.
         - Histograms of ms (``counts`` per bucket of ``hist_bounds_ms``,
           one more than edges: ``counts[i]`` holds ``bounds[i-1] <= x <
           bounds[i]``; exact ``sum`` and ``count``): ``queue_wait_ms``
@@ -627,7 +633,10 @@ class LLMEngine:
         copy to the host is started and ``_retire_firsts`` reads it, so an
         admission burst chains prefills on the device back-to-back, and
         the runtime's limit on programs in flight stays out of reach."""
-        from ray_tpu.models.generation import prefill_into_slot
+        from ray_tpu.models.generation import (
+            admission_rows,
+            prefill_into_slot,
+        )
 
         with self._span("raytpu.engine.admit", pending=len(self.pending)):
             while True:
@@ -649,6 +658,10 @@ class LLMEngine:
                 self._n["requests_admitted"] += 1
                 self._n["prefill_tokens"] += n
                 self._n["prefill_padded_tokens"] += bucket
+                written, rows = admission_rows(
+                    self.config, bucket, self.max_len)
+                self._n["admission_rows_written"] += written
+                self._n["admission_rows_slot"] += rows
                 self._hist["queue_wait_ms"].add(
                     (req.t_admit - req.t_submit) * 1e3)
                 with self._span("raytpu.engine.prefill", rid=req.rid,

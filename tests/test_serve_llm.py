@@ -332,6 +332,10 @@ def test_engine_stats_lifecycle_and_block_counters():
     assert s["tokens_emitted"] == 6 * n
     assert s["prefill_tokens"] == sum(lengths)
     assert s["prefill_padded_tokens"] == 16 * 3 + 32 * 2
+    # ISSUE 59: what an admission touches of its slot is its bucket
+    assert s0["admission_rows_written"] == s0["admission_rows_slot"] == 0
+    assert s["admission_rows_written"] == 16 * 3 + 32 * 2
+    assert s["admission_rows_slot"] == n * 64
     hists = {k: s[k] for k in ("queue_wait_ms", "admit_to_first_ms",
                                "submit_to_first_ms")}
     bounds = s["hist_bounds_ms"]
@@ -583,6 +587,245 @@ def test_decode_block_parks_lanes_at_pos_zero():
     assert pos_g == [12, 0, 9] and pos_s == [12, 51, 9]
     for other in (toks_g, toks_s):
         np.testing.assert_array_equal(other[[0, 2]], toks[[0, 2]])
+
+
+# (bucket, prompt): a token, half a bucket to its edge and one past it, a
+# bucket less one and whole
+_PROMPTS = [(64, 1), (64, 63), (64, 64), (256, 1), (256, 128), (256, 129),
+            (256, 255), (256, 256)]
+
+
+@pytest.mark.parametrize("bucket,n", _PROMPTS)
+@pytest.mark.parametrize("form", ["one_product", "kernel"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_an_ungrouped_prefill_attends_the_prompt_alone(
+        dtype, form, bucket, n, monkeypatch):
+    """A model of full layers with heads of their own (GPT-J's shape)
+    prefills over the prompt alone, in either form of
+    ``ops/attention.prefill_attention`` (one product while the scores are
+    under ``PREFILL_SCORE_BYTES``, the kernel from there). The last real
+    token's logits are the full forward's, the slot's K / V rows below
+    ``prompt_len`` are the rows that forward's layers make, and a reused
+    slot that holds another request's rows changes neither (what
+    ``kv_valid`` guarded when the bucket's queries were scored against
+    every row of the slot); the admission leaves those rows past its
+    bucket as they lay."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models import transformer as tf
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.attention import causal_attention
+
+    if form == "kernel":
+        monkeypatch.setattr(attention, "PREFILL_SCORE_BYTES", 0)
+    cfg = tf.TransformerConfig.tiny(
+        dtype=getattr(jnp, dtype), max_seq_len=512)
+    params, icfg = gen.prepare_for_inference(
+        tf.init_params(cfg, jax.random.key(0)), cfg)
+    assert icfg.n_heads == icfg.kv_heads  # no grouping
+    assert attention.prefill_by_kernel(icfg.n_heads, bucket) == (
+        form == "kernel")
+    toks = np.random.default_rng(bucket + n).integers(
+        1, cfg.vocab_size, n, dtype=np.int32)
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :n] = toks
+
+    # the reference: the uncached forward's logits, and its layers' rows
+    want = tf.forward(params, toks[None], icfg)[0, n - 1].astype(jnp.float32)
+    x, rows = tf.embed_tokens(params, toks[None], icfg), []
+    for li in range(icfg.n_layers):
+        def attend(q, k, v):
+            rows.append((k[0], v[0]))
+            return causal_attention(q, k, v)
+
+        x = tf.apply_block(
+            x, jax.tree.map(lambda a: a[li], params["layers"]), icfg,
+            jnp.arange(n), attend)[0]
+
+    slots, s_max, slot = 3, 320, 1
+    fresh = gen.init_kv_cache(icfg, slots, s_max)
+    # the slot as a longer request left it: rows of another prompt
+    used = jax.tree.map(
+        lambda a: a.at[:, slot].set(jax.random.normal(
+            jax.random.key(3), a[:, slot].shape, a.dtype) * 30), fresh)
+    left = jax.tree.map(np.asarray, used)  # (the cache is donated)
+    tol = 2e-5 if dtype == "float32" else 5e-2
+    out = []
+    for cache in (fresh, used):
+        logits, cache = gen.prefill_into_slot(
+            params, padded, np.int32(n), np.int32(slot), cache, icfg)
+        logits = logits.astype(jnp.float32)
+        out.append((logits, cache))
+        assert float(jnp.abs(logits - want).max()) < tol * max(
+            float(jnp.abs(want).max()), 1.0)
+        for li, (k, v) in enumerate(rows):
+            for leaf, row in ((cache["k"], k), (cache["v"], v)):
+                row = row.astype(jnp.float32)
+                got = leaf[li, slot, :n].reshape(row.shape).astype(
+                    jnp.float32)
+                assert float(jnp.abs(got - row).max()) < tol * max(
+                    1.0, float(jnp.abs(row).max()))
+    (logits, cache), (logits_used, cache_used) = out
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(logits_used))
+    for name in ("k", "v"):
+        a, b = (np.asarray(c[name][:, slot].astype(jnp.float32))
+                for c in (cache, cache_used))
+        np.testing.assert_array_equal(a[:, :bucket], b[:, :bucket])
+        assert not a[:, bucket:].any()  # fresh: as it was made
+        np.testing.assert_array_equal(  # used: what the other request left
+            np.asarray(cache_used[name])[:, slot, bucket:],
+            left[name][:, slot, bucket:])
+        for other in (0, 2):  # and no other slot is touched
+            np.testing.assert_array_equal(
+                np.asarray(cache_used[name])[:, other], left[name][:, other])
+
+
+# one tiny preset a kind of cache whose rows lie one a token from row 0:
+# K / V rows, latent rows, a latent block with an indexer's rows and index
+# keys, and the full layers of a recurrent, a window, a "kda" and a
+# decoder-hybrid-decoder model (whose "cross" layers read them too)
+_ROW_A_TOKEN = ["tiny", "tiny_mla_moe", "tiny_dsa_moe", "tiny_ssm_hybrid",
+                "tiny_swa_moe", "tiny_kda_moe", "tiny_sambay"]
+
+
+def _admit_and_decode(gen, params, icfg, cache, toks, bucket, slot, steps):
+    """One request through ``slot`` as the engine serves it (the other
+    lanes parked): (its tokens, the cache right after its admission as
+    numpy, the cache after its last block)."""
+    import jax
+    import jax.numpy as jnp
+
+    slots = jax.tree.leaves(gen.cache_rows(cache))[0].shape[1]
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :len(toks)] = toks
+    lanes = tuple(jnp.zeros(slots, t) for t in (
+        jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.int32))
+    first, cache, lanes, _stats = gen.prefill_into_slot(
+        params, padded, np.int32(len(toks)), np.int32(slot), cache, icfg,
+        lanes, np.float32(0), np.int32(1))
+    admitted = jax.tree.map(np.asarray, cache)
+    out, cache = gen.decode_block(params, cache, *lanes, icfg, steps)[:2]
+    return [int(first)] + np.asarray(out)[slot].tolist(), admitted, cache
+
+
+@pytest.mark.parametrize("kind", _ROW_A_TOKEN)
+def test_a_reused_slots_stale_rows_are_left_and_never_read(kind):
+    """ISSUE 59: an admission writes its bucket's rows of the slot and
+    leaves the others as they lie. A long request goes through slot 0 and
+    finishes; a short one is admitted into the same slot and decoded past
+    its bucket's edge: its tokens are those it gets from a fresh cache
+    (nothing reads a row at or past its position), and right after its
+    admission rows [bucket, S_max) of every row leaf of the slot are,
+    bit for bit, what the first request left there."""
+    import jax
+
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = getattr(TransformerConfig, kind)()
+    params, icfg = gen.prepare_for_inference(
+        init_params(cfg, jax.random.key(0)), cfg)
+    slots, s_max = 2, 96
+    rng = np.random.default_rng(7)
+    long_one, short = (rng.integers(1, cfg.vocab_size, n, dtype=np.int32)
+                       for n in (60, 9))
+    _toks, _admitted, used = _admit_and_decode(
+        gen, params, icfg, gen.init_kv_cache(icfg, slots, s_max), long_one,
+        64, 0, 8)
+    left = jax.tree.map(np.asarray, used)
+    # the short request: bucket 16, then 12 steps, rows 9 .. 20
+    want, _fresh, _cache = _admit_and_decode(
+        gen, params, icfg, gen.init_kv_cache(icfg, slots, s_max), short,
+        16, 0, 12)
+    got, admitted, _cache = _admit_and_decode(
+        gen, params, icfg, used, short, 16, 0, 12)
+    assert got == want
+    for name, leaf in gen.cache_rows(admitted).items():
+        assert left[name][:, 0, 16:68].any(), name  # the long one's rows
+        np.testing.assert_array_equal(
+            leaf[:, 0, 16:], left[name][:, 0, 16:], err_msg=name)
+        assert (leaf[:, 0, :16] != left[name][:, 0, :16]).any(), name
+
+
+@pytest.mark.parametrize(
+    "kind", ["tiny_ssm_hybrid", "tiny_swa_moe", "tiny_kda_moe",
+             "tiny_sambay", "tiny_eva"])
+def test_an_admission_overwrites_states_rings_and_folded_rows_whole(kind):
+    """What is no row a token is the prompt's to overwrite whole, as
+    before ISSUE 59: a slot full of another request's leftovers comes out
+    of an admission with the states, convolution tails and rings (every
+    leaf under ``"state"``) and an "eva" layer's rows (summaries, then the
+    open window's: where a row lies depends on the windows before it)
+    bit for bit as a fresh cache's slot does: a reused slot starts from an
+    empty state."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = getattr(TransformerConfig, kind)()
+    params, icfg = gen.prepare_for_inference(
+        init_params(cfg, jax.random.key(0)), cfg)
+    slots, s_max, bucket, n, slot = 2, 96, 16, 9, 1
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :n] = np.random.default_rng(3).integers(
+        1, cfg.vocab_size, n, dtype=np.int32)
+    fresh = gen.init_kv_cache(icfg, slots, s_max)
+    used = jax.tree.map(
+        lambda a: a.at[:, slot].set(jax.random.normal(
+            jax.random.key(5), a[:, slot].shape, jnp.float32
+        ).astype(a.dtype) * 3), fresh)
+    (want, a), (got, b) = (gen.prefill_into_slot(
+        params, padded, np.int32(n), np.int32(slot), cache, icfg)
+        for cache in (fresh, used))
+    np.testing.assert_array_equal(np.asarray(want.astype(jnp.float32)),
+                                  np.asarray(got.astype(jnp.float32)))
+    whole = dict(gen.cache_state(a))
+    if kind == "tiny_eva":
+        whole.update(gen.cache_rows(a))
+    assert whole
+    for name, leaf in whole.items():
+        other = {**gen.cache_state(b), **gen.cache_rows(b)}[name]
+        np.testing.assert_array_equal(
+            np.asarray(leaf[:, slot].astype(jnp.float32)),
+            np.asarray(other[:, slot].astype(jnp.float32)), err_msg=name)
+
+
+@pytest.mark.parametrize("kind,bucket,s_max,want", [
+    ("tiny", 16, 64, (16, 64)), ("tiny", 64, 64, (64, 64)),
+    ("tiny_mla_moe", 32, 96, (32, 96)), ("tiny_dsa_moe", 32, 96, (32, 96)),
+    ("tiny_ssm_hybrid", 16, 64, (16, 64)),
+    # rows, but not one a token: the whole slot, as many rows as it has
+    ("tiny_eva", 16, 64, None)])
+def test_the_engine_counts_the_rows_an_admission_touches(
+        kind, bucket, s_max, want):
+    """``generation.admission_rows``: what ``LLMEngine._admit`` adds to
+    ``admission_rows_written`` and ``admission_rows_slot``, plain integers
+    from the bucket and ``max_len``; they are the extents of the program's
+    own copy of the slot (``_admission_slot``)."""
+    import jax
+
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cfg = getattr(TransformerConfig, kind)()
+    got = gen.admission_rows(cfg, bucket, s_max)
+    assert all(type(v) is int for v in got)
+    cache = jax.eval_shape(lambda: gen.init_kv_cache(cfg, 3, s_max))
+    single = jax.eval_shape(lambda: gen._admission_slot(
+        gen.init_kv_cache(cfg, 3, s_max), cfg, bucket))
+    length = "ek" if kind == "tiny_eva" else next(iter(gen.cache_rows(cache)))
+    assert got == (single[length].shape[2], cache[length].shape[2])
+    assert got == (want or (gen.eva_rows(cfg, s_max),) * 2)
+    for name, leaf in single.items():  # every leaf: one slot's, so long
+        for one, big in zip(jax.tree.leaves(leaf),
+                            jax.tree.leaves(cache[name])):
+            rows = big.shape[2:3] if name == "state" else got[:1]
+            assert one.shape == big.shape[:1] + (1,) + rows + big.shape[3:]
 
 
 @pytest.mark.parametrize("temperature,seed", [(0.0, 0), (0.9, 4321)])

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import generation as gen
+from ray_tpu.ops import attention
 from ray_tpu.models import (
     reference,
     reference_dsa,
@@ -56,7 +57,7 @@ class Through(NamedTuple):
     s_max: int
     bucket: int
     steps: int
-    scores_at_once: bool = True  # False: PREFILL_SCORE_BYTES 0, tile by tile
+    scores_at_once: bool = True  # False: PREFILL_SCORE_BYTES 0, the kernel
 
 
 class Cell(NamedTuple):
@@ -223,7 +224,10 @@ MODELS = {
         # three slots at different depths, one of them crossing a chunk
         # edge of the decode walk (256 rows), a parked lane among them
         through={"lanes_across_a_chunk": Through(
-            {0: 250, 2: 31, 3: 120}, 4, 320, 256, 9)},
+            {0: 250, 2: 31, 3: 120}, 4, 320, 256, 9),
+                 # as the 2,048 bucket is served (ISSUE 59)
+                 "scores_too_large_for_one_product": Through(
+                     {1: 120}, 2, 320, 256, 9, scores_at_once=False)},
         # the decode attention walks a second chunk
         generated=(270, 320),
         refused=Through({0: 200}, 1, 256, 256, 0),
@@ -299,10 +303,15 @@ MODELS = {
         shapes={"layers/ln1/scale": (2, 64),
                 "ssm_layers/ln1/scale": (4, 64)},
         counters=(), state=("ssm", 2e-4),
-        through={name: Through({1: n}, 3, 64, bucket, 11)
-                 for name, n, bucket in (
-                     ("above_a_chunk", 13, 16), ("chunks", 21, 32),
-                     ("a_bucket", 32, 32))},
+        through={
+            **{name: Through({1: n}, 3, 64, bucket, 11)
+               for name, n, bucket in (
+                   ("above_a_chunk", 13, 16), ("chunks", 21, 32),
+                   ("a_bucket", 32, 32))},
+            # the grouped full layers through the kernel, as the buckets
+            # from 1,024 on are served (ISSUE 59: one rule for every family)
+            "scores_too_large_for_one_product": Through(
+                {1: 21}, 3, 64, 32, 11, scores_at_once=False)},
         generated=(11, 32), refused=_REFUSED,
         ablations=(
             {"state_bf16": True}, {"state_at_bucket_end": (21, 32)},
@@ -382,8 +391,8 @@ MODELS = {
                    ("under_the_taps", 2, 8), ("below_a_chunk", 5, 8),
                    ("a_chunk", 8, 8), ("above", 13, 16), ("chunks", 21, 32),
                    ("a_bucket", 32, 32), ("many", 43, 64))},
-            # the full layers attend tile by tile, as a prompt whose scores
-            # pass ``PREFILL_SCORE_BYTES`` does
+            # the full layers attend through the kernel, as a prompt whose
+            # scores pass ``PREFILL_SCORE_BYTES`` does
             "scores_too_large_for_one_product": Through(
                 {1: 37}, 2, 96, 48, 12, scores_at_once=False)},
         generated=(11, 32), refused=_REFUSED,
@@ -624,7 +633,7 @@ def test_prefill_and_decode_through_a_slot_match_the_reference(
     m, params = served(name)
     run = m.through[case]
     if not run.scores_at_once:
-        monkeypatch.setattr(gen, "PREFILL_SCORE_BYTES", 0)
+        monkeypatch.setattr(attention, "PREFILL_SCORE_BYTES", 0)
     toks, got, cache = served_through(m, params, run)
     for slot, n in run.lanes.items():
         want, want_states = ref_logits(m, params, toks[slot])

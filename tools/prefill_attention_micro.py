@@ -8,6 +8,7 @@ passes it.
     chiprun -- python3 tools/prefill_attention_micro.py --check   # the table
     chiprun -- python3 tools/prefill_attention_micro.py --dense   # one product
     chiprun -- python3 tools/prefill_attention_micro.py --chosen  # under a mask
+    chiprun -- python3 tools/prefill_attention_micro.py --slot    # GPT-J's buckets
     python3 tools/prefill_attention_micro.py --tiny                # here
 
 A layer-call's time is the host's clock over ``--calls`` calls dispatched
@@ -17,7 +18,7 @@ three. Beside it two rates: the operations of the TILES THE FORM COMPUTES
 the CAUSAL WORK the prompt needs (rows s <= t < length alone), each over
 that time, against the chip's peak. ``--dense`` times ``causal_attention``
 (every score of the bucket in one product: the form a prefill under
-``generation.PREFILL_SCORE_BYTES`` takes) at 1,024 and 2,048 tokens.
+``ops/attention.PREFILL_SCORE_BYTES`` takes) at 1,024 and 2,048 tokens.
 ``--check`` compares the form at 1,024 tokens with ``causal_attention`` in
 float32 (largest error over the largest value; rows past the length must be
 zeros where the form takes one). One JSON line a measurement and the lot in
@@ -27,7 +28,18 @@ wide, as ``generation._prefill_attn_chosen`` calls it): a random causal mask
 of about 2,048 rows a query, buckets of 8,192 and 12,288, the second also
 with a prompt of 8,600; beside it the kernel without the mask, and from a
 tree that still has ``generation._attend_masked`` that tile loop in its
-place. ``--tiny`` walks the same
+place. ``--slot`` is the table behind ``ops/attention.PREFILL_SCORE_BYTES``
+for heads of their own at short buckets: GPT-J's geometry (16 heads, keys
+and values 256 wide) at its buckets of 64-1,024 tokens with the prompts its
+cells send, the kernel with each ``--blocks`` pair (queries x rows, put in
+``prefill_blocks``' place for the call; ``rule``: what the tree itself
+gives), beside ``slot_dense``, this tool's own copy of the form an
+admission took until PR 59: the bucket's queries against all 1,024 rows of
+the slot in one product (whose float32 scores, 64 MiB, the compiler keeps
+in fast memory: PERF.md, section 6, PR 58 / 59), and ``one_product``,
+``causal_attention`` over the prompt alone, the form it takes since. There a call is one of 28 in
+one program, each one's output the next one's queries: a call of 0.1 ms is
+under a dispatch of its own. ``--tiny`` walks the same
 code at a toy size through the Pallas interpreter and reports no rate: a
 time off the chip is no device number. A tool: no cell and no metric reads
 it. It runs from the parent's tree too (``PYTHONPATH=<tree>``, run from
@@ -49,6 +61,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from benchmarks.common import peaks_for
 from ray_tpu.ops import attention
@@ -167,6 +180,117 @@ def chosen(args, dev, say):
         say(row)
 
 
+# GPT-J's admission: heads (each its own KV head), key and value width, the
+# slot's rows, (bucket, prompt) pairs of the chat and the document cells
+SLOT = (16, 256, 256, 1024,
+        ((64, 40), (128, 120), (256, 120), (256, 200), (512, 400),
+         (1024, 640), (1024, 800), (1024, 960), (1024, 1024)))
+
+
+SLOT_LAYERS = 28
+
+
+def slot_dense(q, ck, cv, prompt_len):
+    """The prompt's queries q [1,S,H,D] against ALL rows of the slot, ck /
+    cv [1,S_max,H,D], under the causal mask and the rows the prompt fills:
+    one product, one softmax (what ``generation._attend_prefill`` was)."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, ck,
+                        preferred_element_type=F32) * q.shape[-1] ** -0.5
+    rows = jnp.arange(ck.shape[1])
+    seen = (jnp.arange(q.shape[1])[:, None] >= rows) & (rows < prompt_len)
+    probs = jax.nn.softmax(jnp.where(seen, scores, attention.NEG_INF), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), cv)
+
+
+def with_blocks(pair):
+    """The kernel with (queries, rows) blocks of ``pair`` (None: the
+    tree's own rule), a jit of its own: the blocks are read at the trace."""
+    rule = attention.prefill_blocks
+
+    def blocks(s, heads_a_kv_head, block):
+        return rule(s, heads_a_kv_head, block) if pair is None else (
+            min(pair[0], s), min(pair[1], s))
+
+    def call(q, k, v, n):
+        attention.prefill_blocks = blocks
+        try:
+            return blocked_causal_attention.__wrapped__(q, k, v, n)
+        finally:
+            attention.prefill_blocks = rule
+
+    return call, blocks
+
+
+def layers_of(fn, layers):
+    """``layers`` calls of ``fn`` in ONE program, each one's output the
+    next one's queries (keys as wide as values): a call of 0.1 ms is under
+    a dispatch of its own, so the host's clock reads a stack of them."""
+    @jax.jit
+    def stack(q, k, v, n):
+        return lax.fori_loop(0, layers, lambda _i, x: fn(x, k, v, n), q)
+
+    return stack
+
+
+def slot(args, dev, say):
+    """A full layer's attention of an admission into a slot, by block."""
+    heads, d, dv, s_max, sizes = SLOT
+    if args.tiny:
+        heads, d, dv, s_max, sizes = 2, 16, 16, 96, ((48, 30), (96, 70))
+    flop = 2 * (d + dv) * heads
+    peak = None if args.tiny else peaks_for(dev.device_kind)["flops_bf16"]
+    pairs = [None] + [tuple(int(n) for n in b.split("x"))
+                      for b in args.blocks]
+    if args.tiny:
+        pairs = [None, (16, 16), (16, 48)]
+    calls = 1 if args.tiny else args.calls
+    layers = 1 if args.tiny else SLOT_LAYERS
+    forms = {}
+    for pair in pairs:
+        fn, blocks = with_blocks(pair)
+        forms["rule" if pair is None else "%dx%d" % pair] = (
+            layers_of(fn, layers), blocks)
+    dense = layers_of(slot_dense, layers)
+    # the prompt alone as one product: what an admission takes since PR 59
+    alone = layers_of(lambda q, k, v, n: causal_attention(q, k, v), layers)
+    want = None
+    for tokens, length in sizes:
+        q, k, v = inputs(args.seed, tokens, heads, heads, d, dv)
+        rest = inputs(args.seed + 7, s_max - tokens, heads, heads, d, dv)[1:]
+        ck, cv = (jnp.concatenate([a, b], 1) for a, b in zip((k, v), rest))
+        n = jnp.int32(length)
+        row = {"geometry": "gptj", "tokens": tokens, "prompt_len": length,
+               "device": dev.device_kind}
+        best = timed(dense, q, ck, cv, n, calls=calls) / layers
+        if args.tiny:
+            want = slot_dense(q, ck, cv, n).astype(F32)
+        else:
+            say({**row, "form": "slot_dense", "ms_a_call": 1e3 * best,
+                 "tflops_computed": flop * tokens * s_max / best / 1e12})
+            best = timed(alone, q, k, v, n, calls=calls) / layers
+            say({**row, "form": "one_product", "ms_a_call": 1e3 * best,
+                 "tflops_computed": flop * tokens * tokens / best / 1e12})
+        for name, (fn, blocks) in forms.items():
+            bq, bk = blocks(tokens, 1, 1024)
+            if bk % bq:
+                continue
+            best = timed(fn, q, k, v, n, calls=calls) / layers
+            done = flop * sum(bq * (i * bq // bk + 1) * bk
+                              for i in range(-(-length // bq)))
+            out = {**row, "form": "kernel", "blocks": name,
+                   "bq": bq, "bk": bk}
+            if args.tiny:  # the dense form's answer, and zeros past it
+                got = fn(q, k, v, n).astype(F32)
+                out["err"] = float(jnp.abs(got - want)[:, :length].max()
+                                   / jnp.abs(want).max())
+                out["past_length_all_zero"] = not bool(got[:, length:].any())
+            else:
+                out.update(ms_a_call=1e3 * best,
+                           tflops_computed=done / best / 1e12,
+                           peak_share_computed=100 * done / best / peak)
+            say(out)
+
+
 def timed(fn, *args, calls):
     jax.block_until_ready(fn(*args))
     best = float("inf")
@@ -177,7 +301,7 @@ def timed(fn, *args, calls):
     return best
 
 
-def main():
+def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--geometry", nargs="*", default=list(GEOMETRIES))
     p.add_argument("--tokens", type=int, nargs="*", default=list(TOKENS))
@@ -186,8 +310,11 @@ def main():
     p.add_argument("--check", action="store_true")
     p.add_argument("--dense", action="store_true")
     p.add_argument("--chosen", action="store_true")
+    p.add_argument("--slot", action="store_true")
+    p.add_argument("--blocks", nargs="*",
+                   default=["256x256", "512x512", "1024x1024"])
     p.add_argument("--tiny", action="store_true")
-    args = p.parse_args()
+    args = p.parse_args(argv)
     dev = jax.devices()[0]
     if not args.tiny and dev.platform != "tpu":
         raise SystemExit("a rate needs the chip; --tiny walks the code here")
@@ -200,7 +327,9 @@ def main():
 
     if args.chosen:
         chosen(args, dev, say)
-    for name in () if args.chosen else args.geometry:
+    if args.slot:
+        slot(args, dev, say)
+    for name in () if args.chosen or args.slot else args.geometry:
         heads, kv_heads, d, dv, fill = GEOMETRIES[name]
         if args.tiny:
             heads, kv_heads, d, dv = heads // 8, max(kv_heads // 8, 1), 24, 16
