@@ -55,8 +55,9 @@ layers), so a prefill runs them on the prompt's LAST REAL token alone.
 
 What differs by the KIND of a layer is in one table, ``_KINDS``, a row a
 kind (``transformer.layer_kind``: "attn", "ssm", "swa", "kda", "mamba",
-"gmu", "cross"; a latent block with an indexer has its own "attn" row, a
-model whose layers hand things on too): what a slot keeps for the
+"gmu", "cross", "eva", and "moe", the routed FFN of a block whose layers
+are one branch each; a latent block with an indexer has its own "attn"
+row, a model whose layers hand things on too): what a slot keeps for the
 kind's layers (``init_kv_cache`` merges the rows), how a token decodes
 through one and how a prompt fills it (``_decode_forward_multi`` and
 ``prefill_into_slot`` look the layer's form up, once each), the kind's
@@ -352,7 +353,8 @@ def _eva_closing(c: TransformerConfig, pos):
 
 def _nothing_kept(c: TransformerConfig, n: int, batch: int, max_len: int):
     """A "gmu" or a "cross" layer keeps nothing: it reads what a layer
-    below keeps or hands on."""
+    below keeps or hands on. Nor does a "single" block's routed FFN
+    ("moe"): it has no mixer at all."""
     return {}, {}
 
 
@@ -375,9 +377,11 @@ def _kv_rows(c: TransformerConfig) -> Tuple[Tuple[int, ...], ...]:
     """The shapes of one token's key and value in a layer of the MHA/GQA
     cache: (Hkv, D) and (Hkv, Dv), or both flat, (Hkv x D,) and
     (Hkv x Dv,), where D alone is no whole number of 128-lanes (or the
-    heads pair: ``transformer._diff_pairs`` reads two as one) and the
-    heads together are."""
-    if (c.d_head % 128 or c.diff_attn) and (
+    heads pair: ``transformer._diff_pairs`` reads two as one, or they are
+    fewer than the 8 sublanes a tile of [Hkv, D] has: two KV heads of 128
+    would lie in tiles of 8 or 16, most of each padding) and the heads
+    together are."""
+    if (c.d_head % 128 or c.diff_attn or c.kv_heads < 8) and (
             c.kv_heads * c.d_head) % 128 == 0:
         return (c.kv_heads * c.d_head,), (c.kv_heads * c.v_dim,)
     return (c.kv_heads, c.d_head), (c.kv_heads, c.v_dim)
@@ -1664,6 +1668,13 @@ _KINDS = {
             single, c, p),
         counters=("cross_rows_read",), counts=_cross_stats,
         last_token=True),
+    # a "single" block's routed FFN: no mixer, so its ``attn_fn`` takes
+    # nothing and hands the cache back as it is; the routed layer's
+    # counters are ``block_stat_keys``' own
+    "moe": _Kind(
+        layers=lambda c: c.n_of("experts"), keeps=_nothing_kept,
+        decode=lambda cache, li, lp, c, s, choice: lambda: cache,
+        prefill=lambda single, li, lp, c, p, choice: lambda: single),
 }
 # the "attn" row of a latent block with an indexer (``c.index_topk``)
 _CHOSEN = _Kind(
@@ -1761,6 +1772,23 @@ def _admission_slot(cache, c: TransformerConfig, bucket: int):
             for name, leaf in cache.items()}
 
 
+def _taps(c: TransformerConfig, x):
+    """Room for every layer's INPUT, shaped as ``x``, a kind: ``{kind:
+    [the model's layers of that kind, *x.shape]}``. A program asked for
+    ``taps`` hands them back with the last layer's output under "out", so
+    that a check can put one layer's input, as the program made it, to a
+    reference of that layer alone (a layer's output is the next one's
+    input). Not asked for, nothing of this is in the program."""
+    return {kind: jnp.zeros((n,) + x.shape, x.dtype)
+            for kind, _row, n in _kinds_of(c) if n}
+
+
+def _tap(taps, lp, li, x):
+    kind = layer_kind(lp)
+    return {**taps, kind: lax.dynamic_update_index_in_dim(
+        taps[kind], x, li, 0)}
+
+
 def _prompt_parts(stack, lc: TransformerConfig, first: int):
     """One group of ``layer_groups`` as a prefill runs it: ``(stacks,
     index of their first layer, whether on the last real token alone)``.
@@ -1784,12 +1812,12 @@ def _prompt_parts(stack, lc: TransformerConfig, first: int):
 
 
 def _decode_forward_multi(params, token, cache, pos,
-                          config: TransformerConfig):
+                          config: TransformerConfig, taps: bool = False):
     """Core of the per-slot decode step (tokens [B] at per-slot positions
     pos [B]); shared by decode_step_multi and the scanned decode_block.
     The whole cache travels as the layer scan's carry (aliased in place)
     and each layer writes its token's row. Returns (logits [B,V], cache,
-    stats)."""
+    stats), and with ``taps`` every layer's input as well (``_taps``)."""
     c = config
     x = embed_tokens(params, token, c)[:, None]  # [B,1,D]
     # a parked lane's token picks no expert (only a routed layer asks)
@@ -1804,26 +1832,29 @@ def _decode_forward_multi(params, token, cache, pos,
         for kind, row, _n in kinds})
     choice = _row("attn", c).hands_on(
         c, B, jax.tree.leaves(cache_rows(cache))[0].shape[2])
-    carry = (x, cache, _zero_stats(c), choice)
+    carry = (x, cache, _zero_stats(c), choice) + (
+        (_taps(c, x),) if taps else ())
     for stack, lc, first in layer_groups(params, c):
         def layer(carry, lp, li, lc=lc):
-            x, cache, total, choice = carry
+            x, cache, total, choice, *tapped = carry
             attn = _row(layer_kind(lp), lc).decode(
                 cache, li, lp, lc, step, choice)
             y, _aux, cache, stats = apply_block(
                 x, lp, lc, pos[:, None], attn, token_mask=live)
             if isinstance(cache, tuple):  # a layer that hands something on
                 cache, choice = cache
-            return y, cache, _add_stats(total, stats), choice
+            return (y, cache, _add_stats(total, stats), choice) + tuple(
+                _tap(t, lp, li, x) for t in tapped)
 
         carry = scan_stack(layer, carry, stack, lc, first)
-    x, cache, stats, _choice = carry
+    x, cache, stats, _choice, *tapped = carry
     for _kind, row, n in kinds:
         if row.counts:
             stats = _add_stats(stats, row.counts(pos, c, n, cache))
         if row.closes:
             cache = row.closes(params, cache, pos, c)
-    return lm_logits(params, x, c)[:, 0, :], cache, stats
+    return (lm_logits(params, x, c)[:, 0, :], cache, stats) + tuple(
+        {**t, "out": x} for t in tapped)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -1858,9 +1889,10 @@ def _sample_vec(logits, temps, seeds, counts):
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
-@partial(jax.jit, static_argnames=("config", "steps"), donate_argnums=(1,))
+@partial(jax.jit, static_argnames=("config", "steps", "taps"),
+         donate_argnums=(1,))
 def decode_block(params, cache, token, pos, temps, seeds, counts,
-                 config: TransformerConfig, steps: int):
+                 config: TransformerConfig, steps: int, taps: bool = False):
     """``steps`` decode iterations as ONE compiled program with on-device
     per-slot sampling — the serving engine's unit of work. One host
     transfer ([B, steps] int32 tokens) per block instead of per token:
@@ -1877,26 +1909,29 @@ def decode_block(params, cache, token, pos, temps, seeds, counts,
     Returns (tokens [B, steps], cache, token', pos', counts', stats):
     ``stats`` holds the block's int32 counters named by
     ``block_stat_keys`` (an empty dict for most models), summed over its
-    steps and layers; they leave the device with the tokens."""
+    steps and layers; they leave the device with the tokens. With
+    ``taps`` (another program: a check's, never the engine's) a seventh
+    value: every step's layer inputs, ``{kind: [steps, layers of the
+    kind, B, 1, D], "out": [steps, B, 1, D]}`` (``_taps``)."""
     def step(carry, _):
         tok, cache, pos, counts, total = carry
-        logits, cache, stats = _decode_forward_multi(
-            params, tok, cache, pos, config)
+        logits, cache, stats, *tapped = _decode_forward_multi(
+            params, tok, cache, pos, config, taps)
         nxt = _sample_vec(logits, temps, seeds, counts)
         return (nxt, cache, pos + (pos > 0), counts + 1,
-                _add_stats(total, stats)), nxt
+                _add_stats(total, stats)), (nxt, *tapped)
 
-    (token, cache, pos, counts, stats), toks = lax.scan(
+    (token, cache, pos, counts, stats), (toks, *tapped) = lax.scan(
         step, (token, cache, pos, counts, _zero_stats(config)), None,
         length=steps,
     )
-    return toks.T, cache, token, pos, counts, stats
+    return (toks.T, cache, token, pos, counts, stats, *tapped)
 
 
-@partial(jax.jit, static_argnames=("config",), donate_argnums=(4, 6))
+@partial(jax.jit, static_argnames=("config", "taps"), donate_argnums=(4, 6))
 def prefill_into_slot(params, prompt, prompt_len, slot, cache,
                       config: TransformerConfig, lanes=None,
-                      temperature=None, seed=None):
+                      temperature=None, seed=None, taps: bool = False):
     """Run ONE padded prompt [1, Sb] and write its rows into ``slot`` of
     the shared batch cache (static shapes: Sb is a bucket size; compile
     count = number of buckets). Positions past prompt_len write junk rows
@@ -1938,7 +1973,12 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     (no output at all) but for the int32 scalars ``prefill_stat_keys``
     names and describes (dropless routed experts' pairs, the blocks an
     attention under a choice computes), summed over the layers; they leave
-    the device as the token does."""
+    the device as the token does.
+
+    With ``taps`` (another program: a check's, never the engine's) one
+    value more: every layer's input over the bucket, ``{kind: [layers of
+    the kind, 1, Sb, D], "out": [1, Sb, D]}`` (``_taps``; a model whose
+    upper layers run on the last token alone has none to give)."""
     c = config
     S = prompt.shape[1]
     single = _admission_slot(cache, c, S)
@@ -1958,11 +1998,15 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
         if row.prefill_counts:
             routed_stats = _add_stats(
                 routed_stats, row.prefill_counts(n, S, prompt_len))
-    carry = (x, single, choice, routed_stats)
+    carry = (x, single, choice, routed_stats) + (
+        (_taps(c, x),) if taps else ())
     narrowed = False
     for stack, lc, first in layer_groups(params, c):
         for part, at, last_token in _prompt_parts(stack, lc, first):
             if last_token:  # from here on: the last real token alone
+                if taps:
+                    raise ValueError("no taps where layers run on the "
+                                     "prompt's last token alone")
                 narrowed = True
                 x, single, choice, total = carry
                 carry = (lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, 1),
@@ -1974,21 +2018,23 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
                     if a is not None else None for a in (positions, real))
 
             def layer(carry, lp, li, lc=lc, positions=positions, real=real):
-                x, single, choice, total = carry
+                x, single, choice, total, *tapped = carry
                 attn = _row(layer_kind(lp), lc).prefill(
                     single, li, lp, lc, prompt_holds, choice)
                 y, _aux, single, stats = apply_block(
                     x, lp, lc, positions, attn, token_mask=real)
                 if isinstance(single, tuple):  # it hands something on
                     single, choice = single
-                return y, single, choice, _add_stats(total, {
-                    "prefill_" + k: v for k, v in stats.items()})
+                return (y, single, choice, _add_stats(total, {
+                    "prefill_" + k: v for k, v in stats.items()})) + tuple(
+                        _tap(t, lp, li, x) for t in tapped)
 
             # (a scope of its own: what the last-token rule leaves of them)
             with jax.named_scope("raytpu.upper.last_token"
                                  ) if last_token else nullcontext():
                 carry = scan_stack(layer, carry, part, lc, at)
-    x, single, _choice, routed_stats = carry
+    x, single, _choice, routed_stats, *tapped = carry
+    tapped = tuple({**t, "out": x} for t in tapped)
     x = _norm(x, params["final_ln"], c)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     # [D] — last REAL token's features (all that is left where the upper
@@ -2006,12 +2052,12 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
             big, one, (0, slot) + (0,) * (big.ndim - 2)),
         cache, single)
     if lanes is None:
-        return logits, cache
+        return (logits, cache, *tapped)
     first = _sample_vec(logits[None], temperature[None], seed[None],
                         jnp.zeros(1, jnp.int32))[0]
     lanes = tuple(lane.at[slot].set(v) for lane, v in zip(
         lanes, (first, prompt_len, temperature, seed, 1)))
-    return first, cache, lanes, routed_stats
+    return (first, cache, lanes, routed_stats, *tapped)
 
 
 def generate(

@@ -36,8 +36,10 @@ fine-tune, BASELINE.md; reference trains it via DeepSpeed ZeRO-3 on GPUs —
   the last "mamba" layer's output), or layers that attend their own
   window of rows exactly and every earlier window through pooled chunk
   summaries ("eva", ``ops/eva.py``: such a model has no layer that keeps
-  every row), one stack of parameters a kind, run in the order the list
-  gives;
+  every row), or layers that are ONE branch each (``block`` "single":
+  a state-space mixer, an attention or a routed FFN alone, "experts",
+  whose experts may live in a latent: ``moe_latent``), one stack of
+  parameters a kind, run in the order the list gives;
 - every parameter carries logical axis names (`param_logical_axes`) mapped
   to mesh axes by `ray_tpu.parallel.AxisRules` — TP/SP/DP/FSDP are sharding
   annotations, not code changes;
@@ -106,7 +108,7 @@ class TransformerConfig:
     # "parallel": y = x + attn(ln1 x) + ffn(ln1 x), one norm;
     # "sequential": h = x + attn(ln1 x), y = h + ffn(ln2 h).
     residual: str = "parallel"
-    activation: str = "gelu"  # gelu | silu
+    activation: str = "gelu"  # gelu | silu | relu2
     gated_ffn: bool = False  # wo(act(wg x) * wi x) instead of wo(act(wi x))
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
@@ -265,31 +267,68 @@ class TransformerConfig:
     n_pred_heads: int = 1
     residual_f32: bool = False
     norm_unit_offset: bool = False
+    # A layer that is ONE branch (Nemotron-H; arXiv:2504.03624): with block
+    # "single" every layer is x + Branch(ln1 x), one norm, and
+    # layer_types says which branch: "attention" (the "mha" mixer), "ssm"
+    # (the recurrence above) or "experts", a dropless routed FFN alone
+    # (params["expert_layers"]: ln1 and "moe"), which keeps nothing of a
+    # slot. "pair" is every other model's block: a mixer AND an FFN a
+    # layer. Three more sizes a routed or a state-space layer of any
+    # model may set. moe_latent (0: none): the routed experts live in a
+    # latent moe_latent wide, reached through ONE matrix latent_in [d,
+    # moe_latent] and left through ONE latent_out [moe_latent, d]: the
+    # router reads the layer's input, expert i is W2_i act(W1_i u) on u =
+    # h latent_in (gated as gated_ffn says), the routed sum goes through
+    # latent_out, and the shared expert works on the full width.
+    # moe_shared_d_ff (0: moe_d_ff): the width of a shared expert.
+    # ssm_norm_groups: the state-space mixer's gated norm takes its mean
+    # square over each of that many groups of channels (1: over all).
+    # activation "relu2" is relu(x)^2.
+    block: str = "pair"
+    moe_latent: int = 0
+    moe_shared_d_ff: int = 0
+    ssm_norm_groups: int = 1
 
     def __post_init__(self):
         if self.layer_types:
             kinds = tuple(self.layer_types)
+            single = self.block == "single"
             if len(kinds) != self.n_layers or set(kinds) - {
                     "attention", "ssm", "window", "kda", "mamba", "gmu",
-                    "cross", "eva"} or (
+                    "cross", "eva", "experts"} or (
                     self.mixer != "mha" and set(kinds) - {
                         "attention", "kda"}) or (
                     self.residual != "sequential") or (
                     "ssm" in kinds and (
-                        self.moe_experts or "window" in kinds
+                        (self.moe_experts and not single)
+                        or "window" in kinds
                         or not self.ssm_heads * self.ssm_head_dim
                         * self.ssm_state or self.ssm_heads
-                        % self.ssm_groups)):
+                        % self.ssm_groups or self.ssm_inner
+                        % self.ssm_norm_groups)):
                 raise ValueError(
                     "layer_types needs one entry a layer ('attention' | "
                     "'ssm' | 'window' | 'kda' | 'mamba' | 'gmu' | 'cross' | "
-                    "'eva'), "
+                    "'eva' | 'experts'), "
                     "mixer 'mha' (beside 'kda' layers alone: either "
                     "mixer), a sequential block; 'attention' stands beside "
                     "every kind, 'window' beside all but 'ssm' and 'kda', "
                     "'mamba', 'gmu' and 'cross' beside each other, "
                     "'attention' and 'window'; beside 'ssm' layers a dense "
-                    "FFN and the ssm_* sizes (heads a multiple of groups)")
+                    "FFN (routed layers in a 'single' block alone) and the "
+                    "ssm_* sizes (heads a multiple of groups, channels of "
+                    "ssm_norm_groups)")
+            routed = "experts" in kinds
+            if (routed and not single) or (single and (
+                    set(kinds) - {"attention", "ssm", "experts"}
+                    or self.mixer != "mha" or self.n_dense_layers
+                    or routed != bool(self.moe_experts)
+                    or (routed and self.moe_impl != "dropless"))):
+                raise ValueError(
+                    "an 'experts' layer stands in a 'single' block alone, "
+                    "beside 'attention' and 'ssm' layers: mixer 'mha', "
+                    "dropless experts (none without such a layer), no "
+                    "leading dense layer")
             if "eva" in kinds and (
                     set(kinds) != {"eva"} or self.moe_experts
                     or self.eva_chunk < 1 or self.eva_window < self.eva_chunk
@@ -343,6 +382,13 @@ class TransformerConfig:
             object.__setattr__(self, "layer_types", kinds)
         elif self.window:
             raise ValueError("window needs 'window' layers in layer_types")
+        if self.block not in ("pair", "single") or (
+                self.block == "single" and not self.layer_types) or (
+                (self.moe_latent or self.moe_shared_d_ff) and not (
+                    self.moe_experts and self.moe_impl == "dropless")):
+            raise ValueError(
+                "block is 'pair' or 'single' (with layer_types); moe_latent "
+                "and moe_shared_d_ff need dropless experts")
         if self.n_pred_heads < 1 or (self.n_pred_heads > 1
                                      and self.tie_embeddings) or (
                 self.norm_unit_offset and self.norm != "rms"):
@@ -411,7 +457,8 @@ class TransformerConfig:
         cache holds rows for."""
         return (self.n_layers - self.n_ssm_layers - self.n_window_layers
                 - self.n_kda_layers - self.n_of("mamba") - self.n_of("gmu")
-                - self.n_of("cross") - self.n_of("eva"))
+                - self.n_of("cross") - self.n_of("eva")
+                - self.n_of("experts"))
 
     @property
     def kda_inner(self) -> int:
@@ -441,6 +488,8 @@ class TransformerConfig:
 
     @property
     def n_expert_layers(self) -> int:
+        if self.block == "single":
+            return self.n_of("experts")
         return self.n_layers - self.n_dense_layers if self.moe_experts else 0
 
     def dense_variant(self) -> "TransformerConfig":
@@ -460,9 +509,11 @@ class TransformerConfig:
         if not self.moe_experts:
             ffn = dense_ffn
         elif self.moe_impl == "dropless":
-            fe = self.moe_d_ff or f
-            ffn = (d + 1) * self.moe_experts + mats * d * fe * (
-                self.experts_held + self.moe_shared_experts)
+            fe, dl = self.moe_d_ff or f, self.moe_latent or d
+            fs = (self.moe_shared_d_ff or fe) * self.moe_shared_experts
+            ffn = ((d + 1) * self.moe_experts
+                   + mats * dl * fe * self.experts_held + mats * d * fs
+                   + (2 * d * dl if self.moe_latent else 0))
         else:
             ffn = d * self.moe_experts + 2 * self.moe_experts * d * f
         if self.mixer == "mla":
@@ -513,6 +564,9 @@ class TransformerConfig:
                   + self.n_window_layers * window + self.n_kda_layers * kda
                   + upper + self.n_layers * norms + n_dense * dense_ffn
                   + (self.n_layers - n_dense) * ffn + indexer)
+        if self.block == "single":  # one branch and one norm a layer
+            layers = (self.n_attn_layers * attn + self.n_ssm_layers * ssm
+                      + self.n_expert_layers * ffn + self.n_layers * d)
         head = (0 if self.tie_embeddings
                 else d * self.vocab_size * self.n_pred_heads)
         final = d * (2 if self.norm == "layer" else 1)
@@ -644,6 +698,63 @@ class TransformerConfig:
             ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=1,
             ssm_conv=4, ssm_chunk=8, embed_scale=12.0, residual_scale=0.22,
             logit_scale=1 / 8, attn_scale=1 / 64,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def nemotron3_super(pattern: str = (
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*E"
+            "MEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+            **kw) -> "TransformerConfig":
+        """NVIDIA-Nemotron-3-Super-120B-A12B (nvidia/NVIDIA-Nemotron-3-
+        Super-120B-A12B-BF16 config.json, model_type nemotron_h) at its
+        published widths: 88 layers of ONE branch each, by ``pattern``
+        (``hybrid_override_pattern``): ``M`` a Mamba-2 mixer (128 heads of
+        64, state 128, 8 B/C groups, the gated norm over 8 groups, chunks
+        of 128), ``*`` attention (32 query and 2 KV heads of 128, no
+        positional term), ``E`` 512 routed squared-ReLU experts 2,688 wide
+        in a latent of 1,024 (22 a token, sigmoid scores, weights x 5)
+        beside a shared expert 5,376 wide on the full 4,096; an untied
+        head. A cut passes its own ``pattern`` / ``moe_experts_held`` /
+        ``vocab_size``. The multi-token-prediction module is not part of
+        the block."""
+        kinds = {"M": "ssm", "*": "attention", "E": "experts"}
+        base = dict(
+            vocab_size=131072, d_model=4096, n_layers=len(pattern),
+            n_heads=32, n_kv_heads=2, d_head=128, d_ff=2688, rotary_dim=0,
+            max_seq_len=262144, residual="sequential", activation="relu2",
+            gated_ffn=False, norm_eps=1e-5, block="single",
+            layer_types=tuple(kinds[k] for k in pattern),
+            ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+            ssm_norm_groups=8, ssm_conv=4, ssm_chunk=128, moe_experts=512,
+            moe_top_k=22, moe_impl="dropless", moe_d_ff=2688,
+            moe_latent=1024, moe_shared_experts=1, moe_shared_d_ff=5376,
+            moe_route_scale=5.0,
+        )
+        base.update(kw)
+        return TransformerConfig(**base)
+
+    @staticmethod
+    def tiny_ssm_moe(**kw) -> "TransformerConfig":
+        """The same kind of model at test size (CPU): seven layers ``M E M
+        * E M E``, 4 state-space heads of 8 over a state of 16 in 2 groups
+        (the gated norm over 2), chunks of 8; 4 query heads over 2 KV
+        heads of 64 (flat in their cache row, as the model's two of 128);
+        16 experts 24 wide in a latent of 32, 3 a token, of which the
+        stack holds 4..7; a shared expert 48 wide."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=7, n_heads=4, n_kv_heads=2,
+            d_head=64, d_ff=24, rotary_dim=0, max_seq_len=1024,
+            residual="sequential", activation="relu2", gated_ffn=False,
+            norm_eps=1e-5, block="single",
+            layer_types=("ssm", "experts", "ssm", "attention", "experts",
+                         "ssm", "experts"),
+            ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
+            ssm_norm_groups=2, ssm_conv=4, ssm_chunk=8, moe_experts=16,
+            moe_top_k=3, moe_impl="dropless", moe_d_ff=24, moe_latent=32,
+            moe_shared_experts=1, moe_shared_d_ff=48, moe_route_scale=5.0,
+            moe_experts_held=4, moe_first_expert=4,
         )
         base.update(kw)
         return TransformerConfig(**base)
@@ -918,18 +1029,21 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                 } if c.gated_ffn else {}
 
     def stack(lc: TransformerConfig, L: int, salt: int, first: int,
-              attends: bool = True, window: bool = False) -> Dict:
+              attends: bool = True, window: bool = False,
+              ffn: bool = True) -> Dict:
         """L layers of ``lc``'s block (the model's layers from ``first``
         on), stacked on a leading axis; without ``attends`` the norms and
         the FFN alone (another mixer's weights are the caller's); with
-        ``window`` the mixer is a window layer's, under "swa"."""
+        ``window`` the mixer is a window layer's, under "swa"; without
+        ``ffn`` no FFN (a "single" block's mixer layer, which has one norm
+        whichever its branch)."""
         kq, kk, kv, ko, kwi, kwo = (
             (k_q, k_k, k_v, k_o, k_wi, k_wo) if not salt else
             [jax.random.fold_in(k, salt) for k in (k_q, k_k, k_v, k_o,
                                                    k_wi, k_wo)])
         d = lc.d_model
         layers = {"ln1": norm_init((L, d), jax.random.fold_in(kq, 21))}
-        if lc.residual == "sequential":
+        if lc.residual == "sequential" and lc.block == "pair":
             layers["ln2"] = norm_init((L, d), jax.random.fold_in(kq, 22))
         if not attends:
             pass
@@ -984,8 +1098,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                     math.log(lc.window) + jax.random.normal(
                         jax.random.fold_in(kq, 3), (L, lc.n_heads))
                 ).astype(pd)
-        if lc.moe_experts and lc.moe_impl == "dropless":
+        if not ffn:
+            pass
+        elif lc.moe_experts and lc.moe_impl == "dropless":
             E, f = lc.moe_experts, lc.moe_d_ff or lc.d_ff
+            dl = lc.moe_latent or d  # the width the experts work in
             k_rt = jax.random.fold_in(kwi, 1)
             router = dense_init(k_rt, (L, d, E), d)
             E = lc.experts_held  # the router stays whole; the rest is held
@@ -998,12 +1115,19 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
                 "bias": (0.02 * jax.random.normal(
                     jax.random.fold_in(k_rt, 1),
                     (L, lc.moe_experts))).astype(pd),
-                "wi": dense_init(kwi, (L, E, d, f), d),
-                "wo": dense_init(kwo, (L, E, f, d), f),
-                **gate(kwi, (L, E, d, f), d),
+                "wi": dense_init(kwi, (L, E, dl, f), dl),
+                "wo": dense_init(kwo, (L, E, f, dl), f),
+                **gate(kwi, (L, E, dl, f), dl),
             }
+            if lc.moe_latent:
+                k_lt = jax.random.fold_in(kwi, 4)
+                layers["moe"].update(
+                    latent_in=dense_init(k_lt, (L, d, dl), d),
+                    latent_out=dense_init(jax.random.fold_in(k_lt, 1),
+                                          (L, dl, d), dl))
             if lc.moe_shared_experts:
-                fs, ks = f * lc.moe_shared_experts, jax.random.fold_in(kwi, 3)
+                fs = (lc.moe_shared_d_ff or f) * lc.moe_shared_experts
+                ks = jax.random.fold_in(kwi, 3)
                 layers["moe"]["shared"] = {
                     "wi": dense_init(ks, (L, d, fs), d),
                     "wo": dense_init(jax.random.fold_in(kwo, 3),
@@ -1113,7 +1237,7 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
         normal values would let it explode or vanish): A = -exp(a_log)
         with exp(a_log) ~ U(1, 16), dt_bias the inverse softplus of a step
         log-uniform in 0.001-0.1, D = 1, the convolution U(+-1/sqrt(taps))."""
-        layers = stack(c, L, 11, 0, attends=False)
+        layers = stack(c, L, 11, 0, attends=False, ffn=c.block == "pair")
         d, inner, width, nh = c.d_model, c.ssm_inner, c.ssm_conv_width, \
             c.ssm_heads
         ks = jax.random.split(jax.random.fold_in(k_q, 13), 7)
@@ -1182,7 +1306,11 @@ def init_params(config: TransformerConfig, rng: jax.Array) -> Dict:
     }
     n_rest = c.n_attn_layers - n_dense + dense_kda
     if n_rest or not c.layer_types:  # a model may have no such layer
-        params["layers"] = stack(c, n_rest, 0, n_dense)
+        params["layers"] = stack(c, n_rest, 0, n_dense,
+                                 ffn=c.block == "pair")
+    if c.n_of("experts"):  # a "single" block's routed layers: no mixer
+        params["expert_layers"] = stack(c, c.n_of("experts"), 61, 0,
+                                        attends=False)
     for kind, make in (("mamba", mamba_stack), ("gmu", gmu_stack),
                        ("cross", cross_stack), ("eva", eva_stack)):
         if c.n_of(kind):
@@ -1267,6 +1395,9 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
                 "wi": wi,
                 "wo": ("layers", "experts", "mlp", "embed"),
             }
+            if lc.moe_latent:  # the experts' "embed" is then the latent
+                layers["moe"].update(latent_in=("layers", "embed", None),
+                                     latent_out=("layers", None, "embed"))
             if lc.moe_impl == "dropless":
                 layers["moe"].update(bias=("layers", "experts"), **gate(wi))
                 if lc.moe_shared_experts:
@@ -1283,13 +1414,24 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
             }
         return layers
 
+    def branch(layers: Dict, name: str) -> Dict:
+        """Of a "single" block's layer: its one norm and its one branch,
+        ``name``; any other block's layers as they are."""
+        if config.block != "single":
+            return layers
+        return {"ln1": layers["ln1"], name: layers[name]}
+
     n_dense = config.n_dense_layers if config.moe_experts else 0
     axes = {
         "embed": ("vocab", "embed"),
         "final_ln": norm_axes(("embed",)),
     }
     if config.n_attn_layers or not config.layer_types:
-        axes["layers"] = stack(config, n_dense, config.n_layers - n_dense)
+        axes["layers"] = branch(
+            stack(config, n_dense, config.n_layers - n_dense), "attn")
+    if config.n_of("experts"):
+        axes["expert_layers"] = branch(
+            stack(config, 0, config.n_of("experts")), "moe")
     for kind, mixer in (
             ("mamba", {
                 "wx": ("layers", "embed", "mlp"),
@@ -1333,7 +1475,7 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
             "norm": ("layers", "mlp"),
             "wo": ("layers", "mlp", "embed"),
         }
-        axes["ssm_layers"] = ssm
+        axes["ssm_layers"] = branch(ssm, "ssm")
     if config.n_window_layers:
         swa = stack(config, 0, config.n_window_layers)
         swa["swa"] = swa.pop("attn")
@@ -1373,7 +1515,8 @@ def param_logical_axes(config: TransformerConfig) -> Dict:
 _KIND_STACKS = {"attention": "layers", "ssm": "ssm_layers",
                 "window": "window_layers", "kda": "kda_layers",
                 "mamba": "mamba_layers", "gmu": "gmu_layers",
-                "cross": "cross_layers", "eva": "eva_layers"}
+                "cross": "cross_layers", "eva": "eva_layers",
+                "experts": "expert_layers"}
 
 
 def layer_groups(params: Dict, config: TransformerConfig):
@@ -1913,7 +2056,12 @@ def _ssm_mixer(h, wp, c: TransformerConfig, positions, attn_fn):
     y, extra = recur(xbc, dt, wp)
     with jax.named_scope("raytpu.ssm.gate"):
         y = y.reshape(z.shape) * jax.nn.silu(z)
-        y = _rms_norm(y, wp["norm"], c.norm_eps)
+        if c.ssm_norm_groups > 1:  # the mean square over each group alone
+            grouped = z.shape[:-1] + (c.ssm_norm_groups, -1)
+            y = _rms_norm(y.reshape(grouped), wp["norm"].reshape(
+                grouped[-2:]), c.norm_eps).reshape(z.shape)
+        else:
+            y = _rms_norm(y, wp["norm"], c.norm_eps)
     with jax.named_scope("raytpu.ssm.project"):
         out = jnp.einsum("bsf,fd->bsd", y, wp["wo"].astype(c.dtype))
     return out, extra
@@ -2118,11 +2266,13 @@ _MIXERS = {"attn": _attn_mixer, "ssm": _ssm_mixer, "kda": _kda_mixer,
 def layer_kind(lp: Dict) -> str:
     """The kind of the layer whose weights are ``lp``: the one place that
     tells the kinds apart (``_MIXERS``, and ``generation``'s table of
-    what each keeps of a slot and how it decodes and prefills)."""
-    return next(kind for kind in _MIXERS if kind in lp)
+    what each keeps of a slot and how it decodes and prefills). A layer
+    with no mixer at all is a "single" block's routed FFN, "moe"."""
+    return next((kind for kind in _MIXERS if kind in lp), "moe")
 
 
-_ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
+_ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _dense_ffn(h, wp, c: TransformerConfig):
@@ -2135,6 +2285,17 @@ def _dense_ffn(h, wp, c: TransformerConfig):
     return jnp.einsum("bsf,fd->bsd", m, wp["wo"].astype(c.dtype))
 
 
+def _routed_ffn(h, lp, c: TransformerConfig, token_mask):
+    """A dropless routed layer's FFN as ``c`` describes it: (output, the
+    layer's counters)."""
+    from ray_tpu.ops.moe import routed_ffn
+
+    return routed_ffn(
+        h, lp["moe"], top_k=c.moe_top_k, route_scale=c.moe_route_scale,
+        act=_ACTIVATIONS[c.activation], token_mask=token_mask,
+        first_expert=c.moe_first_expert)
+
+
 def apply_block(
     x: jax.Array,  # [B, S, D]
     lp: Dict,  # ONE layer's params (no leading L dim)
@@ -2144,7 +2305,8 @@ def apply_block(
     mesh: Optional[jax.sharding.Mesh] = None,
     token_mask: Optional[jax.Array] = None,  # [B, S] bool
 ):
-    """One block as ``config`` describes it (mixer, residual form, FFN).
+    """One block as ``config`` describes it (mixer, residual form, FFN;
+    ``config.block`` "single": ONE of them, under one norm).
     ``token_mask`` marks the tokens that count (a parked decode lane, the
     padding of a prompt do not): a dropless routed layer sends the others
     to no expert. Returns (y, aux_loss, extra, moe_stats): ``extra`` is
@@ -2153,6 +2315,16 @@ def apply_block(
     c = config
     h = _norm(x, lp["ln1"], c)
     kind = layer_kind(lp)
+    if c.block == "single":  # one branch: x + Branch(ln1 x)
+        aux, stats = jnp.zeros((), jnp.float32), {}
+        if kind == "moe":
+            a, stats = _routed_ffn(h, lp, c, token_mask)
+            extra = attn_fn()  # no mixer: what the caller keeps, as it is
+        else:
+            a, extra = _MIXERS[kind](h, lp[kind], c, positions, attn_fn)
+        if c.residual_scale != 1.0:
+            a = a * c.residual_scale
+        return x + a, aux, extra, stats
     a, extra = _MIXERS[kind](h, lp[kind], c, positions, attn_fn)
     if c.residual_scale != 1.0:
         a = a * c.residual_scale
@@ -2161,13 +2333,7 @@ def apply_block(
         h = _norm(x, lp["ln2"], c)
     aux, stats = jnp.zeros((), jnp.float32), {}
     if c.moe_experts and c.moe_impl == "dropless":
-        from ray_tpu.ops.moe import routed_ffn
-
-        m, stats = routed_ffn(
-            h, lp["moe"], top_k=c.moe_top_k, route_scale=c.moe_route_scale,
-            act=_ACTIVATIONS[c.activation], token_mask=token_mask,
-            first_expert=c.moe_first_expert,
-        )
+        m, stats = _routed_ffn(h, lp, c, token_mask)
     elif c.moe_experts:
         from ray_tpu.ops.moe import moe_ffn
 
@@ -2229,7 +2395,8 @@ def forward(
         with jax.named_scope("raytpu.swa.attend"):
             return window_attention(q, k, v, sink, window=c.window)
 
-    own = {"swa": window_fn, "eva": partial(_eva_whole_sequence, c=c)}
+    own = {"swa": window_fn, "eva": partial(_eva_whole_sequence, c=c),
+           "moe": lambda: None}
 
     def handing(kind, handed):
         """``attn_fn`` of a layer of a model whose layers hand things on

@@ -194,7 +194,16 @@ def routed_ffn(
     gate, up and the activation in one kernel, down in a second) over the
     sorted rows, so an expert that no token chose is not read, and there
     is no capacity and no [tokens, E, C] tensor. ``wp["shared"]`` is an
-    FFN every token takes.
+    FFN every token takes, gated where IT holds a ``wg``.
+
+    Experts in a LATENT: where ``wp`` holds ``latent_in`` [D, l] and
+    ``latent_out`` [l, D], the router still reads ``x`` but the experts'
+    rows are ``x @ latent_in`` (their matrices are [l, f] and [f, l]) and
+    the weighted sum over a token's choices goes through ``latent_out``
+    (bf16 operands, float32 accumulation) before the shared expert's
+    part, which works on the full width, is added. One projection each
+    way whatever the experts chosen; a share's part stays a part, the
+    projection out being linear.
 
     Tokens outside ``token_mask`` (a parked lane, a prompt's padding) are
     sent to no expert: their pairs sort behind the last group, and their
@@ -257,6 +266,7 @@ def routed_ffn(
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
     gated = "wg" in wp
+    latent = "latent_in" in wp
     if n > ROUTED_TOKENS_A_PASS and n % ROUTED_TOKENS_A_PASS == 0:
         mask = (jnp.ones(n, bool) if token_mask is None
                 else token_mask.reshape(-1))
@@ -296,6 +306,11 @@ def routed_ffn(
     rows = n * top_k
     # every pair in one tile, or every pair live: one fused pass over them
     looped = not whole and rows > ROUTED_ROWS_A_TILE
+    u = x2  # the experts' rows: the tokens', or their latents
+    if latent:
+        with jax.named_scope("raytpu.moe.latent"):
+            u = x2 @ wp["latent_in"].astype(x.dtype)
+            d = u.shape[-1]
     with jax.named_scope("raytpu.moe.experts"):
         def experts(rows, schedule, *names, act=None):
             stacks = [wp[k].astype(x.dtype).reshape((-1, E) + wp[k].shape[-2:])
@@ -306,7 +321,7 @@ def routed_ffn(
         gate_up = ("wg", "wi") if gated else ("wi",)
         if not looped:
             schedule = group_schedule(sizes, rows)
-            xs = x2[order // top_k]  # [n*k, D], sorted by expert
+            xs = u[order // top_k]  # [n*k, D], sorted by expert
             ys = experts(experts(xs, schedule, *gate_up, act=act),
                          schedule, "wo")
             # rows behind the last group hold nothing defined: select, then
@@ -331,7 +346,7 @@ def routed_ffn(
                 cut = jnp.clip(edges, lo, lo + tile)  # the groups, cut here
                 schedule = group_schedule(
                     jnp.diff(cut).astype(jnp.int32), tile)
-                ys = experts(experts(x2[token], schedule, *gate_up, act=act),
+                ys = experts(experts(u[token], schedule, *gate_up, act=act),
                              schedule, "wo")
                 # a row behind the last group holds nothing defined
                 ys = jnp.where(
@@ -346,11 +361,16 @@ def routed_ffn(
             y, visits = jax.lax.fori_loop(
                 0, turns, turn, (jnp.zeros((n, d), f32), jnp.int32(0)))
             moved = (turns * tile).astype(jnp.int32)
+    if latent:
+        with jax.named_scope("raytpu.moe.latent"):
+            y = jnp.dot(y.astype(x.dtype), wp["latent_out"].astype(x.dtype),
+                        preferred_element_type=f32)
     if "shared" in wp:
         with jax.named_scope("raytpu.moe.shared"):
             sp = wp["shared"]
             m = x2 @ sp["wi"].astype(x.dtype)
-            m = act(x2 @ sp["wg"].astype(x.dtype)) * m if gated else act(m)
+            m = (act(x2 @ sp["wg"].astype(x.dtype)) * m if "wg" in sp
+                 else act(m))
             y = y + (m @ sp["wo"].astype(x.dtype)).astype(f32)
     stats = {
         "moe_assignments": edges[E].astype(jnp.int32),

@@ -78,6 +78,40 @@ def pytest_runtest_teardown(item):
     yield from _limited(item, "teardown")
 
 
+def _mappings(path: str) -> int:
+    try:
+        with open(path) as f:
+            return int(f.read()) if path.endswith("count") else sum(
+                1 for _ in f)
+    except (OSError, ValueError):  # no /proc: nothing to watch
+        return 0
+
+
+def pytest_runtest_logfinish(nodeid, location):
+    """A worker lives for a quarter of an hour and keeps every program it
+    ever compiled: each XLA:CPU executable holds ~6 memory mappings, one
+    served model's cases leave ~20,000, and a process may have
+    ``vm.max_map_count`` of them (65,530 here). Past that an ``mmap``
+    fails inside the next compile and the worker dies of a segmentation
+    fault in ``backend_compile_and_load``, in whichever test compiles next
+    (``tests/test_parallel.py::test_moe_transformer_train_step_ep`` in the
+    driver's run of PR 59's tree; four, two and two workers in three runs
+    of PR 60's, which adds a model; two at once end the whole run in
+    xdist's loadfile scheduler). So between tests, a process past half its
+    allowance drops JAX's compiled programs (``jax.clear_caches``: 2,396
+    mappings -> 594 for 300 small programs); the persistent cache gives
+    back what is needed again."""
+    limit = _mappings("/proc/sys/vm/max_map_count")
+    if "jax" in sys.modules and limit and (
+            _mappings("/proc/self/maps") > limit // 2):
+        import gc
+
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

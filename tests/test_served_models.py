@@ -33,6 +33,7 @@ from ray_tpu.models import (
     reference_kda,
     reference_sambay,
     reference_ssm,
+    reference_ssm_moe,
     reference_swa,
 )
 from ray_tpu.models.transformer import (
@@ -182,6 +183,26 @@ def _eva_rehearsed(values, note):
     assert probe["summary_decode"] is not None
 
 
+def _ssm_moe_rehearsed(values, note):
+    # the rehearsal's engine: 4 slots, 3 state layers, 3 routed layers
+    # holding 4 of 16 experts, 3 a token
+    # (the scopes' shares need a device trace; the host's has none)
+    for name in ("engine.moe_expert_read_share", "model.moe_load_imbalance",
+                 "kernel.decode_hbm_share.ssm_moe"):
+        assert values[name] is not None, name
+    assert 0 < values["engine.state_skip_share"] < 100
+    end = note["backlog"]["end"]
+    assert end["slot_state_bytes"] == 3 * (8 * 32 * 16 * 4
+                                           + 3 * (256 + 2 * 2 * 16) * 2)
+    assert end["slot_row_bytes"] == 2 * 2 * 64 * 2
+    assert (end["state_slots_updated"] + end["state_slots_skipped"]
+            == 3 * end["capacity_steps"])
+    assert end["attn_rows_read"] > 0 and end["moe_assignments"] > 0
+    assert end["moe_experts_capacity"] == 4 * 3 * end["steps"]
+    probe = note["probe"]
+    assert probe["replayed"] and probe["decode_rel"] < 0.2
+
+
 def _latent_hp(cfg):
     return {"n_heads": cfg.n_heads, "qk_nope": cfg.qk_nope_dim,
             "qk_rope": cfg.qk_rope_dim, "kv_rank": cfg.kv_lora_rank,
@@ -205,6 +226,9 @@ KDA = TransformerConfig.tiny_kda_moe(dtype=F32)
 SAMBAY = TransformerConfig.tiny_sambay(dtype=F32)
 # three layers, all "eva": windows of 32 tokens in chunks of 4, 3 heads
 EVA = TransformerConfig.tiny_eva(dtype=F32)
+# M E M * E M E, one branch a layer: 2 B/C groups and 2 norm groups, 16
+# experts in a latent of 32 (3 a token) of which the stack holds 4..7
+SSM_MOE = TransformerConfig.tiny_ssm_moe(dtype=F32)
 _SERVED = ("model.decode_step_ms", "device.idle_share.serve",
            "engine.kv_read_share")
 # a prefill of 21 tokens in a bucket of 32, then 12 decode steps (the
@@ -506,6 +530,66 @@ MODELS = {
             ("model.eva_time_share", "model.prefill_eva_share",
              "engine.summary_rows_share", "kernel.decode_hbm_share.eva")
             + _SERVED, _eva_rehearsed)),
+    "ssm_moe": Model(
+        cfg=SSM_MOE, ref=reference_ssm_moe, hp={
+            "n_heads": SSM_MOE.n_heads, "n_kv_heads": SSM_MOE.kv_heads,
+            "d_head": SSM_MOE.d_head, "eps": SSM_MOE.norm_eps,
+            "layer_types": SSM_MOE.layer_types,
+            "ssm_heads": SSM_MOE.ssm_heads,
+            "ssm_head_dim": SSM_MOE.ssm_head_dim,
+            "ssm_state": SSM_MOE.ssm_state,
+            "ssm_groups": SSM_MOE.ssm_groups,
+            "norm_groups": SSM_MOE.ssm_norm_groups,
+            "top_k": SSM_MOE.moe_top_k,
+            "route_scale": SSM_MOE.moe_route_scale,
+            "first_expert": SSM_MOE.moe_first_expert},
+        copy="benchmarks/reference_ssm_moe.py", foreign=PROGRAM,
+        tol=2e-4, metric=0,
+        # one norm and ONE branch a layer
+        stacks={"layers": {"ln1", "attn"}, "ssm_layers": {"ln1", "ssm"},
+                "expert_layers": {"ln1", "moe"}},
+        # the HELD experts in the latent, the whole router, the two
+        # projections round the latent, the shared expert at its own width
+        shapes={"expert_layers/moe/wi": (3, 4, 32, 24),
+                "expert_layers/moe/wo": (3, 4, 24, 32),
+                "expert_layers/moe/router": (3, 64, 16),
+                "expert_layers/moe/latent_in": (3, 64, 32),
+                "expert_layers/moe/latent_out": (3, 32, 64),
+                "expert_layers/moe/shared/wi": (3, 64, 48),
+                "ssm_layers/ssm/wxbc": (3, 64, 32 + 2 * 2 * 16),
+                "ssm_layers/ssm/norm": (3, 32),
+                "layers/attn/wk": (1, 64, 2, 64), "lm_head": (64, 256)},
+        counters=("moe_weight_visits",), state=("ssm", 2e-4),
+        through={
+            **{name: Through({1: n}, 3, 64, bucket, 11)
+               for name, n, bucket in (
+                   ("under_the_taps", 2, 8), ("above_a_chunk", 13, 16),
+                   ("chunks", 21, 32), ("a_bucket", 32, 32))},
+            # lanes at different depths and a parked one between them; 4
+            # lanes x 3 picks: the share's fused form
+            "lanes_at_different_depths": Through(
+                {0: 40, 2: 9, 3: 31}, 4, 64, 48, 10),
+            "scores_too_large_for_one_product": Through(
+                {1: 21}, 3, 64, 32, 11, scores_at_once=False)},
+        generated=(11, 32), refused=_REFUSED,
+        ablations=(
+            {"state_bf16": True}, {"state_at_bucket_end": (21, 32)},
+            {"drop_conv_tail": 21}, {"one_norm_group": True},
+            {"route_scale_one": True}, {"relu": True}, {"top_k": 2},
+            {"no_shared": True}),
+        floor=1e-3, ablated_state=0,
+        cell=Cell(
+            "serve-nemotron3-multiagent-saturated", "serve_ssm_moe",
+            "multiagent-saturated", ("tpot_p50_ms", "setup_s"),
+            ("model.moe_latent_proj_share",
+             "kernel.decode_hbm_share.ssm_moe",
+             "kernel.grouped_matmul_roofline_share.latent",
+             "model.ssm_time_share", "model.prefill_ssm_scan_share",
+             "engine.state_skip_share", "model.moe_time_share",
+             "model.moe_load_imbalance", "engine.moe_expert_read_share",
+             "model.prefill_expert_time_share",
+             "engine.prefill_live_pair_share") + _SERVED,
+            _ssm_moe_rehearsed, slower=0.8, seconds=16)),
 }
 
 

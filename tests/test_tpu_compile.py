@@ -279,6 +279,95 @@ def test_ssm_hybrid_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "raytpu.ssm.scan" in hlo
 
 
+@pytest.mark.parametrize("program", ["decode_block"] + [
+    f"prefill_{b}" for b in (256, 512, 1024, 2048, 4096, 6144)])
+def test_ssm_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """Nemotron-3-Super's first pipeline stage as one of four chips holds
+    it (11 one-branch layers, 128 of 512 experts a routed layer, a quarter
+    of the vocabulary, every width as published, bf16) at the benchmark's
+    engine sizes, 64 slots x 8,192 rows: 11.2 GB static, and every
+    bucket's admission beside it under 16 GB. The three kinds of layer run
+    as scans that index the WHOLE parameter stacks; the slots' 1.3 GB of
+    float32 state and the K/V rows (two KV heads of 128, flat in a row)
+    are updated in place; ``ssm_update`` steps the live lanes' states of
+    8 B/C groups inside the leaf; the held experts are read where they lie
+    by the grouped products, which the latent's two projections stand
+    round."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.nemotron3_super(
+        "MEMEMEM*EME", moe_experts_held=128, vocab_size=32768,
+        param_dtype=jnp.bfloat16)
+    slots, s_max = 64, 8192
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    cache = described(jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, slots, s_max)))
+    assert cache["k"].shape == (1, slots, s_max, 256)  # the heads lie flat
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if program == "decode_block":
+        low = gen.decode_block.lower(
+            params, cache, arr((slots,)), arr((slots,)),
+            arr((slots,), jnp.float32), arr((slots,)), arr((slots,)), cfg, 2)
+    else:
+        low = gen.prefill_into_slot.lower(
+            params, arr((1, int(program.split("_")[1]))), arr(()), arr(()),
+            cache, cfg)
+    compiled = low.compile()
+    mem = compiled.memory_analysis()
+    foot = gen.slot_footprint(cache)
+    cache_bytes = slots * (foot["state_bytes"] + s_max * foot["row_bytes"])
+    static = mem.argument_size_in_bytes
+    assert 11.1e9 < static < 11.3e9  # weights 9.30 + state 1.36 + rows 0.54
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    peak = (static + mem.temp_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes)
+    assert peak < 13.5 * 2 ** 30
+    hlo = compiled.as_text()
+    # no copy of the state, the tails, the rows, a stack of experts, of
+    # state-space or shared-expert weights, the head or the embedding (the
+    # one attention layer's 32 MB of queries' weights are asked for in
+    # another layout, which ``lay_out_for_decode`` gives them at set-up)
+    for of in ("f32[5,64,128,", "f32[5,64,8,", "f32[64,128,64,128",
+               "bf16[5,64,30720", "bf16[1,64,8192,", "bf16[5,128,",
+               "bf16[128,1024,", "bf16[128,2688,", "bf16[5,4096,",
+               "bf16[5,8192,", "bf16[5,5376,", "bf16[5,1024,",
+               "bf16[4096,32768", "bf16[32768,"):
+        assert not _copies(hlo, of), of
+    for scope in ("raytpu.ssm.project", "raytpu.ssm.conv", "raytpu.ssm.gate",
+                  "raytpu.moe.route", "raytpu.moe.experts",
+                  "raytpu.moe.latent", "raytpu.moe.shared",
+                  "raytpu.attn.attend"):
+        assert scope in hlo, scope
+    kernels = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    # two grouped products a routed layer's body, in the share's loop
+    products = [k for k in kernels if "grouped_matmul" in k]
+    assert len(products) >= 2 and all(
+        "bf16[5,128,1024,2688]" in k or "bf16[5,128,2688,1024]" in k
+        for k in products)
+    if program == "decode_block":
+        assert "raytpu.ssm.update" in hlo
+        assert sum("decode_attention" in k for k in kernels) == 1
+        steps = [k for k in kernels if "ssm_update" in k]
+        # G = 8: a tile is (slots, group, row blocks); each call is handed
+        # the live lanes' tiles and writes into the leaf it reads
+        assert steps and all(
+            "f32[5,64,8,8,128,128]" in k and "raytpu.ssm.update" in k
+            and "output_to_operand_aliasing" in k for k in steps)
+    else:
+        assert "raytpu.ssm.scan" in hlo
+
+
 @pytest.mark.parametrize("program", ["decode_block", "prefill_14336"])
 def test_swa_moe_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
     """MiMo-V2-Flash's first pipeline stage (layers 0-6, ``F(dense) | W W
