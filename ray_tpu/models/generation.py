@@ -78,6 +78,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models.quant import QTensor
 from ray_tpu.models.transformer import (
     TransformerConfig,
     _norm,
@@ -114,6 +115,11 @@ from ray_tpu.ops.mamba import mamba_scan, mamba_update
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_update
 
 
+def _is_qtensor(x) -> bool:
+    """``is_leaf`` of a walk that takes an int8 weight as one node."""
+    return isinstance(x, QTensor)
+
+
 def prepare_for_inference(params, config: TransformerConfig):
     """Cast training params (fp32 master copy) to the compute dtype ONCE.
     Serving streams every weight per decode step — fp32 params double that
@@ -122,13 +128,9 @@ def prepare_for_inference(params, config: TransformerConfig):
     inside the consuming matmul. Returns (params, config)."""
     import dataclasses
 
-    from ray_tpu.models.quant import QTensor
-
     cast = jax.tree.map(
         lambda x: x if isinstance(x, QTensor) else x.astype(config.dtype),
-        params,
-        is_leaf=lambda x: isinstance(x, QTensor),
-    )
+        params, is_leaf=_is_qtensor)
     return cast, dataclasses.replace(config, param_dtype=config.dtype)
 
 
@@ -171,20 +173,30 @@ def lay_out_for_decode(params, config: TransformerConfig, slots: int,
     is re-laid-out INSIDE the program, once a block, while requests wait:
     on a v5e the three stacked int8 q/k/v projections of a 6B model, 470
     MB each. The decode step decides (it runs once a token);
-    ``prefill_into_slot`` compiles for what it is handed. One algorithm
-    steered by the compiler's answer for the model and shapes in front of
-    it: where the compiler asks for the layout a leaf already has (every
-    leaf, on the CPU backend) no byte moves.
+    ``prefill_into_slot`` compiles for what it is handed, and is told what
+    that is: every int8 leaf that then lies otherwise than row by row
+    carries its order (``told_where_they_lie``), and the admission holds
+    each layer's slice to it (``_read_where_they_lie``). Left to itself the
+    compiler answers as the decode step does, [L, h, d, k] for ``wq`` /
+    ``wk`` / ``wv``, up to 128 rows; from 256 rows up it wants ``wq`` and
+    ``wk`` as [L, h, k, d], the contracted axis minor (their products are
+    written rows-minor for the rotary step; ``wv`` it takes as it lies),
+    and got that by copying 2 x 16 MB a layer twice over, 3.9-4.2 ms of a
+    GPT-J admission (ISSUE 62). One algorithm steered by the compiler's
+    answer for the model and shapes in front of it: where the compiler
+    asks for the layout a leaf already has (every leaf, on the CPU
+    backend) no byte moves and no leaf is told anything.
 
     A leaf that moves is DONATED, one at a time: set-up's peak rises by
     one leaf, no weight exists twice afterwards, and the arrays of the
     tree passed in that were moved are deleted (the engine owns its
     weights). A leaf that stays is committed where it lies (no copy).
-    Names, logical shapes, dtypes, values and ``QTensor`` structure are
-    unchanged. Returns (params, leaves moved, bytes moved)."""
+    Names, logical shapes, dtypes and values are unchanged, and so is the
+    tree's structure but for the ``order`` of such a ``QTensor``. Returns
+    (params, leaves moved, bytes moved)."""
     leaves, treedef = jax.tree.flatten(params)
-    asked = jax.tree.leaves(
-        decode_weight_formats(params, config, slots, max_len, steps))
+    formats = decode_weight_formats(params, config, slots, max_len, steps)
+    asked = jax.tree.leaves(formats)
     moves = [f.layout is not None and f.layout != x.format.layout
              for x, f in zip(leaves, asked)]
     nbytes = sum(x.nbytes for x, move in zip(leaves, moves) if move)
@@ -194,7 +206,44 @@ def lay_out_for_decode(params, config: TransformerConfig, slots: int,
         jax.block_until_ready(jax.device_put(x, f, donate=True))
         if move else jax.device_put(x, x.sharding)
         for x, f, move in zip(leaves, asked, moves)]
-    return treedef.unflatten(placed), sum(moves), nbytes
+    return (told_where_they_lie(treedef.unflatten(placed), formats),
+            sum(moves), nbytes)
+
+
+def told_where_they_lie(params, formats):
+    """``params`` (arrays or shapes) with every ``QTensor`` told the order
+    its ``q`` lies in (``QTensor.order``) where ``formats``, a tree like
+    ``params`` (``decode_weight_formats``), lays it otherwise than row by
+    row; the other nodes, and every leaf, as they are."""
+    def told(w, f):
+        if not isinstance(w, QTensor):
+            return w
+        order = f.q.layout and tuple(f.q.layout.major_to_minor)
+        return QTensor(w.q, w.s, None if order == tuple(
+            range(w.q.ndim)) else order)
+
+    return jax.tree.map(told, params, formats, is_leaf=_is_qtensor)
+
+
+def _read_where_they_lie(lp):
+    """One layer's weights with each int8 leaf that lies otherwise than row
+    by row (``QTensor.order``) PINNED to that order for the program being
+    traced. A product over many rows would have its weight contracted-axis
+    minor, and the compiler gets it that way at any price: it slices the
+    layer out of the stack and lays it out again, 4 passes over 16 MB a
+    leaf a layer, before it dequantises (``lay_out_for_decode``). Held to
+    the order the stack has, the slice is a view of the stack and the
+    product reads it there, dequantised on the way, as the decode step's
+    does. A tree without such a leaf comes back as it is (no operation)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    def pinned(w):
+        if not (isinstance(w, QTensor) and w.order):
+            return w
+        return QTensor(with_layout_constraint(w.q, Layout(w.lies())), w.s,
+                       w.order)
+
+    return jax.tree.map(pinned, lp, is_leaf=_is_qtensor)
 
 
 def _ckr_width(c: TransformerConfig) -> int:
@@ -1940,7 +1989,9 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     cells before reaching them. For the same reason the admission works on
     the bucket's rows of the slot and on no other (``_admission_slot``):
     rows [Sb, S_max) of a leaf that keeps a row a token are neither zeroed
-    nor written, and hold what an earlier request left there.
+    nor written, and hold what an earlier request left there. An int8
+    weight that says where it lies (``QTensor.order``) is read there, at
+    every bucket (``_read_where_they_lie``).
 
     Latent attention takes the plain form here: per-head keys and values
     are expanded from the prompt's latents and attended causally over the
@@ -2019,6 +2070,7 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
 
             def layer(carry, lp, li, lc=lc, positions=positions, real=real):
                 x, single, choice, total, *tapped = carry
+                lp = _read_where_they_lie(lp)
                 attn = _row(layer_kind(lp), lc).prefill(
                     single, li, lp, lc, prompt_holds, choice)
                 y, _aux, single, stats = apply_block(

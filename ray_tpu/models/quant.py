@@ -23,12 +23,13 @@ each leaf once, at set-up, in the layout its compiled ``decode_block``
 reads (``generation.lay_out_for_decode``; on a v5e the int8 ``wq`` /
 ``wk`` / ``wv`` then lie with the head dimension outside the contracted
 one). A ``QTensor`` keeps its structure through that: ``q`` may move,
-``s`` stays.
+``s`` stays, and the node is told where ``q`` then lies (``order``), which
+the admission program reads (``generation.prefill_into_slot``).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,11 +37,19 @@ import jax.numpy as jnp
 
 @jax.tree_util.register_pytree_node_class
 class QTensor:
-    """Symmetric int8 weight + broadcastable float32 scale."""
+    """Symmetric int8 weight + broadcastable float32 scale. ``order`` is
+    where ``q`` lies: the major-to-minor order of the STACKED leaf's axes in
+    device memory, noted by whoever placed it otherwise than the chip's
+    default (``generation.lay_out_for_decode``), else None. It is static
+    (the node's aux data), so it rides through ``jit`` and ``lax.scan``
+    with the leaf and a program can be written for the bytes as they
+    lie (``lies``)."""
 
-    def __init__(self, q: jax.Array, s: jax.Array):
+    def __init__(self, q: jax.Array, s: jax.Array,
+                 order: Optional[Tuple[int, ...]] = None):
         self.q = q
         self.s = s
+        self.order = order
 
     # -- the drop-in surface the model code uses --
     @property
@@ -54,6 +63,14 @@ class QTensor:
     def astype(self, dtype) -> jax.Array:
         return self.q.astype(dtype) * self.s.astype(dtype)
 
+    def lies(self) -> Optional[Tuple[int, ...]]:
+        """``order`` for ``q`` as it is held here: the stack's, less the
+        leading axes a scan or an index has taken off."""
+        if self.order is None:
+            return None
+        lead = len(self.order) - self.q.ndim
+        return tuple(a - lead for a in self.order if a >= lead)
+
     @property
     def T(self):  # tied-embedding head path
         return self.astype(jnp.bfloat16).T
@@ -63,11 +80,11 @@ class QTensor:
 
     # -- pytree --
     def tree_flatten(self):
-        return (self.q, self.s), None
+        return (self.q, self.s), self.order
 
     @classmethod
-    def tree_unflatten(cls, _aux, children):
-        return cls(*children)
+    def tree_unflatten(cls, order, children):
+        return cls(*children, order=order)
 
 
 def quantize_tensor(w: jax.Array, reduce_axes: Tuple[int, ...]) -> QTensor:

@@ -140,7 +140,9 @@ class LLMEngine:
     its device in the physical layout the compiled ``decode_block`` reads
     it in (``generation.lay_out_for_decode``), and a leaf that had to move
     is donated, so the caller's array of it is deleted. ``self.params``
-    keeps every name, logical shape and dtype.
+    keeps every name, logical shape and dtype; an int8 leaf that lies
+    otherwise than row by row says so (``QTensor.order``), which the
+    admission programs read.
     """
 
     def __init__(self, params, config, *, max_slots: int = 8,

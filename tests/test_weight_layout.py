@@ -104,6 +104,82 @@ def test_lay_out_for_decode_moves_only_what_lies_elsewhere():
             stays(params).unsafe_buffer_pointer())
 
 
+def test_an_int8_leaf_carries_where_it_lies_through_jit_and_scan():
+    """``QTensor.order`` is the node's static part: a scan's body and a
+    jitted function see it, a slice keeps it, and ``lies`` is the order of
+    the axes the holder still has."""
+    q = jnp.arange(2 * 3 * 4 * 5, dtype=jnp.int8).reshape(2, 3, 4, 5)
+    w = QTensor(q, jnp.ones((2, 1, 4, 5)), order=(0, 2, 1, 3))
+    assert w.lies() == (0, 2, 1, 3)
+    leaves, treedef = jax.tree.flatten(w)
+    assert len(leaves) == 2 and treedef.unflatten(leaves).order == w.order
+    assert jax.tree.map(lambda x: x[0], w).lies() == (1, 0, 2)
+    assert QTensor(q, w.s).lies() is None
+    seen = []
+
+    def body(carry, lp):
+        seen.append((lp.order, lp.q.shape, lp.lies()))
+        return carry + lp.astype(jnp.float32).sum(), None
+
+    total, _ = jax.jit(lambda t: jax.lax.scan(body, 0.0, t))(w)
+    assert seen == [((0, 2, 1, 3), (3, 4, 5), (1, 0, 2))]
+    assert float(total) == float(np.asarray(q, np.float32).sum())
+
+
+def test_told_where_they_lie_marks_what_lies_otherwise_and_nothing_else():
+    """Every int8 leaf whose format is not row by row learns its order;
+    the others, the scales and the plain leaves come back as they are
+    (on the CPU, where nothing lies otherwise, the tree's structure is
+    the one it had: ``lay_out_for_decode`` returns what it was given)."""
+    params, _cfg = _model("mha_int8")
+    assert jax.tree.structure(_as_they_lie(params)) == (
+        jax.tree.structure(params))
+    told = _as_they_lie(_projections_elsewhere(params))
+    attn = told["layers"]["attn"]
+    assert [attn[w].order for w in ("wq", "wk", "wv", "wo")] == [
+        (3, 2, 1, 0)] * 3 + [None]
+    assert told["layers"]["mlp"]["wi"].order is None
+    for a, b in zip(jax.tree.leaves(told), jax.tree.leaves(params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_admission_holds_told_leaves_to_their_order_and_decode_does_not():
+    """ISSUE 62. Handed int8 leaves that say where they lie,
+    ``prefill_into_slot`` pins each layer's slice to that order (a
+    ``LayoutConstraint`` a leaf in the layer scan's body: the compiler may
+    not lay the slice out again before the product) and gives the very
+    logits; handed the same leaves untold, its text has no such
+    operation; ``decode_block``'s text is the same either way (the decode
+    step decided where the weights lie: it needs no telling)."""
+    params, cfg = _model("mha_int8")
+    away = _projections_elsewhere(params)
+    told = _as_they_lie(away)
+    prompt = (jnp.arange(1, 17, dtype=jnp.int32) % cfg.vocab_size)[None]
+
+    def admit(tree):
+        cache = gen.init_kv_cache(cfg, SLOTS, MAX_LEN)
+        args = (tree, prompt, np.int32(11), np.int32(1), cache, cfg)
+        text = gen.prefill_into_slot.lower(*args).as_text()
+        logits, cache = gen.prefill_into_slot(*args)
+        return text, np.asarray(logits), jax.tree.map(np.asarray, cache)
+
+    def decode_text(tree):
+        lane = jnp.zeros(SLOTS, jnp.int32)
+        return gen.decode_block.lower(
+            tree, gen.init_kv_cache(cfg, SLOTS, MAX_LEN), lane, lane,
+            jnp.zeros(SLOTS, jnp.float32), lane, lane, cfg, 2).as_text()
+
+    text, logits, cache = admit(told)
+    plain_text, plain_logits, plain_cache = admit(away)
+    assert text.count("@LayoutConstraint") == 3
+    assert "@LayoutConstraint" not in plain_text
+    np.testing.assert_array_equal(logits, plain_logits)
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(plain_cache)):
+        np.testing.assert_array_equal(a, b)
+    assert decode_text(told) == decode_text(away)
+    assert "@LayoutConstraint" not in decode_text(told)
+
+
 SCRIPT = [(np.arange(1, 9), 10), (np.arange(3, 23) % 200, 7),
           (np.full(5, 7), 12), (np.arange(40, 10, -1), 9)]
 
@@ -127,27 +203,41 @@ def _serve(params, cfg):
         eng.shutdown()
 
 
-@pytest.mark.parametrize("engine_lays_out", [True, False],
-                         ids=["with", "without"])
+def _as_they_lie(params):
+    """``params`` with every int8 leaf told where it lies now."""
+    return gen.told_where_they_lie(
+        params, jax.tree.map(lambda x: x.format, params))
+
+
+@pytest.mark.parametrize("engine_lays_out", [True, False, "told"],
+                         ids=["with", "without", "told"])
 def test_engine_tokens_do_not_depend_on_where_the_weights_lay(
         engine_lays_out, monkeypatch):
     """A fixed script of requests: the same tokens from weights that came
     in the layout the decode step reads, from weights that came otherwise
-    and were moved at set-up, and (``without``) from weights left where
-    they lay, for which the programs simply compile."""
+    and were moved at set-up, (``without``) from weights left where they
+    lay, for which the programs simply compile, and (``told``) from
+    weights left there and TOLD so, whose admissions hold them to that
+    order (what the chip's engine serves from, ISSUE 62)."""
     params, cfg = _model("mha_int8")
     want, stats, _ = _serve(params, cfg)
     assert [len(t) for t in want] == [n for _, n in SCRIPT]
     assert (stats["weights_relaid"], stats["weights_relaid_bytes"]) == (0, 0)
 
     given = _projections_elsewhere(params)
-    if not engine_lays_out:
+    if engine_lays_out == "told":
+        monkeypatch.setattr(gen, "lay_out_for_decode",
+                            lambda p, *_a: (_as_they_lie(p), 0, 0))
+    elif not engine_lays_out:
         monkeypatch.setattr(gen, "lay_out_for_decode",
                             lambda p, *_a: (p, 0, 0))
     got, stats, served = _serve(given, cfg)
     assert got == want
     wq = params["layers"]["attn"]["wq"].q
-    if engine_lays_out:
+    if engine_lays_out == "told":
+        assert served["layers"]["attn"]["wq"].order == (3, 2, 1, 0)
+        assert stats["weights_relaid"] == 0
+    elif engine_lays_out:
         assert stats["weights_relaid"] == 3
         assert stats["weights_relaid_bytes"] == 3 * wq.nbytes
         assert served["layers"]["attn"]["wq"].q.format.layout == (

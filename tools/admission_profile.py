@@ -23,7 +23,9 @@ compiler then does to the fusions around it) is not in a kernel's own
 micro-run (``tools/prefill_attention_micro.py``): this is the cheaper
 instrument than a cell's pair, ~1 chip-minute a configuration, and reads
 to 0.01 ms. One line a bucket, with the program's ``--top`` busiest
-operations (name, times a program, ms a program), and the forty busiest in
+operations (name, times a program, ms a program), ``int8_moves_ms`` (the
+summed ms a program of operations whose result is an ``s8[...]`` array: an
+int8 weight copied before it is used; PR 62), and the forty busiest in
 ``chiprun_out/admission_profile_<config>_<score-bytes>.json``. ``--tiny``
 walks the code here at the configuration's rehearsal size without the
 profiler and reports no time. A tool: no cell and no metric reads it. It
@@ -163,7 +165,15 @@ def main(argv=None):
                     / max(len(runs), 1),
                     kernel_ms=1e3 * sum(
                         s for k, (_c, s, _t) in ops.items()
-                        if "prefill_attention" in k) / args.calls)
+                        if "prefill_attention" in k) / args.calls,
+                    # operations whose RESULT is an int8 array: a weight
+                    # sliced out of its stack or laid out again before it
+                    # is dequantised (0.0 where every product reads its
+                    # weight where it lies)
+                    int8_moves_ms=1e3 * sum(
+                        s for k, (_c, s, _t) in ops.items()
+                        if k.partition(":")[2].startswith("s8[")
+                    ) / args.calls)
                 busiest = [
                     [k, c // args.calls, 1e3 * s / args.calls, text]
                     for k, (c, s, text) in sorted(

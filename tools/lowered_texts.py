@@ -12,7 +12,9 @@ A PR that means to leave a configuration's programs alone shows it with
 an empty diff against its parent (``git archive`` of the parent into a
 scratch directory); where lines differ they name the programs to measure.
 Names: gptj glm47 glm52 granite mimo kimi phi4flash evabyte train (default: all
-the tree has).
+the tree has). ``gptj`` prints its programs twice: from the weights as they are
+made, and (``gptj-as-held``, since PR 62) from int8 leaves that say where a
+v5e's engine has laid them, which its admissions read and its decode does not.
 """
 
 from __future__ import annotations
@@ -86,8 +88,20 @@ def main() -> int:
         params = jax.eval_shape(lambda: serve.make_int8_params(cfg, 1))
         params = jax.eval_shape(
             lambda p: gen.prepare_for_inference(p, cfg)[0], params)
-        programs("gptj", params, gen.prepare_for_inference({}, cfg)[1],
-                 model["run"]["engine"])
+        cfg = gen.prepare_for_inference({}, cfg)[1]
+        programs("gptj", params, cfg, model["run"]["engine"])
+        if hasattr(gen, "told_where_they_lie"):
+            # the same programs over the leaves as a v5e's engine holds
+            # them (``lay_out_for_decode``: wq / wk / wv heads-major,
+            # tests/test_tpu_compile.py): the admissions read that order
+            from ray_tpu.models.quant import QTensor
+
+            attn = params["layers"]["attn"]
+            held = {w: QTensor(attn[w].q, attn[w].s, (0, 2, 1, 3))
+                    for w in ("wq", "wk", "wv")}
+            programs("gptj-as-held", {**params, "layers": {
+                **params["layers"], "attn": {**attn, **held}}}, cfg,
+                model["run"]["engine"])
     for name in which:
         if name in SERVED:
             mod = importlib.import_module("benchmarks." + SERVED[name][0])
