@@ -109,7 +109,7 @@ from ray_tpu.ops.decode_attention import (
     decode_attention,
     slot_schedule,
 )
-from ray_tpu.ops.eva import eva_attention, eva_pool
+from ray_tpu.ops.eva import eva_attention, eva_block_pairs, eva_pool
 from ray_tpu.ops.kda import kda_chunked, kda_update
 from ray_tpu.ops.mamba import mamba_scan, mamba_update
 from ray_tpu.ops.ssm import causal_conv, ssm_chunked, ssm_update
@@ -1437,10 +1437,12 @@ def _eva_close(params, cache, pos, c: TransformerConfig):
 def _prefill_eva(single, li, p, c: TransformerConfig):
     """One prefill layer's ``attn_fn`` for an "eva" layer: the bucket's
     complete windows are pooled, the prompt attends as the layer is
-    written (``ops/eva.eva_attention``: block by block, its own window
-    and the summaries before it; a real token's window never sees a
-    summary that holds padding, which only windows past the prompt's last
-    can), and the slot is handed what a decode step expects to find: the
+    written (``ops/eva.eva_attention``: ONE kernel call, a window's
+    queries over the summaries before it and its own rows up to the
+    diagonal, nothing past ``prompt_len`` computed and those rows zeros;
+    a real token's window never sees a summary that holds padding, which
+    only windows past the prompt's last can), and the slot is handed what
+    a decode step expects to find: the
     summaries of the prompt's ``prompt_len // eva_window`` complete
     windows, then the rows of the tokens after them (rows past
     ``eva_read_len(prompt_len)`` hold whatever: nothing attends them
@@ -1458,7 +1460,7 @@ def _prefill_eva(single, li, p, c: TransformerConfig):
                 tuple(x[0, :whole].reshape((-1, W) + x.shape[2:])
                       for x in (k, v))))
         with jax.named_scope("raytpu.eva.attend"):
-            out = eva_attention(q, k, v, ks, vs, window=W,
+            out = eva_attention(q, k, v, ks, vs, p.prompt_len, window=W,
                                 chunk=c.eva_chunk)
         with jax.named_scope("raytpu.eva.pool"):
             closed = p.prompt_len // W
@@ -1488,7 +1490,11 @@ def prefill_stat_keys(config: TransformerConfig) -> Tuple[str, ...]:
     the attention's kernel computes a head for this prompt
     (``ops/attention.prefill_block_pairs``), and
     ``prefill_attn_blocks_bucket``, what the whole bucket's causal blocks
-    would be, summed over the prompt's layers. None for the other models."""
+    would be, summed over the prompt's layers; for "eva" layers the same
+    two names: the blocks of a window's rows and of the summaries before
+    it that the kernel computes, and every block of queries against its
+    whole window and all the bucket's summaries
+    (``ops/eva.eva_block_pairs``). None for the other models."""
     keys = sum((row.prefill_counters
                 for _kind, row, _n in _kinds_of(config)), ())
     if config.moe_experts and config.moe_impl == "dropless":
@@ -1539,7 +1545,8 @@ def _dsa_stats(pos, c: TransformerConfig, n: int, cache):
     }
 
 
-def _chosen_prefill_stats(n: int, bucket: int, prompt_len):
+def _chosen_prefill_stats(c: TransformerConfig, n: int, bucket: int,
+                          prompt_len):
     """What one admission's ``n`` layers that attend under a choice add
     to its counters: the kernel's blocks for the prompt, and for a prompt
     as long as its bucket (a group of heads a call: each its own KV head)."""
@@ -1547,6 +1554,18 @@ def _chosen_prefill_stats(n: int, bucket: int, prompt_len):
                 bucket, prompt_len, 1),
             "prefill_attn_blocks_bucket": n * prefill_block_pairs(
                 bucket, bucket, 1)}
+
+
+def _eva_prefill_stats(c: TransformerConfig, n: int, bucket: int,
+                       prompt_len):
+    """What one admission's ``n`` "eva" layers add to its counters: the
+    blocks their attention computes a head for the prompt, and every
+    block of queries against its whole window and all the bucket's
+    summaries (``ops/eva.eva_block_pairs``)."""
+    computed, every = eva_block_pairs(
+        bucket, prompt_len, window=c.eva_window, chunk=c.eva_chunk)
+    return {"prefill_attn_blocks": n * computed,
+            "prefill_attn_blocks_bucket": n * every}
 
 
 def _window_stats(pos, c: TransformerConfig, n: int, cache):
@@ -1622,7 +1641,7 @@ class _Kind(NamedTuple):
     counters: Tuple[str, ...] = ()
     counts: Optional[Callable] = None
     # an admission's int32 counters of the kind (``prefill_stat_keys``),
-    # and (n, bucket, prompt_len) -> what its n layers add to them
+    # and (c, n, bucket, prompt_len) -> what its n layers add to them
     prefill_counters: Tuple[str, ...] = ()
     prefill_counts: Optional[Callable] = None
     # whether it keeps nothing and reads what layers below keep or hand on
@@ -1700,8 +1719,12 @@ _KINDS = {
             eva_read_len(c, pos), [cache["ek"], cache["ev"]]),
         counters=("eva_window_rows_read", "eva_summary_rows_read",
                   "eva_windows_closed"),
-        counts=_eva_stats, closes=_eva_close, chunk=_dense_chunk,
-        read_len=eva_read_len, slot_rows=eva_rows, row_a_token=False),
+        counts=_eva_stats,
+        prefill_counters=("prefill_attn_blocks",
+                          "prefill_attn_blocks_bucket"),
+        prefill_counts=_eva_prefill_stats, closes=_eva_close,
+        chunk=_dense_chunk, read_len=eva_read_len, slot_rows=eva_rows,
+        row_a_token=False),
     "gmu": _Kind(
         layers=lambda c: c.n_of("gmu"), keeps=_nothing_kept,
         decode=lambda cache, li, lp, c, s, handed: recalling(
@@ -2048,7 +2071,7 @@ def prefill_into_slot(params, prompt, prompt_len, slot, cache,
     for _kind, row, n in _kinds_of(c):
         if row.prefill_counts:
             routed_stats = _add_stats(
-                routed_stats, row.prefill_counts(n, S, prompt_len))
+                routed_stats, row.prefill_counts(c, n, S, prompt_len))
     carry = (x, single, choice, routed_stats) + (
         (_taps(c, x),) if taps else ())
     narrowed = False

@@ -2219,15 +2219,16 @@ def _eva_whole_sequence(q, k, v, wp, c: TransformerConfig):
     (the uncached forward): every window the sequence reaches is pooled
     (``ops/eva.eva_pool``; the last, where it is not whole, is padded with
     rows that no query sees as a summary) and the queries attend their
-    own window and the summaries before it (``ops/eva.eva_attention``)."""
-    S, W = q.shape[1], c.eva_window
-    q, k, v = (jnp.pad(x, ((0, 0), (0, -S % W), (0, 0), (0, 0)))
-               for x in (q, k, v))
+    own window and the summaries before it (``ops/eva.eva_attention``,
+    told the sequences' own length: the padding is not attended)."""
+    W = c.eva_window
+    k, v = (jnp.pad(x, ((0, 0), (0, -x.shape[1] % W), (0, 0), (0, 0)))
+            for x in (k, v))
     with jax.named_scope("raytpu.eva.pool"):
         ks, vs = eva_pool(k, v, wp["phi"], wp["mu"], c.eva_chunk)
     with jax.named_scope("raytpu.eva.attend"):
         out = eva_attention(q, k, v, ks, vs, window=W, chunk=c.eva_chunk)
-    return out[:, :S], None
+    return out, None
 
 
 def _eva_mixer(h, wp, c: TransformerConfig, positions, attn_fn):

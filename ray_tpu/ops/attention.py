@@ -108,7 +108,7 @@ def block_of(n: int, most: int) -> int:
 # may hold in fast memory (a v5e has 128 MiB; 2,048 x 1,024 float32 scores
 # are 8 MiB, their exponentials beside them).
 _PREFILL_ROWS = 2048
-_PREFILL_VMEM = 100 * 2 ** 20
+PREFILL_VMEM = 100 * 2 ** 20
 
 
 def prefill_blocks(s: int, heads_a_kv_head: int, block: int):
@@ -132,6 +132,25 @@ def prefill_block_pairs(s: int, length, heads_a_kv_head: int,
     bq, bk = prefill_blocks(s, heads_a_kv_head, block)
     first = jnp.arange(s // bq, dtype=jnp.int32) * bq  # a block's first query
     return jnp.where(first < length, first // bk + 1, 0).sum()
+
+
+def softmax_block(s, v_ref, lanes, m_ref, l_ref, acc_ref, e=Ellipsis):
+    """One block of rows into a running softmax, inside a kernel: ``s``
+    [queries, rows] (float32, scaled, what a query must not see at
+    NEG_INF or under) against the rows' values ``v_ref[:, lanes]``, into
+    the running maxima ``m_ref[e]`` and sums ``l_ref[e]`` [queries, 1]
+    and the accumulator ``acc_ref[e]`` [queries, Dv], all float32; the
+    exponentials go into the product in the values' type. The body of
+    ``blocked_causal_attention``'s sweep and of ``ops/eva``'s."""
+    m = m_ref[e]
+    m_new = lax.max(m, lax.reduce_max(s, (1,))[:, None])
+    p = lax.exp(s - m_new)
+    shrink = lax.exp(m - m_new)
+    l_ref[e] = shrink * l_ref[e] + lax.reduce_sum(p, (1,))[:, None]
+    acc_ref[e] = shrink * acc_ref[e] + lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[:, lanes],
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[e] = m_new
 
 
 @functools.partial(jax.jit, static_argnames="block")
@@ -243,15 +262,8 @@ def blocked_causal_attention(
                 # causal alone, every query sees the block's first row (bq
                 # divides bk): the maximum is a real score and a masked
                 # exponential is 0
-                m = m_ref[e]
-                m_new = lax.max(m, lax.reduce_max(s, (1,))[:, None])
-                p = lax.exp(s - m_new)
-                shrink = lax.exp(m - m_new)
-                l_ref[e] = shrink * l_ref[e] + lax.reduce_sum(p, (1,))[:, None]
-                acc_ref[e] = shrink * acc_ref[e] + lax.dot_general(
-                    p.astype(v_ref.dtype), v_ref[:, e * Dv:(e + 1) * Dv],
-                    (((1,), (0,)), ((), ())), preferred_element_type=f32)
-                m_ref[e] = m_new
+                softmax_block(s, v_ref, slice(e * Dv, (e + 1) * Dv),
+                              m_ref, l_ref, acc_ref, e)
 
         if masked:
             pl.when(live & (j <= here))(lambda: sweep(False))
@@ -313,7 +325,7 @@ def blocked_causal_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
-            vmem_limit_bytes=_PREFILL_VMEM),
+            vmem_limit_bytes=PREFILL_VMEM),
         interpret=jax.default_backend() != "tpu",
         name="prefill_attention",
     )(length, *operands)

@@ -534,7 +534,10 @@ class LLMEngine:
           the attention's kernel computed a head: the blocks of queries
           with a real token, each up to the diagonal) and
           ``prefill_attn_blocks_bucket`` (what the whole bucket's would
-          have been).
+          have been). For "eva" layers the same two names: the blocks of
+          a window's rows and of the closed windows' summaries computed,
+          and every block of queries against its whole window and every
+          summary of the bucket (``ops/eva.eva_block_pairs``).
         - What was spent before the first request, each stretch written
           where it happens, in seconds (no span: no trace covers set-up).
           By this constructor, each from the previous one's end:

@@ -35,6 +35,7 @@ CFG = TransformerConfig.tiny_eva(dtype=jnp.float32)
 W, C = CFG.eva_window, CFG.eva_chunk
 PER = W // C  # summaries a closed window leaves
 TOL = 2e-5  # relative RMS, float32 against float32: rounding order only
+TOL_BF16 = 1e-2  # bf16 operands and result against float32: ~1.3 of its eps
 HP = {"n_heads": CFG.n_heads, "d_head": CFG.d_head, "eps": CFG.norm_eps,
       "theta": CFG.rope_theta, "window": W, "chunk": C,
       "n_pred_heads": CFG.n_pred_heads}
@@ -100,23 +101,98 @@ def test_the_forward_is_none_of_the_wrong_models(params, switch):
     assert rel(got, wrong) > 50 * TOL
 
 
-def test_the_pooling_and_the_blocked_attention_are_the_references(params):
+@pytest.mark.parametrize("S,length,block,dtype", [
+    (3 * W, None, 1024, jnp.float32),  # whole windows, a block a window
+    (3 * W, None, 8, jnp.float32),  # four blocks a window, one a window's
+    (3 * W, None, 16, jnp.float32),  # summaries; two: window 1 sees half
+    (W + W // 4, None, 8, jnp.float32),  # the 2,560 bucket's shape
+    (W + W // 2, None, 16, jnp.float32),  # the 3,072 bucket's
+    (3 * W, 20, 8, jnp.float32),  # inside window 0: no summary swept
+    (3 * W, 2 * W, 16, jnp.float32),  # at a window's edge
+    (3 * W, 2 * W + 13, 8, jnp.float32),  # mid-window in the last
+    (W + W // 2, W + 5, 16, jnp.float32),  # in a bucket's half window
+    (3 * W, None, 16, jnp.bfloat16),
+    (3 * W, 2 * W + 13, 8, jnp.bfloat16)])
+def test_the_pooling_and_the_blocked_attention_are_the_references(
+        S, length, block, dtype):
+    """``eva_pool`` and ``eva_attention`` (the prefill kernel over the
+    windows, the summaries its prefix) against the reference's one softmax
+    over [summaries | tokens], a head at a time: whole windows and a
+    bucket's part of one, every block size's way through the summaries
+    (none, whole blocks, the block the count falls in), and under a
+    ``length`` the rows before it those of the call on the real tokens
+    alone, the rows from it on zeros. bf16 operands are held to their own
+    rounding against the float32 reference of the same operands."""
+    tol = TOL if dtype == jnp.float32 else TOL_BF16
     k = jax.random.split(jax.random.key(3), 5)
-    S, H, D = 3 * W, CFG.n_heads, CFG.d_head
-    q, key, v = (jax.random.normal(k[i], (1, S, H, D)) for i in range(3))
+    H, D = CFG.n_heads, CFG.d_head
+    q, key, v = (jax.random.normal(k[i], (1, S, H, D), dtype)
+                 for i in range(3))
     phi, mu = (jax.random.normal(k[i], (H, D)) * D ** -0.5 for i in (3, 4))
-    ks, vs = eva_pool(key, v, phi, mu, C)
-    assert ks.shape == (1, S // C, H, D)
-    out = eva_attention(q, key, v, ks, vs, window=W, chunk=C, block=8)
+    whole = S // W * W  # the windows a bucket pools
+    ks, vs = eva_pool(key[:, :whole], v[:, :whole], phi, mu, C)
+    assert ks.shape == (1, whole // C, H, D) and ks.dtype == dtype
+    out = eva_attention(q, key, v, ks, vs, length, window=W, chunk=C,
+                        block=block)
+    assert out.shape == q.shape and out.dtype == dtype
+    n = S if length is None else length
+    if length is not None:
+        assert not out[:, n:].any()
+        closed = n // W * PER
+        alone = eva_attention(
+            q[:, :n], key[:, :n], v[:, :n], ks[:, :closed], vs[:, :closed],
+            window=W, chunk=C, block=block)
+        assert rel(out[:, :n], alone) < tol
+    f32 = [x.astype(jnp.float32) for x in (q, key, v, ks, vs)]
     with jax.default_matmul_precision("highest"):
         for h in range(H):
             want_k, want_v = ref.summaries(
-                key[0, :, h], v[0, :, h], phi[h], mu[h], HP, {})
-            assert rel(ks[0, :, h], want_k) < TOL
-            assert rel(vs[0, :, h], want_v) < TOL
-            assert rel(out[0, :, h], ref.eva_head(
-                q[0, :, h], key[0, :, h], v[0, :, h], want_k, want_v, HP,
-                {})) < TOL
+                f32[1][0, :whole, h], f32[2][0, :whole, h], phi[h], mu[h],
+                HP, {})
+            assert rel(ks[0, :, h], want_k) < tol
+            assert rel(vs[0, :, h], want_v) < tol
+            # the attention alone: over the summaries it was handed
+            assert rel(out[0, :n, h], ref.eva_head(
+                *(x[0, :, h] for x in f32), HP, {})[:n]) < tol
+
+
+@pytest.mark.parametrize("bucket,length,computed,every", [
+    # four windows of two blocks of queries; a window's diagonal 1 + 2
+    # blocks of rows, and its 128 w summaries one block (512 in all)
+    (8192, 8192, 4 * 3 + 3 * 2, 4 * 2 * (1 + 2)),
+    # 904 bytes into window 2: its first block of queries alone
+    (8192, 5000, 2 * 3 + 2 + (1 + 1), 4 * 2 * (1 + 2)),
+    # 1,536 summaries in two blocks of 1,024: windows 9-11 sweep both
+    (24576, 24576, 12 * 3 + (8 + 3 * 2) * 2, 12 * 2 * (2 + 2)),
+    # a window and a quarter: the part sees the one window's 128
+    (2560, 2560, 3 + 1 + 1, 2 * 2 * (1 + 2)),
+    (2560, 2048, 3, 2 * 2 * (1 + 2))])
+def test_the_admissions_counters_are_the_kernels_blocks(
+        bucket, length, computed, every):
+    """EvaByte's geometry (windows of 2,048 in chunks of 16, blocks of
+    1,024): what ``eva_block_pairs`` counts is what the kernel's grid
+    computes and steps through, by hand."""
+    from ray_tpu.ops.eva import eva_block_pairs
+
+    got = eva_block_pairs(bucket, jnp.int32(length), window=2048, chunk=16)
+    assert (int(got[0]), got[1]) == (computed, every)
+
+
+def test_an_admission_reports_the_blocks_its_attention_computed(params):
+    """A prompt of 40 in a bucket of 80 (two windows and a half: a block
+    a window): the admission's counters say two of the three windows were
+    attended, the second over the first's summaries, a layer."""
+    lanes = tuple(jnp.zeros((2,), t) for t in (
+        jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.int32))
+    padded = jnp.zeros((1, 80), jnp.int32).at[0, :40].set(tokens_of(40))
+    stats = gen.prefill_into_slot(
+        params, padded, jnp.int32(40), jnp.int32(1),
+        gen.init_kv_cache(CFG, 2, 128), CFG, lanes, jnp.float32(0),
+        jnp.int32(0))[3]
+    assert tuple(stats) == gen.prefill_stat_keys(CFG) == (
+        "prefill_attn_blocks", "prefill_attn_blocks_bucket")
+    assert int(stats["prefill_attn_blocks"]) == CFG.n_layers * (1 + 2)
+    assert int(stats["prefill_attn_blocks_bucket"]) == CFG.n_layers * 3 * 2
 
 
 def test_a_bf16_model_keeps_its_residual_and_its_logits_in_float32():

@@ -13,7 +13,7 @@ import pytest
 
 _TOOLS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "tools")
 _TIMES = {"ms_a_call", "program_ms", "kernel_ms", "tflops_computed",
-          "peak_share_computed"}
+          "peak_share_computed", "peak_share_useful"}
 
 
 def _tool(name):
@@ -42,9 +42,35 @@ def test_the_slot_table_walks_every_form_against_the_dense_one(
         assert not _TIMES & set(r)
 
 
+def test_the_eva_table_walks_the_kernel_beside_the_block_loop(
+        capsys, tmp_path, monkeypatch):
+    """EvaByte's windows at a toy size: every blocks' kernel gives the
+    block loop's answer up to the prompt and zeros past it, computes no
+    fewer pairs than the prompt needs and, with blocks of a quarter of a
+    window, fewer than the block loop's whole windows and every summary."""
+    monkeypatch.chdir(tmp_path)
+    _tool("prefill_attention_micro").main(["--eva", "--tiny"])
+    rows = _rows(capsys)
+    assert {r["blocks"] for r in rows} == {"rule", "8x8", "8x16x16"}
+    assert {(r["tokens"], r["prompt_len"]) for r in rows} == {
+        (48, 48), (48, 40), (96, 96), (96, 81)}
+    for r in rows:
+        assert r["form"] == "kernel" and r["geometry"] == "eva"
+        assert r["err"] < 2e-2 and r["past_length_all_zero"]
+        assert r["pairs_useful"] <= r["pairs_computed"]
+        if r["blocks"] == "8x8":  # windows of 32 in chunks of 4
+            assert r["pairs_computed"] < r["tokens"] * (
+                32 + r["tokens"] // 32 * 8)
+        assert not _TIMES & set(r)
+    small = {r["prompt_len"]: r["pairs_computed"] for r in rows
+             if r["blocks"] == "8x8" and r["tokens"] == 96}
+    assert small[81] < small[96]  # the padding's blocks are skipped
+
+
 @pytest.mark.parametrize("config,score_bytes,buckets", [
     ("gptj-6b-int8-serve", (), [64, 128, 256, 512, 1024]),
-    ("granite4-h-micro-bf16-serve", (0, 10 ** 12), [64, 128])])
+    ("granite4-h-micro-bf16-serve", (0, 10 ** 12), [64, 128]),
+    ("evabyte-l8-bf16-serve", (), [64, 128])])
 def test_the_admission_profile_walks_a_configurations_buckets(
         capsys, monkeypatch, config, score_bytes, buckets):
     """Every bucket, each form in its turn (a form's program is compiled

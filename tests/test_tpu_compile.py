@@ -611,19 +611,9 @@ def test_sambay_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert "[16384,16384]" not in hlo  # no prompt's scores whole
 
 
-@pytest.mark.parametrize("program", ["decode_block", "admission_28672"])
-def test_eva_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
-    """One pipeline stage of EvaByte (8 of 32 layers, every width as
-    published, the whole byte vocabulary and all eight heads, bf16) at
-    the benchmark's engine sizes: 16 slots of 32,768 positions, 3,968
-    rows a slot-layer. A model with no "attn" layer: the ONE pair of row
-    leaves is the "eva" layers', updated in place by the token's write,
-    by the loop that folds a closed window and by an admission; a decode
-    step reads a slot's summaries and open window with the decode
-    attention's kernel (32 heads of 128) and copies no layer of the
-    stacked weights; the admission at the largest bucket never makes a
-    prompt's scores whole and leaves room on a 16 GB chip (ISSUE 55)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+def _eva_stage(v5e):
+    """One pipeline stage of EvaByte as the benchmark serves it, described
+    for the chip: (cfg, params, cache, the five lanes, arr)."""
     from ray_tpu.models import generation as gen
     from ray_tpu.models.transformer import TransformerConfig, init_params
 
@@ -642,6 +632,26 @@ def test_eva_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         lambda: gen.init_kv_cache(cfg, 16, 32768)))
     lanes = (arr((16,)), arr((16,)), arr((16,), jnp.float32), arr((16,)),
              arr((16,)))
+    return cfg, params, cache, lanes, arr
+
+
+@pytest.mark.parametrize("program", ["decode_block", "admission_28672"])
+def test_eva_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
+    """One pipeline stage of EvaByte (8 of 32 layers, every width as
+    published, the whole byte vocabulary and all eight heads, bf16) at
+    the benchmark's engine sizes: 16 slots of 32,768 positions, 3,968
+    rows a slot-layer. A model with no "attn" layer: the ONE pair of row
+    leaves is the "eva" layers', updated in place by the token's write,
+    by the loop that folds a closed window and by an admission; a decode
+    step reads a slot's summaries and open window with the decode
+    attention's kernel (32 heads of 128) and copies no layer of the
+    stacked weights; the admission at the largest bucket never makes a
+    prompt's scores whole, attends through a kernel that reads q, k, v
+    where they lie (ISSUE 65) and leaves room on a 16 GB chip (ISSUE 55)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+
+    cfg, params, cache, lanes, arr = _eva_stage(v5e)
     if program == "decode_block":
         low = gen.decode_block.lower(params, cache, *lanes, cfg, 8)
     else:
@@ -674,7 +684,16 @@ def test_eva_serving_programs_fit_one_v5e(v5e, program, monkeypatch):
         assert len(calls) == 1 and "decode_attention" in calls[0]
         assert "raytpu.eva.attend" in calls[0]
     else:
-        assert not calls  # plain jnp, block by block
+        # ISSUE 65: the windows' queries over the summaries before them
+        # and their own rows, in the kernel: one body, one call
+        assert len(calls) == 1 and "eva_attention" in calls[0]
+        assert "raytpu.eva.attend" in calls[0]
+        # q and k straight from the rotation, v from its projection, the
+        # output into its own: the call is handed no copy of the bucket
+        # (a kernel over [S, H x D] rows was handed three and gave one)
+        handed = calls[0].split("custom-call(")[1].split(")")[0]
+        assert "copy" not in handed, handed
+        assert not _copies(hlo, "bf16[1,32,")
         assert "[28672,28672]" not in hlo  # no prompt's scores whole
 
 
@@ -847,6 +866,48 @@ def test_gptj_serving_programs_copy_no_stacked_weight(
             r"= s8\[1,4096,16,256\]\S* (?:fusion|copy)\(", hlo)
     before = _lower(program, as_made, cache, cfg, v5e).compile().as_text()
     assert len(_copies(before, "s8[")) == 3  # what the layouts took away
+
+
+@pytest.mark.parametrize("bucket", [2560, 8192, 28672])
+def test_eva_admission_attends_through_its_kernel(v5e, bucket, monkeypatch):
+    """ISSUE 65, from the lowered text alone (nothing is compiled):
+    EvaByte's admission holds ONE kernel (eight layers, one body), called
+    under ``raytpu.eva.attend`` with a head's queries and keys as
+    [128, the bucket's whole windows] (2,560: a window and a quarter,
+    padded to two), its values and its output [the windows, 128], and the
+    whole windows' summaries alike (in blocks of 1,024 where they are
+    more), and no float32 scores of a block of 256 queries against a
+    window or the bucket's summaries (the parent's ``lax.map``:
+    ``[1, 32, 256, 2048]`` and ``[1, 32, 256, NS]``)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from ray_tpu.models import generation as gen
+
+    cfg, params, cache, lanes, arr = _eva_stage(v5e)
+    text = gen.prefill_into_slot.lower(
+        params, arr((1, bucket)), arr(()), arr(()), cache, cfg, lanes,
+        arr((), jnp.float32), arr(())).as_text(debug_info=True)
+    tokens = -(-bucket // 2048) * 2048
+    summaries = bucket // 2048 * 128
+    summaries = -(-summaries // min(summaries, 1024)) * min(summaries, 1024)
+    kernels = [line for line in text.splitlines()
+               if "stablehlo.custom_call @tpu_custom_call" in line]
+    assert len(kernels) == 1
+
+    def columns(n):
+        return f"tensor<1x32x128x{n}xbf16>"
+
+    def rows(n):
+        return f"tensor<1x32x{n}x128xbf16>"
+
+    assert kernels[0].rstrip().rsplit(" : ", 1)[1].startswith(
+        f"(tensor<1xi32>, {columns(tokens)}, {columns(tokens)}, "
+        f"{rows(tokens)}, {columns(summaries)}, {rows(summaries)}) -> "
+        f"{rows(tokens)}")
+    site = re.search(r"call @eva_attention\(.*loc\((#loc\d+)\)",
+                     text).group(1)
+    assert f'{site} = loc("raytpu.eva.attend/jit(eva_attention)' in text
+    assert not re.findall(r"tensor<[\dx]*32x256x\d+xf32>", text)
+    assert "stablehlo.while" in text  # the scan over the eight layers stays
 
 
 def _admission_forms(params, cache, cfg, slots, bucket, v5e):

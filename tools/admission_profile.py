@@ -9,6 +9,8 @@ a cache of the cell's size.
     chiprun -- python3 tools/admission_profile.py \\
         --config glm47flash-l8-bf16-serve --min-bucket 1024 \\
         --score-bytes 0 1000000000000 --repeats 3
+    chiprun -- python3 tools/admission_profile.py \\
+        --config evabyte-l8-bf16-serve --buckets 4096 8192 24576
     python3 tools/admission_profile.py --config gptj-6b-int8-serve --tiny
 
 ``--score-bytes N [M ...]`` puts N in ``ops/attention.PREFILL_SCORE_BYTES``'
@@ -70,6 +72,7 @@ def main(argv=None):
     p.add_argument("--score-bytes", type=int, nargs="+", default=[None])
     p.add_argument("--min-bucket", type=int, default=0)
     p.add_argument("--max-bucket", type=int, default=None)
+    p.add_argument("--buckets", type=int, nargs="*", default=None)
     p.add_argument("--repeats", type=int, default=1)
     p.add_argument("--fill", type=float, default=0.8)
     p.add_argument("--calls", type=int, default=4)
@@ -103,7 +106,8 @@ def main(argv=None):
     out = {}
     for bucket in eng["prefill_buckets"]:
         if bucket < args.min_bucket or (
-                args.max_bucket and bucket > args.max_bucket):
+                args.max_bucket and bucket > args.max_bucket) or (
+                args.buckets and bucket not in args.buckets):
             continue
         n = max(int(bucket * args.fill), 1)
         padded = np.zeros((1, bucket), np.int32)
@@ -165,7 +169,8 @@ def main(argv=None):
                     / max(len(runs), 1),
                     kernel_ms=1e3 * sum(
                         s for k, (_c, s, _t) in ops.items()
-                        if "prefill_attention" in k) / args.calls,
+                        if k.startswith(("prefill_attention",
+                                         "eva_attention"))) / args.calls,
                     # operations whose RESULT is an int8 array: a weight
                     # sliced out of its stack or laid out again before it
                     # is dequantised (0.0 where every product reads its

@@ -11,7 +11,7 @@ is compiled and no weight is made (shapes alone), so it runs anywhere.
 A PR that means to leave a configuration's programs alone shows it with
 an empty diff against its parent (``git archive`` of the parent into a
 scratch directory); where lines differ they name the programs to measure.
-Names: gptj glm47 glm52 granite mimo kimi phi4flash evabyte train (default: all
+Names: gptj glm47 glm52 granite mimo kimi phi4flash evabyte nemotron train (default: all
 the tree has). ``gptj`` prints its programs twice: from the weights as they are
 made, and (``gptj-as-held``, since PR 62) from int8 leaves that say where a
 v5e's engine has laid them, which its admissions read and its decode does not.
@@ -37,6 +37,7 @@ SERVED = {  # name -> (module of benchmarks/, configuration)
     "kimi": ("kda_moe_model", "kimi-linear-l8-e64-bf16-serve"),
     "phi4flash": ("sambay_model", "phi4-mini-flash-bf16-serve"),
     "evabyte": ("eva_model", "evabyte-l8-bf16-serve"),
+    "nemotron": ("ssm_moe_model", "nemotron3-super-l11-e128-bf16-serve"),
 }
 
 

@@ -9,6 +9,7 @@ passes it.
     chiprun -- python3 tools/prefill_attention_micro.py --dense   # one product
     chiprun -- python3 tools/prefill_attention_micro.py --chosen  # under a mask
     chiprun -- python3 tools/prefill_attention_micro.py --slot    # GPT-J's buckets
+    chiprun -- python3 tools/prefill_attention_micro.py --eva     # EvaByte's windows
     python3 tools/prefill_attention_micro.py --tiny                # here
 
 A layer-call's time is the host's clock over ``--calls`` calls dispatched
@@ -39,7 +40,16 @@ the slot in one product (whose float32 scores, 64 MiB, the compiler keeps
 in fast memory: PERF.md, section 6, PR 58 / 59), and ``one_product``,
 ``causal_attention`` over the prompt alone, the form it takes since. There a call is one of 28 in
 one program, each one's output the next one's queries: a call of 0.1 ms is
-under a dispatch of its own. ``--tiny`` walks the same
+under a dispatch of its own. ``--eva`` is EvaByte's
+admission (32 heads of their own, 128 wide, windows of 2,048 in chunks of
+16; 4,096 / 8,192 / 24,576 bytes, each whole and with a prompt of 0.85 of
+it): ``ops/eva.eva_attention`` (its kernel: a window's queries over the
+closed windows' summaries and the window's rows up to the diagonal;
+``--blocks`` pairs here too, ``QxKxP`` with a block of P summaries) beside ``eva_blocks_of_256``, this tool's own copy
+of the form it took until PR 65 (blocks of 256 queries against their whole
+window and every summary of the bucket, in ``jnp``), with the (query, row
+or summary) pairs each form multiplies a head against the pairs the prompt
+needs. ``--tiny`` walks the same
 code at a toy size through the Pallas interpreter and reports no rate: a
 time off the chip is no device number. A tool: no cell and no metric reads
 it. It runs from the parent's tree too (``PYTHONPATH=<tree>``, run from
@@ -51,8 +61,10 @@ bucket.
 from __future__ import annotations
 
 import argparse
+import functools
 import inspect
 import json
+import math
 import os
 import sys
 import time
@@ -291,6 +303,164 @@ def slot(args, dev, say):
             say(out)
 
 
+# EvaByte's admission: heads (each its own KV head), width, window, chunk,
+# buckets, the share of a bucket the shorter prompt fills
+EVA = (32, 128, 2048, 16, (4096, 8192, 24576), 0.85)
+
+
+def eva_blocks_of_256(q, k, v, ks, vs, *, window, chunk, block=256):
+    """What ``ops/eva.eva_attention`` was until PR 65: q [B, S, H, D]
+    against k, v and the summaries ks, vs [B, NS, H, D], a block of
+    queries at a time against the ONE window it lies in (the rows past a
+    query masked) and ALL summaries (those of its own and later windows
+    masked), float32 scores, one softmax. No ``length``: a bucket's
+    padding is attended like the prompt."""
+    B, S, H, D = q.shape
+    scale = D ** -0.5
+    blk = attention.block_of(math.gcd(S, window), block)
+    per = window // chunk
+    n_sum = ks.shape[1]
+    tail = ((0, 0), (0, -k.shape[1] % window), (0, 0), (0, 0))
+    kp, vp = jnp.pad(k, tail), jnp.pad(v, tail)
+
+    def queries(i):
+        first = i * blk
+        w = first // window
+        qb = lax.dynamic_slice_in_dim(q, first, blk, 1)
+        kb = lax.dynamic_slice_in_dim(kp, w * window, window, 1)
+        vb = lax.dynamic_slice_in_dim(vp, w * window, window, 1)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
+                       preferred_element_type=F32) * scale
+        t = (first + jnp.arange(blk))[:, None]
+        seen = w * window + jnp.arange(window)[None, :] <= t
+        s = jnp.where(seen, s, attention.NEG_INF)
+        m = s.max(-1)  # [B,H,blk]
+        if n_sum:
+            ss = jnp.einsum("bqhd,bchd->bhqc", qb, ks,
+                            preferred_element_type=F32) * scale
+            closed = jnp.arange(n_sum) < per * w
+            ss = jnp.where(closed, ss, attention.NEG_INF)
+            m = jnp.maximum(m, ss.max(-1))
+        p = jnp.where(seen, jnp.exp(s - m[..., None]), 0.0)
+        total = p.sum(-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), vb,
+                         preferred_element_type=F32)
+        if n_sum:
+            ps = jnp.where(closed, jnp.exp(ss - m[..., None]), 0.0)
+            total = total + ps.sum(-1)
+            out = out + jnp.einsum("bhqc,bchd->bqhd", ps.astype(q.dtype),
+                                   vs, preferred_element_type=F32)
+        return (out / total.transpose(0, 2, 1)[..., None]).astype(q.dtype)
+
+    out = lax.map(queries, jnp.arange(S // blk))  # [S/blk,B,blk,H,D]
+    return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, D)
+
+
+def eva_with_blocks(triple, window, chunk):
+    """``eva_attention`` with (queries, rows, summaries) blocks of
+    ``triple`` (None: the tree's own rule), a jit of its own, and the
+    (query, row or summary) pairs it then multiplies a head for
+    ``length`` real tokens of ``tokens``."""
+    from ray_tpu.ops import eva
+
+    rule = eva._blocks
+
+    def blocks(s, summaries, window, block):
+        n, bq, bk, bp, ahead = rule(s, summaries, window, block)
+        if triple is not None:
+            bq, bk = min(triple[0], window), min(triple[1], window)
+            bp = min(triple[2] if len(triple) > 2 else bk, summaries)
+            ahead = -(-summaries // bp) if bp else 0
+        return n, bq, bk, bp, ahead
+
+    def call(q, k, v, ks, vs, n):
+        # the kernel's own body, not its jit: the blocks are read at the
+        # trace, and a jit would hand back another triple's trace
+        eva._blocks = blocks
+        try:
+            return eva.eva_attention.__wrapped__(
+                q, k, v, ks, vs, n, window=window, chunk=chunk)
+        finally:
+            eva._blocks = rule
+
+    def pairs(tokens, length):
+        per = window // chunk
+        _, bq, bk, bp, _ = blocks(tokens, tokens // window * per, window, 1024)
+        done = 0
+        for w in range(-(-tokens // window)):
+            real = min(max(length - w * window, 0), window)
+            for i in range(-(-real // bq)):
+                done += bq * ((i * bq // bk + 1) * bk
+                              + -(-per * w // max(bp, 1)) * bp)
+        return done, (bq, bk, bp)
+
+    return jax.jit(call), pairs
+
+
+def eva(args, dev, say):
+    """A layer's attention of an EvaByte admission, by form."""
+    from ray_tpu.ops.eva import eva_attention, eva_pool
+
+    heads, d, window, chunk, sizes, fill = EVA
+    if args.tiny:
+        heads, d, window, chunk, sizes = 2, 16, 32, 4, (48, 96)
+    per = window // chunk
+    flop = 4 * d * heads  # a (query, row) pair, all heads, both products
+    peak = None if args.tiny else peaks_for(dev.device_kind)["flops_bf16"]
+    triples = [None] + [tuple(int(n) for n in b.split("x"))
+                        for b in args.blocks]
+    if args.tiny:
+        triples = [None, (8, 8), (8, 16, 16)]
+    forms = {}
+    if "length" in inspect.signature(eva_attention).parameters:
+        forms = {"rule" if t is None else "x".join(map(str, t)):
+                 eva_with_blocks(t, window, chunk) for t in triples}
+    before = jax.jit(functools.partial(
+        eva_blocks_of_256, window=window, chunk=chunk))
+    calls = 1 if args.tiny else args.calls
+    for tokens in sizes:
+        q, k, v = inputs(args.seed, tokens, heads, heads, d, d)
+        ws = jax.random.split(jax.random.key(args.seed + 3), 2)
+        whole = tokens // window * window
+        ks, vs = eva_pool(k[:, :whole], v[:, :whole], *(
+            jax.random.normal(w, (heads, d)) * d ** -0.5 for w in ws), chunk)
+        row = {"geometry": "eva", "tokens": tokens, "device": dev.device_kind}
+        best = timed(before, q, k, v, ks, vs, calls=calls)
+        every = tokens * (window + ks.shape[1])
+        want = before(q, k, v, ks, vs).astype(F32) if args.tiny else None
+        if not args.tiny:
+            say({**row, "form": "eva_blocks_of_256", "prompt_len": tokens,
+                 "ms_a_call": 1e3 * best, "pairs_computed": every,
+                 "tflops_computed": flop * every / best / 1e12,
+                 "peak_share_computed": 100 * flop * every / best / peak})
+        for length in (tokens, max(int(tokens * fill), 1)):
+            need = sum(t % window + 1 + per * (t // window)
+                       for t in range(length))
+            for name, (fn, pairs) in forms.items():
+                done, (bq, bk, bp) = pairs(tokens, length)
+                if bk % bq or window % bk:
+                    continue
+                best = timed(fn, q, k, v, ks, vs, jnp.int32(length),
+                             calls=calls)
+                out = {**row, "form": "kernel", "blocks": name, "bq": bq,
+                       "bk": bk, "bp": bp, "prompt_len": length,
+                       "pairs_computed": done, "pairs_useful": need}
+                if args.tiny:  # the block loop's answer, and zeros past it
+                    got = fn(q, k, v, ks, vs, jnp.int32(length)).astype(F32)
+                    out["err"] = float(jnp.abs(got - want)[:, :length].max()
+                                       / jnp.abs(want).max())
+                    out["past_length_all_zero"] = not bool(
+                        got[:, length:].any())
+                else:
+                    out.update(ms_a_call=1e3 * best,
+                               tflops_computed=flop * done / best / 1e12,
+                               peak_share_computed=100 * flop * done / best
+                               / peak,
+                               peak_share_useful=100 * flop * need / best
+                               / peak)
+                say(out)
+
+
 def timed(fn, *args, calls):
     jax.block_until_ready(fn(*args))
     best = float("inf")
@@ -311,6 +481,7 @@ def main(argv=None):
     p.add_argument("--dense", action="store_true")
     p.add_argument("--chosen", action="store_true")
     p.add_argument("--slot", action="store_true")
+    p.add_argument("--eva", action="store_true")
     p.add_argument("--blocks", nargs="*",
                    default=["256x256", "512x512", "1024x1024"])
     p.add_argument("--tiny", action="store_true")
@@ -329,7 +500,9 @@ def main(argv=None):
         chosen(args, dev, say)
     if args.slot:
         slot(args, dev, say)
-    for name in () if args.chosen or args.slot else args.geometry:
+    if args.eva:
+        eva(args, dev, say)
+    for name in () if args.chosen or args.slot or args.eva else args.geometry:
         heads, kv_heads, d, dv, fill = GEOMETRIES[name]
         if args.tiny:
             heads, kv_heads, d, dv = heads // 8, max(kv_heads // 8, 1), 24, 16
