@@ -32,6 +32,33 @@ def where():
     return ray_tpu.get_runtime_context().get_node_id()
 
 
+def _wait_until_every_node_has_an_idle_worker(timeout=120.0):
+    """``cluster2`` yields when the second raylet has registered, while
+    that raylet's prestarted workers are still starting (seconds each under
+    six test workers). A lease is not bound to a task: one granted on the
+    head works through the driver's queue for as long as the other node's
+    grant waits for a worker, so a test that counts the nodes its tasks ran
+    on within a few task-lengths assumes the other node can grant at once.
+    This waits for that: an idle worker at every raylet (by then its first
+    heartbeat, which carries its view to the head's raylet, is long out)."""
+    import ray_tpu._private.rpc as rpc
+
+    deadline = time.monotonic() + timeout
+    idle = {}
+    while time.monotonic() < deadline:
+        for n in ray_tpu.nodes():
+            client = rpc.Client.connect(n["raylet_addr"], timeout=5)
+            try:
+                idle[n["raylet_addr"]] = client.call(
+                    "node_stats", None, timeout=5)["num_idle"]
+            finally:
+                client.close()
+        if all(idle.values()):
+            return
+        time.sleep(0.1)
+    raise AssertionError(f"a node's workers never came up: {idle}")
+
+
 def test_two_nodes_visible(cluster2):
     assert len([n for n in ray_tpu.nodes() if n["alive"]]) == 2
     res = ray_tpu.cluster_resources()
@@ -59,6 +86,7 @@ def test_spillback_when_local_full(cluster2):
         time.sleep(2)
         return ray_tpu.get_runtime_context().get_node_id()
 
+    _wait_until_every_node_has_an_idle_worker()
     refs = [hold.remote() for _ in range(4)]
     nodes = set(ray_tpu.get(refs, timeout=240))
     assert len(nodes) == 2, f"expected both nodes used, got {nodes}"
@@ -298,6 +326,7 @@ def test_spread_strategy(cluster2):
         time.sleep(1.0)
         return ray_tpu.get_runtime_context().get_node_id()
 
+    _wait_until_every_node_has_an_idle_worker()
     nodes = set(ray_tpu.get([spread_where.remote() for _ in range(4)],
                             timeout=120))
     assert len(nodes) == 2, f"SPREAD used one node: {nodes}"

@@ -8,7 +8,7 @@ up.
 
 What it shares with the other served models
 (the parameter tree, the uncached forward, the two programs through a
-slot, ``generate``, the ablations, the reference's two copies, the cell's
+slot, ``generate``, the ablations, the reference's independence, the cell's
 listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
@@ -19,9 +19,9 @@ import numpy as np
 import pytest
 from jax import lax
 
+from benchmarks import reference_mla_moe as ref_mla
+from benchmarks import reference_dsa_moe as ref
 from ray_tpu.models import generation as gen
-from ray_tpu.models import reference as ref_mla
-from ray_tpu.models import reference_dsa as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
     init_params,

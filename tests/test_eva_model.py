@@ -21,8 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import reference_eva as ref
 from ray_tpu.models import generation as gen
-from ray_tpu.models import reference_eva as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
     forward,

@@ -9,7 +9,7 @@ the quarter shares of a routed layer against the uncut layer, ill-formed
 
 What it shares with the other served models
 (the parameter tree, the uncached forward, the two programs through a
-slot, ``generate``, the ablations, the reference's two copies, the cell's
+slot, ``generate``, the ablations, the reference's independence, the cell's
 listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
@@ -23,8 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import reference_kda_moe as ref
 from ray_tpu.models import generation as gen
-from ray_tpu.models import reference_kda as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
     init_params,

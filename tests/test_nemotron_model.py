@@ -13,8 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import reference_mla_moe as reference
+from benchmarks import reference_ssm_moe as ref
 from ray_tpu.models import generation as gen
-from ray_tpu.models import reference, reference_ssm_moe as ref
 from ray_tpu.models.transformer import (
     _ACTIVATIONS,
     TransformerConfig,

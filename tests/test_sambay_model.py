@@ -11,7 +11,7 @@ benchmark's arithmetic.
 What it shares with the other served models (the parameter tree, the
 uncached forward, the two programs through a slot with prompts on both
 sides of the window and lanes at different depths, a reused slot through
-``LLMEngine``, ``generate``, the ablations, the reference's two copies,
+``LLMEngine``, ``generate``, the ablations, the reference's independence,
 the cell's listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
@@ -24,8 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import reference_sambay as ref
 from ray_tpu.models import generation as gen
-from ray_tpu.models import reference_sambay as ref
 from ray_tpu.models import transformer as tf
 from ray_tpu.models.transformer import (
     TransformerConfig,

@@ -2,8 +2,10 @@
 one row of ``MODELS`` a model: the parameter tree against its axes and its
 count, the uncached forward, the engine's two programs through a slot
 (rows, rings, states), a prefill beside other slots, a reused slot,
-``generate``, the ablations a comparison must refuse, the two copies of
-the reference, and the model's benchmark cell resolved and rehearsed.
+``generate``, the ablations a comparison must refuse, the reference's
+independence of the program, and the model's benchmark cell resolved and
+rehearsed. The reference is the benchmark's own file, the one that decides
+a cell's ``correct``: there is no other.
 What only one model has (its kernels, scans, selection, shares, published
 numbers) is in that model's own file. A new model is a row here.
 
@@ -24,18 +26,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import generation as gen
-from ray_tpu.ops import attention
-from ray_tpu.models import (
-    reference,
-    reference_dsa,
+from benchmarks import (
+    reference_dsa_moe,
     reference_eva,
-    reference_kda,
+    reference_kda_moe,
+    reference_mla_moe,
     reference_sambay,
     reference_ssm,
     reference_ssm_moe,
-    reference_swa,
+    reference_swa_moe,
 )
+from ray_tpu.models import generation as gen
+from ray_tpu.ops import attention
 from ray_tpu.models.transformer import (
     TransformerConfig,
     forward,
@@ -45,7 +47,7 @@ from ray_tpu.models.transformer import (
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32 = jnp.float32
-# what the benchmark's copy of a reference may not name: the program's code
+# what a reference may not name below its header: the program's code
 PROGRAM = ("ray_tpu", "generation", "transformer", "ops.")
 
 
@@ -76,10 +78,9 @@ class Cell(NamedTuple):
 
 class Model(NamedTuple):
     cfg: TransformerConfig  # the tiny preset, float32
-    ref: object  # the reference under ray_tpu/models/
+    ref: object  # the plain reference: the benchmark's module
     hp: Dict  # what the reference is told of the config
-    copy: str  # the reference's second copy under benchmarks/
-    foreign: Tuple[str, ...]  # what the copies' shared body may not name
+    foreign: Tuple[str, ...]  # what the reference's body may not name
     tol: float  # float32 against float32: rounding order only
     metric: int  # of ``vector_distance``: 0 largest, 1 root mean square
     stacks: Dict[str, set]  # keys of each stack of the parameter tree
@@ -237,8 +238,8 @@ _REFUSED = Through({0: 21}, 1, 64, 32, 12)
 
 MODELS = {
     "mla": Model(
-        cfg=MLA, ref=reference, hp=_latent_hp(MLA),
-        copy="benchmarks/reference_mla_moe.py", foreign=("ray_tpu", "pallas"),
+        cfg=MLA, ref=reference_mla_moe, hp=_latent_hp(MLA),
+        foreign=("ray_tpu", "pallas"),
         tol=1e-4, metric=1,
         stacks={"dense_layers": {"ln1", "ln2", "attn", "mlp"},
                 "layers": {"ln1", "ln2", "attn", "moe"}},
@@ -275,11 +276,11 @@ MODELS = {
             # divide what was retired between its middle and its end
             slower=0.8, seconds=16)),
     "dsa": Model(
-        cfg=DSA, ref=reference_dsa, hp={
+        cfg=DSA, ref=reference_dsa_moe, hp={
             **_latent_hp(DSA), "index_topk": DSA.index_topk,
             "indexer_types": DSA.indexer_types,
             "first_expert": DSA.moe_first_expert},
-        copy="benchmarks/reference_dsa_moe.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=1e-4, metric=1,
         stacks={"dense_layers": {"ln1", "ln2", "attn", "mlp"},
                 "layers": {"ln1", "ln2", "attn", "moe"}},
@@ -320,7 +321,7 @@ MODELS = {
             "layer_types": SSM.layer_types, "ssm_heads": SSM.ssm_heads,
             "ssm_head_dim": SSM.ssm_head_dim, "ssm_state": SSM.ssm_state,
             "ssm_groups": SSM.ssm_groups},
-        copy="benchmarks/reference_ssm.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=2e-4, metric=0,
         stacks={"layers": {"ln1", "ln2", "attn", "mlp"},
                 "ssm_layers": {"ln1", "ln2", "ssm", "mlp"}},
@@ -349,7 +350,7 @@ MODELS = {
              "engine.state_live_share", "kernel.decode_hbm_share.ssm")
             + _SERVED, _ssm_rehearsed)),
     "swa": Model(
-        cfg=SWA, ref=reference_swa, hp={
+        cfg=SWA, ref=reference_swa_moe, hp={
             "n_heads": SWA.n_heads,
             "kv_heads": {"F": SWA.mha_kind(False)[0],
                          "W": SWA.mha_kind(True)[0]},
@@ -362,7 +363,7 @@ MODELS = {
             "first_expert": SWA.moe_first_expert,
             "layer_types": SWA.layer_types,
             "n_dense_layers": SWA.n_dense_layers},
-        copy="benchmarks/reference_swa_moe.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=2e-4, metric=0,
         stacks={"dense_layers": {"ln1", "ln2", "attn", "mlp"},
                 "layers": {"ln1", "ln2", "attn", "moe"},
@@ -394,13 +395,13 @@ MODELS = {
              "kernel.decode_hbm_share.swa_moe", "model.moe_time_share")
             + _SERVED, _swa_rehearsed)),
     "kda": Model(
-        cfg=KDA, ref=reference_kda, hp={
+        cfg=KDA, ref=reference_kda_moe, hp={
             **_latent_hp(KDA),
             "first_expert": KDA.moe_first_expert,
             "layer_types": KDA.layer_types,
             "n_dense_layers": KDA.n_dense_layers,
             "kda_heads": KDA.kda_heads, "kda_head_dim": KDA.kda_head_dim},
-        copy="benchmarks/reference_kda_moe.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=2e-4, metric=0,
         stacks={"dense_layers": {"ln1", "ln2", "kda", "mlp"},
                 "kda_layers": {"ln1", "ln2", "kda", "moe"},
@@ -442,7 +443,7 @@ MODELS = {
             "window": SAMBAY.window, "layer_types": SAMBAY.layer_types,
             "mamba_state": SAMBAY.mamba_state,
             "mamba_dt_rank": SAMBAY.mamba_dt_rank},
-        copy="benchmarks/reference_sambay.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=2e-4, metric=0,
         stacks={"layers": {"ln1", "ln2", "attn", "mlp"},
                 "mamba_layers": {"ln1", "ln2", "mamba", "mlp"},
@@ -495,7 +496,7 @@ MODELS = {
             "eps": EVA.norm_eps, "theta": EVA.rope_theta,
             "window": EVA.eva_window, "chunk": EVA.eva_chunk,
             "n_pred_heads": EVA.n_pred_heads},
-        copy="benchmarks/reference_eva.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=2e-4, metric=0,
         stacks={"eva_layers": {"ln1", "ln2", "eva", "mlp"}},
         shapes={"eva_layers/eva/phi": (3, 4, 16),
@@ -543,7 +544,7 @@ MODELS = {
             "top_k": SSM_MOE.moe_top_k,
             "route_scale": SSM_MOE.moe_route_scale,
             "first_expert": SSM_MOE.moe_first_expert},
-        copy="benchmarks/reference_ssm_moe.py", foreign=PROGRAM,
+        foreign=PROGRAM,
         tol=2e-4, metric=0,
         # one norm and ONE branch a layer
         stacks={"layers": {"ln1", "attn"}, "ssm_layers": {"ln1", "ssm"},
@@ -662,7 +663,7 @@ def worst_margin(m: Model, params, prompt, ids):
     logits, _ = ref_logits(m, params, list(prompt) + list(ids[:-1]))
     if logits.ndim == 3:  # several prediction heads: the next token's
         logits = logits[:, 0]
-    return float(reference.served_token_margin(
+    return float(reference_mla_moe.served_token_margin(
         logits[len(prompt) - 1:], jnp.asarray(ids, jnp.int32)).max())
 
 
@@ -702,7 +703,7 @@ def test_the_uncached_forward_matches_the_reference(name):
         return
     got = forward(params, toks[None], m.cfg)[0]
     want, _ = ref_logits(m, params, toks)
-    assert float(reference.vector_distance(got, want)[m.metric]) < m.tol
+    assert float(reference_mla_moe.vector_distance(got, want)[m.metric]) < m.tol
 
 
 @pytest.mark.parametrize("name,case", [
@@ -722,7 +723,7 @@ def test_prefill_and_decode_through_a_slot_match_the_reference(
     for slot, n in run.lanes.items():
         want, want_states = ref_logits(m, params, toks[slot])
         for step, logits in enumerate(got[slot]):
-            assert float(reference.vector_distance(
+            assert float(reference_mla_moe.vector_distance(
                 logits, want[n - 1 + step])[m.metric]) < m.tol, (slot, step)
         if m.state:
             leaf, bound = m.state
@@ -811,7 +812,7 @@ def test_each_ablation_fails_the_comparison(name, ablate):
 
     def distance(**kw):
         want, states = ref_logits(m, params, toks, **kw)
-        far = float(reference.vector_distance(last, want[-1])[1])
+        far = float(reference_mla_moe.vector_distance(last, want[-1])[1])
         if m.state:
             far = max(far, float(m.ref.state_distance(
                 state, states[m.ablated_state])))
@@ -821,20 +822,20 @@ def test_each_ablation_fails_the_comparison(name, ablate):
 
 
 @each_model
-def test_reference_copies_are_identical_below_their_headers(name):
+def test_the_reference_names_none_of_the_programs_code(name):
+    """The benchmark's file has its marker line once (above it: the
+    docstring, and the import of the reference it builds on), and below it
+    names none of the program's code, so that what decides ``correct`` can
+    share no fault with what it judges."""
     m = MODELS[name]
     marker = "# ---- below this line the two copies are identical ----\n"
-
-    def body(path):
-        with open(os.path.join(ROOT, path)) as f:
-            text = f.read()
-        assert text.count(marker) == 1
-        return text.split(marker)[1]
-
-    mine = body(os.path.relpath(m.ref.__file__, ROOT))
-    assert mine == body(m.copy)
+    assert os.path.dirname(m.ref.__file__) == os.path.join(ROOT, "benchmarks")
+    with open(m.ref.__file__) as f:
+        text = f.read()
+    assert text.count(marker) == 1
+    body = text.split(marker)[1]
     for name in m.foreign:
-        assert name not in mine  # none of the program's code
+        assert name not in body  # none of the program's code
 
 
 # -- the benchmark resolves and rehearses the model's cell -------------------

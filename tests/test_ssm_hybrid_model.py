@@ -8,7 +8,7 @@ defaults.
 
 What it shares with the other served models
 (the parameter tree, the uncached forward, the two programs through a
-slot, ``generate``, the ablations, the reference's two copies, the cell's
+slot, ``generate``, the ablations, the reference's independence, the cell's
 listing and rehearsal) is ``tests/test_served_models.py``'s."""
 
 import dataclasses
@@ -19,8 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import reference_ssm as ref
 from ray_tpu.models import generation as gen
-from ray_tpu.models import reference_ssm as ref
 from ray_tpu.models.transformer import (
     TransformerConfig,
     init_params,

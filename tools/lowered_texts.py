@@ -24,12 +24,10 @@ import importlib
 import json
 import os
 import sys
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from typing import Any, Dict, NamedTuple, Tuple
 
 SERVED = {  # name -> (module of benchmarks/, configuration)
+    "gptj": ("runners.serve", "gptj-6b-int8-serve"),
     "glm47": ("mla_moe_model", "glm47flash-l8-bf16-serve"),
     "glm52": ("dsa_moe_model", "glm52-l6-e16-bf16-serve"),
     "granite": ("ssm_model", "granite4-h-micro-bf16-serve"),
@@ -41,77 +39,135 @@ SERVED = {  # name -> (module of benchmarks/, configuration)
 }
 
 
+class Served(NamedTuple):
+    """What one configuration's cells serve, as shapes: no weight is made."""
+    cfg: Any  # the ``TransformerConfig`` the cell's runner builds
+    params: Any  # the weights as the runner makes them
+    cache: Any  # ``engine["max_slots"]`` slots of ``engine["max_len"]`` rows
+    lanes: Tuple  # the engine's five arrays, an entry a slot
+    engine: Dict  # the file's ``run.engine``: sizes, buckets, block lengths
+    sharding: Any  # what every array above is described on, or None
+
+    @property
+    def block_steps(self):
+        """The two lengths of ``decode_block``: under a burst, and else."""
+        return (self.engine["burst_block_steps"], self.engine["block_steps"])
+
+    def arr(self, shape, dtype="int32"):
+        import jax
+
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self.sharding)
+
+    def lower(self, program: str):
+        """``decode_block_<steps>``, ``decode_step_multi``,
+        ``prefill_<bucket>`` (``prefill_into_slot`` alone) or
+        ``admission_<bucket>`` (the form the engine calls: with the lanes,
+        the request's temperature and its seed), lowered."""
+        from ray_tpu.models import generation as gen
+
+        arr, slots = self.arr, self.engine["max_slots"]
+        if program == "decode_step_multi":
+            return gen.decode_step_multi.lower(
+                self.params, arr((slots,)), self.cache, arr((slots,)),
+                self.cfg)
+        kind, size = program.rsplit("_", 1)
+        sizes = {"decode_block": self.block_steps,
+                 "prefill": self.engine["prefill_buckets"],
+                 "admission": self.engine["prefill_buckets"]}[kind]
+        if int(size) not in sizes:
+            raise ValueError(f"{program}: the configuration's are {sizes}")
+        if kind == "decode_block":
+            return gen.decode_block.lower(
+                self.params, self.cache, *self.lanes, self.cfg, int(size))
+        args = (self.params, arr((1, int(size))), arr(()), arr(()),
+                self.cache, self.cfg)
+        if kind == "admission":
+            args += (self.lanes, arr((), "float32"), arr(()))
+        return gen.prefill_into_slot.lower(*args)
+
+
+def served(name: str, sharding=None) -> Served:
+    """The configuration ``SERVED[name]`` as its runner serves it, read from
+    the benchmark's own file: the model through the benchmark's
+    ``transformer_config``, the weights' shapes through its maker (GPT-J's
+    int8 leaves as ``prepare_for_inference`` leaves them), the engine's
+    sizes from ``run.engine``. ``sharding`` describes every array on a
+    device (``tests/test_tpu_compile.py``: a v5e that is not attached)."""
+    import jax
+
+    from benchmarks import common
+    from ray_tpu.models import generation as gen
+
+    module, config = SERVED[name]
+    mod = importlib.import_module("benchmarks." + module)
+    with open(os.path.join(common.HERE, "configs", config + ".json")) as f:
+        model = json.load(f)
+    cfg = mod.transformer_config(model)
+    if name == "gptj":
+        params = jax.eval_shape(
+            lambda: gen.prepare_for_inference(
+                mod.make_int8_params(cfg, 1), cfg)[0])
+        cfg = gen.prepare_for_inference({}, cfg)[1]
+    else:
+        params = jax.eval_shape(lambda: mod.make_bf16_params(cfg, 1))
+    eng = model["run"]["engine"]
+    slots = eng["max_slots"]
+    cache = jax.eval_shape(
+        lambda: gen.init_kv_cache(cfg, slots, eng["max_len"]))
+    lanes = tuple(jax.ShapeDtypeStruct((slots,), dtype) for dtype in (
+        "int32", "int32", "float32", "int32", "int32"))
+    params, cache, lanes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (params, cache, lanes))
+    return Served(cfg, params, cache, lanes, eng, sharding)
+
+
 def main() -> int:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     root = os.path.abspath(sys.argv[1])
     sys.path.insert(0, root)
     os.chdir(root)
     import jax
-    import jax.numpy as jnp
 
     from ray_tpu.models import generation as gen
 
     def digest(lowered):
         return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
 
-    def arr(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    def load(name):
-        with open(f"benchmarks/configs/{name}.json") as f:
-            return json.load(f)
-
-    def programs(name, params, cfg, eng):
-        slots, rows = eng["max_slots"], eng["max_len"]
-        cache = jax.eval_shape(lambda: gen.init_kv_cache(cfg, slots, rows))
-        lanes = (arr((slots,)), arr((slots,)), arr((slots,), jnp.float32),
-                 arr((slots,)), arr((slots,)))
-        for steps in (2, 8):
-            print(name, "decode_block", steps, digest(gen.decode_block.lower(
-                params, cache, *lanes, cfg, steps)), flush=True)
-        print(name, "decode_step_multi", digest(gen.decode_step_multi.lower(
-            params, arr((slots,)), cache, arr((slots,)), cfg)))
-        for b in eng["prefill_buckets"]:
-            args = (params, arr((1, b)), arr(()), arr(()), cache, cfg)
-            print(name, "prefill plain", b, digest(
-                gen.prefill_into_slot.lower(*args)))
-            print(name, "prefill admission", b, digest(
-                gen.prefill_into_slot.lower(
-                    *args, lanes, arr((), jnp.float32), arr(()))),
-                flush=True)
+    def programs(name, s):
+        for steps in s.block_steps:
+            print(name, "decode_block", steps,
+                  digest(s.lower(f"decode_block_{steps}")), flush=True)
+        print(name, "decode_step_multi", digest(s.lower("decode_step_multi")))
+        for b in s.engine["prefill_buckets"]:
+            print(name, "prefill plain", b, digest(s.lower(f"prefill_{b}")))
+            print(name, "prefill admission", b,
+                  digest(s.lower(f"admission_{b}")), flush=True)
 
     have = [n for n, (_, c) in SERVED.items()
             if os.path.exists(f"benchmarks/configs/{c}.json")]
-    which = sys.argv[2:] or ["gptj", *have, "train"]
-    if "gptj" in which:
-        from benchmarks.runners import serve
-        model = load("gptj-6b-int8-serve")
-        cfg = serve.transformer_config(model)
-        params = jax.eval_shape(lambda: serve.make_int8_params(cfg, 1))
-        params = jax.eval_shape(
-            lambda p: gen.prepare_for_inference(p, cfg)[0], params)
-        cfg = gen.prepare_for_inference({}, cfg)[1]
-        programs("gptj", params, cfg, model["run"]["engine"])
-        if hasattr(gen, "told_where_they_lie"):
+    which = sys.argv[2:] or [*have, "train"]
+    for name in which:
+        if name not in SERVED:
+            continue
+        s = served(name)
+        programs(name, s)
+        if name == "gptj" and hasattr(gen, "told_where_they_lie"):
             # the same programs over the leaves as a v5e's engine holds
             # them (``lay_out_for_decode``: wq / wk / wv heads-major,
             # tests/test_tpu_compile.py): the admissions read that order
             from ray_tpu.models.quant import QTensor
 
-            attn = params["layers"]["attn"]
+            attn = s.params["layers"]["attn"]
             held = {w: QTensor(attn[w].q, attn[w].s, (0, 2, 1, 3))
                     for w in ("wq", "wk", "wv")}
-            programs("gptj-as-held", {**params, "layers": {
-                **params["layers"], "attn": {**attn, **held}}}, cfg,
-                model["run"]["engine"])
-    for name in which:
-        if name in SERVED:
-            mod = importlib.import_module("benchmarks." + SERVED[name][0])
-            model = load(SERVED[name][1])
-            cfg = mod.transformer_config(model)
-            programs(name, jax.eval_shape(
-                lambda: mod.make_bf16_params(cfg, 1)), cfg,
-                model["run"]["engine"])
+            programs("gptj-as-held", s._replace(params={
+                **s.params, "layers": {
+                    **s.params["layers"], "attn": {**attn, **held}}}))
     if "train" in which:
+        import jax.numpy as jnp
         import numpy as np
 
         import ray_tpu.parallel.mesh as pmesh
